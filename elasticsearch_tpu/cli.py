@@ -38,8 +38,10 @@ def cmd_version(_argv) -> int:
 
 def cmd_check(_argv) -> int:
     """Bootstrap checks (BootstrapChecks analog): device availability,
-    kernel smoke, HBM budget sanity."""
+    kernel smoke, HBM budget sanity. Names the platform, device kind
+    and device count it ran on — a pass on the CPU is a pass on the CPU."""
     failures = []
+    device = {"platform": None, "kind": None, "count": 0}
     import numpy as np
 
     try:
@@ -50,6 +52,11 @@ def cmd_check(_argv) -> int:
         if not devices:
             failures.append("no JAX devices available")
         else:
+            device = {
+                "platform": devices[0].platform,
+                "kind": devices[0].device_kind,
+                "count": len(devices),
+            }
             import jax.numpy as jnp
 
             out = jnp.sum(jnp.asarray(np.arange(8))).item()
@@ -66,6 +73,7 @@ def cmd_check(_argv) -> int:
             {
                 "checks_passed": not failures,
                 "failures": failures,
+                "device": device,
                 "hbm_budget_bytes": hbm_ledger.budget,
             }
         )

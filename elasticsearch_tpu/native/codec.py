@@ -1,11 +1,19 @@
 """ctypes bindings + build-on-demand for native/postings_codec.cpp,
-with a pure-NumPy fallback of identical semantics."""
+with a pure-NumPy fallback of identical semantics.
+
+The library is built from native/postings_codec.cpp and nothing else:
+its file name carries a hash of that source, so a binary built from
+another source (a stale copy from another tree, an older checkout) has
+another name and is never loaded. When no library can be built or
+loaded, the NumPy codec serves and says so once on stderr."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional, Tuple
 
@@ -21,8 +29,12 @@ def _source_path() -> str:
     return os.path.join(here, "native", "postings_codec.cpp")
 
 
-def _lib_path() -> str:
-    return os.path.join(os.path.dirname(__file__), "_libpostings.so")
+def _lib_path(src: str) -> str:
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(
+        os.path.dirname(__file__), f"_libpostings_{digest}.so"
+    )
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -34,20 +46,19 @@ def _load() -> Optional[ctypes.CDLL]:
             return _LIB
         _TRIED = True
         src = _source_path()
-        lib = _lib_path()
         try:
-            if not os.path.exists(src):
-                return None
-            if (
-                not os.path.exists(lib)
-                or os.path.getmtime(lib) < os.path.getmtime(src)
-            ):
+            lib = _lib_path(src)
+            if not os.path.exists(lib):
+                # build aside, then rename: concurrent processes (xdist
+                # workers) never load a half-written library
+                tmp = f"{lib}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", src, "-o", lib],
+                    ["g++", "-O3", "-shared", "-fPIC", src, "-o", tmp],
                     check=True,
                     capture_output=True,
                     timeout=120,
                 )
+                os.replace(tmp, lib)
             dll = ctypes.CDLL(lib)
             for name, argtypes in (
                 ("vb_encode", [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]),
@@ -63,8 +74,13 @@ def _load() -> Optional[ctypes.CDLL]:
                 fn.argtypes = argtypes
                 fn.restype = ctypes.c_int64
             _LIB = dll
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
             _LIB = None
+            print(
+                "elasticsearch_tpu.native: postings codec not built from "
+                f"{src} ({e!r}); using the NumPy codec",
+                file=sys.stderr,
+            )
         return _LIB
 
 

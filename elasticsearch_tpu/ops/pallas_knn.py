@@ -14,13 +14,19 @@ Quantization: symmetric per-vector int8 (scale = max|v| / 127), the
 moral equivalent of Lucene's int8_hnsw confidence-interval scheme
 (Lucene99ScalarQuantizedVectorsFormat) minus the percentile clipping.
 
-Works under `interpret=True` on CPU for tests; compiled on real TPU.
+Compiled by Mosaic unless the caller passes `interpret=True` (the
+CPU tests do, explicitly): no code here picks interpret mode from the
+backend it finds. The per-vector scales ride as a 2-D `[1, N]` row: a
+1-D f32 operand gets XLA's `T(1024)` tiling, which a 512-doc block
+cannot agree with (the v5e compiler refuses it), while a `(1, 512)`
+block of a 2-D row tiles cleanly; tests/test_chip_compile.py keeps
+that compile as a test.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,14 +64,14 @@ def _score_kernel(q_ref, qv_ref, scale_ref, out_ref):
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [B, DOC_BLOCK]
-    out_ref[:] = dots * scale_ref[:].reshape(1, -1)
+    out_ref[:] = dots * scale_ref[:]  # [1, DOC_BLOCK] row broadcasts over B
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def int8_dot_scores(
     queries: jax.Array,  # f32 [B, d_pad]
     qvecs: jax.Array,  # int8 [N_pad, d_pad], N_pad % DOC_BLOCK == 0
-    scales: jax.Array,  # f32 [N_pad]
+    scales: jax.Array,  # f32 [N_pad] (or already [1, N_pad])
     interpret: bool = False,
 ) -> jax.Array:
     """Dequantized dot products [B, N_pad] via the Pallas kernel."""
@@ -81,11 +87,13 @@ def int8_dot_scores(
             pl.BlockSpec(
                 (DOC_BLOCK, d), lambda i: (i, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec((DOC_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (1, DOC_BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM
+            ),
         ],
         out_specs=pl.BlockSpec((B, DOC_BLOCK), lambda i: (0, i)),
         interpret=interpret,
-    )(queries, qvecs, scales)
+    )(queries, qvecs, scales.reshape(1, N))
 
 
 class QuantizedVectors:
@@ -105,7 +113,7 @@ class QuantizedVectors:
             scales = np.pad(scales, (0, self.n_pad - self.n))
         self.d_pad = q.shape[1]
         self.qvecs = jnp.asarray(q)
-        self.scales = jnp.asarray(scales)
+        self.scales = jnp.asarray(scales).reshape(1, -1)
 
     def flops(self, n_queries: int) -> int:
         """Estimated useful flops of one search over this corpus, for
@@ -116,7 +124,7 @@ class QuantizedVectors:
         return 2 * n_queries * self.n * self.dims + n_queries * self.n
 
     def search(
-        self, queries: np.ndarray, k: int, interpret: Optional[bool] = None
+        self, queries: np.ndarray, k: int, interpret: bool = False
     ) -> Tuple[jax.Array, jax.Array]:
         """(scores[B,k], docs[B,k]) with the similarity score transform
         applied (models/similarity.py mapping, same as the f32 path).
@@ -126,8 +134,6 @@ class QuantizedVectors:
         here, so a batcher collect stage can feed them straight into
         ops/scoring.knn_merge_segment_topk alongside the f32 segments
         and pay one packed download for the whole group."""
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         q = np.asarray(queries, np.float32)
         if self.similarity == "cosine":
             qn = np.linalg.norm(q, axis=1, keepdims=True)

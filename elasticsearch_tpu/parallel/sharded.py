@@ -57,18 +57,7 @@ from ..models import bm25
 from ..ops.scoring import _score_tiles_inner, bm25_tile_contrib, next_bucket
 from .mesh import DATA_AXIS, SHARD_AXIS, fold_factor
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    # older jax (< 0.6): the API lives in jax.experimental and the
-    # replication-check kwarg is named check_rep, not check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=bool(check_vma),
-        )
+shard_map = jax.shard_map
 
 
 class ShardedTopK(NamedTuple):

@@ -608,6 +608,8 @@ class RestActions:
             "occupancy_jobs": 0,
             "occupancy_slots": 0,
             "express_lane_hits": 0,
+            "warmup_failures": 0,
+            "worker_compile_ms": 0.0,
         }
         # per-device roofline rows (straggler visibility): busy time and
         # flops merged by device id across every index's batcher
@@ -645,6 +647,8 @@ class RestActions:
                 batching["occupancy_jobs"] += bs["occupancy_jobs"]
                 batching["occupancy_slots"] += bs["occupancy_slots"]
                 batching["express_lane_hits"] += bs["express_lane_hits"]
+                batching["warmup_failures"] += bs["warmup_failures"]
+                batching["worker_compile_ms"] += bs["worker_compile_ms"]
             mex = getattr(idx, "_mesh", None)
             if mex is not None:
                 for k in mesh_stats:
@@ -653,26 +657,19 @@ class RestActions:
             from ..common.settings import pipeline_depth
 
             pipeline["depth"] = pipeline_depth()
-        if pipeline["device_busy_ms"] > 0:
-            from ..common.settings import peak_flops
+        from ..common.settings import mfu
 
-            pipeline["mfu"] = pipeline["flops"] / (
-                (pipeline["device_busy_ms"] / 1000.0) * peak_flops()
-            )
+        pipeline["mfu"] = mfu(
+            pipeline["flops"], pipeline["device_busy_ms"] / 1000.0
+        )
         pipeline["device_busy_ms"] = round(pipeline["device_busy_ms"], 3)
         pipeline["host_stall_ms"] = round(pipeline["host_stall_ms"], 3)
-        from ..common.settings import peak_flops as _peak
-
         pipeline["devices"] = [
             {
                 "id": d["id"],
                 "device_busy_ms": round(d["device_busy_ms"], 3),
                 "flops": int(d["flops"]),
-                "mfu": (
-                    d["flops"] / ((d["device_busy_ms"] / 1000.0) * _peak())
-                    if d["device_busy_ms"] > 0
-                    else 0.0
-                ),
+                "mfu": mfu(d["flops"], d["device_busy_ms"] / 1000.0),
             }
             for d in sorted(dev_agg.values(), key=lambda r: r["id"])
         ]

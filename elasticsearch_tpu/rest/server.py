@@ -21,6 +21,7 @@ from urllib.parse import parse_qs, unquote, urlparse
 
 from ..cluster import ClusterError, ClusterService
 from ..common.memory import CircuitBreakingException
+from ..common.settings import peak_flops_override
 from ..common.tracing import OPAQUE_ID_CTX
 from ..index.engine import EngineError, VersionConflictError
 from ..index.mapping import MappingParseError
@@ -199,6 +200,9 @@ class ElasticsearchTpuServer:
         data_path: Optional[str] = None,
         cluster: Optional[ClusterService] = None,
     ):
+        # a malformed ES_TPU_PEAK_FLOPS fails the start, not every later
+        # `_nodes/stats`
+        peak_flops_override()
         self.cluster = cluster or ClusterService(data_path=data_path)
         self.actions = RestActions(self.cluster)
         handler = type("BoundHandler", (ElasticHandler,), {"actions": self.actions})
@@ -222,9 +226,13 @@ class ElasticsearchTpuServer:
 def main(argv=None):
     # plugins install BEFORE any registry is consumed (NodeConstruction
     # ordering): ES_TPU_PLUGINS="module.path:ClassName,..."
+    from ..common.compile_cache import configure_compile_cache
     from ..plugins import plugins_service
 
     plugins_service.load_env()
+    # before JAX compiles anything: a cold node otherwise recompiles
+    # every kernel family x every row bucket on each start
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description="elasticsearch-tpu node")
     ap.add_argument("--port", type=int, default=9200, help="HTTP port")
     ap.add_argument("--host", default="127.0.0.1")

@@ -40,7 +40,7 @@ for name in ("match", "bool", "multi_match", "knn", "hybrid_rrf"):
     c = r["configs"][name]
     print(
         f"{name:12s} qps={c['qps']:<8} p50={c['p50_ms']}ms "
-        f"p50_batch1={c['p50_batch1_ms']}ms mfu={c['mfu']:.2e} "
+        f"p50_batch1={c['p50_batch1_ms']}ms mfu={c['mfu']} "
         f"device_util={c['device_util']:.3f} "
         f"flops/q={c['flops_per_query']:.3g}"
     )
@@ -48,7 +48,7 @@ p = r["pipeline"]
 print(
     f"pipeline     depth={p['depth']} device_busy={p['device_busy_ms']:.0f}ms "
     f"host_stall={p['host_stall_ms']:.0f}ms flops={p['flops']:.3g} "
-    f"mfu={p['mfu']:.2e}"
+    f"mfu={p['mfu']}"
 )
 print("SMOKE OK")
 PY

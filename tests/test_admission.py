@@ -372,6 +372,58 @@ class TestBrownoutTiers:
 # ---------------------------------------------------------------------
 
 
+class TestColdNode:
+    def test_cold_node_does_not_shed_its_first_requests(self, monkeypatch):
+        """A node's defaults (admission on, bucket warm-up on) on a cold
+        process: requests that fan out to more jobs than the batcher has
+        workers queue behind first-use compiles and warm-up ladders for
+        whole seconds. That wait is the compiler's, not load — it must
+        not reach the congestion EWMA: a plain sequence of requests is
+        served with nothing shed."""
+        monkeypatch.setenv("ES_TPU_MESH", "off")  # per-shard fan-out
+        admission.configure(enabled=True)
+        n_shards, dims = 8, 16
+        rng = np.random.default_rng(3)
+        svc = IndexService(
+            "cold-node",
+            settings={"number_of_shards": n_shards, "search.backend": "jax"},
+            mappings_json={"properties": {
+                "body": {"type": "text"},
+                "vec": {"type": "dense_vector", "dims": dims,
+                        "similarity": "cosine"},
+            }},
+        )
+        try:
+            svc._batcher.warmup_enabled = True
+            assert svc._batcher.workers < n_shards
+            for i in range(32 * n_shards):
+                words = rng.integers(0, 12, size=6)
+                svc.index_doc(str(i), {
+                    "body": " ".join(f"term{w}" for w in words),
+                    "vec": [float(x) for x in rng.normal(size=dims)],
+                })
+            svc.refresh()
+            bodies = [
+                {"query": {"match": {"body": "term0 term1"}}, "size": 5},
+                {"knn": {"field": "vec", "k": 5, "num_candidates": 20,
+                         "query_vector": [0.1] * dims}, "size": 5},
+                {"query": {"match": {"body": "term2 term3 term4"}},
+                 "size": 5},
+                {"query": {"bool": {
+                    "must": [{"term": {"body": "term0"}}],
+                    "should": [{"match": {"body": "term5"}}]}}, "size": 5},
+            ]
+            for body in bodies:  # back to back, no waiting for warm idle
+                assert svc.search(body)["hits"]["hits"]
+            st = admission.stats()
+            assert st["shed_rejected"] == 0 and st["brownouts"] == 0, st
+            assert st["queue_delay_ewma_ms"] < st["target_delay_ms"], st
+            # compiles were seen, and kept out of the signal
+            assert svc._batcher.batching_stats()["worker_compile_ms"] > 0.0
+        finally:
+            svc.close()
+
+
 class TestRetryBudget:
     def test_token_bucket_caps_retry_ratio(self):
         ctrl = _controller(retry_budget_ratio=0.1, retry_budget_cap=2.0)

@@ -1,0 +1,379 @@
+"""The main path's programs, compiled for a DESCRIBED TPU v5e (2x2) at
+the real shapes — no chip attached, nothing runs.
+
+What this guards: a kernel or program the chip's compiler refuses (a
+block that disagrees with XLA's tiling, a program that does not fit the
+device's memory, a step that cannot be partitioned over the mesh) fails
+here, on the CPU, in tier-1 — not on the first chip call. Interpret-mode
+Pallas tests and XLA-CPU parity tests cannot see any of that.
+
+What it is not: a chip run. A compile that passes says nothing about
+results or times; chip_smoke.py is the run.
+
+The topology is described inside a module-scoped fixture — never at
+import time, never in conftest.py, never autouse — and every compile
+happens in the test's own process: only one process may load the TPU
+library, and under xdist only the worker that is handed this file does.
+All such tests live in this ONE file for the same reason.
+
+Shapes are the one-chip share of MS MARCO passage the benchmark builds
+(bench.build_corpus: 1,000,000 docs, 50k-term body / 20k-term title
+vocabulary, 768-d fp16 vectors) as the batcher launches them (dtypes and
+plan widths read off a live CPU run of the same corpus at small scale).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from elasticsearch_tpu.ops import scoring
+from elasticsearch_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
+
+N_DOCS = 1_000_000
+DIMS = 768
+TILE = 128
+BODY_TILES = 250_000  # ~25M postings / 128 + per-term tail padding
+TITLE_TILES = 80_000
+BODY_HOT = 512  # dense uint8 hot-term rows (512 MiB cap / 1M docs = 536)
+TITLE_HOT = 256
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """(data=1, shards=4) over the four described devices — the layout
+    parallel/mesh.make_mesh builds for a 4-shard index on a 2x2 host."""
+    assert len(topo.devices) == 4
+    return Mesh(
+        np.asarray(topo.devices).reshape(1, 4), (DATA_AXIS, SHARD_AXIS)
+    )
+
+
+def _on(sharding):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return spec
+
+
+def _fits(compiled) -> int:
+    """Bytes ONE device needs for ONE program — arguments + outputs +
+    temporaries (not what else the process keeps resident) — and they
+    must fit the chip."""
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes
+        + m.output_size_in_bytes
+        + m.temp_size_in_bytes
+    )
+    assert total < HBM_BYTES, f"program needs {total} bytes of HBM"
+    return total
+
+
+# ---------------------------------------------------------------------------
+# exact kNN — the batched matmul + top-k the batcher launches per segment
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows", [1, 32])
+def test_exact_knn_topk_batch(one_chip, rows):
+    s = _on(one_chip)
+    compiled = scoring.knn_topk_batch.lower(
+        s((rows, DIMS), jnp.float32),
+        s((rows,), jnp.bool_),
+        s((N_DOCS, DIMS), jnp.float16),
+        s((N_DOCS,), jnp.bool_),
+        similarity="cosine",
+        k=100,
+    ).compile()
+    _fits(compiled)
+
+
+# ---------------------------------------------------------------------------
+# fused text programs at the plan shape the batcher sends
+# ---------------------------------------------------------------------------
+
+
+def _plan_width(n_fields: int) -> int:
+    # FusedScorer / MultiFusedScorer.plan_shape_rows
+    return n_fields * (2 * scoring.FUSED_T_RARE + 2 * scoring.FUSED_H) + 1
+
+
+@pytest.mark.parametrize("rows", [1, 32])
+def test_fused_match_program(one_chip, rows):
+    """FusedScorer's program (`match` on one field)."""
+    s = _on(one_chip)
+    compiled = scoring._fused_query.lower(
+        s((BODY_TILES, TILE), jnp.int32),
+        s((BODY_TILES, TILE), jnp.int32),
+        s((N_DOCS,), jnp.float32),
+        None,  # live: no deletes in a freshly built segment
+        s((BODY_HOT, N_DOCS), jnp.uint8),
+        s((rows, _plan_width(1)), jnp.int32),
+        t_rare=scoring.FUSED_T_RARE,
+        n_hot=scoring.FUSED_H,
+        k=16,
+        with_cnt=False,
+    ).compile()
+    _fits(compiled)
+
+
+def test_fused_multi_field_program(one_chip):
+    """MultiFusedScorer's program as `multi_match` best_fields sends it
+    (title+body, "max_tie"); `bool` rides the same program over one
+    field with "sum", a strict subset of this one."""
+    s = _on(one_chip)
+    tiles = (TITLE_TILES, BODY_TILES)
+    hot = (TITLE_HOT, BODY_HOT)
+    compiled = scoring._fused_query_mf.lower(
+        tuple(s((t, TILE), jnp.int32) for t in tiles),
+        tuple(s((t, TILE), jnp.int32) for t in tiles),
+        tuple(s((N_DOCS,), jnp.float32) for _ in tiles),
+        tuple(s((h, N_DOCS), jnp.uint8) for h in hot),
+        None,
+        s((32, _plan_width(2)), jnp.int32),
+        s((), jnp.float32),
+        t_rare=scoring.FUSED_T_RARE,
+        n_hot=scoring.FUSED_H,
+        k=16,
+        combine="max_tie",
+    ).compile()
+    _fits(compiled)
+
+
+def test_cross_segment_merges(one_chip):
+    """merge_segment_topk / knn_merge_segment_topk's kernels over four
+    segments' device-resident candidate buffers."""
+    s = _on(one_chip)
+    rows, segs, kt, kk = 32, 4, 16, 128
+    text = scoring._merge_segments.lower(
+        tuple(s((rows, kt), jnp.float32) for _ in range(segs)),
+        tuple(s((rows, kt), jnp.int32) for _ in range(segs)),
+        tuple(s((rows,), jnp.int32) for _ in range(segs)),
+        s((segs * kt,), jnp.int32),
+        k=kt,
+    ).compile()
+    _fits(text)
+    knn = scoring._knn_merge_segments.lower(
+        tuple(s((rows, kk), jnp.float32) for _ in range(segs)),
+        tuple(s((rows, kk), jnp.int32) for _ in range(segs)),
+        s((segs * kk,), jnp.int32),
+        s((rows, segs * kk), jnp.bool_),
+        k=kk,
+    ).compile()
+    _fits(knn)
+
+
+# ---------------------------------------------------------------------------
+# the mesh steps on a Mesh over the four described devices
+# ---------------------------------------------------------------------------
+
+MESH_SHARDS = 4
+SHARD_DOCS = N_DOCS // MESH_SHARDS
+SHARD_TILES = 96_000  # 250k docs x ~25 tokens / 128 + per-term padding
+
+
+def test_mesh_knn_step(mesh4):
+    """build_mesh_knn_step: the builder closes over its stacked arrays,
+    so it is traced inside an outer jit whose arguments stand for them —
+    the program compiled is the one the mesh executor launches."""
+    from elasticsearch_tpu.parallel.sharded import build_mesh_knn_step
+
+    rows, kc = 32, 128
+
+    def on(spec):
+        return _on(NamedSharding(mesh4, spec))
+
+    def launch(vectors, cand, queries, nc):
+        return build_mesh_knn_step(mesh4, vectors, cand, "cosine", kc)(
+            queries, nc
+        )
+
+    compiled = jax.jit(launch).lower(
+        on(P(SHARD_AXIS, None, None))(
+            (MESH_SHARDS, SHARD_DOCS, DIMS), jnp.float16
+        ),
+        on(P(SHARD_AXIS, None))((MESH_SHARDS, SHARD_DOCS), jnp.bool_),
+        on(P(DATA_AXIS, None))((rows, DIMS), jnp.float32),
+        on(P(SHARD_AXIS, DATA_AXIS))((MESH_SHARDS, rows), jnp.int32),
+    ).compile()
+    # each device holds ONE shard's vectors (250k x 768 fp16 = 366 MiB),
+    # not the whole stack
+    assert _fits(compiled) < MESH_SHARDS * SHARD_DOCS * DIMS * 2
+    assert "all-gather" in compiled.as_text()
+
+
+def test_mesh_text_step(mesh4):
+    from elasticsearch_tpu.parallel.sharded import build_mesh_text_step
+
+    rows, t_slots, kb = 32, 256, 16
+
+    def on(spec):
+        return _on(NamedSharding(mesh4, spec))
+
+    p3, p2 = P(SHARD_AXIS, None, None), P(SHARD_AXIS, None)
+    p_plan = P(SHARD_AXIS, DATA_AXIS, None)
+
+    def launch(doc_ids, tfs, inv_norm, live, ti, tw, tv, msm):
+        step = build_mesh_text_step(
+            mesh4, [doc_ids], [tfs], [inv_norm], live, kb,
+            with_cnt=False, count_signed=False,
+        )
+        return step([ti], [tw], [tv], msm)
+
+    compiled = jax.jit(launch).lower(
+        on(p3)((MESH_SHARDS, SHARD_TILES, TILE), jnp.int32),
+        on(p3)((MESH_SHARDS, SHARD_TILES, TILE), jnp.int32),
+        on(p2)((MESH_SHARDS, SHARD_DOCS), jnp.float32),
+        on(p2)((MESH_SHARDS, SHARD_DOCS), jnp.bool_),
+        on(p_plan)((MESH_SHARDS, rows, t_slots), jnp.int32),
+        on(p_plan)((MESH_SHARDS, rows, t_slots), jnp.float32),
+        on(p_plan)((MESH_SHARDS, rows, t_slots), jnp.bool_),
+        on(P(DATA_AXIS))((rows,), jnp.int32),
+    ).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "all-gather" in text  # the ICI candidate merge
+    assert "all-reduce" in text  # psum of the totals
+
+
+# ---------------------------------------------------------------------------
+# the Pallas int8 kernel (imported only by tests today, ROADMAP D5)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows", [1, 32])
+def test_pallas_int8_dot_scores(one_chip, rows):
+    """Refused by Mosaic before this file existed: the 1-D f32 `scales`
+    operand carries XLA's T(1024) tiling, which a 512-doc block cannot
+    match. The scales now ride as a [1, N] row."""
+    from elasticsearch_tpu.ops.pallas_knn import DOC_BLOCK, int8_dot_scores
+
+    n_pad = 1_000_448
+    assert n_pad % DOC_BLOCK == 0
+    s = _on(one_chip)
+    compiled = int8_dot_scores.lower(
+        s((rows, DIMS), jnp.float32),
+        s((n_pad, DIMS), jnp.int8),
+        s((n_pad,), jnp.float32),
+    ).compile()
+    _fits(compiled)
+    assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not interpret
+
+
+# ---------------------------------------------------------------------------
+# one program each from the families that compile only on first use
+# (the agg segment-sum, ops/agg_kernels.sorted_bucket_counts, compiles
+# too but takes ~20 s here at 1M docs — too slow to keep in tier-1)
+# ---------------------------------------------------------------------------
+
+
+def test_ivf_probe(one_chip):
+    from elasticsearch_tpu.ops import ivf
+    from elasticsearch_tpu.search.ann import DEFAULT_NPROBE
+
+    nlist = ivf.auto_nlist(N_DOCS)  # 2000 clusters of ~500
+    cmax = 768  # the build splits clusters past ~1.5x the mean
+    n_flat = N_DOCS + cmax
+    s = _on(one_chip)
+    compiled = ivf._ivf_probe_topk.lower(
+        s((32, DIMS), jnp.float32),
+        s((32,), jnp.bool_),
+        s((nlist, DIMS), jnp.float32),
+        s((nlist,), jnp.int32),
+        s((nlist,), jnp.int32),
+        s((n_flat,), jnp.int32),
+        s((n_flat, DIMS), jnp.float16),
+        None,
+        None,
+        None,
+        similarity="cosine",
+        nprobe=DEFAULT_NPROBE,
+        k=16,  # the candidate page of a k<=16 request; 128 takes ~9 s here
+        cmax=cmax,
+        qchunk=ivf.QCHUNK,
+    ).compile()
+    _fits(compiled)
+
+
+def test_impact_scorer_chunk(one_chip):
+    """ImpactScorer's chunk launch over an int8 impact column, at the
+    one-row (express lane) bucket; the 32-row bucket takes ~10 s here."""
+    from elasticsearch_tpu.ops import impact
+
+    n_tiles = 60_000  # ~6 sparse terms/doc over 1M docs / 128
+    rows = 1
+    s = _on(one_chip)
+    compiled = impact._impact_chunk_add.lower(
+        s((n_tiles, TILE), jnp.int32),
+        s((n_tiles, TILE), jnp.int8),
+        s((rows, N_DOCS + 1), jnp.float32),
+        s((rows, N_DOCS + 1), jnp.int32),
+        s((rows, scoring.TCHUNK), jnp.int32),
+        s((rows, scoring.TCHUNK), jnp.float32),
+        s((rows, scoring.TCHUNK), jnp.bool_),
+    ).compile()
+    _fits(compiled)
+
+
+def test_maxsim_rescore(one_chip):
+    from elasticsearch_tpu.ops import rerank
+
+    rows, qt, d, window, tmax = 8, 32, 128, 128, 64
+    n_tok = N_DOCS * 24  # ColBERT-shaped: tens of tokens per passage
+    s = _on(one_chip)
+    compiled = rerank._maxsim_rescore.lower(
+        s((rows, qt, d), jnp.float32),
+        s((rows, qt), jnp.bool_),
+        s((N_DOCS,), jnp.int32),
+        s((N_DOCS,), jnp.int32),
+        s((n_tok + tmax, d), jnp.float16),
+        None,
+        s((rows, window), jnp.int32),
+        s((rows, window), jnp.float32),
+        s((rows, window), jnp.bool_),
+        s((2,), jnp.float32),
+        tmax=tmax,
+        window=window,
+    ).compile()
+    _fits(compiled)
+
+
+def test_segment_build_postings(one_chip):
+    """The device segment build's postings kernel at one refresh-sized
+    launch (8,192 docs x ~32 tokens, pow2-bucketed)."""
+    from elasticsearch_tpu.ops import index_build
+
+    n_slots, n_docs_pad, p_pad = 16_384 * TILE, 8_192, 262_144
+    s = _on(one_chip)
+    compiled = index_build._postings_kernel(n_slots, n_docs_pad).lower(
+        s((p_pad,), jnp.int32),
+        s((p_pad,), jnp.int32),
+        s((p_pad,), jnp.int32),
+        s((n_docs_pad,), jnp.int32),
+    ).compile()
+    _fits(compiled)

@@ -18,6 +18,7 @@ REQUIRED = {
     "pipeline.batching": {
         "buckets", "launches_by_bucket", "occupancy_jobs",
         "occupancy_slots", "express_lane_hits", "avg_occupancy",
+        "warmup_failures", "worker_compile_ms",
     },
     "pipeline.mesh": {
         "routed", "launches", "jobs", "rebuilds", "degraded",

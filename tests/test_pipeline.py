@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from elasticsearch_tpu.cluster.indices import IndexService
+from elasticsearch_tpu.ops import scoring
 from elasticsearch_tpu.search.batcher import (
     EsRejectedExecutionError,
     QueryBatcher,
@@ -165,6 +166,8 @@ def fingerprint(resp):
 
 class TestDepthParity:
     def test_depth2_vs_depth1_float_exact(self, service):
+        """Float-exact for the text families; kNN within the stated
+        tolerance (see below)."""
         rng = np.random.default_rng(3)
         bodies = mixed_bodies(rng)
         b = service._batcher
@@ -179,7 +182,22 @@ class TestDepthParity:
         finally:
             b.pipeline_depth = old
         for i, (a, c) in enumerate(zip(r1, r2)):
-            assert fingerprint(a) == fingerprint(c), bodies[i]
+            if "knn" not in bodies[i]:
+                assert fingerprint(a) == fingerprint(c), bodies[i]
+                continue
+            # kNN scores are not bit-identical across launch shapes
+            # (ROADMAP D7): depth changes how requests group, the group's
+            # row count picks the matmul's internal tiling, and the same
+            # fp32 dot product is summed in another order — last-ulp,
+            # bounded by d * 2^-24. Same ids, same order, scores within
+            # scoring.KNN_SCORE_RTOL (chip_smoke.py uses the same bound).
+            (ha, ta), (hc, tc) = fingerprint(a), fingerprint(c)
+            assert [d for d, _ in ha] == [d for d, _ in hc], bodies[i]
+            assert ta == tc, bodies[i]
+            np.testing.assert_allclose(
+                [s for _, s in ha], [s for _, s in hc],
+                rtol=scoring.KNN_SCORE_RTOL, atol=0.0,
+            )
 
     def test_pipelining_actually_engages(self, service):
         # with depth=2 and a flood of submissions, jobs/launches stats
@@ -378,7 +396,9 @@ class TestRooflineStats:
         assert ps["depth"] >= 1
         assert ps["flops"] > 0
         assert ps["device_busy_ms"] > 0
-        assert 0.0 <= ps["mfu"] < 1.0
+        # the CPU is in no peak-FLOP/s table: mfu is null there, never a
+        # figure against another chip's peak
+        assert ps["mfu"] is None or 0.0 <= ps["mfu"] < 1.0
 
     def test_nodes_stats_pipeline_block(self):
         from elasticsearch_tpu.cluster.service import ClusterService

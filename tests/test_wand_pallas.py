@@ -195,7 +195,7 @@ class TestInt8Quantization:
         vectors = rng.standard_normal((n, d)).astype(np.float32)
         qv = QuantizedVectors(vectors, similarity="cosine")
         queries = rng.standard_normal((4, d)).astype(np.float32)
-        s, docs = qv.search(queries, k=k)
+        s, docs = qv.search(queries, k=k, interpret=True)
         docs = np.asarray(docs)
         # exact reference
         vn = vectors / np.linalg.norm(vectors, axis=1, keepdims=True)
@@ -211,7 +211,9 @@ class TestInt8Quantization:
         vectors = rng.standard_normal((600, 32)).astype(np.float32)
         for sim in ("dot_product", "max_inner_product"):
             qv = QuantizedVectors(vectors, similarity=sim)
-            s, docs = qv.search(rng.standard_normal((2, 32)), k=5)
+            s, docs = qv.search(
+                rng.standard_normal((2, 32)), k=5, interpret=True
+            )
             s = np.asarray(s)
             assert np.isfinite(s).all()
             assert (np.diff(s, axis=1) <= 1e-6).all()
@@ -220,5 +222,7 @@ class TestInt8Quantization:
         rng = np.random.default_rng(3)
         vectors = rng.standard_normal((100, 16)).astype(np.float32)  # < DOC_BLOCK
         qv = QuantizedVectors(vectors, similarity="cosine")
-        s, docs = qv.search(rng.standard_normal((1, 16)), k=50)
+        s, docs = qv.search(
+            rng.standard_normal((1, 16)), k=50, interpret=True
+        )
         assert (np.asarray(docs) < 100).all()
