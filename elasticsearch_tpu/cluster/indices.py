@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..analysis import AnalysisRegistry
 from ..common.faults import faults
 from ..common.slowlog import FETCH_ACC, SearchSlowLog
+from ..common import tracing
 from ..common.tracing import OPAQUE_ID_CTX, TRACE_CTX
 from ..index.engine import OpResult, ShardEngine, VersionConflictError
 from ..index.mapping import Mappings
@@ -1325,6 +1326,28 @@ class IndexService:
            aggs?: partial, profile?: entry}
         `body` arrives with from/size already collapsed to 0/(from+size)
         by the coordinator."""
+        tr = TRACE_CTX.get()
+        if tr is None:
+            return self._shard_search(sid, body, pinned_executor, task)
+        # the `shard_search` span, child of the coordinator's `fan_out`:
+        # its id is reserved so the batcher jobs and the fetch phase of
+        # this shard name it as their parent; written on success
+        ts = time.perf_counter_ns()
+        span_id = tr.reserve_span()
+        with tracing.under(span_id):
+            out = self._shard_search(sid, body, pinned_executor, task)
+        tr.add_span(
+            "shard_search", ts, time.perf_counter_ns(), span_id=span_id,
+            index=self.name, shard=sid,
+            backend=str(self.settings.get("search.backend")),
+        )
+        return out
+
+    def _shard_search(
+        self, sid: int, body: Optional[dict], pinned_executor, task,
+    ) -> dict:
+        """`shard_search_local`'s body (which wraps it in the trace's
+        `shard_search` span)."""
         ts = time.perf_counter_ns()
         body = body or {}
         # per-shard cooperative timeout (QueryPhase's timer analog): the
@@ -1811,6 +1834,9 @@ class IndexService:
             prof_phases["fetch_ns"] = (
                 prof_phases.get("fetch_ns", 0) + fetch_ns
             )
+        tr = TRACE_CTX.get()
+        if tr is not None:
+            tr.add_span("fetch", t_fetch, t_fetch + fetch_ns)
         out = {
             "total": int(td.total),
             "relation": td.relation,
@@ -1821,13 +1847,6 @@ class IndexService:
             out["aggs"] = agg_partial
         if "suggest" in body:
             out["suggest"] = self._shard_suggest(ex, body["suggest"])
-        tr = TRACE_CTX.get()
-        if tr is not None:
-            tr.add_span(
-                "shard_search", ts, time.perf_counter_ns(),
-                index=self.name, shard=sid,
-                backend=str(self.settings.get("search.backend")),
-            )
         if profile:
             # per-shard query-phase breakdown ("profile": true —
             # Profilers/QueryProfiler response shape). The breakdown
@@ -2483,10 +2502,12 @@ class IndexService:
         t0 = time.perf_counter()
         tns0 = time.perf_counter_ns()
         mesh_prof = {"families": {}} if body.get("profile") else None
+        tr, mesh_id = tracing.reserve()
         try:
-            job = self._batcher.submit_nowait(
-                mesh, plan, from_ + size, kind=kind, prof=mesh_prof,
-            )
+            with tracing.under(mesh_id):
+                job = self._batcher.submit_nowait(
+                    mesh, plan, from_ + size, kind=kind, prof=mesh_prof,
+                )
             td = QueryBatcher.wait(job)
         except MeshUnavailable as e:
             if e.budget:
@@ -2531,10 +2552,10 @@ class IndexService:
         self.search_stats["query_time_in_millis"] += took
         self.search_stats["fetch_total"] += 1
         mesh.note_routed()
-        tr = TRACE_CTX.get()
         if tr is not None:
             tr.add_span(
                 "mesh_search", tns0, time.perf_counter_ns(),
+                span_id=mesh_id,
                 index=self.name, shards=self.num_shards, took_ms=took,
             )
         n = self.num_shards
@@ -2700,6 +2721,7 @@ class IndexService:
             # limit, deadline shedding, brownout degraded modes. Raises
             # EsOverloadedError (429 + Retry-After) when this request
             # is shed. ----
+            t_adm = time.perf_counter_ns()
             ticket = admission.acquire(
                 self.name,
                 weight=float(
@@ -2707,6 +2729,13 @@ class IndexService:
                 ),
                 deadline=deadline_from(body),
             )
+            tr = TRACE_CTX.get()
+            if tr is not None:
+                # before the coordinator span starts: a root of its own
+                tr.add_span(
+                    "admission_wait", t_adm, time.perf_counter_ns(),
+                    tier=ticket.tier, limit=int(admission.limit),
+                )
             try:
                 degraded, actions = apply_brownout(body, ticket.tier)
                 resp = self._search_reduced(degraded, None, task)
@@ -2875,10 +2904,14 @@ class IndexService:
                 sub["_dfs"] = dfs
         m_dfs = time.perf_counter_ns()
         deadline = deadline_from(body)
-        per_shard, failures, timed_out = self._fan_out(
-            sub, pinned_executors, skipped_shards, fixed_owners,
-            deadline=deadline, task=task,
-        )
+        # the `fan_out` span is written with the other phases below; its
+        # id is reserved here so the shard spans name it as their parent
+        tr, fan_id = tracing.reserve()
+        with tracing.under(fan_id):
+            per_shard, failures, timed_out = self._fan_out(
+                sub, pinned_executors, skipped_shards, fixed_owners,
+                deadline=deadline, task=task,
+            )
         m_fanout = time.perf_counter_ns()
         allow_partial = parse_allow_partial(
             body.get("allow_partial_search_results")
@@ -2976,19 +3009,20 @@ class IndexService:
             "fan_out_ns": m_fanout - m_dfs,
             "reduce_ns": m_reduce - m_fanout,
         }
-        tr = TRACE_CTX.get()
         if tr is not None:
             root = tr.add_span(
                 "coordinator", tns, m_reduce,
                 index=self.name, shards=n, took_ms=took,
             )
             prev = tns
-            for pname, mark in (
-                ("parse", m_parse), ("can_match", m_canmatch),
-                ("dfs", m_dfs), ("fan_out", m_fanout),
-                ("reduce", m_reduce),
+            for pname, mark, span_id in (
+                ("parse", m_parse, None), ("can_match", m_canmatch, None),
+                ("dfs", m_dfs, None), ("fan_out", m_fanout, fan_id),
+                ("reduce", m_reduce, None),
             ):
-                tr.add_span(pname, prev, mark, parent_id=root)
+                tr.add_span(
+                    pname, prev, mark, parent_id=root, span_id=span_id
+                )
                 prev = mark
         if profile:
             resp["profile"] = {
@@ -3295,10 +3329,12 @@ class IndexService:
         window = max(from_ + size, 10)
         # the new kwargs ride only on profiled requests so external
         # wrappers of the original signatures keep working
-        ranked = self._run_retriever(
-            body["retriever"], window, size, extra_filter, pins,
-            **({"prof_out": prof} if prof is not None else {}),
-        )
+        tr, retr_id = tracing.reserve()
+        with tracing.under(retr_id):
+            ranked = self._run_retriever(
+                body["retriever"], window, size, extra_filter, pins,
+                **({"prof_out": prof} if prof is not None else {}),
+            )
         m_retr = time.perf_counter_ns()
         if "rescore" in body and ranked:
             from ..search import rescorer
@@ -3334,13 +3370,14 @@ class IndexService:
         if acc is not None:
             acc["fetch_ns"] += m_fetch - m_resc
         took = int((time.perf_counter() - t0) * 1000)
-        tr = TRACE_CTX.get()
         if tr is not None:
             root = tr.add_span(
                 "retriever_search", tns, m_fetch,
                 index=self.name, took_ms=took,
             )
-            tr.add_span("retriever", tns, m_retr, parent_id=root)
+            tr.add_span(
+                "retriever", tns, m_retr, parent_id=root, span_id=retr_id
+            )
             tr.add_span("rescore", m_retr, m_resc, parent_id=root)
             tr.add_span("fetch", m_resc, m_fetch, parent_id=root)
         n = self.num_shards
@@ -3463,15 +3500,24 @@ class IndexService:
         children = params.get("retrievers", [])
         t_start = time.perf_counter()
         t_start_ns = time.perf_counter_ns()
+        # the `rrf` span and its `leg:<label>` children are written
+        # below; their ids are reserved so what each leg submits or
+        # runs names its leg as the parent
+        tr, rrf_id = tracing.reserve()
+        leg_ids = [
+            tr.reserve_span() if tr is not None else None for _ in children
+        ]
         # submit every leg before collecting any: plannable legs enter
         # the batcher (device overlap), the rest ride the thread pool
-        handles = [
-            self._submit_leg(
-                child, window2, extra_filter, pins,
-                profiled=prof_out is not None,
-            )
-            for child in children
-        ]
+        handles = []
+        for child, leg_id in zip(children, leg_ids):
+            with tracing.under(leg_id):
+                handles.append(
+                    self._submit_leg(
+                        child, window2, extra_filter, pins,
+                        profiled=prof_out is not None,
+                    )
+                )
         legs = [self._wait_leg(h, window2, extra_filter, t_start, pins)
                 for h in handles]
         t_fuse = time.perf_counter()
@@ -3525,18 +3571,17 @@ class IndexService:
                 (t_end - t_fuse) * 1e9
             )
             prof_out["fused_on_device"] = device
-        tr = TRACE_CTX.get()
         if tr is not None:
             t_end_ns = time.perf_counter_ns()
-            root = tr.add_span(
-                "rrf", t_start_ns, t_end_ns,
+            tr.add_span(
+                "rrf", t_start_ns, t_end_ns, span_id=rrf_id,
                 index=self.name, legs=len(legs), device_fused=device,
             )
-            for leg in legs:
+            for leg, leg_id in zip(legs, leg_ids):
                 tr.add_span(
                     f"leg:{leg['label']}", t_start_ns,
-                    t_start_ns + int(leg["ms"] * 1e6), parent_id=root,
-                    mode=leg.get("mode", "?"),
+                    t_start_ns + int(leg["ms"] * 1e6), parent_id=rrf_id,
+                    span_id=leg_id, mode=leg.get("mode", "?"),
                 )
         return fused
 

@@ -1,4 +1,5 @@
-"""Per-request span-tree tracing (lightweight, always-cheap).
+"""Per-request span-tree tracing (lightweight, always-cheap), and the
+node's host<->device transfer counters.
 
 Reference analog: the `X-Opaque-Id` header + task-manager description
 propagation in org.elasticsearch.tasks, and the APM-style span trees
@@ -15,10 +16,48 @@ Design:
     Fan-out pools propagate the var with `contextvars.copy_context()`;
     the Trace object itself is shared and thread-safe, so spans added
     from shard/leg worker threads land in the request's tree.
+  * Spans are written retroactively (`add_span`) from marks the code
+    took anyway. A span names the span that caused it: `PARENT_CTX`
+    holds the id of the span the current code runs under and is
+    `add_span`'s default parent. A span that must be a parent before
+    its end is known reserves its id first (`reserve_span`, made
+    current with `under`) and is written with `span_id=` later. A
+    layer's self time is its duration less what its children cover.
   * Completed traces go into a bounded ring (`ES_TPU_TRACE_RING`,
     default 256) queryable via `GET /_internal/traces` — a test/smoke
     surface, not a production exporter.
   * `ES_TPU_TRACING=off` disables arming entirely (`begin()` → None).
+
+The spans of a search, parent > children (clock: `perf_counter_ns` of
+the serving process; tags in brackets):
+
+    coordinator [index, shards, took_ms]
+      > parse, can_match, dfs, fan_out, reduce   (tile the coordinator)
+    fan_out > shard_search [index, shard, backend]   (one per shard)
+    shard_search (or `leg:<label>` of an rrf retriever, or
+    `mesh_search`) > the job spans of search/batcher.py, which tile the
+    job's life from submit to its waiter's wake-up:
+        queue_wait [family, cold_ms]  submit -> a worker starts the
+            job's group; cold_ms = compile time that accrued meanwhile
+        dispatch [family, jobs, rows, launches, express, overflow]
+            -> the group's last kernel is enqueued
+        inflight  -> the worker comes back to collect the group
+        collect [d2h_bytes]  merge kernel, blocking download, hits
+        compile [program, seconds]  child of the dispatch (or collect)
+            span the worker compiled in; one per program
+    shard_search > fetch   (sources, highlight: the folded fetch phase)
+    admission_wait [tier, limit]   root; the wait in admission.acquire,
+        before the coordinator span starts
+
+The same worker phases are on the profiler's clock as
+`jax.profiler.TraceAnnotation`s `es.dispatch` / `es.collect` (arguments
+`family`, `rows`): start `jax.profiler.start_trace(dir)` on the serving
+process and they land on the host plane of the `.xplane.pb`, one line
+per dispatcher thread, beside the device's `XLA Ops` line.
+
+`note_transfer` counts the query path's host<->device transfers where
+they happen (ops/scoring.py and the kNN upload in search/batcher.py);
+`_nodes/stats` reports the totals as `transfer.scoring.*`.
 
 `OPAQUE_ID_CTX` carries the request's `X-Opaque-Id` header value so
 task descriptions, slow-log records, and traces can all attribute work
@@ -27,6 +66,7 @@ to the caller's id without threading a parameter through every layer.
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import itertools
 import os
@@ -46,9 +86,9 @@ TRACE_CTX: contextvars.ContextVar = contextvars.ContextVar(
     "trace_ctx", default=None
 )
 
-# parent span id for nested `Trace.span()` scopes (copy-on-thread via
-# contextvars, so concurrent legs each see their own parent chain)
-_PARENT_CTX: contextvars.ContextVar = contextvars.ContextVar(
+# id of the span the current code runs under (copy-on-thread via
+# contextvars, so concurrent shards and legs each see their own chain)
+PARENT_CTX: contextvars.ContextVar = contextvars.ContextVar(
     "span_parent", default=None
 )
 
@@ -119,29 +159,34 @@ class Trace:
 
     # ---- recording ----
 
+    def reserve_span(self) -> int:
+        """An id for a span that is written later, when its end is
+        known, so that spans recorded meanwhile can name it as their
+        parent (`under(id)`, or an explicit `parent_id`)."""
+        with self._lock:
+            return next(self._span_ids)
+
     def add_span(
         self, name: str, start_ns: int, end_ns: int,
-        parent_id: Optional[int] = None, **tags: Any,
+        parent_id: Optional[int] = None, span_id: Optional[int] = None,
+        **tags: Any,
     ) -> Optional[int]:
         """Retroactive span from two already-taken perf_counter_ns
         marks (the cheap pattern for code that timed itself anyway).
-        Returns the span id, or None if the trace is full."""
+        `parent_id` defaults to the span the caller runs under;
+        `span_id` is an id from `reserve_span`. Returns the span id, or
+        None if the trace is full."""
         if parent_id is None:
-            parent_id = _PARENT_CTX.get()
+            parent_id = PARENT_CTX.get()
         with self._lock:
             if len(self._spans) >= MAX_SPANS:
                 self._dropped += 1
                 return None
-            sid = next(self._span_ids)
+            sid = next(self._span_ids) if span_id is None else span_id
             self._spans.append(
                 Span(sid, parent_id, name, int(start_ns), int(end_ns), tags)
             )
         return sid
-
-    def span(self, name: str, **tags: Any):
-        """Context-manager scope: times the block and parents any span
-        recorded inside it (contextvar chain, thread-local per leg)."""
-        return _SpanScope(self, name, tags)
 
     def finish(self) -> None:
         """Closes the trace and publishes it to the ring."""
@@ -170,42 +215,16 @@ class Trace:
         }
 
 
-class _SpanScope:
-    __slots__ = ("trace", "name", "tags", "t0", "_tok")
-
-    def __init__(self, trace: Trace, name: str, tags: Dict[str, Any]):
-        self.trace = trace
-        self.name = name
-        self.tags = tags
-        self.t0 = 0
-        self._tok = None
-
-    def __enter__(self):
-        self.t0 = time.perf_counter_ns()
-        # reserve the id up front so children can parent onto it; the
-        # end time is patched at exit
-        with self.trace._lock:
-            sid = next(self.trace._span_ids)
-        self._tok = _PARENT_CTX.set(sid)
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        end = time.perf_counter_ns()
-        sid = _PARENT_CTX.get()
-        parent = None
-        if self._tok is not None:
-            parent = self._tok.old_value
-            if parent is contextvars.Token.MISSING:
-                parent = None
-            _PARENT_CTX.reset(self._tok)
-        with self.trace._lock:
-            if len(self.trace._spans) >= MAX_SPANS:
-                self.trace._dropped += 1
-            else:
-                self.trace._spans.append(
-                    Span(sid, parent, self.name, self.t0, end, self.tags)
-                )
-        return False
+@contextlib.contextmanager
+def under(span_id: Optional[int]):
+    """Code in the block runs under span `span_id`: spans it records,
+    and jobs it submits to the batcher, name that span as their parent.
+    The var rides `copy_context()` into the fan-out pools."""
+    tok = PARENT_CTX.set(span_id)
+    try:
+        yield
+    finally:
+        PARENT_CTX.reset(tok)
 
 
 # ---- completed-trace ring (GET /_internal/traces) ----
@@ -257,3 +276,45 @@ def end(handle) -> None:
 
 def current() -> Optional[Trace]:
     return TRACE_CTX.get()
+
+
+def reserve():
+    """(the current trace, an id reserved in it for a span written
+    later), or (None, None) when the request is not traced."""
+    tr = TRACE_CTX.get()
+    return tr, (tr.reserve_span() if tr is not None else None)
+
+
+# ---- host<->device transfer counters (_nodes/stats transfer.scoring) ----
+
+class _ThreadTransfers(threading.local):
+    d2h_bytes = 0
+
+
+_xfer_lock = threading.Lock()
+_xfer = {"h2d_count": 0, "h2d_bytes": 0, "d2h_count": 0, "d2h_bytes": 0}
+_xfer_thread = _ThreadTransfers()
+
+
+def note_transfer(direction: str, nbytes: int) -> None:
+    """One host<->device transfer of the query path, noted where it
+    happens. `direction` is "h2d" (an upload: an explicit `device_put`
+    or a host array handed to a jitted program) or "d2h" (a blocking
+    download, i.e. a host sync). Exact, so they repeat on any backend."""
+    nbytes = int(nbytes)
+    with _xfer_lock:
+        _xfer[direction + "_count"] += 1
+        _xfer[direction + "_bytes"] += nbytes
+    if direction == "d2h":
+        _xfer_thread.d2h_bytes += nbytes
+
+
+def transfer_stats() -> Dict[str, int]:
+    with _xfer_lock:
+        return dict(_xfer)
+
+
+def thread_d2h_bytes() -> int:
+    """Bytes the calling thread has downloaded so far: a dispatcher
+    worker reads it around a group's collect for the span's tag."""
+    return _xfer_thread.d2h_bytes

@@ -42,6 +42,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common.tracing import note_transfer
+
+
+def _to_host(x) -> np.ndarray:
+    """The blocking download of one device array (a host sync), noted
+    for `_nodes/stats` `transfer.scoring`. Uploads note themselves where
+    they happen: an explicit `device_put`, or a host array handed to a
+    jitted program."""
+    out = np.asarray(x)
+    note_transfer("d2h", out.nbytes)
+    return out
+
+
+def _to_device(x: np.ndarray) -> jax.Array:
+    """The upload of one host array, noted the same way."""
+    note_transfer("h2d", x.nbytes)
+    return jnp.asarray(x)
+
 
 def next_bucket(n: int, minimum: int = 8) -> int:
     """Round up to a power of two for shape-stable compilation."""
@@ -289,6 +307,8 @@ class ChunkedScorer:
                     ti[j, :m] = sl
                     tw[j, :m] = wl[c0 : c0 + TCHUNK]
                     tv[j, :m] = True
+            for plane in (ti, tw, tv):  # host arrays: the launch uploads them
+                note_transfer("h2d", plane.nbytes)
             if cnt is None:
                 acc = _chunk_add(self.doc_ids, self.tfs, self.inv_norm, acc, ti, tw, tv)
             else:
@@ -307,16 +327,17 @@ class ChunkedScorer:
             k=min(k, self.n_docs),
             block_size=self.block_size,
         )
-        return np.asarray(theta), np.asarray(accmax)
+        return _to_host(theta), _to_host(accmax)
 
     def finalize(self, acc, cnt, msm: np.ndarray, k: int, live=None):
         s, d, tot = self.finalize_device(acc, cnt, msm, k, live=live)
-        return np.asarray(s), np.asarray(d), np.asarray(tot)
+        return _to_host(s), _to_host(d), _to_host(tot)
 
     def finalize_device(self, acc, cnt, msm: np.ndarray, k: int, live=None):
         """Like finalize() but the (scores, docs, totals) triple STAYS on
         device, so the cross-segment merge kernel can consume it with no
         per-segment host sync."""
+        note_transfer("h2d", 4 * len(msm))
         return _finalize(
             acc,
             cnt,
@@ -482,6 +503,7 @@ class FusedScorer:
             else None
         )
         packed = self.pack_plans(plans, out=buf, rows=shape[0])
+        note_transfer("h2d", packed.nbytes)
         out = _fused_query(
             self.doc_ids,
             self.tfs,
@@ -501,7 +523,7 @@ class FusedScorer:
         """Blocks on the device transfer and unpacks to
         (scores f32[B,k], docs i32[B,k], totals i64[B])."""
         out, k = pending
-        out = np.asarray(out)
+        out = _to_host(out)
         scores = out[:, :k].copy().view(np.float32)
         docs = out[:, k : 2 * k]
         totals = out[:, 2 * k].astype(np.int64)
@@ -683,6 +705,8 @@ class MultiFusedScorer:
             else None
         )
         packed = self.pack_plans(plans, out=buf, rows=shape[0])
+        note_transfer("h2d", packed.nbytes)
+        note_transfer("h2d", 4)  # tie
         out = _fused_query_mf(
             tuple(p["doc_ids"] for p in self.parts),
             tuple(p["tfs"] for p in self.parts),
@@ -840,12 +864,12 @@ def merge_segment_topk(items, k: int):
     entries pad past the real candidates."""
     widths = [int(s.shape[1]) for _, s, _, _ in items]
     k = min(k, sum(widths))
-    seg_of_slot = jnp.asarray(
+    seg_of_slot = _to_device(
         np.repeat(
             np.asarray([si for si, *_ in items], np.int32), widths
         )
     )
-    out = np.asarray(
+    out = _to_host(
         _merge_segments(
             tuple(s for _, s, _, _ in items),
             tuple(d for _, _, d, _ in items),
@@ -895,18 +919,18 @@ def knn_merge_segment_topk(items, nc_rows: np.ndarray, k: int):
     device→host transfer."""
     widths = [int(s.shape[1]) for _, s, _ in items]
     k = min(k, sum(widths))
-    seg_of_slot = jnp.asarray(
+    seg_of_slot = _to_device(
         np.repeat(np.asarray([si for si, *_ in items], np.int32), widths)
     )
     rank_of_slot = np.concatenate(
         [np.arange(w, dtype=np.int32) for w in widths]
     )
     # bool [B, total_slots]: slot rank < that (job, segment)'s budget
-    nc_cat = jnp.asarray(
+    nc_cat = _to_device(
         rank_of_slot[None, :]
         < np.repeat(nc_rows.astype(np.int32), widths, axis=1)
     )
-    out = np.asarray(
+    out = _to_host(
         _knn_merge_segments(
             tuple(s for _, s, _ in items),
             tuple(d for _, _, d in items),

@@ -1419,7 +1419,7 @@ class MeshExecutor:
                 hits=hits,
                 snapshot=snap,
             )
-            j.event.set()
+            j.finish()
 
     def dispatch_sparse(self, jobs, kb: int):
         """One SPMD learned-sparse launch for a same-(field, spec) job
@@ -1568,7 +1568,7 @@ class MeshExecutor:
                 hits=hits,
                 snapshot=snap,
             )
-            j.event.set()
+            j.finish()
 
     # ---- mesh aggregations (one SPMD launch per agg-body group) ----
 
@@ -1835,7 +1835,7 @@ class MeshExecutor:
                 "partials": partials,
                 "snapshot": snap,
             }
-            j.event.set()
+            j.finish()
 
     def _hit(self, snap, score, entry, doc) -> MeshHit:
         sid, si = snap.entries[entry]

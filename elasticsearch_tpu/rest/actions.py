@@ -610,6 +610,7 @@ class RestActions:
             "express_lane_hits": 0,
             "warmup_failures": 0,
             "worker_compile_ms": 0.0,
+            "worker_compiles": 0,
         }
         # per-device roofline rows (straggler visibility): busy time and
         # flops merged by device id across every index's batcher
@@ -649,6 +650,7 @@ class RestActions:
                 batching["express_lane_hits"] += bs["express_lane_hits"]
                 batching["warmup_failures"] += bs["warmup_failures"]
                 batching["worker_compile_ms"] += bs["worker_compile_ms"]
+                batching["worker_compiles"] += bs["worker_compiles"]
             mex = getattr(idx, "_mesh", None)
             if mex is not None:
                 for k in mesh_stats:
@@ -835,6 +837,11 @@ class RestActions:
                         **category_breakers,
                     },
                     "pipeline": pipeline,
+                    # host<->device transfers of the query path, counted
+                    # where they happen (common/tracing.note_transfer);
+                    # named for their scope: ops/scoring.py and the kNN
+                    # upload. d2h_count = blocking downloads (host syncs)
+                    "transfer": {"scoring": tracing.transfer_stats()},
                     "aggs": aggs_block,
                     "knn": knn_block,
                     "rescore": rescore_block,

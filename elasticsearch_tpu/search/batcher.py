@@ -48,9 +48,16 @@ from typing import Deque, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 from jax import monitoring as jax_monitoring
+from jax.profiler import TraceAnnotation
 
 from ..common.faults import faults
 from ..common.settings import batch_buckets, bucket_for, bucket_warmup
+from ..common.tracing import (
+    PARENT_CTX,
+    TRACE_CTX,
+    note_transfer,
+    thread_d2h_bytes,
+)
 from ..index.mapping import SPARSE_VECTOR, TEXT
 from ..ops import scoring
 from ..ops.scoring import BPAD
@@ -77,14 +84,25 @@ MAX_BATCH = BPAD
 # Seconds are summed over workers, so concurrent compiles over-subtract:
 # the correction only ever errs towards admitting while compiling.
 _COMPILE_EVENTS = "/jax/core/compile/"
-_worker_tl = threading.local()  # .batcher: set on dispatcher workers
+_BACKEND_COMPILE = _COMPILE_EVENTS + "backend_compile_duration"
+# on dispatcher workers: .batcher (the owner) and .group (the _Group the
+# worker is dispatching or collecting right now, else None)
+_worker_tl = threading.local()
 
 
-def _on_compile_seconds(event: str, duration: float, **_kw) -> None:
+def _on_compile_seconds(
+    event: str, duration: float, fun_name: str = "?", **_kw
+) -> None:
     b = getattr(_worker_tl, "batcher", None)
-    if b is not None and event.startswith(_COMPILE_EVENTS):
-        with b._cold_lock:
-            b._cold_s += float(duration)
+    if b is None or not event.startswith(_COMPILE_EVENTS):
+        return
+    built = event == _BACKEND_COMPILE
+    with b._cold_lock:
+        b._cold_s += float(duration)
+        b._compiles += built
+    g = getattr(_worker_tl, "group", None)
+    if g is not None:
+        g.note_compile(fun_name, float(duration), built)
 
 
 jax_monitoring.register_event_duration_secs_listener(_on_compile_seconds)
@@ -463,7 +481,8 @@ class _Job:
 
     __slots__ = (
         "executor", "kind", "plan", "k", "query", "event", "result",
-        "error", "deadline", "t_enq", "cold0", "prof",
+        "error", "deadline", "t_enq", "cold0", "prof", "trace", "parent",
+        "group",
     )
 
     def __init__(
@@ -481,16 +500,154 @@ class _Job:
         # monotonic deadline (the shard's search-timeout budget): a job
         # still queued past it is dropped at dequeue, never dispatched
         self.deadline = deadline
-        self.t_enq = time.monotonic()
+        # the job's one clock: perf_counter_ns, the request trace's
+        self.t_enq = time.perf_counter_ns()
         # the owning batcher's cold clock at enqueue (submit_nowait)
         self.cold0 = 0.0
-        # "profile": true — a shared mutable dict the dispatch/collect
-        # phases write per-family timing into (None = unprofiled; the
+        # "profile": true — a shared mutable dict the job's breakdown
+        # is written into when it finishes (None = unprofiled; the
         # submitter owns the dict and reads it after wait())
         self.prof = prof
+        # the submitting request's trace and the span that caused the
+        # job (None on an untraced request, and on the worker threads
+        # that make warm-up jobs)
+        self.trace = TRACE_CTX.get()
+        self.parent = PARENT_CTX.get()
+        # the dispatched group's marks, set when a worker starts it
+        self.group: Optional[_Group] = None
 
     def done(self) -> bool:
         return self.event.is_set()
+
+    def finish(self) -> None:
+        """Wakes the waiter, after the job's spans and profile entry
+        are written: a request never sees its own job missing from
+        them. A job no worker started (shed, cancelled, closed) has no
+        marks and records nothing."""
+        g = self.group
+        if g is not None and (
+            self.trace is not None or self.prof is not None
+        ):
+            g.record(self, time.perf_counter_ns())
+        self.event.set()
+
+
+class _Group:
+    """One dispatched group's marks: consecutive `perf_counter_ns`
+    readings the worker takes once, whatever reads them. They tile each
+    job's life from `submit_nowait` to its waiter's wake-up — queue_wait
+    | dispatch | inflight | collect — and are written, when a job
+    finishes, as spans of the submitting request's trace and as the
+    `"profile": true` breakdown (common/tracing.py lists the spans)."""
+
+    __slots__ = (
+        "family", "jobs", "rows", "express", "cold_s", "t_start",
+        "t_dispatched", "t_collect", "d2h0", "launches", "flops",
+        "overflow", "compiles",
+    )
+
+    def __init__(self, family: str, jobs: int, rows: Optional[int],
+                 express: bool = False, cold_s: float = 0.0):
+        self.family = family  # the jobs' kind: match, serve, knn, ...
+        self.jobs = jobs
+        self.rows = int(rows or 0)  # the launch's bucket (mesh: set later)
+        self.express = express
+        self.cold_s = cold_s  # the batcher's cold clock at t_start
+        self.t_dispatched = self.t_collect = 0
+        self.d2h0 = 0
+        self.launches = 0
+        self.flops = 0
+        self.overflow = False  # the group left the fused kernel
+        # program -> [start_ns, end_ns, seconds, built] compiled meanwhile
+        self.compiles: Dict[str, list] = {}
+        self.t_start = time.perf_counter_ns()
+
+    def dispatched(self) -> None:
+        """The group's last kernel is enqueued."""
+        self.t_dispatched = time.perf_counter_ns()
+
+    def collecting(self, at: Optional[int] = None) -> None:
+        """The worker is back to collect the group (`at`: the same mark
+        as `dispatched`, for a group that never was in flight)."""
+        self.t_collect = at or time.perf_counter_ns()
+        self.d2h0 = thread_d2h_bytes()
+
+    def phase(self, name: str) -> TraceAnnotation:
+        """The worker's phase on the profiler's clock (`es.dispatch`,
+        `es.collect`): outside a profiler session a flag test, inside
+        one an event on this thread's line of the host plane."""
+        return TraceAnnotation(name, family=self.family, rows=self.rows)
+
+    def note_compile(self, program: str, seconds: float,
+                     built: bool) -> None:
+        """One of a program's compile events (trace, lowering, backend
+        compile: JAX names the first `f`, the others `jit(f)`). A name
+        that never reaches the backend was traced inside another
+        program, whose events cover it."""
+        if program.endswith(")") and "(" in program:
+            program = program[program.index("(") + 1:-1]
+        end = time.perf_counter_ns()
+        start = end - int(seconds * 1e9)
+        c = self.compiles.get(program)
+        if c is None:
+            self.compiles[program] = [start, end, seconds, built]
+        else:
+            c[0], c[1], c[2] = min(c[0], start), end, c[2] + seconds
+            c[3] = c[3] or built
+
+    def record(self, j: _Job, t_done: int) -> None:
+        # a group that failed on the way has its later marks missing
+        t1 = self.t_dispatched or t_done
+        t2 = self.t_collect or t1
+        tr = j.trace
+        if tr is not None:
+            up = j.parent
+            tr.add_span(
+                "queue_wait", j.t_enq, self.t_start, parent_id=up,
+                family=self.family,
+                cold_ms=round((self.cold_s - j.cold0) * 1000.0, 3),
+            )
+            disp = tr.add_span(
+                "dispatch", self.t_start, t1, parent_id=up,
+                family=self.family, jobs=self.jobs, rows=self.rows,
+                launches=self.launches, express=self.express,
+                overflow=self.overflow,
+            )
+            tr.add_span("inflight", t1, t2, parent_id=up)
+            coll = tr.add_span(
+                "collect", t2, t_done, parent_id=up,
+                d2h_bytes=thread_d2h_bytes() - self.d2h0,
+            )
+            for program, (c0, c1, secs, built) in self.compiles.items():
+                if built:
+                    tr.add_span(
+                        "compile", c0, c1,
+                        parent_id=disp if c1 <= t1 else coll,
+                        program=program, seconds=round(secs, 6),
+                    )
+        p = j.prof
+        if p is not None:
+            # built aside and dict-swapped in, so a reader that races
+            # the write never observes a half-built entry
+            fams = p.setdefault("families", {})
+            prev = fams.get(j.kind)
+            e = dict(prev) if prev else {
+                "launches": 0, "dispatch_ns": 0, "collect_ns": 0,
+                "queue_wait_ns": 0, "flops": 0, "bucket": 0,
+                "batch_jobs": 0, "express_lane": False, "pruned": False,
+            }
+            e["launches"] += 1
+            e["dispatch_ns"] += t1 - self.t_start
+            e["collect_ns"] += t_done - t2
+            e["queue_wait_ns"] += max(0, self.t_start - j.t_enq)
+            e["flops"] += self.flops // max(self.jobs, 1)
+            e["bucket"] = self.rows
+            e["batch_jobs"] = self.jobs
+            if self.express:
+                e["express_lane"] = True
+            if p.get("pruned_jobs"):
+                e["pruned"] = True
+            fams[j.kind] = e
 
 
 class _BatchCtx:
@@ -623,6 +780,7 @@ class QueryBatcher:
         # this batcher's workers, fed by the jax.monitoring listener
         self._cold_lock = threading.Lock()
         self._cold_s = 0.0
+        self._compiles = 0  # programs those workers built or fetched
         # family → groups currently dispatched-but-not-collected,
         # across ALL workers (guarded by self._lock)
         self._inflight = {
@@ -633,11 +791,6 @@ class QueryBatcher:
         # groups attribute to device 0, mesh groups to every device in
         # the mesh (guarded by self._lock)
         self._devs: Dict[int, list] = {}
-        # per-worker profiling scratch: while a profiled group
-        # dispatches, `group_flops` accumulates the flops the group's
-        # launches report via _add_flops (thread-local — each worker
-        # dispatches one group at a time)
-        self._tl = threading.local()
 
     def _ensure_thread(self):
         with self._lock:
@@ -860,7 +1013,7 @@ class QueryBatcher:
                 for j in ctx.batch:
                     if not j.event.is_set():
                         j.error = err
-                        j.event.set()
+                        j.finish()
                 self._ring_exit()
             self._drain_queue(RuntimeError("query batcher worker exited"))
             if self._closed:
@@ -888,10 +1041,13 @@ class QueryBatcher:
             # the worst enqueue→dispatch wait in this batch (the
             # "queue delay vs target" the adaptive limit steers on),
             # less the seconds the workers spent compiling meanwhile
-            now = time.monotonic()
+            now = time.perf_counter_ns()
             cold = self._cold_s
             admission.observe_queue_delay(
-                max(now - j.t_enq - (cold - j.cold0) for j in batch)
+                max(
+                    (now - j.t_enq) / 1e9 - (cold - j.cold0)
+                    for j in batch
+                )
             )
             with self._lock:
                 self.stats["jobs"] += len(batch)
@@ -973,17 +1129,18 @@ class QueryBatcher:
                 # mesh groups pick theirs internally (the data-axis
                 # divisibility constraint lives there)
                 rows = None if mesh else bucket_for(len(jobs), self.buckets)
+                # the group's marks start here: its jobs' queue wait
+                # ends, and compiles on this thread are the group's
+                g = _Group(
+                    jobs[0].kind, len(jobs), rows, express, self._cold_s
+                )
+                for j in jobs:
+                    j.group = g
+                _worker_tl.group = g
                 dev_ids: Tuple[int, ...] = (0,)
                 dev_entered = False
                 self._enter_kind(fam)
-                dispatched = False
-                # "profile": true — arm the per-group scratch only when
-                # a job in the group carries a prof dict (zero cost on
-                # the unprofiled path beyond this any())
-                prof_on = any(j.prof is not None for j in jobs)
-                if prof_on:
-                    self._tl.group_flops = 0
-                    t_prof = time.perf_counter_ns()
+                dispatched = warm = False
                 try:
                     if not mesh:
                         self._dev_enter(dev_ids)
@@ -998,119 +1155,74 @@ class QueryBatcher:
                         # record BEFORE dispatch: match groups complete
                         # their waiters inside _run_group, and a waiter
                         # must never observe its own launch missing
-                        # from the histogram — the profile mark rides a
-                        # callback for the same reason (it must land
-                        # before the events fire, and before warm loops:
-                        # bucket warming is compile time, not this
-                        # query's time)
+                        # from the histogram
                         self._record_bucket(rows, len(jobs))
-                        cb = None
-                        if prof_on:
-                            cb = (lambda j=jobs, r=rows, t=t_prof, n=now,
-                                  e=express: self._prof_mark(j, r, t, n, e))
-                        self._run_group(jobs, key[2], kb, rows=rows,
-                                        prof_cb=cb)
-                        self._maybe_warm(key, jobs, kb, rows)
-                    elif kind == "s":
-                        self._record_bucket(rows, len(jobs))
-                        ctx.pending.append(
-                            (key, jobs, fam,
-                             self._dispatch_serve_group(jobs, kb, rows=rows),
-                             dev_ids)
-                        )
-                        dispatched = True
-                        if prof_on:
-                            self._prof_mark(jobs, rows, t_prof, now,
-                                            express)
-                        self._maybe_warm(key, jobs, kb, rows)
-                    elif kind == "k":
-                        self._record_bucket(rows, len(jobs))
-                        ctx.pending.append(
-                            (key, jobs, fam,
-                             self._dispatch_knn_group(jobs, rows=rows),
-                             dev_ids)
-                        )
-                        dispatched = True
-                        if prof_on:
-                            self._prof_mark(jobs, rows, t_prof, now,
-                                            express)
-                        self._maybe_warm(key, jobs, kb, rows)
-                    elif kind == "a":
-                        ctx.pending.append(
-                            (key, jobs, fam,
-                             self._dispatch_agg_group(jobs), dev_ids)
-                        )
-                        dispatched = True
-                        if prof_on:
-                            self._prof_mark(jobs, rows, t_prof, now,
-                                            express)
-                    elif kind == "r":
-                        self._record_bucket(rows, len(jobs))
-                        ctx.pending.append(
-                            (key, jobs, fam,
-                             self._dispatch_rerank_group(jobs, rows=rows),
-                             dev_ids)
-                        )
-                        dispatched = True
-                        if prof_on:
-                            self._prof_mark(jobs, rows, t_prof, now,
-                                            express)
-                    elif kind == "v":
-                        self._record_bucket(rows, len(jobs))
-                        ctx.pending.append(
-                            (key, jobs, fam,
-                             self._dispatch_sparse_group(jobs, kb,
-                                                         rows=rows),
-                             dev_ids)
-                        )
-                        dispatched = True
-                        if prof_on:
-                            self._prof_mark(jobs, rows, t_prof, now,
-                                            express)
-                        self._maybe_warm(key, jobs, kb, rows)
-                    else:
+                        self._run_group(jobs, key[2], kb, rows=rows)
+                        warm = True
+                    elif mesh:
                         mex = jobs[0].executor
-                        if kind == "Mm":
-                            pend = mex.dispatch_match(jobs, kb)
-                        elif kind == "Ms":
-                            pend = mex.dispatch_serve(jobs, kb)
-                        elif kind == "Ma":
-                            pend = mex.dispatch_agg(jobs)
-                        elif kind == "Mv":
-                            pend = mex.dispatch_sparse(jobs, kb)
-                        else:
-                            pend = mex.dispatch_knn(jobs, kb)
-                        # the busy window opens on the devices the
-                        # snapshot actually spans
-                        dev_ids = mex.device_ids
-                        self._dev_enter(dev_ids)
-                        dev_entered = True
-                        with self._lock:
-                            self.stats["launches"] += 1
-                            self.stats["fused_jobs"] += len(jobs)
-                        self._add_flops(pend["flops"], dev_ids)
-                        self._record_bucket(
-                            pend.get("rows", BPAD), len(jobs)
-                        )
+                        with g.phase("es.dispatch"):
+                            if kind == "Mm":
+                                pend = mex.dispatch_match(jobs, kb)
+                            elif kind == "Ms":
+                                pend = mex.dispatch_serve(jobs, kb)
+                            elif kind == "Ma":
+                                pend = mex.dispatch_agg(jobs)
+                            elif kind == "Mv":
+                                pend = mex.dispatch_sparse(jobs, kb)
+                            else:
+                                pend = mex.dispatch_knn(jobs, kb)
+                            # the busy window opens on the devices the
+                            # snapshot actually spans
+                            dev_ids = mex.device_ids
+                            self._dev_enter(dev_ids)
+                            dev_entered = True
+                            with self._lock:
+                                self.stats["launches"] += 1
+                                self.stats["fused_jobs"] += len(jobs)
+                            self._add_flops(pend["flops"], dev_ids)
+                            g.rows = int(pend.get("rows", BPAD))
+                            self._record_bucket(g.rows, len(jobs))
+                        g.dispatched()
                         ctx.pending.append((key, jobs, fam, pend, dev_ids))
                         dispatched = True
-                        if prof_on:
-                            self._prof_mark(
-                                jobs, pend.get("rows", BPAD), t_prof,
-                                now, express,
-                            )
+                    else:
+                        if kind != "a":
+                            self._record_bucket(rows, len(jobs))
+                        with g.phase("es.dispatch"):
+                            if kind == "s":
+                                pend = self._dispatch_serve_group(
+                                    jobs, kb, rows=rows)
+                            elif kind == "k":
+                                pend = self._dispatch_knn_group(
+                                    jobs, rows=rows)
+                            elif kind == "a":
+                                pend = self._dispatch_agg_group(jobs)
+                            elif kind == "r":
+                                pend = self._dispatch_rerank_group(
+                                    jobs, rows=rows)
+                            else:  # "v"
+                                pend = self._dispatch_sparse_group(
+                                    jobs, kb, rows=rows)
+                        g.dispatched()
+                        ctx.pending.append((key, jobs, fam, pend, dev_ids))
+                        dispatched = True
+                        warm = kind in ("s", "k", "v")
                 except BaseException as e:  # surface to waiters
                     for j in jobs:
                         if not j.event.is_set():
                             j.error = e
-                            j.event.set()
+                            j.finish()
                 finally:
-                    if prof_on:
-                        self._tl.group_flops = None
+                    _worker_tl.group = None
                     if not dispatched:
                         self._exit_kind(fam)
                         if dev_entered:
                             self._dev_exit(dev_ids)
+                if warm:
+                    # after the group's own marks and waiters: bucket
+                    # warming is compile time, not this query's time
+                    self._maybe_warm(key, jobs, kb, rows)
         except BaseException as e:
             # stats/grouping crash between dequeue and the per-group
             # guard: already-dequeued jobs are not in the queue, so the
@@ -1120,7 +1232,7 @@ class QueryBatcher:
             for j in batch:
                 if not j.event.is_set():
                     j.error = e
-                    j.event.set()
+                    j.finish()
         return ctx
 
     def _collect_batch(self, ctx: "_BatchCtx"):
@@ -1129,51 +1241,52 @@ class QueryBatcher:
         try:
             for key, jobs, fam, pend, dev_ids in ctx.pending:
                 kind = key[1]
-                prof_on = any(j.prof is not None for j in jobs)
-                tc0 = time.perf_counter_ns() if prof_on else 0
+                g = jobs[0].group
+                _worker_tl.group = g
+                g.collecting()
                 try:
-                    # fault site: a collect-phase failure (device→host
-                    # transfer) fails this group's waiters only
-                    faults.check(
-                        "batcher.collect", family=fam, jobs=len(jobs),
-                        mesh=int(kind in ("Mm", "Ms", "Mk", "Mv")),
-                    )
-                    if kind == "s":
-                        self._collect_serve_group(jobs, key[-1], pend)
-                    elif kind == "k":
-                        self._collect_knn_group(jobs, pend)
-                    elif kind == "a":
-                        self._collect_agg_group(jobs, pend)
-                    elif kind == "r":
-                        self._collect_rerank_group(jobs, pend)
-                    elif kind == "v":
-                        self._collect_sparse_group(jobs, key[-1], pend)
-                    elif kind in ("Mm", "Ms"):
-                        t0 = time.perf_counter()
-                        jobs[0].executor.collect_match(jobs, pend)
-                        self._add_stall(time.perf_counter() - t0)
-                    elif kind == "Mk":
-                        t0 = time.perf_counter()
-                        jobs[0].executor.collect_knn(jobs, pend)
-                        self._add_stall(time.perf_counter() - t0)
-                    elif kind == "Ma":
-                        t0 = time.perf_counter()
-                        jobs[0].executor.collect_agg(jobs, pend)
-                        self._add_stall(time.perf_counter() - t0)
-                    elif kind == "Mv":
-                        t0 = time.perf_counter()
-                        jobs[0].executor.collect_sparse(jobs, pend)
-                        self._add_stall(time.perf_counter() - t0)
-                    else:
-                        self._collect_knn_group(jobs, pend)
-                    if prof_on:
-                        self._prof_collect(jobs, tc0)
+                    with g.phase("es.collect"):
+                        # fault site: a collect-phase failure (device→
+                        # host transfer) fails this group's waiters only
+                        faults.check(
+                            "batcher.collect", family=fam, jobs=len(jobs),
+                            mesh=int(kind in ("Mm", "Ms", "Mk", "Mv")),
+                        )
+                        if kind == "s":
+                            self._collect_serve_group(jobs, key[-1], pend)
+                        elif kind == "k":
+                            self._collect_knn_group(jobs, pend)
+                        elif kind == "a":
+                            self._collect_agg_group(jobs, pend)
+                        elif kind == "r":
+                            self._collect_rerank_group(jobs, pend)
+                        elif kind == "v":
+                            self._collect_sparse_group(jobs, key[-1], pend)
+                        elif kind in ("Mm", "Ms"):
+                            t0 = time.perf_counter()
+                            jobs[0].executor.collect_match(jobs, pend)
+                            self._add_stall(time.perf_counter() - t0)
+                        elif kind == "Mk":
+                            t0 = time.perf_counter()
+                            jobs[0].executor.collect_knn(jobs, pend)
+                            self._add_stall(time.perf_counter() - t0)
+                        elif kind == "Ma":
+                            t0 = time.perf_counter()
+                            jobs[0].executor.collect_agg(jobs, pend)
+                            self._add_stall(time.perf_counter() - t0)
+                        elif kind == "Mv":
+                            t0 = time.perf_counter()
+                            jobs[0].executor.collect_sparse(jobs, pend)
+                            self._add_stall(time.perf_counter() - t0)
+                        else:
+                            self._collect_knn_group(jobs, pend)
                 except BaseException as e:
                     for j in jobs:
                         if not j.event.is_set():
                             j.error = e
-                            j.event.set()
+                            j.finish()
                 finally:
+                    _worker_tl.group = None
                     self._exit_kind(fam)
                     self._dev_exit(dev_ids)
         finally:
@@ -1196,11 +1309,13 @@ class QueryBatcher:
 
     def _add_flops(self, n: int, dev_ids: Tuple[int, ...] = (0,)):
         n = int(n)
-        gf = getattr(self._tl, "group_flops", None)
-        if gf is not None:
-            # a profiled group is dispatching on this worker: credit the
-            # flops to it as well as to the node-level roofline counters
-            self._tl.group_flops = gf + n
+        g = getattr(_worker_tl, "group", None)
+        if g is not None:
+            # called once per recorded launch: credit the launch and its
+            # flops to the group this worker is dispatching as well as
+            # to the node-level roofline counters
+            g.launches += 1
+            g.flops += n
         with self._lock:
             self._flops += n
             if dev_ids:
@@ -1209,61 +1324,17 @@ class QueryBatcher:
                     d = self._devs.setdefault(did, [0, 0.0, 0.0, 0])
                     d[3] += share + (n - share * len(dev_ids) if i == 0 else 0)
 
-    # ---- per-request profiling ("profile": true) ----
-
-    def _prof_mark(self, jobs, rows, t0_ns, now_mono, express=False):
-        """Writes the dispatch-side breakdown of one profiled group into
-        every carrying job's prof dict: wall time of the launch, queue
-        wait, the group's flops (even share — the launch is shared),
-        pad bucket, batch width, and express-lane membership. Entries
-        are built aside and dict-swapped in so a reader that races the
-        write never observes a half-built entry."""
-        dt = time.perf_counter_ns() - t0_ns
-        fl = int(getattr(self._tl, "group_flops", 0) or 0)
-        self._tl.group_flops = None
-        n = max(len(jobs), 1)
-        for j in jobs:
-            p = j.prof
-            if p is None:
-                continue
-            fams = p.setdefault("families", {})
-            prev = fams.get(j.kind)
-            e = dict(prev) if prev else {
-                "launches": 0, "dispatch_ns": 0, "collect_ns": 0,
-                "queue_wait_ns": 0, "flops": 0, "bucket": 0,
-                "batch_jobs": 0, "express_lane": False, "pruned": False,
-            }
-            e["launches"] += 1
-            e["dispatch_ns"] += dt
-            e["queue_wait_ns"] += max(0, int((now_mono - j.t_enq) * 1e9))
-            e["flops"] += fl // n
-            e["bucket"] = int(rows or 0)
-            e["batch_jobs"] = n
-            if express:
-                e["express_lane"] = True
-            if p.get("pruned_jobs"):
-                e["pruned"] = True
-            fams[j.kind] = e
-
-    def _prof_collect(self, jobs, t0_ns):
-        """Collect-side twin of _prof_mark: adds the device→host
-        transfer + host-merge wall time of one profiled group."""
-        dt = time.perf_counter_ns() - t0_ns
-        for j in jobs:
-            p = j.prof
-            if p is None:
-                continue
-            fams = p.setdefault("families", {})
-            prev = fams.get(j.kind)
-            e = dict(prev) if prev else {
-                "launches": 0, "dispatch_ns": 0, "collect_ns": 0,
-                "queue_wait_ns": 0, "flops": 0, "bucket": 0,
-                "batch_jobs": 0, "express_lane": False, "pruned": False,
-            }
-            e["collect_ns"] += dt
-            if p.get("pruned_jobs"):
-                e["pruned"] = True
-            fams[j.kind] = e
+    def _count_overflow(self, fplans: list):
+        """Jobs whose plan does not fit the fused kernel's slots send
+        their whole group down the slower path: counted, and flagged on
+        the group this worker is dispatching (its `dispatch` span)."""
+        g = getattr(_worker_tl, "group", None)
+        if g is not None:
+            g.overflow = True
+        with self._lock:
+            self.stats["fused_overflow_jobs"] += sum(
+                1 for p in fplans if p is None
+            )
 
     def _add_stall(self, seconds: float):
         with self._lock:
@@ -1297,6 +1368,7 @@ class QueryBatcher:
             warm_failed = self.stats["warmup_failures"]
         with self._cold_lock:
             cold_ms = round(self._cold_s * 1000.0, 3)
+            compiles = self._compiles
         return {
             "buckets": list(self.buckets),
             "launches_by_bucket": hist,
@@ -1308,6 +1380,7 @@ class QueryBatcher:
             # the cold clock: compile time on the dispatcher workers,
             # kept out of the admission layer's queue-delay signal
             "worker_compile_ms": cold_ms,
+            "worker_compiles": compiles,
         }
 
     def _maybe_warm(self, key, jobs: List[_Job], kb: int, rows: int):
@@ -1477,17 +1550,30 @@ class QueryBatcher:
         }
 
     def _run_group(self, jobs: List[_Job], field: str, kb: int,
-                   rows: Optional[int] = None, record: bool = True,
-                   prof_cb=None):
-        """`rows` is the group's padded launch width (a ladder bucket >=
-        len(jobs); default BPAD); `record=False` (bucket warmup) skips
-        all stats/flop accounting. `prof_cb` (profiled groups) fires
-        after device work completes but BEFORE waiter events are set, so
-        a profiled request never observes its own launch missing."""
+                   rows: Optional[int] = None, record: bool = True):
+        """A match group from dispatch to its waiters' wake-up, on the
+        worker (its pruning round is host-dependent, so the group is
+        never in flight: `inflight` has no length). `rows` is the
+        group's padded launch width (a ladder bucket >= len(jobs);
+        default BPAD); `record=False` (bucket warmup) skips all
+        stats/flop accounting."""
+        rows = rows or BPAD
+        g = jobs[0].group or _Group("match", len(jobs), rows)  # warm-up
+        with g.phase("es.dispatch"):
+            pend = self._dispatch_match_group(jobs, field, kb, rows, record)
+        g.dispatched()
+        g.collecting(g.t_dispatched)
+        with g.phase("es.collect"):
+            self._collect_match_group(jobs, kb, pend, record)
+
+    def _dispatch_match_group(self, jobs: List[_Job], field: str, kb: int,
+                              rows: int, record: bool) -> Tuple:
+        """Every segment's scoring, up to the device-resident candidate
+        buffers: the fused kernel, or the chunked block-max path with
+        its one host-dependent threshold round."""
         ex = jobs[0].executor
         reader = ex.reader
         nj = len(jobs)
-        rows = rows or BPAD
         staging = getattr(ex, "staging_slab", None)
         # shard-level pruning eligibility: a capped total may only be
         # shortcut to (cap, gte) when ≥ cap live matches are guaranteed
@@ -1536,10 +1622,7 @@ class QueryBatcher:
                     dev_items.append((si, *fs.device_result(pend)))
                     continue
                 if record:
-                    with self._lock:
-                        self.stats["fused_overflow_jobs"] += sum(
-                            1 for p in fplans if p is None
-                        )
+                    self._count_overflow(fplans)
             # ---- chunked path (small segments / slot overflow) ----
             bmx = ex.block_index(si, field)
             cs = ex.chunked_scorer(si, field)
@@ -1624,9 +1707,17 @@ class QueryBatcher:
             dev_items.append(
                 (si, *cs.finalize_device(acc, cnt, msm, kb))
             )
-        # device-side cross-segment merge: ONE top-k kernel + ONE packed
-        # download per group (score desc, (segment, doc) asc — identical
-        # ordering to the old host sort, selection only → float-exact)
+        return dev_items, pruned_flags
+
+    def _collect_match_group(self, jobs: List[_Job], kb: int, pend: Tuple,
+                             record: bool):
+        """Device-side cross-segment merge: ONE top-k kernel + ONE packed
+        download per group (score desc, (segment, doc) asc — identical
+        ordering to the old host sort, selection only → float-exact),
+        then each job's hits."""
+        dev_items, pruned_flags = pend
+        reader = jobs[0].executor.reader
+        nj = len(jobs)
         if dev_items:
             t0 = time.perf_counter()
             ms, mseg, mdoc, mtot = scoring.merge_segment_topk(dev_items, kb)
@@ -1636,8 +1727,6 @@ class QueryBatcher:
             ms = np.full((nj, 0), -np.inf, np.float32)
             mseg = mdoc = np.zeros((nj, 0), np.int32)
             mtot = np.zeros((nj, 0), np.int64)
-        if prof_cb is not None:
-            prof_cb()
         for ji, j in enumerate(jobs):
             finite = np.isfinite(ms[ji])
             hits = [
@@ -1677,7 +1766,7 @@ class QueryBatcher:
                 max_score=hits[0].score if hits else None,
                 relation=relation,
             )
-            j.event.set()
+            j.finish()
 
     # ---- dispatch/collect pairs (device work launches in dispatch;
     # only collect blocks on host transfers) ----
@@ -1751,10 +1840,7 @@ class QueryBatcher:
                 items.append(("fused", si, fs, pend))
             else:
                 if record and fs is not None and fplans is not None:
-                    with self._lock:
-                        self.stats["fused_overflow_jobs"] += sum(
-                            1 for p in fplans if p is None
-                        )
+                    self._count_overflow(fplans)
                 items.append(("fallback", si, None, None))
         return items
 
@@ -1829,7 +1915,7 @@ class QueryBatcher:
                 continue
             if tag == "err":
                 j.error = pend
-                j.event.set()
+                j.finish()
                 continue
             try:
                 t0 = time.perf_counter()
@@ -1837,7 +1923,7 @@ class QueryBatcher:
                 self._add_stall(time.perf_counter() - t0)
             except BaseException as e:
                 j.error = e
-            j.event.set()
+            j.finish()
 
     def _dispatch_rerank_group(self, jobs: List[_Job],
                                rows: Optional[int] = None) -> Tuple:
@@ -1914,7 +2000,7 @@ class QueryBatcher:
             for j in jobs:
                 if not j.event.is_set():
                     j.result = ("skip", None, None, 0.0)
-                    j.event.set()
+                    j.finish()
             return
         t1 = time.perf_counter()
         scores, perm = rerank_ops.unpack_rescore(out)
@@ -1925,7 +2011,7 @@ class QueryBatcher:
                 continue
             w = len(j.plan.first)
             j.result = ("ok", scores[ji][:w], perm[ji][:w], kernel_ms)
-            j.event.set()
+            j.finish()
 
     def _dispatch_knn_group(self, jobs: List[_Job],
                             rows: Optional[int] = None,
@@ -2011,7 +2097,13 @@ class QueryBatcher:
             vectors, exists = ex.device_segments[si].vectors[field]
             cand_mask = exists
             if live is not None:
-                cand_mask = cand_mask & np.asarray(live)
+                live = np.asarray(live)
+                note_transfer("h2d", live.nbytes)
+                cand_mask = cand_mask & live
+            # host rows handed to the jitted program: the launch uploads
+            # them (noted here, the program itself cannot)
+            note_transfer("h2d", q.nbytes)
+            note_transfer("h2d", valid.nbytes)
             s, d, _ = scoring.knn_topk_batch(
                 np.asarray(q), np.asarray(valid),
                 vectors, cand_mask, vf.similarity, kc,
@@ -2077,7 +2169,7 @@ class QueryBatcher:
                     max_score=hits[0].score if hits else None,
                     relation="eq",
                 )
-                j.event.set()
+                j.finish()
             return
         for si, n, s, d in items:
             s = np.asarray(s)
@@ -2287,7 +2379,7 @@ class QueryBatcher:
                 max_score=hits[0].score if hits else None,
                 relation=relation,
             )
-            j.event.set()
+            j.finish()
 
     def _finish_jobs(self, jobs, per_job_cands, totals, reader,
                      page_caps=None):
@@ -2315,7 +2407,7 @@ class QueryBatcher:
                 max_score=hits[0].score if hits else None,
                 relation="eq",
             )
-            j.event.set()
+            j.finish()
 
     @staticmethod
     def _collect(jobs, per_job_cands, totals, si, s, d, t):

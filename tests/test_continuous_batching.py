@@ -753,7 +753,7 @@ class TestBatchingStats:
         assert set(bs) == {
             "buckets", "launches_by_bucket", "occupancy_jobs",
             "occupancy_slots", "avg_occupancy", "express_lane_hits",
-            "warmup_failures", "worker_compile_ms",
+            "warmup_failures", "worker_compile_ms", "worker_compiles",
         }
         assert bs["warmup_failures"] == 0
         assert bs["worker_compile_ms"] > 0.0  # this batcher compiled

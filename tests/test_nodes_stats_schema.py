@@ -18,7 +18,7 @@ REQUIRED = {
     "pipeline.batching": {
         "buckets", "launches_by_bucket", "occupancy_jobs",
         "occupancy_slots", "express_lane_hits", "avg_occupancy",
-        "warmup_failures", "worker_compile_ms",
+        "warmup_failures", "worker_compile_ms", "worker_compiles",
     },
     "pipeline.mesh": {
         "routed", "launches", "jobs", "rebuilds", "degraded",
@@ -45,6 +45,9 @@ REQUIRED = {
     "ingest": {"refreshers_running"},
     "breakers": {"hbm"},
     "thread_pool": {"search"},
+    "transfer.scoring": {
+        "h2d_count", "h2d_bytes", "d2h_count", "d2h_bytes",
+    },
 }
 
 
