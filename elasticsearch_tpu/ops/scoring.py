@@ -26,9 +26,10 @@ parallel" idea from BASELINE.json's north star):
 * `FusedScorer` — one round trip per large segment: the whole query
   phase (rare-tile gather + dense hot-term rows + msm mask + top-k)
   runs as a single compiled program fed by one packed int32 plan
-  upload and returning one packed download, because on the measured
-  hardware each host↔device transfer costs ~100 ms while the kernels
-  are <15 ms (see the cost model below).
+  upload and returning one packed download. On the attached chip a
+  round trip is 0.4-0.5 ms and the program 1-2 ms, against ~27 launches
+  and two blocking downloads on the chunked path (see the cost model
+  below).
 
 Scores are float32 end-to-end for oracle parity.
 """
@@ -375,29 +376,45 @@ def _score_tiles_inner(doc_rows, tf_rows, tile_weights, tile_valid, inv_norm, n_
 # per-doc tf rows — a pure vectorized add with no scatter — and rare
 # terms through the tile scatter. Totals come out exact, so
 # track_total_hits semantics reduce to response shaping. Block-max
-# pruning (ops/wand.py) stays off this path because its θ-broadcast
-# needs a mid-batch host<->device transfer; the pruned path remains for
-# segments without dense rows and as the scale-out strategy when dense
-# rows exceed the HBM budget.
+# pruning (ops/wand.py) stays off this path: its θ-broadcast is a
+# blocking download between two rounds of chunk launches. The chunked
+# pruned path remains as the fallback for segments without dense rows
+# (under FUSED_MIN_DOCS), for queries over a slot budget below, and as
+# the scale-out strategy when dense rows exceed the HBM budget.
 #
-# Where that design came from: it was tuned to the host<->device
-# transfer costs of hardware that is no longer present, on which one
-# transfer cost far more than the kernels it fed. On the attached chip
-# those costs — and therefore whether one round trip per batch, no
-# pruning and several dispatcher workers are still the right trade —
-# are "not measured" until chip_smoke.py's round-trip and latency
-# figures are recorded in CHANGES.md; re-read this block against them.
+# What it costs on the attached chip (TPU v5e; PERF.md sections 5, 6):
+# a host<->device round trip is 0.4-0.5 ms (4 bytes: 0.51 ms up, 0.37 ms
+# down; PR 21), not the ~100 ms this design was first tuned against, so
+# the one upload and the one download are a tenth of a 5.5 ms request.
+# What is expensive is LEAVING this program: a query that overflows a
+# slot budget takes its whole launch group to the chunked path, where a
+# hot term is scored through its tiles (a term of rank 10 in a 1M-doc
+# segment is ~2,700 tiles = 5 chunk launches of ~1.1 ms) and a request
+# is ~27 launches, ~126 KB of uploads and two blocking downloads:
+# 48.7 ms against 5.5 (PR 25).
+#
+# Hence the budgets. FUSED_H covers every word of a natural-language
+# question: the standard analyzer keeps stop words, `match` ORs them
+# in, and on MS MARCO's shapes (Zipf(1), ~500 terms past the dense
+# threshold, questions of 2-12 words) 14.5% of questions hold more than
+# 4 hot terms, 2.2% more than 6, 0.15% more than 8 and none more than
+# 12. The budget only widens the plan row (537 int32): the program
+# loops over the slots a launch uses (`_add_hot_rows`), so an unused
+# slot costs nothing. FUSED_T_RARE is not what overflows (99.9th
+# percentile 169 tiles of 256 there). `pipeline.batching.
+# fused_hot_slots` in `_nodes/stats` counts the slots fused jobs really
+# use; `fused_overflow_jobs` the jobs that did not fit.
 # ---------------------------------------------------------------------------
 
 FUSED_T_RARE = 256  # rare tile slots per query (fixed compile shape)
-FUSED_H = 4  # dense hot-term slots per query (fixed compile shape)
+FUSED_H = 12  # dense hot-term slots per query (fixed compile shape)
 DENSE_TF_MAX = 255  # uint8 dense rows; overflowing postings go sparse
 
 
 def build_dense_rows(doc_ids, tfs, hot_tiles, hot_rank_of_tile, n_hot, n_docs):
     """uint8[n_hot, n_docs] per-doc tf rows for hot terms, built ON
-    DEVICE from the already-resident postings tiles (no 100ms-per-MB
-    host upload). Postings with tf > DENSE_TF_MAX are stored as 0 here
+    DEVICE from the already-resident postings tiles (nothing is
+    uploaded). Postings with tf > DENSE_TF_MAX are stored as 0 here
     and must be scored through sparse overflow tiles (exactness)."""
 
     @functools.partial(jax.jit, static_argnames=("n_hot", "n_docs"))
@@ -547,6 +564,57 @@ class FusedScorer:
         )
 
 
+def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed):
+    """Adds a launch's dense hot-term rows to its accumulators: `acc`
+    f32[B, n], `cnt` i32[B, >= n] or None (no count plane), `hot_ids`
+    i32[B, H] rows of `dense` (-1 = unused), `hot_w` f32[B, H]. With
+    `signed` a weight's sign says whether the term counts (w > 0) and
+    |w| scores (MultiFusedScorer); without, every match counts.
+
+    A loop over the slots the launch USES, not over the budget H, and
+    one dynamic-slice per row, not a gather of rows. Measured on the
+    TPU v5e at 1M docs, 500 dense rows, H = 12 (PERF.md section 6,
+    PR 26): an unrolled `for h in range(H)` over `dense[hid]` costs
+    47 us a slot and row whether the slot is used or not (a one-row
+    launch 1.63 ms where H = 4 took 1.25: a row of the (8,128)(4,1)-
+    tiled uint8 plane is read as whole 32-row tiles). This form takes
+    1.18-1.21 ms at one row and 2.7-2.9 at four (H = 4 unrolled: 3.97;
+    the gather of rows also cost the rest of the program its layout),
+    6.6-7.2 at 8 and 13.5-14.8 at 16 (unrolled 8.45, 15.5); only at 32
+    rows is the unrolled gather ahead (32.0 against 35-40). An unused
+    slot added 0.0, so the sums are the same floats as before."""
+    n = acc.shape[1]
+    H = hot_ids.shape[1]
+    # the highest slot any row uses (pack_plans fills slots from 0 up)
+    used = jnp.max(
+        jnp.where(hot_ids >= 0, jnp.arange(1, H + 1, dtype=jnp.int32), 0)
+    )
+
+    def slot(h, carry):
+        acc, cnt = carry
+        hid = jax.lax.dynamic_index_in_dim(hot_ids, h, axis=1, keepdims=False)
+        w = jax.lax.dynamic_index_in_dim(hot_w, h, axis=1, keepdims=False)
+        ok = hid >= 0
+        safe = jnp.clip(hid, 0, dense.shape[0] - 1)
+        row_tf = jnp.concatenate(
+            [
+                jax.lax.dynamic_slice(dense, (safe[b], 0), (1, n))
+                for b in range(hot_ids.shape[0])
+            ],
+            axis=0,
+        ).astype(jnp.float32)
+        wa = jnp.where(ok, jnp.abs(w) if signed else w, 0.0)[:, None]
+        contrib = wa - wa / (jnp.float32(1.0) + row_tf * inv_norm[None, :])
+        match = (row_tf > 0) & ok[:, None]
+        acc = acc + jnp.where(match, contrib, 0.0)
+        if cnt is not None:
+            counted = match & (w > 0)[:, None] if signed else match
+            cnt = cnt.at[:, :n].add(counted.astype(jnp.int32))
+        return acc, cnt
+
+    return jax.lax.fori_loop(0, used, slot, (acc, cnt))
+
+
 @functools.partial(
     jax.jit, static_argnames=("t_rare", "n_hot", "k", "with_cnt")
 )
@@ -572,6 +640,7 @@ def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, t_rare, n_hot, k, wi
     acc = jnp.zeros((plan.shape[0], n + 1), jnp.float32)
     acc = jax.vmap(lambda a, d, v: a.at[d.ravel()].add(v.ravel()))(acc, tgt, s)
     acc = acc[:, :n]
+    cnt = None
     if with_cnt:
         cnt = jnp.zeros((plan.shape[0], n + 1), jnp.int32)
         cnt = jax.vmap(
@@ -581,16 +650,9 @@ def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, t_rare, n_hot, k, wi
 
     # ---- hot terms: dense per-doc tf rows, pure vector math ----
     if dense is not None and dense.shape[0] > 0:
-        for h in range(H):
-            hid = hot_ids[:, h]
-            ok = hid >= 0
-            row_tf = dense[jnp.clip(hid, 0, dense.shape[0] - 1)].astype(jnp.float32)
-            wh = jnp.where(ok, hot_w[:, h], 0.0)[:, None]
-            contrib = wh - wh / (jnp.float32(1.0) + row_tf * inv_norm[None, :])
-            match = (row_tf > 0) & ok[:, None]
-            acc = acc + jnp.where(match, contrib, 0.0)
-            if with_cnt:
-                cnt = cnt + match.astype(jnp.int32)
+        acc, cnt = _add_hot_rows(
+            acc, cnt, dense, inv_norm, hot_ids, hot_w, signed=False
+        )
 
     # ---- collection ----
     if with_cnt:
@@ -779,22 +841,11 @@ def _fused_query_mf(
             lambda c, d, v: c.at[d.ravel()].add(v.ravel().astype(jnp.int32))
         )(cnt, tgt, counted)
         acc = acc[:, :n]
-        # hot terms: dense rows
+        # hot terms: dense rows; |w| scores, w>0 counts
         if dense is not None and dense.shape[0] > 0:
-            for h in range(H):
-                hid = hot_ids[:, h]
-                ok = hid >= 0
-                row_tf = dense[jnp.clip(hid, 0, dense.shape[0] - 1)].astype(
-                    jnp.float32
-                )
-                wa = jnp.where(ok, jnp.abs(hot_w[:, h]), 0.0)[:, None]
-                contrib = wa - wa / (
-                    jnp.float32(1.0) + row_tf * inv_norm[None, :]
-                )
-                match = (row_tf > 0) & ok[:, None]
-                acc = acc + jnp.where(match, contrib, 0.0)
-                counted_h = match & (hot_w[:, h] > 0)[:, None]
-                cnt = cnt.at[:, :n].add(counted_h.astype(jnp.int32))
+            acc, cnt = _add_hot_rows(
+                acc, cnt, dense, inv_norm, hot_ids, hot_w, signed=True
+            )
         accs.append(acc)
     cnt = cnt[:, :n]
     if F == 1:

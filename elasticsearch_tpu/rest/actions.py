@@ -609,6 +609,7 @@ class RestActions:
             "occupancy_slots": 0,
             "express_lane_hits": 0,
             "warmup_failures": 0,
+            "fused_hot_slots": {},
             "worker_compile_ms": 0.0,
             "worker_compiles": 0,
         }
@@ -649,6 +650,10 @@ class RestActions:
                 batching["occupancy_slots"] += bs["occupancy_slots"]
                 batching["express_lane_hits"] += bs["express_lane_hits"]
                 batching["warmup_failures"] += bs["warmup_failures"]
+                for h, n in bs["fused_hot_slots"].items():
+                    batching["fused_hot_slots"][h] = (
+                        batching["fused_hot_slots"].get(h, 0) + n
+                    )
                 batching["worker_compile_ms"] += bs["worker_compile_ms"]
                 batching["worker_compiles"] += bs["worker_compiles"]
             mex = getattr(idx, "_mesh", None)

@@ -721,6 +721,9 @@ class QueryBatcher:
         self._device_busy_s = 0.0
         self._host_stall_s = 0.0
         live_batchers.add(self)
+        # dense hot-term slots each fused match job used, 0..FUSED_H:
+        # how much of the kernel's slot budget real questions take
+        self._fused_hot_slots = [0] * (scoring.FUSED_H + 1)
         # observability: how many launches / jobs / batched jobs
         self.stats = {
             "launches": 0,
@@ -1366,6 +1369,9 @@ class QueryBatcher:
             jobs, slots = self._occ_jobs, self._occ_slots
             express = self.stats["express_lane_hits"]
             warm_failed = self.stats["warmup_failures"]
+            hot_slots = {
+                str(h): n for h, n in enumerate(self._fused_hot_slots)
+            }
         with self._cold_lock:
             cold_ms = round(self._cold_s * 1000.0, 3)
             compiles = self._compiles
@@ -1377,6 +1383,8 @@ class QueryBatcher:
             "avg_occupancy": round(jobs / slots, 4) if slots else 0.0,
             "express_lane_hits": express,
             "warmup_failures": warm_failed,
+            # fused match jobs by dense hot-term slots used (0..FUSED_H)
+            "fused_hot_slots": hot_slots,
             # the cold clock: compile time on the dispatcher workers,
             # kept out of the admission layer's queue-delay signal
             "worker_compile_ms": cold_ms,
@@ -1613,6 +1621,8 @@ class QueryBatcher:
                         with self._lock:
                             self.stats["launches"] += 1
                             self.stats["fused_jobs"] += nj
+                            for p in fplans:
+                                self._fused_hot_slots[len(p[2])] += 1
                         self._add_flops(sum(
                             scoring.text_plan_flops(
                                 len(p[0]), len(p[2]), n_docs

@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from elasticsearch_tpu.common import tracing
+from elasticsearch_tpu.ops import scoring
 
 
 @pytest.fixture(autouse=True)
@@ -152,10 +153,13 @@ DIMS = 8
 WORDS = ["alpha", "beta", "gamma", "delta", "epsilon", "zeta", "eta",
          "theta", "iota", "kappa"]
 MATCH = {"query": {"match": {"body": "alpha beta"}}, "size": 10}
-# six terms: more than the fused kernel's FUSED_H = 4 dense slots take.
-# Terms are hot from 1,024 postings up, which this small index has not,
-# so the test below applies the slot rule to the term count instead and
-# the group overflows to the chunked block-max path as a real one does
+# six terms. The fused kernel overflows when a query holds more HOT
+# terms than its dense slots (scoring.FUSED_H), and terms are hot from
+# 1,024 postings up, which this small index has not. So the test below
+# applies a slot rule of its own to the term count (more than 4 terms:
+# the number is this test's, not the kernel's budget) and the group
+# overflows to the chunked block-max path as a real one does;
+# tests/test_fused_slots.py overflows the real budget with hot terms
 OVERFLOW = {"query": {"match": {
     "body": "alpha beta gamma delta epsilon zeta"}}, "size": 10}
 KNN = {"knn": {"field": "vec", "query_vector": [1.0] + [0.0] * (DIMS - 1),
@@ -432,10 +436,11 @@ class TestTransferCounters:
         }
 
     def test_one_fused_match_request(self, fused_service):
-        # up: the packed plan i32[1, 2 * 256 + 2 * 4 + 1] and the merge's
-        # i32[16]; down: one packed i32[1, 3 * 16 + 1]
+        # up: the packed plan i32[1, 2 * 256 + 2 * FUSED_H + 1] and the
+        # merge's i32[16]; down: one packed i32[1, 3 * 16 + 1]
+        plan_bytes = 4 * (2 * scoring.FUSED_T_RARE + 2 * scoring.FUSED_H + 1)
         assert self.delta(fused_service, MATCH) == {
-            "h2d_count": 2, "h2d_bytes": 2084 + 64,
+            "h2d_count": 2, "h2d_bytes": plan_bytes + 64,
             "d2h_count": 1, "d2h_bytes": 196,
         }
 
