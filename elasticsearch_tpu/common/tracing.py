@@ -39,7 +39,9 @@ the serving process; tags in brackets):
     job's life from submit to its waiter's wake-up:
         queue_wait [family, cold_ms]  submit -> a worker starts the
             job's group; cold_ms = compile time that accrued meanwhile
-        dispatch [family, jobs, rows, launches, express, overflow]
+        dispatch [family, jobs, rows, launches, express, overflow; a
+            fused serve group also fields, hot_slots, rare_tiles: the
+            most dense rows / tile slots a job and field used]
             -> the group's last kernel is enqueued
         inflight  -> the worker comes back to collect the group
         collect [d2h_bytes]  merge kernel, blocking download, hits
@@ -56,7 +58,8 @@ process and they land on the host plane of the `.xplane.pb`, one line
 per dispatcher thread, beside the device's `XLA Ops` line.
 
 `note_transfer` counts the query path's host<->device transfers where
-they happen (ops/scoring.py and the kNN upload in search/batcher.py);
+they happen (ops/scoring.py, the kNN upload in search/batcher.py, and
+the serve family's per-job fallback, `JaxExecutor.segment_topk`);
 `_nodes/stats` reports the totals as `transfer.scoring.*`.
 
 `OPAQUE_ID_CTX` carries the request's `X-Opaque-Id` header value so

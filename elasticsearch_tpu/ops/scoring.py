@@ -394,42 +394,58 @@ def _score_tiles_inner(doc_rows, tf_rows, tile_weights, tile_valid, inv_norm, n_
 # 48.7 ms against 5.5 (PR 25).
 #
 # Hence the budgets. FUSED_H covers every word of a natural-language
-# question: the standard analyzer keeps stop words, `match` ORs them
-# in, and on MS MARCO's shapes (Zipf(1), ~500 terms past the dense
-# threshold, questions of 2-12 words) 14.5% of questions hold more than
-# 4 hot terms, 2.2% more than 6, 0.15% more than 8 and none more than
-# 12. The budget only widens the plan row (537 int32): the program
-# loops over the slots a launch uses (`_add_hot_rows`), so an unused
-# slot costs nothing. FUSED_T_RARE is not what overflows (99.9th
-# percentile 169 tiles of 256 there). `pipeline.batching.
-# fused_hot_slots` in `_nodes/stats` counts the slots fused jobs really
-# use; `fused_overflow_jobs` the jobs that did not fit.
+# question: the standard analyzer keeps stop words, `match` and
+# `multi_match` OR them in, and questions of 2-12 words drawn from a
+# Zipf(1) collection hold at most 12 terms that hold a dense row, in
+# both deployments the benchmark builds. MS MARCO passage (1M passages
+# of ~56 tokens a shard): 500 terms pass the dense threshold and all
+# hold a row; 14.5% of questions hold more than 4 of them, 2.2% more
+# than 6, 0.15% more than 8, none more than 12. MS MARCO document
+# (401,729 documents of ~1,150 tokens): 9,510 body terms pass it, the
+# row budget holds 2,672 (executor_jax.DENSE_ROWS_HBM_BUDGET), and no
+# question of 5,000 holds more than 10. The budget only widens the plan
+# row (537 int32 a field): the program loops over the slots a launch
+# uses (`_add_hot_rows`), so an unused slot costs nothing. FUSED_T_RARE
+# is what a long-document shard overflows: a term left without a row
+# costs its tiles (up to ~90 there, 61 in passages); the 99.9th
+# percentile question carries 169 tiles of 256 in passages, the 99th
+# 157 in documents, where 0.02% pass 256. In `_nodes/stats`,
+# `pipeline.batching.fused_hot_slots` / `serve_hot_slots` count the
+# slots fused jobs really use, `fused_overflow_jobs` /
+# `serve_fallback_jobs` the jobs that did not fit.
 # ---------------------------------------------------------------------------
 
 FUSED_T_RARE = 256  # rare tile slots per query (fixed compile shape)
 FUSED_H = 12  # dense hot-term slots per query (fixed compile shape)
-DENSE_TF_MAX = 255  # uint8 dense rows; overflowing postings go sparse
+DENSE_TF_MAX = 255  # uint8 dense rows
+# uint16 rows for the few hot terms whose tf passes DENSE_TF_MAX somewhere
+# (a stop word in a document of thousands of tokens); past this a term
+# stays sparse: a row never clips a tf
+WIDE_TF_MAX = 65535
 
 
-def build_dense_rows(doc_ids, tfs, hot_tiles, hot_rank_of_tile, n_hot, n_docs):
-    """uint8[n_hot, n_docs] per-doc tf rows for hot terms, built ON
+def build_dense_rows(doc_ids, tfs, hot_tiles, hot_rank_of_tile, n_hot, n_docs,
+                     dtype=jnp.uint8):
+    """`dtype`[n_hot, n_docs] per-doc tf rows for hot terms, built ON
     DEVICE from the already-resident postings tiles (nothing is
-    uploaded). Postings with tf > DENSE_TF_MAX are stored as 0 here
-    and must be scored through sparse overflow tiles (exactness)."""
+    uploaded). The caller gives a term a row of a dtype that holds its
+    largest tf (uint8, or uint16 past DENSE_TF_MAX); a posting over the
+    dtype's range would be stored as 0, never clipped."""
 
-    @functools.partial(jax.jit, static_argnames=("n_hot", "n_docs"))
-    def build(doc_ids, tfs, hot_tiles, rank_of_tile, n_hot, n_docs):
+    @functools.partial(jax.jit, static_argnames=("n_hot", "n_docs", "dtype"))
+    def build(doc_ids, tfs, hot_tiles, rank_of_tile, n_hot, n_docs, dtype):
         rows_d = doc_ids[hot_tiles]  # [T_hot, 128]
         rows_t = tfs[hot_tiles]
-        valid = (rows_d >= 0) & (rows_t <= DENSE_TF_MAX)
+        valid = (rows_d >= 0) & (rows_t <= jnp.iinfo(dtype).max)
         docs = jnp.where(valid, rows_d, n_docs)
         flat = rank_of_tile[:, None] * (n_docs + 1) + docs
-        tf8 = jnp.where(valid, rows_t, 0).astype(jnp.uint8)
-        dense = jnp.zeros(n_hot * (n_docs + 1), jnp.uint8)
-        dense = dense.at[flat.ravel()].set(tf8.ravel())
+        tf = jnp.where(valid, rows_t, 0).astype(dtype)
+        dense = jnp.zeros(n_hot * (n_docs + 1), dtype)
+        dense = dense.at[flat.ravel()].set(tf.ravel())
         return dense.reshape(n_hot, n_docs + 1)[:, :n_docs]
 
-    return build(doc_ids, tfs, hot_tiles, hot_rank_of_tile, n_hot, n_docs)
+    return build(doc_ids, tfs, hot_tiles, hot_rank_of_tile, n_hot, n_docs,
+                 jnp.dtype(dtype))
 
 
 class FusedScorer:
@@ -438,7 +454,9 @@ class FusedScorer:
     Plan packing (int32[B, 2*T_RARE + 2*H + 1]):
       [0:T)          rare tile ids into the postings arrays (-1 = pad)
       [T:2T)         float32 tile weights, bitcast
-      [2T:2T+H)      dense hot rows (-1 = pad)
+      [2T:2T+H)      dense hot rows (-1 = pad): r < n8 is row r of the
+                     uint8 plane, r >= n8 row r - n8 of the uint16 one
+                     (those first: `wide_rows_first`)
       [2T+H:2T+2H)   float32 hot weights, bitcast
       [2T+2H]        minimum_should_match
 
@@ -455,12 +473,14 @@ class FusedScorer:
         dense_rows,  # uint8[n_hot, n_docs] (may be n_hot == 0)
         t_rare: int = FUSED_T_RARE,
         n_hot_slots: int = FUSED_H,
+        wide_rows=None,  # uint16[n_wide, n_docs]: tf past DENSE_TF_MAX
     ):
         self.doc_ids = doc_ids
         self.tfs = tfs
         self.inv_norm = jnp.asarray(inv_norm, jnp.float32)
         self.live = jnp.asarray(live) if live is not None else None
         self.dense = dense_rows
+        self.wide = wide_rows
         self.n_docs = int(self.inv_norm.shape[0])
         self.t_rare = t_rare
         self.n_hot_slots = n_hot_slots
@@ -528,6 +548,7 @@ class FusedScorer:
             live if live is not None else self.live,
             self.dense,
             jax.device_put(packed),
+            self.wide,
             t_rare=self.t_rare,
             n_hot=self.n_hot_slots,
             k=k,
@@ -564,7 +585,42 @@ class FusedScorer:
         )
 
 
-def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed):
+def wide_rows_first(hot_rows: list, hot_w: list, dense) -> None:
+    """Orders a plan's hot slots in place, uint16 rows (numbered on from
+    the rows of the uint8 plane `dense`) before uint8 ones, each kind in
+    its given order: `_add_hot_terms` then walks one run of slots a
+    plane."""
+    n8 = 0 if dense is None else dense.shape[0]
+    order = sorted(range(len(hot_rows)), key=lambda i: hot_rows[i] < n8)
+    hot_rows[:] = [hot_rows[i] for i in order]
+    hot_w[:] = [hot_w[i] for i in order]
+
+
+def _add_hot_terms(acc, cnt, dense, wide, inv_norm, hot_ids, hot_w, signed):
+    """The hot-term pass of both fused programs over a field's two dense
+    planes: `dense` uint8[n8, n] and, where some hot term's tf passes
+    DENSE_TF_MAX, `wide` uint16[n16, n] (hot id r >= n8 is its row
+    r - n8). Without `wide` (every deployment whose documents are short)
+    this is `_add_hot_rows` over `dense`, the same program as before."""
+    n8 = 0 if dense is None else dense.shape[0]
+    if wide is None or wide.shape[0] == 0:
+        if n8:
+            acc, cnt = _add_hot_rows(
+                acc, cnt, dense, inv_norm, hot_ids, hot_w, signed)
+        return acc, cnt
+    is_wide = hot_ids >= n8
+    acc, cnt = _add_hot_rows(
+        acc, cnt, wide, inv_norm, jnp.where(is_wide, hot_ids - n8, -1),
+        hot_w, signed, from_first_used=True)
+    if n8:
+        acc, cnt = _add_hot_rows(
+            acc, cnt, dense, inv_norm, jnp.where(is_wide, -1, hot_ids),
+            hot_w, signed, from_first_used=True)
+    return acc, cnt
+
+
+def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
+                  from_first_used=False):
     """Adds a launch's dense hot-term rows to its accumulators: `acc`
     f32[B, n], `cnt` i32[B, >= n] or None (no count plane), `hot_ids`
     i32[B, H] rows of `dense` (-1 = unused), `hot_w` f32[B, H]. With
@@ -582,13 +638,19 @@ def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed):
     the gather of rows also cost the rest of the program its layout),
     6.6-7.2 at 8 and 13.5-14.8 at 16 (unrolled 8.45, 15.5); only at 32
     rows is the unrolled gather ahead (32.0 against 35-40). An unused
-    slot added 0.0, so the sums are the same floats as before."""
+    slot added 0.0, so the sums are the same floats as before.
+
+    `from_first_used` starts the loop at the lowest used slot instead of
+    slot 0: the caller has split the slots between two planes, and the
+    other plane's run (`wide_rows_first`) reads as unused here."""
     n = acc.shape[1]
     H = hot_ids.shape[1]
     # the highest slot any row uses (pack_plans fills slots from 0 up)
-    used = jnp.max(
-        jnp.where(hot_ids >= 0, jnp.arange(1, H + 1, dtype=jnp.int32), 0)
-    )
+    slots = jnp.arange(1, H + 1, dtype=jnp.int32)
+    used = jnp.max(jnp.where(hot_ids >= 0, slots, 0))
+    first = 0
+    if from_first_used:
+        first = jnp.min(jnp.where(hot_ids >= 0, slots - 1, H))
 
     def slot(h, carry):
         acc, cnt = carry
@@ -612,13 +674,14 @@ def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed):
             cnt = cnt.at[:, :n].add(counted.astype(jnp.int32))
         return acc, cnt
 
-    return jax.lax.fori_loop(0, used, slot, (acc, cnt))
+    return jax.lax.fori_loop(first, used, slot, (acc, cnt))
 
 
 @functools.partial(
     jax.jit, static_argnames=("t_rare", "n_hot", "k", "with_cnt")
 )
-def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, t_rare, n_hot, k, with_cnt):
+def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, wide=None, *,
+                 t_rare, n_hot, k, with_cnt):
     n = inv_norm.shape[0]
     T, H = t_rare, n_hot
     rare_ti = plan[:, :T]
@@ -649,10 +712,9 @@ def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, t_rare, n_hot, k, wi
         cnt = cnt[:, :n]
 
     # ---- hot terms: dense per-doc tf rows, pure vector math ----
-    if dense is not None and dense.shape[0] > 0:
-        acc, cnt = _add_hot_rows(
-            acc, cnt, dense, inv_norm, hot_ids, hot_w, signed=False
-        )
+    acc, cnt = _add_hot_terms(
+        acc, cnt, dense, wide, inv_norm, hot_ids, hot_w, signed=False
+    )
 
     # ---- collection ----
     if with_cnt:
@@ -703,7 +765,7 @@ class MultiFusedScorer:
 
     def __init__(self, fields, parts, live, t_rare=FUSED_T_RARE,
                  n_hot_slots=FUSED_H):
-        # parts: per field dict(doc_ids, tfs, inv_norm, dense, hot_rank)
+        # parts: per field dict(doc_ids, tfs, inv_norm, dense, wide, hot_rank)
         self.fields = tuple(fields)
         self.parts = parts
         self.live = jnp.asarray(live) if live is not None else None
@@ -777,6 +839,7 @@ class MultiFusedScorer:
             live if live is not None else self.live,
             jax.device_put(packed),
             jnp.float32(tie),
+            tuple(p["wide"] for p in self.parts),
             t_rare=self.t_rare,
             n_hot=self.n_hot_slots,
             k=k,
@@ -798,7 +861,7 @@ class MultiFusedScorer:
     jax.jit, static_argnames=("t_rare", "n_hot", "k", "combine")
 )
 def _fused_query_mf(
-    doc_ids_f, tfs_f, inv_norm_f, dense_f, live, plan, tie,
+    doc_ids_f, tfs_f, inv_norm_f, dense_f, live, plan, tie, wide_f=None, *,
     t_rare, n_hot, k, combine,
 ):
     F = len(doc_ids_f)
@@ -842,10 +905,10 @@ def _fused_query_mf(
         )(cnt, tgt, counted)
         acc = acc[:, :n]
         # hot terms: dense rows; |w| scores, w>0 counts
-        if dense is not None and dense.shape[0] > 0:
-            acc, cnt = _add_hot_rows(
-                acc, cnt, dense, inv_norm, hot_ids, hot_w, signed=True
-            )
+        acc, cnt = _add_hot_terms(
+            acc, cnt, dense, None if wide_f is None else wide_f[f],
+            inv_norm, hot_ids, hot_w, signed=True,
+        )
         accs.append(acc)
     cnt = cnt[:, :n]
     if F == 1:

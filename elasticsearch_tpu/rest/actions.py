@@ -589,6 +589,8 @@ class RestActions:
             "jobs": 0, "launches": 0, "rejected": 0, "fused_jobs": 0,
             "pruned_jobs": 0, "fused_overflow_jobs": 0,
             "shed_dead_jobs": 0, "cancelled_jobs": 0,
+            "serve_fallback_jobs": 0, "serve_launches": 0,
+            "serve_rare_tiles": 0, "serve_hot_rows": 0,
         }
         # serving-pipeline roofline counters (QueryBatcher.pipeline_stats):
         # depth/in_flight of the dispatch ring, device-busy and host-stall
@@ -610,6 +612,12 @@ class RestActions:
             "express_lane_hits": 0,
             "warmup_failures": 0,
             "fused_hot_slots": {},
+            "serve_hot_slots": {},
+            # dense hot-term rows over the loaded fields of every shard's
+            # executor (JaxExecutor.dense_rows_stats): gauges
+            "dense_rows_wanted": 0,
+            "dense_rows_held": 0,
+            "dense_tf_overflow_postings": 0,
             "worker_compile_ms": 0.0,
             "worker_compiles": 0,
         }
@@ -650,12 +658,16 @@ class RestActions:
                 batching["occupancy_slots"] += bs["occupancy_slots"]
                 batching["express_lane_hits"] += bs["express_lane_hits"]
                 batching["warmup_failures"] += bs["warmup_failures"]
-                for h, n in bs["fused_hot_slots"].items():
-                    batching["fused_hot_slots"][h] = (
-                        batching["fused_hot_slots"].get(h, 0) + n
-                    )
+                for hist in ("fused_hot_slots", "serve_hot_slots"):
+                    for h, n in bs[hist].items():
+                        batching[hist][h] = batching[hist].get(h, 0) + n
                 batching["worker_compile_ms"] += bs["worker_compile_ms"]
                 batching["worker_compiles"] += bs["worker_compiles"]
+            for _gen, ex in list(getattr(idx, "_executors", {}).values()):
+                rows = getattr(ex, "dense_rows_stats", None)
+                if rows is not None:
+                    for k, v in rows().items():
+                        batching[k] += v
             mex = getattr(idx, "_mesh", None)
             if mex is not None:
                 for k in mesh_stats:
@@ -876,6 +888,13 @@ class RestActions:
                             ],
                             "shed_dead_jobs": batch["shed_dead_jobs"],
                             "cancelled_jobs": batch["cancelled_jobs"],
+                            # the serve family (bool / multi_match)
+                            "serve_fallback_jobs": batch[
+                                "serve_fallback_jobs"
+                            ],
+                            "serve_launches": batch["serve_launches"],
+                            "serve_rare_tiles": batch["serve_rare_tiles"],
+                            "serve_hot_rows": batch["serve_hot_rows"],
                         }
                     },
                     "uptime_in_millis": int(

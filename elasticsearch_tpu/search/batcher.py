@@ -543,7 +543,7 @@ class _Group:
     __slots__ = (
         "family", "jobs", "rows", "express", "cold_s", "t_start",
         "t_dispatched", "t_collect", "d2h0", "launches", "flops",
-        "overflow", "compiles",
+        "overflow", "compiles", "plan_tags",
     )
 
     def __init__(self, family: str, jobs: int, rows: Optional[int],
@@ -558,6 +558,10 @@ class _Group:
         self.launches = 0
         self.flops = 0
         self.overflow = False  # the group left the fused kernel
+        # a serve group's fused plans, for its `dispatch` span: `fields`,
+        # `hot_slots` (most dense rows a job and field used), `rare_tiles`
+        # (most tile slots a job and field used)
+        self.plan_tags: Dict[str, int] = {}
         # program -> [start_ns, end_ns, seconds, built] compiled meanwhile
         self.compiles: Dict[str, list] = {}
         self.t_start = time.perf_counter_ns()
@@ -611,7 +615,7 @@ class _Group:
                 "dispatch", self.t_start, t1, parent_id=up,
                 family=self.family, jobs=self.jobs, rows=self.rows,
                 launches=self.launches, express=self.express,
-                overflow=self.overflow,
+                overflow=self.overflow, **self.plan_tags,
             )
             tr.add_span("inflight", t1, t2, parent_id=up)
             coll = tr.add_span(
@@ -724,6 +728,8 @@ class QueryBatcher:
         # dense hot-term slots each fused match job used, 0..FUSED_H:
         # how much of the kernel's slot budget real questions take
         self._fused_hot_slots = [0] * (scoring.FUSED_H + 1)
+        # the same for serve jobs, one count a field's plan section
+        self._serve_hot_slots = [0] * (scoring.FUSED_H + 1)
         # observability: how many launches / jobs / batched jobs
         self.stats = {
             "launches": 0,
@@ -736,6 +742,15 @@ class QueryBatcher:
             # fallback path would hide a Zipf-tail regression (VERDICT
             # r3 weak #9) — count it
             "fused_overflow_jobs": 0,
+            # the serve family (bool / multi_match): jobs whose segment
+            # ran per job on the unbatched executor (`segment_topk`: a
+            # slot overflow, or a segment under FUSED_MIN_DOCS), fused
+            # launches, and what their plans carried, summed over jobs
+            # and fields (the bytes a launch must move follow from them)
+            "serve_fallback_jobs": 0,
+            "serve_launches": 0,
+            "serve_rare_tiles": 0,
+            "serve_hot_rows": 0,
             # times a kNN group and a text (match/serve) group were in
             # flight on device simultaneously — the observable proof
             # that hybrid legs overlap instead of serializing
@@ -1372,6 +1387,9 @@ class QueryBatcher:
             hot_slots = {
                 str(h): n for h, n in enumerate(self._fused_hot_slots)
             }
+            serve_hot_slots = {
+                str(h): n for h, n in enumerate(self._serve_hot_slots)
+            }
         with self._cold_lock:
             cold_ms = round(self._cold_s * 1000.0, 3)
             compiles = self._compiles
@@ -1385,6 +1403,8 @@ class QueryBatcher:
             "warmup_failures": warm_failed,
             # fused match jobs by dense hot-term slots used (0..FUSED_H)
             "fused_hot_slots": hot_slots,
+            # serve jobs' plan sections (one a field) by the same
+            "serve_hot_slots": serve_hot_slots,
             # the cold clock: compile time on the dispatcher workers,
             # kept out of the admission layer's queue-delay signal
             "worker_compile_ms": cold_ms,
@@ -1836,16 +1856,27 @@ class QueryBatcher:
                     rows=rows,
                 )
                 if record:
+                    secs = [sec for sections, _ in fplans for sec in sections]
+                    rare = [len(sec[0]) for sec in secs]
+                    hot = [len(sec[2]) for sec in secs]
                     with self._lock:
                         self.stats["launches"] += 1
                         self.stats["fused_jobs"] += nj
+                        self.stats["serve_launches"] += 1
+                        self.stats["serve_rare_tiles"] += sum(rare)
+                        self.stats["serve_hot_rows"] += sum(hot)
+                        for h in hot:
+                            self._serve_hot_slots[h] += 1
+                    g = getattr(_worker_tl, "group", None)
+                    if g is not None:
+                        t = g.plan_tags
+                        t["fields"] = len(fields)
+                        t["hot_slots"] = max(t.get("hot_slots", 0), *hot)
+                        t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
                     n_docs = ex.reader.segments[si].num_docs
                     self._add_flops(sum(
-                        scoring.text_plan_flops(
-                            len(sec[0]), len(sec[2]), n_docs
-                        )
-                        for sections, _ in fplans
-                        for sec in sections
+                        scoring.text_plan_flops(r, h, n_docs)
+                        for r, h in zip(rare, hot)
                     ))
                 items.append(("fused", si, fs, pend))
             else:
@@ -1892,6 +1923,7 @@ class QueryBatcher:
                 if record:
                     with self._lock:
                         self.stats["launches"] += 1
+                        self.stats["serve_fallback_jobs"] += 1
                 self._collect(
                     [j], [per_job_cands[ji]], totals[ji: ji + 1],
                     si, s1[None, :], d1[None, :], np.array([t1]),

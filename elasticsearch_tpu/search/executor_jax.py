@@ -48,8 +48,13 @@ from .executor import Hit, NumpyExecutor, ShardReader, TopDocs, _coerce_numeric
 # segments below this size score through the shared-shape chunked path;
 # above it the per-segment fused program + dense hot rows pay off
 FUSED_MIN_DOCS = 100_000
-# HBM budget for dense hot-term tf rows, bytes (uint8 per doc per term)
-DENSE_ROWS_HBM_BUDGET = 512 * 1024 * 1024
+# HBM budget for one field's dense hot-term tf rows, bytes (uint8 per doc
+# per term, uint16 for a term whose tf passes 255). 1M short passages
+# want 500 rows (0.5 GB); 401,729 whole documents want 9,510 (3.8 GB):
+# under 512 MiB (1,336 rows) 2.5% of six-word questions pass the plan's
+# FUSED_T_RARE tiles with the terms left sparse, under 1 GiB (2,672 rows)
+# 0.02% (PERF.md section 6, PR 27)
+DENSE_ROWS_HBM_BUDGET = 1024 * 1024 * 1024
 
 
 class DevicePostings:
@@ -908,11 +913,15 @@ class JaxExecutor:
         idx, w, v = scoring.pad_tiles(
             np.asarray(tile_idx, np.int32), np.asarray(tile_w, np.float32)
         )
-        rows_doc = dp.doc_ids[jnp.asarray(idx)]
-        rows_tf = dp.tfs[jnp.asarray(idx)]
+        # uploads noted (`transfer.scoring`): the batcher's serve family
+        # falls back here per job (`segment_topk`)
+        idx = scoring._to_device(idx)
+        rows_doc = dp.doc_ids[idx]
+        rows_tf = dp.tfs[idx]
         inv_norm = self._inv_norm(si, field, n)
         scores, cnt = scoring.score_tiles(
-            rows_doc, rows_tf, jnp.asarray(w), jnp.asarray(v), inv_norm, n
+            rows_doc, rows_tf, scoring._to_device(w), scoring._to_device(v),
+            inv_norm, n,
         )
         return scores, cnt
 
@@ -1056,6 +1065,19 @@ class JaxExecutor:
             self._fused_parts[key] = parts
             return parts
 
+    def dense_rows_stats(self) -> Dict[str, int]:
+        """Over the fields whose fused parts are loaded: terms that want
+        a dense row, terms that hold one, and the postings whose tf the
+        uint16 rows carry past DENSE_TF_MAX (`_fused_parts_build`)."""
+        parts = [p for p in list(self._fused_parts.values()) if p is not None]
+        return {
+            "dense_rows_wanted": sum(p["rows_wanted"] for p in parts),
+            "dense_rows_held": sum(p["rows_held"] for p in parts),
+            "dense_tf_overflow_postings": sum(
+                p["tf_overflow_postings"] for p in parts
+            ),
+        }
+
     def fused_scorer_mf(self, si: int, fields: tuple):
         """Cached MultiFusedScorer over one segment and a field tuple
         (the multi_match / bool serving engine); None when any field
@@ -1088,75 +1110,103 @@ class JaxExecutor:
                 parts["inv_norm"],
                 self.reader.live_docs[si],
                 parts["dense"],
+                wide_rows=parts["wide"],
             )
             fs.hot_rank = parts["hot_rank"]
         self._fused_scorers[key] = fs
         return fs
 
     def _fused_parts_build(self, si: int, field: str):
+        """The field's device arrays for fused scoring, and its choice of
+        hot terms. A term WANTS a dense per-doc tf row when its df is at
+        least max(1024, n / 128): left sparse it costs a plan at most
+        n / 16384 of its FUSED_T_RARE tile slots (25 at 401,729 docs, 61
+        at 1M). Rows are HELD by df rank, most frequent first, while the
+        budget lasts (DENSE_ROWS_HBM_BUDGET a field, and the ledger's
+        free HBM): under any budget that leaves sparse the wanted terms
+        of fewest tiles, so every question's plan carries the fewest
+        rare tiles that budget allows. A row is uint8, or uint16 (two
+        rows of the budget) where the term's tf passes DENSE_TF_MAX in
+        some document; only a tf past WIDE_TF_MAX keeps a term sparse.
+        The questions that still pass a slot budget are counted
+        (`fused_overflow_jobs`, `serve_fallback_jobs`)."""
         seg = self.reader.segments[si]
         pf = seg.postings.get(field)
-        if pf is not None and seg.num_docs >= FUSED_MIN_DOCS:
-            n = seg.num_docs
-            dp = self.device_segments[si].postings[field]
-            n_terms = len(pf.terms)
-            # per-term max tf (dense rows are uint8: terms with a larger
-            # tf anywhere stay sparse for exactness)
-            counts = pf.term_tile_count.astype(np.int64)
-            starts = pf.term_tile_start.astype(np.int64)
-            tile_of = (
-                np.arange(int(counts.sum()), dtype=np.int64)
-                - np.repeat(np.cumsum(counts) - counts, counts)
-                + np.repeat(starts, counts)
-            )
-            term_of_tile = np.repeat(np.arange(n_terms, dtype=np.int64), counts)
-            term_max_tf = np.zeros(n_terms, np.int64)
-            np.maximum.at(term_max_tf, term_of_tile, pf.tile_max_tf[tile_of])
-            hot_mask = (pf.term_df.astype(np.int64) >= max(1024, n // 128)) & (
-                term_max_tf <= scoring.DENSE_TF_MAX
-            )
-            hot_ids = np.nonzero(hot_mask)[0]
-            # HBM budget for dense rows (uint8 per doc per hot term):
-            # the static per-field cap AND the live global ledger — when
-            # HBM is tight the fused path degrades to sparse tiles (an
-            # optimization lost, not correctness) and counts it
-            from ..common.memory import hbm_ledger
+        if pf is None or seg.num_docs < FUSED_MIN_DOCS:
+            return None
+        n = seg.num_docs
+        dp = self.device_segments[si].postings[field]
+        n_terms = len(pf.terms)
+        counts = pf.term_tile_count.astype(np.int64)
+        starts = pf.term_tile_start.astype(np.int64)
+        tile_of = (
+            np.arange(int(counts.sum()), dtype=np.int64)
+            - np.repeat(np.cumsum(counts) - counts, counts)
+            + np.repeat(starts, counts)
+        )
+        term_of_tile = np.repeat(np.arange(n_terms, dtype=np.int64), counts)
+        term_max_tf = np.zeros(n_terms, np.int64)
+        np.maximum.at(term_max_tf, term_of_tile, pf.tile_max_tf[tile_of])
+        df = pf.term_df.astype(np.int64)
+        wanted = np.nonzero(
+            (df >= max(1024, n // 128)) & (term_max_tf <= scoring.WIDE_TF_MAX)
+        )[0]
+        from ..common.memory import hbm_ledger
 
-            max_hot = max(0, DENSE_ROWS_HBM_BUDGET // max(n, 1))
-            headroom = max(0, hbm_ledger.budget - hbm_ledger.used)
-            max_hot = min(max_hot, headroom // max(n + 1, 1))
-            if len(hot_ids) > max_hot:
-                order = np.argsort(-pf.term_df[hot_ids])
-                hot_ids = np.sort(hot_ids[order[:max_hot]])
-                hbm_ledger.note_degraded()
-            if len(hot_ids):
-                sel = np.isin(term_of_tile, hot_ids)
-                hot_tiles = tile_of[sel]
-                rank_map = {int(t): r for r, t in enumerate(hot_ids)}
-                rank_of_tile = np.array(
-                    [rank_map[int(t)] for t in term_of_tile[sel]], np.int32
+        # HBM budget for dense rows: the static per-field cap AND the
+        # live global ledger — when HBM is tight the fused path degrades
+        # to sparse tiles (an optimization lost, not correctness) and
+        # counts it
+        headroom = max(0, hbm_ledger.budget - hbm_ledger.used)
+        max_rows = min(DENSE_ROWS_HBM_BUDGET // n, headroom // (n + 1))
+        by_df = wanted[np.argsort(-df[wanted], kind="stable")]
+        is_wide = term_max_tf[by_df] > scoring.DENSE_TF_MAX
+        held = by_df[np.cumsum(1 + is_wide) <= max_rows]
+        if len(held) < len(wanted):
+            hbm_ledger.note_degraded()
+        planes = []
+        hot_rank: Dict[int, int] = {}
+        tf_overflow_postings = 0
+        for wide in (False, True):
+            ids = np.sort(held[(term_max_tf[held] > scoring.DENSE_TF_MAX) == wide])
+            if not len(ids):
+                planes.append(None)
+                continue
+            sel = np.isin(term_of_tile, ids)
+            if wide:
+                tf_overflow_postings = int(
+                    (pf.tfs[tile_of[sel]] > scoring.DENSE_TF_MAX).sum()
                 )
-                dense = scoring.build_dense_rows(
-                    dp.doc_ids,
-                    dp.tfs,
-                    jnp.asarray(hot_tiles.astype(np.int32)),
-                    jnp.asarray(rank_of_tile),
-                    n_hot=len(hot_ids),
-                    n_docs=n,
-                )
-                self._charge("dense_rows", _tree_nbytes(dense), False)
-                hot_rank = rank_map
-            else:
-                dense = None
-                hot_rank = {}
-            return {
-                "doc_ids": dp.doc_ids,
-                "tfs": dp.tfs,
-                "inv_norm": self._inv_norm(si, field, n),
-                "dense": dense,
-                "hot_rank": hot_rank,
-            }
-        return None
+            plane = scoring.build_dense_rows(
+                dp.doc_ids,
+                dp.tfs,
+                jnp.asarray(tile_of[sel].astype(np.int32)),
+                jnp.asarray(
+                    np.searchsorted(ids, term_of_tile[sel]).astype(np.int32)
+                ),
+                n_hot=len(ids),
+                n_docs=n,
+                dtype=jnp.uint16 if wide else jnp.uint8,
+            )
+            self._charge("dense_rows", _tree_nbytes(plane), False)
+            base = len(hot_rank)  # uint16 rows number on from the uint8 ones
+            hot_rank.update((int(t), base + r) for r, t in enumerate(ids))
+            planes.append(plane)
+        dense, wide_rows = planes
+        return {
+            "doc_ids": dp.doc_ids,
+            "tfs": dp.tfs,
+            "inv_norm": self._inv_norm(si, field, n),
+            "dense": dense,
+            "wide": wide_rows,
+            "hot_rank": hot_rank,
+            # for `_nodes/stats` (pipeline.batching.dense_rows_*): terms
+            # that want a row, terms that hold one, and the postings
+            # whose tf the uint16 rows carry past DENSE_TF_MAX
+            "rows_wanted": int(len(wanted)),
+            "rows_held": int(len(held)),
+            "tf_overflow_postings": tf_overflow_postings,
+        }
 
     def fused_plan_field(
         self, si: int, field: str, parts, terms_flagged, boost: float
@@ -1204,6 +1254,8 @@ class JaxExecutor:
                 rw.extend([w] * c)
         if len(rt) > scoring.FUSED_T_RARE or len(hr) > scoring.FUSED_H:
             return None
+        if parts["wide"] is not None:
+            scoring.wide_rows_first(hr, hw, parts["dense"])
         return (
             np.asarray(rt, np.int64),
             np.asarray(rw, np.float32),
@@ -1236,6 +1288,8 @@ class JaxExecutor:
                 rw.extend([w] * c)
         if len(rt) > fs.t_rare or len(hr) > fs.n_hot_slots:
             return None
+        if fs.wide is not None:
+            scoring.wide_rows_first(hr, hw, fs.dense)
         return (
             np.asarray(rt, np.int64),
             np.asarray(rw, np.float32),
@@ -1544,8 +1598,8 @@ class JaxExecutor:
         if live is not None:
             mask = mask & jnp.asarray(live)
         s, d = scoring.topk_hits(scores, mask, min(k, n))
-        total = int(np.asarray(mask.sum()))
-        return np.asarray(s), np.asarray(d), total
+        total = int(scoring._to_host(mask.sum()))
+        return scoring._to_host(s), scoring._to_host(d), total
 
     def _exec_match(self, q: MatchQuery, si: int) -> Tuple[jax.Array, jax.Array]:
         seg = self.reader.segments[si]

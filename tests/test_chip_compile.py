@@ -165,6 +165,31 @@ def test_fused_multi_field_program(one_chip):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("rows", [1, 32])
+def test_fused_multi_field_program_at_document_length(one_chip, rows):
+    """The same program over the one-chip share of MS MARCO document the
+    benchmark builds (401,729 docs; `zipf_title_body`'s tile counts; a
+    1 GiB row budget a field), where nine body terms' tf passes 255 and
+    hold uint16 rows beside the uint8 plane."""
+    n, tiles, hot = 401_729, (636_122, 4_406_437), (66, 2_654)
+    s = _on(one_chip)
+    compiled = scoring._fused_query_mf.lower(
+        tuple(s((t, TILE), jnp.int32) for t in tiles),
+        tuple(s((t, TILE), jnp.int32) for t in tiles),
+        tuple(s((n,), jnp.float32) for _ in tiles),
+        tuple(s((h, n), jnp.uint8) for h in hot),
+        None,
+        s((rows, _plan_width(2)), jnp.int32),
+        s((), jnp.float32),
+        (None, s((9, n), jnp.uint16)),
+        t_rare=scoring.FUSED_T_RARE,
+        n_hot=scoring.FUSED_H,
+        k=16,
+        combine="max_tie",
+    ).compile()
+    _fits(compiled)
+
+
 def test_cross_segment_merges(one_chip):
     """merge_segment_topk / knn_merge_segment_topk's kernels over four
     segments' device-resident candidate buffers."""
