@@ -44,7 +44,9 @@ the serving process; tags in brackets):
             most dense rows / tile slots a job and field used]
             -> the group's last kernel is enqueued
         inflight  -> the worker comes back to collect the group
-        collect [d2h_bytes]  merge kernel, blocking download, hits
+        collect [d2h_bytes; a text or sparse group also merged: false
+            when it downloaded the fused kernel's packed row as it was,
+            true when the merge program ran]  blocking download, hits
         compile [program, seconds]  child of the dispatch (or collect)
             span the worker compiled in; one per program
     shard_search > fetch   (sources, highlight: the folded fetch phase)

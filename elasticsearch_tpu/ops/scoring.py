@@ -462,6 +462,10 @@ class FusedScorer:
 
     Result packing (int32[B, 2k + 1]):
       [0:k) float32 scores bitcast · [k:2k) doc ids · [2k] total
+    A row is in its final order (score desc, doc asc, -inf pads last),
+    so a shard with one scoring segment downloads it as it is
+    (`packed_segment_topk`); with more, `_merge_segments` unpacks it
+    inside its own trace. Nothing unpacks it eagerly on the device.
     """
 
     def __init__(
@@ -566,16 +570,6 @@ class FusedScorer:
         docs = out[:, k : 2 * k]
         totals = out[:, 2 * k].astype(np.int64)
         return scores, docs, totals
-
-    @staticmethod
-    def device_result(pending):
-        """Unpacks a pending launch WITHOUT leaving the device: returns
-        (scores f32[B,k], docs i32[B,k], totals i32[B]) as device arrays
-        for the cross-segment merge kernel (merge_segment_topk) — no
-        host transfer happens here."""
-        out, k = pending
-        scores = jax.lax.bitcast_convert_type(out[:, :k], jnp.float32)
-        return scores, out[:, k : 2 * k], out[:, 2 * k]
 
     def search(self, plans, k: int, with_cnt: bool, live=None, rows=None):
         """One device round trip for up to BPAD jobs. Returns
@@ -838,7 +832,7 @@ class MultiFusedScorer:
             tuple(p["dense"] for p in self.parts),
             live if live is not None else self.live,
             jax.device_put(packed),
-            jnp.float32(tie),
+            np.float32(tie),  # the jitted call uploads it: no eager convert
             tuple(p["wide"] for p in self.parts),
             t_rare=self.t_rare,
             n_hot=self.n_hot_slots,
@@ -848,7 +842,6 @@ class MultiFusedScorer:
         return out, k
 
     decode_result = staticmethod(FusedScorer.decode_result)
-    device_result = staticmethod(FusedScorer.device_result)
 
     def search(self, plans, k: int, combine: str, tie: float, live=None,
                rows=None):
@@ -938,14 +931,23 @@ def _fused_query_mf(
 
 
 # ---------------------------------------------------------------------------
-# Device-side cross-segment top-k merge — the round-6 zero-sync collect.
+# Cross-segment top-k merge: one blocking download a group.
 #
-# Before this, every segment's (scores, docs, totals) came back to the
-# host separately (one device→host sync per segment) and merged in
-# Python. Here the per-segment candidate buffers STAY on device and one
-# padded top-k kernel selects the group-wide winners, so a whole batch
-# group costs exactly ONE packed download regardless of segment count —
-# the GPUSparse lesson (keep scoring AND merging accelerator-resident).
+# Each scoring segment leaves its candidates on the device, as the fused
+# kernel's packed row (i32[B, 2k+1], see FusedScorer) or as the chunked /
+# sparse paths' (scores, docs, totals) triple. What the group then costs
+# follows from how many there are:
+#
+#   one fused launch   its packed row IS the merged answer (a top-k of k
+#                      sorted candidates returns them; totals are the one
+#                      segment's): `packed_segment_topk` downloads it as
+#                      the kernel wrote it. No merge program, no upload.
+#   anything else      `_merge_segments`, ONE program: it unpacks the
+#                      packed rows inside its trace (no eager slicing),
+#                      concatenates the candidates, selects the group-wide
+#                      winners and packs one result. The segment of each
+#                      slot is a constant of the trace (static ids and
+#                      widths), not an upload. S segments: S + 1 programs.
 #
 # Ordering parity with the host merge (score desc, (segment, doc) asc):
 # slots are concatenated (segment asc, per-segment rank asc) and
@@ -955,10 +957,32 @@ def _fused_query_mf(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _merge_segments(s_list, d_list, t_list, seg_of_slot, k):
+def _part_width(part) -> int:
+    """Candidates a row of one segment's part holds."""
+    if isinstance(part, tuple):
+        return int(part[0].shape[1])
+    return (int(part.shape[1]) - 1) // 2
+
+
+def _unpack_part(part):
+    """(scores f32[B,k], docs i32[B,k], totals i32[B]) of one segment's
+    candidates, traced: a triple as it is, a fused launch's packed row
+    sliced and bitcast."""
+    if isinstance(part, tuple):
+        return part
+    k = _part_width(part)
+    scores = jax.lax.bitcast_convert_type(part[:, :k], jnp.float32)
+    return scores, part[:, k : 2 * k], part[:, 2 * k]
+
+
+@functools.partial(jax.jit, static_argnames=("segs", "k"))
+def _merge_segments(parts, segs, k):
+    s_list, d_list, t_list = zip(*(_unpack_part(p) for p in parts))
     scores = jnp.concatenate(s_list, axis=1)  # [B, total_slots]
     docs = jnp.concatenate(d_list, axis=1)
+    seg_of_slot = jnp.asarray(np.repeat(
+        np.asarray(segs, np.int32), [s.shape[1] for s in s_list]
+    ))
     s, idx = jax.lax.top_k(scores, k)
     seg = seg_of_slot[idx]
     doc = jnp.take_along_axis(docs, idx, axis=1)
@@ -969,26 +993,35 @@ def _merge_segments(s_list, d_list, t_list, seg_of_slot, k):
     )
 
 
-def merge_segment_topk(items, k: int):
-    """items: [(si, scores f32[B,ki], docs i32[B,ki], totals i32[B])]
-    device triples in ascending segment order. Returns host arrays
-    (scores f32[B,k], segments i32[B,k], docs i32[B,k], totals
-    i64[B, n_segments]) via ONE top-k kernel and ONE device→host
-    transfer. Rows are ordered score desc / (segment, doc) asc; -inf
-    entries pad past the real candidates."""
-    widths = [int(s.shape[1]) for _, s, _, _ in items]
-    k = min(k, sum(widths))
-    seg_of_slot = _to_device(
-        np.repeat(
-            np.asarray([si for si, *_ in items], np.int32), widths
-        )
+def is_packed(part) -> bool:
+    """A segment's candidates as the fused kernel packed them (one
+    array), not a (scores, docs, totals) triple."""
+    return not isinstance(part, tuple)
+
+
+def packed_segment_topk(si: int, packed):
+    """`merge_segment_topk` of one fused launch, with no program: the
+    one blocking download of the kernel's packed i32[B, 2k+1], decoded
+    on the host. Same return, same floats, ids, order and totals."""
+    scores, docs, totals = FusedScorer.decode_result(
+        (packed, _part_width(packed))
     )
+    return scores, np.full_like(docs, si), docs, totals[:, None]
+
+
+def merge_segment_topk(items, k: int):
+    """items: [(si, part)] in ascending segment order, `part` a fused
+    launch's packed output i32[B, 2ki+1] or a device triple (scores
+    f32[B,ki], docs i32[B,ki], totals i32[B]). Returns host arrays
+    (scores f32[B,k], segments i32[B,k], docs i32[B,k], totals
+    i64[B, n_segments]) via ONE program and ONE device→host transfer.
+    Rows are ordered score desc / (segment, doc) asc; -inf entries pad
+    past the real candidates."""
+    k = min(k, sum(_part_width(p) for _, p in items))
     out = _to_host(
         _merge_segments(
-            tuple(s for _, s, _, _ in items),
-            tuple(d for _, _, d, _ in items),
-            tuple(t for _, _, _, t in items),
-            seg_of_slot,
+            tuple(p for _, p in items),
+            segs=tuple(int(si) for si, _ in items),
             k=k,
         )
     )

@@ -610,6 +610,9 @@ class RestActions:
             "occupancy_jobs": 0,
             "occupancy_slots": 0,
             "express_lane_hits": 0,
+            # groups whose result was downloaded as the fused kernel
+            # packed it (one scoring segment: no merge program)
+            "direct_collect_groups": 0,
             "warmup_failures": 0,
             "fused_hot_slots": {},
             "serve_hot_slots": {},
@@ -657,6 +660,9 @@ class RestActions:
                 batching["occupancy_jobs"] += bs["occupancy_jobs"]
                 batching["occupancy_slots"] += bs["occupancy_slots"]
                 batching["express_lane_hits"] += bs["express_lane_hits"]
+                batching["direct_collect_groups"] += bs[
+                    "direct_collect_groups"
+                ]
                 batching["warmup_failures"] += bs["warmup_failures"]
                 for hist in ("fused_hot_slots", "serve_hot_slots"):
                     for h, n in bs[hist].items():

@@ -543,7 +543,7 @@ class _Group:
     __slots__ = (
         "family", "jobs", "rows", "express", "cold_s", "t_start",
         "t_dispatched", "t_collect", "d2h0", "launches", "flops",
-        "overflow", "compiles", "plan_tags",
+        "overflow", "compiles", "plan_tags", "merged",
     )
 
     def __init__(self, family: str, jobs: int, rows: Optional[int],
@@ -562,6 +562,9 @@ class _Group:
         # `hot_slots` (most dense rows a job and field used), `rare_tiles`
         # (most tile slots a job and field used)
         self.plan_tags: Dict[str, int] = {}
+        # a text or sparse group's `collect` span: whether its candidates
+        # went through the merge program (`_group_topk`), else None
+        self.merged: Optional[bool] = None
         # program -> [start_ns, end_ns, seconds, built] compiled meanwhile
         self.compiles: Dict[str, list] = {}
         self.t_start = time.perf_counter_ns()
@@ -621,6 +624,7 @@ class _Group:
             coll = tr.add_span(
                 "collect", t2, t_done, parent_id=up,
                 d2h_bytes=thread_d2h_bytes() - self.d2h0,
+                **({} if self.merged is None else {"merged": self.merged}),
             )
             for program, (c0, c1, secs, built) in self.compiles.items():
                 if built:
@@ -733,6 +737,9 @@ class QueryBatcher:
         # observability: how many launches / jobs / batched jobs
         self.stats = {
             "launches": 0,
+            # groups whose result was downloaded as the fused kernel
+            # packed it: one scoring segment, no merge program
+            "direct_collect_groups": 0,
             "jobs": 0,
             "max_batch_seen": 0,
             "pruned_jobs": 0,
@@ -1383,6 +1390,7 @@ class QueryBatcher:
             }
             jobs, slots = self._occ_jobs, self._occ_slots
             express = self.stats["express_lane_hits"]
+            direct = self.stats["direct_collect_groups"]
             warm_failed = self.stats["warmup_failures"]
             hot_slots = {
                 str(h): n for h, n in enumerate(self._fused_hot_slots)
@@ -1400,6 +1408,7 @@ class QueryBatcher:
             "occupancy_slots": slots,
             "avg_occupancy": round(jobs / slots, 4) if slots else 0.0,
             "express_lane_hits": express,
+            "direct_collect_groups": direct,
             "warmup_failures": warm_failed,
             # fused match jobs by dense hot-term slots used (0..FUSED_H)
             "fused_hot_slots": hot_slots,
@@ -1616,9 +1625,9 @@ class QueryBatcher:
                 ok = max_df - ex.deleted_count >= j.plan.tth_cap
             prune.append(ok)
         with_cnt = any(j.plan.msm > 1 for j in jobs)
-        # per-segment candidate buffers STAY on device; one merge kernel
-        # + one packed download replaces the per-segment host syncs
-        dev_items: List[Tuple] = []  # (si, s_dev, d_dev, tot_dev)
+        # per-segment candidates STAY on device, a fused launch's as the
+        # kernel packed them: the collect is one download (`_group_topk`)
+        dev_items: List[Tuple] = []  # (si, packed | (s, d, tot))
         pruned_flags = [False] * nj
         empty_i = np.empty(0, np.int64)
         empty_w = np.empty(0, np.float32)
@@ -1649,7 +1658,7 @@ class QueryBatcher:
                             )
                             for p in fplans
                         ))
-                    dev_items.append((si, *fs.device_result(pend)))
+                    dev_items.append((si, pend[0]))
                     continue
                 if record:
                     self._count_overflow(fplans)
@@ -1734,25 +1743,20 @@ class QueryBatcher:
                     ))
             msm = np.ones(rows, np.int32)
             msm[:nj] = [j.plan.msm for j in jobs]
-            dev_items.append(
-                (si, *cs.finalize_device(acc, cnt, msm, kb))
-            )
+            dev_items.append((si, cs.finalize_device(acc, cnt, msm, kb)))
         return dev_items, pruned_flags
 
     def _collect_match_group(self, jobs: List[_Job], kb: int, pend: Tuple,
                              record: bool):
-        """Device-side cross-segment merge: ONE top-k kernel + ONE packed
-        download per group (score desc, (segment, doc) asc — identical
-        ordering to the old host sort, selection only → float-exact),
-        then each job's hits."""
+        """The group's ONE blocking download (`_group_topk`: the fused
+        kernel's packed row as it is when it is the only item, else the
+        cross-segment merge program's; score desc, (segment, doc) asc,
+        selection only → float-exact), then each job's hits."""
         dev_items, pruned_flags = pend
         reader = jobs[0].executor.reader
         nj = len(jobs)
         if dev_items:
-            t0 = time.perf_counter()
-            ms, mseg, mdoc, mtot = scoring.merge_segment_topk(dev_items, kb)
-            if record:
-                self._add_stall(time.perf_counter() - t0)
+            ms, mseg, mdoc, mtot = self._group_topk(dev_items, kb, record)
         else:
             ms = np.full((nj, 0), -np.inf, np.float32)
             mseg = mdoc = np.zeros((nj, 0), np.int32)
@@ -1797,6 +1801,30 @@ class QueryBatcher:
                 relation=relation,
             )
             j.finish()
+
+    def _group_topk(self, items: List[Tuple], kb: int, record: bool):
+        """A text or sparse group's device candidates, [(si, part)]
+        (segment asc), merged on the host after ONE blocking download:
+        (scores, segments, docs, totals[B, len(items)]) as
+        `scoring.merge_segment_topk` returns them. What it launches
+        follows from what the group holds: a lone fused launch's packed
+        row is the answer already and is downloaded as the kernel wrote
+        it (no program, no upload); anything else goes through the one
+        merge program, which unpacks packed rows in its own trace."""
+        t0 = time.perf_counter()
+        direct = len(items) == 1 and scoring.is_packed(items[0][1])
+        if direct:
+            out = scoring.packed_segment_topk(*items[0])
+        else:
+            out = scoring.merge_segment_topk(items, kb)
+        g = getattr(_worker_tl, "group", None)
+        if g is not None:
+            g.merged = not direct
+        if record:
+            with self._lock:
+                self._host_stall_s += time.perf_counter() - t0
+                self.stats["direct_collect_groups"] += direct
+        return out
 
     # ---- dispatch/collect pairs (device work launches in dispatch;
     # only collect blocks on host transfers) ----
@@ -1878,36 +1906,30 @@ class QueryBatcher:
                         scoring.text_plan_flops(r, h, n_docs)
                         for r, h in zip(rare, hot)
                     ))
-                items.append(("fused", si, fs, pend))
+                items.append(("fused", si, pend[0]))
             else:
                 if record and fs is not None and fplans is not None:
                     self._count_overflow(fplans)
-                items.append(("fallback", si, None, None))
+                items.append(("fallback", si, None))
         return items
 
     def _collect_serve_group(self, jobs: List[_Job], kb: int, items,
                              record: bool = True):
-        """Host side of the serve group: one device-side merge + packed
-        download covers every fused segment; fallback segments (below
-        FUSED_MIN_DOCS / slot overflow) run per job on the host and join
-        the final merge. Totals are exact (the fused program scores
-        exactly — no pruning on this path)."""
+        """Host side of the serve group: one blocking download covers
+        every fused segment (`_group_topk`: one segment's packed row as
+        the kernel wrote it, several through the merge program);
+        fallback segments (below FUSED_MIN_DOCS / slot overflow) run per
+        job on the host and join the final merge. Totals are exact (the
+        fused program scores exactly — no pruning on this path)."""
         ex = jobs[0].executor
         reader = ex.reader
         per_job_cands: List[List[Tuple[float, int, int]]] = [[] for _ in jobs]
         totals = np.zeros(len(jobs), np.int64)
         fused_items = [
-            (si, *fs.device_result(pend))
-            for tag, si, fs, pend in items
-            if tag == "fused"
+            (si, packed) for tag, si, packed in items if tag == "fused"
         ]
         if fused_items:
-            t0 = time.perf_counter()
-            ms, mseg, mdoc, mtot = scoring.merge_segment_topk(
-                fused_items, kb
-            )
-            if record:
-                self._add_stall(time.perf_counter() - t0)
+            ms, mseg, mdoc, mtot = self._group_topk(fused_items, kb, record)
             for ji in range(len(jobs)):
                 finite = np.isfinite(ms[ji])
                 for s, si, d in zip(
@@ -1915,7 +1937,7 @@ class QueryBatcher:
                 ):
                     per_job_cands[ji].append((float(s), int(si), int(d)))
                 totals[ji] += int(mtot[ji].sum())
-        for tag, si, fs, pend in items:
+        for tag, si, _packed in items:
             if tag != "fallback":
                 continue
             for ji, j in enumerate(jobs):
@@ -2344,9 +2366,9 @@ class QueryBatcher:
 
     def _collect_sparse_group(self, jobs: List[_Job], kb: int, items,
                               record: bool = True):
-        """Host side of the sparse group: one device-side merge + one
-        packed download covers every device segment; fallback segments
-        (fault / degrade) run per job through the executor's generic
+        """Host side of the sparse group: one merge program + one packed
+        download (`_group_topk`) covers every device segment; fallback
+        segments (fault / degrade) run per job through the executor's generic
         per-segment top-k — which routes SparseVectorQuery to the host
         dense oracle — and join the final merge. Hits are exact either
         way; totals turn relation "gte" when block-max pruning dropped
@@ -2364,15 +2386,10 @@ class QueryBatcher:
             if tag != "dev":
                 continue
             pend, pruned_flags = payload
-            dev_items.append((si, *pend))
+            dev_items.append((si, pend))
             pruned_any |= pruned_flags
         if dev_items:
-            t0 = time.perf_counter()
-            ms, mseg, mdoc, mtot = scoring.merge_segment_topk(
-                dev_items, kb
-            )
-            if record:
-                self._add_stall(time.perf_counter() - t0)
+            ms, mseg, mdoc, mtot = self._group_topk(dev_items, kb, record)
             for ji in range(len(jobs)):
                 finite = np.isfinite(ms[ji])
                 for s, si, d in zip(

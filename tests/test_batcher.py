@@ -155,12 +155,9 @@ class TestConcurrentCoalescing:
         service.search({"query": {"match": {"body": "alpha"}}, "size": 5})
         batcher = service._batcher
         assert batcher is not None
-        base_jobs = batcher.stats["jobs"]
-
-        results = {}
         errs = []
 
-        def one(i):
+        def one(results, i):
             try:
                 results[i] = service.search(
                     {"query": {"match": {"body": WORDS[i % 8]}}, "size": 5}
@@ -168,15 +165,26 @@ class TestConcurrentCoalescing:
             except Exception as e:  # pragma: no cover
                 errs.append(e)
 
-        threads = [threading.Thread(target=one, args=(i,)) for i in range(24)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errs
-        assert len(results) == 24
-        assert batcher.stats["jobs"] - base_jobs == 24
-        # at least one launch must have carried more than one job
+        # whether two of a burst's jobs meet in the queue is the
+        # scheduler's to say (on a loaded machine the threads start one
+        # by one and six workers take a job each): a few bursts, until
+        # one launch has carried more than one job
+        for _ in range(8):
+            base_jobs = batcher.stats["jobs"]
+            results = {}
+            threads = [
+                threading.Thread(target=one, args=(results, i))
+                for i in range(24)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            assert not errs
+            assert len(results) == 24
+            assert batcher.stats["jobs"] - base_jobs == 24
+            if batcher.stats["max_batch_seen"] > 1:
+                break
         assert batcher.stats["max_batch_seen"] > 1
 
 

@@ -192,15 +192,16 @@ def test_fused_multi_field_program_at_document_length(one_chip, rows):
 
 def test_cross_segment_merges(one_chip):
     """merge_segment_topk / knn_merge_segment_topk's kernels over four
-    segments' device-resident candidate buffers."""
+    segments' device-resident candidate buffers: for text two fused
+    launches' packed rows, unpacked inside the merge's own trace, beside
+    two chunked triples."""
     s = _on(one_chip)
     rows, segs, kt, kk = 32, 4, 16, 128
+    packed = s((rows, 2 * kt + 1), jnp.int32)
+    triple = (s((rows, kt), jnp.float32), s((rows, kt), jnp.int32),
+              s((rows,), jnp.int32))
     text = scoring._merge_segments.lower(
-        tuple(s((rows, kt), jnp.float32) for _ in range(segs)),
-        tuple(s((rows, kt), jnp.int32) for _ in range(segs)),
-        tuple(s((rows,), jnp.int32) for _ in range(segs)),
-        s((segs * kt,), jnp.int32),
-        k=kt,
+        (packed, triple, packed, triple), segs=tuple(range(segs)), k=kt,
     ).compile()
     _fits(text)
     knn = scoring._knn_merge_segments.lower(

@@ -771,7 +771,7 @@ class TestBatchingStats:
             "buckets", "launches_by_bucket", "occupancy_jobs",
             "occupancy_slots", "avg_occupancy", "express_lane_hits",
             "warmup_failures", "worker_compile_ms", "worker_compiles",
-            "fused_hot_slots", "serve_hot_slots",
+            "fused_hot_slots", "serve_hot_slots", "direct_collect_groups",
         }
         assert bs["warmup_failures"] == 0
         assert bs["worker_compile_ms"] > 0.0  # this batcher compiled

@@ -364,20 +364,18 @@ class TestBatcherJobSpans:
 
     def test_compile_span_on_first_use_of_a_shape_only(self, fused_service):
         # a page size no other test of this module asks for: its top-k
-        # bucket (64) makes a new program of the fused kernel (the merge
-        # kernel's shapes do not depend on the index, so another test
-        # file of this process may have built that one already)
+        # bucket (64) makes a new program of the fused kernel, the only
+        # program of a fused request on a one-segment shard (its packed
+        # row is downloaded as it is: no merge program to build)
         body = {**MATCH, "size": 40}
         spans, by = traced_search(fused_service, body)
         compiles = [s for s in spans if s["name"] == "compile"]
-        assert "_fused_query" in {s["tags"]["program"] for s in compiles}
+        assert {s["tags"]["program"] for s in compiles} == {"_fused_query"}
         for s in compiles:
             assert s["tags"]["seconds"] > 0
-            parent = "collect" if (
-                s["tags"]["program"] == "_merge_segments") else "dispatch"
-            assert s["parent_id"] == by[parent]["id"]
-            assert by[parent]["start_ns"] <= s["start_ns"]
-            assert end_ns(s) <= end_ns(by[parent])
+            assert s["parent_id"] == by["dispatch"]["id"]
+            assert by["dispatch"]["start_ns"] <= s["start_ns"]
+            assert end_ns(s) <= end_ns(by["dispatch"])
         stats = fused_service._batcher.batching_stats()
         assert stats["worker_compiles"] >= len(compiles)
         assert stats["worker_compile_ms"] > 0
@@ -436,12 +434,14 @@ class TestTransferCounters:
         }
 
     def test_one_fused_match_request(self, fused_service):
-        # up: the packed plan i32[1, 2 * 256 + 2 * FUSED_H + 1] and the
-        # merge's i32[16]; down: one packed i32[1, 3 * 16 + 1]
+        # up: the packed plan i32[1, 2 * 256 + 2 * FUSED_H + 1] alone;
+        # down: the kernel's own packed i32[1, 2 * 16 + 1], as it is (one
+        # scoring segment: no merge program, so no i32[16] goes up for
+        # it and no segment column comes down)
         plan_bytes = 4 * (2 * scoring.FUSED_T_RARE + 2 * scoring.FUSED_H + 1)
         assert self.delta(fused_service, MATCH) == {
-            "h2d_count": 2, "h2d_bytes": plan_bytes + 64,
-            "d2h_count": 1, "d2h_bytes": 196,
+            "h2d_count": 1, "h2d_bytes": plan_bytes,
+            "d2h_count": 1, "d2h_bytes": 132,
         }
 
     def test_collect_span_carries_the_groups_download(self, fused_service):
