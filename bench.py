@@ -705,32 +705,6 @@ def batch1_p50(svc, bodies, n=32):
     return p50
 
 
-def _mfu4(flops, seconds):
-    """MFU to four significant digits; None (JSON null) on a device
-    with no known peak (common/settings.peak_flops)."""
-    from elasticsearch_tpu.common.settings import mfu
-
-    v = mfu(flops, seconds)
-    return None if v is None else float(f"{v:.4e}")
-
-
-def roofline_window(svc, before, wall_s, n_queries):
-    """Per-config MFU/roofline numbers from the batcher's pipeline
-    counters over one measured window: mfu over the WALL clock (the
-    serving-level number — includes every host stall), device_util =
-    fraction of the wall with kernels in flight, flops_per_query =
-    estimated useful flops per request."""
-    after = svc._batcher.pipeline_stats()
-    flops = after["flops"] - before["flops"]
-    busy_s = (after["device_busy_ms"] - before["device_busy_ms"]) / 1000.0
-    return {
-        "mfu": _mfu4(flops, wall_s),
-        "device_util": round(min(busy_s / wall_s, 1.0), 4)
-        if wall_s > 0 else 0.0,
-        "flops_per_query": float(f"{flops / max(1, n_queries):.4e}"),
-    }
-
-
 def recall_gate(svc_jax, svc_oracle, bodies, n=12, k=1000):
     """recall@k of the device path vs the oracle + max relative score
     delta on common hits (the fp re-association residue, bounded)."""
@@ -849,7 +823,7 @@ def build_mesh_services(oracle: bool = True):
 
 def mesh_sweep(svc, svc_oracle, body_df):
     """Scaling sweep over 1/2/4/8 devices: per-device QPS, scaling
-    efficiency vs the 1-device mesh, per-device MFU, the sequential
+    efficiency vs the 1-device mesh, the sequential
     fan-out baseline, and recall/float-exactness gates."""
     import jax
 
@@ -875,7 +849,6 @@ def mesh_sweep(svc, svc_oracle, body_df):
         for v in qv
     ]
     mex = svc.mesh_executor()
-    batcher = svc._batcher
 
     # sequential (per-shard fan-out) baseline on the SAME index
     os.environ["ES_TPU_MESH"] = "off"
@@ -899,24 +872,8 @@ def mesh_sweep(svc, svc_oracle, body_df):
             for b in match_bodies[:4] + knn_bodies[:4]:
                 svc.search(b)  # warm/compile the nd-device programs
             routed0 = mex.stats["routed"]
-            dev0 = {r["id"]: r for r in batcher.device_stats()}
             m_qps, m_p50, _, _ = run_load(svc, match_bodies)
             k_qps, k_p50, _, _ = run_load(svc, knn_bodies)
-            per_device = []
-            for r in batcher.device_stats():
-                r0 = dev0.get(r["id"], {"device_busy_ms": 0.0, "flops": 0})
-                busy = r["device_busy_ms"] - r0["device_busy_ms"]
-                fl = r["flops"] - r0["flops"]
-                if busy <= 0 and fl <= 0:
-                    continue
-                per_device.append(
-                    {
-                        "id": r["id"],
-                        "device_busy_ms": round(busy, 1),
-                        "flops": int(fl),
-                        "mfu": _mfu4(fl, busy / 1000.0),
-                    }
-                )
             assert mex.stats["routed"] > routed0, "sweep did not mesh-route"
             sweep.append(
                 {
@@ -927,19 +884,12 @@ def mesh_sweep(svc, svc_oracle, body_df):
                     "knn_p50_ms": round(k_p50, 2),
                     "match_qps_per_device": round(m_qps / nd, 1),
                     "knn_qps_per_device": round(k_qps / nd, 1),
-                    "per_device": per_device,
                 }
             )
             log(
                 f"[mesh] {nd} device(s): match={m_qps:.1f} QPS "
                 f"p50={m_p50:.2f}ms  knn={k_qps:.1f} QPS p50={k_p50:.2f}ms"
             )
-            for row in per_device:
-                log(
-                    f"[mesh]   device {row['id']}: "
-                    f"busy={row['device_busy_ms']:.0f}ms "
-                    f"mfu={row['mfu']}"
-                )
         base = sweep[0]
         for entry in sweep:
             entry["scaling_match"] = (
@@ -1745,15 +1695,12 @@ def main():
                     svc_jax.rrf_stats[key] = 0
                 for dq in svc_jax.rrf_leg_samples.values():
                     dq.clear()
-        pipe0 = batcher.pipeline_stats()
         batch0 = batcher.batching_stats()
-        qps, p50, p99, wall = run_load(svc_jax, blist)
-        roof = roofline_window(svc_jax, pipe0, wall, len(blist))
+        qps, p50, p99, _ = run_load(svc_jax, blist)
         batch_block = batching_window(batch0, batcher.batching_stats())
         rrf_snapshot = dict(svc_jax.rrf_stats) if name == "hybrid_rrf" else None
         rrf_leg_block = leg_p50s(svc_jax) if name == "hybrid_rrf" else None
-        log(f"[{name}] jax: {qps:.1f} QPS, p50={p50:.2f}ms p99={p99:.2f}ms "
-            f"mfu={roof['mfu']} device_util={roof['device_util']:.3f}")
+        log(f"[{name}] jax: {qps:.1f} QPS, p50={p50:.2f}ms p99={p99:.2f}ms")
         # single-inflight latency: throughput-mode batching must not
         # hide a latency regression
         p50_b1 = batch1_p50(svc_jax, blist)
@@ -1792,7 +1739,6 @@ def main():
             "recall": round(recall, 4),
             "max_score_rel_delta": float(f"{max_rel:.3e}"),
             "batching": batch_block,
-            **roof,
             **depth_block,
         }
         log(
@@ -2164,21 +2110,10 @@ def main():
             f"knn={hy.get('knn_leg_p50_ms')}ms"
         )
 
-    # cumulative serving-pipeline roofline block (the "23× vs oracle"
-    # headline finally gets a denominator: flops, device-busy time,
-    # MFU against ES_TPU_PEAK_FLOPS)
-    pipeline_block = batcher.pipeline_stats()
-    if pipeline_block["mfu"] is not None:
-        pipeline_block["mfu"] = float(f"{pipeline_block['mfu']:.4e}")
-    pipeline_block["devices"] = batcher.device_stats()
-    log(f"[pipeline] depth={pipeline_block['depth']} "
-        f"device_busy={pipeline_block['device_busy_ms']:.0f}ms "
-        f"host_stall={pipeline_block['host_stall_ms']:.0f}ms "
-        f"mfu={pipeline_block['mfu']}")
-    for row in pipeline_block["devices"]:
-        log(f"[pipeline]   device {row['id']}: "
-            f"busy={row['device_busy_ms']:.0f}ms flops={row['flops']:.3g} "
-            f"mfu={row['mfu']}")
+    # device time is not measured here: the per-cell harness reads it
+    # from the profiler's device plane (benchmarks/, PERF.md §3)
+    pipeline_block = {"depth": batcher.pipeline_depth}
+    log(f"[pipeline] depth={pipeline_block['depth']}")
 
     # ---- mesh scaling sweep (its own multi-shard index) ----
     mesh_block = None

@@ -1901,7 +1901,7 @@ class IndexService:
                 # this request dispatched through (match/serve/knn/
                 # sparse/agg/rerank and the mesh_* variants) — launch
                 # count, kernel dispatch/collect wall time, queue wait,
-                # roofline flops, pad bucket, batch width, express-lane
+                # estimated flops, pad bucket, batch width, express-lane
                 # and pruning hits
                 "families": dict(phases.get("families", {})),
                 "phases": {

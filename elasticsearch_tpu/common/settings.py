@@ -30,19 +30,6 @@ INDEX_SCOPE = "index"
 PIPELINE_DEPTH_ENV = "ES_TPU_PIPELINE_DEPTH"
 PIPELINE_DEPTH_DEFAULT = 2
 
-# Peak accelerator FLOP/s used as the MFU/roofline denominator, keyed by
-# the `device_kind` JAX reports. bf16 MXU peaks — a conservative (large)
-# denominator for the fp32 kernels, so reported MFU understates rather
-# than flatters. A device that is in neither the table nor the
-# ES_TPU_PEAK_FLOPS override has NO peak: its `mfu` is null, never a
-# figure against another chip's peak.
-PEAK_FLOPS_ENV = "ES_TPU_PEAK_FLOPS"
-PEAK_FLOPS_BY_DEVICE_KIND = {
-    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
-    # (JAX 0.9.0 reports the part's device_kind as "TPU v5 lite")
-    "TPU v5 lite": 1.97e14,
-}
-
 # ---- continuous-batching launch-shape ladder (search/batcher.py) ----
 #
 # ES_TPU_BATCH_BUCKETS:  comma/space-separated query-row bucket sizes the
@@ -291,46 +278,6 @@ def bg_refresh_enabled() -> bool:
 
 class SettingsError(ValueError):
     pass
-
-
-def peak_flops_override() -> Optional[float]:
-    """ES_TPU_PEAK_FLOPS as a number, None when unset. A malformed value
-    is an error, not a default; a node reads it once at start
-    (rest/server.py) so that it fails there and not at the first
-    `_nodes/stats`."""
-    raw = os.environ.get(PEAK_FLOPS_ENV, "").strip()
-    if not raw:
-        return None
-    try:
-        v = float(raw)
-    except ValueError:
-        v = 0.0
-    if not v > 0:
-        raise SettingsError(
-            f"{PEAK_FLOPS_ENV} must be a positive number of FLOP/s, "
-            f"got [{raw}]"
-        )
-    return v
-
-
-def peak_flops() -> Optional[float]:
-    """Accelerator peak FLOP/s for MFU accounting: the override, else
-    the table entry of the device JAX reports, else None."""
-    v = peak_flops_override()
-    if v is not None:
-        return v
-    import jax
-
-    return PEAK_FLOPS_BY_DEVICE_KIND.get(jax.devices()[0].device_kind)
-
-
-def mfu(flops: float, busy_s: float) -> Optional[float]:
-    """Useful flops over (busy seconds x the device's peak); None where
-    the device has no known peak."""
-    peak = peak_flops()
-    if peak is None:
-        return None
-    return flops / (busy_s * peak) if busy_s > 0 else 0.0
 
 
 def _parse_bool(v) -> bool:

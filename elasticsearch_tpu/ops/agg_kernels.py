@@ -142,6 +142,6 @@ def masked_total_and_max(mask, scores):
 
 
 def agg_flops(n_slots: int, n_outputs: int) -> int:
-    """Rough useful-work estimate for the roofline counters: every slot
+    """Rough useful-work estimate for the profile breakdown: every slot
     is read once per output accumulator plus the mask combine."""
     return int(n_slots) * (2 + 3 * max(int(n_outputs), 1))

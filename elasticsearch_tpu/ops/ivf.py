@@ -254,7 +254,7 @@ def auto_nlist(n: int) -> int:
 
 
 def ann_flops(n_queries: int, nlist: int, nprobe: int, cmax: int, dims: int) -> int:
-    """Useful-flop estimate of one probed search (MFU accounting): the
+    """Useful-flop estimate of one probed search (the profile's): the
     centroid scan plus the gathered-candidate contraction."""
     scanned = nlist + nprobe * cmax
     return 2 * n_queries * scanned * dims

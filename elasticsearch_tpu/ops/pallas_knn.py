@@ -115,14 +115,6 @@ class QuantizedVectors:
         self.qvecs = jnp.asarray(q)
         self.scales = jnp.asarray(scales).reshape(1, -1)
 
-    def flops(self, n_queries: int) -> int:
-        """Estimated useful flops of one search over this corpus, for
-        the serving pipeline's MFU/roofline accounting (same convention
-        as ops/scoring.knn_flops): the 2·B·N·d MXU contraction plus the
-        per-element dequant scale multiply that rides the VPU pass.
-        Padding rows/lanes are excluded — MFU reflects useful work."""
-        return 2 * n_queries * self.n * self.dims + n_queries * self.n
-
     def search(
         self, queries: np.ndarray, k: int, interpret: bool = False
     ) -> Tuple[jax.Array, jax.Array]:

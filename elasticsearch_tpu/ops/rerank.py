@@ -39,7 +39,7 @@ import numpy as np
 def rerank_flops(
     n_queries: int, n_qtoks: int, window: int, tmax: int, dims: int
 ) -> int:
-    """Useful-flop estimate of one maxsim launch (MFU accounting)."""
+    """Useful-flop estimate of one maxsim launch (the profile's)."""
     return 2 * n_queries * n_qtoks * window * tmax * dims
 
 

@@ -148,15 +148,14 @@ class BatchedScoreResult(NamedTuple):
 BPAD = 32  # max query rows per launch (top of the bucket ladder)
 TCHUNK = 512  # fixed tiles per row per launch
 
-# ---- FLOP estimates for MFU/roofline accounting -------------------------
+# ---- FLOP estimates for the `"profile": true` breakdown -----------------
 # Useful (non-padding) work per scored element, counted at dispatch time
-# so bench.py / _nodes/stats can put a roofline denominator next to QPS.
+# into the group's `flops` (search/batcher.py _Group.add_flops).
 # Per posting slot the BM25 kernel does ~6 flops (tf·inv_norm multiply,
 # 1+x add, divide, w−x subtract, validity select, scatter add); a dense
 # hot-term row does ~4 per doc (no gather/scatter). top_k selection is
 # not counted (comparisons, not flops). These are estimates of USEFUL
-# work — padded rows/slots are excluded, so MFU reflects end-to-end
-# efficiency including padding waste.
+# work — padded rows/slots are excluded.
 
 FLOPS_PER_POSTING_SLOT = 6
 FLOPS_PER_DENSE_SLOT = 4

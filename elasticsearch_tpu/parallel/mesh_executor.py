@@ -1674,7 +1674,8 @@ class MeshExecutor:
                 raise MeshUnavailable(f"mesh agg type [{node.type}]")
         return MeshAggPlan(nodes, specs, mplan)
 
-    def dispatch_agg(self, jobs):
+    def dispatch_agg(self, jobs, kb: int):
+        # `kb`: the dispatch_* signature; an aggregation pages no top-k
         snap = self.ensure_snapshot()
         plan0 = jobs[0].plan
         rows = self._rows_for(snap, len(jobs))

@@ -592,13 +592,10 @@ class RestActions:
             "serve_fallback_jobs": 0, "serve_launches": 0,
             "serve_rare_tiles": 0, "serve_hot_rows": 0,
         }
-        # serving-pipeline roofline counters (QueryBatcher.pipeline_stats):
-        # depth/in_flight of the dispatch ring, device-busy and host-stall
-        # wall time, estimated useful flops, and MFU over busy time
-        pipeline = {
-            "depth": 0, "in_flight": 0, "device_busy_ms": 0.0,
-            "host_stall_ms": 0.0, "flops": 0, "mfu": 0.0,
-        }
+        # the serving pipeline: the workers' in-flight ring bound, the
+        # continuous-batching block and the mesh counters. Device time is
+        # not here: the profiler's device plane measures it (PERF.md §3)
+        pipeline = {"depth": 0}
         queue_capacity = 0
         # continuous-batching counters (QueryBatcher.batching_stats):
         # per-bucket launch histogram + occupancy, so padding waste is a
@@ -624,9 +621,6 @@ class RestActions:
             "worker_compile_ms": 0.0,
             "worker_compiles": 0,
         }
-        # per-device roofline rows (straggler visibility): busy time and
-        # flops merged by device id across every index's batcher
-        dev_agg: dict = {}
         mesh_stats = {
             "routed": 0, "launches": 0, "jobs": 0, "rebuilds": 0,
             "degraded": 0, "fallbacks": 0,
@@ -637,19 +631,7 @@ class RestActions:
                 for k in batch:
                     batch[k] += b.stats.get(k, 0)
                 queue_capacity = max(queue_capacity, b._queue.maxsize)
-                ps = b.pipeline_stats()
-                pipeline["depth"] = max(pipeline["depth"], ps["depth"])
-                pipeline["in_flight"] += ps["in_flight"]
-                pipeline["device_busy_ms"] += ps["device_busy_ms"]
-                pipeline["host_stall_ms"] += ps["host_stall_ms"]
-                pipeline["flops"] += ps["flops"]
-                for row in b.device_stats():
-                    d = dev_agg.setdefault(
-                        row["id"], {"id": row["id"],
-                                    "device_busy_ms": 0.0, "flops": 0}
-                    )
-                    d["device_busy_ms"] += row["device_busy_ms"]
-                    d["flops"] += row["flops"]
+                pipeline["depth"] = max(pipeline["depth"], b.pipeline_depth)
                 bs = b.batching_stats()
                 if len(bs["buckets"]) > len(batching["buckets"]):
                     batching["buckets"] = bs["buckets"]
@@ -682,22 +664,6 @@ class RestActions:
             from ..common.settings import pipeline_depth
 
             pipeline["depth"] = pipeline_depth()
-        from ..common.settings import mfu
-
-        pipeline["mfu"] = mfu(
-            pipeline["flops"], pipeline["device_busy_ms"] / 1000.0
-        )
-        pipeline["device_busy_ms"] = round(pipeline["device_busy_ms"], 3)
-        pipeline["host_stall_ms"] = round(pipeline["host_stall_ms"], 3)
-        pipeline["devices"] = [
-            {
-                "id": d["id"],
-                "device_busy_ms": round(d["device_busy_ms"], 3),
-                "flops": int(d["flops"]),
-                "mfu": mfu(d["flops"], d["device_busy_ms"] / 1000.0),
-            }
-            for d in sorted(dev_agg.values(), key=lambda r: r["id"])
-        ]
         batching["avg_occupancy"] = (
             round(batching["occupancy_jobs"] / batching["occupancy_slots"], 4)
             if batching["occupancy_slots"]

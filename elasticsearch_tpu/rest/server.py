@@ -21,7 +21,6 @@ from urllib.parse import parse_qs, unquote, urlparse
 
 from ..cluster import ClusterError, ClusterService
 from ..common.memory import CircuitBreakingException
-from ..common.settings import peak_flops_override
 from ..common.tracing import OPAQUE_ID_CTX
 from ..index.engine import EngineError, VersionConflictError
 from ..index.mapping import MappingParseError
@@ -200,9 +199,6 @@ class ElasticsearchTpuServer:
         data_path: Optional[str] = None,
         cluster: Optional[ClusterService] = None,
     ):
-        # a malformed ES_TPU_PEAK_FLOPS fails the start, not every later
-        # `_nodes/stats`
-        peak_flops_override()
         self.cluster = cluster or ClusterService(data_path=data_path)
         self.actions = RestActions(self.cluster)
         handler = type("BoundHandler", (ElasticHandler,), {"actions": self.actions})

@@ -44,7 +44,7 @@ import time
 import weakref
 from collections import deque
 from dataclasses import dataclass
-from typing import Deque, Dict, List, Optional, Tuple, Union
+from typing import Callable, Deque, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 from jax import monitoring as jax_monitoring
@@ -490,7 +490,7 @@ class _Job:
         deadline: Optional[float] = None, prof=None,
     ):
         self.executor = executor
-        self.kind = kind  # "match" | "serve" | "knn"
+        self.kind = kind  # a key of FAMILIES
         self.plan = plan
         self.k = k
         self.query = query  # parsed Query node for per-segment fallback
@@ -579,6 +579,12 @@ class _Group:
         self.t_collect = at or time.perf_counter_ns()
         self.d2h0 = thread_d2h_bytes()
 
+    def add_flops(self, n: int) -> None:
+        """One recorded launch and its estimated useful flops (the
+        `dispatch` span's `launches`, the profile breakdown's `flops`)."""
+        self.launches += 1
+        self.flops += int(n)
+
     def phase(self, name: str) -> TraceAnnotation:
         """The worker's phase on the profiler's clock (`es.dispatch`,
         `es.collect`): outside a profiler session a flag test, inside
@@ -666,7 +672,155 @@ class _BatchCtx:
 
     def __init__(self, batch: List[_Job]):
         self.batch = batch
-        self.pending: List[Tuple] = []  # (key, jobs, fam, pend, dev_ids)
+        self.pending: List[Tuple] = []  # (family, key, kb, jobs, pend)
+
+
+def _group_now() -> _Group:
+    """The group this worker is dispatching or collecting. Off a worker
+    and in a warm-up (a dispatch / collect pair driven by hand) a
+    throwaway nothing reads."""
+    return getattr(_worker_tl, "group", None) or _Group("", 0, 0)
+
+
+@dataclass(frozen=True)
+class _Family:
+    """All the batcher knows about one job kind (an entry of FAMILIES):
+    which jobs may share a launch, how a group of them is launched and
+    collected, whether its first dispatch warms the bucket ladder. The
+    calls name the batcher's group methods when they run, not before:
+    a subclass or a test may replace one."""
+
+    # what runs beside what: a class of `_inflight`, and the `family` of
+    # the `batcher.dispatch` / `batcher.collect` fault sites
+    overlap: str  # text | knn | agg | rerank | sparse
+    # plan -> the attributes two jobs must agree in to share a launch;
+    # the batcher adds the executor, the kind and the top-k bucket `kb`
+    share: Callable[[object], Tuple]
+    # (batcher, jobs, key, kb, rows, record) -> pend: the group's device
+    # work, enqueued without a host sync (`record=False`: a warm-up
+    # launch, which appears in no counter and fires no fault site)
+    dispatch: Callable
+    # (batcher, jobs, key, kb, pend, record): the blocking downloads and
+    # the waiters' wake-up. None: the group completes inside `dispatch`,
+    # after the batch's asynchronous groups (its host syncs overlap them)
+    collect: Optional[Callable] = None
+    # a placement over all the index's shards (MeshExecutor): the launch
+    # width is not a ladder bucket but what dispatch returns as
+    # pend["rows"] (the mesh's data axis must divide it)
+    mesh: bool = False
+    bucket_recorded: bool = True  # in `launches_by_bucket`
+    # jobs -> (what the programs specialize on beyond the key, the job a
+    # warm-up dummy is cloned from). None: the family warms no ladder
+    warm: Optional[Callable] = None
+
+
+def _mesh_family(overlap: str, share: Callable, dispatch: str,
+                 collect: str) -> _Family:
+    """A family placed on the mesh: `dispatch` / `collect` name the
+    MeshExecutor's pair (B queries x all shards in one SPMD program)."""
+
+    def launch(b, jobs, key, kb, rows, record):
+        pend = getattr(jobs[0].executor, dispatch)(jobs, kb)
+        with b._lock:
+            b.stats["launches"] += 1
+            b.stats["fused_jobs"] += len(jobs)
+        _group_now().add_flops(pend["flops"])
+        return pend
+
+    def download(b, jobs, key, kb, pend, record):
+        getattr(jobs[0].executor, collect)(jobs, pend)
+
+    return _Family(overlap, share, launch, download, mesh=True)
+
+
+def _serve_share(p) -> Tuple:
+    return p.fields, p.combine, p.tie
+
+
+def _warm_first(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
+    return (), jobs[0]
+
+
+def _warm_match(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
+    # the match kernels specialize on the count plane too
+    with_cnt = [j for j in jobs if j.plan.msm > 1]
+    return (bool(with_cnt),), (with_cnt or jobs)[0]
+
+
+def _warm_knn(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
+    # the kNN candidate page is a compile bucket of its own
+    j0 = max(jobs, key=lambda j: j.plan.num_candidates)
+    return (scoring.next_bucket(j0.plan.num_candidates, 16),), j0
+
+
+FAMILIES: Dict[str, _Family] = {
+    # `_run_group`: dispatch to wake-up on the worker
+    "match": _Family(
+        "text", lambda p: (p.field,),
+        lambda b, jobs, key, kb, rows, record: b._run_group(
+            jobs, key[2], kb, rows=rows, record=record),
+        warm=_warm_match,
+    ),
+    "serve": _Family(
+        "text", _serve_share,
+        lambda b, jobs, key, kb, rows, record: b._dispatch_serve_group(
+            jobs, kb, rows=rows, record=record),
+        lambda b, jobs, key, kb, pend, record: b._collect_serve_group(
+            jobs, kb, pend, record=record),
+        warm=_warm_first,
+    ),
+    # `ann` rides the key: exact and IVF-probed jobs never share
+    "knn": _Family(
+        "knn", lambda p: (p.field, p.ann),
+        lambda b, jobs, key, kb, rows, record: b._dispatch_knn_group(
+            jobs, rows=rows, record=record),
+        lambda b, jobs, key, kb, pend, record: b._collect_knn_group(
+            jobs, pend, record=record),
+        warm=_warm_knn,
+    ),
+    # device aggregations: identical dashboard shapes (the compiled
+    # plan's structural signature) share one dispatch slot
+    "agg": _Family(
+        "agg", lambda p: (p.sig,),
+        lambda b, jobs, key, kb, rows, record: b._dispatch_agg_group(jobs),
+        lambda b, jobs, key, kb, pend, record: b._collect_agg_group(
+            jobs, pend),
+        bucket_recorded=False,
+    ),
+    # second-stage rerank: jobs share a maxsim launch when model, padded
+    # window / query-token shapes, static window and blend weights agree
+    "rerank": _Family(
+        "rerank", lambda p: (p.sig,),
+        lambda b, jobs, key, kb, rows, record: b._dispatch_rerank_group(
+            jobs, rows=rows),
+        lambda b, jobs, key, kb, pend, record: b._collect_rerank_group(
+            jobs, pend),
+    ),
+    # learned sparse: the frozen SparseSpec rides the key, so int8 and
+    # fp32 servings of one field never share a launch
+    "sparse": _Family(
+        "sparse", lambda p: (p.field, p.spec),
+        lambda b, jobs, key, kb, rows, record: b._dispatch_sparse_group(
+            jobs, kb, rows=rows, record=record),
+        lambda b, jobs, key, kb, pend, record: b._collect_sparse_group(
+            jobs, kb, pend, record=record),
+        warm=_warm_first,
+    ),
+    # a fused mesh rescore rides the plan (`rescore_sig`, None for plain
+    # match): different specs / page sizes never share an SPMD launch
+    "mesh_match": _mesh_family(
+        "text", lambda p: (p.field, getattr(p, "rescore_sig", None)),
+        "dispatch_match", "collect_match"),
+    "mesh_serve": _mesh_family(
+        "text", _serve_share, "dispatch_serve", "collect_match"),
+    "mesh_knn": _mesh_family(
+        "knn", lambda p: (p.field, p.ann), "dispatch_knn", "collect_knn"),
+    "mesh_sparse": _mesh_family(
+        "sparse", lambda p: (p.field, p.spec),
+        "dispatch_sparse", "collect_sparse"),
+    "mesh_agg": _mesh_family(
+        "agg", lambda p: (p.sig,), "dispatch_agg", "collect_agg"),
+}
 
 
 WORKERS = 6  # parallel dispatcher pipelines, so several batches' round
@@ -719,15 +873,6 @@ class QueryBatcher:
         self._threads: List[threading.Thread] = []
         self._closed = False
         self._lock = threading.Lock()
-        # MFU/roofline accounting (guarded by self._lock): estimated
-        # useful flops dispatched, wall time with >= 1 batch in flight
-        # on device (union of dispatch→collect intervals), and time
-        # workers spent blocked on device→host downloads
-        self._flops = 0
-        self._ring_inflight = 0
-        self._busy_t0 = 0.0
-        self._device_busy_s = 0.0
-        self._host_stall_s = 0.0
         live_batchers.add(self)
         # dense hot-term slots each fused match job used, 0..FUSED_H:
         # how much of the kernel's slot budget real questions take
@@ -806,16 +951,9 @@ class QueryBatcher:
         self._cold_lock = threading.Lock()
         self._cold_s = 0.0
         self._compiles = 0  # programs those workers built or fetched
-        # family → groups currently dispatched-but-not-collected,
+        # overlap class → groups currently dispatched-but-not-collected,
         # across ALL workers (guarded by self._lock)
-        self._inflight = {
-            "text": 0, "knn": 0, "agg": 0, "rerank": 0, "sparse": 0,
-        }
-        # per-device roofline accounting (straggler visibility): device
-        # id → [inflight_groups, busy_t0, busy_s, flops]; single-device
-        # groups attribute to device 0, mesh groups to every device in
-        # the mesh (guarded by self._lock)
-        self._devs: Dict[int, list] = {}
+        self._inflight = {f.overlap: 0 for f in FAMILIES.values()}
 
     def _ensure_thread(self):
         with self._lock:
@@ -874,6 +1012,8 @@ class QueryBatcher:
         at dequeue instead of dispatched dead."""
         if self._closed:
             raise RuntimeError("query batcher closed")
+        if kind not in FAMILIES:
+            raise ValueError(f"unknown job kind [{kind}]")
         job = _Job(executor, plan, k, kind=kind, query=query,
                    deadline=deadline, prof=prof)
         job.cold0 = self._cold_s
@@ -892,15 +1032,7 @@ class QueryBatcher:
             self.close()
         return job
 
-    # historical name; same semantics (the return value was always a
-    # handle — submit_nowait formalizes it as the public future API)
-    submit = submit_nowait
-
-    def execute(
-        self, executor, plan, k: int, kind: str = "match", query=None
-    ) -> TopDocs:
-        job = self.submit_nowait(executor, plan, k, kind=kind, query=query)
-        return self.wait(job)
+    submit = submit_nowait  # the older name
 
     @staticmethod
     def wait(job: _Job, timeout: Optional[float] = None) -> TopDocs:
@@ -1032,14 +1164,12 @@ class QueryBatcher:
             err = RuntimeError("query batcher closed")
             while inflight:
                 ctx = inflight.popleft()
-                for _, jobs, fam, _, dev_ids in ctx.pending:
-                    self._exit_kind(fam)
-                    self._dev_exit(dev_ids)
+                for fam, *_ in ctx.pending:
+                    self._exit_kind(fam.overlap)
                 for j in ctx.batch:
                     if not j.event.is_set():
                         j.error = err
                         j.finish()
-                self._ring_exit()
             self._drain_queue(RuntimeError("query batcher worker exited"))
             if self._closed:
                 # the drain above may have eaten peers' wake sentinels:
@@ -1052,15 +1182,14 @@ class QueryBatcher:
     def _dispatch_batch(
         self, batch: List[_Job], express: bool = False
     ) -> "_BatchCtx":
-        """Groups a batch and launches all its device work. serve/knn
-        groups dispatch asynchronously (collected later by
-        _collect_batch); match groups run dispatch+collect fused (their
-        pruning rounds are host-dependent) AFTER the async dispatches,
-        so their host syncs overlap the in-flight serve/knn kernels
+        """Groups a batch and launches all its device work. A family
+        with a collect stage dispatches asynchronously (collected later
+        by _collect_batch); one without (match: its pruning rounds are
+        host-dependent) runs dispatch+collect fused AFTER the async
+        dispatches, so its host syncs overlap the in-flight kernels
         instead of stalling them. Never raises: failures surface to the
         affected jobs' waiters."""
         ctx = _BatchCtx(batch)
-        self._ring_enter()
         try:
             # congestion signal for the admission layer's AIMD limit:
             # the worst enqueue→dispatch wait in this batch (the
@@ -1079,160 +1208,59 @@ class QueryBatcher:
                 self.stats["max_batch_seen"] = max(
                     self.stats["max_batch_seen"], len(batch)
                 )
-            # group jobs that can share launches (same reader
-            # generation, plan family, and top-k compile bucket);
-            # mesh_* families group whole-index query batches on the
-            # MeshExecutor (B queries × all shards in one SPMD program)
-            groups: Dict[Tuple, List[_Job]] = {}
+            # group jobs that can share launches: same reader generation
+            # (the executor), kind, whatever the family keys on, and
+            # top-k compile bucket
+            groups: Dict[Tuple, Tuple[_Family, int, List[_Job]]] = {}
             for j in batch:
+                fam = FAMILIES[j.kind]
                 kb = 16 if j.k <= 16 else scoring.next_bucket(j.k, 16)
-                if j.kind == "match":
-                    key = (id(j.executor), "m", j.plan.field, kb)
-                elif j.kind == "serve":
-                    key = (
-                        id(j.executor), "s", j.plan.fields,
-                        j.plan.combine, j.plan.tie, kb,
-                    )
-                elif j.kind == "mesh_match":
-                    # a fused mesh rescore rides the plan (rescore_sig
-                    # None for plain match): different specs / page
-                    # sizes never share an SPMD launch
-                    key = (
-                        id(j.executor), "Mm", j.plan.field,
-                        getattr(j.plan, "rescore_sig", None), kb,
-                    )
-                elif j.kind == "mesh_serve":
-                    key = (
-                        id(j.executor), "Ms", j.plan.fields,
-                        j.plan.combine, j.plan.tie, kb,
-                    )
-                elif j.kind == "mesh_knn":
-                    key = (id(j.executor), "Mk", j.plan.field, j.plan.ann, kb)
-                elif j.kind == "mesh_sparse":
-                    key = (
-                        id(j.executor), "Mv", j.plan.field, j.plan.spec, kb,
-                    )
-                elif j.kind == "agg":
-                    # device-aggregations family: jobs group by the
-                    # compiled plan's structural signature so identical
-                    # dashboard shapes share one dispatch slot
-                    key = (id(j.executor), "a", j.plan.sig, kb)
-                elif j.kind == "rerank":
-                    # second-stage rerank family: jobs share a maxsim
-                    # launch when model, padded window/query-token
-                    # shapes, static window, and blend weights agree
-                    key = (id(j.executor), "r", j.plan.sig, kb)
-                elif j.kind == "sparse":
-                    # learned-sparse family: the frozen SparseSpec rides
-                    # the key so int8 and fp32 servings of one field
-                    # never share a launch
-                    key = (id(j.executor), "v", j.plan.field, j.plan.spec, kb)
-                elif j.kind == "mesh_agg":
-                    key = (id(j.executor), "Ma", j.plan.sig, kb)
-                else:  # knn (exact and IVF-probed jobs never share;
-                    # kb stays LAST — dispatch reads it as key[-1])
-                    key = (id(j.executor), "k", j.plan.field, j.plan.ann, kb)
-                groups.setdefault(key, []).append(j)
+                key = (id(j.executor), j.kind, *fam.share(j.plan), kb)
+                groups.setdefault(key, (fam, kb, []))[2].append(j)
             ordered = sorted(
-                groups.items(), key=lambda kv: kv[0][1] == "m"
+                groups.items(), key=lambda kv: kv[1][0].collect is None
             )
-            for key, jobs in ordered:
-                kind, kb = key[1], key[-1]
-                mesh = kind in ("Mm", "Ms", "Mk", "Ma", "Mv")
-                if kind in ("k", "Mk"):
-                    fam = "knn"
-                elif kind in ("a", "Ma"):
-                    fam = "agg"
-                elif kind == "r":
-                    fam = "rerank"
-                elif kind in ("v", "Mv"):
-                    fam = "sparse"
-                else:
-                    fam = "text"
+            for key, (fam, kb, jobs) in ordered:
                 # pad-bucket ladder: the group's launch width is the
-                # smallest compiled bucket covering its occupancy —
-                # mesh groups pick theirs internally (the data-axis
-                # divisibility constraint lives there)
-                rows = None if mesh else bucket_for(len(jobs), self.buckets)
+                # smallest compiled bucket covering its occupancy
+                rows = (
+                    None if fam.mesh
+                    else bucket_for(len(jobs), self.buckets)
+                )
                 # the group's marks start here: its jobs' queue wait
                 # ends, and compiles on this thread are the group's
-                g = _Group(
-                    jobs[0].kind, len(jobs), rows, express, self._cold_s
-                )
+                g = _Group(key[1], len(jobs), rows, express, self._cold_s)
                 for j in jobs:
                     j.group = g
                 _worker_tl.group = g
-                dev_ids: Tuple[int, ...] = (0,)
-                dev_entered = False
-                self._enter_kind(fam)
+                self._enter_kind(fam.overlap)
                 dispatched = warm = False
                 try:
-                    if not mesh:
-                        self._dev_enter(dev_ids)
-                        dev_entered = True
                     # fault site: an injected dispatch failure surfaces
                     # to exactly this group's waiters, not the batch
                     faults.check(
-                        "batcher.dispatch", family=fam, jobs=len(jobs),
-                        mesh=int(mesh),
+                        "batcher.dispatch", family=fam.overlap,
+                        jobs=len(jobs), mesh=int(fam.mesh),
                     )
-                    if kind == "m":
-                        # record BEFORE dispatch: match groups complete
-                        # their waiters inside _run_group, and a waiter
-                        # must never observe its own launch missing
-                        # from the histogram
+                    if fam.bucket_recorded and not fam.mesh:
+                        # BEFORE dispatch: a group that completes there
+                        # wakes its waiters, and a waiter must never
+                        # observe its own launch missing from the
+                        # histogram
                         self._record_bucket(rows, len(jobs))
-                        self._run_group(jobs, key[2], kb, rows=rows)
-                        warm = True
-                    elif mesh:
-                        mex = jobs[0].executor
-                        with g.phase("es.dispatch"):
-                            if kind == "Mm":
-                                pend = mex.dispatch_match(jobs, kb)
-                            elif kind == "Ms":
-                                pend = mex.dispatch_serve(jobs, kb)
-                            elif kind == "Ma":
-                                pend = mex.dispatch_agg(jobs)
-                            elif kind == "Mv":
-                                pend = mex.dispatch_sparse(jobs, kb)
-                            else:
-                                pend = mex.dispatch_knn(jobs, kb)
-                            # the busy window opens on the devices the
-                            # snapshot actually spans
-                            dev_ids = mex.device_ids
-                            self._dev_enter(dev_ids)
-                            dev_entered = True
-                            with self._lock:
-                                self.stats["launches"] += 1
-                                self.stats["fused_jobs"] += len(jobs)
-                            self._add_flops(pend["flops"], dev_ids)
-                            g.rows = int(pend.get("rows", BPAD))
-                            self._record_bucket(g.rows, len(jobs))
-                        g.dispatched()
-                        ctx.pending.append((key, jobs, fam, pend, dev_ids))
-                        dispatched = True
+                    if fam.collect is None:
+                        fam.dispatch(self, jobs, key, kb, rows, True)
                     else:
-                        if kind != "a":
-                            self._record_bucket(rows, len(jobs))
                         with g.phase("es.dispatch"):
-                            if kind == "s":
-                                pend = self._dispatch_serve_group(
-                                    jobs, kb, rows=rows)
-                            elif kind == "k":
-                                pend = self._dispatch_knn_group(
-                                    jobs, rows=rows)
-                            elif kind == "a":
-                                pend = self._dispatch_agg_group(jobs)
-                            elif kind == "r":
-                                pend = self._dispatch_rerank_group(
-                                    jobs, rows=rows)
-                            else:  # "v"
-                                pend = self._dispatch_sparse_group(
-                                    jobs, kb, rows=rows)
+                            pend = fam.dispatch(
+                                self, jobs, key, kb, rows, True)
+                            if fam.mesh:
+                                g.rows = int(pend.get("rows", BPAD))
+                                self._record_bucket(g.rows, len(jobs))
                         g.dispatched()
-                        ctx.pending.append((key, jobs, fam, pend, dev_ids))
+                        ctx.pending.append((fam, key, kb, jobs, pend))
                         dispatched = True
-                        warm = kind in ("s", "k", "v")
+                    warm = fam.warm is not None
                 except BaseException as e:  # surface to waiters
                     for j in jobs:
                         if not j.event.is_set():
@@ -1241,13 +1269,11 @@ class QueryBatcher:
                 finally:
                     _worker_tl.group = None
                     if not dispatched:
-                        self._exit_kind(fam)
-                        if dev_entered:
-                            self._dev_exit(dev_ids)
+                        self._exit_kind(fam.overlap)
                 if warm:
                     # after the group's own marks and waiters: bucket
                     # warming is compile time, not this query's time
-                    self._maybe_warm(key, jobs, kb, rows)
+                    self._maybe_warm(fam, key, jobs, kb, rows)
         except BaseException as e:
             # stats/grouping crash between dequeue and the per-group
             # guard: already-dequeued jobs are not in the queue, so the
@@ -1264,8 +1290,7 @@ class QueryBatcher:
         """Host side of one dispatched batch: transfer the merged device
         results and finish the waiters. Never raises."""
         try:
-            for key, jobs, fam, pend, dev_ids in ctx.pending:
-                kind = key[1]
+            for fam, key, kb, jobs, pend in ctx.pending:
                 g = jobs[0].group
                 _worker_tl.group = g
                 g.collecting()
@@ -1274,37 +1299,10 @@ class QueryBatcher:
                         # fault site: a collect-phase failure (device→
                         # host transfer) fails this group's waiters only
                         faults.check(
-                            "batcher.collect", family=fam, jobs=len(jobs),
-                            mesh=int(kind in ("Mm", "Ms", "Mk", "Mv")),
+                            "batcher.collect", family=fam.overlap,
+                            jobs=len(jobs), mesh=int(fam.mesh),
                         )
-                        if kind == "s":
-                            self._collect_serve_group(jobs, key[-1], pend)
-                        elif kind == "k":
-                            self._collect_knn_group(jobs, pend)
-                        elif kind == "a":
-                            self._collect_agg_group(jobs, pend)
-                        elif kind == "r":
-                            self._collect_rerank_group(jobs, pend)
-                        elif kind == "v":
-                            self._collect_sparse_group(jobs, key[-1], pend)
-                        elif kind in ("Mm", "Ms"):
-                            t0 = time.perf_counter()
-                            jobs[0].executor.collect_match(jobs, pend)
-                            self._add_stall(time.perf_counter() - t0)
-                        elif kind == "Mk":
-                            t0 = time.perf_counter()
-                            jobs[0].executor.collect_knn(jobs, pend)
-                            self._add_stall(time.perf_counter() - t0)
-                        elif kind == "Ma":
-                            t0 = time.perf_counter()
-                            jobs[0].executor.collect_agg(jobs, pend)
-                            self._add_stall(time.perf_counter() - t0)
-                        elif kind == "Mv":
-                            t0 = time.perf_counter()
-                            jobs[0].executor.collect_sparse(jobs, pend)
-                            self._add_stall(time.perf_counter() - t0)
-                        else:
-                            self._collect_knn_group(jobs, pend)
+                        fam.collect(self, jobs, key, kb, pend, True)
                 except BaseException as e:
                     for j in jobs:
                         if not j.event.is_set():
@@ -1312,58 +1310,19 @@ class QueryBatcher:
                             j.finish()
                 finally:
                     _worker_tl.group = None
-                    self._exit_kind(fam)
-                    self._dev_exit(dev_ids)
+                    self._exit_kind(fam.overlap)
         finally:
             ctx.pending = []
-            self._ring_exit()
-
-    # ---- pipeline accounting (MFU/roofline) ----
-
-    def _ring_enter(self):
-        with self._lock:
-            self._ring_inflight += 1
-            if self._ring_inflight == 1:
-                self._busy_t0 = time.perf_counter()
-
-    def _ring_exit(self):
-        with self._lock:
-            self._ring_inflight -= 1
-            if self._ring_inflight == 0:
-                self._device_busy_s += time.perf_counter() - self._busy_t0
-
-    def _add_flops(self, n: int, dev_ids: Tuple[int, ...] = (0,)):
-        n = int(n)
-        g = getattr(_worker_tl, "group", None)
-        if g is not None:
-            # called once per recorded launch: credit the launch and its
-            # flops to the group this worker is dispatching as well as
-            # to the node-level roofline counters
-            g.launches += 1
-            g.flops += n
-        with self._lock:
-            self._flops += n
-            if dev_ids:
-                share = n // len(dev_ids)
-                for i, did in enumerate(dev_ids):
-                    d = self._devs.setdefault(did, [0, 0.0, 0.0, 0])
-                    d[3] += share + (n - share * len(dev_ids) if i == 0 else 0)
 
     def _count_overflow(self, fplans: list):
         """Jobs whose plan does not fit the fused kernel's slots send
         their whole group down the slower path: counted, and flagged on
         the group this worker is dispatching (its `dispatch` span)."""
-        g = getattr(_worker_tl, "group", None)
-        if g is not None:
-            g.overflow = True
+        _group_now().overflow = True
         with self._lock:
             self.stats["fused_overflow_jobs"] += sum(
                 1 for p in fplans if p is None
             )
-
-    def _add_stall(self, seconds: float):
-        with self._lock:
-            self._host_stall_s += seconds
 
     # ---- continuous-batching accounting + bucket warmup ----
 
@@ -1420,7 +1379,8 @@ class QueryBatcher:
             "worker_compiles": compiles,
         }
 
-    def _maybe_warm(self, key, jobs: List[_Job], kb: int, rows: int):
+    def _maybe_warm(self, fam: _Family, key, jobs: List[_Job], kb: int,
+                    rows: int):
         """Eagerly compiles the remaining ladder buckets of this group's
         kernel family the first time the family dispatches, by running
         one dummy job (cloned from the live group's plan) through the
@@ -1431,30 +1391,14 @@ class QueryBatcher:
         `warmup_enabled` attribute (tier-1 pins it off)."""
         if not self.warmup_enabled or len(self.buckets) <= 1:
             return
-        kind = key[1]
-        warm_key: Tuple = key
-        if kind == "m":
-            # the match kernels specialize on the count plane too
-            warm_key = key + (any(j.plan.msm > 1 for j in jobs),)
-        elif kind == "k":
-            # the kNN candidate page is a compile bucket of its own
-            warm_key = key + (
-                scoring.next_bucket(
-                    max(j.plan.num_candidates for j in jobs), 16
-                ),
-            )
+        specialized, j0 = fam.warm(jobs)
+        warm_key = key + specialized
         with self._lock:
             if warm_key in self._warmed:
                 return
             self._warmed.add(warm_key)
             self._warm_inflight += 1
         try:
-            if kind == "m":
-                j0 = next((j for j in jobs if j.plan.msm > 1), jobs[0])
-            elif kind == "k":
-                j0 = max(jobs, key=lambda j: j.plan.num_candidates)
-            else:
-                j0 = jobs[0]
             for b in self.buckets:
                 if b == rows:
                     continue
@@ -1463,26 +1407,9 @@ class QueryBatcher:
                          query=j0.query)
                 ]
                 try:
-                    if kind == "m":
-                        self._run_group(dummy, key[2], kb, rows=b,
-                                        record=False)
-                    elif kind == "s":
-                        pend = self._dispatch_serve_group(
-                            dummy, kb, rows=b, record=False
-                        )
-                        self._collect_serve_group(dummy, kb, pend,
-                                                  record=False)
-                    elif kind == "v":
-                        pend = self._dispatch_sparse_group(
-                            dummy, kb, rows=b, record=False
-                        )
-                        self._collect_sparse_group(dummy, kb, pend,
-                                                   record=False)
-                    else:
-                        pend = self._dispatch_knn_group(
-                            dummy, rows=b, record=False
-                        )
-                        self._collect_knn_group(dummy, pend, record=False)
+                    pend = fam.dispatch(self, dummy, key, kb, b, False)
+                    if fam.collect is not None:
+                        fam.collect(self, dummy, key, kb, pend, False)
                 except BaseException as e:
                     # warmup is opportunistic: a failed bucket just
                     # compiles lazily on its first live hit instead —
@@ -1493,7 +1420,7 @@ class QueryBatcher:
                     if first:
                         logger.warning(
                             "bucket warm-up launch failed (family %r, "
-                            "rows=%d): %r", kind, b, e,
+                            "rows=%d): %r", j0.kind, b, e,
                         )
         finally:
             with self._lock:
@@ -1512,79 +1439,6 @@ class QueryBatcher:
                     return True
             time.sleep(0.01)
         return False
-
-    # ---- per-device busy windows (straggler visibility) ----
-
-    def _dev_enter(self, dev_ids: Tuple[int, ...]):
-        now = time.perf_counter()
-        with self._lock:
-            for did in dev_ids:
-                d = self._devs.setdefault(did, [0, 0.0, 0.0, 0])
-                d[0] += 1
-                if d[0] == 1:
-                    d[1] = now
-
-    def _dev_exit(self, dev_ids: Tuple[int, ...]):
-        now = time.perf_counter()
-        with self._lock:
-            for did in dev_ids:
-                d = self._devs.get(did)
-                if d is None:
-                    continue
-                d[0] -= 1
-                if d[0] == 0:
-                    d[2] += now - d[1]
-
-    def device_stats(self) -> list:
-        """Per-device roofline rows [{id, device_busy_ms, flops, mfu}]
-        so one straggler chip is visible next to the aggregate MFU.
-        Busy time is the union of this device's group dispatch→collect
-        windows; flops split evenly across a mesh group's devices."""
-        from ..common.settings import mfu
-
-        now = time.perf_counter()
-        out = []
-        with self._lock:
-            for did in sorted(self._devs):
-                inflight, t0, busy, flops = self._devs[did]
-                if inflight > 0:
-                    busy += now - t0
-                out.append(
-                    {
-                        "id": did,
-                        "device_busy_ms": round(busy * 1000.0, 3),
-                        "flops": int(flops),
-                        "mfu": mfu(flops, busy),
-                    }
-                )
-        return out
-
-    def pipeline_stats(self) -> dict:
-        """Snapshot of the serving-pipeline roofline counters.
-
-        device_busy_ms approximates accelerator-occupied wall time as
-        the union of dispatch→collect intervals across workers (an
-        upper bound: host work inside a match group's pruning round is
-        included). mfu = estimated useful flops / (device_busy ·
-        the device's peak, common/settings.peak_flops; null where the
-        device has none) — flop formulas in ops/scoring.py."""
-        from ..common.settings import mfu
-
-        with self._lock:
-            busy = self._device_busy_s
-            if self._ring_inflight > 0:
-                busy += time.perf_counter() - self._busy_t0
-            flops = self._flops
-            stall = self._host_stall_s
-            inflight = self._ring_inflight
-        return {
-            "depth": self.pipeline_depth,
-            "in_flight": inflight,
-            "device_busy_ms": round(busy * 1000.0, 3),
-            "host_stall_ms": round(stall * 1000.0, 3),
-            "flops": int(flops),
-            "mfu": mfu(flops, busy),
-        }
 
     def _run_group(self, jobs: List[_Job], field: str, kb: int,
                    rows: Optional[int] = None, record: bool = True):
@@ -1652,7 +1506,7 @@ class QueryBatcher:
                             self.stats["fused_jobs"] += nj
                             for p in fplans:
                                 self._fused_hot_slots[len(p[2])] += 1
-                        self._add_flops(sum(
+                        _group_now().add_flops(sum(
                             scoring.text_plan_flops(
                                 len(p[0]), len(p[2]), n_docs
                             )
@@ -1702,16 +1556,13 @@ class QueryBatcher:
             if record:
                 with self._lock:
                     self.stats["launches"] += 1
-                self._add_flops(scoring.text_plan_flops(
+                _group_now().add_flops(scoring.text_plan_flops(
                     sum(len(t) for t in a_tiles), 0, 0
                 ))
             if any(deferred):
                 # ---- the threshold broadcast + survival test (the one
                 # host-dependent round: only runs when pruning engages) ----
-                t0 = time.perf_counter()
                 theta, accmax = cs.threshold(acc, kb)
-                if record:
-                    self._add_stall(time.perf_counter() - t0)
                 b_tiles: List[np.ndarray] = []
                 b_w: List[np.ndarray] = []
                 for ji, hots in enumerate(deferred):
@@ -1738,7 +1589,7 @@ class QueryBatcher:
                 if record:
                     with self._lock:
                         self.stats["launches"] += 1
-                    self._add_flops(scoring.text_plan_flops(
+                    _group_now().add_flops(scoring.text_plan_flops(
                         sum(len(t) for t in b_tiles), 0, 0
                     ))
             msm = np.ones(rows, np.int32)
@@ -1811,19 +1662,15 @@ class QueryBatcher:
         row is the answer already and is downloaded as the kernel wrote
         it (no program, no upload); anything else goes through the one
         merge program, which unpacks packed rows in its own trace."""
-        t0 = time.perf_counter()
         direct = len(items) == 1 and scoring.is_packed(items[0][1])
         if direct:
             out = scoring.packed_segment_topk(*items[0])
         else:
             out = scoring.merge_segment_topk(items, kb)
-        g = getattr(_worker_tl, "group", None)
-        if g is not None:
-            g.merged = not direct
-        if record:
+        _group_now().merged = not direct
+        if record and direct:
             with self._lock:
-                self._host_stall_s += time.perf_counter() - t0
-                self.stats["direct_collect_groups"] += direct
+                self.stats["direct_collect_groups"] += 1
         return out
 
     # ---- dispatch/collect pairs (device work launches in dispatch;
@@ -1895,14 +1742,12 @@ class QueryBatcher:
                         self.stats["serve_hot_rows"] += sum(hot)
                         for h in hot:
                             self._serve_hot_slots[h] += 1
-                    g = getattr(_worker_tl, "group", None)
-                    if g is not None:
-                        t = g.plan_tags
-                        t["fields"] = len(fields)
-                        t["hot_slots"] = max(t.get("hot_slots", 0), *hot)
-                        t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
+                    t = _group_now().plan_tags
+                    t["fields"] = len(fields)
+                    t["hot_slots"] = max(t.get("hot_slots", 0), *hot)
+                    t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
                     n_docs = ex.reader.segments[si].num_docs
-                    self._add_flops(sum(
+                    _group_now().add_flops(sum(
                         scoring.text_plan_flops(r, h, n_docs)
                         for r, h in zip(rare, hot)
                     ))
@@ -1969,7 +1814,7 @@ class QueryBatcher:
             with self._lock:
                 self.stats["launches"] += 1
                 self.stats["agg_jobs"] += 1
-            self._add_flops(j.plan.flops_estimate())
+            _group_now().add_flops(j.plan.flops_estimate())
             out.append(("ok", pend))
         return out
 
@@ -1982,9 +1827,7 @@ class QueryBatcher:
                 j.finish()
                 continue
             try:
-                t0 = time.perf_counter()
                 j.result = j.plan.collect(pend)  # (TopDocs, partials)
-                self._add_stall(time.perf_counter() - t0)
             except BaseException as e:
                 j.error = e
             j.finish()
@@ -2048,7 +1891,7 @@ class QueryBatcher:
         with self._lock:
             self.stats["launches"] += 1
             self.stats["rerank_jobs"] += nj
-        self._add_flops(
+        _group_now().add_flops(
             rerank_ops.rerank_flops(nj, qb, wb, col["tmax"], dims)
         )
         return ("ok", out, t0)
@@ -2066,9 +1909,7 @@ class QueryBatcher:
                     j.result = ("skip", None, None, 0.0)
                     j.finish()
             return
-        t1 = time.perf_counter()
         scores, perm = rerank_ops.unpack_rescore(out)
-        self._add_stall(time.perf_counter() - t1)
         kernel_ms = (time.perf_counter() - t0) * 1000.0
         for ji, j in enumerate(jobs):
             if j.event.is_set():
@@ -2151,7 +1992,7 @@ class QueryBatcher:
                     with self._lock:
                         self.stats["launches"] += 1
                         self.stats["fused_jobs"] += nj
-                    self._add_flops(
+                    _group_now().add_flops(
                         ivf.ann_flops(
                             nj, idx.nlist, spec.nprobe, idx.cmax, dims
                         )
@@ -2176,7 +2017,7 @@ class QueryBatcher:
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["fused_jobs"] += nj
-                self._add_flops(scoring.knn_flops(nj, n, dims))
+                _group_now().add_flops(scoring.knn_flops(nj, n, dims))
             items.append((si, n, s, d))
         return items
 
@@ -2204,12 +2045,9 @@ class QueryBatcher:
                 for ji, j in enumerate(jobs):
                     nc_rows[ji, ii] = min(j.plan.num_candidates, n)
             k_out = max(max(j.k, 1) for j in jobs)
-            t0 = time.perf_counter()
             ms, mseg, mdoc, counts = scoring.knn_merge_segment_topk(
                 [(si, s, d) for si, _, s, d in items], nc_rows, k_out
             )
-            if record:
-                self._add_stall(time.perf_counter() - t0)
             for ji, j in enumerate(jobs):
                 finite = np.isfinite(ms[ji])
                 cap = min(j.plan.k, j.k)
@@ -2360,7 +2198,7 @@ class QueryBatcher:
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["sparse_jobs"] += nj
-                self._add_flops(impact_ops.sparse_flops(tiles_scored))
+                _group_now().add_flops(impact_ops.sparse_flops(tiles_scored))
             items.append(("dev", si, (pend, pruned_flags)))
         return items
 

@@ -436,16 +436,6 @@ class TestFaultInjection:
 
 
 class TestObservability:
-    def test_device_stats_rows(self, service):
-        os.environ["ES_TPU_MESH"] = "force"
-        service.search({"query": {"match": {"body": "alpha"}}, "size": 5})
-        rows = service._batcher.device_stats()
-        assert len(rows) >= 2  # the mesh spans several devices
-        for row in rows:
-            assert set(row) == {"id", "device_busy_ms", "flops", "mfu"}
-            assert row["device_busy_ms"] >= 0.0
-            assert row["mfu"] is None or row["mfu"] >= 0.0  # null on CPU
-
     def test_nodes_stats_devices_and_mesh_block(self):
         from elasticsearch_tpu.cluster.service import ClusterService
         from elasticsearch_tpu.rest.actions import RestActions
@@ -467,10 +457,6 @@ class TestObservability:
             _, resp = actions.nodes_stats(None, {}, {})
             pipe = resp["nodes"]["node-0"]["pipeline"]
             assert pipe["mesh"]["routed"] >= 1
-            assert len(pipe["devices"]) >= 2
-            for row in pipe["devices"]:
-                assert {"id", "device_busy_ms", "flops", "mfu"} <= set(row)
-                assert row["mfu"] is None or row["mfu"] >= 0.0  # null on CPU
         finally:
             for svc in list(c.indices.values()):
                 svc.close()

@@ -11,10 +11,7 @@ from elasticsearch_tpu.cluster import ClusterService
 from elasticsearch_tpu.rest.actions import RestActions
 
 REQUIRED = {
-    "pipeline": {
-        "depth", "in_flight", "device_busy_ms", "host_stall_ms",
-        "flops", "mfu", "devices", "batching", "mesh",
-    },
+    "pipeline": {"depth", "batching", "mesh"},
     "pipeline.batching": {
         "buckets", "launches_by_bucket", "occupancy_jobs",
         "occupancy_slots", "express_lane_hits", "avg_occupancy",

@@ -384,22 +384,7 @@ class TestCloseInFlight:
             assert not t.is_alive()
 
 
-class TestRooflineStats:
-    def test_pipeline_stats_shape_and_growth(self, service):
-        b = service._batcher
-        service.search({"query": {"match": {"body": "alpha"}}, "size": 5})
-        ps = b.pipeline_stats()
-        assert set(ps) == {
-            "depth", "in_flight", "device_busy_ms", "host_stall_ms",
-            "flops", "mfu",
-        }
-        assert ps["depth"] >= 1
-        assert ps["flops"] > 0
-        assert ps["device_busy_ms"] > 0
-        # the CPU is in no peak-FLOP/s table: mfu is null there, never a
-        # figure against another chip's peak
-        assert ps["mfu"] is None or 0.0 <= ps["mfu"] < 1.0
-
+class TestPipelineStats:
     def test_nodes_stats_pipeline_block(self):
         from elasticsearch_tpu.cluster.service import ClusterService
         from elasticsearch_tpu.rest.actions import RestActions
@@ -418,10 +403,10 @@ class TestRooflineStats:
             actions = RestActions(c)
             _, resp = actions.nodes_stats(None, {}, {})
             pipe = resp["nodes"]["node-0"]["pipeline"]
+            # device time is the profiler's to measure (PERF.md §3)
+            assert set(pipe) == {"depth", "batching", "mesh"}
             assert pipe["depth"] >= 1
-            assert pipe["flops"] > 0
-            assert "mfu" in pipe and "host_stall_ms" in pipe
-            assert pipe["device_busy_ms"] > 0
+            assert pipe["batching"]["occupancy_jobs"] >= 1
         finally:
             c.close()
 

@@ -3,9 +3,7 @@
 import pytest
 
 from elasticsearch_tpu.cluster import ClusterError, ClusterService, IndexService
-from elasticsearch_tpu.common import settings as common_settings
 from elasticsearch_tpu.common.settings import (
-    PEAK_FLOPS_ENV,
     SettingsError,
     validate_index_settings,
 )
@@ -109,48 +107,3 @@ class TestStatsAndProfile:
         assert q["type"] == "MatchQuery"
         assert q["time_in_nanos"] >= 0
         assert "collector" in shards[0]["searches"][0]
-
-
-class TestPeakFlops:
-    """`peak_flops()` is asked of the device, never assumed: the
-    override wins, a malformed override is an error, a device in no
-    table has no peak and its `mfu` is null."""
-
-    def test_valid_override_is_used(self, monkeypatch):
-        monkeypatch.setenv(PEAK_FLOPS_ENV, "2.5e14")
-        assert common_settings.peak_flops() == 2.5e14
-        assert common_settings.mfu(2.5e14, 2.0) == 0.5
-
-    @pytest.mark.parametrize("raw", ["197 TFLOPs", "-1", "0", "nan"])
-    def test_malformed_override_is_an_error(self, monkeypatch, raw):
-        monkeypatch.setenv(PEAK_FLOPS_ENV, raw)
-        with pytest.raises(SettingsError):
-            common_settings.peak_flops()
-        # ...and it fails the node's start, not its first _nodes/stats
-        from elasticsearch_tpu.rest.server import ElasticsearchTpuServer
-
-        with pytest.raises(SettingsError):
-            ElasticsearchTpuServer(port=0)
-
-    def test_unknown_device_kind_has_no_peak(self, monkeypatch):
-        import jax
-
-        monkeypatch.delenv(PEAK_FLOPS_ENV, raising=False)
-        assert jax.devices()[0].device_kind not in (
-            common_settings.PEAK_FLOPS_BY_DEVICE_KIND
-        )
-        assert common_settings.peak_flops() is None
-        assert common_settings.mfu(1e12, 1.0) is None
-
-    def test_table_entry_of_the_reported_device_kind(self, monkeypatch):
-        import jax
-
-        monkeypatch.delenv(PEAK_FLOPS_ENV, raising=False)
-        monkeypatch.setitem(
-            common_settings.PEAK_FLOPS_BY_DEVICE_KIND,
-            jax.devices()[0].device_kind, 1e12,
-        )
-        assert common_settings.peak_flops() == 1e12
-        assert common_settings.PEAK_FLOPS_BY_DEVICE_KIND["TPU v5 lite"] == (
-            1.97e14
-        )

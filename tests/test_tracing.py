@@ -499,14 +499,15 @@ class TestRestSurface:
                 span = ids[span["parent_id"]]
                 chain.append(span["name"])
             assert chain == ["shard_search", "fan_out", "coordinator"], name
-        # the node's transfer counters and compile count
+        # the node's transfer counters (whether this search compiled
+        # depends on what the process built before it: the compile count
+        # is test_compile_span_on_first_use_of_a_shape_only's)
         _, stats = self._call(server, "GET", "/_nodes/stats")
         node = next(iter(stats["nodes"].values()))
         assert node["transfer"]["scoring"].keys() == {
             "h2d_count", "h2d_bytes", "d2h_count", "d2h_bytes",
         }
         assert node["transfer"]["scoring"]["d2h_count"] >= 1
-        assert node["pipeline"]["batching"]["worker_compiles"] >= 1
         # DELETE clears the ring
         status, _ = self._call(server, "DELETE", "/_internal/traces")
         assert status == 200
