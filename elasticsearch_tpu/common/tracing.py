@@ -40,8 +40,9 @@ the serving process; tags in brackets):
         queue_wait [family, cold_ms]  submit -> a worker starts the
             job's group; cold_ms = compile time that accrued meanwhile
         dispatch [family, jobs, rows, launches, express, overflow; a
-            fused serve group also fields, hot_slots, rare_tiles: the
-            most dense rows / tile slots a job and field used]
+            fused match or serve group also rare_tiles, a serve group
+            fields and hot_slots: the most tile slots / dense rows a
+            job and field used]
             -> the group's last kernel is enqueued
         inflight  -> the worker comes back to collect the group
         collect [d2h_bytes; a text or sparse group also merged: false

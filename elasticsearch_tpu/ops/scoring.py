@@ -155,7 +155,10 @@ TCHUNK = 512  # fixed tiles per row per launch
 # 1+x add, divide, w−x subtract, validity select, scatter add); a dense
 # hot-term row does ~4 per doc (no gather/scatter). top_k selection is
 # not counted (comparisons, not flops). These are estimates of USEFUL
-# work — padded rows/slots are excluded.
+# work — padded rows/slots are excluded: what a fused launch's rare-term
+# pass really scores is `rare_slots_scattered` (every row rides every
+# trip of the loop, and the last trip is padded to RARE_CHUNK), a
+# chunked launch its TCHUNK-padded chunks.
 
 FLOPS_PER_POSTING_SLOT = 6
 FLOPS_PER_DENSE_SLOT = 4
@@ -163,7 +166,10 @@ TILE_WIDTH = 128
 
 
 def text_plan_flops(n_tile_slots: int, n_hot_rows: int, n_docs: int) -> int:
-    """Estimated flops of one job's text-scoring plan on one segment."""
+    """Estimated useful flops of one job's text-scoring plan on one
+    segment: the tile slots that hold one of its postings tiles (not the
+    pad slots of the trip or chunk they ride in, nor other rows') and
+    the dense rows it reads."""
     return (
         n_tile_slots * TILE_WIDTH * FLOPS_PER_POSTING_SLOT
         + n_hot_rows * n_docs * FLOPS_PER_DENSE_SLOT
@@ -383,14 +389,16 @@ def _score_tiles_inner(doc_rows, tf_rows, tile_weights, tile_valid, inv_norm, n_
 #
 # What it costs on the attached chip (TPU v5e; PERF.md sections 5, 6):
 # a host<->device round trip is 0.4-0.5 ms (4 bytes: 0.51 ms up, 0.37 ms
-# down; PR 21), not the ~100 ms this design was first tuned against, so
-# the one upload and the one download are a tenth of a 5.5 ms request.
-# What is expensive is LEAVING this program: a query that overflows a
-# slot budget takes its whole launch group to the chunked path, where a
-# hot term is scored through its tiles (a term of rank 10 in a 1M-doc
-# segment is ~2,700 tiles = 5 chunk launches of ~1.1 ms) and a request
-# is ~27 launches, ~126 KB of uploads and two blocking downloads:
-# 48.7 ms against 5.5 (PR 25).
+# down; PR 21), not the ~100 ms this design was first tuned against. Since
+# PR 30 the program itself is 0.35-0.45 ms a one-row launch at a
+# question's 20-60 rare tiles, so the one upload in front of it and the
+# one download behind it (~1.1 ms together) are a third of a 3.3 ms
+# request and three times the kernel. What is expensive is LEAVING this
+# program: a query that overflows a slot budget takes its whole launch
+# group to the chunked path, where a hot term is scored through its
+# tiles (a term of rank 10 in a 1M-doc segment is ~2,700 tiles = 5 chunk
+# launches of ~1.1 ms) and a request is ~27 launches, ~126 KB of uploads
+# and two blocking downloads: 48.7 ms (PR 25).
 #
 # Hence the budgets. FUSED_H covers every word of a natural-language
 # question: the standard analyzer keeps stop words, `match` and
@@ -402,16 +410,23 @@ def _score_tiles_inner(doc_rows, tf_rows, tile_weights, tile_valid, inv_norm, n_
 # than 6, 0.15% more than 8, none more than 12. MS MARCO document
 # (401,729 documents of ~1,150 tokens): 9,510 body terms pass it, the
 # row budget holds 2,672 (executor_jax.DENSE_ROWS_HBM_BUDGET), and no
-# question of 5,000 holds more than 10. The budget only widens the plan
+# question of 5,000 holds more than 10. Both budgets only widen the plan
 # row (537 int32 a field): the program loops over the slots a launch
-# uses (`_add_hot_rows`), so an unused slot costs nothing. FUSED_T_RARE
-# is what a long-document shard overflows: a term left without a row
-# costs its tiles (up to ~90 there, 61 in passages); the 99.9th
-# percentile question carries 169 tiles of 256 in passages, the 99th
-# 157 in documents, where 0.02% pass 256. In `_nodes/stats`,
-# `pipeline.batching.fused_hot_slots` / `serve_hot_slots` count the
-# slots fused jobs really use, `fused_overflow_jobs` /
-# `serve_fallback_jobs` the jobs that did not fit.
+# uses, hot rows one at a time (`_add_hot_rows`) and rare tiles
+# RARE_CHUNK at a time (`_add_rare_tiles`), so an unused hot slot costs
+# nothing and unused tile slots cost at most the padding of the last
+# chunk (a tile ~2 us where the one pass over 256 slots cost 0.84 ms
+# whatever it held). FUSED_T_RARE is what a long-document shard
+# overflows: a term left without a row costs its tiles (up to ~90
+# there, 61 in passages); the 99.9th percentile question carries 169
+# tiles of 256 in passages, the 99th 157 in documents, where 0.02% pass
+# 256. In `_nodes/stats`, `pipeline.batching.fused_hot_slots` /
+# `serve_hot_slots` count the hot slots fused jobs really use,
+# `thread_pool.search.fused_rare_tiles` / `serve_rare_tiles` their
+# tiles, `pipeline.batching.rare_slots_scattered` of
+# `rare_slots_budget` the tile slots their launches walked,
+# `fused_overflow_jobs` / `serve_fallback_jobs` the jobs that did not
+# fit.
 # ---------------------------------------------------------------------------
 
 FUSED_T_RARE = 256  # rare tile slots per query (fixed compile shape)
@@ -550,7 +565,7 @@ class FusedScorer:
             self.inv_norm,
             live if live is not None else self.live,
             self.dense,
-            jax.device_put(packed),
+            packed,  # the jitted call uploads it: no eager device_put
             self.wide,
             t_rare=self.t_rare,
             n_hot=self.n_hot_slots,
@@ -670,6 +685,91 @@ def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
     return jax.lax.fori_loop(first, used, slot, (acc, cnt))
 
 
+# Tile slots a trip of `_add_rare_tiles` gathers, scores and scatters.
+# Measured on the TPU v5e at the passage cell's shapes (1M docs, one-row
+# launch, device ms by tiles used 16 / 32 / 64 / 128 / 256; PERF.md
+# section 6, PR 30): chunks of 8 0.350 / 0.386 / 0.458 / 0.602 / 0.889,
+# of 16 0.347 / 0.379 / 0.444 / 0.574 / 0.834, of 32 0.379 / 0.376 /
+# 0.437 / 0.559 / 0.805, the one pass over all 256 slots 1.04-0.98. A
+# trip costs its elements (16 tiles: scatter 15.9 us, norm gather 14.7,
+# ~2 us of everything else), so the chunk only sets how much padding
+# the last trip carries against ~2 us a trip: 16 is within 4% of the
+# best at every count, and questions carry a median of ~23 tiles.
+RARE_CHUNK = 16
+
+
+def rare_slots_scattered(rows: int, tiles) -> int:
+    """Tile slots `_add_rare_tiles` gathers and scatters in one launch
+    of `rows` query rows (pad rows too) over one field, `tiles` the
+    tile count of each job's plan there: every row rides every trip."""
+    trips = -(-max(tiles, default=0) // RARE_CHUNK)
+    return rows * trips * RARE_CHUNK
+
+
+def _add_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
+                    signed):
+    """Adds a launch's rare-term postings tiles to its accumulators,
+    which are FLAT: `acc` f32[B * (n + 1)] and `cnt` i32[B * (n + 1)] or
+    None (no count plane) hold the B rows' planes end to end, row b's
+    document d at b * (n + 1) + d and the spill of its pad postings at
+    b * (n + 1) + n (`_doc_planes` gives the [B, n] view afterwards).
+    `rare_ti` i32[B, T] are tile ids into `doc_ids` / `tfs` (-1 =
+    unused), `rare_tw` f32[B, T] their weights. With `signed` a weight's
+    sign says whether the term counts (w > 0) and |w| scores
+    (MultiFusedScorer); without, every posting counts.
+
+    A loop over the slots the launch USES, RARE_CHUNK at a trip, not
+    one pass over the budget T: everything that is proportional to
+    slots (the gathers of tile rows and norms, the score, the scatter-
+    adds) is inside it, and `pack_plans` fills slots from 0 up, so the
+    trip count is ceil(highest used slot of any row / RARE_CHUNK); no
+    tile, no trip. Chunks go in slot order and a chunk's postings in
+    slot order, so a document's contributions are added in the order
+    the one-pass form added them: the same float32 sums (bit-equal to
+    it on the chip in every case measured).
+
+    Why flat: the TPU's scatter works on the flat plane, and a loop
+    that carries [B, n + 1] planes is relaid to it and back every trip
+    (117 us a trip of 16 tiles at one row where this form takes 32;
+    a 256-tile launch 2.16 ms against 0.83, the one pass 0.98)."""
+    n = inv_norm.shape[0]
+    B, T = rare_ti.shape
+    C = RARE_CHUNK
+    pad = -T % C  # a budget that is no multiple of the chunk: pad slots
+    rare_ti = jnp.pad(rare_ti, ((0, 0), (0, pad)), constant_values=-1)
+    rare_tw = jnp.pad(rare_tw, ((0, 0), (0, pad)))
+    slots = jnp.arange(1, rare_ti.shape[1] + 1, dtype=jnp.int32)
+    used = jnp.max(jnp.where(rare_ti >= 0, slots, 0))
+    last_tile = doc_ids.shape[0] - 1
+    row_base = (jnp.arange(B, dtype=jnp.int32) * (n + 1))[:, None, None]
+
+    def chunk(i, carry):
+        acc, cnt = carry
+        ti = jax.lax.dynamic_slice_in_dim(rare_ti, i * C, C, axis=1)
+        tw = jax.lax.dynamic_slice_in_dim(rare_tw, i * C, C, axis=1)
+        safe = jnp.clip(ti, 0, last_tile)
+        rows_d = doc_ids[safe]  # [B, C, 128]
+        rows_t = tfs[safe]
+        valid = (rows_d >= 0) & (ti >= 0)[:, :, None]
+        tgt = (jnp.where(valid, rows_d, n) + row_base).ravel()
+        inv = inv_norm[jnp.clip(rows_d, 0, n - 1)]
+        w = (jnp.abs(tw) if signed else tw)[:, :, None]
+        s = w - w / (jnp.float32(1.0) + rows_t.astype(jnp.float32) * inv)
+        acc = acc.at[tgt].add(jnp.where(valid, s, 0.0).ravel())
+        if cnt is not None:
+            counted = valid & (tw > 0)[:, :, None] if signed else valid
+            cnt = cnt.at[tgt].add(counted.astype(jnp.int32).ravel())
+        return acc, cnt
+
+    return jax.lax.fori_loop(0, (used + C - 1) // C, chunk, (acc, cnt))
+
+
+def _doc_planes(flat, rows: int, n: int):
+    """[rows, n] of a flat accumulator of `_add_rare_tiles`: each row's
+    documents, without its spill slot."""
+    return flat.reshape(rows, n + 1)[:, :n]
+
+
 @functools.partial(
     jax.jit, static_argnames=("t_rare", "n_hot", "k", "with_cnt")
 )
@@ -683,26 +783,16 @@ def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, wide=None, *,
     hot_w = jax.lax.bitcast_convert_type(plan[:, 2 * T + H : 2 * T + 2 * H], jnp.float32)
     msm = plan[:, 2 * T + 2 * H]
 
-    # ---- rare terms: tile gather + scatter-add ----
-    tile_ok = rare_ti >= 0
-    rows_d = doc_ids[jnp.clip(rare_ti, 0, doc_ids.shape[0] - 1)]  # [B,T,128]
-    rows_t = tfs[jnp.clip(rare_ti, 0, doc_ids.shape[0] - 1)]
-    valid = (rows_d >= 0) & tile_ok[:, :, None]
-    tgt = jnp.where(valid, rows_d, n)
-    inv = inv_norm[jnp.clip(rows_d, 0, n - 1)]
-    w = rare_tw[:, :, None]
-    s = w - w / (jnp.float32(1.0) + rows_t.astype(jnp.float32) * inv)
-    s = jnp.where(valid, s, 0.0)
-    acc = jnp.zeros((plan.shape[0], n + 1), jnp.float32)
-    acc = jax.vmap(lambda a, d, v: a.at[d.ravel()].add(v.ravel()))(acc, tgt, s)
-    acc = acc[:, :n]
-    cnt = None
+    # ---- rare terms: tile gather + scatter-add, the slots in use ----
+    B = plan.shape[0]
+    acc = jnp.zeros(B * (n + 1), jnp.float32)
+    cnt = jnp.zeros(B * (n + 1), jnp.int32) if with_cnt else None
+    acc, cnt = _add_rare_tiles(
+        acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw, signed=False
+    )
+    acc = _doc_planes(acc, B, n)
     if with_cnt:
-        cnt = jnp.zeros((plan.shape[0], n + 1), jnp.int32)
-        cnt = jax.vmap(
-            lambda c, d, v: c.at[d.ravel()].add(v.ravel().astype(jnp.int32))
-        )(cnt, tgt, valid)
-        cnt = cnt[:, :n]
+        cnt = _doc_planes(cnt, B, n)
 
     # ---- hot terms: dense per-doc tf rows, pure vector math ----
     acc, cnt = _add_hot_terms(
@@ -830,8 +920,8 @@ class MultiFusedScorer:
             tuple(p["inv_norm"] for p in self.parts),
             tuple(p["dense"] for p in self.parts),
             live if live is not None else self.live,
-            jax.device_put(packed),
-            np.float32(tie),  # the jitted call uploads it: no eager convert
+            packed,  # the jitted call uploads both: no eager device_put
+            np.float32(tie),
             tuple(p["wide"] for p in self.parts),
             t_rare=self.t_rare,
             n_hot=self.n_hot_slots,
@@ -862,47 +952,40 @@ def _fused_query_mf(
     sec = 2 * T + 2 * H
     B = plan.shape[0]
     msm = plan[:, F * sec]
-    cnt = jnp.zeros((B, n + 1), jnp.int32)
+    wide_f = wide_f or (None,) * F
+
+    def f32(x):
+        return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+    def section(f):
+        """(rare_ti, rare_tw, hot_ids, hot_w) of field f's plan section."""
+        base = f * sec
+        return (
+            plan[:, base: base + T],
+            f32(plan[:, base + T: base + 2 * T]),
+            plan[:, base + 2 * T: base + 2 * T + H],
+            f32(plan[:, base + 2 * T + H: base + sec]),
+        )
+
+    # rare terms of every field first: the tile slots in use, into flat
+    # planes (one count plane over the fields); |w| scores, w>0 counts
+    cnt = jnp.zeros(B * (n + 1), jnp.int32)
     accs = []
     for f in range(F):
-        base = f * sec
-        rare_ti = plan[:, base: base + T]
-        rare_tw = jax.lax.bitcast_convert_type(
-            plan[:, base + T: base + 2 * T], jnp.float32
+        rare_ti, rare_tw, _, _ = section(f)
+        acc, cnt = _add_rare_tiles(
+            jnp.zeros(B * (n + 1), jnp.float32), cnt, doc_ids_f[f],
+            tfs_f[f], inv_norm_f[f], rare_ti, rare_tw, signed=True,
         )
-        hot_ids = plan[:, base + 2 * T: base + 2 * T + H]
-        hot_w = jax.lax.bitcast_convert_type(
-            plan[:, base + 2 * T + H: base + sec], jnp.float32
+        accs.append(_doc_planes(acc, B, n))
+    cnt = _doc_planes(cnt, B, n)
+    # then each field's hot terms: dense rows; |w| scores, w>0 counts
+    for f in range(F):
+        _, _, hot_ids, hot_w = section(f)
+        accs[f], cnt = _add_hot_terms(
+            accs[f], cnt, dense_f[f], wide_f[f], inv_norm_f[f],
+            hot_ids, hot_w, signed=True,
         )
-        doc_ids, tfs, inv_norm, dense = (
-            doc_ids_f[f], tfs_f[f], inv_norm_f[f], dense_f[f]
-        )
-        # rare terms: tile gather + scatter-add; |w| scores, w>0 counts
-        tile_ok = rare_ti >= 0
-        rows_d = doc_ids[jnp.clip(rare_ti, 0, doc_ids.shape[0] - 1)]
-        rows_t = tfs[jnp.clip(rare_ti, 0, doc_ids.shape[0] - 1)]
-        valid = (rows_d >= 0) & tile_ok[:, :, None]
-        tgt = jnp.where(valid, rows_d, n)
-        inv = inv_norm[jnp.clip(rows_d, 0, n - 1)]
-        w = jnp.abs(rare_tw)[:, :, None]
-        s = w - w / (jnp.float32(1.0) + rows_t.astype(jnp.float32) * inv)
-        s = jnp.where(valid, s, 0.0)
-        acc = jnp.zeros((B, n + 1), jnp.float32)
-        acc = jax.vmap(lambda a, d, v: a.at[d.ravel()].add(v.ravel()))(
-            acc, tgt, s
-        )
-        counted = valid & (rare_tw > 0)[:, :, None]
-        cnt = jax.vmap(
-            lambda c, d, v: c.at[d.ravel()].add(v.ravel().astype(jnp.int32))
-        )(cnt, tgt, counted)
-        acc = acc[:, :n]
-        # hot terms: dense rows; |w| scores, w>0 counts
-        acc, cnt = _add_hot_terms(
-            acc, cnt, dense, None if wide_f is None else wide_f[f],
-            inv_norm, hot_ids, hot_w, signed=True,
-        )
-        accs.append(acc)
-    cnt = cnt[:, :n]
     if F == 1:
         combined = accs[0]
     elif combine == "sum":

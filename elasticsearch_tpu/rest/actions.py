@@ -591,6 +591,7 @@ class RestActions:
             "shed_dead_jobs": 0, "cancelled_jobs": 0,
             "serve_fallback_jobs": 0, "serve_launches": 0,
             "serve_rare_tiles": 0, "serve_hot_rows": 0,
+            "fused_rare_tiles": 0,
         }
         # the serving pipeline: the workers' in-flight ring bound, the
         # continuous-batching block and the mesh counters. Device time is
@@ -610,6 +611,10 @@ class RestActions:
             # groups whose result was downloaded as the fused kernel
             # packed it (one scoring segment: no merge program)
             "direct_collect_groups": 0,
+            # tile slots the fused launches' rare-term pass scattered,
+            # of the slots of their budget (rows x 256 a field)
+            "rare_slots_scattered": 0,
+            "rare_slots_budget": 0,
             "warmup_failures": 0,
             "fused_hot_slots": {},
             "serve_hot_slots": {},
@@ -645,6 +650,8 @@ class RestActions:
                 batching["direct_collect_groups"] += bs[
                     "direct_collect_groups"
                 ]
+                for k in ("rare_slots_scattered", "rare_slots_budget"):
+                    batching[k] += bs[k]
                 batching["warmup_failures"] += bs["warmup_failures"]
                 for hist in ("fused_hot_slots", "serve_hot_slots"):
                     for h, n in bs[hist].items():
@@ -867,6 +874,8 @@ class RestActions:
                             "serve_launches": batch["serve_launches"],
                             "serve_rare_tiles": batch["serve_rare_tiles"],
                             "serve_hot_rows": batch["serve_hot_rows"],
+                            # the match family's twin of serve_rare_tiles
+                            "fused_rare_tiles": batch["fused_rare_tiles"],
                         }
                     },
                     "uptime_in_millis": int(

@@ -558,9 +558,9 @@ class _Group:
         self.launches = 0
         self.flops = 0
         self.overflow = False  # the group left the fused kernel
-        # a serve group's fused plans, for its `dispatch` span: `fields`,
-        # `hot_slots` (most dense rows a job and field used), `rare_tiles`
-        # (most tile slots a job and field used)
+        # a group's fused plans, for its `dispatch` span: `rare_tiles`
+        # (most tile slots a job and field used) and, of a serve group,
+        # `fields` and `hot_slots` (most dense rows a job and field used)
         self.plan_tags: Dict[str, int] = {}
         # a text or sparse group's `collect` span: whether its candidates
         # went through the merge program (`_group_topk`), else None
@@ -903,6 +903,14 @@ class QueryBatcher:
             "serve_launches": 0,
             "serve_rare_tiles": 0,
             "serve_hot_rows": 0,
+            # the match family's twin of `serve_rare_tiles`, and how far
+            # the rare-term pass's loop engages, over fused launches of
+            # both families and their fields: tile slots the launches
+            # gathered and scattered (rows x the trips' slots) of those
+            # they would have at the whole budget (rows x t_rare)
+            "fused_rare_tiles": 0,
+            "rare_slots_scattered": 0,
+            "rare_slots_budget": 0,
             # times a kNN group and a text (match/serve) group were in
             # flight on device simultaneously — the observable proof
             # that hybrid legs overlap instead of serializing
@@ -1314,6 +1322,14 @@ class QueryBatcher:
         finally:
             ctx.pending = []
 
+    def _count_rare_slots(self, rows: int, t_rare: int, tiles: List[int]):
+        """One fused launch's rare-term pass over one field (`tiles`: a
+        job's tile count each), under the lock: the slots it scattered
+        of the slots of its budget."""
+        self.stats["rare_slots_scattered"] += scoring.rare_slots_scattered(
+            rows, tiles)
+        self.stats["rare_slots_budget"] += rows * t_rare
+
     def _count_overflow(self, fplans: list):
         """Jobs whose plan does not fit the fused kernel's slots send
         their whole group down the slower path: counted, and flagged on
@@ -1350,6 +1366,8 @@ class QueryBatcher:
             jobs, slots = self._occ_jobs, self._occ_slots
             express = self.stats["express_lane_hits"]
             direct = self.stats["direct_collect_groups"]
+            scattered = self.stats["rare_slots_scattered"]
+            budget = self.stats["rare_slots_budget"]
             warm_failed = self.stats["warmup_failures"]
             hot_slots = {
                 str(h): n for h, n in enumerate(self._fused_hot_slots)
@@ -1368,6 +1386,10 @@ class QueryBatcher:
             "avg_occupancy": round(jobs / slots, 4) if slots else 0.0,
             "express_lane_hits": express,
             "direct_collect_groups": direct,
+            # tile slots fused launches' rare-term pass scattered, and
+            # the slots of their whole budget (rows x t_rare a field)
+            "rare_slots_scattered": scattered,
+            "rare_slots_budget": budget,
             "warmup_failures": warm_failed,
             # fused match jobs by dense hot-term slots used (0..FUSED_H)
             "fused_hot_slots": hot_slots,
@@ -1501,11 +1523,16 @@ class QueryBatcher:
                         fplans, kb, with_cnt, staging=staging, rows=rows
                     )
                     if record:
+                        rare = [len(p[0]) for p in fplans]
                         with self._lock:
                             self.stats["launches"] += 1
                             self.stats["fused_jobs"] += nj
+                            self.stats["fused_rare_tiles"] += sum(rare)
+                            self._count_rare_slots(rows, fs.t_rare, rare)
                             for p in fplans:
                                 self._fused_hot_slots[len(p[2])] += 1
+                        t = _group_now().plan_tags
+                        t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
                         _group_now().add_flops(sum(
                             scoring.text_plan_flops(
                                 len(p[0]), len(p[2]), n_docs
@@ -1740,6 +1767,9 @@ class QueryBatcher:
                         self.stats["serve_launches"] += 1
                         self.stats["serve_rare_tiles"] += sum(rare)
                         self.stats["serve_hot_rows"] += sum(hot)
+                        for f in range(len(fields)):  # secs: job-major
+                            self._count_rare_slots(
+                                rows, fs.t_rare, rare[f::len(fields)])
                         for h in hot:
                             self._serve_hot_slots[h] += 1
                     t = _group_now().plan_tags

@@ -123,11 +123,9 @@ def _plan_width(n_fields: int) -> int:
     return n_fields * (2 * scoring.FUSED_T_RARE + 2 * scoring.FUSED_H) + 1
 
 
-@pytest.mark.parametrize("rows", [1, 32])
-def test_fused_match_program(one_chip, rows):
-    """FusedScorer's program (`match` on one field)."""
-    s = _on(one_chip)
-    compiled = scoring._fused_query.lower(
+def _lower_match(s, rows: int):
+    """FusedScorer's program (`match` on one field), lowered."""
+    return scoring._fused_query.lower(
         s((BODY_TILES, TILE), jnp.int32),
         s((BODY_TILES, TILE), jnp.int32),
         s((N_DOCS,), jnp.float32),
@@ -138,31 +136,37 @@ def test_fused_match_program(one_chip, rows):
         n_hot=scoring.FUSED_H,
         k=16,
         with_cnt=False,
-    ).compile()
-    _fits(compiled)
+    )
 
 
-def test_fused_multi_field_program(one_chip):
+def _lower_multi_field(s, rows: int):
     """MultiFusedScorer's program as `multi_match` best_fields sends it
-    (title+body, "max_tie"); `bool` rides the same program over one
-    field with "sum", a strict subset of this one."""
-    s = _on(one_chip)
+    (title+body, "max_tie"), lowered; `bool` rides the same program over
+    one field with "sum", a strict subset of this one."""
     tiles = (TITLE_TILES, BODY_TILES)
     hot = (TITLE_HOT, BODY_HOT)
-    compiled = scoring._fused_query_mf.lower(
+    return scoring._fused_query_mf.lower(
         tuple(s((t, TILE), jnp.int32) for t in tiles),
         tuple(s((t, TILE), jnp.int32) for t in tiles),
         tuple(s((N_DOCS,), jnp.float32) for _ in tiles),
         tuple(s((h, N_DOCS), jnp.uint8) for h in hot),
         None,
-        s((32, _plan_width(2)), jnp.int32),
+        s((rows, _plan_width(2)), jnp.int32),
         s((), jnp.float32),
         t_rare=scoring.FUSED_T_RARE,
         n_hot=scoring.FUSED_H,
         k=16,
         combine="max_tie",
-    ).compile()
-    _fits(compiled)
+    )
+
+
+@pytest.mark.parametrize("rows", [1, 32])
+def test_fused_match_program(one_chip, rows):
+    _fits(_lower_match(_on(one_chip), rows).compile())
+
+
+def test_fused_multi_field_program(one_chip):
+    _fits(_lower_multi_field(_on(one_chip), 32).compile())
 
 
 @pytest.mark.parametrize("rows", [1, 32])
@@ -188,6 +192,33 @@ def test_fused_multi_field_program_at_document_length(one_chip, rows):
         combine="max_tie",
     ).compile()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("family", ["match", "serve"])
+def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
+    """The rare-term pass compiles as a `while` over chunks of
+    RARE_CHUNK tiles inside the family's one program a row bucket: no
+    operand as wide as the slot budget (rows x 256 tiles x 128 postings)
+    is left, and nothing but the plan's shape decides the program, so
+    the programs `_maybe_warm` compiles a family are its row buckets, as
+    before the loop (no static argument was added to either program)."""
+    import inspect
+
+    rows = 1
+    fn, statics, lower = {
+        "match": (scoring._fused_query,
+                  {"t_rare", "n_hot", "k", "with_cnt"}, _lower_match),
+        "serve": (scoring._fused_query_mf,
+                  {"t_rare", "n_hot", "k", "combine"}, _lower_multi_field),
+    }[family]
+    lowered = lower(_on(one_chip), rows)
+    params = inspect.signature(fn.__wrapped__).parameters.values()
+    assert {p.name for p in params if p.kind == p.KEYWORD_ONLY} == statics
+    text = lowered.compile().as_text()
+    budget = rows * scoring.FUSED_T_RARE * TILE
+    chunk = rows * scoring.RARE_CHUNK * TILE
+    assert f"[{budget}]" not in text and f",{budget}]" not in text
+    assert " while(" in text and f"[{chunk}]" in text
 
 
 def test_cross_segment_merges(one_chip):

@@ -772,6 +772,7 @@ class TestBatchingStats:
             "occupancy_slots", "avg_occupancy", "express_lane_hits",
             "warmup_failures", "worker_compile_ms", "worker_compiles",
             "fused_hot_slots", "serve_hot_slots", "direct_collect_groups",
+            "rare_slots_scattered", "rare_slots_budget",
         }
         assert bs["warmup_failures"] == 0
         assert bs["worker_compile_ms"] > 0.0  # this batcher compiled

@@ -244,11 +244,15 @@ class TestBatcherJobSpans:
         assert end_ns(c) <= by["fetch"]["start_ns"]
         assert end_ns(by["fetch"]) <= end_ns(sh) <= t_after
         assert q["tags"] == {"family": family, "cold_ms": 0.0}
+        # a fused match group also says how many tile slots it carried
+        fused = family == "match" and not overflow
         assert d["tags"] == {
             "family": family, "jobs": 1, "rows": 1,
             "launches": d["tags"]["launches"], "express": True,
             "overflow": overflow,
+            **({"rare_tiles": d["tags"]["rare_tiles"]} if fused else {}),
         }
+        assert not fused or d["tags"]["rare_tiles"] >= 1
         assert d["tags"]["launches"] >= 1
         assert c["tags"]["d2h_bytes"] > 0
         if family == "match":
