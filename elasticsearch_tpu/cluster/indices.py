@@ -357,6 +357,68 @@ def _reader_locations(ex) -> Dict[str, Tuple[int, int]]:
     return locs
 
 
+class _Ranked(list):
+    """One retriever node's ranked [(doc_id, score)], with what its
+    search knew beside the list: `total`, the node's `hits.total`
+    ({"value", "relation"}; None where nothing tracked it), and `where`,
+    {doc_id: (segment, local_doc)} of the hits that came back with their
+    identity in the request's pinned reader."""
+
+    def __init__(self, hits=(), total: Optional[dict] = None, where=None):
+        super().__init__(hits)
+        self.total = total
+        self.where: Dict[str, Tuple[int, int]] = where or {}
+
+    @classmethod
+    def of_response(cls, resp: dict) -> "_Ranked":
+        """A sub-search's page and the total it tracked."""
+        return cls(
+            ((h["_id"], h["_score"]) for h in resp["hits"]["hits"]),
+            total=resp["hits"].get("total"),
+        )
+
+
+def _match_plan_misses(reader, plan, locs) -> int:
+    """How many of the documents `locs` [(segment, local_doc)] a flat
+    match plan does not match: fewer than `msm` of its terms hold them,
+    read from the segments' own postings (a term's docs are sorted)."""
+    import numpy as np
+
+    by_seg: Dict[int, list] = {}
+    for si, local in locs:
+        by_seg.setdefault(si, []).append(local)
+    misses = 0
+    for si, locals_ in by_seg.items():
+        docs = np.asarray(locals_, np.int64)
+        held = np.zeros(len(docs), np.int64)
+        pf = reader.segments[si].postings.get(plan.field)
+        for term in plan.terms if pf is not None else ():
+            tid = pf.term_id(term)
+            if tid < 0 or not pf.term_df[tid]:
+                continue
+            term_docs = pf.term_docs(tid)
+            at = np.minimum(
+                np.searchsorted(term_docs, docs), len(term_docs) - 1
+            )
+            held += term_docs[at] == docs
+        misses += int((held < plan.msm).sum())
+    return misses
+
+
+def _tracked_total(value: int, relation: str, tth) -> Optional[dict]:
+    """`hits.total` under the request's `track_total_hits` (default
+    10,000: exact up to it, then a `gte` bound; false: no total)."""
+    if tth is False:
+        return None
+    if tth is True:
+        return {"value": value, "relation": relation}
+    limit = int(tth)
+    return {
+        "value": min(value, limit),
+        "relation": "gte" if value > limit else relation,
+    }
+
+
 class IndexService:
     """The shard set of one index (see module docstring for the two
     deployment shapes)."""
@@ -3336,6 +3398,12 @@ class IndexService:
                 **({"prof_out": prof} if prof is not None else {}),
             )
         m_retr = time.perf_counter_ns()
+        # what upstream reports for a ranked search: the total of the
+        # queries behind the ranks, not the length of the ranked window
+        found = getattr(ranked, "total", None) or {
+            "value": len(ranked), "relation": "eq",
+        }
+        where = getattr(ranked, "where", {})
         if "rescore" in body and ranked:
             from ..search import rescorer
 
@@ -3354,14 +3422,18 @@ class IndexService:
 
         out_hits = []
         for doc_id, score in page:
-            src = self._fetch_source_pinned(doc_id, pins)
             entry = {
                 "_index": self.name,
                 "_id": doc_id,
                 "_score": float(score),
             }
-            if src is not None and source_spec is not False:
-                filtered = filter_source(src, source_spec)
+            if source_spec is not False:
+                # a page that asks for no source reads none: nothing of
+                # a request may grow with the shard
+                src = self._fetch_source_pinned(doc_id, pins, where)
+                filtered = (
+                    None if src is None else filter_source(src, source_spec)
+                )
                 if filtered is not None:
                     entry["_source"] = filtered
             out_hits.append(entry)
@@ -3370,26 +3442,33 @@ class IndexService:
         if acc is not None:
             acc["fetch_ns"] += m_fetch - m_resc
         took = int((time.perf_counter() - t0) * 1000)
+        n = self.num_shards
         if tr is not None:
+            # the root every search path has, tiled by this path's phases
             root = tr.add_span(
-                "retriever_search", tns, m_fetch,
-                index=self.name, took_ms=took,
+                "coordinator", tns, m_fetch,
+                index=self.name, shards=n, took_ms=took,
             )
             tr.add_span(
                 "retriever", tns, m_retr, parent_id=root, span_id=retr_id
             )
             tr.add_span("rescore", m_retr, m_resc, parent_id=root)
             tr.add_span("fetch", m_resc, m_fetch, parent_id=root)
-        n = self.num_shards
+        hits_obj: dict = {
+            "max_score": max((s for _, s in page), default=None),
+            "hits": out_hits,
+        }
+        total = _tracked_total(
+            int(found["value"]), found["relation"],
+            body.get("track_total_hits", 10_000),
+        )
+        if total is not None:
+            hits_obj = {"total": total, **hits_obj}
         resp = {
             "took": took,
             "timed_out": False,
             "_shards": {"total": n, "successful": n, "skipped": 0, "failed": 0},
-            "hits": {
-                "total": {"value": len(ranked), "relation": "eq"},
-                "max_score": max((s for _, s in page), default=None),
-                "hits": out_hits,
-            },
+            "hits": hits_obj,
         }
         if prof is not None:
             resp["profile"] = {
@@ -3410,15 +3489,19 @@ class IndexService:
 
     # ---- hybrid retrieval: concurrent legs + RRF fusion ----
 
-    def _fetch_source_pinned(self, doc_id: str, pins):
+    def _fetch_source_pinned(self, doc_id: str, pins, where=None):
         """Fetch-phase source read from the PINNED reader generation
         (the same snapshot the candidates were scored against); realtime
-        get is the fallback for unpinned/distributed requests."""
+        get is the fallback for unpinned/distributed requests. `where`
+        holds the hits that came back from their leg with (segment,
+        local_doc); only a hit it lacks costs the reader's id table."""
         if pins:
             sid = route_shard_id(doc_id, self.num_shards)
             pin = pins[sid] if sid < len(pins) else None
             if pin is not None and not isinstance(pin, dict):
-                loc = _reader_locations(pin).get(doc_id)
+                loc = (where or {}).get(doc_id)
+                if loc is None:
+                    loc = _reader_locations(pin).get(doc_id)
                 if loc is not None:
                     return pin.reader.segments[loc[0]].sources[loc[1]]
                 return None  # not in the pinned generation
@@ -3463,7 +3546,7 @@ class IndexService:
                 prof_out.setdefault("legs", []).append(
                     {"label": "bm25", "profile": resp["profile"]}
                 )
-            return [(h["_id"], h["_score"]) for h in resp["hits"]["hits"]]
+            return _Ranked.of_response(resp)
         if kind == "knn":
             knn_params = dict(params)
             if extra_filter is not None:
@@ -3482,7 +3565,7 @@ class IndexService:
                 prof_out.setdefault("legs", []).append(
                     {"label": "knn", "profile": resp["profile"]}
                 )
-            return [(h["_id"], h["_score"]) for h in resp["hits"]["hits"]]
+            return _Ranked.of_response(resp)
         if kind == "rrf":
             return self._run_rrf(
                 params, window, size, extra_filter, pins, prof_out=prof_out
@@ -3498,7 +3581,6 @@ class IndexService:
         rank_constant = int(params.get("rank_constant", 60))
         window2 = int(params.get("rank_window_size", max(window, size)))
         children = params.get("retrievers", [])
-        t_start = time.perf_counter()
         t_start_ns = time.perf_counter_ns()
         # the `rrf` span and its `leg:<label>` children are written
         # below; their ids are reserved so what each leg submits or
@@ -3518,19 +3600,23 @@ class IndexService:
                         profiled=prof_out is not None,
                     )
                 )
-        legs = [self._wait_leg(h, window2, extra_filter, t_start, pins)
+        legs = [self._wait_leg(h, window2, extra_filter, t_start_ns, pins)
                 for h in handles]
-        t_fuse = time.perf_counter()
+        # the last leg's waiter is awake: what follows is the fuse
+        t_fuse_ns = time.perf_counter_ns()
         fused: Optional[List[tuple]] = None
         device = False
+        moved = (0, 0)  # the fuse's upload and download, bytes
         executors = {id(l["ex"]) for l in legs if l["ex"] is not None}
         if (
             len(legs) >= 2
             and all(l["td"] is not None for l in legs)
             and len(executors) == 1
         ):
-            fused = self._fuse_legs_device(legs, window2, rank_constant)
-            device = fused is not None
+            fused, *moved = self._fuse_legs_device(
+                legs, window2, rank_constant
+            )
+            device = True
         if fused is None:
             # host fallback/oracle: dict accumulation, tie-break on
             # ascending doc id string (pre-concurrency semantics)
@@ -3543,11 +3629,21 @@ class IndexService:
             fused = sorted(acc.items(), key=lambda kv: (-kv[1], kv[0]))[
                 :window2
             ]
-        t_end = time.perf_counter()
+        t_end_ns = time.perf_counter_ns()
+        fuse_ns = t_end_ns - t_fuse_ns
+        # the legs' hits that came back with (segment, local_doc): what
+        # the total's membership test and the fetch read, instead of a
+        # table over every id of the shard
+        where = {
+            h.doc_id: (h.segment, h.local_doc)
+            for leg in legs if leg["td"] is not None
+            for h in leg["td"].hits
+        }
+        fused = _Ranked(fused, self._rrf_total(legs, where), where)
         with self._rrf_lock:
             st = self.rrf_stats
             st["searches"] += 1
-            st["fuse_ms"] += (t_end - t_fuse) * 1000.0
+            st["fuse_ms"] += fuse_ns / 1e6
             st["device_fused" if device else "host_fused"] += 1
             for leg in legs:
                 if leg["label"] in ("bm25", "knn", "sparse"):
@@ -3567,23 +3663,77 @@ class IndexService:
                 if leg.get("sub_profile"):
                     entry["profile"] = leg["sub_profile"]
                 out_legs.append(entry)
-            prof_out["fuse_ns"] = prof_out.get("fuse_ns", 0) + int(
-                (t_end - t_fuse) * 1e9
-            )
+            prof_out["fuse_ns"] = prof_out.get("fuse_ns", 0) + fuse_ns
             prof_out["fused_on_device"] = device
         if tr is not None:
-            t_end_ns = time.perf_counter_ns()
             tr.add_span(
-                "rrf", t_start_ns, t_end_ns, span_id=rrf_id,
+                "rrf", t_start_ns, time.perf_counter_ns(), span_id=rrf_id,
                 index=self.name, legs=len(legs), device_fused=device,
             )
             for leg, leg_id in zip(legs, leg_ids):
+                # a leg ends at its own completion mark, whatever leg
+                # the request thread was waiting for meanwhile
                 tr.add_span(
-                    f"leg:{leg['label']}", t_start_ns,
-                    t_start_ns + int(leg["ms"] * 1e6), parent_id=rrf_id,
-                    span_id=leg_id, mode=leg.get("mode", "?"),
+                    f"leg:{leg['label']}", t_start_ns, leg["end_ns"],
+                    parent_id=rrf_id, span_id=leg_id,
+                    mode=leg.get("mode", "?"),
                 )
+            tr.add_span(
+                "fuse", t_fuse_ns, t_end_ns, parent_id=rrf_id,
+                device=device, window=window2,
+                h2d_bytes=moved[0], d2h_bytes=moved[1],
+            )
         return fused
+
+    def _rrf_total(self, legs: List[dict], where: dict) -> dict:
+        """`hits.total` of a ranked search as upstream counts it: the
+        documents its combined query matches — one that any leg's query
+        matches, once — before `track_total_hits` cuts it. A leg whose
+        ranked list is its whole match set (a kNN leg's k, a text query
+        of few matches) counts by identity. A leg known only by its
+        count (a text query matching more than the window) adds the
+        other legs' documents it does not match, which a flat match plan
+        tells from the segments' postings. Anything else (two counted
+        legs, a counted leg with no such plan, hits without identity) is
+        reported as what is certain: the largest lower bound, `gte`."""
+        from ..search.batcher import MatchPlan
+
+        listed: set = set()
+        counted = []
+        for leg in legs:
+            total = getattr(leg["ranked"], "total", None)
+            if total is None:
+                total = {"value": len(leg["ranked"]), "relation": "gte"}
+            if (
+                total["relation"] == "eq"
+                and total["value"] == len(leg["ranked"])
+            ):
+                listed.update(doc_id for doc_id, _ in leg["ranked"])
+            else:
+                counted.append((leg, int(total["value"]), total["relation"]))
+        if not counted:
+            return {"value": len(listed), "relation": "eq"}
+        if len(counted) == 1:
+            leg, value, relation = counted[0]
+            others = listed.difference(doc_id for doc_id, _ in leg["ranked"])
+            if not others:
+                return {"value": value, "relation": relation}
+            plan = leg["plan"]
+            if isinstance(plan, MatchPlan) and all(
+                doc_id in where for doc_id in others
+            ):
+                if plan.tth_cap and value > plan.tth_cap:
+                    # past what the plan tracks totals to: a lower bound
+                    # is all that is reported of it
+                    return {"value": value, "relation": "gte"}
+                misses = _match_plan_misses(
+                    leg["ex"].reader, plan, [where[d] for d in others]
+                )
+                return {"value": value + misses, "relation": relation}
+        return {
+            "value": max(len(listed), *(v for _, v, _ in counted)),
+            "relation": "gte",
+        }
 
     def _submit_leg(
         self, child: dict, window: int, extra_filter: Optional[dict],
@@ -3616,7 +3766,7 @@ class IndexService:
                     prof=leg_prof,
                 )
                 return {
-                    "mode": "batcher", "job": job, "ex": ex,
+                    "mode": "batcher", "job": job, "ex": ex, "plan": plan,
                     "label": label, "child": child, "prof": leg_prof,
                 }
             except RuntimeError:
@@ -3631,17 +3781,23 @@ class IndexService:
                     child, window, window, extra_filter, pins,
                     prof_out=sink,
                 ),
+                "end_ns": time.perf_counter_ns(),
                 "label": label, "child": child, "prof_sink": sink,
             }
+
+        def run_leg():
+            # the leg's own end, read where it ends: the request thread
+            # may be waiting for another leg then
+            ranked = self._run_retriever(
+                child, window, window, extra_filter, pins, sink
+            )
+            return ranked, time.perf_counter_ns()
+
         # copied context per leg: the fetch accumulator, trace, and
         # opaque id stay visible inside pool threads (each submit gets
         # its own copy — one Context object cannot be entered twice)
         cctx = contextvars.copy_context()
-        fut = _LEG_POOL.submit(
-            cctx.copy().run,
-            self._run_retriever, child, window, window, extra_filter,
-            pins, sink,
-        )
+        fut = _LEG_POOL.submit(cctx.copy().run, run_leg)
         return {
             "mode": "pool", "fut": fut, "label": label, "child": child,
             "prof_sink": sink,
@@ -3715,27 +3871,39 @@ class IndexService:
 
     def _wait_leg(
         self, handle: dict, window: int, extra_filter: Optional[dict],
-        t_start: float, pins=None,
+        t_start_ns: int, pins=None,
     ) -> dict:
-        """Collects one leg: {"ranked", "td", "ex", "label", "ms"}."""
+        """Collects one leg: {"ranked", "td", "ex", "label", "ms",
+        "end_ns"}. `end_ns` is the leg's own completion mark (a batcher
+        job's `t_done`, the end of a pool or inline run), not the moment
+        this wait returned: legs are waited in the order they were
+        submitted, and a leg that finished early must not read as long
+        as the wait in front of it. `ms` counts from the legs' common
+        start to that mark."""
         td = None
         ex = None
+        end_ns = 0
         if handle["mode"] == "batcher":
             from ..search.batcher import QueryBatcher
 
             try:
                 td = QueryBatcher.wait(handle["job"])
                 ex = handle["ex"]
-                ranked = [(h.doc_id, h.score) for h in td.hits]
+                end_ns = handle["job"].t_done
+                ranked = _Ranked(
+                    ((h.doc_id, h.score) for h in td.hits),
+                    total={"value": td.total, "relation": td.relation},
+                )
             except RuntimeError:
                 # batcher closed mid-flight → sync fallback
                 ranked = self._run_retriever(
                     handle["child"], window, window, extra_filter, pins
                 )
         elif handle["mode"] == "done":
-            ranked = handle["ranked"]
+            ranked, end_ns = handle["ranked"], handle["end_ns"]
         else:
-            ranked = handle["fut"].result()
+            ranked, end_ns = handle["fut"].result()
+        end_ns = end_ns or time.perf_counter_ns()
         sink = handle.get("prof_sink")
         sub_profile = None
         if sink and sink.get("legs"):
@@ -3744,11 +3912,13 @@ class IndexService:
             "ranked": ranked,
             "td": td,
             "ex": ex,
+            "plan": handle.get("plan") if td is not None else None,
             "label": handle["label"],
             "mode": handle["mode"],
             "prof": handle.get("prof"),
             "sub_profile": sub_profile,
-            "ms": (time.perf_counter() - t_start) * 1000.0,
+            "end_ns": end_ns,
+            "ms": (end_ns - t_start_ns) / 1e6,
         }
 
     def _fuse_legs_device(
@@ -3760,38 +3930,39 @@ class IndexService:
         program (ops/fusion), and winners map back to _id strings on the
         host. Tie-break is ascending global doc — the same (segment,
         doc) asc order every other merge in the engine uses. Legs pad to
-        a fixed [1, window] shape so the kernel compiles once per
-        (n_legs, window, k)."""
-        from ..ops.fusion import rrf_fuse_device
+        the rows of one fixed [n_legs, window] array, so the kernel
+        compiles once per (n_legs, window, k), the launch carries one
+        upload and the answer is one packed download. Returns (fused
+        [(doc_id, score)], uploaded bytes, downloaded bytes)."""
+        from ..ops.fusion import rrf_fuse_request
+        from ..ops.scoring import rank_order
 
         import numpy as np
 
         ex = next(l["ex"] for l in legs if l["ex"] is not None)
-        reader = ex.reader
-        bases = np.zeros(len(reader.segments) + 1, np.int64)
-        np.cumsum(
-            [seg.num_docs for seg in reader.segments], out=bases[1:]
-        )
+        bases = [0]
+        for seg in ex.reader.segments:
+            bases.append(bases[-1] + seg.num_docs)
         id_map: Dict[int, str] = {}
-        arrays = []
         width = max(int(k), 1)
-        for leg in legs:
+        ranked = np.full((len(legs), width), -1, np.int32)
+        for li, leg in enumerate(legs):
             hits = leg["td"].hits[:width]
-            arr = np.full((1, width), -1, np.int32)
-            for r, h in enumerate(hits):
-                g = int(bases[h.segment] + h.local_doc)
-                arr[0, r] = g
-                id_map[g] = h.doc_id
-            arrays.append(arr)
-        s, d = rrf_fuse_device(arrays, k, rank_constant)
-        s = np.asarray(s)[0]
-        d = np.asarray(d)[0]
-        out: List[tuple] = []
-        for sc, doc in zip(s, d):
-            if doc < 0 or not np.isfinite(sc):
-                break  # padding sorts last
-            out.append((id_map[int(doc)], float(sc)))
-        return out
+            gids = [bases[h.segment] + h.local_doc for h in hits]
+            ranked[li, :len(gids)] = gids
+            id_map.update(zip(gids, (h.doc_id for h in hits)))
+        s, d = rrf_fuse_request(ranked, k, rank_constant)
+        # a leg's rank i ties the other's rank i exactly: ascending
+        # global doc among equal scores is the host's to settle (the
+        # TPU's top-k returns exact ties in no particular order)
+        (s,), _, (d,) = rank_order(s[None], np.zeros((1, len(d)), d.dtype),
+                                   d[None])
+        keep = int(((d >= 0) & np.isfinite(s)).sum())  # padding sorts last
+        fused = [
+            (id_map[g], sc)
+            for g, sc in zip(d[:keep].tolist(), s[:keep].tolist())
+        ]
+        return fused, ranked.nbytes, s.nbytes + d.nbytes
 
     def count(
         self, body: Optional[dict] = None, extra_filter: Optional[dict] = None

@@ -34,6 +34,18 @@ the serving process; tags in brackets):
     coordinator [index, shards, took_ms]
       > parse, can_match, dfs, fan_out, reduce   (tile the coordinator)
     fan_out > shard_search [index, shard, backend]   (one per shard)
+    coordinator of a `retriever` (or `rank: {rrf}`) search, the same
+    root under the same name
+      > retriever, rescore, fetch   (tile it; no fan_out: the legs of
+        an rrf node go to the batcher themselves)
+    retriever > rrf [index, legs, device_fused]
+      > leg:<label> [mode]   (bm25, knn, sparse, other; one per child)
+            the legs' common start -> the leg's OWN completion mark (a
+            batcher job's `t_done`, the end of a pool or inline run),
+            whatever order the request thread waited in
+      > fuse [device, window, h2d_bytes, d2h_bytes]   the last leg's
+            waiter awake -> the fused list (ops/fusion: one upload, one
+            packed download; `device: false` = the host's dict fuse)
     shard_search (or `leg:<label>` of an rrf retriever, or
     `mesh_search`) > the job spans of search/batcher.py, which tile the
     job's life from submit to its waiter's wake-up:
@@ -61,9 +73,13 @@ process and they land on the host plane of the `.xplane.pb`, one line
 per dispatcher thread, beside the device's `XLA Ops` line.
 
 `note_transfer` counts the query path's host<->device transfers where
-they happen (ops/scoring.py, the kNN upload in search/batcher.py, and
-the serve family's per-job fallback, `JaxExecutor.segment_topk`);
-`_nodes/stats` reports the totals as `transfer.scoring.*`.
+they happen (ops/scoring.py, the kNN upload in search/batcher.py, the
+serve family's per-job fallback, `JaxExecutor.segment_topk`, and the
+rrf fuse's upload and download, ops/fusion.py);
+`_nodes/stats` reports the totals as `transfer.scoring.*`, and the
+hybrid searches' own counters (`IndexService.rrf_stats`: searches,
+device_fused, host_fused, fuse_ms, the legs' summed ms) as
+`pipeline.rrf.*`.
 
 `OPAQUE_ID_CTX` carries the request's `X-Opaque-Id` header value so
 task descriptions, slow-log records, and traces can all attribute work

@@ -9,15 +9,19 @@ jitted program with a single [B, k] download.
 
 Used by two call sites:
   * the serving path (`IndexService._retriever_search` /
-    `rank: {rrf: ...}`) fusing the concurrent BM25 + kNN batcher legs;
+    `rank: {rrf: ...}`) fusing the concurrent BM25 + kNN batcher legs
+    (`rrf_fuse_request`: one upload, one packed download a request);
   * the SPMD multi-chip path (`parallel/sharded.rrf_fuse`) fusing
     all-gathered per-shard top-k lists.
 
 Ordering contract (matched by the host oracle `rrf_fuse_host`, and by
 the engine's cross-segment merges everywhere else): fused score desc,
-then ASCENDING doc id among ties. `lax.top_k` keeps the lowest index
-among equal scores, so candidates are pre-sorted doc-ascending before
-the cut — that makes the tie-break exact, not incidental.
+then ASCENDING doc id among ties. Candidates are pre-sorted
+doc-ascending before the cut, so a `lax.top_k` that keeps the lowest
+index among equal scores (the CPU's) gives that tie-break by itself; the
+TPU's returns exact ties in no particular order (PERF.md section 6,
+PR 31), so the serving path puts the downloaded list in that order on
+the host (`IndexService._fuse_legs_device`).
 """
 
 from __future__ import annotations
@@ -28,6 +32,8 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..common.tracing import note_transfer
 
 _PAD_SORT_KEY = np.iinfo(np.int32).max
 
@@ -56,7 +62,7 @@ def _fuse_ranked(legs, rank_constant: int, k: int):
         pos[None, None, :] < pos[None, :, None]
     )
     fused = jnp.where(dup.any(-1), -jnp.inf, fused)
-    # doc-ascending layout so top_k's lowest-index tie-keep IS the
+    # doc-ascending layout so a lowest-index tie-keep in top_k IS the
     # ascending-doc tie-break (pads sort last)
     order = jnp.argsort(jnp.where(docs >= 0, docs, _PAD_SORT_KEY), axis=1)
     docs_sorted = jnp.take_along_axis(docs, order, axis=1)
@@ -80,6 +86,35 @@ def rrf_fuse_device(
         int(rank_constant),
         int(k),
     )
+
+
+@functools.partial(jax.jit, static_argnames=("rank_constant", "k"))
+def _fuse_ranked_packed(legs, rank_constant: int, k: int):
+    """`_fuse_ranked` of one query, its legs the rows of one int32
+    [n_legs, k_leg] array and its answer one int32[2, k'] array (row 0
+    the scores' bits, row 1 the docs)."""
+    s, d = _fuse_ranked(
+        tuple(legs[i][None, :] for i in range(legs.shape[0])),
+        rank_constant, k,
+    )
+    return jnp.stack([jax.lax.bitcast_convert_type(s[0], jnp.int32), d[0]])
+
+
+def rrf_fuse_request(
+    legs: np.ndarray, k: int, rank_constant: int = 60
+) -> Tuple[np.ndarray, np.ndarray]:
+    """One request's legs fused on device, as the serving path asks it:
+    `legs` a host int32[n_legs, k_leg] array of doc ids in rank order
+    (-1 padding), handed to the program as it is (the launch uploads
+    it); the answer comes back in one blocking download. Returns host
+    (scores f32[k'], docs i32[k']), padding as `rrf_fuse_device`. Both
+    transfers are noted (`transfer.scoring`)."""
+    if legs.shape[0] < 2:
+        raise ValueError("rrf fusion needs at least two legs")
+    note_transfer("h2d", legs.nbytes)
+    out = np.asarray(_fuse_ranked_packed(legs, int(rank_constant), int(k)))
+    note_transfer("d2h", out.nbytes)
+    return out[0].view(np.float32), out[1]
 
 
 def rrf_fuse_host(

@@ -8,7 +8,9 @@ doc-at-a-time iterators with:
   gather tile rows (XLA gather from HBM-resident [n_tiles, 128] arrays)
   → elementwise BM25 on the VPU
   → scatter-add into a dense per-doc accumulator (term-at-a-time)
-  → lax.top_k (ties broken by lowest index = doc asc, matching Lucene).
+  → lax.top_k, then `rank_order` on the host: score desc, doc asc among
+    exact ties, matching Lucene (the TPU's top_k alone returns exact
+    ties in no particular order; the CPU's keeps the lowest index).
 
 Scatter-add also accumulates a per-doc *matching-term count*, which makes
 conjunctions (operator=and) and minimum_should_match pure elementwise
@@ -1033,9 +1035,10 @@ def _fused_query_mf(
 #
 # Ordering parity with the host merge (score desc, (segment, doc) asc):
 # slots are concatenated (segment asc, per-segment rank asc) and
-# lax.top_k keeps the LOWEST slot among equal scores; per-segment ranks
-# already break equal scores doc-asc, so the merged order is identical
-# to the host sort — selection only, scores untouched → float-exact.
+# lax.top_k keeps the LOWEST slot among equal scores on the CPU; on the
+# TPU it returns exact ties in no particular order, so the host puts the
+# downloaded rows in rank order (`rank_order`). Selection only, scores
+# untouched → float-exact.
 # ---------------------------------------------------------------------------
 
 
@@ -1079,6 +1082,34 @@ def is_packed(part) -> bool:
     """A segment's candidates as the fused kernel packed them (one
     array), not a (scores, docs, totals) triple."""
     return not isinstance(part, tuple)
+
+
+def rank_order(scores: np.ndarray, segs: np.ndarray, docs: np.ndarray):
+    """Host rows [B, k] of a top-k download, put in the engine's rank
+    order: score descending, then (segment, doc) ascending (Lucene's).
+    The device's selection does not promise the second key. On the CPU
+    `lax.top_k` keeps the lowest index among equal scores; on the TPU it
+    returns exact ties in no particular order (PERF.md section 6, PR 31:
+    of 69 passage questions whose first seven BM25 ranks hold an exact
+    tie - a sixth of all questions - 28 came back with the tied passages
+    reversed, their float32 scores bit-equal). Rows without a tie among
+    their real candidates stay as they are; -inf padding stays last.
+    Which passages of a tie group that the cut at k splits were selected
+    stays the device's choice.
+
+    A row with a tie is sorted as Python lists, not by `np.lexsort`:
+    NumPy's sorts release the interpreter lock whatever the size, and a
+    dispatcher worker that lets go of it under load waits for it again."""
+    tied = (scores[:, 1:] == scores[:, :-1]) & np.isfinite(scores[:, 1:])
+    if not tied.any():
+        return scores, segs, docs
+    scores, segs, docs = scores.copy(), segs.copy(), docs.copy()
+    for b in np.flatnonzero(tied.any(axis=1)).tolist():
+        ranked = sorted(zip((-scores[b]).tolist(), segs[b].tolist(),
+                            docs[b].tolist()))
+        negated, segs[b], docs[b] = zip(*ranked)
+        scores[b] = [-x for x in negated]
+    return scores, segs, docs
 
 
 def packed_segment_topk(si: int, packed):

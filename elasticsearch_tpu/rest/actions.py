@@ -630,7 +630,15 @@ class RestActions:
             "routed": 0, "launches": 0, "jobs": 0, "rebuilds": 0,
             "degraded": 0, "fallbacks": 0,
         }
+        # hybrid (rrf) searches over the indices (IndexService.rrf_stats):
+        # how many, how many fused on the device and on the host, the
+        # fuse's and the legs' summed milliseconds (a leg from the legs'
+        # common start to its own completion mark)
+        rrf: dict = {}
         for idx in self.cluster.indices.values():
+            with idx._rrf_lock:
+                for k, v in idx.rrf_stats.items():
+                    rrf[k] = rrf.get(k, 0) + v
             b = getattr(idx, "_batcher", None)
             if b is not None:
                 for k in batch:
@@ -683,6 +691,7 @@ class RestActions:
             batching["buckets"] = list(batch_buckets(BPAD))
         pipeline["batching"] = batching
         pipeline["mesh"] = mesh_stats
+        pipeline["rrf"] = rrf
         if queue_capacity == 0:
             from ..search.batcher import QUEUE_CAPACITY
 

@@ -482,7 +482,7 @@ class _Job:
     __slots__ = (
         "executor", "kind", "plan", "k", "query", "event", "result",
         "error", "deadline", "t_enq", "cold0", "prof", "trace", "parent",
-        "group",
+        "group", "t_done",
     )
 
     def __init__(
@@ -515,6 +515,10 @@ class _Job:
         self.parent = PARENT_CTX.get()
         # the dispatched group's marks, set when a worker starts it
         self.group: Optional[_Group] = None
+        # the job's completion mark (`finish`): its `collect` span's end.
+        # A waiter that holds several jobs reads each one's own end here,
+        # whatever order it waits in. 0: no worker finished the job
+        self.t_done = 0
 
     def done(self) -> bool:
         return self.event.is_set()
@@ -525,10 +529,11 @@ class _Job:
         them. A job no worker started (shed, cancelled, closed) has no
         marks and records nothing."""
         g = self.group
+        self.t_done = time.perf_counter_ns()
         if g is not None and (
             self.trace is not None or self.prof is not None
         ):
-            g.record(self, time.perf_counter_ns())
+            g.record(self, self.t_done)
         self.event.set()
 
 
@@ -1691,14 +1696,16 @@ class QueryBatcher:
         merge program, which unpacks packed rows in its own trace."""
         direct = len(items) == 1 and scoring.is_packed(items[0][1])
         if direct:
-            out = scoring.packed_segment_topk(*items[0])
+            ms, mseg, mdoc, mtot = scoring.packed_segment_topk(*items[0])
         else:
-            out = scoring.merge_segment_topk(items, kb)
+            ms, mseg, mdoc, mtot = scoring.merge_segment_topk(items, kb)
         _group_now().merged = not direct
         if record and direct:
             with self._lock:
                 self.stats["direct_collect_groups"] += 1
-        return out
+        # exact ties in (segment, doc) order: the device's top-k does not
+        # promise it
+        return (*scoring.rank_order(ms, mseg, mdoc), mtot)
 
     # ---- dispatch/collect pairs (device work launches in dispatch;
     # only collect blocks on host transfers) ----
@@ -2078,6 +2085,7 @@ class QueryBatcher:
             ms, mseg, mdoc, counts = scoring.knn_merge_segment_topk(
                 [(si, s, d) for si, _, s, d in items], nc_rows, k_out
             )
+            ms, mseg, mdoc = scoring.rank_order(ms, mseg, mdoc)
             for ji, j in enumerate(jobs):
                 finite = np.isfinite(ms[ji])
                 cap = min(j.plan.k, j.k)

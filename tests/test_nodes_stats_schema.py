@@ -11,7 +11,7 @@ from elasticsearch_tpu.cluster import ClusterService
 from elasticsearch_tpu.rest.actions import RestActions
 
 REQUIRED = {
-    "pipeline": {"depth", "batching", "mesh"},
+    "pipeline": {"depth", "batching", "mesh", "rrf"},
     "pipeline.batching": {
         "buckets", "launches_by_bucket", "occupancy_jobs",
         "occupancy_slots", "express_lane_hits", "avg_occupancy",
@@ -20,6 +20,10 @@ REQUIRED = {
     "pipeline.mesh": {
         "routed", "launches", "jobs", "rebuilds", "degraded",
         "fallbacks",
+    },
+    "pipeline.rrf": {
+        "searches", "device_fused", "host_fused", "fuse_ms",
+        "bm25_leg_ms", "knn_leg_ms", "sparse_leg_ms",
     },
     "admission": {
         "enabled", "limit", "inflight", "queued", "pressure",

@@ -404,7 +404,7 @@ class TestPipelineStats:
             _, resp = actions.nodes_stats(None, {}, {})
             pipe = resp["nodes"]["node-0"]["pipeline"]
             # device time is the profiler's to measure (PERF.md §3)
-            assert set(pipe) == {"depth", "batching", "mesh"}
+            assert set(pipe) == {"depth", "batching", "mesh", "rrf"}
             assert pipe["depth"] >= 1
             assert pipe["batching"]["occupancy_jobs"] >= 1
         finally:

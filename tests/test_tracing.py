@@ -403,8 +403,7 @@ class TestBatcherJobSpans:
         assert families == {"match": "leg:bm25", "knn": "leg:knn"}
         assert ids[by["leg:knn"]["parent_id"]]["name"] == "rrf"
         assert ids[by["rrf"]["parent_id"]]["name"] == "retriever"
-        assert ids[by["retriever"]["parent_id"]]["name"] == (
-            "retriever_search")
+        assert ids[by["retriever"]["parent_id"]]["name"] == "coordinator"
 
     def test_annotations_are_inert_without_a_profiler_session(self):
         from elasticsearch_tpu.search.batcher import _Group
