@@ -143,8 +143,8 @@ class BatchedScoreResult(NamedTuple):
 # loop batches still coalesce up to BPAD. The ladder stays tiny and
 # data-independent (row counts, never tile counts), so the compile-count
 # blowup the round-2 lesson warns about cannot recur: the serving path
-# compiles len(buckets) programs per family total, eagerly warmed on a
-# family's first dispatch (search/batcher.py _maybe_warm).
+# compiles len(buckets) programs per family total, eagerly warmed after
+# a family's first collect (search/batcher.py _warm_ladder).
 # ---------------------------------------------------------------------------
 
 BPAD = 32  # max query rows per launch (top of the bucket ladder)

@@ -1109,9 +1109,9 @@ class MeshExecutor:
         )
 
     def _pack_match(self, snap, view, jobs, t_cap, rows: int):
-        """Per-(entry, job) tile plans in EXACTLY the sequential
-        _run_group order: BlockMaxIndex.plan term order, all tiles
-        essential (no pruning on the mesh path)."""
+        """Per-(entry, job) tile plans in EXACTLY the order of the
+        batcher's `_dispatch_match_group`: BlockMaxIndex.plan term
+        order, all tiles essential (no pruning on the mesh path)."""
         e_pad = snap.e_pad
         lists: List[List[Tuple[np.ndarray, np.ndarray]]] = []
         t_max = 1

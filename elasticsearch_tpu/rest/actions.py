@@ -611,6 +611,9 @@ class RestActions:
             # groups whose result was downloaded as the fused kernel
             # packed it (one scoring segment: no merge program)
             "direct_collect_groups": 0,
+            # groups launched beside another group of their batch (a
+            # hybrid request's legs), of `launches_by_bucket`'s groups
+            "groups_launched_together": 0,
             # tile slots the fused launches' rare-term pass scattered,
             # of the slots of their budget (rows x 256 a field)
             "rare_slots_scattered": 0,
@@ -655,10 +658,9 @@ class RestActions:
                 batching["occupancy_jobs"] += bs["occupancy_jobs"]
                 batching["occupancy_slots"] += bs["occupancy_slots"]
                 batching["express_lane_hits"] += bs["express_lane_hits"]
-                batching["direct_collect_groups"] += bs[
-                    "direct_collect_groups"
-                ]
-                for k in ("rare_slots_scattered", "rare_slots_budget"):
+                for k in ("direct_collect_groups",
+                          "groups_launched_together",
+                          "rare_slots_scattered", "rare_slots_budget"):
                     batching[k] += bs[k]
                 batching["warmup_failures"] += bs["warmup_failures"]
                 for hist in ("fused_hot_slots", "serve_hot_slots"):

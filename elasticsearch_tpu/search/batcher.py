@@ -20,8 +20,8 @@ admission layer is the QoS half). Lone queries arriving on an idle
 worker additionally take a depth-1 EXPRESS LANE: dispatched immediately
 at bucket 1 and collected before the next dequeue, skipping the
 in-flight ring entirely. Every ladder bucket of a kernel family is
-eagerly warmed on that family's first dispatch (`_maybe_warm`, gated by
-ES_TPU_BUCKET_WARMUP), so bucket selection never compiles on the
+eagerly warmed after that family's first collect (`_warm_ladder`, gated
+by ES_TPU_BUCKET_WARMUP), so bucket selection never compiles on the
 steady-state hot path.
 
 Collection mode follows ES semantics (QueryPhase + WANDScorer:
@@ -578,10 +578,9 @@ class _Group:
         """The group's last kernel is enqueued."""
         self.t_dispatched = time.perf_counter_ns()
 
-    def collecting(self, at: Optional[int] = None) -> None:
-        """The worker is back to collect the group (`at`: the same mark
-        as `dispatched`, for a group that never was in flight)."""
-        self.t_collect = at or time.perf_counter_ns()
+    def collecting(self) -> None:
+        """The worker is back to collect the group."""
+        self.t_collect = time.perf_counter_ns()
         self.d2h0 = thread_d2h_bytes()
 
     def add_flops(self, n: int) -> None:
@@ -670,8 +669,8 @@ class _Group:
 
 
 class _BatchCtx:
-    """One dispatched batch in the worker's in-flight ring: the jobs it
-    carries plus the async serve/knn groups awaiting collect."""
+    """One dispatched batch: the jobs it carries plus its launched
+    groups awaiting collect, in launch order."""
 
     __slots__ = ("batch", "pending")
 
@@ -691,7 +690,7 @@ def _group_now() -> _Group:
 class _Family:
     """All the batcher knows about one job kind (an entry of FAMILIES):
     which jobs may share a launch, how a group of them is launched and
-    collected, whether its first dispatch warms the bucket ladder. The
+    collected, whether its first group warms the bucket ladder. The
     calls name the batcher's group methods when they run, not before:
     a subclass or a test may replace one."""
 
@@ -703,12 +702,14 @@ class _Family:
     share: Callable[[object], Tuple]
     # (batcher, jobs, key, kb, rows, record) -> pend: the group's device
     # work, enqueued without a host sync (`record=False`: a warm-up
-    # launch, which appears in no counter and fires no fault site)
+    # launch, which appears in no counter and fires no fault site). Two
+    # dispatches block all the same, on one threshold download each: a
+    # match group that leaves the fused kernel for the chunked block-max
+    # path (`cs.threshold`), and a sparse group's phase A
     dispatch: Callable
     # (batcher, jobs, key, kb, pend, record): the blocking downloads and
-    # the waiters' wake-up. None: the group completes inside `dispatch`,
-    # after the batch's asynchronous groups (its host syncs overlap them)
-    collect: Optional[Callable] = None
+    # the waiters' wake-up
+    collect: Callable
     # a placement over all the index's shards (MeshExecutor): the launch
     # width is not a ladder bucket but what dispatch returns as
     # pend["rows"] (the mesh's data axis must divide it)
@@ -759,11 +760,12 @@ def _warm_knn(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
 
 
 FAMILIES: Dict[str, _Family] = {
-    # `_run_group`: dispatch to wake-up on the worker
     "match": _Family(
         "text", lambda p: (p.field,),
-        lambda b, jobs, key, kb, rows, record: b._run_group(
+        lambda b, jobs, key, kb, rows, record: b._dispatch_match_group(
             jobs, key[2], kb, rows=rows, record=record),
+        lambda b, jobs, key, kb, pend, record: b._collect_match_group(
+            jobs, kb, pend, record=record),
         warm=_warm_match,
     ),
     "serve": _Family(
@@ -842,10 +844,10 @@ class QueryBatcher:
 
     Submission is a FUTURE API: `submit_nowait()` returns a job handle
     immediately and `wait(handle)` collects, so one request can hold
-    several legs in flight at once (hybrid BM25 + kNN). Workers split
-    serve/kNN groups into an async device-dispatch phase and a blocking
-    collect phase, so the legs' kernels launch back-to-back with no
-    host sync between them."""
+    several legs in flight at once (hybrid BM25 + kNN). A worker
+    launches every group of a batch (a family's `dispatch`) before it
+    collects any (its `collect`, the blocking downloads), so the legs'
+    kernels launch back-to-back with no host sync between them."""
 
     def __init__(
         self,
@@ -860,8 +862,8 @@ class QueryBatcher:
         # groups pad to the smallest bucket >= occupancy; the top of
         # the ladder bounds how many jobs one batch may carry
         self.buckets = batch_buckets(BPAD)
-        # eager per-family bucket warmup on first dispatch (mutable per
-        # instance; tier-1 pins the env off, tests re-arm per batcher)
+        # eager per-family bucket warmup after a first collect (mutable
+        # per instance; tier-1 pins the env off, tests re-arm per batcher)
         self.warmup_enabled = bucket_warmup()
         self.max_batch = min(max_batch, BPAD, self.buckets[-1])
         self.workers = workers
@@ -916,10 +918,10 @@ class QueryBatcher:
             "fused_rare_tiles": 0,
             "rare_slots_scattered": 0,
             "rare_slots_budget": 0,
-            # times a kNN group and a text (match/serve) group were in
-            # flight on device simultaneously — the observable proof
-            # that hybrid legs overlap instead of serializing
-            "hybrid_overlap_events": 0,
+            # groups launched beside another of their batch: at the end
+            # of a batch's launches, the groups it holds uncollected when
+            # they are two or more (a hybrid request's legs)
+            "groups_launched_together": 0,
             # overload protection: jobs dropped at dequeue because
             # their deadline budget was already spent (never launched)
             # and jobs cancelled while still queued (task cancel)
@@ -1118,7 +1120,7 @@ class QueryBatcher:
 
     def _run(self):
         # bounded in-flight ring: each entry is a dispatched batch whose
-        # serve/knn device results have not been collected yet. With
+        # device results have not been collected yet. With
         # pipeline_depth=1 this is exactly the classic loop (dispatch,
         # then immediately collect); with depth=2 the worker dispatches
         # batch N+1 while batch N's kernels are still on device and
@@ -1195,13 +1197,10 @@ class QueryBatcher:
     def _dispatch_batch(
         self, batch: List[_Job], express: bool = False
     ) -> "_BatchCtx":
-        """Groups a batch and launches all its device work. A family
-        with a collect stage dispatches asynchronously (collected later
-        by _collect_batch); one without (match: its pruning rounds are
-        host-dependent) runs dispatch+collect fused AFTER the async
-        dispatches, so its host syncs overlap the in-flight kernels
-        instead of stalling them. Never raises: failures surface to the
-        affected jobs' waiters."""
+        """Groups a batch and launches all its device work, a group
+        after the other in the order their first jobs were submitted;
+        `_collect_batch` collects them in that order. Never raises:
+        failures surface to the affected jobs' waiters."""
         ctx = _BatchCtx(batch)
         try:
             # congestion signal for the admission layer's AIMD limit:
@@ -1230,10 +1229,7 @@ class QueryBatcher:
                 kb = 16 if j.k <= 16 else scoring.next_bucket(j.k, 16)
                 key = (id(j.executor), j.kind, *fam.share(j.plan), kb)
                 groups.setdefault(key, (fam, kb, []))[2].append(j)
-            ordered = sorted(
-                groups.items(), key=lambda kv: kv[1][0].collect is None
-            )
-            for key, (fam, kb, jobs) in ordered:
+            for key, (fam, kb, jobs) in groups.items():
                 # pad-bucket ladder: the group's launch width is the
                 # smallest compiled bucket covering its occupancy
                 rows = (
@@ -1247,7 +1243,7 @@ class QueryBatcher:
                     j.group = g
                 _worker_tl.group = g
                 self._enter_kind(fam.overlap)
-                dispatched = warm = False
+                dispatched = False
                 try:
                     # fault site: an injected dispatch failure surfaces
                     # to exactly this group's waiters, not the batch
@@ -1256,24 +1252,15 @@ class QueryBatcher:
                         jobs=len(jobs), mesh=int(fam.mesh),
                     )
                     if fam.bucket_recorded and not fam.mesh:
-                        # BEFORE dispatch: a group that completes there
-                        # wakes its waiters, and a waiter must never
-                        # observe its own launch missing from the
-                        # histogram
                         self._record_bucket(rows, len(jobs))
-                    if fam.collect is None:
-                        fam.dispatch(self, jobs, key, kb, rows, True)
-                    else:
-                        with g.phase("es.dispatch"):
-                            pend = fam.dispatch(
-                                self, jobs, key, kb, rows, True)
-                            if fam.mesh:
-                                g.rows = int(pend.get("rows", BPAD))
-                                self._record_bucket(g.rows, len(jobs))
-                        g.dispatched()
-                        ctx.pending.append((fam, key, kb, jobs, pend))
-                        dispatched = True
-                    warm = fam.warm is not None
+                    with g.phase("es.dispatch"):
+                        pend = fam.dispatch(self, jobs, key, kb, rows, True)
+                        if fam.mesh:
+                            g.rows = int(pend.get("rows", BPAD))
+                            self._record_bucket(g.rows, len(jobs))
+                    g.dispatched()
+                    ctx.pending.append((fam, key, kb, jobs, pend))
+                    dispatched = True
                 except BaseException as e:  # surface to waiters
                     for j in jobs:
                         if not j.event.is_set():
@@ -1283,10 +1270,10 @@ class QueryBatcher:
                     _worker_tl.group = None
                     if not dispatched:
                         self._exit_kind(fam.overlap)
-                if warm:
-                    # after the group's own marks and waiters: bucket
-                    # warming is compile time, not this query's time
-                    self._maybe_warm(fam, key, jobs, kb, rows)
+            if len(ctx.pending) >= 2:
+                with self._lock:
+                    self.stats["groups_launched_together"] += len(
+                        ctx.pending)
         except BaseException as e:
             # stats/grouping crash between dequeue and the per-group
             # guard: already-dequeued jobs are not in the queue, so the
@@ -1301,13 +1288,22 @@ class QueryBatcher:
 
     def _collect_batch(self, ctx: "_BatchCtx"):
         """Host side of one dispatched batch: transfer the merged device
-        results and finish the waiters. Never raises."""
+        results and finish the waiters, then warm the ladder of a family
+        seen for the first time (compile time, not these queries' time).
+        Never raises."""
+        warm: List[Tuple] = []
         try:
             for fam, key, kb, jobs, pend in ctx.pending:
                 g = jobs[0].group
                 _worker_tl.group = g
                 g.collecting()
                 try:
+                    # claimed before the waiters wake: `wait_warm_idle`
+                    # never reads idle between a first request's answer
+                    # and its ladder's warm-up
+                    j0 = self._warm_due(fam, key, jobs)
+                    if j0 is not None:
+                        warm.append((fam, key, kb, g.rows, j0))
                     with g.phase("es.collect"):
                         # fault site: a collect-phase failure (device→
                         # host transfer) fails this group's waiters only
@@ -1326,6 +1322,8 @@ class QueryBatcher:
                     self._exit_kind(fam.overlap)
         finally:
             ctx.pending = []
+            for w in warm:
+                self._warm_ladder(*w)
 
     def _count_rare_slots(self, rows: int, t_rare: int, tiles: List[int]):
         """One fused launch's rare-term pass over one field (`tiles`: a
@@ -1371,6 +1369,7 @@ class QueryBatcher:
             jobs, slots = self._occ_jobs, self._occ_slots
             express = self.stats["express_lane_hits"]
             direct = self.stats["direct_collect_groups"]
+            together = self.stats["groups_launched_together"]
             scattered = self.stats["rare_slots_scattered"]
             budget = self.stats["rare_slots_budget"]
             warm_failed = self.stats["warmup_failures"]
@@ -1391,6 +1390,8 @@ class QueryBatcher:
             "avg_occupancy": round(jobs / slots, 4) if slots else 0.0,
             "express_lane_hits": express,
             "direct_collect_groups": direct,
+            # groups launched beside another group of their batch
+            "groups_launched_together": together,
             # tile slots fused launches' rare-term pass scattered, and
             # the slots of their whole budget (rows x t_rare a field)
             "rare_slots_scattered": scattered,
@@ -1406,25 +1407,34 @@ class QueryBatcher:
             "worker_compiles": compiles,
         }
 
-    def _maybe_warm(self, fam: _Family, key, jobs: List[_Job], kb: int,
-                    rows: int):
-        """Eagerly compiles the remaining ladder buckets of this group's
-        kernel family the first time the family dispatches, by running
-        one dummy job (cloned from the live group's plan) through the
-        real group path at every other bucket. Steady-state bucket
-        selection then never compiles. Best-effort and stat-silent
-        (record=False): warm launches appear in no histogram, flop or
-        fault accounting. Gated by ES_TPU_BUCKET_WARMUP / the
-        `warmup_enabled` attribute (tier-1 pins it off)."""
-        if not self.warmup_enabled or len(self.buckets) <= 1:
-            return
+    def _warm_due(self, fam: _Family, key,
+                  jobs: List[_Job]) -> Optional[_Job]:
+        """The job `_warm_ladder` clones its dummies from, the first
+        time a group of this kernel family (the key and what its
+        programs specialize on) is collected, else None; counted in
+        `_warm_inflight` until that `_warm_ladder` ends. Gated by
+        ES_TPU_BUCKET_WARMUP / `warmup_enabled` (tier-1 pins it off)."""
+        if (fam.warm is None or not self.warmup_enabled
+                or len(self.buckets) <= 1):
+            return None
         specialized, j0 = fam.warm(jobs)
         warm_key = key + specialized
         with self._lock:
             if warm_key in self._warmed:
-                return
+                return None
             self._warmed.add(warm_key)
             self._warm_inflight += 1
+        return j0
+
+    def _warm_ladder(self, fam: _Family, key, kb: int, rows: int,
+                     j0: _Job):
+        """Eagerly compiles the remaining ladder buckets of a group's
+        kernel family, by running one dummy job (cloned from the live
+        group's `j0`) through the real dispatch / collect pair at every
+        bucket but the group's own `rows`. Steady-state bucket selection
+        then never compiles. Best-effort and stat-silent (record=False):
+        warm launches appear in no histogram, flop or fault
+        accounting."""
         try:
             for b in self.buckets:
                 if b == rows:
@@ -1434,9 +1444,9 @@ class QueryBatcher:
                          query=j0.query)
                 ]
                 try:
-                    pend = fam.dispatch(self, dummy, key, kb, b, False)
-                    if fam.collect is not None:
-                        fam.collect(self, dummy, key, kb, pend, False)
+                    fam.collect(
+                        self, dummy, key, kb,
+                        fam.dispatch(self, dummy, key, kb, b, False), False)
                 except BaseException as e:
                     # warmup is opportunistic: a failed bucket just
                     # compiles lazily on its first live hit instead —
@@ -1467,31 +1477,20 @@ class QueryBatcher:
             time.sleep(0.01)
         return False
 
-    def _run_group(self, jobs: List[_Job], field: str, kb: int,
-                   rows: Optional[int] = None, record: bool = True):
-        """A match group from dispatch to its waiters' wake-up, on the
-        worker (its pruning round is host-dependent, so the group is
-        never in flight: `inflight` has no length). `rows` is the
-        group's padded launch width (a ladder bucket >= len(jobs);
-        default BPAD); `record=False` (bucket warmup) skips all
-        stats/flop accounting."""
-        rows = rows or BPAD
-        g = jobs[0].group or _Group("match", len(jobs), rows)  # warm-up
-        with g.phase("es.dispatch"):
-            pend = self._dispatch_match_group(jobs, field, kb, rows, record)
-        g.dispatched()
-        g.collecting(g.t_dispatched)
-        with g.phase("es.collect"):
-            self._collect_match_group(jobs, kb, pend, record)
-
     def _dispatch_match_group(self, jobs: List[_Job], field: str, kb: int,
-                              rows: int, record: bool) -> Tuple:
+                              rows: Optional[int] = None,
+                              record: bool = True) -> Tuple:
         """Every segment's scoring, up to the device-resident candidate
-        buffers: the fused kernel, or the chunked block-max path with
-        its one host-dependent threshold round."""
+        buffers: the fused kernel, enqueued without a host sync, or the
+        chunked block-max path with its one host-dependent threshold
+        round (a download: that dispatch blocks). `rows` is the group's
+        padded launch width (a ladder bucket >= len(jobs); default
+        BPAD); `record=False` (bucket warmup) skips all stats/flop
+        accounting."""
         ex = jobs[0].executor
         reader = ex.reader
         nj = len(jobs)
+        rows = rows or BPAD
         staging = getattr(ex, "staging_slab", None)
         # shard-level pruning eligibility: a capped total may only be
         # shortcut to (cap, gte) when ≥ cap live matches are guaranteed
@@ -1630,7 +1629,7 @@ class QueryBatcher:
         return dev_items, pruned_flags
 
     def _collect_match_group(self, jobs: List[_Job], kb: int, pend: Tuple,
-                             record: bool):
+                             record: bool = True):
         """The group's ONE blocking download (`_group_topk`: the fused
         kernel's packed row as it is when it is the only item, else the
         cross-segment merge program's; score desc, (segment, doc) asc,
@@ -1713,8 +1712,6 @@ class QueryBatcher:
     def _enter_kind(self, fam: str):
         with self._lock:
             self._inflight[fam] += 1
-            if self._inflight["knn"] and self._inflight["text"]:
-                self.stats["hybrid_overlap_events"] += 1
 
     def _exit_kind(self, fam: str):
         with self._lock:

@@ -7,9 +7,13 @@ Contract under test:
     of them do not;
   * a kind outside the table is refused at `submit_nowait`, not served
     as some other family;
+  * every family is a dispatch / collect pair, and a batch launches
+    its groups in the order their first jobs were submitted;
   * a raise inside a family's dispatch or collect fails that group's
     waiters only and leaves the in-flight count of its overlap class
-    at 0.
+    at 0;
+  * `groups_launched_together` counts a batch's groups when it holds
+    two or more uncollected at the end of its launches.
 
 No device work: the sharing cases stub the family's dispatch / collect
 pair (its `share`, overlap class and placement stay the table's), the
@@ -86,8 +90,7 @@ def groups_of(batcher, monkeypatch, jobs):
     for kind in {j.kind for j in jobs}:
         fam = FAMILIES[kind]
         monkeypatch.setitem(FAMILIES, kind, dataclasses.replace(
-            fam, dispatch=dispatch, warm=None,
-            collect=fam.collect and (lambda *a: None),
+            fam, dispatch=dispatch, warm=None, collect=lambda *a: None,
         ))
     batcher._collect_batch(batcher._dispatch_batch(jobs))
     assert all(n == 0 for n in batcher._inflight.values())
@@ -105,6 +108,10 @@ class TestTheTable:
         assert {k for k, f in FAMILIES.items() if f.warm} == {
             "match", "serve", "knn", "sparse",
         }
+
+    def test_every_family_is_a_dispatch_collect_pair(self):
+        for kind, fam in FAMILIES.items():
+            assert callable(fam.dispatch) and callable(fam.collect), kind
 
     def test_unknown_kind_is_refused_at_submit(self, batcher):
         with pytest.raises(ValueError, match="unknown job kind"):
@@ -140,12 +147,12 @@ class TestLaunchSharing:
         else:
             assert sorted(groups, key=lambda g: g[0] is b) == [[a], [b]]
 
-    def test_kinds_never_share_and_fused_groups_run_last(
+    def test_kinds_never_share_and_groups_launch_in_submission_order(
         self, batcher, monkeypatch
     ):
         """A per-shard family and its mesh placement key on the same
-        attributes and still never share; a family that completes inside
-        dispatch (match) runs after the batch's asynchronous groups."""
+        attributes and still never share; a batch's groups launch in the
+        order their first jobs were submitted, whatever the family."""
         ex = object()
         jobs = [
             _Job(ex, plan_of("match"), 10, kind="match"),
@@ -154,7 +161,19 @@ class TestLaunchSharing:
             _Job(ex, plan_of("match"), 10, kind="match"),
         ]
         groups = groups_of(batcher, monkeypatch, jobs)
-        assert groups == [[jobs[1]], [jobs[2]], [jobs[0], jobs[3]]]
+        assert groups == [[jobs[0], jobs[3]], [jobs[1]], [jobs[2]]]
+        # three groups were uncollected at the end of the launches
+        assert batcher.stats["groups_launched_together"] == 3
+
+    def test_a_batch_of_one_group_counts_no_group_launched_together(
+        self, batcher, monkeypatch
+    ):
+        ex = object()
+        jobs = [_Job(ex, plan_of("match"), 10, kind="match")
+                for _ in range(3)]
+        assert groups_of(batcher, monkeypatch, jobs) == [jobs]
+        assert batcher.stats["groups_launched_together"] == 0
+        assert batcher.batching_stats()["groups_launched_together"] == 0
 
 
 class Boom(RuntimeError):
@@ -177,7 +196,7 @@ def fake_agg_job(ex):
 
 class TestFailureIsolation:
     @pytest.mark.parametrize("kind,method", [
-        ("match", "_run_group"),
+        ("match", "_dispatch_match_group"),
         ("serve", "_dispatch_serve_group"),
         ("knn", "_dispatch_knn_group"),
         ("sparse", "_dispatch_sparse_group"),
@@ -203,6 +222,7 @@ class TestFailureIsolation:
         assert all(n == 0 for n in batcher._inflight.values())
 
     @pytest.mark.parametrize("kind,dispatch,collect", [
+        ("match", "_dispatch_match_group", "_collect_match_group"),
         ("serve", "_dispatch_serve_group", "_collect_serve_group"),
         ("knn", "_dispatch_knn_group", "_collect_knn_group"),
         ("sparse", "_dispatch_sparse_group", "_collect_sparse_group"),

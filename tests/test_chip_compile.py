@@ -200,7 +200,7 @@ def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
     RARE_CHUNK tiles inside the family's one program a row bucket: no
     operand as wide as the slot budget (rows x 256 tiles x 128 postings)
     is left, and nothing but the plan's shape decides the program, so
-    the programs `_maybe_warm` compiles a family are its row buckets, as
+    the programs `_warm_ladder` compiles a family are its row buckets, as
     before the loop (no static argument was added to either program)."""
     import inspect
 

@@ -228,7 +228,9 @@ def run_bucket(b, ex, plans, kind, kb, rows):
         for p, q in plans
     ]
     if kind == "match":
-        b._run_group(jobs, plans[0][0].field, kb, rows=rows)
+        pend = b._dispatch_match_group(jobs, plans[0][0].field, kb,
+                                       rows=rows)
+        b._collect_match_group(jobs, kb, pend)
     elif kind == "serve":
         pend = b._dispatch_serve_group(jobs, kb, rows=rows)
         b._collect_serve_group(jobs, kb, pend)
@@ -359,6 +361,98 @@ class TestBucketParity:
 
 
 # ---------------------------------------------------------------------
+# a batch launches every group it holds before it collects any
+# ---------------------------------------------------------------------
+
+PAIRS = {
+    "match": ("_dispatch_match_group", "_collect_match_group"),
+    "serve": ("_dispatch_serve_group", "_collect_serve_group"),
+    "knn": ("_dispatch_knn_group", "_collect_knn_group"),
+}
+
+
+class TestLaunchThenCollect:
+    def test_two_families_launch_in_submission_order_then_collect(
+        self, service, monkeypatch
+    ):
+        """One `match` and one `knn` job in a batch (a hybrid request's
+        legs): both groups are launched before either is collected, in
+        submission order, and each answer is the job's own run alone,
+        bit for bit (the same one-row launches)."""
+        ex = service._executor(service.shards[0])
+        b = workerless(monkeypatch, workers=1)
+        (mp, mq), = match_plans(service, 1)
+        (kp, _), = knn_plans(service, 1)
+
+        def submit():
+            return [b.submit_nowait(ex, mp, 10, kind="match", query=mq),
+                    b.submit_nowait(ex, kp, 8, kind="knn")]
+
+        alone = []
+        for j in submit():
+            b._collect_batch(b._dispatch_batch([j], express=True))
+            alone.append(QueryBatcher.wait(j, timeout=30))
+        assert b.stats["groups_launched_together"] == 0  # one group each
+        order = []
+        for kind in ("match", "knn"):
+            for name in PAIRS[kind]:
+                def spy(*a, _real=getattr(b, name), _name=name, **kw):
+                    order.append(_name)
+                    return _real(*a, **kw)
+                monkeypatch.setattr(b, name, spy)
+        jobs = submit()
+        ctx = b._dispatch_batch(jobs)
+        assert not any(j.done() for j in jobs)
+        assert b._inflight["text"] == b._inflight["knn"] == 1
+        b._collect_batch(ctx)
+        assert order == [PAIRS["match"][0], PAIRS["knn"][0],
+                         PAIRS["match"][1], PAIRS["knn"][1]]
+        for j, ref in zip(jobs, alone):
+            assert (td_fingerprint(QueryBatcher.wait(j, timeout=30))
+                    == td_fingerprint(ref))
+        assert b.stats["groups_launched_together"] == 2
+        assert all(n == 0 for n in b._inflight.values())
+        # the text group was in flight while the kNN group was launched
+        text, knn = (j.group for j in jobs)
+        assert text.t_dispatched <= knn.t_start
+        assert knn.t_dispatched <= text.t_collect <= knn.t_collect
+        b.close()
+
+    @pytest.mark.parametrize("kind", ["match", "serve", "knn"])
+    def test_first_request_wakes_before_its_ladder_warms(
+        self, service, monkeypatch, kind
+    ):
+        """Bucket warming is compile time, not the first query's time:
+        nothing warms before the batch's collect, and the waiter is
+        awake at every warm-up launch, which `wait_warm_idle` reads as
+        running from before the waiter woke."""
+        ex = service._executor(service.shards[0])
+        b = workerless(monkeypatch, workers=1)
+        b.warmup_enabled = True
+        maker = {"match": match_plans, "serve": serve_plans,
+                 "knn": knn_plans}[kind]
+        (plan, q), = maker(service, 1)
+        j = b.submit_nowait(ex, plan, 8, kind=kind, query=q)
+        real = getattr(b, PAIRS[kind][0])
+        seen = []
+
+        def spy(jobs, *a, **kw):
+            seen.append((kw["record"], j.done(), b._warm_inflight))
+            return real(jobs, *a, **kw)
+
+        monkeypatch.setattr(b, PAIRS[kind][0], spy)
+        ctx = b._dispatch_batch([j], express=True)
+        assert seen == [(True, False, 0)]
+        assert b._warmed == set() and not j.done()
+        b._collect_batch(ctx)
+        assert QueryBatcher.wait(j, timeout=30).hits
+        assert seen[1:] == [(False, True, 1)] * (len(b.buckets) - 1)
+        assert b.wait_warm_idle(timeout=1.0)
+        assert b.stats["warmup_failures"] == 0
+        b.close()
+
+
+# ---------------------------------------------------------------------
 # express lane
 # ---------------------------------------------------------------------
 
@@ -410,7 +504,7 @@ class TestNoRecompileAfterWarmup:
         svc = make_service(n_docs=200, seed=11, name="cb-warm")
         try:
             svc._batcher.warmup_enabled = True
-            # one query per family signature → _maybe_warm compiles the
+            # one query per family signature → _warm_ladder compiles the
             # whole ladder for each (same k bucket, fixed nc)
             warm_bodies = [
                 {"query": {"match": {"body": "alpha beta"}}, "size": 7},
@@ -561,15 +655,25 @@ class TestColdClock:
             monkeypatch.setattr(admission, "observe_queue_delay",
                                 samples.append)
             held = 0.4
-            real = b._run_group
+            real = b._dispatch_match_group
+            real_collect = b._collect_match_group
             first = threading.Event()
             # what the held worker really spent, on the batcher's clock:
             # asleep (reported as compile time) and running the first
-            # group afterwards (real work, which the queued jobs wait
-            # behind too). On a loaded host (the driver's six workers)
-            # the sleep overshoots and the run takes tens of ms; pinned
-            # to the nominal 0.4 s, their sum read over the 75 ms target
+            # group afterwards, its dispatch and its collect (real work,
+            # which the queued jobs wait behind too). On a loaded host
+            # (the driver's six workers) the sleep overshoots and the
+            # run takes tens of ms; pinned to the nominal 0.4 s, their
+            # sum read over the 75 ms target
             spent = {}
+
+            def timed_collect(jobs, *a, **kw):
+                t0 = time.perf_counter()
+                try:
+                    return real_collect(jobs, *a, **kw)
+                finally:
+                    spent.setdefault(
+                        "collecting", time.perf_counter() - t0)
 
             def slow_first(jobs, *a, **kw):
                 if first.is_set():
@@ -589,7 +693,8 @@ class TestColdClock:
                 finally:
                     spent["running"] = time.perf_counter() - t0
 
-            monkeypatch.setattr(b, "_run_group", slow_first)
+            monkeypatch.setattr(b, "_dispatch_match_group", slow_first)
+            monkeypatch.setattr(b, "_collect_match_group", timed_collect)
             j1 = b.submit_nowait(ex, mp[0], 10)
             assert first.wait(10)
             queued = [b.submit_nowait(ex, p, 10) for p in mp[1:]]
@@ -599,8 +704,8 @@ class TestColdClock:
             if compiling:
                 # the compile is taken out whole; what is left is the
                 # wait behind the first group's own run
-                assert worst - spent["running"] < admission.target_delay_s, (
-                    samples, spent)
+                assert (worst - spent["running"] - spent["collecting"]
+                        < admission.target_delay_s), (samples, spent)
             else:
                 assert worst >= 0.75 * held, samples
         finally:
@@ -773,6 +878,7 @@ class TestBatchingStats:
             "warmup_failures", "worker_compile_ms", "worker_compiles",
             "fused_hot_slots", "serve_hot_slots", "direct_collect_groups",
             "rare_slots_scattered", "rare_slots_budget",
+            "groups_launched_together",
         }
         assert bs["warmup_failures"] == 0
         assert bs["worker_compile_ms"] > 0.0  # this batcher compiled

@@ -139,7 +139,8 @@ def run_group(b, ex, plans, family, rows):
     jobs = [b.submit_nowait(ex, p, 10, kind=family, query=q)
             for p, q in plans]
     if family == "match":
-        b._run_group(jobs, plans[0][0].field, KB, rows=rows)
+        b._collect_match_group(jobs, KB, b._dispatch_match_group(
+            jobs, plans[0][0].field, KB, rows=rows))
     else:
         b._collect_serve_group(
             jobs, KB, b._dispatch_serve_group(jobs, KB, rows=rows))

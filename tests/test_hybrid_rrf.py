@@ -197,33 +197,33 @@ class TestHybridServing:
             h["_id"] for h in r2["hits"]["hits"]
         ]
 
-    def test_legs_overlap_in_flight(self, service):
-        """Both hybrid legs must be dispatched concurrently: widen the
-        kNN dispatch window deterministically and check the counter."""
-        batcher = service._batcher
-        orig = QueryBatcher._dispatch_knn_group
+    def test_legs_overlap_in_flight(self, service, monkeypatch):
+        """Both hybrid legs are launched before either is collected: a
+        worker that finds both queued drains them as one batch of two
+        groups, and the counter reads both. The one worker is held at
+        its dequeue until the request thread has submitted the second
+        leg, so the batch is deterministic."""
+        want = [
+            h["_id"]
+            for h in service.search(hybrid_body(seed=10))["hits"]["hits"]
+        ]
+        b = QueryBatcher(workers=1)
+        monkeypatch.setattr(service, "_batcher", b)
+        admit = b._admit_job
 
-        def slow_dispatch(self, jobs, rows=None, record=True):
-            items = orig(self, jobs, rows=rows, record=record)
-            time.sleep(0.05)  # keep "knn" in flight while text enters
-            return items
+        def slow_admit(job):
+            time.sleep(0.05)
+            return admit(job)
 
-        before = batcher.stats["hybrid_overlap_events"]
+        monkeypatch.setattr(b, "_admit_job", slow_admit)
         try:
-            QueryBatcher._dispatch_knn_group = slow_dispatch
-            threads = [
-                threading.Thread(
-                    target=lambda i=i: service.search(hybrid_body(seed=10 + i))
-                )
-                for i in range(6)
-            ]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
+            got = service.search(hybrid_body(seed=10))
+            assert [h["_id"] for h in got["hits"]["hits"]] == want
+            assert b.stats["max_batch_seen"] == 2
+            assert b.stats["groups_launched_together"] == 2
+            assert all(n == 0 for n in b._inflight.values())
         finally:
-            QueryBatcher._dispatch_knn_group = orig
-        assert batcher.stats["hybrid_overlap_events"] > before
+            b.close()
 
 
 class TestAsyncSubmission:

@@ -16,6 +16,7 @@ REQUIRED = {
         "buckets", "launches_by_bucket", "occupancy_jobs",
         "occupancy_slots", "express_lane_hits", "avg_occupancy",
         "warmup_failures", "worker_compile_ms", "worker_compiles",
+        "groups_launched_together",
     },
     "pipeline.mesh": {
         "routed", "launches", "jobs", "rebuilds", "degraded",

@@ -255,10 +255,9 @@ class TestBatcherJobSpans:
         assert not fused or d["tags"]["rare_tiles"] >= 1
         assert d["tags"]["launches"] >= 1
         assert c["tags"]["d2h_bytes"] > 0
-        if family == "match":
-            # a match group's pruning round is host-dependent: the
-            # worker collects it at once, it is never in flight
-            assert i["duration_ns"] == 0
+        # every family is a dispatch / collect pair: the group is in
+        # flight from its last launch to the worker's return
+        assert i["duration_ns"] > 0
 
     def test_express_lane_job_is_tagged_and_counted(self, fused_service):
         b = fused_service._batcher
@@ -412,8 +411,8 @@ class TestBatcherJobSpans:
         with g.phase("es.dispatch"):
             g.dispatched()
         with g.phase("es.collect"):
-            g.collecting(g.t_dispatched)
-        assert g.t_start <= g.t_dispatched == g.t_collect
+            g.collecting()
+        assert g.t_start <= g.t_dispatched <= g.t_collect
 
 
 class TestTransferCounters:
