@@ -1585,6 +1585,12 @@ class IndexService:
                             query, self.mappings, self.analysis
                         )
                         kind = "serve"
+                        if plan is None:
+                            # the unbatched executor below; the mesh twin
+                            # and a retriever's leg that found no plan
+                            # come through here too, so this is the one
+                            # place that counts them
+                            self._batcher.note_unplanned()
                 elif query is None and knn is not None:
                     plan = extract_knn_plan(knn, self.mappings)
                     kind = "knn"
@@ -2515,6 +2521,11 @@ class IndexService:
                         query, self.mappings, self.analysis
                     )
                     kind = "mesh_serve"
+                    if plan is not None and plan.counts_clauses:
+                        # the mesh kernels count terms, and a clause
+                        # of several terms counts once however many of
+                        # them a document holds: the shard path takes it
+                        return None
         else:
             knn_body = body["knn"]
             knn = [

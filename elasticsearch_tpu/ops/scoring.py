@@ -599,43 +599,54 @@ def wide_rows_first(hot_rows: list, hot_w: list, dense) -> None:
     """Orders a plan's hot slots in place, uint16 rows (numbered on from
     the rows of the uint8 plane `dense`) before uint8 ones, each kind in
     its given order: `_add_hot_terms` then walks one run of slots a
-    plane."""
+    plane. A serve plan's rows may carry their clause above
+    SLOT_ID_BITS (`clause_slot_ids`)."""
     n8 = 0 if dense is None else dense.shape[0]
-    order = sorted(range(len(hot_rows)), key=lambda i: hot_rows[i] < n8)
+    order = sorted(range(len(hot_rows)),
+                   key=lambda i: (hot_rows[i] & SLOT_ID_MASK) < n8)
     hot_rows[:] = [hot_rows[i] for i in order]
     hot_w[:] = [hot_w[i] for i in order]
 
 
-def _add_hot_terms(acc, cnt, dense, wide, inv_norm, hot_ids, hot_w, signed):
+def _add_hot_terms(acc, cnt, dense, wide, inv_norm, hot_ids, hot_w, signed,
+                   clauses=False):
     """The hot-term pass of both fused programs over a field's two dense
     planes: `dense` uint8[n8, n] and, where some hot term's tf passes
     DENSE_TF_MAX, `wide` uint16[n16, n] (hot id r >= n8 is its row
     r - n8). Without `wide` (every deployment whose documents are short)
-    this is `_add_hot_rows` over `dense`, the same program as before."""
+    this is `_add_hot_rows` over `dense`, the same program as before.
+    With `clauses` the ids carry their clause counter above SLOT_ID_BITS
+    (`_add_hot_rows`), which taking n8 off a wide row's id leaves as it
+    is."""
     n8 = 0 if dense is None else dense.shape[0]
     if wide is None or wide.shape[0] == 0:
         if n8:
             acc, cnt = _add_hot_rows(
-                acc, cnt, dense, inv_norm, hot_ids, hot_w, signed)
+                acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
+                clauses=clauses)
         return acc, cnt
-    is_wide = hot_ids >= n8
+    row = hot_ids & SLOT_ID_MASK if clauses else hot_ids
+    is_wide = (hot_ids >= 0) & (row >= n8)
     acc, cnt = _add_hot_rows(
         acc, cnt, wide, inv_norm, jnp.where(is_wide, hot_ids - n8, -1),
-        hot_w, signed, from_first_used=True)
+        hot_w, signed, from_first_used=True, clauses=clauses)
     if n8:
         acc, cnt = _add_hot_rows(
             acc, cnt, dense, inv_norm, jnp.where(is_wide, -1, hot_ids),
-            hot_w, signed, from_first_used=True)
+            hot_w, signed, from_first_used=True, clauses=clauses)
     return acc, cnt
 
 
 def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
-                  from_first_used=False):
+                  from_first_used=False, clauses=False):
     """Adds a launch's dense hot-term rows to its accumulators: `acc`
     f32[B, n], `cnt` i32[B, >= n] or None (no count plane), `hot_ids`
     i32[B, H] rows of `dense` (-1 = unused), `hot_w` f32[B, H]. With
     `signed` a weight's sign says whether the term counts (w > 0) and
-    |w| scores (MultiFusedScorer); without, every match counts.
+    |w| scores (MultiFusedScorer); without, every match counts. With
+    `clauses` an id carries its clause counter above SLOT_ID_BITS and a
+    counted match adds that counter's unit to the count plane
+    (`clause_units`, inside the slot's own trip); without, 1.
 
     A loop over the slots the launch USES, not over the budget H, and
     one dynamic-slice per row, not a gather of rows. Measured on the
@@ -666,6 +677,8 @@ def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
         acc, cnt = carry
         hid = jax.lax.dynamic_index_in_dim(hot_ids, h, axis=1, keepdims=False)
         w = jax.lax.dynamic_index_in_dim(hot_w, h, axis=1, keepdims=False)
+        if clauses:
+            hid, unit = clause_units(hid)
         ok = hid >= 0
         safe = jnp.clip(hid, 0, dense.shape[0] - 1)
         row_tf = jnp.concatenate(
@@ -681,7 +694,9 @@ def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
         acc = acc + jnp.where(match, contrib, 0.0)
         if cnt is not None:
             counted = match & (w > 0)[:, None] if signed else match
-            cnt = cnt.at[:, :n].add(counted.astype(jnp.int32))
+            cnt = cnt.at[:, :n].add(
+                jnp.where(counted, unit[:, None], 0) if clauses
+                else counted.astype(jnp.int32))
         return acc, cnt
 
     return jax.lax.fori_loop(first, used, slot, (acc, cnt))
@@ -709,7 +724,7 @@ def rare_slots_scattered(rows: int, tiles) -> int:
 
 
 def _add_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
-                    signed):
+                    signed, clauses=False):
     """Adds a launch's rare-term postings tiles to its accumulators,
     which are FLAT: `acc` f32[B * (n + 1)] and `cnt` i32[B * (n + 1)] or
     None (no count plane) hold the B rows' planes end to end, row b's
@@ -718,7 +733,11 @@ def _add_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
     `rare_ti` i32[B, T] are tile ids into `doc_ids` / `tfs` (-1 =
     unused), `rare_tw` f32[B, T] their weights. With `signed` a weight's
     sign says whether the term counts (w > 0) and |w| scores
-    (MultiFusedScorer); without, every posting counts.
+    (MultiFusedScorer); without, every posting counts. With `clauses`
+    a tile id carries its clause counter above SLOT_ID_BITS and a counted
+    posting adds that counter's unit to the count plane (`clause_units`,
+    on a trip's own C ids: nothing is decoded in front of the loop);
+    without, 1: the same one scatter a trip either way.
 
     A loop over the slots the launch USES, RARE_CHUNK at a trip, not
     one pass over the budget T: everything that is proportional to
@@ -749,6 +768,8 @@ def _add_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
         acc, cnt = carry
         ti = jax.lax.dynamic_slice_in_dim(rare_ti, i * C, C, axis=1)
         tw = jax.lax.dynamic_slice_in_dim(rare_tw, i * C, C, axis=1)
+        if clauses:
+            ti, unit = clause_units(ti)
         safe = jnp.clip(ti, 0, last_tile)
         rows_d = doc_ids[safe]  # [B, C, 128]
         rows_t = tfs[safe]
@@ -760,7 +781,9 @@ def _add_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
         acc = acc.at[tgt].add(jnp.where(valid, s, 0.0).ravel())
         if cnt is not None:
             counted = valid & (tw > 0)[:, :, None] if signed else valid
-            cnt = cnt.at[tgt].add(counted.astype(jnp.int32).ravel())
+            add = (jnp.where(counted, unit[:, :, None], 0) if clauses
+                   else counted.astype(jnp.int32))
+            cnt = cnt.at[tgt].add(add.ravel())
         return acc, cnt
 
     return jax.lax.fori_loop(0, (used + C - 1) // C, chunk, (acc, cnt))
@@ -835,9 +858,66 @@ def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, wide=None, *,
 #     "sum" = most_fields, "max_tie" = best_fields/dis_max
 #     (DisjunctionMaxQuery: max + tie_breaker * (sum - max)).
 #
+#   * clause-level counts (PR 33; BooleanQuery counts CLAUSES, a clause
+#     of several words matches on any of them): the one int32 count
+#     plane holds two kinds of counter. Its low COUNT_TERM_BITS count
+#     the hits of counted clauses of ONE term, as the whole plane did
+#     before (a flat plan - multi_match, most_fields, term-only bools -
+#     uses nothing else and reads as it always has); above them stand
+#     CLAUSE_DIGITS digits of CLAUSE_DIGIT_BITS bits, one a counted
+#     clause of SEVERAL terms, which count that clause's terms the
+#     document holds. A slot says which counter it feeds in the bits of
+#     its tile / row id above SLOT_ID_BITS (0: the term counter; d + 1:
+#     digit d), so the plan is no wider than it was, and a counted
+#     posting adds that counter's unit in the scatter it already made. A
+#     document's matched clauses are then its term counter plus its
+#     nonzero digits, held to `msm` (`clauses_hit`): every `must` clause
+#     (msm = their number) or `minimum_should_match` should clauses. A
+#     digit holds 2**CLAUSE_DIGIT_BITS - 1 terms and there are
+#     CLAUSE_DIGITS of them: the planner (search/batcher.py) turns away
+#     what passes either.
+#
 # Everything else follows the single-field fused design: one packed
 # int32 plan upload, whole query phase on device, one packed download.
 # ---------------------------------------------------------------------------
+
+SLOT_ID_BITS = 27  # a tile or dense-row id; the slot's counter above them
+SLOT_ID_MASK = (1 << SLOT_ID_BITS) - 1
+COUNT_TERM_BITS = 16  # the count plane's low bits: one-term clauses hit
+CLAUSE_DIGITS = 4  # counted clauses of several terms a plan may hold
+CLAUSE_DIGIT_BITS = 4
+CLAUSE_TERMS_MAX = (1 << CLAUSE_DIGIT_BITS) - 1  # terms of such a clause
+
+
+def clause_slot_ids(ids, count: int):
+    """Plan slot ids (tiles or dense rows) of a term whose `count` is
+    the planner's (batcher.FieldGroup): 0 or 1, the ids as they are (a
+    term that only scores, or feeds the term counter); 2 + d, the ids
+    with digit d's counter, d + 1, above SLOT_ID_BITS."""
+    return [i | ((count - 1) << SLOT_ID_BITS) for i in ids] if count > 1 else ids
+
+
+def clause_units(ids):
+    """(plain ids, units) of a plan's slot ids (int32, any shape; -1 =
+    unused): the tile / row ids without their counter bits, and what a
+    counted hit of each slot adds to the count plane."""
+    used = ids >= 0
+    counter = ids >> SLOT_ID_BITS
+    shift = jnp.where(
+        counter > 0,
+        COUNT_TERM_BITS - CLAUSE_DIGIT_BITS + CLAUSE_DIGIT_BITS * counter, 0)
+    return (jnp.where(used, ids & SLOT_ID_MASK, -1),
+            jnp.where(used, jnp.left_shift(jnp.int32(1), shift), 0))
+
+
+def clauses_hit(cnt):
+    """Counted clauses each document matched, of a count plane built
+    from `clause_units`: its one-term clauses' hits plus its multi-term
+    clauses' nonzero digits."""
+    digits = jax.lax.shift_right_logical(cnt, jnp.int32(COUNT_TERM_BITS))
+    nz = digits | (digits >> 1)
+    nz = (nz | (nz >> 2)) & 0x1111  # a digit's lowest bit: any bit set
+    return (cnt & ((1 << COUNT_TERM_BITS) - 1)) + jax.lax.population_count(nz)
 
 
 class MultiFusedScorer:
@@ -845,7 +925,9 @@ class MultiFusedScorer:
 
     Per-field plan section (int32[2*T + 2*H]): rare tile ids + signed
     float32 weights (bitcast) + dense hot row ids + signed hot weights.
-    Trailing int32: msm (count threshold over POSITIVE-weight slots).
+    A POSITIVE weight counts, into the counter its slot's id names above
+    SLOT_ID_BITS (`clause_slot_ids`). Trailing int32: msm, the counted
+    clauses a document must match (`clauses_hit`).
     """
 
     def __init__(self, fields, parts, live, t_rare=FUSED_T_RARE,
@@ -857,6 +939,8 @@ class MultiFusedScorer:
         self.n_docs = int(parts[0]["inv_norm"].shape[0])
         self.t_rare = t_rare
         self.n_hot_slots = n_hot_slots
+        if any(p["doc_ids"].shape[0] > SLOT_ID_MASK for p in parts):
+            raise ValueError("a field's tiles pass the plan's slot ids")
 
     @property
     def plan_shape(self):
@@ -971,6 +1055,7 @@ def _fused_query_mf(
 
     # rare terms of every field first: the tile slots in use, into flat
     # planes (one count plane over the fields); |w| scores, w>0 counts
+    # its slot's unit
     cnt = jnp.zeros(B * (n + 1), jnp.int32)
     accs = []
     for f in range(F):
@@ -978,6 +1063,7 @@ def _fused_query_mf(
         acc, cnt = _add_rare_tiles(
             jnp.zeros(B * (n + 1), jnp.float32), cnt, doc_ids_f[f],
             tfs_f[f], inv_norm_f[f], rare_ti, rare_tw, signed=True,
+            clauses=True,
         )
         accs.append(_doc_planes(acc, B, n))
     cnt = _doc_planes(cnt, B, n)
@@ -986,7 +1072,7 @@ def _fused_query_mf(
         _, _, hot_ids, hot_w = section(f)
         accs[f], cnt = _add_hot_terms(
             accs[f], cnt, dense_f[f], wide_f[f], inv_norm_f[f],
-            hot_ids, hot_w, signed=True,
+            hot_ids, hot_w, signed=True, clauses=True,
         )
     if F == 1:
         combined = accs[0]
@@ -998,7 +1084,7 @@ def _fused_query_mf(
         stack = jnp.stack(accs)
         best = stack.max(axis=0)
         combined = best + tie * (stack.sum(axis=0) - best)
-    mask = cnt >= jnp.maximum(msm, 1)[:, None]
+    mask = clauses_hit(cnt) >= jnp.maximum(msm, 1)[:, None]
     if live is not None:
         mask = mask & live[None, :]
     masked = jnp.where(mask, combined, -jnp.inf)

@@ -591,6 +591,7 @@ class RestActions:
             "shed_dead_jobs": 0, "cancelled_jobs": 0,
             "serve_fallback_jobs": 0, "serve_launches": 0,
             "serve_rare_tiles": 0, "serve_hot_rows": 0,
+            "serve_clauses": 0, "serve_multi_term_clauses": 0,
             "fused_rare_tiles": 0,
         }
         # the serving pipeline: the workers' in-flight ring bound, the
@@ -614,6 +615,10 @@ class RestActions:
             # groups launched beside another group of their batch (a
             # hybrid request's legs), of `launches_by_bucket`'s groups
             "groups_launched_together": 0,
+            # query-only searches of a jax shard that no planner took:
+            # they ran on the unbatched executor (0 from the node's
+            # start, so a window without one reads 0)
+            "unplanned_queries": 0,
             # tile slots the fused launches' rare-term pass scattered,
             # of the slots of their budget (rows x 256 a field)
             "rare_slots_scattered": 0,
@@ -659,7 +664,7 @@ class RestActions:
                 batching["occupancy_slots"] += bs["occupancy_slots"]
                 batching["express_lane_hits"] += bs["express_lane_hits"]
                 for k in ("direct_collect_groups",
-                          "groups_launched_together",
+                          "groups_launched_together", "unplanned_queries",
                           "rare_slots_scattered", "rare_slots_budget"):
                     batching[k] += bs[k]
                 batching["warmup_failures"] += bs["warmup_failures"]
@@ -885,6 +890,12 @@ class RestActions:
                             "serve_launches": batch["serve_launches"],
                             "serve_rare_tiles": batch["serve_rare_tiles"],
                             "serve_hot_rows": batch["serve_hot_rows"],
+                            # counted clauses over serve jobs, and those
+                            # of more than one term
+                            "serve_clauses": batch["serve_clauses"],
+                            "serve_multi_term_clauses": batch[
+                                "serve_multi_term_clauses"
+                            ],
                             # the match family's twin of serve_rare_tiles
                             "fused_rare_tiles": batch["fused_rare_tiles"],
                         }

@@ -187,29 +187,47 @@ def extract_match_plan(
 
 @dataclass(frozen=True)
 class FieldGroup:
-    """One field's flat term list: (term, boost_multiplier, counted).
-    `counted` terms contribute to the match-count threshold (bool MUST
-    clauses); uncounted terms only score (bool SHOULD next to a must)."""
+    """One field's flat term list: (term, boost_multiplier, count).
+    `count` says what a term's hit adds to the match count: 0 nothing,
+    the term only scores (bool SHOULD next to a must); 1 one, the term
+    is a counted clause of its own; 2 + d the term belongs to counted
+    clause d of several terms, which counts once however many of its
+    terms a document holds (d < scoring.CLAUSE_DIGITS, shared by the
+    plan's fields)."""
 
     field: str
-    terms: Tuple[Tuple[str, float, bool], ...]
+    terms: Tuple[Tuple[str, float, int], ...]
 
 
 @dataclass(frozen=True)
 class ServePlan:
     """A bool / multi_match query reduced to per-field weighted-term
     groups for the multi-field fused kernel (round-5 extension of
-    MatchPlan; BASELINE configs 2 and 3)."""
+    MatchPlan; BASELINE configs 2 and 3). Every term of every group
+    scores; a document matches when at least `msm` of the plan's
+    COUNTED CLAUSES hold a term of theirs in it, as BooleanQuery counts
+    (`FieldGroup` says which terms are which clause)."""
 
     groups: Tuple[FieldGroup, ...]
-    msm: int  # threshold over counted terms
+    msm: int  # counted clauses a document must match
     combine: str  # "sum" (bool, most_fields) | "max_tie" (best_fields)
     tie: float
     boost: float
+    # the query's counted clauses, and those of more than one term (a
+    # should-only bool at msm 1 and a multi_match count them term by
+    # term: any hit passes)
+    clauses: int = 1
+    multi_term_clauses: int = 0
 
     @property
     def fields(self) -> Tuple[str, ...]:
         return tuple(g.field for g in self.groups)
+
+    @property
+    def counts_clauses(self) -> bool:
+        """Some clause of several terms counts once (the count plane's
+        digits): a plan the term-counting mesh twin cannot take."""
+        return any(t[2] > 1 for g in self.groups for t in g.terms)
 
 
 @dataclass(frozen=True)
@@ -268,9 +286,14 @@ def extract_sparse_plan(query, mappings) -> Optional[SparsePlan]:
     )
 
 
-def _clause_terms(q, mappings, analysis) -> Optional[Tuple[str, List[str], float]]:
-    """(field, analyzed terms, boost) for a match/term clause on a text
-    field, or None when the clause can't ride the fused plan."""
+def _clause_terms(
+    q, mappings, analysis, nested: bool = True
+) -> Optional[List[Tuple[str, str, float]]]:
+    """[(field, analyzed term, boost)] of one bool clause that matches
+    on ANY of its terms and scores all of them: a `term` or a `match`
+    (operator or) on a text field, or (`nested`) a bool of only such
+    `should` clauses at its default minimum_should_match. None when the
+    clause can't ride the fused plan."""
     if isinstance(q, dsl.MatchQuery):
         mf = mappings.get(q.field)
         if mf is None or mf.type != TEXT:
@@ -283,31 +306,51 @@ def _clause_terms(q, mappings, analysis) -> Optional[Tuple[str, List[str], float
         except ValueError:
             return None
         if not terms or (q.operator == "and" and len(terms) > 1):
-            # a multi-term AND clause needs clause-local counting the
-            # flat plan can't express
+            # every word required INSIDE a clause: not a count of clauses
             return None
-        return q.field, terms, q.boost
+        return [(q.field, t, q.boost) for t in terms]
     if isinstance(q, dsl.TermQuery):
         mf = mappings.get(q.field)
         if mf is None or mf.type != TEXT:
             return None
-        return q.field, [dsl.term_token(q.value)], q.boost
+        return [(q.field, dsl.term_token(q.value), q.boost)]
+    if nested and isinstance(q, dsl.BoolQuery):
+        if (q.must or q.filter or q.must_not or not q.should
+                or q.minimum_should_match is not None):
+            return None
+        out: List[Tuple[str, str, float]] = []
+        for c in q.should:
+            got = _clause_terms(c, mappings, analysis, nested=False)
+            if got is None:
+                return None
+            out.extend((f, t, b * q.boost) for f, t, b in got)
+        return out
     return None
 
 
 def extract_serve_plan(
     query, mappings, analysis
 ) -> Optional[ServePlan]:
-    """Reduces a bool (must/should of single-field text clauses) or a
-    multi_match (best_fields/most_fields, operator=or) to a ServePlan
-    for the multi-field fused kernel. None → normal executor path.
+    """Reduces a bool (must/should of text clauses) or a multi_match
+    (best_fields/most_fields, operator=or) to a ServePlan for the
+    multi-field fused kernel. None → normal executor path.
 
-    Count semantics (the flat-plan subset of BooleanQuery):
-      * must clauses must be single-term → each term counted, msm = #must
-      * should clauses score only (uncounted) when musts exist; with no
-        must, all terms counted and msm = minimum_should_match (default
-        1), rejecting multi-term clauses when msm > 1 (clause-level vs
-        term-level counting diverges there).
+    Count semantics (BooleanQuery's: clauses are counted, not terms):
+      * a clause is a term, a match (operator or) of any number of
+        words, or a bool of such should clauses (`_clause_terms`): it
+        matches on any of its terms and all of them score;
+      * every must clause is counted, msm = #must; should clauses beside
+        them only score;
+      * with no must, every should clause is counted and msm =
+        minimum_should_match (default 1). At msm 1 any hit passes, so
+        the terms are counted one by one (a flat plan, as a multi_match
+        is); above it a clause of several terms counts once;
+      * a counted clause of several terms takes one of the count
+        plane's scoring.CLAUSE_DIGITS digits and holds at most
+        scoring.CLAUSE_TERMS_MAX terms: a query past either is turned
+        away, as are must_not, filter, `operator: and` inside a clause,
+        minimum_should_match beside must and anything that is no text
+        clause.
     """
     if isinstance(query, dsl.TermQuery):
         # a bare term on a text field is a one-term plan — without this
@@ -316,10 +359,10 @@ def extract_serve_plan(
         got = _clause_terms(query, mappings, analysis)
         if got is None:
             return None
-        field, terms, _ = got
+        field, term, _ = got[0]
         return ServePlan(
             groups=(
-                FieldGroup(field=field, terms=((terms[0], 1.0, True),)),
+                FieldGroup(field=field, terms=((term, 1.0, 1),)),
             ),
             msm=1,
             combine="sum",
@@ -330,25 +373,9 @@ def extract_serve_plan(
         if query.must_not or query.filter:
             return None
         if query.must and query.minimum_should_match is not None:
-            return None  # msm-on-should next to must: clause-level count
-        groups: Dict[str, List[Tuple[str, float, bool]]] = {}
-        n_counted = 0
+            return None  # msm-on-should next to must: two thresholds
         if query.must:
-            for c in query.must:
-                got = _clause_terms(c, mappings, analysis)
-                if got is None or len(got[1]) != 1:
-                    return None  # multi-term must → clause-local OR
-                field, terms, cb = got
-                groups.setdefault(field, []).append((terms[0], cb, True))
-                n_counted += 1
-            for c in query.should:
-                got = _clause_terms(c, mappings, analysis)
-                if got is None:
-                    return None
-                field, terms, cb = got
-                for t in terms:
-                    groups.setdefault(field, []).append((t, cb, False))
-            msm = n_counted
+            counted, scored, msm = query.must, query.should, len(query.must)
         else:
             if not query.should:
                 return None
@@ -359,19 +386,31 @@ def extract_serve_plan(
                 # explicit msm of 0 means every doc matches (the oracle
                 # applies no count mask) — not expressible here
                 return None
-            multi_ok = msm_req <= 1
-            for c in query.should:
-                got = _clause_terms(c, mappings, analysis)
-                if got is None:
-                    return None
-                field, terms, cb = got
-                if len(terms) > 1 and not multi_ok:
-                    return None
-                for t in terms:
-                    groups.setdefault(field, []).append((t, cb, True))
-            msm = max(1, msm_req)
-        if not groups:
-            return None
+            counted, scored, msm = query.should, [], max(1, msm_req)
+        groups: Dict[str, List[Tuple[str, float, int]]] = {}
+        multi = 0  # counted clauses of several terms
+        digits = 0  # those that take a digit of the count plane
+        for c in counted:
+            got = _clause_terms(c, mappings, analysis)
+            if got is None:
+                return None
+            count = 1
+            if len(got) > 1:
+                multi += 1
+                if msm > 1:
+                    if (digits == scoring.CLAUSE_DIGITS
+                            or len(got) > scoring.CLAUSE_TERMS_MAX):
+                        return None
+                    count = 2 + digits
+                    digits += 1
+            for field, t, cb in got:
+                groups.setdefault(field, []).append((t, cb, count))
+        for c in scored:
+            got = _clause_terms(c, mappings, analysis)
+            if got is None:
+                return None
+            for field, t, cb in got:
+                groups.setdefault(field, []).append((t, cb, 0))
         return ServePlan(
             groups=tuple(
                 FieldGroup(field=f, terms=tuple(ts))
@@ -381,6 +420,8 @@ def extract_serve_plan(
             combine="sum",
             tie=0.0,
             boost=query.boost,
+            clauses=len(counted),
+            multi_term_clauses=multi,
         )
     if isinstance(query, dsl.MultiMatchQuery):
         if query.type not in ("best_fields", "most_fields"):
@@ -404,7 +445,7 @@ def extract_serve_plan(
             groups_l.append(
                 FieldGroup(
                     field=field,
-                    terms=tuple((t, fboost, True) for t in terms),
+                    terms=tuple((t, fboost, 1) for t in terms),
                 )
             )
         if not groups_l:
@@ -417,6 +458,8 @@ def extract_serve_plan(
             ),
             tie=float(query.tie_breaker or 0.0),
             boost=query.boost,
+            clauses=len(groups_l),
+            multi_term_clauses=sum(len(g.terms) > 1 for g in groups_l),
         )
     return None
 
@@ -910,6 +953,14 @@ class QueryBatcher:
             "serve_launches": 0,
             "serve_rare_tiles": 0,
             "serve_hot_rows": 0,
+            # counted clauses over all serve jobs, and those of more
+            # than one term (ServePlan.clauses / .multi_term_clauses)
+            "serve_clauses": 0,
+            "serve_multi_term_clauses": 0,
+            # query-only searches of a jax shard that neither planner
+            # (extract_match_plan, extract_serve_plan) gave a plan: they
+            # ran on the unbatched executor (`note_unplanned`)
+            "unplanned_queries": 0,
             # the match family's twin of `serve_rare_tiles`, and how far
             # the rare-term pass's loop engages, over fused launches of
             # both families and their fields: tile slots the launches
@@ -1357,6 +1408,14 @@ class QueryBatcher:
             self._occ_jobs += njobs
             self._occ_slots += rows
 
+    def note_unplanned(self) -> None:
+        """A query-only search of a jax shard left for the unbatched
+        executor: neither planner gave it a plan (cluster/indices.py,
+        the shard path, which the mesh twin and a retriever's leg fall
+        through to)."""
+        with self._lock:
+            self.stats["unplanned_queries"] += 1
+
     def batching_stats(self) -> dict:
         """The continuous-batching block for `_nodes/stats`: per-bucket
         launch histogram, occupancy sums (raw, so windows can diff),
@@ -1370,6 +1429,7 @@ class QueryBatcher:
             express = self.stats["express_lane_hits"]
             direct = self.stats["direct_collect_groups"]
             together = self.stats["groups_launched_together"]
+            unplanned = self.stats["unplanned_queries"]
             scattered = self.stats["rare_slots_scattered"]
             budget = self.stats["rare_slots_budget"]
             warm_failed = self.stats["warmup_failures"]
@@ -1392,6 +1452,8 @@ class QueryBatcher:
             "direct_collect_groups": direct,
             # groups launched beside another group of their batch
             "groups_launched_together": together,
+            # query-only searches no planner took (unbatched executor)
+            "unplanned_queries": unplanned,
             # tile slots fused launches' rare-term pass scattered, and
             # the slots of their whole budget (rows x t_rare a field)
             "rare_slots_scattered": scattered,
@@ -1771,6 +1833,10 @@ class QueryBatcher:
                         self.stats["serve_launches"] += 1
                         self.stats["serve_rare_tiles"] += sum(rare)
                         self.stats["serve_hot_rows"] += sum(hot)
+                        self.stats["serve_clauses"] += sum(
+                            j.plan.clauses for j in jobs)
+                        self.stats["serve_multi_term_clauses"] += sum(
+                            j.plan.multi_term_clauses for j in jobs)
                         for f in range(len(fields)):  # secs: job-major
                             self._count_rare_slots(
                                 rows, fs.t_rare, rare[f::len(fields)])
@@ -1780,6 +1846,8 @@ class QueryBatcher:
                     t["fields"] = len(fields)
                     t["hot_slots"] = max(t.get("hot_slots", 0), *hot)
                     t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
+                    t["clauses"] = max(j.plan.clauses for j in jobs)
+                    t["msm"] = max(j.plan.msm for j in jobs)
                     n_docs = ex.reader.segments[si].num_docs
                     _group_now().add_flops(sum(
                         scoring.text_plan_flops(r, h, n_docs)

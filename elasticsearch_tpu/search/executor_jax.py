@@ -1215,7 +1215,10 @@ class JaxExecutor:
         (rare_tiles, rare_w_signed, hot_ranks, hot_w_signed) — weight
         sign marks whether a term counts toward the match threshold
         (positive = required/counted). terms_flagged: [(term, term_boost,
-        counted)]. None on slot-budget overflow."""
+        count)], `count` as FieldGroup gives it: 0 scores only, 1 a
+        clause of its own, 2 + d a term of multi-term clause d, whose
+        slots carry that clause's counter above their ids
+        (scoring.clause_slot_ids). None on slot-budget overflow."""
         pf = self.reader.segments[si].postings.get(field)
         if pf is None:
             return (
@@ -1227,7 +1230,7 @@ class JaxExecutor:
         rw: list = []
         hr: list = []
         hw: list = []
-        for t, tb, counted in terms_flagged:
+        for t, tb, count in terms_flagged:
             tid = pf.term_id(t)
             if tid < 0:
                 continue
@@ -1241,16 +1244,16 @@ class JaxExecutor:
                 # nudge to the smallest positive float so required terms
                 # still count (score contribution is ~0 either way)
                 w = 1e-30
-            if not counted:
+            if not count:
                 w = -w
             r = parts["hot_rank"].get(tid)
             if r is not None:
-                hr.append(r)
+                hr.extend(scoring.clause_slot_ids([r], count))
                 hw.append(w)
             else:
                 s0 = int(pf.term_tile_start[tid])
                 c = int(pf.term_tile_count[tid])
-                rt.extend(range(s0, s0 + c))
+                rt.extend(scoring.clause_slot_ids(range(s0, s0 + c), count))
                 rw.extend([w] * c)
         if len(rt) > scoring.FUSED_T_RARE or len(hr) > scoring.FUSED_H:
             return None

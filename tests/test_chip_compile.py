@@ -194,6 +194,29 @@ def test_fused_multi_field_program_at_document_length(one_chip, rows):
     _fits(compiled)
 
 
+def test_fused_bool_program_at_passage_shapes(one_chip):
+    """`bool` as the Boolean deployment sends it (the benchmark's
+    `msmarco-bool-wand.solo`): the same program over ONE field with the
+    "sum" combine, one row a launch, whose count plane holds the clause
+    counters (`scoring.clauses_hit`: shifts and a population count)."""
+    s = _on(one_chip)
+    compiled = scoring._fused_query_mf.lower(
+        (s((BODY_TILES, TILE), jnp.int32),),
+        (s((BODY_TILES, TILE), jnp.int32),),
+        (s((N_DOCS,), jnp.float32),),
+        (s((BODY_HOT, N_DOCS), jnp.uint8),),
+        None,
+        s((1, _plan_width(1)), jnp.int32),
+        s((), jnp.float32),
+        t_rare=scoring.FUSED_T_RARE,
+        n_hot=scoring.FUSED_H,
+        k=16,
+        combine="sum",
+    ).compile()
+    _fits(compiled)
+    assert "popcnt" in compiled.as_text() or "population" in compiled.as_text()
+
+
 @pytest.mark.parametrize("family", ["match", "serve"])
 def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
     """The rare-term pass compiles as a `while` over chunks of

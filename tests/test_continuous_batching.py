@@ -878,6 +878,7 @@ class TestBatchingStats:
             "warmup_failures", "worker_compile_ms", "worker_compiles",
             "fused_hot_slots", "serve_hot_slots", "direct_collect_groups",
             "rare_slots_scattered", "rare_slots_budget",
+            "unplanned_queries",
             "groups_launched_together",
         }
         assert bs["warmup_failures"] == 0

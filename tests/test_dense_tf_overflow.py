@@ -198,6 +198,35 @@ def test_fused_paths_keep_tf_over_255_exact(service, oracle, monkeypatch,
     assert set(served["t4000"][0][:2]) == {"5", "9"}
 
 
+@pytest.mark.parametrize("query", [
+    # a uint16 and a uint8 row in ONE counted clause of two words: their
+    # slot ids carry the clause's counter through the split of the planes
+    {"bool": {"must": [{"term": {"body": "rare03"}},
+                       {"match": {"body": "t4000 h1"}}]}},
+    {"bool": {"must": [{"match": {"body": "t256 rare05"}},
+                       {"match": {"body": "t4000 t255 rare07"}}]}},
+    {"bool": {"should": [{"match": {"body": "rare01 rare02"}},
+                         {"match": {"body": "t256 rare03"}},
+                         {"term": {"title": "onlytitle"}}],
+              "minimum_should_match": 2}},
+], ids=["term_and_both_planes", "two_clauses_both_planes",
+        "should_msm2_two_fields"])
+def test_clause_counts_ride_wide_and_uint8_rows(service, oracle, monkeypatch,
+                                                query):
+    with_budget(service, monkeypatch, "ample")
+    stats = service._batcher.stats
+    before = dict(stats)
+    body = {"query": query, "size": 10, "track_total_hits": True}
+    ids, scores, total = page(search(service, body))
+    assert stats["fused_jobs"] == before["fused_jobs"] + 1
+    assert stats["serve_fallback_jobs"] == before["serve_fallback_jobs"]
+    assert stats["serve_multi_term_clauses"] > before[
+        "serve_multi_term_clauses"]
+    want_ids, want_scores, want_total = page(search(oracle, body))
+    assert ids == want_ids and total == want_total and total["value"] > 0
+    np.testing.assert_allclose(scores, want_scores, rtol=1e-6)
+
+
 def traced_dispatch_tags(svc, body: dict) -> dict:
     tracing.clear()
     handle = tracing.begin("search", index=svc.name)

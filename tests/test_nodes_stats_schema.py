@@ -16,7 +16,7 @@ REQUIRED = {
         "buckets", "launches_by_bucket", "occupancy_jobs",
         "occupancy_slots", "express_lane_hits", "avg_occupancy",
         "warmup_failures", "worker_compile_ms", "worker_compiles",
-        "groups_launched_together",
+        "groups_launched_together", "unplanned_queries",
     },
     "pipeline.mesh": {
         "routed", "launches", "jobs", "rebuilds", "degraded",
@@ -47,6 +47,10 @@ REQUIRED = {
     "ingest": {"refreshers_running"},
     "breakers": {"hbm"},
     "thread_pool": {"search"},
+    "thread_pool.search": {
+        "queue_capacity", "completed", "rejected", "launches",
+        "serve_fallback_jobs", "serve_clauses", "serve_multi_term_clauses",
+    },
     "transfer.scoring": {
         "h2d_count", "h2d_bytes", "d2h_count", "d2h_bytes",
     },

@@ -36,12 +36,14 @@ TIE = np.float32(0.3)
 
 
 def one_pass_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
-                        signed):
+                        signed, clauses=False):
     """The plain form: gather, score and scatter-add every slot of the
     budget at once, used or not, a scatter a row (the rare pass of both
     programs before the loop), on `_add_rare_tiles`' flat planes."""
     n = inv_norm.shape[0]
     B = rare_ti.shape[0]
+    if clauses:  # ids carry their clause counter: its unit counts
+        rare_ti, units = scoring.clause_units(rare_ti)
     tile_ok = rare_ti >= 0
     safe = jnp.clip(rare_ti, 0, doc_ids.shape[0] - 1)
     rows_d = doc_ids[safe]  # [B, T, 128]
@@ -56,6 +58,8 @@ def one_pass_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
         acc.reshape(B, n + 1), tgt, s).ravel()
     if cnt is not None:
         counted = valid & (rare_tw > 0)[:, :, None] if signed else valid
+        if clauses:
+            counted = jnp.where(counted, units[:, :, None], 0)
         cnt = jax.vmap(
             lambda c, d, v: c.at[d.ravel()].add(v.ravel().astype(jnp.int32))
         )(cnt.reshape(B, n + 1), tgt, counted).ravel()
