@@ -3369,10 +3369,11 @@ class IndexService:
         are submitted through the QueryBatcher's async future API so the
         BM25 and kNN device kernels overlap; everything else fans out on
         the shared thread pool. Both legs share one rank_window_size
-        candidate budget, and when every leg came back with integer
-        (segment, doc) identity from one executor the fusion itself runs
-        on device (ops/fusion.rrf_fuse_device) with the host dict fuse
-        kept as fallback + oracle.
+        candidate budget. The fusion is the host's
+        (ops/fusion.rrf_fuse_ranked) over the hits the legs' waiters
+        returned: keyed by the global doc when every leg came back with
+        integer (segment, doc) identity from one executor, by `_id`
+        otherwise; nothing crosses to the device.
 
         Generation pinning: the per-shard executors are resolved ONCE,
         up front, and every phase — leg search, rescore, fetch — reads
@@ -3589,6 +3590,8 @@ class IndexService:
     ) -> List[tuple]:
         """Concurrent child legs + fusion. All legs share ONE
         rank_window_size candidate budget."""
+        from ..ops.fusion import rrf_fuse_ranked
+
         rank_constant = int(params.get("rank_constant", 60))
         window2 = int(params.get("rank_window_size", max(window, size)))
         children = params.get("retrievers", [])
@@ -3613,33 +3616,38 @@ class IndexService:
                 )
         legs = [self._wait_leg(h, window2, extra_filter, t_start_ns, pins)
                 for h in handles]
-        # the last leg's waiter is awake: what follows is the fuse
+        # the last leg's waiter is awake: what follows is the fuse, on
+        # the host over the hits the waiters returned (no transfer)
         t_fuse_ns = time.perf_counter_ns()
-        fused: Optional[List[tuple]] = None
-        device = False
-        moved = (0, 0)  # the fuse's upload and download, bytes
         executors = {id(l["ex"]) for l in legs if l["ex"] is not None}
-        if (
-            len(legs) >= 2
-            and all(l["td"] is not None for l in legs)
-            and len(executors) == 1
-        ):
-            fused, *moved = self._fuse_legs_device(
-                legs, window2, rank_constant
-            )
-            device = True
-        if fused is None:
-            # host fallback/oracle: dict accumulation, tie-break on
-            # ascending doc id string (pre-concurrency semantics)
-            acc: Dict[str, float] = {}
+        if all(l["td"] is not None for l in legs) and len(executors) == 1:
+            # every leg came back from one executor with (segment,
+            # local_doc): the key is the global doc (segment base +
+            # local doc), so ties break on ascending (segment, doc) as
+            # in every other merge of the engine
+            bases = [0]
+            for seg in legs[0]["ex"].reader.segments:
+                bases.append(bases[-1] + seg.num_docs)
+            names: Dict[int, str] = {}
+            keyed = []
             for leg in legs:
-                for rank, (doc_id, _) in enumerate(leg["ranked"], 1):
-                    acc[doc_id] = acc.get(doc_id, 0.0) + 1.0 / (
-                        rank_constant + rank
-                    )
-            fused = sorted(acc.items(), key=lambda kv: (-kv[1], kv[0]))[
-                :window2
+                gids = []
+                for h in leg["td"].hits:
+                    gid = bases[h.segment] + h.local_doc
+                    names[gid] = h.doc_id
+                    gids.append(gid)
+                keyed.append(gids)
+            fused = [
+                (names[g], sc)
+                for g, sc in rrf_fuse_ranked(keyed, window2, rank_constant)
             ]
+        else:
+            # legs without integer identity (thread-pool legs, several
+            # shards): keyed by `_id`, ties on the ascending id string
+            fused = rrf_fuse_ranked(
+                [[doc_id for doc_id, _ in leg["ranked"]] for leg in legs],
+                window2, rank_constant,
+            )
         t_end_ns = time.perf_counter_ns()
         fuse_ns = t_end_ns - t_fuse_ns
         # the legs' hits that came back with (segment, local_doc): what
@@ -3655,7 +3663,7 @@ class IndexService:
             st = self.rrf_stats
             st["searches"] += 1
             st["fuse_ms"] += fuse_ns / 1e6
-            st["device_fused" if device else "host_fused"] += 1
+            st["host_fused"] += 1
             for leg in legs:
                 if leg["label"] in ("bm25", "knn", "sparse"):
                     st[f"{leg['label']}_leg_ms"] += leg["ms"]
@@ -3675,11 +3683,11 @@ class IndexService:
                     entry["profile"] = leg["sub_profile"]
                 out_legs.append(entry)
             prof_out["fuse_ns"] = prof_out.get("fuse_ns", 0) + fuse_ns
-            prof_out["fused_on_device"] = device
+            prof_out["fused_on_device"] = False
         if tr is not None:
             tr.add_span(
                 "rrf", t_start_ns, time.perf_counter_ns(), span_id=rrf_id,
-                index=self.name, legs=len(legs), device_fused=device,
+                index=self.name, legs=len(legs), device_fused=False,
             )
             for leg, leg_id in zip(legs, leg_ids):
                 # a leg ends at its own completion mark, whatever leg
@@ -3691,8 +3699,7 @@ class IndexService:
                 )
             tr.add_span(
                 "fuse", t_fuse_ns, t_end_ns, parent_id=rrf_id,
-                device=device, window=window2,
-                h2d_bytes=moved[0], d2h_bytes=moved[1],
+                device=False, window=window2, h2d_bytes=0, d2h_bytes=0,
             )
         return fused
 
@@ -3931,49 +3938,6 @@ class IndexService:
             "end_ns": end_ns,
             "ms": (end_ns - t_start_ns) / 1e6,
         }
-
-    def _fuse_legs_device(
-        self, legs: List[dict], k: int, rank_constant: int
-    ) -> Optional[List[tuple]]:
-        """Device-side RRF over the legs' top-window (segment, doc)
-        arrays: global int doc ids (segment-base + local doc) keep
-        exact-doc identity, fusion + dedup + top-k run as one jitted
-        program (ops/fusion), and winners map back to _id strings on the
-        host. Tie-break is ascending global doc — the same (segment,
-        doc) asc order every other merge in the engine uses. Legs pad to
-        the rows of one fixed [n_legs, window] array, so the kernel
-        compiles once per (n_legs, window, k), the launch carries one
-        upload and the answer is one packed download. Returns (fused
-        [(doc_id, score)], uploaded bytes, downloaded bytes)."""
-        from ..ops.fusion import rrf_fuse_request
-        from ..ops.scoring import rank_order
-
-        import numpy as np
-
-        ex = next(l["ex"] for l in legs if l["ex"] is not None)
-        bases = [0]
-        for seg in ex.reader.segments:
-            bases.append(bases[-1] + seg.num_docs)
-        id_map: Dict[int, str] = {}
-        width = max(int(k), 1)
-        ranked = np.full((len(legs), width), -1, np.int32)
-        for li, leg in enumerate(legs):
-            hits = leg["td"].hits[:width]
-            gids = [bases[h.segment] + h.local_doc for h in hits]
-            ranked[li, :len(gids)] = gids
-            id_map.update(zip(gids, (h.doc_id for h in hits)))
-        s, d = rrf_fuse_request(ranked, k, rank_constant)
-        # a leg's rank i ties the other's rank i exactly: ascending
-        # global doc among equal scores is the host's to settle (the
-        # TPU's top-k returns exact ties in no particular order)
-        (s,), _, (d,) = rank_order(s[None], np.zeros((1, len(d)), d.dtype),
-                                   d[None])
-        keep = int(((d >= 0) & np.isfinite(s)).sum())  # padding sorts last
-        fused = [
-            (id_map[g], sc)
-            for g, sc in zip(d[:keep].tolist(), s[:keep].tolist())
-        ]
-        return fused, ranked.nbytes, s.nbytes + d.nbytes
 
     def count(
         self, body: Optional[dict] = None, extra_filter: Optional[dict] = None

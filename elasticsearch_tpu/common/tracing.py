@@ -38,14 +38,15 @@ the serving process; tags in brackets):
     root under the same name
       > retriever, rescore, fetch   (tile it; no fan_out: the legs of
         an rrf node go to the batcher themselves)
-    retriever > rrf [index, legs, device_fused]
+    retriever > rrf [index, legs, device_fused (false)]
       > leg:<label> [mode]   (bm25, knn, sparse, other; one per child)
             the legs' common start -> the leg's OWN completion mark (a
             batcher job's `t_done`, the end of a pool or inline run),
             whatever order the request thread waited in
       > fuse [device, window, h2d_bytes, d2h_bytes]   the last leg's
-            waiter awake -> the fused list (ops/fusion: one upload, one
-            packed download; `device: false` = the host's dict fuse)
+            waiter awake -> the fused list: the host's dictionary over
+            the legs' hits (ops/fusion.rrf_fuse_ranked), so `device` is
+            false and both byte counts are 0 on every request
     shard_search (or `leg:<label>` of an rrf retriever, or
     `mesh_search`) > the job spans of search/batcher.py, which tile the
     job's life from submit to its waiter's wake-up:
@@ -74,12 +75,12 @@ per dispatcher thread, beside the device's `XLA Ops` line.
 
 `note_transfer` counts the query path's host<->device transfers where
 they happen (ops/scoring.py, the kNN upload in search/batcher.py, the
-serve family's per-job fallback, `JaxExecutor.segment_topk`, and the
-rrf fuse's upload and download, ops/fusion.py);
+serve family's per-job fallback and `JaxExecutor.segment_topk`; the rrf
+fuse moves nothing);
 `_nodes/stats` reports the totals as `transfer.scoring.*`, and the
 hybrid searches' own counters (`IndexService.rrf_stats`: searches,
-device_fused, host_fused, fuse_ms, the legs' summed ms) as
-`pipeline.rrf.*`.
+host_fused (every search), device_fused (0: the serving path has no
+device fuse), fuse_ms, the legs' summed ms) as `pipeline.rrf.*`.
 
 `OPAQUE_ID_CTX` carries the request's `X-Opaque-Id` header value so
 task descriptions, slow-log records, and traces can all attribute work

@@ -1,39 +1,37 @@
-"""Device-side reciprocal-rank fusion (RRF) of ranked retriever legs.
+"""Reciprocal-rank fusion (RRF) of ranked retriever legs.
 
 Reference analog: x-pack rank-rrf's RRFQueryPhaseRankCoordinatorContext —
 score = Σ over legs of 1/(rank_constant + rank), exact-doc dedup, top-k.
-The reference fuses on the coordinator heap; here the legs' top-window
-(doc, score) arrays are already device-resident (or trivially uploaded),
-so the rank maps, the dedup compare, and the final top-k all run as one
-jitted program with a single [B, k] download.
 
-Used by two call sites:
-  * the serving path (`IndexService._retriever_search` /
-    `rank: {rrf: ...}`) fusing the concurrent BM25 + kNN batcher legs
-    (`rrf_fuse_request`: one upload, one packed download a request);
-  * the SPMD multi-chip path (`parallel/sharded.rrf_fuse`) fusing
-    all-gathered per-shard top-k lists.
+Two call sites, two forms:
+  * the serving path (`IndexService._run_rrf`: the `rrf` retriever and
+    `rank: {rrf: ...}`) fuses on the HOST (`rrf_fuse_ranked`): when the
+    last leg's waiter wakes, both legs' hits are Python objects already,
+    so a dictionary over legs x window keys is the whole work and nothing
+    is uploaded, launched or downloaded (PERF.md section 6, PR 34: the
+    device program's round trip was 1.56 ms a request for 200 ids);
+  * the SPMD multi-chip path (`parallel/sharded.rrf_fuse`) fuses
+    all-gathered per-shard top-k lists that are device-resident already
+    (`rrf_fuse_device`: rank maps, dedup compare and top-k as one jitted
+    program), with `rrf_fuse_host` as its NumPy oracle.
 
-Ordering contract (matched by the host oracle `rrf_fuse_host`, and by
-the engine's cross-segment merges everywhere else): fused score desc,
-then ASCENDING doc id among ties. Candidates are pre-sorted
+Ordering contract of all three: fused score desc, then ASCENDING key
+(doc id) among ties. On the device candidates are pre-sorted
 doc-ascending before the cut, so a `lax.top_k` that keeps the lowest
 index among equal scores (the CPU's) gives that tie-break by itself; the
 TPU's returns exact ties in no particular order (PERF.md section 6,
-PR 31), so the serving path puts the downloaded list in that order on
-the host (`IndexService._fuse_legs_device`).
+PR 31), which a caller that downloads the list has to settle
+(`ops/scoring.rank_order`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import Hashable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ..common.tracing import note_transfer
 
 _PAD_SORT_KEY = np.iinfo(np.int32).max
 
@@ -88,33 +86,25 @@ def rrf_fuse_device(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("rank_constant", "k"))
-def _fuse_ranked_packed(legs, rank_constant: int, k: int):
-    """`_fuse_ranked` of one query, its legs the rows of one int32
-    [n_legs, k_leg] array and its answer one int32[2, k'] array (row 0
-    the scores' bits, row 1 the docs)."""
-    s, d = _fuse_ranked(
-        tuple(legs[i][None, :] for i in range(legs.shape[0])),
-        rank_constant, k,
-    )
-    return jnp.stack([jax.lax.bitcast_convert_type(s[0], jnp.int32), d[0]])
-
-
-def rrf_fuse_request(
-    legs: np.ndarray, k: int, rank_constant: int = 60
-) -> Tuple[np.ndarray, np.ndarray]:
-    """One request's legs fused on device, as the serving path asks it:
-    `legs` a host int32[n_legs, k_leg] array of doc ids in rank order
-    (-1 padding), handed to the program as it is (the launch uploads
-    it); the answer comes back in one blocking download. Returns host
-    (scores f32[k'], docs i32[k']), padding as `rrf_fuse_device`. Both
-    transfers are noted (`transfer.scoring`)."""
-    if legs.shape[0] < 2:
-        raise ValueError("rrf fusion needs at least two legs")
-    note_transfer("h2d", legs.nbytes)
-    out = np.asarray(_fuse_ranked_packed(legs, int(rank_constant), int(k)))
-    note_transfer("d2h", out.nbytes)
-    return out[0].view(np.float32), out[1]
+def rrf_fuse_ranked(
+    legs: Sequence[Sequence[Hashable]], k: int, rank_constant: int = 60
+) -> List[Tuple[Hashable, float]]:
+    """One request's legs fused on the host, as the serving path asks
+    it: each leg its keys in rank order (global doc ints, or `_id`
+    strings where the legs have no integer identity). Returns the first
+    `k` (key, score) by score desc, then key asc. The sum is Python
+    floats in leg order: the plain reference's own arithmetic
+    (benchmarks/references/rrf_match_knn.py), so equal rank sets give
+    bit-equal scores and the served tie groups are the reference's."""
+    fused: dict = {}
+    for keys in legs:
+        for rank, key in enumerate(keys, 1):
+            fused[key] = fused.get(key, 0.0) + 1.0 / (rank_constant + rank)
+    # ascending key first: the sort by score is stable (`reverse` keeps
+    # equal scores in the order they had), so ties stay on the key
+    order = sorted(fused)
+    order.sort(key=fused.__getitem__, reverse=True)
+    return [(key, fused[key]) for key in order[:k]]
 
 
 def rrf_fuse_host(

@@ -1262,8 +1262,9 @@ def rrf_fuse(
     """Reciprocal-rank fusion of two ranked lists (x-pack rank-rrf:
     `RRFQueryPhaseRankCoordinatorContext`, score = Σ 1/(rank_constant+rank)).
 
-    Device-side via the shared ops/fusion kernel (also the serving
-    path's fuser): exact-doc dedup over the union of both lists, top-k
+    Device-side via the ops/fusion kernel (the lists are on the device
+    already; the serving path fuses host-side hits with
+    `rrf_fuse_ranked`): exact-doc dedup over the union of both lists, top-k
     with ascending-global-doc tie-break. Returns (scores[B,k],
     global_docs[B,k])."""
     from ..ops.fusion import rrf_fuse_device

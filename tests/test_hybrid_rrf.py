@@ -1,9 +1,12 @@
-"""Concurrent hybrid retrieval: async batcher futures + device RRF.
+"""Concurrent hybrid retrieval: async batcher futures + RRF fusion.
 
 Covers the tentpole contract of the hybrid pipeline:
-  * device RRF fusion (ops/fusion.rrf_fuse_device) is hit-for-hit with
-    the host oracle — ranks, scores, exact-doc dedup, and the ascending
-    doc-id tie-break;
+  * the served fuse (ops/fusion.rrf_fuse_ranked: the host's dictionary
+    over the hits the legs returned) is hit-for-hit with the NumPy
+    oracle and with the mesh path's device program — ranks, scores,
+    exact-doc dedup, and the ascending doc-id tie-break;
+  * device RRF fusion (ops/fusion.rrf_fuse_device, the mesh path's) is
+    hit-for-hit with the same oracle;
   * both hybrid legs are genuinely in flight at the same time
     (instrumented batcher counters);
   * the async submission path (`submit_nowait`) keeps the dispatcher's
@@ -19,7 +22,11 @@ import numpy as np
 import pytest
 
 from elasticsearch_tpu.cluster.indices import IndexService
-from elasticsearch_tpu.ops.fusion import rrf_fuse_device, rrf_fuse_host
+from elasticsearch_tpu.ops.fusion import (
+    rrf_fuse_device,
+    rrf_fuse_host,
+    rrf_fuse_ranked,
+)
 from elasticsearch_tpu.search.batcher import (
     EsRejectedExecutionError,
     QueryBatcher,
@@ -155,12 +162,68 @@ class TestDeviceHostParity:
         self._check(legs, k=10)
 
 
+def _random_legs(seed, n_legs, universe, width):
+    rng = np.random.default_rng(seed)
+    return [rng.permutation(universe)[:width].tolist() for _ in range(n_legs)]
+
+
+# one query's legs, each its docs in rank order, and the cut
+SERVED_FUSE_CASES = {
+    # overlapping universes force cross-leg accumulation
+    "random_legs_with_shared_documents": (_random_legs(11, 2, 30, 12), 24),
+    # leg a's rank i ties leg b's rank i: every score is a tie group of two
+    "two_legs_tied_at_every_rank": (
+        [[40, 7, 33, 2, 19, 28], [5, 41, 3, 30, 20, 11]], 12),
+    "short_leg": (_random_legs(12, 1, 30, 12) + [[4, 17]], 14),
+    "empty_leg": (_random_legs(13, 1, 30, 10) + [[]], 10),
+    "cut_smaller_than_the_union": (_random_legs(14, 2, 30, 12), 5),
+    "three_legs": (_random_legs(15, 3, 20, 8), 24),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SERVED_FUSE_CASES))
+def test_served_host_fuse_matches_oracle_and_device_program(case):
+    """What the serving path runs (`rrf_fuse_ranked`, Python floats over
+    the keys the legs returned) against the NumPy oracle and the mesh
+    path's program on the CPU, both fed the same legs as the rows of
+    one -1-padded array: same documents in the same order, scores
+    within float32's rounding of the float64 sums."""
+    legs, k = SERVED_FUSE_CASES[case]
+    served = rrf_fuse_ranked(legs, k, 60)
+    width = max(len(leg) for leg in legs)
+    padded = np.full((len(legs), width), -1, np.int32)
+    for i, leg in enumerate(legs):
+        padded[i, :len(leg)] = leg
+    rows = tuple(padded[i][None, :] for i in range(len(legs)))
+    union = set().union(*legs)
+    assert len(served) == min(k, len(union))
+    assert len({doc for doc, _ in served}) == len(served)
+    for name, (s, d) in (("host", rrf_fuse_host(rows, k, 60)),
+                         ("device", rrf_fuse_device(rows, k, 60))):
+        s, d = np.asarray(s)[0], np.asarray(d)[0]
+        assert d[:len(served)].tolist() == [doc for doc, _ in served], name
+        assert (d[len(served):] == -1).all(), name
+        np.testing.assert_allclose(
+            s[:len(served)], [sc for _, sc in served], rtol=1e-6,
+            err_msg=name)
+    if case == "two_legs_tied_at_every_rank":
+        # each tie group comes out lower doc first
+        a, b = legs
+        assert [doc for doc, _ in served] == [
+            doc for pair in zip(a, b) for doc in sorted(pair)]
+    # string keys (legs without integer identity) fuse by the same rule
+    by_id = rrf_fuse_ranked(
+        [[f"{doc:04d}" for doc in leg] for leg in legs], k, 60)
+    assert by_id == [(f"{doc:04d}", sc) for doc, sc in served]
+
+
 class TestHybridServing:
-    def test_device_fused_path_engaged(self, service):
-        before = service.rrf_stats["device_fused"]
+    def test_host_fused_path_engaged(self, service):
+        before = dict(service.rrf_stats)
         r = service.search(hybrid_body(seed=1))
         assert r["hits"]["hits"], "hybrid search returned no hits"
-        assert service.rrf_stats["device_fused"] == before + 1
+        assert service.rrf_stats["host_fused"] == before["host_fused"] + 1
+        assert service.rrf_stats["device_fused"] == before["device_fused"] == 0
         # per-leg breakdown recorded for bench reporting
         assert service.rrf_stats["bm25_leg_ms"] > 0
         assert service.rrf_stats["knn_leg_ms"] > 0
@@ -174,8 +237,8 @@ class TestHybridServing:
             jd = {h["_id"]: round(h["_score"], 6) for h in rj["hits"]["hits"]}
             nd = {h["_id"]: round(h["_score"], 6) for h in rn["hits"]["hits"]}
             # same fused scores per doc; ordering may differ only on
-            # exact ties (device ties break on (segment, doc), the host
-            # fallback on the _id string)
+            # exact ties (the batcher legs' ties break on (segment, doc),
+            # the NumPy backend's thread-pool legs' on the _id string)
             assert jd == nd
         finally:
             svc_np.close()
@@ -221,6 +284,12 @@ class TestHybridServing:
             assert [h["_id"] for h in got["hits"]["hits"]] == want
             assert b.stats["max_batch_seen"] == 2
             assert b.stats["groups_launched_together"] == 2
+            # the worker leaves a group's class after it has woken the
+            # group's waiter, and the host fuse no longer keeps the
+            # request ~1 ms behind that: give the worker its moment
+            deadline = time.monotonic() + 5.0
+            while any(b._inflight.values()) and time.monotonic() < deadline:
+                time.sleep(0.005)
             assert all(n == 0 for n in b._inflight.values())
         finally:
             b.close()
