@@ -1369,13 +1369,19 @@ class IndexService:
                     else min(wait_s, max(remaining, 0.0))
                 )
             try:
-                return QueryBatcher.wait(job, timeout=wait_s)
+                got = QueryBatcher.wait(job, timeout=wait_s)
             except TimeoutError:
                 if shard_deadline is None or (
                     time.monotonic() < shard_deadline
                 ):
                     continue  # poll tick; budget not spent yet
                 raise _timeout()
+            tr = job.trace
+            if tr is not None:
+                # `collect` ended at the worker's completion mark; the
+                # interpreter's hand-over to this thread is the `wake`
+                tr.add_span("wake", job.t_done, time.perf_counter_ns())
+            return got
 
     def shard_search_local(
         self, sid: int, body: Optional[dict], pinned_executor=None,
@@ -1594,12 +1600,25 @@ class IndexService:
                 elif query is None and knn is not None:
                     plan = extract_knn_plan(knn, self.mappings)
                     kind = "knn"
+                tr = TRACE_CTX.get()
+                if tr is not None and plan is None:
+                    tr.add_span(
+                        "plan", ts, time.perf_counter_ns(),
+                        family=None, planned=False,
+                    )
                 if plan is not None:
                     try:
                         job = self._batcher.submit_nowait(
                             ex, plan, k, kind=kind, query=query,
                             deadline=shard_deadline, prof=prof_phases,
                         )
+                        if tr is not None:
+                            # the shard's entry -> the job's submit mark,
+                            # where its `queue_wait` starts: parse, plan
+                            tr.add_span(
+                                "plan", ts, job.t_enq,
+                                family=kind, planned=True,
+                            )
                         # the batcher future honors the shard's timeout
                         # budget: an expired wait abandons the job (the
                         # worker sheds it at dequeue) and reports this
@@ -3606,6 +3625,10 @@ class IndexService:
         # submit every leg before collecting any: plannable legs enter
         # the batcher (device overlap), the rest ride the thread pool
         handles = []
+        # what each leg's planning and submit took on this thread, by
+        # label, and the mark after the last: the `plan_legs` span
+        plan_ms: Dict[str, float] = {}
+        t_planned_ns = t_start_ns
         for child, leg_id in zip(children, leg_ids):
             with tracing.under(leg_id):
                 handles.append(
@@ -3613,6 +3636,12 @@ class IndexService:
                         child, window2, extra_filter, pins,
                         profiled=prof_out is not None,
                     )
+                )
+            if tr is not None:
+                t_leg_ns, t_planned_ns = t_planned_ns, time.perf_counter_ns()
+                key = f"{handles[-1]['label']}_ms"
+                plan_ms[key] = round(
+                    plan_ms.get(key, 0.0) + (t_planned_ns - t_leg_ns) / 1e6, 3
                 )
         legs = [self._wait_leg(h, window2, extra_filter, t_start_ns, pins)
                 for h in handles]
@@ -3685,22 +3714,29 @@ class IndexService:
             prof_out["fuse_ns"] = prof_out.get("fuse_ns", 0) + fuse_ns
             prof_out["fused_on_device"] = False
         if tr is not None:
-            tr.add_span(
-                "rrf", t_start_ns, time.perf_counter_ns(), span_id=rrf_id,
-                index=self.name, legs=len(legs), device_fused=False,
+            spans = [
+                ("rrf", t_start_ns, time.perf_counter_ns(),
+                 tracing.PARENT_CTX.get(), rrf_id,
+                 {"index": self.name, "legs": len(legs)}),
+                ("plan_legs", t_start_ns, t_planned_ns, rrf_id, None,
+                 {"legs": len(legs), **plan_ms}),
+                ("fuse", t_fuse_ns, t_end_ns, rrf_id, None,
+                 {"window": window2}),
+            ]
+            # a leg ends at its own completion mark, whatever leg the
+            # request thread was waiting for meanwhile
+            spans.extend(
+                (f"leg:{leg['label']}", t_start_ns, leg["end_ns"], rrf_id,
+                 leg_id, {"mode": leg.get("mode", "?")})
+                for leg, leg_id in zip(legs, leg_ids)
             )
-            for leg, leg_id in zip(legs, leg_ids):
-                # a leg ends at its own completion mark, whatever leg
-                # the request thread was waiting for meanwhile
-                tr.add_span(
-                    f"leg:{leg['label']}", t_start_ns, leg["end_ns"],
-                    parent_id=rrf_id, span_id=leg_id,
-                    mode=leg.get("mode", "?"),
-                )
-            tr.add_span(
-                "fuse", t_fuse_ns, t_end_ns, parent_id=rrf_id,
-                device=False, window=window2, h2d_bytes=0, d2h_bytes=0,
-            )
+            if legs:
+                # the last leg done -> this thread running again
+                spans.append((
+                    "wake", max(leg["end_ns"] for leg in legs), t_fuse_ns,
+                    rrf_id, None, {},
+                ))
+            tr.add_spans(spans)
         return fused
 
     def _rrf_total(self, legs: List[dict], where: dict) -> dict:

@@ -13,6 +13,8 @@ Design:
   * `TRACE_CTX` is a contextvar: the REST layer arms it per request
     (`begin()` / `end()`), and every seam that wants a span just reads
     the var — `None` means tracing is off and costs one dict lookup.
+    An HTTP handler announces itself in `REQUEST_CTX`; a trace armed
+    under one is handed to it and closed after the response.
     Fan-out pools propagate the var with `contextvars.copy_context()`;
     the Trace object itself is shared and thread-safe, so spans added
     from shard/leg worker threads land in the request's tree.
@@ -28,28 +30,57 @@ Design:
     surface, not a production exporter.
   * `ES_TPU_TRACING=off` disables arming entirely (`begin()` → None).
 
-The spans of a search, parent > children (clock: `perf_counter_ns` of
-the serving process; tags in brackets):
+The spans of a search over HTTP, parent > children (clock:
+`perf_counter_ns` of the serving process; tags in brackets). The trace
+starts at `http`'s start and reaches the ring when `http` ends:
 
+    http [method, status, request_bytes, response_bytes]   the root: the
+        request line read (the entry of `parse_request`; the wait in
+        front of it on an idle connection is the client's) -> the
+        response's last byte written. The handler thread takes the marks
+        on every request (`RequestMarks`); the spans are written where
+        an action armed a trace (`begin`), 4xx and 429 answers included
+      > http_read   request line and headers parsed, body read
+      > request_parse [bytes]   urlparse, parse_qs, the router,
+            `json.loads` of the body
+      > admission_wait [tier, limit]   the wait in admission.acquire,
+            before the coordinator span starts
+      > coordinator   (below)
+      > respond [bytes, dumps_ms]   `json.dumps` (dumps_ms of it), status
+            line and headers, the socket write
+        http's self time is the action's own: `qs` handling, the task
+        registry, arming the trace, the response's assembly
+    (a search called as a library, with no handler above it, has no
+    `http`: `admission_wait` and `coordinator` are its roots)
     coordinator [index, shards, took_ms]
       > parse, can_match, dfs, fan_out, reduce   (tile the coordinator)
     fan_out > shard_search [index, shard, backend]   (one per shard)
     coordinator of a `retriever` (or `rank: {rrf}`) search, the same
-    root under the same name
+    span under the same name
       > retriever, rescore, fetch   (tile it; no fan_out: the legs of
         an rrf node go to the batcher themselves)
-    retriever > rrf [index, legs, device_fused (false)]
+    retriever > rrf [index, legs]
+      > plan_legs [legs, <label>_ms a leg]   the legs' common start ->
+            the last leg's job submitted: `_plan_leg` and the submits,
+            on the request thread, inside every `leg:<label>` span
       > leg:<label> [mode]   (bm25, knn, sparse, other; one per child)
             the legs' common start -> the leg's OWN completion mark (a
             batcher job's `t_done`, the end of a pool or inline run),
             whatever order the request thread waited in
-      > fuse [device, window, h2d_bytes, d2h_bytes]   the last leg's
-            waiter awake -> the fused list: the host's dictionary over
-            the legs' hits (ops/fusion.rrf_fuse_ranked), so `device` is
-            false and both byte counts are 0 on every request
-    shard_search (or `leg:<label>` of an rrf retriever, or
-    `mesh_search`) > the job spans of search/batcher.py, which tile the
-    job's life from submit to its waiter's wake-up:
+      > wake   the latest leg's completion mark -> the request thread
+            is running again (the start of `fuse`)
+      > fuse [window]   -> the fused list: the host's dictionary over
+            the legs' hits (ops/fusion.rrf_fuse_ranked)
+    shard_search is tiled plan | queue_wait | dispatch | inflight |
+    collect | wake | fetch; what is left of it is the hand-over between
+    them. A `leg:<label>` of an rrf retriever (or `mesh_search`) holds
+    the four job spans alone.
+        plan [family, planned]   the shard's entry -> the job's submit
+            mark in `submit_nowait` (`t_enq`, where `queue_wait` starts):
+            parse_query / the kNN section, extract_*_plan; planned
+            false: no plan, the unbatched executor ran what follows
+        the job spans of search/batcher.py, consecutive marks of the
+        dispatcher worker from submit to the worker's completion mark:
         queue_wait [family, cold_ms]  submit -> a worker starts the
             job's group; cold_ms = compile time that accrued meanwhile
         dispatch [family, jobs, rows, launches, express, overflow; a
@@ -61,17 +92,23 @@ the serving process; tags in brackets):
         collect [d2h_bytes; a text or sparse group also merged: false
             when it downloaded the fused kernel's packed row as it was,
             true when the merge program ran]  blocking download, hits
+            -> the job's `t_done`, the WORKER's mark before it writes
+            the job's spans and sets the waiter's event
         compile [program, seconds]  child of the dispatch (or collect)
             span the worker compiled in; one per program
-    shard_search > fetch   (sources, highlight: the folded fetch phase)
-    admission_wait [tier, limit]   root; the wait in admission.acquire,
-        before the coordinator span starts
+        wake   `t_done` -> the waiter is back from `QueryBatcher.wait`:
+            the worker's span writes and the hand-over of the
+            interpreter to the request thread
+        fetch   (sources, highlight: the folded fetch phase)
 
-The same worker phases are on the profiler's clock as
-`jax.profiler.TraceAnnotation`s `es.dispatch` / `es.collect` (arguments
-`family`, `rows`): start `jax.profiler.start_trace(dir)` on the serving
-process and they land on the host plane of the `.xplane.pb`, one line
-per dispatcher thread, beside the device's `XLA Ops` line.
+On the profiler's clock the same phases are
+`jax.profiler.TraceAnnotation`s: the workers' `es.dispatch` /
+`es.collect` (arguments `family`, `rows`), the request thread's
+`es.http` (the `http` span's interval) and `es.search` [`route`] (around
+the action's `cluster.search` call: the `coordinator` span's thread and
+interval). Start `jax.profiler.start_trace(dir)` on the serving process
+and they land on the host plane of the `.xplane.pb`, one line per
+dispatcher or connection thread, beside the device's `XLA Ops` line.
 
 `note_transfer` counts the query path's host<->device transfers where
 they happen (ops/scoring.py, the kNN upload in search/batcher.py, the
@@ -113,6 +150,12 @@ TRACE_CTX: contextvars.ContextVar = contextvars.ContextVar(
 # contextvars, so concurrent shards and legs each see their own chain)
 PARENT_CTX: contextvars.ContextVar = contextvars.ContextVar(
     "span_parent", default=None
+)
+
+# the HTTP request the current thread is serving: its handler's
+# `RequestMarks` (None: no handler above this code, as in a library call)
+REQUEST_CTX: contextvars.ContextVar = contextvars.ContextVar(
+    "http_request", default=None
 )
 
 # hard cap per trace: a runaway fan-out must not grow one trace without
@@ -167,14 +210,17 @@ class Trace:
     recorded on different threads order correctly within one host."""
 
     def __init__(self, name: str, opaque_id: Optional[str] = None,
-                 **tags: Any):
+                 start_ns: Optional[int] = None, **tags: Any):
         self.trace_id = f"trace-{next(_trace_ids)}"
         self.name = name
         self.opaque_id = opaque_id
         self.tags = dict(tags)
-        self.start_ns = time.perf_counter_ns()
+        now = time.perf_counter_ns()
+        # `start_ns`: a mark taken before the trace was armed (an HTTP
+        # request's first line): the trace starts there, on both clocks
+        self.start_ns = now if start_ns is None else start_ns
         self.end_ns: Optional[int] = None
-        self.wall_start = time.time()
+        self.wall_start = time.time() - (now - self.start_ns) / 1e9
         self._spans: List[Span] = []
         self._dropped = 0
         self._span_ids = itertools.count(1)
@@ -189,6 +235,18 @@ class Trace:
         with self._lock:
             return next(self._span_ids)
 
+    def _write(self, name, start_ns, end_ns, parent_id, span_id, tags):
+        """One span, the lock held. -> its id, or None if the trace is
+        full (the drop is counted)."""
+        if len(self._spans) >= MAX_SPANS:
+            self._dropped += 1
+            return None
+        sid = next(self._span_ids) if span_id is None else span_id
+        self._spans.append(
+            Span(sid, parent_id, name, int(start_ns), int(end_ns), tags)
+        )
+        return sid
+
     def add_span(
         self, name: str, start_ns: int, end_ns: int,
         parent_id: Optional[int] = None, span_id: Optional[int] = None,
@@ -202,20 +260,23 @@ class Trace:
         if parent_id is None:
             parent_id = PARENT_CTX.get()
         with self._lock:
-            if len(self._spans) >= MAX_SPANS:
-                self._dropped += 1
-                return None
-            sid = next(self._span_ids) if span_id is None else span_id
-            self._spans.append(
-                Span(sid, parent_id, name, int(start_ns), int(end_ns), tags)
-            )
-        return sid
+            return self._write(name, start_ns, end_ns, parent_id, span_id,
+                               tags)
 
-    def finish(self) -> None:
-        """Closes the trace and publishes it to the ring."""
+    def add_spans(self, spans) -> None:
+        """Several retroactive spans in one acquisition of the lock:
+        (name, start_ns, end_ns, parent_id, span_id, tags) each, parent
+        and id explicit (None: a root, a fresh id)."""
+        with self._lock:
+            for span in spans:
+                self._write(*span)
+
+    def finish(self, end_ns: Optional[int] = None) -> None:
+        """Closes the trace (at `end_ns`, a mark already taken, else
+        now) and publishes it to the ring."""
         if self.end_ns is not None:
             return
-        self.end_ns = time.perf_counter_ns()
+        self.end_ns = time.perf_counter_ns() if end_ns is None else end_ns
         _ring_append(self)
 
     # ---- export ----
@@ -275,26 +336,84 @@ def clear() -> None:
 
 # ---- REST-layer arming helpers ----
 
+class RequestMarks:
+    """What an HTTP handler thread keeps of the request it is serving:
+    `perf_counter_ns` marks it takes on every request, and the trace an
+    action armed meanwhile (`begin`), which the handler closes after the
+    response is written (`finish`). One object a connection; a request
+    that arms no trace costs the marks and nothing else."""
+
+    __slots__ = (
+        "t_line", "t_read", "t_parsed", "t_respond", "t_dumped", "t_end",
+        "request_bytes", "response_bytes", "status", "trace", "http_id",
+    )
+
+    def __init__(self):
+        self.t_line = self.t_read = self.t_parsed = 0
+        self.t_respond = self.t_dumped = self.t_end = 0
+        self.request_bytes = self.response_bytes = self.status = 0
+        self.trace: Optional[Trace] = None
+        self.http_id: Optional[int] = None
+
+    def finish(self, method: str) -> None:
+        """Writes the handler's spans (module docstring: `http` and its
+        three own children) into the trace the action armed and
+        publishes it, ending where the response's last byte was
+        written. No-op on a request that armed none."""
+        tr, root = self.trace, self.http_id
+        if tr is None:
+            return
+        self.trace = None
+        tr.add_spans((
+            ("http", self.t_line, self.t_end, None, root, {
+                "method": method, "status": self.status,
+                "request_bytes": self.request_bytes,
+                "response_bytes": self.response_bytes,
+            }),
+            ("http_read", self.t_line, self.t_read, root, None, {}),
+            ("request_parse", self.t_read, self.t_parsed, root, None,
+             {"bytes": self.request_bytes}),
+            ("respond", self.t_respond, self.t_end, root, None, {
+                "bytes": self.response_bytes,
+                "dumps_ms": round(
+                    (self.t_dumped - self.t_respond) / 1e6, 3),
+            }),
+        ))
+        tr.finish(self.t_end)
+
+
 def begin(name: str, **tags: Any):
     """Arms TRACE_CTX for the current context. Returns an opaque handle
-    for `end()`, or None when tracing is disabled."""
+    for `end()`, or None when tracing is disabled. Under an HTTP handler
+    (`REQUEST_CTX`) the trace starts at the request's first line, what
+    runs until `end()` runs under its `http` span, and the handler, not
+    `end()`, publishes it."""
     if not enabled():
         return None
-    tr = Trace(name, opaque_id=OPAQUE_ID_CTX.get(), **tags)
-    tok = TRACE_CTX.set(tr)
-    return (tr, tok)
+    req = REQUEST_CTX.get()
+    if req is None:
+        tr = Trace(name, opaque_id=OPAQUE_ID_CTX.get(), **tags)
+        return (tr, TRACE_CTX.set(tr), None)
+    tr = Trace(name, opaque_id=OPAQUE_ID_CTX.get(), start_ns=req.t_line,
+               **tags)
+    req.trace, req.http_id = tr, tr.reserve_span()
+    return (tr, TRACE_CTX.set(tr), PARENT_CTX.set(req.http_id))
 
 
 def end(handle) -> None:
-    """Finishes the trace begun by `begin()` (no-op on None)."""
+    """Disarms the trace begun by `begin()` (no-op on None) and, unless
+    an HTTP handler holds it to close after its response, finishes it."""
     if handle is None:
         return
-    tr, tok = handle
+    tr, tok, parent_tok = handle
     try:
         TRACE_CTX.reset(tok)
     except ValueError:  # pragma: no cover - cross-context reset
         TRACE_CTX.set(None)
-    tr.finish()
+    if parent_tok is None:
+        tr.finish()
+    else:
+        PARENT_CTX.reset(parent_tok)
 
 
 def current() -> Optional[Trace]:

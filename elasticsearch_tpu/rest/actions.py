@@ -18,6 +18,8 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from ..cluster import ClusterError, ClusterService
 from ..common import deep_merge
 from ..common import tracing
@@ -1477,8 +1479,15 @@ class RestActions:
             profile=bool(body.get("profile")),
         )
         try:
-            return 200, self.cluster.search(params["index"], body, task=task)
+            # the request thread inside the search, on the profiler's
+            # clock (the `coordinator` span's thread and interval)
+            with TraceAnnotation("es.search", route="_search"):
+                return 200, self.cluster.search(
+                    params["index"], body, task=task
+                )
         finally:
+            # under an HTTP handler the trace stays open: the handler
+            # closes it once the response is written
             tracing.end(handle)
             self.cluster.tasks.unregister(task)
 

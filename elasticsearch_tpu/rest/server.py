@@ -15,13 +15,16 @@ from __future__ import annotations
 import argparse
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.parse import parse_qs, unquote, urlparse
 
+from jax.profiler import TraceAnnotation
+
 from ..cluster import ClusterError, ClusterService
 from ..common.memory import CircuitBreakingException
-from ..common.tracing import OPAQUE_ID_CTX
+from ..common.tracing import OPAQUE_ID_CTX, REQUEST_CTX, RequestMarks
 from ..index.engine import EngineError, VersionConflictError
 from ..index.mapping import MappingParseError
 from ..search.admission import EsOverloadedError, admission, overload_body
@@ -44,6 +47,36 @@ class ElasticHandler(BaseHTTPRequestHandler):
     def log_message(self, fmt, *args):
         pass
 
+    def setup(self):
+        super().setup()
+        # this connection thread's marks of the request it is serving
+        # (common/tracing.py: the `http` span and its children)
+        self.marks = RequestMarks()
+        self._on_profiler: Optional[TraceAnnotation] = None
+
+    def handle_one_request(self):
+        """One request, then its trace: a search action leaves the trace
+        it armed with `self.marks`, and it is closed here, after the
+        response's last byte, whatever way the request ended."""
+        try:
+            super().handle_one_request()
+        finally:
+            ann, self._on_profiler = self._on_profiler, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+                self.marks.finish(self.command)
+
+    def parse_request(self):
+        # the request line has been read: the wait in front of it (an
+        # idle keep-alive connection) was the client's, not the
+        # request's. From here to the response's last byte the thread is
+        # inside `es.http` on the profiler's clock (outside a profiler
+        # session a flag test)
+        self.marks.t_line = time.perf_counter_ns()
+        self._on_profiler = TraceAnnotation("es.http")
+        self._on_profiler.__enter__()
+        return super().parse_request()
+
     def _read_body(self) -> bytes:
         length = int(self.headers.get("Content-Length") or 0)
         return self.rfile.read(length) if length else b""
@@ -52,27 +85,38 @@ class ElasticHandler(BaseHTTPRequestHandler):
         self, status: int, payload, head_only: bool = False,
         headers: Optional[dict] = None,
     ) -> None:
-        if isinstance(payload, (dict, list)):
-            data = json.dumps(payload).encode()
-            ctype = "application/json"
-        else:
-            data = str(payload).encode()
-            ctype = "text/plain; charset=UTF-8"
-        self.send_response(status)
-        self.send_header("X-elastic-product", "Elasticsearch")
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(data)))
-        for k, v in (headers or {}).items():
-            self.send_header(k, str(v))
-        self.end_headers()
-        if not head_only:
-            self.wfile.write(data)
+        m = self.marks
+        m.status = status
+        m.t_respond = time.perf_counter_ns()
+        try:
+            if isinstance(payload, (dict, list)):
+                data = json.dumps(payload).encode()
+                ctype = "application/json"
+            else:
+                data = str(payload).encode()
+                ctype = "text/plain; charset=UTF-8"
+            m.t_dumped = time.perf_counter_ns()
+            m.response_bytes = len(data)
+            self.send_response(status)
+            self.send_header("X-elastic-product", "Elasticsearch")
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(data)))
+            for k, v in (headers or {}).items():
+                self.send_header(k, str(v))
+            self.end_headers()
+            if not head_only:
+                self.wfile.write(data)
+        finally:
+            m.t_end = time.perf_counter_ns()
 
     def _handle(self, method: str) -> None:
+        m = self.marks
+        raw = self._read_body()
+        m.request_bytes = len(raw)
+        m.t_read = time.perf_counter_ns()
         parsed = urlparse(self.path)
         path = parsed.path
         qs = parse_qs(parsed.query, keep_blank_values=True)
-        raw = self._read_body()
         head_only = method == "HEAD"
         route, params, path_exists = self.actions.router.dispatch(method, path)
         # percent-decode extracted path params AFTER routing so an
@@ -108,8 +152,10 @@ class ElasticHandler(BaseHTTPRequestHandler):
         # X-Opaque-Id rides a contextvar for the request's lifetime so
         # task descriptions, traces, and slow logs can stamp it
         opaque_tok = OPAQUE_ID_CTX.set(self.headers.get("X-Opaque-Id"))
+        request_tok = REQUEST_CTX.set(m)
         try:
             body = self._parse_body(path, raw)
+            m.t_parsed = time.perf_counter_ns()
             status, payload = route.handler(body, params or {}, qs)
         except ClusterError as e:
             status, payload = e.status, error_body(e.status, e.err_type, e.reason)
@@ -147,6 +193,7 @@ class ElasticHandler(BaseHTTPRequestHandler):
         except Exception as e:  # the 500 of last resort
             status, payload = 500, error_body(500, "exception", repr(e))
         finally:
+            REQUEST_CTX.reset(request_tok)
             OPAQUE_ID_CTX.reset(opaque_tok)
         self._respond(status, payload, head_only, headers=resp_headers)
 

@@ -583,7 +583,8 @@ class _Job:
 class _Group:
     """One dispatched group's marks: consecutive `perf_counter_ns`
     readings the worker takes once, whatever reads them. They tile each
-    job's life from `submit_nowait` to its waiter's wake-up — queue_wait
+    job's life from `submit_nowait` to its completion mark `t_done` (the
+    waiter's wake-up after it is the waiter's own `wake` span) — queue_wait
     | dispatch | inflight | collect — and are written, when a job
     finishes, as spans of the submitting request's trace and as the
     `"profile": true` breakdown (common/tracing.py lists the spans)."""

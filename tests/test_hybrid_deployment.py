@@ -299,9 +299,16 @@ def test_spans_and_counters_of_a_hybrid_request(deployment):
         assert end(legs[name]) == end(jobs[-1])
     assert by["fuse"]["start_ns"] >= max(end(s) for s in legs.values())
     assert end(by["fuse"]) <= end(by["rrf"])
-    assert by["fuse"]["tags"] == {
-        "device": False, "window": WINDOW, "h2d_bytes": 0, "d2h_bytes": 0}
-    assert by["rrf"]["tags"]["device_fused"] is False
+    assert by["fuse"]["tags"] == {"window": WINDOW}
+    assert by["rrf"]["tags"] == {"index": by["rrf"]["tags"]["index"],
+                                 "legs": 2}
+    # the request thread's own part of the node: planning and submitting
+    # the legs, and its wake-up between the last leg and the fuse
+    assert parent(by["plan_legs"]) == parent(by["wake"]) == "rrf"
+    assert by["plan_legs"]["start_ns"] == by["rrf"]["start_ns"]
+    assert by["plan_legs"]["tags"].keys() == {"legs", "bm25_ms", "knn_ms"}
+    assert by["wake"]["start_ns"] == max(end(s) for s in legs.values())
+    assert end(by["wake"]) == by["fuse"]["start_ns"]
 
 
 @pytest.mark.parametrize("case", ["ties_reordered", "no_tie_untouched",

@@ -12,7 +12,13 @@ Contract under test:
   * a batcher job's life is four spans of the submitting request's
     trace (queue_wait | dispatch | inflight | collect) that tile it
     exactly, children of the `shard_search` (or leg) span that
-    submitted it, whose parent is the coordinator's `fan_out`;
+    submitted it, whose parent is the coordinator's `fan_out`; the
+    request thread's own `plan` in front of them and `wake` behind them
+    tile the rest of `shard_search` up to `fetch`;
+  * over HTTP the trace's root is `http`, from the request line to the
+    response's last byte: the handler's `http_read`, `request_parse`
+    and `respond`, and `admission_wait` and `coordinator`, are its
+    children, and the trace reaches the ring after the response;
   * the query path's host<->device transfers are counted exactly
     (`_nodes/stats` `transfer.scoring`).
 """
@@ -238,10 +244,16 @@ class TestBatcherJobSpans:
         assert sum(by[n]["duration_ns"] for n in JOB_SPANS) == (
             end_ns(c) - q["start_ns"]
         )
-        # the job lies inside its shard_search span, inside the request
+        # the job lies inside its shard_search span, inside the request,
+        # between the request thread's own two spans: `plan` ends at the
+        # job's submit mark, `wake` starts at its completion mark
         sh = by["shard_search"]
-        assert t_before <= sh["start_ns"] <= q["start_ns"]
-        assert end_ns(c) <= by["fetch"]["start_ns"]
+        assert t_before <= sh["start_ns"] <= by["plan"]["start_ns"]
+        assert end_ns(by["plan"]) == q["start_ns"]
+        assert by["plan"]["tags"] == {"family": family, "planned": True}
+        assert by["wake"]["start_ns"] == end_ns(c)
+        assert by["wake"]["tags"] == {}
+        assert end_ns(by["wake"]) <= by["fetch"]["start_ns"]
         assert end_ns(by["fetch"]) <= end_ns(sh) <= t_after
         assert q["tags"] == {"family": family, "cold_ms": 0.0}
         # a fused match group also says how many tile slots it carried
@@ -272,11 +284,14 @@ class TestBatcherJobSpans:
     ):
         spans, by = traced_search(fused_service, KNN)
         ids = {s["id"]: s for s in spans}
-        for name in JOB_SPANS + ("fetch",):
-            assert ids[by[name]["parent_id"]]["name"] == "shard_search", name
+        kids = [s["name"] for s in sorted(spans, key=lambda s: s["start_ns"])
+                if s["parent_id"] == by["shard_search"]["id"]]
+        assert kids == ["plan", *JOB_SPANS, "wake", "fetch"]
         assert ids[by["shard_search"]["parent_id"]]["name"] == "fan_out"
         assert ids[by["fan_out"]["parent_id"]]["name"] == "coordinator"
-        # the only roots: the coordinator and the wait before it
+        # a library call has no handler above it, so no `http`: the only
+        # roots are the coordinator and the wait before it (over HTTP
+        # both hang off `http`: TestHttpRoot)
         assert {s["name"] for s in spans if s["parent_id"] is None} == {
             "coordinator", "admission_wait",
         }
@@ -401,6 +416,13 @@ class TestBatcherJobSpans:
                 families[s["tags"]["family"]] = legs[s["parent_id"]]
         assert families == {"match": "leg:bm25", "knn": "leg:knn"}
         assert ids[by["leg:knn"]["parent_id"]]["name"] == "rrf"
+        # the request thread's own spans hang off the node, not a leg
+        assert ids[by["plan_legs"]["parent_id"]]["name"] == "rrf"
+        assert ids[by["wake"]["parent_id"]]["name"] == "rrf"
+        assert by["plan_legs"]["tags"].keys() == {"legs", "bm25_ms", "knn_ms"}
+        assert by["wake"]["start_ns"] == max(
+            end_ns(by["leg:bm25"]), end_ns(by["leg:knn"]))
+        assert end_ns(by["wake"]) == by["fuse"]["start_ns"]
         assert ids[by["rrf"]["parent_id"]]["name"] == "retriever"
         assert ids[by["retriever"]["parent_id"]]["name"] == "coordinator"
 
@@ -413,6 +435,12 @@ class TestBatcherJobSpans:
         with g.phase("es.collect"):
             g.collecting()
         assert g.t_start <= g.t_dispatched <= g.t_collect
+        # the request thread's two (rest/server.py, rest/actions.py)
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("es.http"):
+            with TraceAnnotation("es.search", route="_search"):
+                pass
 
 
 class TestTransferCounters:
@@ -462,14 +490,9 @@ class TestRestSurface:
         srv.close()
 
     def _call(self, server, method, path, body=None, headers=None):
-        url = f"http://127.0.0.1:{server.port}{path}"
-        data = json.dumps(body).encode() if body is not None else None
-        req = urllib.request.Request(
-            url, data=data, method=method,
-            headers={"Content-Type": "application/json", **(headers or {})},
-        )
-        with urllib.request.urlopen(req) as resp:
-            return resp.status, json.loads(resp.read() or b"null")
+        status, _, payload = http_call(server, method, path, body, headers)
+        assert status < 400, (status, payload)
+        return status, payload
 
     def test_traces_endpoint_and_opaque_id(self, server):
         self._call(server, "PUT", "/tr-rest", {
@@ -483,7 +506,13 @@ class TestRestSurface:
             headers={"X-Opaque-Id": "caller-42"},
         )
         assert status == 200
-        status, out = self._call(server, "GET", "/_internal/traces?n=5")
+        # the trace is published after its response's last byte, so a
+        # client that has the answer may have to look twice
+        deadline = time.monotonic() + 10.0
+        while True:
+            status, out = self._call(server, "GET", "/_internal/traces?n=5")
+            if out["traces"] or time.monotonic() > deadline:
+                break
         assert status == 200
         assert out["enabled"] is True
         search_traces = [t for t in out["traces"] if t["name"] == "search"]
@@ -492,15 +521,17 @@ class TestRestSurface:
         assert tr["opaque_id"] == "caller-42"
         assert tr["tags"]["index"] == "tr-rest"
         assert any(s["name"] == "coordinator" for s in tr["spans"])
-        # a tree: coordinator > fan_out > shard_search > the job's spans
+        # a tree: http > coordinator > fan_out > shard_search > the
+        # job's spans and the request thread's own around them
         ids = {s["id"]: s for s in tr["spans"]}
-        for name in JOB_SPANS + ("fetch",):
+        for name in ("plan",) + JOB_SPANS + ("wake", "fetch"):
             span = next(s for s in tr["spans"] if s["name"] == name)
             chain = []
             while span["parent_id"] is not None:
                 span = ids[span["parent_id"]]
                 chain.append(span["name"])
-            assert chain == ["shard_search", "fan_out", "coordinator"], name
+            assert chain == [
+                "shard_search", "fan_out", "coordinator", "http"], name
         # the node's transfer counters (whether this search compiled
         # depends on what the process built before it: the compile count
         # is test_compile_span_on_first_use_of_a_shape_only's)
@@ -549,3 +580,258 @@ class TestRestSurface:
             assert any(r.get("opaque_id") == "tenant-7" for r in recs), recs
         finally:
             root.removeHandler(cap)
+
+
+# ---------------------------------------------------------------------
+# the request thread's spans: `http` is the root of a search's trace
+# ---------------------------------------------------------------------
+
+RRF = {"retriever": {"rrf": {"retrievers": [
+    {"standard": {"query": MATCH["query"]}},
+    {"knn": KNN["knn"]},
+]}}, "size": 5}
+HTTP_CHILDREN = ["http_read", "request_parse", "admission_wait",
+                 "coordinator", "respond"]
+
+
+@pytest.fixture(scope="module")
+def http_server():
+    """A real server on the CPU backend holding one small index with a
+    text and a vector field."""
+    from elasticsearch_tpu.rest.server import ElasticsearchTpuServer
+
+    srv = ElasticsearchTpuServer(port=0)
+    srv.start_background()
+    rng = np.random.default_rng(11)
+    http_call(srv, "PUT", "/tr-http", {
+        "settings": {"number_of_shards": 1},
+        "mappings": {"properties": {
+            "body": {"type": "text"},
+            "vec": {"type": "dense_vector", "dims": DIMS,
+                    "similarity": "cosine"},
+        }},
+    })
+    for i in range(40):
+        http_call(srv, "POST", f"/tr-http/_doc/{i}", {
+            "body": " ".join(rng.choice(WORDS, int(rng.integers(3, 9)))),
+            "vec": [float(x) for x in rng.normal(size=DIMS)],
+        })
+    http_call(srv, "POST", "/tr-http/_refresh")
+    yield srv
+    srv.close()
+
+
+def http_call(srv, method, path, body=None, headers=None):
+    """-> (status, headers, payload); a 4xx or 429 answer is returned,
+    not raised."""
+    import urllib.error
+
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{srv.port}{path}", method=method,
+        data=None if body is None else json.dumps(body).encode(),
+        headers={"Content-Type": "application/json", **(headers or {})},
+    )
+    try:
+        with urllib.request.urlopen(req) as resp:
+            return resp.status, resp.headers, json.loads(resp.read() or b"null")
+    except urllib.error.HTTPError as err:
+        return err.code, err.headers, json.loads(err.read())
+
+
+def ring_after(n: int = 1) -> list:
+    """The ring once it holds `n` traces: a trace is published after its
+    response is written, so the client may read the answer first."""
+    deadline = time.monotonic() + 10.0
+    while len(tracing.recent(50)) < n and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return tracing.recent(50)
+
+
+def children_of(spans, parent):
+    return sorted((s for s in spans if s["parent_id"] == parent["id"]),
+                  key=lambda s: s["start_ns"])
+
+
+class TestHttpRoot:
+    @pytest.mark.parametrize("body", [MATCH, KNN, RRF],
+                             ids=["match", "knn", "rrf"])
+    def test_one_trace_a_request_rooted_at_http(self, http_server, body):
+        raw = json.dumps(body).encode()
+        status, _, answer = http_call(http_server, "POST",
+                                      "/tr-http/_search", body)
+        t_answered = time.perf_counter_ns()
+        assert status == 200 and answer["hits"]["hits"]
+        traces = ring_after(1)
+        assert len(traces) == 1
+        tr = traces[0]
+        spans = tr["spans"]
+        roots = [s for s in spans if s["parent_id"] is None]
+        assert [s["name"] for s in roots] == ["http"]
+        http = roots[0]
+        assert http["tags"] == {
+            "method": "POST", "status": 200, "request_bytes": len(raw),
+            "response_bytes": http["tags"]["response_bytes"],
+        }
+        # the handler's three spans and the two that were roots are its
+        # children, in this order, none overlapping, all inside it
+        kids = children_of(spans, http)
+        assert [s["name"] for s in kids] == HTTP_CHILDREN
+        assert kids[0]["start_ns"] == http["start_ns"]
+        assert end_ns(kids[0]) == kids[1]["start_ns"]  # read | parse
+        for a, b in zip(kids, kids[1:]):
+            assert end_ns(a) <= b["start_ns"], (a["name"], b["name"])
+        assert end_ns(kids[-1]) == end_ns(http)
+        by = {s["name"]: s for s in kids}
+        assert by["request_parse"]["tags"] == {"bytes": len(raw)}
+        assert by["respond"]["tags"].keys() == {"bytes", "dumps_ms"}
+        assert by["respond"]["tags"]["bytes"] == (
+            http["tags"]["response_bytes"]) > 0
+        assert 0 <= by["respond"]["tags"]["dumps_ms"] * 1e6 <= (
+            by["respond"]["duration_ns"])
+        # the trace IS the http span, and it was published after the
+        # response was written: its length covers `respond`
+        assert tr["duration_ns"] == http["duration_ns"]
+        assert end_ns(by["respond"]) == http["start_ns"] + tr["duration_ns"]
+        assert end_ns(http) <= t_answered
+
+    def test_request_thread_spans_tile_shard_search(self, http_server):
+        for body in (MATCH, KNN):
+            tracing.clear()
+            http_call(http_server, "POST", "/tr-http/_search", body)
+            spans = ring_after(1)[0]["spans"]
+            sh = next(s for s in spans if s["name"] == "shard_search")
+            kids = [s for s in children_of(spans, sh)
+                    if s["name"] != "compile"]
+            assert [s["name"] for s in kids] == [
+                "plan", *JOB_SPANS, "wake", "fetch"]
+            # consecutive marks from `plan`'s end to `wake`'s start
+            for a, b in zip(kids[:6], kids[1:6]):
+                assert end_ns(a) == b["start_ns"], (a["name"], b["name"])
+            assert sh["start_ns"] <= kids[0]["start_ns"]
+            assert end_ns(kids[5]) <= kids[6]["start_ns"]
+            assert end_ns(kids[6]) <= end_ns(sh)
+
+    def test_rrf_node_holds_plan_legs_and_wake(self, http_server):
+        http_call(http_server, "POST", "/tr-http/_search", RRF)
+        spans = ring_after(1)[0]["spans"]
+        by = {s["name"]: s for s in spans if s["name"] not in JOB_SPANS}
+        kids = [s["name"] for s in children_of(spans, by["rrf"])]
+        assert sorted(kids) == sorted(
+            ["plan_legs", "leg:bm25", "leg:knn", "wake", "fuse"])
+        assert by["plan_legs"]["start_ns"] == by["rrf"]["start_ns"]
+        assert by["plan_legs"]["tags"]["legs"] == 2
+        # it lies inside every leg: a leg cannot end before it is planned
+        for leg in ("leg:bm25", "leg:knn"):
+            assert end_ns(by["plan_legs"]) <= end_ns(by[leg])
+            assert [s["name"] for s in children_of(spans, by[leg])
+                    if s["name"] != "compile"] == list(JOB_SPANS)
+        assert by["wake"]["start_ns"] == max(
+            end_ns(by["leg:bm25"]), end_ns(by["leg:knn"]))
+        assert end_ns(by["wake"]) == by["fuse"]["start_ns"]
+        assert by["fuse"]["tags"].keys() == {"window"}
+        assert by["rrf"]["tags"].keys() == {"index", "legs"}
+
+    @pytest.mark.parametrize("case", ["malformed_query_400",
+                                      "overloaded_429"])
+    def test_refused_request_still_finishes_its_trace(
+        self, http_server, case
+    ):
+        from elasticsearch_tpu.search.admission import admission
+
+        if case == "overloaded_429":
+            admission.configure(enabled=True, target_delay_ms=10)
+            for _ in range(60):
+                admission.observe_queue_delay(0.5)  # tier 4: reject
+            want, body = 429, MATCH
+        else:
+            want, body = 400, {"query": {"no_such_query": {}}}
+        try:
+            status, headers, _ = http_call(
+                http_server, "POST", "/tr-http/_search", body)
+        finally:
+            admission.reset()
+        assert status == want
+        assert want != 429 or int(headers["Retry-After"]) >= 1
+        traces = ring_after(1)
+        assert len(traces) == 1
+        spans = traces[0]["spans"]
+        http = next(s for s in spans if s["parent_id"] is None)
+        assert http["name"] == "http" and http["tags"]["status"] == want
+        names = [s["name"] for s in children_of(spans, http)]
+        assert names[:2] == ["http_read", "request_parse"]
+        assert names[-1] == "respond" and "coordinator" not in names
+        assert traces[0]["duration_ns"] == http["duration_ns"]
+
+    def test_route_that_arms_no_trace_leaves_the_ring_alone(
+        self, http_server
+    ):
+        http_call(http_server, "POST", "/tr-http/_search", MATCH)
+        before = [t["trace_id"] for t in ring_after(1)]
+        for method, path, body in (
+            ("GET", "/tr-http/_doc/1", None),
+            ("POST", "/tr-http/_count", {"query": MATCH["query"]}),
+            ("GET", "/_nodes/stats", None),
+            ("GET", "/no/such/route/at/all", None),
+        ):
+            http_call(http_server, method, path, body)
+        # a later search's trace is the next one: nothing was armed,
+        # held over or published in between
+        http_call(http_server, "POST", "/tr-http/_search", MATCH)
+        after = [t["trace_id"] for t in ring_after(2)]
+        assert len(after) == 2 and after[1:] == before
+        assert int(after[0].split("-")[1]) == int(before[0].split("-")[1]) + 1
+
+    def test_tracing_off_records_nothing_and_answers_the_same(
+        self, http_server, monkeypatch
+    ):
+        _, _, traced = http_call(http_server, "POST", "/tr-http/_search",
+                                 MATCH)
+        ring_after(1)
+        tracing.clear()
+        monkeypatch.setenv("ES_TPU_TRACING", "off")
+        answers = [http_call(http_server, "POST", "/tr-http/_search", b)
+                   for b in (MATCH, RRF)]
+        http_call(http_server, "GET", "/_nodes/stats")  # the thread is idle
+        assert tracing.recent(50) == []
+        assert [a[0] for a in answers] == [200, 200]
+        assert answers[0][2]["hits"] == traced["hits"]
+
+    def test_search_called_as_a_library_finishes_its_own_trace(
+        self, http_server
+    ):
+        """No handler above the action: `end()` publishes the trace, as
+        it did, and nothing is left armed on the calling thread."""
+        status, _ = http_server.actions.search(
+            dict(MATCH), {"index": "tr-http"}, {})
+        assert status == 200
+        assert tracing.current() is None
+        assert tracing.PARENT_CTX.get() is None
+        roots = {s["name"] for s in tracing.recent(1)[0]["spans"]
+                 if s["parent_id"] is None}
+        assert roots == {"admission_wait", "coordinator"}
+
+
+class TestAddSpans:
+    def test_one_call_writes_roots_children_and_reserved_ids(self):
+        tr = tracing.Trace("t", start_ns=1_000)
+        root = tr.reserve_span()
+        with tracing.under(99):  # explicit parents: the var is not read
+            tr.add_spans((
+                ("http", 1_000, 9_000, None, root, {"status": 200}),
+                ("respond", 8_000, 9_000, root, None, {}),
+            ))
+        tr.finish(9_000)
+        d = tr.to_dict()
+        assert d["duration_ns"] == 8_000
+        by = {s["name"]: s for s in d["spans"]}
+        assert by["http"]["id"] == root and by["http"]["parent_id"] is None
+        assert by["respond"]["parent_id"] == root != by["respond"]["id"]
+
+    def test_cap_counts_what_it_drops(self):
+        tr = tracing.Trace("t")
+        tr.add_spans(
+            (f"s{i}", 0, 1, None, None, {})
+            for i in range(tracing.MAX_SPANS + 3))
+        d = tr.to_dict()
+        assert d["span_count"] == tracing.MAX_SPANS
+        assert d["dropped_spans"] == 3
