@@ -87,19 +87,29 @@ ACTION_SHARD_DFS = "indices:data/read/dfs"
 ACTION_SHARD_CAN_MATCH = "indices:data/read/can_match"
 
 
+class _NeedsPool(Exception):
+    """A one-shard fan-out that began on the request thread has reached
+    work that no wait of its own polls the request's task through: a
+    transport hop to a remote copy, or a query the batcher's planners
+    turned away (the unbatched executor). Raised BEFORE that work, so
+    `_fan_out` can hand the shard to the pool, whose gather loop polls."""
+
+
 def _request_scoped_error(e: BaseException) -> bool:
     """Errors that indict the REQUEST, not the shard copy: parse
     errors, 4xx-shaped ClusterErrors, and backpressure/breaker
     rejections. They propagate unchanged from the fan-out instead of
     becoming `_shards.failures` entries — retrying a malformed query
-    on a replica cannot succeed, and a 429 must keep its contract."""
+    on a replica cannot succeed, and a 429 must keep its contract.
+    `_NeedsPool` is no copy's failure either: it leaves the fan-out's
+    retry logic the same way."""
     from ..common.memory import CircuitBreakingException
     from ..search.batcher import EsRejectedExecutionError
     from .service import ClusterError
 
     if isinstance(
         e, (dsl.QueryParseError, EsRejectedExecutionError,
-            CircuitBreakingException, EsOverloadedError),
+            CircuitBreakingException, EsOverloadedError, _NeedsPool),
     ):
         return True
     try:
@@ -546,6 +556,11 @@ class IndexService:
             "device_fused": 0,
             "host_fused": 0,
         }
+        # `_fan_out` calls by where the shards ran: on the request's own
+        # thread (one local shard whose wait polls the task itself) or
+        # in the fan-out pool (`thread_pool.search.fan_out.*`)
+        self._fan_out_lock = threading.Lock()
+        self.fan_out_stats = {"inline": 0, "pooled": 0}
         # bounded per-leg latency reservoirs (newest-wins) so bench.py
         # can report per-leg p50/p99 next to the cumulative averages —
         # kept OUTSIDE rrf_stats, whose values are reset-to-zero numbers
@@ -1385,7 +1400,7 @@ class IndexService:
 
     def shard_search_local(
         self, sid: int, body: Optional[dict], pinned_executor=None,
-        task=None,
+        task=None, planned_only: bool = False,
     ) -> dict:
         """Full per-shard query phase + folded fetch for ONE locally-held
         shard. Returns a wire-shaped dict:
@@ -1393,17 +1408,24 @@ class IndexService:
            hits: [{_id, _score, _source?, sort?, highlight?}],
            aggs?: partial, profile?: entry}
         `body` arrives with from/size already collapsed to 0/(from+size)
-        by the coordinator."""
+        by the coordinator. `planned_only`: the caller is a request
+        thread that nothing polls for a cancel, so only a batcher job
+        (whose wait does) may run here; anything else raises `_NeedsPool`
+        before it is executed, counted or traced."""
         tr = TRACE_CTX.get()
         if tr is None:
-            return self._shard_search(sid, body, pinned_executor, task)
+            return self._shard_search(
+                sid, body, pinned_executor, task, planned_only
+            )
         # the `shard_search` span, child of the coordinator's `fan_out`:
         # its id is reserved so the batcher jobs and the fetch phase of
         # this shard name it as their parent; written on success
         ts = time.perf_counter_ns()
         span_id = tr.reserve_span()
         with tracing.under(span_id):
-            out = self._shard_search(sid, body, pinned_executor, task)
+            out = self._shard_search(
+                sid, body, pinned_executor, task, planned_only
+            )
         tr.add_span(
             "shard_search", ts, time.perf_counter_ns(), span_id=span_id,
             index=self.name, shard=sid,
@@ -1413,6 +1435,7 @@ class IndexService:
 
     def _shard_search(
         self, sid: int, body: Optional[dict], pinned_executor, task,
+        planned_only: bool = False,
     ) -> dict:
         """`shard_search_local`'s body (which wraps it in the trace's
         `shard_search` span)."""
@@ -1591,21 +1614,24 @@ class IndexService:
                             query, self.mappings, self.analysis
                         )
                         kind = "serve"
-                        if plan is None:
-                            # the unbatched executor below; the mesh twin
-                            # and a retriever's leg that found no plan
-                            # come through here too, so this is the one
-                            # place that counts them
-                            self._batcher.note_unplanned()
                 elif query is None and knn is not None:
                     plan = extract_knn_plan(knn, self.mappings)
                     kind = "knn"
                 tr = TRACE_CTX.get()
-                if tr is not None and plan is None:
-                    tr.add_span(
-                        "plan", ts, time.perf_counter_ns(),
-                        family=None, planned=False,
-                    )
+                if plan is None:
+                    if planned_only:
+                        raise _NeedsPool()
+                    if kind == "serve":
+                        # a query neither planner took: the unbatched
+                        # executor below; the mesh twin and a retriever's
+                        # leg that found no plan come through here too,
+                        # so this is the one place that counts them
+                        self._batcher.note_unplanned()
+                    if tr is not None:
+                        tr.add_span(
+                            "plan", ts, time.perf_counter_ns(),
+                            family=None, planned=False,
+                        )
                 if plan is not None:
                     try:
                         job = self._batcher.submit_nowait(
@@ -1641,6 +1667,10 @@ class IndexService:
                             split[0], split[1], k, tth,
                             self.mappings, self.analysis,
                         )
+        if planned_only and td is None:
+            # no job served it (aggregations, a sort, a pinned reader,
+            # the numpy backend, a batcher closed under the request)
+            raise _NeedsPool()
         agg_partial = None
         try:
             agg_deviceable = (
@@ -2235,10 +2265,12 @@ class IndexService:
         """Scatter the per-shard request to every shard (local direct
         call or transport hop) with per-shard failure isolation.
 
-        Returns ``(results, failures, timed_out)``: `results[sid]` is
-        the wire-shaped shard result or None when the shard failed;
-        `failures` holds ShardSearchFailure-shaped entries; `timed_out`
-        is True when any shard blew the request's `timeout` budget.
+        Returns ``(results, failures, timed_out, inline)``:
+        `results[sid]` is the wire-shaped shard result or None when the
+        shard failed; `failures` holds ShardSearchFailure-shaped
+        entries; `timed_out` is True when any shard blew the request's
+        `timeout` budget; `inline` says the one shard ran on the
+        caller's thread and not in the pool (below).
 
         One shard's exception never poisons the fan-out: the call is
         retried once on another in-sync copy (excluding the failed
@@ -2251,14 +2283,20 @@ class IndexService:
         selection to the copies the prefilter consulted."""
         from ..tasks import TaskCancelledException
 
-        def attempt(sid: int, owner: Optional[str], pin) -> dict:
+        def attempt(
+            sid: int, owner: Optional[str], pin, planned_only: bool
+        ) -> dict:
+            local = owner is None or owner == self.local_node
+            if planned_only and not local:
+                raise _NeedsPool()  # a blocking transport hop
             faults.check(
                 "shard.search", index=self.name, shard=sid,
                 node=owner if owner is not None else (self.local_node or "local"),
             )
-            if owner is None or owner == self.local_node:
+            if local:
                 return self.shard_search_local(
-                    sid, body, pinned_executor=pin, task=task
+                    sid, body, pinned_executor=pin, task=task,
+                    planned_only=planned_only,
                 )
             return self.remote_call(
                 owner,
@@ -2266,7 +2304,7 @@ class IndexService:
                 {"index": self.name, "shard": sid, "body": body},
             )
 
-        def run(sid: int):
+        def run(sid: int, planned_only: bool = False):
             if skipped and sid in skipped:
                 return "ok", {
                     "total": 0,
@@ -2316,7 +2354,7 @@ class IndexService:
                 owners[sid] if owners is not None else self._search_node(sid)
             )
             try:
-                return "ok", attempt(sid, owner, pin)
+                return "ok", attempt(sid, owner, pin, planned_only)
             except TaskCancelledException:
                 raise
             except SearchTimeoutError as e:
@@ -2352,7 +2390,7 @@ class IndexService:
                             self.name, sid, owner, e
                         )
                     try:
-                        return "ok", attempt(sid, alt, pin)
+                        return "ok", attempt(sid, alt, pin, planned_only)
                     except SearchTimeoutError as e2:
                         return "timeout", shard_failure(self.name, sid, alt, e2)
                     except Exception as e2:
@@ -2363,24 +2401,47 @@ class IndexService:
                 return "fail", shard_failure(self.name, sid, owner, e)
 
         n = self.num_shards
-        if n == 1 and deadline is None and task is None:
-            outcomes = [run(0)]
-        else:
-            # copy the caller's context per shard so contextvars (the
-            # request's Trace, the FETCH_ACC accumulator, X-Opaque-Id)
-            # reach the fan-out worker threads — the vars hold shared
-            # mutable objects, so writes made in the workers are visible
-            # to the coordinator
-            cctx = contextvars.copy_context()
-            futs = [
-                _FANOUT_POOL.submit(cctx.copy().run, run, sid)
-                for sid in range(n)
-            ]
-            outcomes = []
-            for sid, f in enumerate(futs):
-                outcomes.append(
-                    self._gather_one(f, sid, deadline, task)
-                )
+        # One shard and no deadline to abandon it at: a second thread
+        # would have nothing to do but cost two hand-overs of the
+        # interpreter lock, so the shard runs on this one. With a task to
+        # poll, that holds only while the shard's own wait polls it
+        # (`_wait_batched`, a batcher job), which `planned_only` asks of
+        # the shard path; only a jax shard plans jobs and a pinned
+        # reader never does, so those are not asked (they would parse
+        # the body twice to hear no).
+        inline = n == 1 and deadline is None and (
+            task is None
+            or (pinned is None
+                and str(self.settings.get("search.backend")) == "jax")
+        )
+        try:
+            # the shard runs under a copy of the caller's context on
+            # either branch: contextvars (the request's Trace, the
+            # FETCH_ACC accumulator, X-Opaque-Id) reach it — the vars
+            # hold shared mutable objects, so writes made under the copy
+            # are visible to the coordinator — and nothing the shard
+            # sets outlives its call
+            if inline:
+                try:
+                    outcomes = [contextvars.copy_context().run(
+                        run, 0, task is not None
+                    )]
+                except _NeedsPool:
+                    inline = False
+            if not inline:
+                cctx = contextvars.copy_context()
+                futs = [
+                    _FANOUT_POOL.submit(cctx.copy().run, run, sid)
+                    for sid in range(n)
+                ]
+                outcomes = []
+                for sid, f in enumerate(futs):
+                    outcomes.append(
+                        self._gather_one(f, sid, deadline, task)
+                    )
+        finally:
+            with self._fan_out_lock:
+                self.fan_out_stats["inline" if inline else "pooled"] += 1
         results: List[Optional[dict]] = [None] * n
         failures: List[dict] = []
         timed_out = False
@@ -2391,7 +2452,7 @@ class IndexService:
                 failures.append(payload)
                 if tag == "timeout":
                     timed_out = True
-        return results, failures, timed_out
+        return results, failures, timed_out, inline
 
     def _gather_one(self, fut, sid: int, deadline: Optional[float], task):
         """Bounded wait for one shard future: an expired request budget
@@ -3000,7 +3061,7 @@ class IndexService:
         # id is reserved here so the shard spans name it as their parent
         tr, fan_id = tracing.reserve()
         with tracing.under(fan_id):
-            per_shard, failures, timed_out = self._fan_out(
+            per_shard, failures, timed_out, inline = self._fan_out(
                 sub, pinned_executors, skipped_shards, fixed_owners,
                 deadline=deadline, task=task,
             )
@@ -3107,13 +3168,17 @@ class IndexService:
                 index=self.name, shards=n, took_ms=took,
             )
             prev = tns
-            for pname, mark, span_id in (
-                ("parse", m_parse, None), ("can_match", m_canmatch, None),
-                ("dfs", m_dfs, None), ("fan_out", m_fanout, fan_id),
-                ("reduce", m_reduce, None),
+            for pname, mark, span_id, tags in (
+                ("parse", m_parse, None, {}),
+                ("can_match", m_canmatch, None, {}),
+                ("dfs", m_dfs, None, {}),
+                # inline: the one shard ran on this thread, not the pool
+                ("fan_out", m_fanout, fan_id, {"inline": inline}),
+                ("reduce", m_reduce, None, {}),
             ):
                 tr.add_span(
-                    pname, prev, mark, parent_id=root, span_id=span_id
+                    pname, prev, mark, parent_id=root, span_id=span_id,
+                    **tags,
                 )
                 prev = mark
         if profile:

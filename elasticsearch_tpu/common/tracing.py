@@ -15,9 +15,10 @@ Design:
     the var — `None` means tracing is off and costs one dict lookup.
     An HTTP handler announces itself in `REQUEST_CTX`; a trace armed
     under one is handed to it and closed after the response.
-    Fan-out pools propagate the var with `contextvars.copy_context()`;
-    the Trace object itself is shared and thread-safe, so spans added
-    from shard/leg worker threads land in the request's tree.
+    A fan-out runs each shard under `contextvars.copy_context()`, in
+    its pool or on the request thread; the Trace object itself is
+    shared and thread-safe, so spans added from shard/leg worker
+    threads land in the request's tree.
   * Spans are written retroactively (`add_span`) from marks the code
     took anyway. A span names the span that caused it: `PARENT_CTX`
     holds the id of the span the current code runs under and is
@@ -53,7 +54,10 @@ starts at `http`'s start and reaches the ring when `http` ends:
     (a search called as a library, with no handler above it, has no
     `http`: `admission_wait` and `coordinator` are its roots)
     coordinator [index, shards, took_ms]
-      > parse, can_match, dfs, fan_out, reduce   (tile the coordinator)
+      > parse, can_match, dfs, fan_out [inline], reduce   (tile the
+        coordinator; inline true: the index's one shard ran on the
+        request thread, false: in the fan-out pool — several shards, a
+        `timeout`, a remote or pinned copy, a query no planner took)
     fan_out > shard_search [index, shard, backend]   (one per shard)
     coordinator of a `retriever` (or `rank: {rrf}`) search, the same
     span under the same name

@@ -645,10 +645,17 @@ class RestActions:
         # fuse's and the legs' summed milliseconds (a leg from the legs'
         # common start to its own completion mark)
         rrf: dict = {}
+        # coordinator fan-outs by where the shards ran: the request's
+        # own thread (one local shard whose wait polls the task) or the
+        # fan-out pool (IndexService.fan_out_stats)
+        fan_out = {"inline": 0, "pooled": 0}
         for idx in self.cluster.indices.values():
             with idx._rrf_lock:
                 for k, v in idx.rrf_stats.items():
                     rrf[k] = rrf.get(k, 0) + v
+            with idx._fan_out_lock:
+                for k, v in idx.fan_out_stats.items():
+                    fan_out[k] += v
             b = getattr(idx, "_batcher", None)
             if b is not None:
                 for k in batch:
@@ -900,6 +907,7 @@ class RestActions:
                             ],
                             # the match family's twin of serve_rare_tiles
                             "fused_rare_tiles": batch["fused_rare_tiles"],
+                            "fan_out": fan_out,
                         }
                     },
                     "uptime_in_millis": int(

@@ -611,6 +611,10 @@ class TestQueuedJobCancel:
         ):
             time.sleep(0.01)
         assert svc._batcher.stats["cancelled_jobs"] == 1
+        # one shard, a planned job: both searches stayed on the calling
+        # thread, and the poll that cancelled the job was the shard's
+        # own wait (`_wait_batched`), not the fan-out pool's gather loop
+        assert svc.fan_out_stats == {"inline": 2, "pooled": 0}
         faults.clear()
         assert launches0 >= 1  # the warm query did launch
         svc.close()

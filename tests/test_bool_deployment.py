@@ -126,7 +126,9 @@ def node_numbers(server) -> dict:
 
     _status, body = RestActions(server.cluster).nodes_stats(None, {}, {})
     node = body["nodes"]["node-0"]
-    return {**node["thread_pool"]["search"],
+    pool = node["thread_pool"]["search"]
+    return {**{k: v for k, v in pool.items() if isinstance(v, int)},
+            **{f"fan_out.{k}": v for k, v in pool["fan_out"].items()},
             **{k: v for k, v in node["pipeline"]["batching"].items()
                if isinstance(v, int)}}
 
@@ -153,12 +155,17 @@ def test_every_request_of_the_mix_is_one_serve_job(deployment):
     assert moved["direct_collect_groups"] == n
     assert moved["serve_clauses"] == 2 * n
     assert moved["serve_multi_term_clauses"] == mixed
-    # what the planner still turns away is counted, once a query
+    # one shard, every request a job: none left the request thread
+    assert (moved["fan_out.inline"], moved["fan_out.pooled"]) == (n, 0)
+    # what the planner still turns away is counted, once a query (the
+    # request thread only asks; the pool's run of it is the one counted)
     post(server.port, path, {"query": {"bool": {
         "must": [{"term": {"body": "w00051"}}],
         "must_not": [{"term": {"body": "w00052"}}]}}, "size": 10})
-    assert node_numbers(server)["unplanned_queries"] == (
-        after["unplanned_queries"] + 1)
+    last = node_numbers(server)
+    assert last["unplanned_queries"] == after["unplanned_queries"] + 1
+    assert (last["fan_out.inline"], last["fan_out.pooled"]) == (
+        after["fan_out.inline"], after["fan_out.pooled"] + 1)
 
 
 @pytest.mark.parametrize("case,clauses,msm", [

@@ -50,7 +50,9 @@ REQUIRED = {
     "thread_pool.search": {
         "queue_capacity", "completed", "rejected", "launches",
         "serve_fallback_jobs", "serve_clauses", "serve_multi_term_clauses",
+        "fan_out",
     },
+    "thread_pool.search.fan_out": {"inline", "pooled"},
     "transfer.scoring": {
         "h2d_count", "h2d_bytes", "d2h_count", "d2h_bytes",
     },

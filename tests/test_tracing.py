@@ -25,6 +25,7 @@ Contract under test:
 
 import contextvars
 import json
+import threading
 import time
 import urllib.request
 
@@ -657,6 +658,7 @@ class TestHttpRoot:
                              ids=["match", "knn", "rrf"])
     def test_one_trace_a_request_rooted_at_http(self, http_server, body):
         raw = json.dumps(body).encode()
+        t_sent = time.perf_counter_ns()
         status, _, answer = http_call(http_server, "POST",
                                       "/tr-http/_search", body)
         t_answered = time.perf_counter_ns()
@@ -692,7 +694,12 @@ class TestHttpRoot:
         # response was written: its length covers `respond`
         assert tr["duration_ns"] == http["duration_ns"]
         assert end_ns(by["respond"]) == http["start_ns"] + tr["duration_ns"]
-        assert end_ns(http) <= t_answered
+        # `http` ends at a mark the handler thread takes once its last
+        # socket write has returned, so the client may hold the answer
+        # first: the span starts after the request left and ends within
+        # a second of the answer (a thread's turn under six workers)
+        assert t_sent <= http["start_ns"]
+        assert end_ns(http) <= t_answered + 1_000_000_000
 
     def test_request_thread_spans_tile_shard_search(self, http_server):
         for body in (MATCH, KNN):
@@ -835,3 +842,195 @@ class TestAddSpans:
         d = tr.to_dict()
         assert d["span_count"] == tracing.MAX_SPANS
         assert d["dropped_spans"] == 3
+
+
+# ---------------------------------------------------------------------
+# a one-shard fan-out on the request thread (`fan_out` [inline])
+# ---------------------------------------------------------------------
+
+
+def fan_out_counts(srv) -> dict:
+    _, _, stats = http_call(srv, "GET", "/_nodes/stats")
+    node = next(iter(stats["nodes"].values()))
+    return dict(node["thread_pool"]["search"]["fan_out"])
+
+
+def tree_shape(spans) -> set:
+    """{(name, parent's name, tag names)}: what two runs of one request
+    over different fan-outs must agree on."""
+    ids = {s["id"]: s["name"] for s in spans}
+    return {(s["name"], ids.get(s["parent_id"]), tuple(sorted(s["tags"])))
+            for s in spans if s["name"] != "compile"}
+
+
+@pytest.fixture()
+def shard_threads(monkeypatch):
+    """The names of the threads `shard_search_local` ran on, and what
+    the context variables read there."""
+    from elasticsearch_tpu.cluster.indices import IndexService
+
+    seen = []
+    inner = IndexService.shard_search_local
+
+    def spy(self, *args, **kwargs):
+        seen.append({
+            "thread": threading.current_thread().name,
+            "trace": tracing.TRACE_CTX.get(),
+            "parent": tracing.PARENT_CTX.get(),
+            "opaque": tracing.OPAQUE_ID_CTX.get(),
+        })
+        LEAK.set("set inside the shard call")
+        return inner(self, *args, **kwargs)
+
+    monkeypatch.setattr(IndexService, "shard_search_local", spy)
+    return seen
+
+
+LEAK: contextvars.ContextVar = contextvars.ContextVar("leak", default=None)
+
+
+def cancellable_task():
+    from elasticsearch_tpu.tasks import TaskManager
+
+    return TaskManager("n").register(
+        "indices:data/read/search", "t", cancellable=True)
+
+
+class TestInlineFanOut:
+    def test_rest_search_of_one_shard_stays_on_the_request_thread(
+        self, http_server, shard_threads, monkeypatch
+    ):
+        """(a) every REST search is a cancellable task; on a one-shard
+        jax index its shard runs on the handler's thread, and the trace
+        is the one a pooled two-shard fan-out writes."""
+        before = fan_out_counts(http_server)
+        http_call(http_server, "POST", "/tr-http/_search", MATCH,
+                  headers={"X-Opaque-Id": "caller-7"})
+        one = ring_after(1)[0]
+        assert fan_out_counts(http_server) == {
+            "inline": before["inline"] + 1, "pooled": before["pooled"]}
+        by = {s["name"]: s for s in one["spans"]}
+        assert by["fan_out"]["tags"] == {"inline": True}
+        (ran,) = shard_threads
+        assert not ran["thread"].startswith("search-fanout")
+        # the shard read the request's own trace, parent and header
+        assert ran["trace"].trace_id == one["trace_id"]
+        assert ran["parent"] == by["fan_out"]["id"]
+        assert ran["opaque"] == "caller-7"
+        # two shards on the shard path (the mesh twin would take them)
+        monkeypatch.setenv("ES_TPU_MESH", "off")
+        http_call(http_server, "PUT", "/tr-http2", {
+            "settings": {"number_of_shards": 2},
+            "mappings": {"properties": {"body": {"type": "text"}}},
+        })
+        try:
+            # (as many documents a shard as `tr-http` holds, so both
+            # sides of the fused kernel's size gate agree)
+            for i in range(80):
+                http_call(http_server, "POST", f"/tr-http2/_doc/{i}",
+                          {"body": "alpha beta"})
+            http_call(http_server, "POST", "/tr-http2/_refresh")
+            del shard_threads[:]
+            tracing.clear()
+            http_call(http_server, "POST", "/tr-http2/_search", MATCH)
+            two = ring_after(1)[0]
+            # (the node sums the counters of the indices it holds)
+            assert fan_out_counts(http_server) == {
+                "inline": before["inline"] + 1,
+                "pooled": before["pooled"] + 1,
+            }
+        finally:
+            http_call(http_server, "DELETE", "/tr-http2")
+        assert [s["tags"] for s in two["spans"]
+                if s["name"] == "fan_out"] == [{"inline": False}]
+        assert len(shard_threads) == 2 and all(
+            r["thread"].startswith("search-fanout") for r in shard_threads)
+        assert tree_shape(one["spans"]) == tree_shape(two["spans"])
+
+    def test_nothing_set_inside_the_shard_call_leaks(
+        self, fused_service, shard_threads
+    ):
+        """(d) the shard runs under a copy of the request's context on
+        either branch."""
+        inline0 = fused_service.fan_out_stats["inline"]
+        fused_service.search(json.loads(json.dumps(MATCH)),
+                             task=cancellable_task())
+        assert fused_service.fan_out_stats["inline"] == inline0 + 1
+        (ran,) = shard_threads
+        assert ran["thread"] == threading.current_thread().name
+        assert LEAK.get() is None
+        assert tracing.PARENT_CTX.get() is None
+
+    @pytest.mark.parametrize("case", [
+        "timeout", "pinned_reader", "remote_copy", "unplanned_query"])
+    def test_what_keeps_the_pool_is_abandoned_as_before(
+        self, fused_service, shard_threads, monkeypatch, case
+    ):
+        """(c) a deadline, a pinned or remote copy and a query no planner
+        takes run in the pool: the request thread stays free to give up
+        at the deadline or within a poll step of a cancel."""
+        from elasticsearch_tpu.tasks import TaskCancelledException
+
+        svc = fused_service
+        task = cancellable_task()
+        body = json.loads(json.dumps(MATCH))
+        held = 1.5  # seconds the shard's work takes
+
+        def slow(*args, **kwargs):
+            time.sleep(held)
+            return {"total": 0, "relation": "eq", "max_score": None,
+                    "hits": []}
+
+        pins = None
+        direct = {}
+        if case == "timeout":
+            body["timeout"] = "150ms"
+            monkeypatch.setattr(
+                type(svc), "_shard_search", lambda *a, **k: slow())
+        elif case == "pinned_reader":
+            pins = [{"node": "elsewhere", "ctx": "c0"}]
+            monkeypatch.setattr(svc, "remote_call", slow, raising=False)
+        elif case == "remote_copy":
+            direct = {"owners": {0: "elsewhere"}}
+            monkeypatch.setattr(svc, "remote_call", slow, raising=False)
+        else:
+            # a range query has no plan: the unbatched executor runs it
+            body["query"] = {"range": {"body": {"gte": "a"}}}
+            ex = svc._executor(svc.shards[0])
+            monkeypatch.setattr(
+                ex, "execute", lambda *a, **k: time.sleep(held))
+        unplanned0 = svc._batcher.stats["unplanned_queries"]
+        pooled0 = svc.fan_out_stats["pooled"]
+        timer = threading.Timer(0.1, task.cancel)
+        if case != "timeout":
+            timer.start()
+        t0 = time.monotonic()
+        try:
+            if direct:
+                with pytest.raises(TaskCancelledException):
+                    svc._fan_out(body, task=task, **direct)
+            elif case == "timeout":
+                handle = tracing.begin("search", index=svc.name)
+                resp = svc.search(body, task=task)
+                tracing.end(handle)
+                assert resp["timed_out"] is True
+                assert resp["_shards"]["failures"][0]["reason"]["type"] == (
+                    "timeout_exception")
+                assert [s["tags"] for s in tracing.recent(1)[0]["spans"]
+                        if s["name"] == "fan_out"] == [{"inline": False}]
+            else:
+                with pytest.raises(TaskCancelledException):
+                    svc.search(body, pinned_executors=pins, task=task)
+        finally:
+            timer.cancel()
+        # given up long before the shard's work ends
+        assert time.monotonic() - t0 < 0.5 * held
+        assert svc.fan_out_stats["pooled"] == pooled0 + 1
+        if case in ("pinned_reader", "remote_copy"):
+            assert shard_threads == []  # no local shard call at all
+        elif case == "unplanned_query":
+            # asked once on the request thread (not counted, no span),
+            # run once in the pool
+            assert [r["thread"].startswith("search-fanout")
+                    for r in shard_threads] == [False, True]
+            assert svc._batcher.stats["unplanned_queries"] == unplanned0 + 1
