@@ -1603,7 +1603,7 @@ class IndexService:
                     query.sparse = sparse_mod.resolve(
                         self.settings, bool(body.get("exact"))
                     )
-                    plan = extract_sparse_plan(query, self.mappings)
+                    plan = extract_sparse_plan(query, self.mappings, tth)
                     kind = "sparse"
                 elif query is not None and knn is None:
                     plan = extract_match_plan(
@@ -2589,7 +2589,7 @@ class IndexService:
                 query.sparse = sparse_mod.resolve(
                     self.settings, bool(body.get("exact"))
                 )
-                plan = extract_sparse_plan(query, self.mappings)
+                plan = extract_sparse_plan(query, self.mappings, tth)
                 kind = "mesh_sparse"
             else:
                 plan = extract_match_plan(

@@ -90,8 +90,13 @@ starts at `http`'s start and reaches the ring when `http` ends:
         dispatch [family, jobs, rows, launches, express, overflow; a
             fused match or serve group also rare_tiles, a serve group
             fields and hot_slots: the most tile slots / dense rows a
-            job and field used]
+            job and field used; a sparse group terms, tiles_scored,
+            tiles_pruned, chunk_launches (sums over its jobs and
+            segments) and quantized]
             -> the group's last kernel is enqueued
+          > sparse_theta [segment, launches]  a sparse group's phase A
+            on one segment: its chunk launches and the blocking
+            download of theta (the dispatch's one host sync)
         inflight  -> the worker comes back to collect the group
         collect [d2h_bytes; a text or sparse group also merged: false
             when it downloaded the fused kernel's packed row as it was,
@@ -116,8 +121,9 @@ dispatcher or connection thread, beside the device's `XLA Ops` line.
 
 `note_transfer` counts the query path's host<->device transfers where
 they happen (ops/scoring.py, the kNN upload in search/batcher.py, the
-serve family's per-job fallback and `JaxExecutor.segment_topk`; the rrf
-fuse moves nothing);
+serve family's per-job fallback and `JaxExecutor.segment_topk`, the
+sparse family's chunk planes and theta in ops/impact.py; the rrf fuse
+moves nothing);
 `_nodes/stats` reports the totals as `transfer.scoring.*`, and the
 hybrid searches' own counters (`IndexService.rrf_stats`: searches,
 host_fused (every search), device_fused (0: the serving path has no

@@ -31,9 +31,17 @@ dropped iff
 
 A doc occurs at most once in a term's postings, so that bound caps the
 doc's TOTAL score: dropped docs score strictly below theta and can
-never displace the top-k — the surviving-hits answer is EXACT (totals
-become lower bounds when tiles were dropped; callers surface the
-`pruned` flag exactly like the serve-plan path does).
+never displace the top-k — the surviving-hits answer is EXACT. The
+COUNT of matches is not: docs that only dropped tiles hold go
+uncounted, so the caller (search/batcher._dispatch_sparse_group) lets a
+job drop tiles only where its reported `hits.total` cannot move by it
+(totals untracked, or some query term's postings alone prove more
+matches than `track_total_hits` counts to).
+
+Every host<->device transfer of the family is noted where it happens
+(`common/tracing.note_transfer`): the three staged planes a chunk
+launch uploads, the theta download; the packed collect notes itself in
+ops/scoring.
 """
 
 from __future__ import annotations
@@ -45,7 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .scoring import BPAD, TCHUNK, _finalize, _threshold
+from ..common.tracing import note_transfer
+from .scoring import BPAD, TCHUNK, _finalize, _threshold, _to_host
 
 TILE_WIDTH = 128
 
@@ -59,6 +68,13 @@ FLOPS_PER_IMPACT_SLOT = 4
 def sparse_flops(n_tile_slots: int) -> int:
     """Estimated useful flops of one sparse job's plan on one segment."""
     return n_tile_slots * TILE_WIDTH * FLOPS_PER_IMPACT_SLOT
+
+
+def chunk_launches(tile_lists) -> int:
+    """`_impact_chunk_add` launches `ImpactScorer.score_into` makes for
+    these per-row tile lists: the longest row, TCHUNK tiles a launch."""
+    t_max = max((len(t) for t in tile_lists), default=0)
+    return -(-t_max // TCHUNK)
 
 
 def impact_tile_contrib(rows_d, rows_v, tw, valid, n_docs):
@@ -121,25 +137,21 @@ class ImpactScorer:
         cnt = jnp.zeros((rows, self.n_docs + 1), jnp.int32)
         return acc, cnt
 
-    def score_into(self, acc, cnt, tile_lists, weight_lists, staging=None):
+    def score_into(self, acc, cnt, tile_lists, weight_lists):
         """Streams per-row tile/weight lists (≤ acc rows, any length)
-        through TCHUNK-wide launches into the donated accumulators;
-        `staging` optionally supplies the executor's persistent host
-        slabs ((family, shape, dtype) → np.ndarray) — only the validity
-        plane needs clearing, stale ids/weights under tv=False rows
-        contribute exactly zero."""
+        through TCHUNK-wide launches into the donated accumulators.
+        Every launch is handed three host planes of its own, never
+        written again: a jitted call may still be reading a host
+        operand after it returns (the CPU backend aliases an aligned
+        NumPy buffer and runs the program later), so one slab refilled
+        chunk after chunk would score the wrong tiles (PERF.md section
+        4, the sparse deployment's table)."""
         rows = int(acc.shape[0])
         t_max = max((len(t) for t in tile_lists), default=0)
         for c0 in range(0, t_max, TCHUNK):
-            if staging is not None:
-                ti = staging("sparse_ti", (rows, TCHUNK), np.int32)
-                tw = staging("sparse_tw", (rows, TCHUNK), np.float32)
-                tv = staging("sparse_tv", (rows, TCHUNK), np.bool_)
-                tv[:] = False
-            else:
-                ti = np.zeros((rows, TCHUNK), np.int32)
-                tw = np.zeros((rows, TCHUNK), np.float32)
-                tv = np.zeros((rows, TCHUNK), bool)
+            ti = np.zeros((rows, TCHUNK), np.int32)
+            tw = np.zeros((rows, TCHUNK), np.float32)
+            tv = np.zeros((rows, TCHUNK), bool)
             for j, (tl, wl) in enumerate(zip(tile_lists, weight_lists)):
                 sl = tl[c0 : c0 + TCHUNK]
                 m = len(sl)
@@ -147,26 +159,31 @@ class ImpactScorer:
                     ti[j, :m] = sl
                     tw[j, :m] = wl[c0 : c0 + TCHUNK]
                     tv[j, :m] = True
+            for plane in (ti, tw, tv):  # host arrays: the launch uploads them
+                note_transfer("h2d", plane.nbytes)
             acc, cnt = _impact_chunk_add(
                 self.doc_ids, self.values, acc, cnt, ti, tw, tv
             )
         return acc, cnt
 
     def threshold(self, acc, k: int, live=None):
-        """(theta[B], accmax[B, n_blocks]) after phase A — the kth best
-        partial score per row (a sound lower bound on the final kth
-        best, so pruning against it stays exact)."""
-        theta, accmax = _threshold(
+        """theta[B] after phase A — the kth best partial score per row
+        (a sound lower bound on the final kth best, so pruning against
+        it stays exact): ONE blocking download. The program is the
+        chunked text path's `_threshold`; the block maxima it also
+        computes stay on the device (the impact-ordered bounds are
+        per tile, on the host)."""
+        theta, _accmax = _threshold(
             acc,
             live if live is not None else self.live,
             k=min(k, self.n_docs),
             block_size=self.block_size,
         )
-        return np.asarray(theta), np.asarray(accmax)
+        return _to_host(theta)
 
     def finalize(self, acc, cnt, k: int, live=None):
         s, d, tot = self.finalize_device(acc, cnt, k, live=live)
-        return np.asarray(s), np.asarray(d), np.asarray(tot)
+        return _to_host(s), _to_host(d), _to_host(tot)
 
     def finalize_device(self, acc, cnt, k: int, live=None):
         """(scores[B,k], docs[B,k], totals[B]) STAYING on device, in the

@@ -147,6 +147,15 @@ class MatchPlan:
         return self.msm == 1 and self.tth_cap is not None
 
 
+def _tth_cap(tth: Union[bool, int]) -> Optional[int]:
+    """A request's `track_total_hits` as a plan's `tth_cap`."""
+    if tth is True:
+        return None
+    if tth is False:
+        return 0
+    return max(1, int(tth))
+
+
 def extract_match_plan(
     query, mappings, analysis, tth: Union[bool, int] = 10_000
 ) -> Optional[MatchPlan]:
@@ -170,18 +179,12 @@ def extract_match_plan(
         msm = max(
             1, dsl.parse_minimum_should_match(query.minimum_should_match, len(terms))
         )
-    if tth is True:
-        cap: Optional[int] = None
-    elif tth is False:
-        cap = 0
-    else:
-        cap = max(1, int(tth))
     return MatchPlan(
         field=query.field,
         terms=tuple(terms),
         msm=msm,
         boost=query.boost,
-        tth_cap=cap,
+        tth_cap=_tth_cap(tth),
     )
 
 
@@ -254,15 +257,22 @@ class SparsePlan:
     oracle folds them) and term-sorted — the canonical accumulation
     order both paths share, which is what keeps the fp32 device path
     bit-equal to the oracle. `spec` (search/sparse.SparseSpec) rides
-    the group key, so int8 and fp32 servings never share a launch."""
+    the group key, so int8 and fp32 servings never share a launch.
+    `tth_cap` is MatchPlan's: what `hits.total` has to be exact up to
+    (None: exact whatever it is; 0: not tracked; N: up to N, the
+    request's `track_total_hits`), which decides whether the job may
+    drop tiles at all (`_dispatch_sparse_group`)."""
 
     field: str
     terms: Tuple[str, ...]
     weights: Tuple[float, ...]
     spec: object
+    tth_cap: Optional[int] = 10_000
 
 
-def extract_sparse_plan(query, mappings) -> Optional[SparsePlan]:
+def extract_sparse_plan(
+    query, mappings, tth: Union[bool, int] = 10_000
+) -> Optional[SparsePlan]:
     """Returns a SparsePlan when `query` is a bare sparse_vector query
     over a sparse_vector field with a resolved SparseSpec (the hot REST
     shape), else None → normal executor path (host oracle)."""
@@ -283,6 +293,7 @@ def extract_sparse_plan(query, mappings) -> Optional[SparsePlan]:
             float(np.float32(boost * np.float32(w))) for _, w in items
         ),
         spec=spec,
+        tth_cap=_tth_cap(tth),
     )
 
 
@@ -592,7 +603,7 @@ class _Group:
     __slots__ = (
         "family", "jobs", "rows", "express", "cold_s", "t_start",
         "t_dispatched", "t_collect", "d2h0", "launches", "flops",
-        "overflow", "compiles", "plan_tags", "merged",
+        "overflow", "compiles", "plan_tags", "merged", "sub_spans",
     )
 
     def __init__(self, family: str, jobs: int, rows: Optional[int],
@@ -610,7 +621,13 @@ class _Group:
         # a group's fused plans, for its `dispatch` span: `rare_tiles`
         # (most tile slots a job and field used) and, of a serve group,
         # `fields` and `hot_slots` (most dense rows a job and field used)
+        # of a sparse group also `terms`, `tiles_scored`, `tiles_pruned`,
+        # `chunk_launches` (sums over its jobs and segments), `quantized`
         self.plan_tags: Dict[str, int] = {}
+        # (name, start_ns, end_ns, tags): spans inside `dispatch`, its
+        # children (`sparse_theta`: phase A's launches and the blocking
+        # threshold download)
+        self.sub_spans: List[Tuple] = []
         # a text or sparse group's `collect` span: whether its candidates
         # went through the merge program (`_group_topk`), else None
         self.merged: Optional[bool] = None
@@ -674,6 +691,8 @@ class _Group:
                 launches=self.launches, express=self.express,
                 overflow=self.overflow, **self.plan_tags,
             )
+            for name, s0, s1, tags in self.sub_spans:
+                tr.add_span(name, s0, s1, parent_id=disp, **tags)
             tr.add_span("inflight", t1, t2, parent_id=up)
             coll = tr.add_span(
                 "collect", t2, t_done, parent_id=up,
@@ -2207,13 +2226,25 @@ class QueryBatcher:
         of same-(field, spec) sparse_vector jobs on every segment
         carrying the column. Two-phase per segment: phase A scores each
         query term's FIRST tile (where impact ordering puts the term
-        maxima), one theta download, then the surviving block-max tile
-        list scores into a fresh accumulator whose finalize triple
-        stays ON DEVICE until collect. The `sparse.score` fault site
-        fires per segment — an injected error (like an HBM degrade or
-        missing column) falls back DETERMINISTICALLY to the host dense
-        oracle for that segment at collect time, exact answers
-        included."""
+        maxima), one theta download (the `sparse_theta` span, a child
+        of `dispatch`), then the surviving block-max tile list scores
+        into a fresh accumulator whose finalize triple stays ON DEVICE
+        until collect.
+
+        `hits.total` follows Elasticsearch's rule whatever was dropped
+        (exact up to `track_total_hits`, then a `gte` bound): matches
+        are counted over the tiles that were scored, so a job may drop
+        tiles only where the total it has to report cannot move by it:
+        totals untracked (`tth_cap` 0), or one query term's postings
+        on this shard, less the shard's deleted docs, already MORE than
+        the cap (each posting a distinct matching doc: collect then
+        reports the cap and `gte`). Exact totals (`tth_cap` None) and
+        jobs with no such term score every tile and count exactly.
+
+        The `sparse.score` fault site fires per segment — an injected
+        error (like an HBM degrade or missing column) falls back
+        DETERMINISTICALLY to the host dense oracle for that segment at
+        collect time, exact answers included."""
         from ..ops import impact as impact_ops
         from . import sparse as sparse_mod
 
@@ -2221,10 +2252,24 @@ class QueryBatcher:
         reader = ex.reader
         nj = len(jobs)
         rows = rows or BPAD
-        staging = getattr(ex, "staging_slab", None)
         plan0 = jobs[0].plan
         field = plan0.field
         spec = plan0.spec
+        may_drop = []
+        for j in jobs:
+            cap = j.plan.tth_cap
+            ok = cap is not None
+            if ok and cap:
+                max_df = max(
+                    (ex.sparse_shard_df(field, t) for t in j.plan.terms),
+                    default=0,
+                )
+                ok = max_df - ex.deleted_count > cap
+            may_drop.append(ok)
+        tags = _group_now().plan_tags
+        if record:
+            tags["quantized"] = bool(spec.quantized)
+            tags["terms"] = sum(len(j.plan.terms) for j in jobs)
         items: List[Tuple] = []
         for si, seg in enumerate(reader.segments):
             sfh = (getattr(seg, "sparse", None) or {}).get(field)
@@ -2249,7 +2294,7 @@ class QueryBatcher:
             bound = sfh.tile_qmax if spec.quantized else sfh.tile_max
             bms = []
             prunable = []
-            for j in jobs:
+            for ji, j in enumerate(jobs):
                 tids, tws, bws, _, _ = impact_ops.impact_tile_lists(
                     sfh, j.plan.terms, j.plan.weights, spec.quantized
                 )
@@ -2262,19 +2307,26 @@ class QueryBatcher:
                 # block-max upper bounds assume non-negative tile
                 # weights; a negative query weight keeps the job exact
                 # but unpruned
-                prunable.append(bool((tws >= 0).all()))
+                prunable.append(may_drop[ji] and bool((tws >= 0).all()))
             thetas = np.full(len(jobs), -np.inf, np.float32)
+            launches = 0
+            theta_syncs = 0
             if any(
                 p and bm.n_tail_tiles for p, bm in zip(prunable, bms)
             ):
+                t_theta = time.perf_counter_ns()
+                a_tiles, a_weights = zip(*(bm.phase_a() for bm in bms))
                 acc, cnt = sc.new_acc(rows)
-                acc, cnt = sc.score_into(
-                    acc, cnt,
-                    [bm.phase_a()[0] for bm in bms],
-                    [bm.phase_a()[1] for bm in bms],
-                    staging=staging,
-                )
-                th, _ = sc.threshold(acc, kb)
+                acc, cnt = sc.score_into(acc, cnt, a_tiles, a_weights)
+                th = sc.threshold(acc, kb)
+                a_launches = impact_ops.chunk_launches(a_tiles)
+                launches += a_launches
+                theta_syncs = 1
+                if record:
+                    _group_now().sub_spans.append((
+                        "sparse_theta", t_theta, time.perf_counter_ns(),
+                        {"segment": si, "launches": a_launches},
+                    ))
                 for ji in range(len(jobs)):
                     if prunable[ji]:
                         thetas[ji] = th[ji]
@@ -2291,18 +2343,22 @@ class QueryBatcher:
                 tiles_scored += len(t)
                 tiles_pruned += dropped
             acc, cnt = sc.new_acc(rows)
-            acc, cnt = sc.score_into(
-                acc, cnt, tile_lists, weight_lists, staging=staging
-            )
+            acc, cnt = sc.score_into(acc, cnt, tile_lists, weight_lists)
+            launches += impact_ops.chunk_launches(tile_lists)
             pend = sc.finalize_device(acc, cnt, kb)
             if record:
                 sparse_mod.note_search(
-                    nj, spec.quantized, tiles_scored, tiles_pruned
+                    nj, spec.quantized, tiles_scored, tiles_pruned,
+                    chunk_launches=launches, theta_syncs=theta_syncs,
                 )
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["sparse_jobs"] += nj
                 _group_now().add_flops(impact_ops.sparse_flops(tiles_scored))
+                for name, n in (("tiles_scored", tiles_scored),
+                                ("tiles_pruned", tiles_pruned),
+                                ("chunk_launches", launches)):
+                    tags[name] = tags.get(name, 0) + n
             items.append(("dev", si, (pend, pruned_flags)))
         return items
 
@@ -2313,9 +2369,11 @@ class QueryBatcher:
         segments (fault / degrade) run per job through the executor's generic
         per-segment top-k — which routes SparseVectorQuery to the host
         dense oracle — and join the final merge. Hits are exact either
-        way; totals turn relation "gte" when block-max pruning dropped
-        tiles (the dropped docs provably score below the kth best, but
-        they are no longer counted)."""
+        way, and so is the total up to the job's `tth_cap`: a job that
+        dropped tiles was let to only because more than `tth_cap`
+        matches were proved beforehand (`_dispatch_sparse_group`), so
+        its count over the scored tiles is raised to that proof and the
+        relation turns "gte", as Lucene's does once counting stops."""
         ex = jobs[0].executor
         reader = ex.reader
         per_job_cands: List[List[Tuple[float, int, int]]] = [
@@ -2364,6 +2422,7 @@ class QueryBatcher:
                 )
                 for s, si, d in page
             ]
+            total = int(totals[ji])
             relation = "eq"
             if pruned_any[ji]:
                 if record:
@@ -2373,9 +2432,14 @@ class QueryBatcher:
                     j.prof["pruned_jobs"] = (
                         j.prof.get("pruned_jobs", 0) + 1
                     )
+                # the count over the scored tiles is a lower bound; the
+                # job dropped tiles on the proof of MORE than `tth_cap`
+                # live matches (or tracks no total at all)
                 relation = "gte"
+                if j.plan.tth_cap:
+                    total = max(total, j.plan.tth_cap + 1)
             j.result = TopDocs(
-                total=int(totals[ji]),
+                total=total,
                 hits=hits,
                 max_score=hits[0].score if hits else None,
                 relation=relation,

@@ -974,6 +974,22 @@ class JaxExecutor:
             self._shard_dfs[key] = df
         return df
 
+    def sparse_shard_df(self, field: str, term: str) -> int:
+        """Postings of one `sparse_vector` term over the shard's
+        segments (each a distinct doc that matches any query holding
+        the term): what proves a capped total before tiles may drop."""
+        key = ("sparse", field, term)
+        df = self._shard_dfs.get(key)
+        if df is None:
+            df = 0
+            for seg in self.reader.segments:
+                sf = (getattr(seg, "sparse", None) or {}).get(field)
+                tid = sf.term_id(term) if sf is not None else -1
+                if tid >= 0:
+                    df += int(sf.term_df[tid])
+            self._shard_dfs[key] = df
+        return df
+
     @property
     def deleted_count(self) -> int:
         if self._deleted_count is None:

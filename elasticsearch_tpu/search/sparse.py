@@ -7,9 +7,13 @@ with per-term symmetric scales — or `none` for full-fidelity fp32); a
 request opts into the fp32 column regardless via a body-level
 `"exact": true` (the same escape hatch the ANN tier honors). Pruning
 is always the exact impact-ordered block-max pass — it never changes
-the returned hits, only how many tiles get scored — so there is no
-recall knob to resolve here; the only lossy choice is int8 storage,
-and even that is gated by a recall@10 ≥ 0.95 floor in tier-1.
+the returned hits, only how many tiles get scored — and it never
+changes `hits.total` either: a job drops tiles only where the total
+Elasticsearch would report is already proved (search/batcher
+`_dispatch_sparse_group`: exact up to `track_total_hits`, then a `gte`
+bound). So there is no recall knob to resolve here; the only lossy
+choice is int8 storage, and even that is gated by a recall@10 ≥ 0.95
+floor in tier-1.
 
 The dense host oracle (NumpyExecutor's term-at-a-time fp32 scorer) is
 never removed: every device-path failure (injected `sparse.score`
@@ -58,6 +62,8 @@ SPARSE_STATS = {
     "tiles_scored": 0,  # Σ tiles actually launched
     "tiles_pruned": 0,  # Σ tail tiles dropped by block-max bounds
     "pruned_searches": 0,  # scorings where at least one tile dropped
+    "chunk_launches": 0,  # Σ `_impact_chunk_add` launches, both phases
+    "theta_syncs": 0,  # phase A threshold downloads (one a scoring)
     # bytes of the impact VALUE planes actually uploaded vs what the
     # same planes would cost at fp32 — the headline int8 compression
     # ratio (4x per plane; ≥2x smaller gated in tier-1). The doc-id
@@ -74,9 +80,13 @@ def note(key: str, n: int = 1) -> None:
 
 
 def note_search(
-    jobs: int, quantized: bool, tiles_scored: int, tiles_pruned: int
+    jobs: int, quantized: bool, tiles_scored: int, tiles_pruned: int,
+    chunk_launches: int = 0, theta_syncs: int = 0,
 ) -> None:
-    """One impact-tile scoring of `jobs` queries against one segment."""
+    """One impact-tile scoring of `jobs` queries against one segment:
+    `tiles_scored` are the final pass's (phase A rescored among them),
+    `chunk_launches` both phases' kernel launches, `theta_syncs` the
+    blocking threshold downloads between them (0 or 1)."""
     with _STATS_LOCK:
         SPARSE_STATS["searches"] += jobs
         if quantized:
@@ -85,6 +95,8 @@ def note_search(
         SPARSE_STATS["tiles_pruned"] += tiles_pruned
         if tiles_pruned:
             SPARSE_STATS["pruned_searches"] += jobs
+        SPARSE_STATS["chunk_launches"] += chunk_launches
+        SPARSE_STATS["theta_syncs"] += theta_syncs
 
 
 def stats_snapshot() -> dict:

@@ -401,11 +401,13 @@ def test_ivf_probe(one_chip):
 
 
 def test_impact_scorer_chunk(one_chip):
-    """ImpactScorer's chunk launch over an int8 impact column, at the
-    one-row (express lane) bucket; the 32-row bucket takes ~10 s here."""
+    """ImpactScorer's chunk launch over an int8 impact column at the
+    learned-sparse deployment's size (`msmarco-splade-sparse`: ~127
+    non-zeros a passage over 1M passages), at the one-row (express lane)
+    bucket; the 32-row bucket takes ~10 s here."""
     from elasticsearch_tpu.ops import impact
 
-    n_tiles = 60_000  # ~6 sparse terms/doc over 1M docs / 128
+    n_tiles = 1_005_620  # the builder's count at 1,000,000 passages
     rows = 1
     s = _on(one_chip)
     compiled = impact._impact_chunk_add.lower(

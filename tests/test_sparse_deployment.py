@@ -1,0 +1,447 @@
+"""The learned-sparse deployment (big-ann-benchmarks' sparse track;
+`benchmarks/configs/msmarco-splade-sparse.json`) at a small size:
+SPLADE-shaped weighted-token queries over a few tens of thousands of
+seeded passages of the benchmark's own corpus builder
+(`corpora/splade_impacts.py`), served over HTTP through the batcher's
+`sparse` family and held to the benchmark's own plain reference
+(`references/impact_sum.py`) by the benchmark's own rule
+(`benchmarks/compare.py`, `exact`: ids tie group by tie group, scores
+within 1e-5, `hits.total` equal), under both storages of the impacts
+(the shipped int8 twin, and `index.sparse.quantization: none`).
+
+48,000 passages: the most frequent term is in 25% of them, 12,000, so a
+total past `track_total_hits`'s 10,000 can be proved by one term and a
+job may drop tiles; the full 49-token queries match ~33,000 passages.
+"""
+
+import http.client
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from elasticsearch_tpu.common import tracing
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from compare import compare_all, compare_one, reference_body  # noqa: E402
+from plugins import load_json, load_plugin  # noqa: E402
+from run import place_segment  # noqa: E402
+
+DOCS, SEED, N_BODIES = 48_000, 5, 24
+STORAGES = ("int8", "float32")
+
+
+def post(port: int, path: str, body: dict, method: str = "POST") -> dict:
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=300)
+    try:
+        conn.request(method, path, body=json.dumps(body).encode(),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        payload = resp.read()
+        assert resp.status == 200, (resp.status, payload[:400])
+        return json.loads(payload)
+    finally:
+        conn.close()
+
+
+def get(port: int, path: str) -> dict:
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=300)
+    try:
+        conn.request("GET", path)
+        return json.loads(conn.getresponse().read())
+    finally:
+        conn.close()
+
+
+def vector_body(field: str, vector: dict, **extra) -> dict:
+    return {"query": {"sparse_vector": {"field": field,
+                                        "query_vector": vector}},
+            "size": 10, "_source": False, **extra}
+
+
+class Deployment:
+    """One server holding the corpus under both storages, the plain
+    reference of each, and the bodies the cases pick from."""
+
+    def __init__(self):
+        from elasticsearch_tpu.rest.server import ElasticsearchTpuServer
+
+        base = load_json("configs", "msmarco-splade-sparse.json")
+        corpus = load_plugin("corpora", base["corpus"]["builder"]).build(
+            base, SEED, DOCS)
+        self.corpus = corpus
+        self.field = corpus["body_context"]["field"]
+        self.configs, self.refs, self.index = {}, {}, {}
+        self.server = ElasticsearchTpuServer(port=0)
+        self.server.start_background()
+        self.port = self.server.port
+        for storage in STORAGES:
+            config = json.loads(json.dumps(base))
+            settings = dict(config["settings"])
+            if storage == "float32":
+                settings["index.sparse.quantization"] = "none"
+                config["guarantees"]["stored"] = "float32"
+            else:
+                # the deployment's own file: the node's shipped storage
+                assert "index.sparse.quantization" not in settings
+                assert config["guarantees"]["stored"] == "int8"
+            index = f"{config['index']}-{storage}"
+            post(self.port, f"/{index}", {"settings": settings,
+                                          "mappings": corpus["mappings"]},
+                 "PUT")
+            place_segment(self.server.cluster.indices[index],
+                          corpus["segment"])
+            self.configs[storage], self.index[storage] = config, index
+            self.refs[storage] = load_plugin(
+                "references", config["reference"]).Reference(
+                    corpus["reference"], config)
+        gen = load_plugin("bodies", base["body"]["generator"])
+        ctx = corpus["body_context"]
+        self.bodies = [json.loads(b) for b in gen.make(
+            ctx, base["body"]["args"], np.random.default_rng([37, 9]),
+            N_BODIES)]
+        df = np.asarray(ctx["term_df"])
+        width = ctx["term_width"]
+        self.df_of = {f"t{int(t):0{width}d}": int(d)
+                      for t, d in zip(ctx["terms"], df)}
+        by_df = sorted(self.df_of, key=lambda t: -self.df_of[t])
+        self.frequent = by_df  # most frequent first
+        corpus_mod = load_plugin("corpora", base["corpus"]["builder"])
+        rng = np.random.default_rng(11)
+        # a query of the 128 most frequent tokens: several launches of
+        # TCHUNK tiles at one row
+        w = corpus_mod.draw_weights(
+            rng, np.full(128, 0.6), base["body"]["args"]["weights"])
+        self.long_body = vector_body(self.field, {
+            t: round(float(x), 4) for t, x in zip(by_df[:128], w)})
+        rare = [t for t in by_df if 40 <= self.df_of[t] <= 400][:3]
+        # the most frequent token beside a light, rare one: the first's
+        # tail tiles cannot reach the top ten, and the tiles that are
+        # kept hold far fewer than 10,000 passages
+        self.pruning_vector = {by_df[0]: 2.0, rare[0]: 0.05}
+        # a full-shape query that holds the most frequent token, as
+        # nearly every query of the deployment's size holds one whose
+        # postings alone pass 10,000: phase A and theta run
+        full = dict(self.bodies[7]["query"]["sparse_vector"]["query_vector"])
+        full.setdefault(by_df[0], 0.5)
+        self.proved_body = vector_body(self.field, full)
+        # three rare tokens: a total far below 10,000
+        self.rare_body = vector_body(
+            self.field, {t: 1.0 + 0.25 * i for i, t in enumerate(rare)})
+
+    def search(self, storage: str, body: dict) -> dict:
+        return post(self.port, f"/{self.index[storage]}/_search", body)
+
+    def held(self, storage: str, body: dict, served: dict) -> dict:
+        g = self.configs[storage]["guarantees"]
+        (expected,) = self.refs[storage].answer_many(
+            [reference_body(g["rule"], body)])
+        got = compare_one(g["rule"], g["score_rtol"], body, served, expected)
+        assert got["page_ok"], got["why"]
+        assert got["total_ok"], (served["hits"]["total"],
+                                 expected["hits"]["total"])
+        assert got["score_rel"] <= g["score_rtol"], got["score_rel"]
+        return expected
+
+    def sparse_stats(self) -> dict:
+        node = next(iter(get(self.port, "/_nodes/stats")["nodes"].values()))
+        return node["sparse"]
+
+    def last_trace(self) -> dict:
+        return get(self.port, "/_internal/traces?n=1")["traces"][-1]
+
+
+@pytest.fixture(scope="module")
+def dep():
+    d = Deployment()
+    yield d
+    d.server.close()
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+@pytest.mark.parametrize("i", range(6))
+def test_full_shape_query_over_http_is_the_plain_references(dep, storage, i):
+    body = dep.bodies[i]
+    assert 8 <= len(body["query"]["sparse_vector"]["query_vector"]) <= 128
+    expected = dep.held(storage, body, dep.search(storage, body))
+    # ~49 tokens drawn by posting mass match most of the shard
+    assert expected["hits"]["total"] == {"value": 10_000, "relation": "gte"}
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_total_below_the_threshold_is_exact(dep, storage):
+    before = dep.sparse_stats()
+    served = dep.search(storage, dep.rare_body)
+    expected = dep.held(storage, dep.rare_body, served)
+    total = served["hits"]["total"]
+    assert total["relation"] == "eq" and 10 < total["value"] < 10_000
+    assert total == expected["hits"]["total"]
+    # nothing proves a total past the cap: no tile may be dropped
+    after = dep.sparse_stats()
+    assert after["tiles_pruned"] == before["tiles_pruned"]
+    assert after["theta_syncs"] == before["theta_syncs"]
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_pruned_job_answers_elasticsearchs_total(dep, storage):
+    """One token alone is in more passages than `track_total_hits`
+    counts to, so tiles drop; the page and the total stay the
+    reference's: 10,000 and `gte`, not the count over the kept tiles."""
+    body = vector_body(dep.field, dep.pruning_vector)
+    assert dep.df_of[dep.frequent[0]] > 10_000
+    before = dep.sparse_stats()
+    served = dep.search(storage, body)
+    after = dep.sparse_stats()
+    assert after["tiles_pruned"] > before["tiles_pruned"]
+    assert after["pruned_searches"] == before["pruned_searches"] + 1
+    assert after["theta_syncs"] == before["theta_syncs"] + 1
+    kept = 128 * (after["tiles_scored"] - before["tiles_scored"])
+    assert kept < 10_000  # a count over the kept tiles would fall short
+    assert served["hits"]["total"] == {"value": 10_000, "relation": "gte"}
+    dep.held(storage, body, served)
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_exact_total_is_counted_over_every_tile(dep, storage):
+    """`track_total_hits: true`: no tile may be dropped, the same page,
+    the count of passages that hold either token."""
+    body = vector_body(dep.field, dep.pruning_vector, track_total_hits=True)
+    before = dep.sparse_stats()
+    served = dep.search(storage, body)
+    after = dep.sparse_stats()
+    assert after["tiles_pruned"] == before["tiles_pruned"]
+    ref, data = dep.refs[storage], dep.corpus["reference"]
+    holders = set()
+    for token in dep.pruning_vector:
+        t = ref.term_of[int(token[1:])]
+        lo, hi = data["post_start"][t], data["post_start"][t + 1]
+        holders.update(data["post_doc"][lo:hi].tolist())
+    assert served["hits"]["total"] == {"value": len(holders),
+                                       "relation": "eq"}
+    default = dict(body)
+    del default["track_total_hits"]
+    pruned = dep.search(storage, default)
+    assert ([h["_id"] for h in served["hits"]["hits"]]
+            == [h["_id"] for h in pruned["hits"]["hits"]])
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_query_of_several_chunk_launches_stays_right(dep, storage):
+    """128 frequent tokens: more tiles than one launch carries at one
+    row, so the three staged planes are made launch after launch."""
+    from elasticsearch_tpu.ops.scoring import TCHUNK
+
+    before = dep.sparse_stats()
+    served = dep.search(storage, dep.long_body)
+    after = dep.sparse_stats()
+    tiles = after["tiles_scored"] - before["tiles_scored"]
+    launches = after["chunk_launches"] - before["chunk_launches"]
+    assert tiles > 3 * TCHUNK
+    assert launches >= -(-tiles // TCHUNK) >= 4
+    dep.held(storage, dep.long_body, served)
+
+
+def test_no_launch_is_handed_a_plane_that_is_written_again(dep, monkeypatch):
+    """A jitted call may read a host operand after it returns (the CPU
+    backend aliases an aligned NumPy buffer), so `score_into` gives
+    every launch planes of its own: when the last launch is enqueued,
+    each launch's planes still hold that launch's own chunk."""
+    from elasticsearch_tpu.ops import impact as impact_ops
+    from elasticsearch_tpu.ops.scoring import TCHUNK
+
+    sf = dep.corpus["segment"].sparse[dep.field]
+    sc = impact_ops.ImpactScorer(sf.doc_ids, sf.qweights, DOCS)
+    vector = dep.long_body["query"]["sparse_vector"]["query_vector"]
+    _tids, tws, _bws, starts, counts = impact_ops.impact_tile_lists(
+        sf, list(vector), list(vector.values()), True)
+    tiles = np.concatenate([np.arange(s, s + c) for s, c in
+                            zip(starts, counts)])
+    weights = np.repeat(tws, counts)
+    handed = []
+    launch = impact_ops._impact_chunk_add
+
+    def recording(doc_ids, values, acc, cnt, ti, tw, tv):
+        handed.append((ti, tw, tv))
+        return launch(doc_ids, values, acc, cnt, ti, tw, tv)
+
+    monkeypatch.setattr(impact_ops, "_impact_chunk_add", recording)
+    sc.score_into(*sc.new_acc(1), [tiles], [weights])
+    assert len(handed) == impact_ops.chunk_launches([tiles]) >= 4
+    assert len({id(p) for planes in handed for p in planes}) == 3 * len(handed)
+    for c, (ti, tw, tv) in enumerate(handed):
+        want = tiles[c * TCHUNK:(c + 1) * TCHUNK]
+        m = len(want)
+        assert (ti[0, :m] == want).all() and tv[0, :m].all()
+        assert not tv[0, m:].any()
+        assert (tw[0, :m] == weights[c * TCHUNK:c * TCHUNK + m]).all()
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_bf16_control_fails(dep, storage):
+    """The reference one precision down, put in the program's place,
+    is caught by pages or scores; the reference itself passes."""
+    g = dep.configs[storage]["guarantees"]
+    ref, bodies = dep.refs[storage], dep.bodies
+    refs = ref.answer_many([reference_body(g["rule"], b) for b in bodies])
+    same = compare_all(g, bodies, ref.answer_many(bodies), refs)
+    assert same["correct"], same
+    low = compare_all(g, bodies, ref.answer_many(bodies, precision="lower"),
+                      refs)
+    assert not low["correct"]
+    assert (low["numbers"]["page_mismatches"][0] > 0
+            or low["numbers"]["score_rel_max"][0] > g["score_rtol"])
+    assert low["numbers"]["score_rel_max"][0] > 10 * g["score_rtol"]
+
+
+def test_stored_precision_differs_between_the_storages(dep):
+    """The int8 answers are the int8 reference's, not the float32
+    one's: the check is tight on the stated storage."""
+    g = dep.configs["int8"]["guarantees"]
+    bodies = dep.bodies
+    served = [dep.search("int8", b) for b in bodies]
+    refs = dep.refs["float32"].answer_many(
+        [reference_body(g["rule"], b) for b in bodies])
+    cross = compare_all(g, bodies, served, refs)
+    assert not cross["correct"]
+    assert cross["numbers"]["score_rel_max"][0] > g["score_rtol"]
+
+
+@pytest.mark.parametrize("which", ["full_shape", "long", "pruning", "rare"])
+def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
+    """`transfer.scoring.*` moves by exactly what the job moved: three
+    staged planes a chunk launch, theta, the packed collect."""
+    from elasticsearch_tpu.ops.scoring import TCHUNK
+
+    body = {"full_shape": dep.proved_body, "long": dep.long_body,
+            "pruning": vector_body(dep.field, dep.pruning_vector),
+            "rare": dep.rare_body}[which]
+    dep.search("int8", body)  # every program built
+    s0, x0 = dep.sparse_stats(), tracing.transfer_stats()
+    dep.search("int8", body)
+    s1, x1 = dep.sparse_stats(), tracing.transfer_stats()
+    spans = {s["name"]: s for s in dep.last_trace()["spans"]}
+    rows = spans["dispatch"]["tags"]["rows"]
+    launches = s1["chunk_launches"] - s0["chunk_launches"]
+    syncs = s1["theta_syncs"] - s0["theta_syncs"]
+    assert launches >= 1 and syncs == (which != "rare")
+    assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches
+    assert (x1["h2d_bytes"] - x0["h2d_bytes"]
+            == launches * rows * TCHUNK * (4 + 4 + 1))
+    assert x1["d2h_count"] - x0["d2h_count"] == syncs + 1
+    assert (x1["d2h_bytes"] - x0["d2h_bytes"]
+            == syncs * 4 * rows + spans["collect"]["tags"]["d2h_bytes"])
+
+
+def test_the_request_goes_the_normal_path(dep):
+    """A planned sparse job on the request thread's inline fan-out: the
+    seven job spans under `shard_search`, `sparse_theta` under
+    `dispatch`, the group's tags, nothing unplanned, no fallback."""
+    from elasticsearch_tpu.rest.actions import RestActions
+
+    def numbers():
+        _s, out = RestActions(dep.server.cluster).nodes_stats(None, {}, {})
+        node = out["nodes"]["node-0"]
+        return {"inline": node["thread_pool"]["search"]["fan_out"]["inline"],
+                "pooled": node["thread_pool"]["search"]["fan_out"]["pooled"],
+                "unplanned": node["pipeline"]["batching"]["unplanned_queries"],
+                **node["sparse"]}
+
+    body = dep.proved_body
+    n_terms = len(body["query"]["sparse_vector"]["query_vector"])
+    before = numbers()
+    dep.search("int8", body)
+    after = numbers()
+    assert after["inline"] == before["inline"] + 1
+    assert after["pooled"] == before["pooled"]
+    assert after["unplanned"] == before["unplanned"]
+    assert after["fallbacks"] == before["fallbacks"]
+    assert after["searches"] == before["searches"] + 1
+    assert after["quantized_searches"] == before["quantized_searches"] + 1
+    trace = dep.last_trace()
+    by_id = {s["id"]: s for s in trace["spans"]}
+    spans = {s["name"]: s for s in trace["spans"]}
+    shard = spans["shard_search"]
+    for name in ("plan", "queue_wait", "dispatch", "inflight", "collect",
+                 "wake", "fetch"):
+        assert spans[name]["parent_id"] == shard["id"], name
+    assert spans["plan"]["tags"] == {"family": "sparse", "planned": True}
+    assert spans["fan_out"]["tags"]["inline"] is True
+    tags = spans["dispatch"]["tags"]
+    assert tags["family"] == "sparse" and tags["quantized"] is True
+    assert tags["terms"] == n_terms
+    assert tags["tiles_scored"] == (after["tiles_scored"]
+                                    - before["tiles_scored"])
+    assert tags["tiles_pruned"] == (after["tiles_pruned"]
+                                    - before["tiles_pruned"])
+    assert tags["chunk_launches"] == (after["chunk_launches"]
+                                      - before["chunk_launches"]) >= 2
+    theta = spans["sparse_theta"]
+    assert by_id[theta["parent_id"]]["name"] == "dispatch"
+    assert theta["tags"]["launches"] == 1
+    assert (spans["dispatch"]["start_ns"] <= theta["start_ns"]
+            and theta["start_ns"] + theta["duration_ns"]
+            <= spans["dispatch"]["start_ns"]
+            + spans["dispatch"]["duration_ns"])
+    assert spans["collect"]["tags"]["merged"] is True
+
+
+def test_the_builders_plan_is_the_planners(dep):
+    """`sparse_from_plan` on the builder's array plan equals
+    `sparse_from_plan(sparse_plan(dict of dicts))` on the same small
+    postings: the builder's layout is the planner's."""
+    from elasticsearch_tpu.index.segment import sparse_from_plan, sparse_plan
+
+    config = dep.configs["int8"]
+    docs = 2_000
+    corpus = load_plugin("corpora", config["corpus"]["builder"]).build(
+        config, 3, docs)
+    built = corpus["segment"].sparse[dep.field]
+    data = corpus["reference"]
+    width = corpus["body_context"]["term_width"]
+    inv = {}
+    for i, t in enumerate(data["terms"]):
+        lo, hi = int(data["post_start"][i]), int(data["post_start"][i + 1])
+        inv[f"t{int(t):0{width}d}"] = dict(zip(
+            data["post_doc"][lo:hi].tolist(),
+            data["post_w"][lo:hi].tolist()))
+    planned = sparse_from_plan(sparse_plan(inv, 0.0), docs, built.exists)
+    assert planned.terms == built.terms and planned.pruned == built.pruned == 0
+    for name in ("term_df", "term_tile_start", "term_tile_count", "doc_ids",
+                 "weights", "qweights", "scales", "tile_max", "tile_qmax",
+                 "exists"):
+        a, b = getattr(planned, name), getattr(built, name)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_the_corpus_keeps_the_sources_shapes(dep):
+    """What the configuration's file states of the source, read off the
+    built data: the whole vocabulary's ids, ~127 non-zeros a passage,
+    ~49 a query, positive weights inside SPLADE's range, no static
+    pruning, one seed moving ids and weights but not the structure."""
+    config = dep.configs["int8"]
+    args, data = config["corpus"]["args"], dep.corpus["reference"]
+    assert len(data["terms"]) == args["vocab_in_use"] == 30_100
+    assert data["terms"].max() < args["vocab"] == 30_522
+    df = np.diff(data["post_start"])
+    assert (df >= 1).all()
+    assert abs(len(data["post_doc"]) / DOCS - 127) < 3
+    assert abs(df.max() / DOCS - args["df_law"]["max_share"]) < 0.02
+    w = data["post_w"]
+    assert w.dtype == np.float32 and 0 < w.min() and w.max() <= 3.5
+    sf = dep.corpus["segment"].sparse[dep.field]
+    assert sf.pruned == 0 and int(sf.term_df.sum()) == len(w)
+    sizes = [len(b["query"]["sparse_vector"]["query_vector"])
+             for b in dep.bodies]
+    assert 40 < np.mean(sizes) < 58
+    other = load_plugin("corpora", config["corpus"]["builder"]).build(
+        config, SEED + 1, DOCS)["reference"]
+    assert (np.diff(other["post_start"]) == df).all()
+    assert not (other["post_doc"][:1000] == data["post_doc"][:1000]).all()
+    assert not (other["post_w"][:1000] == w[:1000]).all()
