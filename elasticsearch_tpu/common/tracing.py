@@ -94,9 +94,10 @@ starts at `http`'s start and reaches the ring when `http` ends:
             tiles_pruned, chunk_launches (sums over its jobs and
             segments) and quantized]
             -> the group's last kernel is enqueued
-          > sparse_theta [segment, launches]  a sparse group's phase A
-            on one segment: its chunk launches and the blocking
-            download of theta (the dispatch's one host sync)
+          > sparse_theta [segment, launches, postings]  a sparse
+            group's thresholds on one segment, computed on the host
+            from its prunable jobs' first tiles (`postings` slots
+            gathered; launches 0: no device work, no host sync)
         inflight  -> the worker comes back to collect the group
         collect [d2h_bytes; a text or sparse group also merged: false
             when it downloaded the fused kernel's packed row as it was,

@@ -23,9 +23,11 @@ ops/scoring.merge_segment_topk unchanged.
 `SparseBlockMax` is the ops/wand.py analog for impact-ordered tiles.
 Because every term's postings are sorted by impact DESC, the per-tile
 `tile_max` sidecar is non-increasing within a term and the term's
-global maximum lives in its FIRST tile. Phase A scores exactly those
-first tiles → theta = kth best partial score; a tail tile of term t is
-dropped iff
+global maximum lives in its FIRST tile. Those first tiles alone give
+theta = kth best partial score, and the HOST reads it from the ≤ terms
+x 128 postings it already holds (`SparseBlockMax.host_theta`): no
+launch, no download, so the device runs one pass, over the surviving
+tiles. A tail tile of term t is dropped iff
 
     qw_t * tile_bound[tile] + sum_{t' != t} qw_t' * term_max_t' < theta
 
@@ -40,8 +42,7 @@ matches than `track_total_hits` counts to).
 
 Every host<->device transfer of the family is noted where it happens
 (`common/tracing.note_transfer`): the three staged planes a chunk
-launch uploads, the theta download; the packed collect notes itself in
-ops/scoring.
+launch uploads; the packed collect notes itself in ops/scoring.
 """
 
 from __future__ import annotations
@@ -54,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.tracing import note_transfer
-from .scoring import BPAD, TCHUNK, _finalize, _threshold, _to_host
+from .scoring import BPAD, TCHUNK, _finalize, _to_host
 
 TILE_WIDTH = 128
 
@@ -121,15 +122,13 @@ class ImpactScorer:
     tiled postings with fixed launch shapes (ChunkedScorer's serving
     recipe applied to the sparse column — see module comment)."""
 
-    def __init__(self, doc_ids, values, n_docs: int, live=None,
-                 block_size: int = 4096):
+    def __init__(self, doc_ids, values, n_docs: int, live=None):
         self.doc_ids = jnp.asarray(doc_ids)
         # stored dtype (int8 qweights or f32 weights) — cast happens
         # inside the kernel, post-gather
         self.values = jnp.asarray(values)
         self.n_docs = int(n_docs)
         self.live = jnp.asarray(live) if live is not None else None
-        self.block_size = block_size
 
     def new_acc(self, rows: int = BPAD):
         """Donated accumulators at one query-row bucket of the ladder."""
@@ -166,21 +165,6 @@ class ImpactScorer:
             )
         return acc, cnt
 
-    def threshold(self, acc, k: int, live=None):
-        """theta[B] after phase A — the kth best partial score per row
-        (a sound lower bound on the final kth best, so pruning against
-        it stays exact): ONE blocking download. The program is the
-        chunked text path's `_threshold`; the block maxima it also
-        computes stay on the device (the impact-ordered bounds are
-        per tile, on the host)."""
-        theta, _accmax = _threshold(
-            acc,
-            live if live is not None else self.live,
-            k=min(k, self.n_docs),
-            block_size=self.block_size,
-        )
-        return _to_host(theta)
-
     def finalize(self, acc, cnt, k: int, live=None):
         s, d, tot = self.finalize_device(acc, cnt, k, live=live)
         return _to_host(s), _to_host(d), _to_host(tot)
@@ -202,9 +186,9 @@ class ImpactScorer:
 
 
 class SparseBlockMax:
-    """Two-phase impact-ordered block-max pruning plan for ONE query row
-    over one SparseField (see module comment for the soundness
-    argument). All arrays are host numpy — the plan is layout work; the
+    """Impact-ordered block-max pruning plan for ONE query row over one
+    SparseField (see module comment for the soundness argument). All
+    arrays are host numpy — the plan, theta included, is host work; the
     scoring launches stay on device."""
 
     def __init__(
@@ -241,22 +225,66 @@ class SparseBlockMax:
         )
         self.sum_bound = float((self.bws * self.term_max).sum())
 
-    def phase_a(self) -> Tuple[np.ndarray, np.ndarray]:
-        """(tiles, weights): every query term's FIRST tile — the tiles
-        holding each term's maximum impacts, the cheapest set that
-        makes theta meaningful."""
-        return self.starts.copy(), self.tws.copy()
+    def host_theta(self, doc_ids, values, live, kb: int) -> float:
+        """theta: a lower bound on the `kb`-th best FINAL score as the
+        device will compute it, read from every query term's FIRST tile
+        (the tiles holding each term's maximum impacts, the cheapest
+        set that makes theta meaningful) of the host planes: `doc_ids`,
+        and `values` = the plane the kernel serves (int8 `qweights`
+        under the folded `tws`, or fp32 `weights`); `live` is the
+        segment's live mask or None. `-inf` where fewer than `kb` live
+        docs score above 0 there.
+
+        Per valid live slot the product p = tw * f32(value) is formed in
+        float32, as the kernel forms it (`impact_tile_contrib`); the
+        products are summed by doc id in float64 and the `kb`-th
+        largest positive sum H is taken. Why the returned value is
+        sound, for non-negative weights (the caller's rule): a doc's
+        final device score is a float32 sum, in some order, of its
+        products over the kept tiles, at most T = len(terms) addends (a
+        doc is at most once in a term's postings), and first tiles are
+        always kept. Any float32 summation of T non-negative terms is
+        >= (1 - (T-1) * 2^-24 / (1 - (T-1) * 2^-24)) times their true
+        sum, which is >= the true first-tile sum, which the float64
+        accumulation misses by at most T * 2^-53 relative. So
+        H * (1 - T * 2^-23) (twice what the sums need: the other half
+        covers a device product one ulp below the host's), less T *
+        float32's smallest normal (the chip flushes a subnormal product
+        to 0), rounded DOWN to float32, is <= the device's final score
+        of each of those `kb` docs, hence <= its `kb`-th best. `kept`
+        compares float32 bounds with it, so the value handed back is a
+        float32 exactly."""
+        n_terms = len(self.starts)
+        d = doc_ids[self.starts]  # [T, 128]
+        p = self.tws[:, None] * values[self.starts].astype(np.float32)
+        ok = d >= 0
+        d, p = d[ok], p[ok]
+        if live is not None:
+            ok = live[d]
+            d, p = d[ok], p[ok]
+        _docs, row = np.unique(d, return_inverse=True)
+        sums = np.bincount(row, weights=p.astype(np.float64))
+        sums = sums[sums > 0]
+        if len(sums) < kb:
+            return -np.inf
+        kth = np.partition(sums, len(sums) - kb)[len(sums) - kb]
+        low = kth * (1.0 - n_terms * 2.0**-23) - n_terms * float(
+            np.finfo(np.float32).tiny
+        )
+        theta = np.float32(low)
+        if theta > low:
+            theta = np.nextafter(theta, np.float32(-np.inf))
+        return float(theta)
 
     def kept(
         self, theta: float
     ) -> Tuple[np.ndarray, np.ndarray, int]:
         """(tiles, weights, dropped): the FULL surviving tile list —
         first tiles always, tail tiles filtered against `theta` — laid
-        out per term in term order. Callers score this list into a
-        FRESH accumulator (phase A tiles are rescored; one tile per
-        term, cheap) so per-doc-cell accumulation runs in pure
-        query-term order: the fp32 serving path stays bit-identical to
-        the numpy oracle whether or not pruning dropped anything."""
+        out per term in term order, so the one device pass accumulates
+        each doc cell in pure query-term order: the fp32 serving path
+        stays bit-identical to the numpy oracle whether or not pruning
+        dropped anything."""
         tiles: List[np.ndarray] = []
         weights: List[np.ndarray] = []
         dropped = 0
@@ -288,8 +316,8 @@ class SparseBlockMax:
 
     @property
     def n_tail_tiles(self) -> int:
-        """Tiles beyond each term's first — zero means phase A already
-        scored everything and the threshold pass can be skipped."""
+        """Tiles beyond each term's first — zero means there is nothing
+        a threshold could drop, and none is computed."""
         return int(np.maximum(self.counts - 1, 0).sum())
 
 

@@ -625,8 +625,8 @@ class _Group:
         # `chunk_launches` (sums over its jobs and segments), `quantized`
         self.plan_tags: Dict[str, int] = {}
         # (name, start_ns, end_ns, tags): spans inside `dispatch`, its
-        # children (`sparse_theta`: phase A's launches and the blocking
-        # threshold download)
+        # children (`sparse_theta`: the host's threshold from the query
+        # terms' first tiles)
         self.sub_spans: List[Tuple] = []
         # a text or sparse group's `collect` span: whether its candidates
         # went through the merge program (`_group_topk`), else None
@@ -765,10 +765,10 @@ class _Family:
     share: Callable[[object], Tuple]
     # (batcher, jobs, key, kb, rows, record) -> pend: the group's device
     # work, enqueued without a host sync (`record=False`: a warm-up
-    # launch, which appears in no counter and fires no fault site). Two
-    # dispatches block all the same, on one threshold download each: a
-    # match group that leaves the fused kernel for the chunked block-max
-    # path (`cs.threshold`), and a sparse group's phase A
+    # launch, which appears in no counter and fires no fault site). One
+    # dispatch blocks all the same, on a threshold download: a match
+    # group that leaves the fused kernel for the chunked block-max path
+    # (`cs.threshold`)
     dispatch: Callable
     # (batcher, jobs, key, kb, pend, record): the blocking downloads and
     # the waiters' wake-up
@@ -2224,12 +2224,13 @@ class QueryBatcher:
                                record: bool = True) -> List[Tuple]:
         """Launches the impact-tile kernels (ops/impact.py) for a group
         of same-(field, spec) sparse_vector jobs on every segment
-        carrying the column. Two-phase per segment: phase A scores each
-        query term's FIRST tile (where impact ordering puts the term
-        maxima), one theta download (the `sparse_theta` span, a child
-        of `dispatch`), then the surviving block-max tile list scores
-        into a fresh accumulator whose finalize triple stays ON DEVICE
-        until collect.
+        carrying the column. Per segment: the host reads each prunable
+        job's theta from its query terms' FIRST tiles (where impact
+        ordering puts the term maxima) of the planes it holds
+        (`SparseBlockMax.host_theta`; the `sparse_theta` span, a child
+        of `dispatch`), then the surviving block-max tile lists score
+        in ONE device pass whose finalize triple stays ON DEVICE until
+        collect: the dispatch never waits for the device.
 
         `hits.total` follows Elasticsearch's rule whatever was dropped
         (exact up to `track_total_hits`, then a `gte` bound): matches
@@ -2293,43 +2294,36 @@ class QueryBatcher:
             # unsound against quantized scores
             bound = sfh.tile_qmax if spec.quantized else sfh.tile_max
             bms = []
-            prunable = []
+            theta_jobs = []  # jobs a threshold can drop tiles of
             for ji, j in enumerate(jobs):
                 tids, tws, bws, _, _ = impact_ops.impact_tile_lists(
                     sfh, j.plan.terms, j.plan.weights, spec.quantized
                 )
-                bms.append(
-                    impact_ops.SparseBlockMax(
-                        sfh.term_tile_start, sfh.term_tile_count,
-                        bound, tids, tws, bws,
-                    )
+                bm = impact_ops.SparseBlockMax(
+                    sfh.term_tile_start, sfh.term_tile_count,
+                    bound, tids, tws, bws,
                 )
+                bms.append(bm)
                 # block-max upper bounds assume non-negative tile
                 # weights; a negative query weight keeps the job exact
                 # but unpruned
-                prunable.append(may_drop[ji] and bool((tws >= 0).all()))
+                if may_drop[ji] and (tws >= 0).all() and bm.n_tail_tiles:
+                    theta_jobs.append(ji)
             thetas = np.full(len(jobs), -np.inf, np.float32)
-            launches = 0
-            theta_syncs = 0
-            if any(
-                p and bm.n_tail_tiles for p, bm in zip(prunable, bms)
-            ):
+            if theta_jobs:
                 t_theta = time.perf_counter_ns()
-                a_tiles, a_weights = zip(*(bm.phase_a() for bm in bms))
-                acc, cnt = sc.new_acc(rows)
-                acc, cnt = sc.score_into(acc, cnt, a_tiles, a_weights)
-                th = sc.threshold(acc, kb)
-                a_launches = impact_ops.chunk_launches(a_tiles)
-                launches += a_launches
-                theta_syncs = 1
+                values = sfh.qweights if spec.quantized else sfh.weights
+                for ji in theta_jobs:
+                    thetas[ji] = bms[ji].host_theta(
+                        sfh.doc_ids, values, reader.live_docs[si], kb
+                    )
                 if record:
                     _group_now().sub_spans.append((
                         "sparse_theta", t_theta, time.perf_counter_ns(),
-                        {"segment": si, "launches": a_launches},
+                        {"segment": si, "launches": 0,
+                         "postings": impact_ops.TILE_WIDTH * sum(
+                             len(bms[ji].starts) for ji in theta_jobs)},
                     ))
-                for ji in range(len(jobs)):
-                    if prunable[ji]:
-                        thetas[ji] = th[ji]
             tile_lists: List[np.ndarray] = []
             weight_lists: List[np.ndarray] = []
             pruned_flags = np.zeros(len(jobs), bool)
@@ -2344,12 +2338,12 @@ class QueryBatcher:
                 tiles_pruned += dropped
             acc, cnt = sc.new_acc(rows)
             acc, cnt = sc.score_into(acc, cnt, tile_lists, weight_lists)
-            launches += impact_ops.chunk_launches(tile_lists)
+            launches = impact_ops.chunk_launches(tile_lists)
             pend = sc.finalize_device(acc, cnt, kb)
             if record:
                 sparse_mod.note_search(
                     nj, spec.quantized, tiles_scored, tiles_pruned,
-                    chunk_launches=launches, theta_syncs=theta_syncs,
+                    chunk_launches=launches, theta_host=len(theta_jobs),
                 )
                 with self._lock:
                     self.stats["launches"] += 1

@@ -62,8 +62,8 @@ SPARSE_STATS = {
     "tiles_scored": 0,  # Σ tiles actually launched
     "tiles_pruned": 0,  # Σ tail tiles dropped by block-max bounds
     "pruned_searches": 0,  # scorings where at least one tile dropped
-    "chunk_launches": 0,  # Σ `_impact_chunk_add` launches, both phases
-    "theta_syncs": 0,  # phase A threshold downloads (one a scoring)
+    "chunk_launches": 0,  # Σ `_impact_chunk_add` launches
+    "theta_host": 0,  # thetas the host computed (a prunable job × segment)
     # bytes of the impact VALUE planes actually uploaded vs what the
     # same planes would cost at fp32 — the headline int8 compression
     # ratio (4x per plane; ≥2x smaller gated in tier-1). The doc-id
@@ -81,12 +81,14 @@ def note(key: str, n: int = 1) -> None:
 
 def note_search(
     jobs: int, quantized: bool, tiles_scored: int, tiles_pruned: int,
-    chunk_launches: int = 0, theta_syncs: int = 0,
+    chunk_launches: int = 0, theta_host: int = 0,
 ) -> None:
     """One impact-tile scoring of `jobs` queries against one segment:
-    `tiles_scored` are the final pass's (phase A rescored among them),
-    `chunk_launches` both phases' kernel launches, `theta_syncs` the
-    blocking threshold downloads between them (0 or 1)."""
+    `tiles_scored` and `chunk_launches` are its one device pass's,
+    `theta_host` the jobs among them whose threshold the host computed
+    beforehand from the query terms' first tiles (how often the
+    block-max mechanism engages; `tiles_pruned` says how often it
+    pays)."""
     with _STATS_LOCK:
         SPARSE_STATS["searches"] += jobs
         if quantized:
@@ -96,7 +98,7 @@ def note_search(
         if tiles_pruned:
             SPARSE_STATS["pruned_searches"] += jobs
         SPARSE_STATS["chunk_launches"] += chunk_launches
-        SPARSE_STATS["theta_syncs"] += theta_syncs
+        SPARSE_STATS["theta_host"] += theta_host
 
 
 def stats_snapshot() -> dict:

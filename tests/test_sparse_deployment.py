@@ -185,7 +185,7 @@ def test_total_below_the_threshold_is_exact(dep, storage):
     # nothing proves a total past the cap: no tile may be dropped
     after = dep.sparse_stats()
     assert after["tiles_pruned"] == before["tiles_pruned"]
-    assert after["theta_syncs"] == before["theta_syncs"]
+    assert after["theta_host"] == before["theta_host"]
 
 
 @pytest.mark.parametrize("storage", STORAGES)
@@ -200,7 +200,7 @@ def test_pruned_job_answers_elasticsearchs_total(dep, storage):
     after = dep.sparse_stats()
     assert after["tiles_pruned"] > before["tiles_pruned"]
     assert after["pruned_searches"] == before["pruned_searches"] + 1
-    assert after["theta_syncs"] == before["theta_syncs"] + 1
+    assert after["theta_host"] == before["theta_host"] + 1
     kept = 128 * (after["tiles_scored"] - before["tiles_scored"])
     assert kept < 10_000  # a count over the kept tiles would fall short
     assert served["hits"]["total"] == {"value": 10_000, "relation": "gte"}
@@ -312,10 +312,102 @@ def test_stored_precision_differs_between_the_storages(dep):
     assert cross["numbers"]["score_rel_max"][0] > g["score_rtol"]
 
 
+KB = 16  # the top-k bucket of a ten-hit page
+
+
+def theta_case(dep, storage, vector, live):
+    """(host theta, what the old device program `_threshold` returned
+    for the same first tiles, the `KB`-th best score of the device's
+    pass over EVERY tile, the plan) of one query under one live mask."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops import impact as impact_ops
+    from elasticsearch_tpu.ops.scoring import _threshold
+
+    sf = dep.corpus["segment"].sparse[dep.field]
+    quantized = storage == "int8"
+    values = sf.qweights if quantized else sf.weights
+    tids, tws, bws, starts, counts = impact_ops.impact_tile_lists(
+        sf, list(vector), list(vector.values()), quantized)
+    bm = impact_ops.SparseBlockMax(
+        sf.term_tile_start, sf.term_tile_count,
+        sf.tile_qmax if quantized else sf.tile_max, tids, tws, bws)
+    host = bm.host_theta(sf.doc_ids, values, live, KB)
+    sc = impact_ops.ImpactScorer(sf.doc_ids, values, DOCS, live)
+    acc, _cnt = sc.score_into(*sc.new_acc(1), [bm.starts], [bm.tws])
+    twin = float(_threshold(
+        acc, None if live is None else jnp.asarray(live), k=KB,
+        block_size=4096)[0][0])
+    tiles = np.concatenate([np.arange(s, s + c) for s, c in
+                            zip(starts, counts)])
+    scores, _docs, _tot = sc.finalize(
+        *sc.score_into(*sc.new_acc(1), [tiles], [np.repeat(tws, counts)]),
+        KB)
+    return host, twin, float(scores[0][KB - 1]), bm
+
+
+def first_tile_sums(dep, storage, bm):
+    """doc -> float64 sum of the first tiles' products, all docs live."""
+    sf = dep.corpus["segment"].sparse[dep.field]
+    values = sf.qweights if storage == "int8" else sf.weights
+    sums = np.zeros(DOCS)
+    d = sf.doc_ids[bm.starts]
+    p = bm.tws[:, None] * values[bm.starts].astype(np.float32)
+    np.add.at(sums, d[d >= 0], p[d >= 0].astype(np.float64))
+    return sums
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+@pytest.mark.parametrize("n_tokens", [2, 3, 5, 8, 16, 33, 49, 80, 128])
+def test_host_theta_is_a_sound_bound_within_its_margin(dep, storage,
+                                                       n_tokens):
+    """Seeded queries drawn by posting mass, all docs live and with the
+    first tiles' best docs deleted: the host's theta never passes the
+    device's final `KB`-th best score (nothing of the page can drop),
+    never passes the old device threshold of the same first tiles, and
+    stays within its stated margin of it; deleted docs lower it."""
+    rng = np.random.default_rng([38, n_tokens])
+    tokens = np.asarray(dep.frequent)
+    mass = np.asarray([dep.df_of[t] for t in tokens], np.float64)
+    for _ in range(3):
+        picked = rng.choice(tokens, n_tokens, replace=False,
+                            p=mass / mass.sum())
+        vector = {str(t): float(w) for t, w in
+                  zip(picked, rng.uniform(0.02, 3.0, n_tokens))}
+        host, twin, final, bm = theta_case(dep, storage, vector, None)
+        assert np.isfinite(twin) and host <= twin <= final
+        margin = (n_tokens + 2) * 2.0 ** -22
+        assert host >= twin * (1.0 - margin)
+        best = np.argsort(-first_tile_sums(dep, storage, bm))[:2 * KB]
+        live = np.ones(DOCS, bool)
+        live[best] = False
+        host_d, twin_d, final_d, _ = theta_case(dep, storage, vector, live)
+        assert host_d <= twin_d <= final_d and host_d < host
+        assert host_d == -np.inf or host_d >= twin_d * (1.0 - margin)
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_host_theta_needs_a_full_page_of_live_matches(dep, storage):
+    """Fewer than `KB` live docs in the first tiles: `-inf`, as the
+    masked top-k of the old program read; exactly `KB`: finite."""
+    token = next(t for t in dep.frequent if 2 * KB <= dep.df_of[t] <= 128)
+    vector = {token: 1.5}
+    _host, _twin, _final, bm = theta_case(dep, storage, vector, None)
+    holders = np.flatnonzero(first_tile_sums(dep, storage, bm) > 0)
+    assert len(holders) == dep.df_of[token]
+    for left, finite in ((KB - 1, False), (KB, True)):
+        live = np.ones(DOCS, bool)
+        live[holders[left:]] = False
+        host, twin, final, _ = theta_case(dep, storage, vector, live)
+        assert np.isfinite(host) == np.isfinite(twin) == finite
+        assert host <= twin <= final or not finite
+
+
 @pytest.mark.parametrize("which", ["full_shape", "long", "pruning", "rare"])
 def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
     """`transfer.scoring.*` moves by exactly what the job moved: three
-    staged planes a chunk launch, theta, the packed collect."""
+    staged planes a chunk launch of the one device pass, and the packed
+    collect, the job's one download (theta is the host's)."""
     from elasticsearch_tpu.ops.scoring import TCHUNK
 
     body = {"full_shape": dep.proved_body, "long": dep.long_body,
@@ -327,21 +419,23 @@ def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
     s1, x1 = dep.sparse_stats(), tracing.transfer_stats()
     spans = {s["name"]: s for s in dep.last_trace()["spans"]}
     rows = spans["dispatch"]["tags"]["rows"]
+    tiles = s1["tiles_scored"] - s0["tiles_scored"]
     launches = s1["chunk_launches"] - s0["chunk_launches"]
-    syncs = s1["theta_syncs"] - s0["theta_syncs"]
-    assert launches >= 1 and syncs == (which != "rare")
+    assert launches == -(-tiles // TCHUNK) >= 1
+    assert s1["theta_host"] - s0["theta_host"] == (which != "rare")
     assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches
     assert (x1["h2d_bytes"] - x0["h2d_bytes"]
             == launches * rows * TCHUNK * (4 + 4 + 1))
-    assert x1["d2h_count"] - x0["d2h_count"] == syncs + 1
+    assert x1["d2h_count"] - x0["d2h_count"] == 1
     assert (x1["d2h_bytes"] - x0["d2h_bytes"]
-            == syncs * 4 * rows + spans["collect"]["tags"]["d2h_bytes"])
+            == spans["collect"]["tags"]["d2h_bytes"])
 
 
 def test_the_request_goes_the_normal_path(dep):
     """A planned sparse job on the request thread's inline fan-out: the
     seven job spans under `shard_search`, `sparse_theta` under
-    `dispatch`, the group's tags, nothing unplanned, no fallback."""
+    `dispatch` with no launch of its own, the group's tags, nothing
+    unplanned, no fallback."""
     from elasticsearch_tpu.rest.actions import RestActions
 
     def numbers():
@@ -380,10 +474,12 @@ def test_the_request_goes_the_normal_path(dep):
     assert tags["tiles_pruned"] == (after["tiles_pruned"]
                                     - before["tiles_pruned"])
     assert tags["chunk_launches"] == (after["chunk_launches"]
-                                      - before["chunk_launches"]) >= 2
+                                      - before["chunk_launches"]) >= 1
+    assert after["theta_host"] == before["theta_host"] + 1
     theta = spans["sparse_theta"]
     assert by_id[theta["parent_id"]]["name"] == "dispatch"
-    assert theta["tags"]["launches"] == 1
+    assert theta["tags"]["launches"] == 0
+    assert theta["tags"]["postings"] == 128 * n_terms
     assert (spans["dispatch"]["start_ns"] <= theta["start_ns"]
             and theta["start_ns"] + theta["duration_ns"]
             <= spans["dispatch"]["start_ns"]
