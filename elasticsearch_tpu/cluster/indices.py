@@ -1345,6 +1345,19 @@ class IndexService:
             old[1].close()
         return ex
 
+    def _check_byte_query_vectors(self, knn) -> None:
+        """A knn section over an `element_type: byte` field takes what
+        the field stores: whole numbers in [-128, 127] (Elasticsearch's
+        400, in the mapper's words)."""
+        from ..index.mapping import byte_vector_error
+
+        for sec in knn:
+            mf = self.mappings.get(sec.field)
+            if mf is not None and getattr(mf, "element_type", "float") == "byte":
+                why = byte_vector_error(sec.query_vector)
+                if why is not None:
+                    raise dsl.QueryParseError(f"[knn] field [{sec.field}]: {why}")
+
     def _wait_batched(self, job, sid: int, shard_deadline, task):
         """Collects a batcher future under the shard's timeout budget
         and the request task's cancellation. An expired budget CANCELS
@@ -1525,6 +1538,7 @@ class IndexService:
                 dsl.parse_knn(kb)
                 for kb in (knn_body if isinstance(knn_body, list) else [knn_body])
             ]
+            self._check_byte_query_vectors(knn)
             if str(self.settings.get("search.backend")) == "jax":
                 # IVF ANN routing (index.knn.type, ?exact=true escape
                 # hatch, per-section nprobe): the numpy oracle backend
@@ -1621,11 +1635,13 @@ class IndexService:
                 if plan is None:
                     if planned_only:
                         raise _NeedsPool()
-                    if kind == "serve":
-                        # a query neither planner took: the unbatched
-                        # executor below; the mesh twin and a retriever's
-                        # leg that found no plan come through here too,
-                        # so this is the one place that counts them
+                    if kind in ("serve", "knn"):
+                        # a query neither planner took, or a knn section
+                        # (a filter past `extract_knn_filter`, a
+                        # similarity cut-off, several sections): the
+                        # unbatched executor below; the mesh twin and a
+                        # retriever's leg that found no plan come through
+                        # here too, so this is the one place that counts
                         self._batcher.note_unplanned()
                     if tr is not None:
                         tr.add_span(
@@ -2612,11 +2628,16 @@ class IndexService:
                 dsl.parse_knn(kb)
                 for kb in (knn_body if isinstance(knn_body, list) else [knn_body])
             ]
+            self._check_byte_query_vectors(knn)
             from ..search import ann as ann_mod
 
             ann_mod.annotate(knn, self.settings, body)
             plan = extract_knn_plan(knn, self.mappings)
             kind = "mesh_knn"
+            if plan is not None and plan.filter is not None:
+                # the mesh step has one candidate mask an entry: a
+                # filtered section takes the shard path's knn family
+                return None
         if plan is None:
             return None
         if "rescore" in body:
@@ -3977,6 +3998,7 @@ class IndexService:
         if kind == "knn":
             try:
                 sec = dsl.parse_knn(params)
+                self._check_byte_query_vectors([sec])
             except (dsl.QueryParseError, KeyError, TypeError, ValueError):
                 return None  # malformed → sync path raises the real error
             from ..search import ann as ann_mod

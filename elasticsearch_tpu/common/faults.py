@@ -38,6 +38,10 @@ Schedule shape (env `ES_TPU_FAULTS`, or `POST /_internal/faults`):
     the deterministic rerank→first-stage-order fallback (the request
     keeps its first-stage ranking bit-for-bit and the `fallbacks`
     counter increments), delay kind the slow-not-wrong contract)
+  - ``knn.filter``          (a filtered kNN group's mask launch, per
+    segment — ctx carries field/segment; error kind proves the
+    deterministic fallback to the unbatched executor's filter
+    evaluation (exact answers, `knn_filtered.fallbacks` bump))
   - ``sparse.score``        (learned-sparse impact-tile scoring — per
     segment on the batcher path with ctx field/segment, mesh=1 on the
     SPMD path; error kind proves the deterministic impact→dense-host-

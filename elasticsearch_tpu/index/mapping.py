@@ -41,6 +41,31 @@ PERCOLATOR = "percolator"
 
 NUMERIC_TYPES = (LONG, INTEGER, SHORT, BYTE, DOUBLE, FLOAT, HALF_FLOAT)
 _INT_TYPES = (LONG, INTEGER, SHORT, BYTE)
+VECTOR_ELEMENT_TYPES = ("float", "byte")
+
+
+def byte_vector_error(values) -> Optional[str]:
+    """Why `values` is no vector of an `element_type: byte` field, in
+    DenseVectorFieldMapper's words, or None: every element a whole
+    number in [-128, 127]. The same check holds a stored vector and a
+    query vector."""
+    for dim, x in enumerate(values):
+        if isinstance(x, bool) or not isinstance(x, (int, float)):
+            return (
+                "element_type [byte] vectors only support numbers but "
+                f"found [{x!r}] at dim [{dim}]"
+            )
+        if not math.isfinite(x) or x != int(x):
+            return (
+                "element_type [byte] vectors only support non-decimal "
+                f"values but found decimal value [{x}] at dim [{dim}]"
+            )
+        if not -128 <= x <= 127:
+            return (
+                "element_type [byte] vectors only support integers between "
+                f"[-128, 127] but found [{int(x)}] at dim [{dim}]"
+            )
+    return None
 
 
 @dataclass
@@ -55,6 +80,9 @@ class MappedField:
     # dense_vector options
     dims: int = 0
     similarity: str = "cosine"  # cosine | dot_product | l2_norm
+    # float (float32 rows) | byte (int8 rows: one byte an element on the
+    # host and on the device, integers -128..127 at index and query time)
+    element_type: str = "float"
     # date format (subset: epoch_millis and ISO handled)
     format: Optional[str] = None
     # keyword ignore_above
@@ -139,6 +167,7 @@ class Mappings:
             boost=float(cfg.get("boost", 1.0)),
             dims=int(cfg.get("dims", 0)),
             similarity=cfg.get("similarity", "cosine"),
+            element_type=cfg.get("element_type", "float"),
             format=cfg.get("format"),
             ignore_above=cfg.get("ignore_above"),
             copy_to=tuple(
@@ -152,6 +181,11 @@ class Mappings:
             raise MappingParseError(
                 f"pruning_ratio on field [{path}] must be in [0, 1), "
                 f"got [{f.pruning_ratio}]"
+            )
+        if ftype == DENSE_VECTOR and f.element_type not in VECTOR_ELEMENT_TYPES:
+            raise MappingParseError(
+                f"invalid element_type [{f.element_type}] on field [{path}]; "
+                f"available types are {list(VECTOR_ELEMENT_TYPES)}"
             )
         if ftype == DENSE_VECTOR and f.dims <= 0:
             # ES infers dims from the first vector if unset; we allow that too
@@ -248,7 +282,8 @@ class Mappings:
                         f"mapper [{name}] cannot be changed from type "
                         f"[{mine.type}] to [{f.type}]"
                     )
-                for param in ("analyzer", "dims", "similarity", "pruning_ratio"):
+                for param in ("analyzer", "dims", "similarity",
+                              "element_type", "pruning_ratio"):
                     theirs = getattr(f, param)
                     if param == "dims" and not theirs:
                         # dims omitted in the incoming mapping: keep the
@@ -307,6 +342,8 @@ class Mappings:
         if f.type in (DENSE_VECTOR, RANK_VECTORS):
             entry["dims"] = f.dims
             entry["similarity"] = f.similarity
+            if f.element_type != "float":
+                entry["element_type"] = f.element_type
         if f.type == SPARSE_VECTOR and f.pruning_ratio:
             entry["pruning_ratio"] = f.pruning_ratio
         if f.ignore_above is not None:
@@ -622,6 +659,10 @@ class DocumentParser:
             if weights:
                 out.sparse_vectors[path] = weights
         elif f.type == DENSE_VECTOR:
+            if f.element_type == "byte":
+                why = byte_vector_error(values)
+                if why is not None:
+                    raise MappingParseError(f"[{path}]: {why}")
             vec = [float(x) for x in values]
             if f.dims and len(vec) != f.dims:
                 raise MappingParseError(

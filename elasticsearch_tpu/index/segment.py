@@ -153,9 +153,21 @@ class OrdinalField:
     mv_offsets: np.ndarray  # int32[N+1]
 
 
+def stored_rows(mat: np.ndarray, mf) -> np.ndarray:
+    """A dense_vector column as its mapping's `element_type` stores it:
+    float32 rows as they are, `byte` rows as int8 (the mapper admitted
+    whole numbers in [-128, 127] only, so the cast loses nothing)."""
+    if mf is not None and getattr(mf, "element_type", "float") == "byte":
+        return mat.astype(np.int8)
+    return mat
+
+
 @dataclass
 class VectorField:
-    vectors: np.ndarray  # float32[N, dims]; zero rows where missing
+    # float32[N, dims], or int8[N, dims] of an `element_type: byte`
+    # field (host and device hold one byte an element; the kernels cast);
+    # zero rows where missing
+    vectors: np.ndarray
     exists: np.ndarray  # bool[N]
     similarity: str
     unit_vectors: Optional[np.ndarray] = None  # normalized copy for cosine
@@ -827,7 +839,9 @@ class SegmentBuilder:
                     mat[local_id] = np.asarray(v, dtype=np.float32)
                     exists[local_id] = True
             sim = mf.similarity if mf else "cosine"
-            vf = VectorField(vectors=mat, exists=exists, similarity=sim)
+            vf = VectorField(
+                vectors=stored_rows(mat, mf), exists=exists, similarity=sim
+            )
             if sim == "cosine":
                 vf.unit_vectors = _unit_normalize(mat)
             vectors[fname] = vf

@@ -53,6 +53,7 @@ from .segment import (
     TILE,
     _unit_normalize,
     sparse_plan,
+    stored_rows,
 )
 
 # ---------------------------------------------------------------------------
@@ -298,7 +299,9 @@ def _device_build(builder: SegmentBuilder) -> Segment:
         else:
             mat = np.zeros((n, dims), np.float32)
             exists = np.zeros(n, bool)
-        vf = VectorField(vectors=mat, exists=exists, similarity=sim)
+        vf = VectorField(
+            vectors=stored_rows(mat, mf), exists=exists, similarity=sim
+        )
         if sim == "cosine":
             # float reduction: shared host routine in BOTH paths (like
             # tokenization — normalization is part of doc prep)

@@ -1232,7 +1232,7 @@ def merge_segment_topk(items, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _knn_merge_segments(s_list, d_list, seg_of_slot, nc_cat, k):
+def _knn_merge_segments(s_list, d_list, seg_of_slot, nc_cat, k, passed=None):
     scores = jnp.concatenate(s_list, axis=1)  # [B, total_slots]
     docs = jnp.concatenate(d_list, axis=1)
     # per-(job, segment) num_candidates rank cut, applied on device: a
@@ -1244,25 +1244,29 @@ def _knn_merge_segments(s_list, d_list, seg_of_slot, nc_cat, k):
     seg = seg_of_slot[idx]
     doc = jnp.take_along_axis(docs, idx, axis=1)
     counts = valid.sum(axis=1, dtype=jnp.int32)
-    return jnp.concatenate(
-        [
-            jax.lax.bitcast_convert_type(s, jnp.int32),
-            seg,
-            doc,
-            counts[:, None],
-        ],
-        axis=1,
-    )
+    cols = [
+        jax.lax.bitcast_convert_type(s, jnp.int32),
+        seg,
+        doc,
+        counts[:, None],
+    ]
+    if passed is not None:
+        # a filtered group: the rows each job's filter passed, summed
+        # over the segments, ride the same packed download
+        cols.append(sum(passed)[:, None])
+    return jnp.concatenate(cols, axis=1)
 
 
-def knn_merge_segment_topk(items, nc_rows: np.ndarray, k: int):
+def knn_merge_segment_topk(items, nc_rows: np.ndarray, k: int, passed=None):
     """kNN variant of merge_segment_topk. items: [(si, scores f32[B,ki],
     docs i32[B,ki])] device pairs (segment asc); nc_rows: host int32
     [B, n_segments] per-(job, segment) num_candidates cut (the
     coordinator's per-segment candidate budget). Returns (scores,
     segments, docs, counts i64[B]) — counts is the number of surviving
     candidates across segments (before the final k cut), in ONE
-    device→host transfer."""
+    device→host transfer. `passed` (a filtered group: one device
+    i32[B] a segment, the rows each job's filter passed there) adds a
+    fifth result, their sums i64[B], to the same transfer."""
     widths = [int(s.shape[1]) for _, s, _ in items]
     k = min(k, sum(widths))
     seg_of_slot = _to_device(
@@ -1283,12 +1287,15 @@ def knn_merge_segment_topk(items, nc_rows: np.ndarray, k: int):
             seg_of_slot,
             nc_cat,
             k=k,
+            passed=None if passed is None else tuple(passed),
         )
     )
     scores = out[:, :k].copy().view(np.float32)
     segs = out[:, k : 2 * k]
     docs = out[:, 2 * k : 3 * k]
     counts = out[:, 3 * k].astype(np.int64)
+    if passed is not None:
+        return scores, segs, docs, counts, out[:, 3 * k + 1].astype(np.int64)
     return scores, segs, docs, counts
 
 
@@ -1310,7 +1317,14 @@ def knn_scores(
     similarity: str,
 ) -> jax.Array:
     """Dense [B, N] similarity scores: one MXU matmul + the Lucene
-    VectorSimilarityFunction transform (see models/similarity.py)."""
+    VectorSimilarityFunction transform (see models/similarity.py).
+    Rows of an `element_type: byte` field arrive as int8 and are cast
+    here, inside the program: the device holds one byte an element.
+    Whole numbers of 8 bits are exact in bfloat16 and every product and
+    partial sum of them in float32 (192 x 128^2 < 2^24), so an MXU pass
+    at the default precision gives their exact dot products."""
+    if jnp.issubdtype(vectors.dtype, jnp.integer):
+        vectors = vectors.astype(jnp.float32)
     if similarity == "l2_norm":
         # ||q - v||² = |q|² + |v|² - 2 q·v — matmul-friendly
         dots = queries @ vectors.T
@@ -1352,6 +1366,133 @@ def knn_topk_batch(
     s, d = jax.lax.top_k(masked, k)
     totals = mask.sum(axis=1, dtype=jnp.int32)
     return s, d, totals
+
+
+# Postings tiles a trip of `knn_filter_mask` gathers and scatters, a
+# query row. Measured on the TPU v5e at the filtered cell's shapes (10M
+# rows, one-row launch, device ms by the tag's tiles 1 / 76 / 728 /
+# 5,613 / 22,130; PERF.md section 6, PR 39): chunks of 16 0.96 / 1.06 /
+# 2.07 / 10.11 / 37.06, of 64 0.98 / 1.04 / 2.09 / 10.10 / 37.03, of 256
+# 0.97 / 1.04 / 2.11 / 10.07 / 37.08: a launch costs ~0.96 ms (the 40 MB
+# plane zeroed, counted and masked) and 13 ns a posting slot scattered,
+# whatever the chunk.
+FILTER_CHUNK = 64
+# Term slots of a filter plan row: the compile buckets of the plan's
+# width. A filter of more terms than the last is not planned.
+FILTER_SLOT_BUCKETS = (8, 64)
+
+
+def filter_slot_bucket(n_terms: int) -> Optional[int]:
+    """The plan width `n_terms` filter terms ride, or None: too many."""
+    return next((b for b in FILTER_SLOT_BUCKETS if n_terms <= b), None)
+
+
+def pack_filter_plans(pf, filters, rows: int) -> Tuple[np.ndarray, int]:
+    """(`knn_filter_mask`'s plan int32[rows, 3 * S + 1], tiles it names)
+    for one launch over one segment: `pf` the filter field's
+    PostingsField there, `filters` each job's clauses (tuples of terms;
+    batcher.KnnFilter.clauses), S the slot bucket of the widest. A
+    clause of one term feeds the count plane's term counter, the d-th
+    clause of several terms its digit d (the planner admits at most
+    CLAUSE_DIGITS of them, of CLAUSE_TERMS_MAX terms each); a term the
+    segment does not hold keeps an empty range."""
+    S = filter_slot_bucket(max(sum(map(len, f)) for f in filters))
+    plan = np.zeros((rows, 3 * S + 1), np.int32)
+    tiles = 0
+    for ji, clauses in enumerate(filters):
+        slot = digit = 0
+        for clause in clauses:
+            unit = 1
+            if len(clause) > 1:
+                unit = 1 << (COUNT_TERM_BITS + CLAUSE_DIGIT_BITS * digit)
+                digit += 1
+            for term in clause:
+                tid = pf.term_id(term)
+                if tid >= 0:
+                    plan[ji, slot] = pf.term_tile_start[tid]
+                    plan[ji, S + slot] = pf.term_tile_count[tid]
+                    tiles += int(pf.term_tile_count[tid])
+                plan[ji, 2 * S + slot] = unit
+                slot += 1
+        plan[ji, 3 * S] = len(clauses)
+    return plan, tiles
+
+
+@jax.jit
+def knn_filter_mask(
+    doc_ids: jax.Array,  # int32[n_tiles, 128] the filter field's postings
+    cand: jax.Array,  # bool[N] rows that hold a vector and are live
+    plan: jax.Array,  # int32[B, 3 * S + 1]
+) -> Tuple[jax.Array, jax.Array]:
+    """Each query row's candidate mask under its own filter, built on
+    the device from the filter field's postings tiles: (bool[B, N],
+    rows passed int32[B]).
+
+    A row of `plan` holds S term slots and the number of clauses a
+    document must match: the first tile of each term's contiguous tile
+    range, the ranges' lengths (0 = unused slot, or a term the segment
+    does not hold: its clause then matches nothing), each slot's unit in
+    the count plane (`clause_units`' units: 1 for a clause of one term,
+    a digit's unit for a clause of several, which counts once however
+    many of its terms a document holds) and, last, the clauses needed
+    (`clauses_hit(cnt) >= need`; 0 on a pad row, whose mask is empty).
+
+    The rows' tile lists are never uploaded: a term's tiles are
+    consecutive, so trip t takes tiles [t * C, (t + 1) * C) of the row's
+    concatenated ranges, found from the ranges' running sums, gathers
+    their doc ids and scatter-adds their slot's unit into the flat count
+    plane (`_add_rare_tiles`' layout: row b's document d at
+    b * (N + 1) + d, pad postings spill at b * (N + 1) + N). The trip
+    count is the longest row's, a value of the launch: one program
+    serves a tag of one tile and one of tens of thousands."""
+    n = cand.shape[0]
+    B = plan.shape[0]
+    S = (plan.shape[1] - 1) // 3
+    C = FILTER_CHUNK
+    starts, counts = plan[:, :S], plan[:, S : 2 * S]
+    units, need = plan[:, 2 * S : 3 * S], plan[:, 3 * S]
+    ends = jnp.cumsum(counts, axis=1)  # [B, S]
+    begins = ends - counts
+    total = ends[:, -1]
+    last_tile = doc_ids.shape[0] - 1
+    row_base = (jnp.arange(B, dtype=jnp.int32) * (n + 1))[:, None, None]
+    lane = jnp.arange(C, dtype=jnp.int32)[None, :]
+
+    def trip(t, cnt):
+        i = t * C + lane  # [1, C] positions in a row's tile list
+        slot = jnp.sum(i[:, :, None] >= ends[:, None, :], axis=2)  # [B, C]
+        slot = jnp.minimum(slot, S - 1)
+        tile = (jnp.take_along_axis(starts, slot, axis=1)
+                + i - jnp.take_along_axis(begins, slot, axis=1))
+        used = i < total[:, None]
+        rows_d = doc_ids[jnp.clip(tile, 0, last_tile)]  # [B, C, 128]
+        valid = (rows_d >= 0) & used[:, :, None]
+        tgt = (jnp.where(valid, rows_d, n) + row_base).ravel()
+        unit = jnp.take_along_axis(units, slot, axis=1)[:, :, None]
+        return cnt.at[tgt].add(jnp.where(valid, unit, 0).ravel())
+
+    cnt = jax.lax.fori_loop(
+        0, (jnp.max(total) + C - 1) // C, trip,
+        jnp.zeros(B * (n + 1), jnp.int32),
+    )
+    hit = clauses_hit(_doc_planes(cnt, B, n))
+    mask = (hit >= need[:, None]) & (need > 0)[:, None] & cand[None, :]
+    return mask, mask.sum(axis=1, dtype=jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("similarity", "k"))
+def knn_topk_filtered(
+    queries: jax.Array,  # float32[B, d] (padded rows are zeros)
+    vectors: jax.Array,  # [N, d] float rows, or int8 of a byte field
+    mask: jax.Array,  # bool[B, N]: each row's own candidates
+    similarity: str,
+    k: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """`knn_topk_batch` where every query row brings a candidate mask of
+    its own (`knn_filter_mask`): every row of `vectors` is scored, the
+    rows a filter passes compete. (scores[B, k], docs[B, k])."""
+    scores = knn_scores(queries, vectors, similarity)
+    return jax.lax.top_k(jnp.where(mask, scores, -jnp.inf), k)
 
 
 @functools.partial(jax.jit, static_argnames=("similarity", "k"))

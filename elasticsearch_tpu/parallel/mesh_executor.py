@@ -510,6 +510,8 @@ class MeshExecutor:
             dims = int(present[0][0].shape[1])
             similarity = present[0][1].similarity
             dtype = np.result_type(*[m[0].dtype for m in present])
+            if np.issubdtype(dtype, np.integer):
+                dtype = np.float32  # a byte field: the mesh step squares rows
             vectors = np.zeros((snap.e_pad, snap.n_docs_max, dims), dtype)
             cand = np.zeros((snap.e_pad, snap.n_docs_max), bool)
             n_per_entry = np.zeros(snap.e_pad, np.int64)

@@ -649,6 +649,14 @@ class RestActions:
         # own thread (one local shard whose wait polls the task) or the
         # fan-out pool (IndexService.fan_out_stats)
         fan_out = {"inline": 0, "pooled": 0}
+        # the knn family's filtered groups (QueryBatcher.knn_filtered):
+        # scans under a mask of the job's own, rows scored and rows the
+        # filters passed, postings tiles the mask launches scattered,
+        # and the scans that fell back to the unbatched executor
+        knn_filtered = {
+            "searches": 0, "rows_scanned": 0, "rows_passed": 0,
+            "filter_tiles": 0, "mask_launches": 0, "fallbacks": 0,
+        }
         for idx in self.cluster.indices.values():
             with idx._rrf_lock:
                 for k, v in idx.rrf_stats.items():
@@ -660,6 +668,9 @@ class RestActions:
             if b is not None:
                 for k in batch:
                     batch[k] += b.stats.get(k, 0)
+                with b._lock:
+                    for k, v in b.knn_filtered.items():
+                        knn_filtered[k] += v
                 queue_capacity = max(queue_capacity, b._queue.maxsize)
                 pipeline["depth"] = max(pipeline["depth"], b.pipeline_depth)
                 bs = b.batching_stats()
@@ -865,6 +876,7 @@ class RestActions:
                     "transfer": {"scoring": tracing.transfer_stats()},
                     "aggs": aggs_block,
                     "knn": knn_block,
+                    "knn_filtered": knn_filtered,
                     "rescore": rescore_block,
                     "sparse": sparse_block,
                     "translog": translog_block,

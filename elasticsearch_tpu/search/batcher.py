@@ -43,7 +43,7 @@ import threading
 import time
 import weakref
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple, Union
 
 import numpy as np
@@ -58,7 +58,7 @@ from ..common.tracing import (
     note_transfer,
     thread_d2h_bytes,
 )
-from ..index.mapping import SPARSE_VECTOR, TEXT
+from ..index.mapping import KEYWORD, SPARSE_VECTOR, TEXT
 from ..ops import scoring
 from ..ops.scoring import BPAD
 from . import dsl
@@ -234,12 +234,35 @@ class ServePlan:
 
 
 @dataclass(frozen=True)
+class KnnFilter:
+    """A knn section's `filter` as the knn family plans it: a
+    conjunction of clauses over ONE keyword field, each clause the
+    terms of which a document must hold at least one (a `term`: one; a
+    `terms`: any of its values). The device builds each job's candidate
+    mask from the field's postings tiles (`scoring.knn_filter_mask`);
+    `query`, the parsed filter, serves a segment whose mask build
+    failed, on the unbatched executor."""
+
+    field: str
+    clauses: Tuple[Tuple[str, ...], ...]
+    query: object = field(default=None, compare=False)
+
+    @property
+    def n_terms(self) -> int:
+        return sum(len(c) for c in self.clauses)
+
+
+@dataclass(frozen=True)
 class KnnPlan:
-    """A bare top-level knn section (no filter/threshold): batched
+    """A single top-level knn section with no similarity threshold,
+    bare or under a `filter` the planner took (`KnnFilter`): batched
     brute-force matmul per segment (BASELINE config 4), or — when `ann`
     carries a resolved search/ann.AnnSpec — the IVF probed path over
     the same launch/merge plumbing. `ann` rides the group key, so exact
-    and probed jobs (or different probe widths) never share a launch."""
+    and probed jobs (or different probe widths) never share a launch;
+    so does WHETHER a job is filtered (bare jobs keep the program with
+    one shared candidate mask), never the filter itself: filtered jobs
+    of any filters share a launch, each row under its own mask."""
 
     field: str
     vector: Tuple[float, ...]
@@ -247,6 +270,7 @@ class KnnPlan:
     num_candidates: int
     boost: float
     ann: Optional[object] = None
+    filter: Optional[KnnFilter] = None
 
 
 @dataclass(frozen=True)
@@ -504,15 +528,64 @@ def split_filtered_bool(query):
     return stripped, list(query.filter)
 
 
+def extract_knn_filter(query, mappings) -> Optional[KnnFilter]:
+    """A knn `filter` the device can build a mask for from postings
+    tiles: a `term` or `terms` on a keyword field, or a `bool` whose
+    `filter` / `must` hold only those, all on one field (a conjunction
+    of counted clauses). None for anything else (`range`, `must_not`,
+    `should`, nested bools, another field type) and for a filter past
+    the mask program's counters: more than scoring.CLAUSE_DIGITS
+    clauses of several terms, one of more than scoring.CLAUSE_TERMS_MAX
+    terms, more terms than its widest plan."""
+    if isinstance(query, dsl.BoolQuery):
+        if (query.should or query.must_not
+                or query.minimum_should_match is not None):
+            return None
+        leaves = list(query.filter) + list(query.must)
+    else:
+        leaves = [query]
+    clauses: List[Tuple[str, ...]] = []
+    fields = set()
+    for q in leaves:
+        if isinstance(q, dsl.TermQuery):
+            values = [q.value]
+        elif isinstance(q, dsl.TermsQuery):
+            values = q.values
+        else:
+            return None
+        fields.add(q.field)
+        # distinct values, in the request's order
+        clauses.append(tuple(dict.fromkeys(
+            dsl.term_token(v) for v in values)))
+    if len(fields) != 1 or not all(clauses):
+        return None
+    fname = fields.pop()
+    mf = mappings.get(fname)
+    if mf is None or mf.type != KEYWORD:
+        return None
+    multi = [c for c in clauses if len(c) > 1]
+    if (len(multi) > scoring.CLAUSE_DIGITS
+            or any(len(c) > scoring.CLAUSE_TERMS_MAX for c in multi)
+            or scoring.filter_slot_bucket(sum(map(len, clauses))) is None):
+        return None
+    return KnnFilter(field=fname, clauses=tuple(clauses), query=query)
+
+
 def extract_knn_plan(knn_sections, mappings) -> Optional[KnnPlan]:
-    """A single bare knn section (no filter, no similarity threshold)
-    rides the batched matmul launch. A dims mismatch stays OFF the
-    shared launch so one malformed request can't fail a whole group."""
+    """A single knn section with no similarity threshold rides the
+    batched matmul launch: bare, or with a `filter` that
+    `extract_knn_filter` plans. A dims mismatch stays OFF the shared
+    launch so one malformed request can't fail a whole group."""
     if knn_sections is None or len(knn_sections) != 1:
         return None
     sec = knn_sections[0]
-    if sec.filter is not None or sec.similarity is not None:
+    if sec.similarity is not None:
         return None
+    flt = None
+    if sec.filter is not None:
+        flt = extract_knn_filter(sec.filter, mappings)
+        if flt is None:
+            return None
     mf = mappings.get(sec.field)
     dims = getattr(mf, "dims", None) if mf is not None else None
     if dims is not None and len(sec.query_vector) != int(dims):
@@ -524,6 +597,7 @@ def extract_knn_plan(knn_sections, mappings) -> Optional[KnnPlan]:
         num_candidates=int(sec.num_candidates),
         boost=float(sec.boost),
         ann=getattr(sec, "ann", None),
+        filter=flt,
     )
 
 
@@ -817,9 +891,14 @@ def _warm_match(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
 
 
 def _warm_knn(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
-    # the kNN candidate page is a compile bucket of its own
-    j0 = max(jobs, key=lambda j: j.plan.num_candidates)
-    return (scoring.next_bucket(j0.plan.num_candidates, 16),), j0
+    # the kNN candidate page is a compile bucket of its own, and so is
+    # the width of a filtered group's mask plan
+    def terms(j: _Job) -> int:
+        return j.plan.filter.n_terms if j.plan.filter else 0
+
+    j0 = max(jobs, key=lambda j: (j.plan.num_candidates, terms(j)))
+    return (scoring.next_bucket(j0.plan.num_candidates, 16),
+            scoring.filter_slot_bucket(terms(j0)) if terms(j0) else 0), j0
 
 
 FAMILIES: Dict[str, _Family] = {
@@ -839,9 +918,10 @@ FAMILIES: Dict[str, _Family] = {
             jobs, kb, pend, record=record),
         warm=_warm_first,
     ),
-    # `ann` rides the key: exact and IVF-probed jobs never share
+    # `ann` rides the key: exact and IVF-probed jobs never share; nor
+    # do bare and filtered jobs (two programs: one mask, a mask a row)
     "knn": _Family(
-        "knn", lambda p: (p.field, p.ann),
+        "knn", lambda p: (p.field, p.ann, p.filter is not None),
         lambda b, jobs, key, kb, rows, record: b._dispatch_knn_group(
             jobs, rows=rows, record=record),
         lambda b, jobs, key, kb, pend, record: b._collect_knn_group(
@@ -977,8 +1057,9 @@ class QueryBatcher:
             # than one term (ServePlan.clauses / .multi_term_clauses)
             "serve_clauses": 0,
             "serve_multi_term_clauses": 0,
-            # query-only searches of a jax shard that neither planner
-            # (extract_match_plan, extract_serve_plan) gave a plan: they
+            # searches of a jax shard that no planner gave a plan (a
+            # query neither extract_match_plan nor extract_serve_plan
+            # took, a knn section extract_knn_plan turned away): they
             # ran on the unbatched executor (`note_unplanned`)
             "unplanned_queries": 0,
             # the match family's twin of `serve_rare_tiles`, and how far
@@ -1018,6 +1099,17 @@ class QueryBatcher:
             # live hit instead, but the failure is counted and the first
             # one logged — a bring-up run requires this to read zero
             "warmup_failures": 0,
+        }
+        # the knn family's filtered groups (`_nodes/stats`
+        # `knn_filtered`; under self._lock): (job x segment) scans under
+        # a mask the device built, the rows scored and the rows the
+        # filters passed (counted on the device, read at collect; both
+        # count a fallback's rows too), the postings tiles the mask
+        # launches scattered, those launches, and the (job x segment)
+        # scans that left the planned path for the unbatched executor
+        self.knn_filtered = {
+            "searches": 0, "rows_scanned": 0, "rows_passed": 0,
+            "filter_tiles": 0, "mask_launches": 0, "fallbacks": 0,
         }
         # per-bucket launch histogram + occupancy sums (guarded by
         # self._lock; surfaced via batching_stats() → _nodes/stats):
@@ -1429,10 +1521,10 @@ class QueryBatcher:
             self._occ_slots += rows
 
     def note_unplanned(self) -> None:
-        """A query-only search of a jax shard left for the unbatched
-        executor: neither planner gave it a plan (cluster/indices.py,
-        the shard path, which the mesh twin and a retriever's leg fall
-        through to)."""
+        """A query-only or knn-only search of a jax shard left for the
+        unbatched executor: no planner gave it a plan
+        (cluster/indices.py, the shard path, which the mesh twin and a
+        retriever's leg fall through to)."""
         with self._lock:
             self.stats["unplanned_queries"] += 1
 
@@ -2045,7 +2137,24 @@ class QueryBatcher:
                             record: bool = True) -> List[Tuple]:
         """Launches the batched brute-force kNN matmul per segment
         (BASELINE config 4); results stay on device until collect.
-        `rows` pads the query-row dimension to one ladder bucket."""
+        `rows` pads the query-row dimension to one ladder bucket.
+
+        A FILTERED group (every job carries a `KnnFilter`; the group
+        key keeps them apart from bare jobs) gives each row a candidate
+        mask of its own, built on the device from the filter field's
+        postings tiles by one launch of `scoring.knn_filter_mask` a
+        segment (the `filter_mask` span, a child of `dispatch`), and
+        scores every stored row under it (`knn_topk_filtered`); the
+        rows each filter passed are counted on the device and come down
+        with the collect's packed download. The planned path neither
+        reads nor feeds the node's filter-bitset cache: a tag's mask is
+        rebuilt from its postings in microseconds to milliseconds, a
+        cached one is a byte a document for every distinct filter. A
+        segment whose mask cannot be built (the `knn.filter` fault
+        site, a postings upload the HBM breaker refuses) is served per
+        job by the unbatched executor at collect, and counted
+        (`knn_filtered.fallbacks`); the IVF tier is not asked for
+        filtered jobs."""
         ex = jobs[0].executor
         reader = ex.reader
         nj = len(jobs)
@@ -2053,6 +2162,14 @@ class QueryBatcher:
         staging = getattr(ex, "staging_slab", None)
         field = jobs[0].plan.field
         spec = jobs[0].plan.ann  # shared: ann rides the group key
+        filtered = jobs[0].plan.filter is not None  # shared: the key's
+        if filtered:
+            spec = None
+            if record:
+                tags = _group_now().plan_tags
+                tags["filtered"] = True
+                tags["clauses"] = max(
+                    len(j.plan.filter.clauses) for j in jobs)
         items: List[Tuple] = []
         for si, seg in enumerate(reader.segments):
             if seg.vectors.get(field) is None:
@@ -2119,7 +2236,7 @@ class QueryBatcher:
                             nj, idx.nlist, spec.nprobe, idx.cmax, dims
                         )
                     )
-                items.append((si, n, s, d))
+                items.append((si, n, s, d, None))
                 continue
             vectors, exists = ex.device_segments[si].vectors[field]
             cand_mask = exists
@@ -2127,6 +2244,13 @@ class QueryBatcher:
                 live = np.asarray(live)
                 note_transfer("h2d", live.nbytes)
                 cand_mask = cand_mask & live
+            if filtered:
+                item = self._dispatch_knn_filtered(
+                    jobs, si, n, np.asarray(q), vectors, cand_mask,
+                    vf.similarity, kc, rows, record)
+                if item is not None:
+                    items.append(item)
+                continue
             # host rows handed to the jitted program: the launch uploads
             # them (noted here, the program itself cannot)
             note_transfer("h2d", q.nbytes)
@@ -2140,8 +2264,60 @@ class QueryBatcher:
                     self.stats["launches"] += 1
                     self.stats["fused_jobs"] += nj
                 _group_now().add_flops(scoring.knn_flops(nj, n, dims))
-            items.append((si, n, s, d))
+            items.append((si, n, s, d, None))
         return items
+
+    def _dispatch_knn_filtered(self, jobs: List[_Job], si: int, n: int,
+                               q: np.ndarray, vectors, cand_mask,
+                               similarity: str, kc: int, rows: int,
+                               record: bool) -> Tuple:
+        """One segment of a filtered group: the mask launch, then the
+        scan under the masks. -> (si, n, scores, docs, passed), the
+        three on the device; (si, n, None, None, None) where the segment
+        is left to the unbatched executor at collect; None where no
+        document of the segment holds the filter's field (nothing
+        passes, nothing is launched)."""
+        ex = jobs[0].executor
+        t0 = time.perf_counter_ns()
+        fname = jobs[0].plan.filter.field
+        pf = ex.reader.segments[si].postings.get(fname)
+        if pf is None:
+            return None
+        try:
+            if record:
+                faults.check("knn.filter", field=fname, segment=si)
+            dp = ex.device_segments[si].postings[fname]
+        except Exception:
+            if record:
+                with self._lock:
+                    self.knn_filtered["fallbacks"] += len(jobs)
+            return si, n, None, None, None
+        plan, tiles = scoring.pack_filter_plans(
+            pf, [j.plan.filter.clauses for j in jobs], rows)
+        note_transfer("h2d", plan.nbytes)
+        mask, passed = scoring.knn_filter_mask(dp.doc_ids, cand_mask, plan)
+        if record:
+            g = _group_now()
+            g.plan_tags["filter_tiles"] = (
+                g.plan_tags.get("filter_tiles", 0) + tiles)
+            g.sub_spans.append((
+                "filter_mask", t0, time.perf_counter_ns(),
+                {"segment": si, "launches": 1, "tiles": tiles},
+            ))
+        note_transfer("h2d", q.nbytes)
+        s, d = scoring.knn_topk_filtered(q, vectors, mask, similarity, kc)
+        if record:
+            dims = int(q.shape[1])
+            with self._lock:
+                self.stats["launches"] += 1
+                self.stats["fused_jobs"] += len(jobs)
+                kf = self.knn_filtered
+                kf["searches"] += len(jobs)
+                kf["rows_scanned"] += len(jobs) * n
+                kf["filter_tiles"] += tiles
+                kf["mask_launches"] += 1
+            _group_now().add_flops(scoring.knn_flops(len(jobs), n, dims))
+        return si, n, s, d, passed
 
     def _collect_knn_group(self, jobs: List[_Job], items,
                            record: bool = True):
@@ -2156,20 +2332,34 @@ class QueryBatcher:
         negative boost would reorder, so that group merges on host."""
         if record:
             faults.check("knn.collect", jobs=len(jobs))
-        reader = jobs[0].executor.reader
+        ex = jobs[0].executor
+        reader = ex.reader
+        nj = len(jobs)
+        # items: (segment, its docs, scores, docs, rows passed); the
+        # last is a filtered group's (device int32[rows], None on a bare
+        # one); a filtered segment whose mask was not built holds None
+        # for all three and is served per job below
+        filtered = jobs[0].plan.filter is not None
+        on_device = all(s is not None for _si, _n, s, _d, _p in items)
+        passed_rows = 0
         per_job_cands: List[List[Tuple[float, int, int]]] = [[] for _ in jobs]
-        if items and all(j.plan.boost > 0.0 for j in jobs):
+        if items and on_device and all(j.plan.boost > 0.0 for j in jobs):
             # the device buffers' row bucket; padded query rows keep
             # nc=0 (their scores are -inf anyway)
             rows = int(items[0][2].shape[0])
             nc_rows = np.zeros((rows, len(items)), np.int32)
-            for ii, (si, n, _, _) in enumerate(items):
+            for ii, (_si, n, *_rest) in enumerate(items):
                 for ji, j in enumerate(jobs):
                     nc_rows[ji, ii] = min(j.plan.num_candidates, n)
             k_out = max(max(j.k, 1) for j in jobs)
-            ms, mseg, mdoc, counts = scoring.knn_merge_segment_topk(
-                [(si, s, d) for si, _, s, d in items], nc_rows, k_out
+            ms, mseg, mdoc, counts, *passed = scoring.knn_merge_segment_topk(
+                [(si, s, d) for si, _n, s, d, _p in items], nc_rows, k_out,
+                passed=[p for *_rest, p in items] if filtered else None,
             )
+            if filtered and record:
+                with self._lock:
+                    self.knn_filtered["rows_passed"] += int(
+                        passed[0][:nj].sum())
             ms, mseg, mdoc = scoring.rank_order(ms, mseg, mdoc)
             for ji, j in enumerate(jobs):
                 finite = np.isfinite(ms[ji])
@@ -2196,18 +2386,36 @@ class QueryBatcher:
                 )
                 j.finish()
             return
-        for si, n, s, d in items:
-            s = np.asarray(s)
-            d = np.asarray(d)
+        for si, n, s, d, passed in items:
+            if s is not None:
+                s = np.asarray(s)
+                d = np.asarray(d)
+                if filtered:
+                    passed_rows += int(np.asarray(passed)[:nj].sum())
             for ji, j in enumerate(jobs):
                 nc = min(j.plan.num_candidates, n)
-                row_s, row_d = s[ji][:nc], d[ji][:nc]
+                if s is None:
+                    # the unbatched executor's filter evaluation and
+                    # one-row scan (`fallbacks` counted at dispatch)
+                    row_s, row_d, n_pass = ex.knn_filtered_segment(
+                        j.plan.field, j.plan.vector, j.plan.filter.query,
+                        si, nc)
+                    passed_rows += n_pass
+                    if record:
+                        with self._lock:
+                            self.stats["launches"] += 1
+                            self.knn_filtered["rows_scanned"] += n
+                else:
+                    row_s, row_d = s[ji][:nc], d[ji][:nc]
                 finite = np.isfinite(row_s)
                 boost = j.plan.boost
                 for sc, doc in zip(row_s[finite], row_d[finite]):
                     per_job_cands[ji].append(
                         (float(sc) * boost, si, int(doc))
                     )
+        if filtered and record:
+            with self._lock:
+                self.knn_filtered["rows_passed"] += passed_rows
         # global k cut; totals = number of winners (knn semantics)
         totals = np.asarray(
             [min(len(per_job_cands[ji]), j.plan.k)

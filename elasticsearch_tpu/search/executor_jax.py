@@ -58,9 +58,27 @@ DENSE_ROWS_HBM_BUDGET = 1024 * 1024 * 1024
 
 
 class DevicePostings:
-    def __init__(self, pf, device=None):
+    """A field's postings tiles on the device: the doc-id plane at
+    once, the tf plane at its first use. A filter reads ids alone (the
+    knn family's masks over a keyword field never upload its tfs: half
+    the field's bytes); every scoring path reads both."""
+
+    def __init__(self, pf, device=None, charge=None):
         self.doc_ids = jax.device_put(pf.doc_ids, device)
-        self.tfs = jax.device_put(pf.tfs, device)
+        self._pf, self._device, self._charge = pf, device, charge
+        self._tfs = None
+        self._lock = threading.Lock()
+
+    @property
+    def tfs(self) -> jax.Array:
+        if self._tfs is None:
+            with self._lock:
+                if self._tfs is None:
+                    tfs = jax.device_put(self._pf.tfs, self._device)
+                    if self._charge is not None:
+                        self._charge("postings", int(tfs.nbytes), False)
+                    self._tfs = tfs
+        return self._tfs
 
 
 def _tree_nbytes(v) -> int:
@@ -119,7 +137,8 @@ class DeviceSegment:
         self.seg = seg
         self.device = device
         self.postings = _LazyDeviceMap(
-            seg.postings, lambda f: DevicePostings(seg.postings[f], device),
+            seg.postings,
+            lambda f: DevicePostings(seg.postings[f], device, charge),
             charge=charge, category="postings",
         )
         self.numerics = _LazyDeviceMap(
@@ -1969,6 +1988,8 @@ class JaxExecutor:
                     and vf.unit_vectors is not None
                     else vf.vectors
                 )
+                if np.issubdtype(mat.dtype, np.integer):
+                    mat = mat.astype(np.float32)  # a byte field's rows
                 nlist = spec.nlist or ivf.auto_nlist(n)
                 nlist = max(1, min(nlist, n))
                 est = ivf.IvfSegmentIndex.estimate_nbytes(
@@ -2138,6 +2159,27 @@ class JaxExecutor:
             return col
 
     # ---- knn (device matmul + global top-k cut) ----
+
+    def knn_filtered_segment(
+        self, field: str, vector, filter_query: Query, si: int, k: int
+    ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """One filtered kNN job on one segment, unbatched: the filter
+        through `filter_mask` (the query-tree evaluation and the bitset
+        cache), a one-row scan under it. -> (scores[k], docs[k], rows
+        the filter passed), on the host. What a filtered job of the
+        batcher's knn family falls back to when its mask launch cannot
+        be made (search/batcher `_dispatch_knn_filtered`)."""
+        seg = self.reader.segments[si]
+        vf = seg.vectors[field]
+        cand = jnp.asarray(vf.exists) & self.filter_mask(filter_query, si)
+        live = self.reader.live_docs[si]
+        if live is not None:
+            cand = cand & jnp.asarray(live)
+        vectors, _exists = self.device_segments[si].vectors[field]
+        q = jnp.asarray(np.asarray(vector, np.float32))[None, :]
+        top_s, top_d = scoring.knn_topk(
+            q, vectors, cand, vf.similarity, min(k, seg.num_docs))
+        return np.asarray(top_s[0]), np.asarray(top_d[0]), int(cand.sum())
 
     def _knn_topk_global(self, sec: KnnSection) -> List[Tuple[jax.Array, jax.Array]]:
         from ..common.faults import faults

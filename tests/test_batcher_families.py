@@ -36,7 +36,10 @@ KEYED = {
         "combine": ("sum", "max_tie"),
         "tie": (0.0, 0.3),
     },
-    "knn": {"field": ("vec", "vec2"), "ann": (None, "ivf:8")},
+    # `filter`: bare and filtered jobs are two programs (WHETHER a job
+    # is filtered is keyed on, never which filter it carries)
+    "knn": {"field": ("vec", "vec2"), "ann": (None, "ivf:8"),
+            "filter": (None, "tags:t1")},
     "agg": {"sig": ("terms:tag", "terms:cat")},
     "rerank": {"sig": ("m1:16:8", "m1:32:8")},
     "sparse": {"field": ("sv", "sv2"), "spec": ("fp32", "int8")},

@@ -113,6 +113,36 @@ def test_exact_knn_topk_batch(one_chip, rows):
     _fits(compiled)
 
 
+# the filtered kNN deployment (benchmarks/configs/yfcc10m-filtered-knn.json):
+# 10M x 192 int8 rows under a mask a query row, built from the tag
+# field's 932,453 postings tiles; one row (the cell) and the ladder's top
+FILTERED_DOCS = 10_000_000
+FILTERED_TILES = 932_453
+
+
+@pytest.mark.parametrize("rows", [1, 32])
+def test_filtered_byte_knn_programs(one_chip, rows):
+    s = _on(one_chip)
+    width = 3 * scoring.FILTER_SLOT_BUCKETS[0] + 1
+    mask = scoring.knn_filter_mask.lower(
+        s((FILTERED_TILES, TILE), jnp.int32),
+        s((FILTERED_DOCS,), jnp.bool_),
+        s((rows, width), jnp.int32),
+    ).compile()
+    assert "while" in mask.as_text()  # the trips are a loop of the program
+    scan = scoring.knn_topk_filtered.lower(
+        s((rows, 192), jnp.float32),
+        s((FILTERED_DOCS, 192), jnp.int8),
+        s((rows, FILTERED_DOCS), jnp.bool_),
+        similarity="l2_norm",
+        k=128,
+    ).compile()
+    # both beside what the deployment keeps resident: rows and two planes
+    resident = FILTERED_DOCS * 192 + 2 * FILTERED_TILES * TILE * 4
+    assert _fits(mask) + resident < HBM_BYTES
+    assert _fits(scan) + resident < HBM_BYTES
+
+
 # ---------------------------------------------------------------------------
 # fused text programs at the plan shape the batcher sends
 # ---------------------------------------------------------------------------

@@ -1,3 +1,4 @@
+import numpy as np
 import pytest
 
 from elasticsearch_tpu.analysis import AnalysisRegistry
@@ -24,6 +25,54 @@ class TestMappingsMerge:
         m = Mappings({"properties": {"v": {"type": "dense_vector", "dims": 4}}})
         with pytest.raises(MappingParseError, match="dims"):
             m.merge({"properties": {"v": {"type": "dense_vector", "dims": 8}}})
+
+    def test_reject_element_type_change(self):
+        m = Mappings({"properties": {"v": {
+            "type": "dense_vector", "dims": 4, "element_type": "byte"}}})
+        with pytest.raises(MappingParseError, match="element_type"):
+            m.merge({"properties": {"v": {"type": "dense_vector", "dims": 4}}})
+        m.merge({"properties": {"v": {  # the same mapping again: a no-op
+            "type": "dense_vector", "dims": 4, "element_type": "byte"}}})
+        assert m.to_json()["properties"]["v"]["element_type"] == "byte"
+
+
+class TestByteVectors:
+    @pytest.mark.parametrize("values, why", [
+        ([1, -128, 127, 0], None),
+        ([1.0, 2.0], None),  # whole numbers, however JSON wrote them
+        ([1, 128], "between [-128, 127] but found [128] at dim [1]"),
+        ([-129], "between [-128, 127] but found [-129] at dim [0]"),
+        ([0, 0.5], "decimal value [0.5] at dim [1]"),
+        ([float("nan")], "decimal value [nan]"),
+        ([True], "only support numbers"),
+        (["7"], "only support numbers"),
+    ], ids=["ints", "whole_floats", "high", "low", "decimal", "nan", "bool",
+            "string"])
+    def test_byte_vector_error(self, values, why):
+        from elasticsearch_tpu.index.mapping import byte_vector_error
+
+        got = byte_vector_error(values)
+        assert (got is None) if why is None else (why in got)
+
+    def test_byte_rows_are_stored_as_int8(self):
+        from elasticsearch_tpu.index.segment import SegmentBuilder
+
+        m = Mappings({"properties": {
+            "b": {"type": "dense_vector", "dims": 3, "element_type": "byte",
+                  "similarity": "l2_norm"},
+            "f": {"type": "dense_vector", "dims": 3, "similarity": "l2_norm"}}})
+        p = DocumentParser(m, AnalysisRegistry())
+        docs = [p.parse(str(i), {"b": [i, -i, 127], "f": [i, -i, 127]})
+                for i in range(4)]
+        builder = SegmentBuilder(m)
+        for d in docs:
+            builder.add(d)
+        seg = builder.build()
+        assert seg.vectors["b"].vectors.dtype == np.int8
+        assert seg.vectors["f"].vectors.dtype == np.float32
+        assert (seg.vectors["b"].vectors == seg.vectors["f"].vectors).all()
+        with pytest.raises(MappingParseError, match="non-decimal|between"):
+            p.parse("x", {"b": [1, 2, 300]})
 
 
 class TestLeafObjectConflicts:
