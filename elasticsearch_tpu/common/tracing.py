@@ -91,8 +91,8 @@ starts at `http`'s start and reaches the ring when `http` ends:
             fused match or serve group also rare_tiles, a serve group
             fields and hot_slots: the most tile slots / dense rows a
             job and field used; a sparse group terms, tiles_scored,
-            tiles_pruned, chunk_launches (sums over its jobs and
-            segments) and quantized; a filtered knn group filtered,
+            tiles_pruned, chunk_launches, dense_rows, tiles_dense
+            (sums over its jobs and segments) and quantized; a filtered knn group filtered,
             clauses (the most a job's filter holds) and filter_tiles
             (postings tiles its mask launches scattered, summed over
             jobs and segments)]

@@ -40,14 +40,37 @@ job drop tiles only where its reported `hits.total` cannot move by it
 (totals untracked, or some query term's postings alone prove more
 matches than `track_total_hits` counts to).
 
+Hot terms of the int8 column leave the scatter (`ImpactRows`). A
+scatter-add is serial on the chip (~7 ns a slot), so a term frequent
+enough (the caller's rule, executor_jax.impact_scorer: the text
+family's df threshold and HBM budget) holds a dense ROW: one int8 a
+document, the stored `q` where the term has a posting and ROW_ABSENT
+(-128, which the quantizer's clip to -127..127 never stores) where it
+has none, so a posting whose `q` is 0 is still present: it matches and
+counts as its tile slot does. Rows are built ON the device from the
+resident tile planes (`build_impact_rows`), and `_impact_dense_add`
+streams a query's rows into the accumulators: per row `acc += tw *
+f32(q)`, `cnt += 1` where present — the product `impact_tile_contrib`
+forms, the same postings in another layout. A query's terms without a
+row (and its hot terms past DENSE_SLOTS) go through their tiles as
+before, and so does every term of the float32 column, whose bit
+equality with the oracle rests on pure term order. A document's score
+is therefore the same set of float32 addends in another order: its
+rows' products first, in term order, then its tiles' in term order
+(`SparseBlockMax.kept`). `host_theta`'s soundness argument covers any
+order; block-max bounds still sum EVERY term's maximum and hot terms
+are never dropped, which keeps more tiles, never fewer.
+
 Every host<->device transfer of the family is noted where it happens
 (`common/tracing.note_transfer`): the three staged planes a chunk
-launch uploads; the packed collect notes itself in ops/scoring.
+launch uploads and the two of a row launch; the packed collect notes
+itself in ops/scoring.
 """
 
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -66,9 +89,29 @@ TILE_WIDTH = 128
 FLOPS_PER_IMPACT_SLOT = 4
 
 
-def sparse_flops(n_tile_slots: int) -> int:
-    """Estimated useful flops of one sparse job's plan on one segment."""
-    return n_tile_slots * TILE_WIDTH * FLOPS_PER_IMPACT_SLOT
+# Hot-term row slots a query row carries into `_impact_dense_add`: a
+# compile shape like scoring.FUSED_H. The program loops over the slots a
+# launch USES, so an unused slot costs nothing but 8 bytes of upload; a
+# query's hot terms past it (the least frequent of them) go through
+# their tiles, which stay resident: an overflow costs time, never an
+# answer. In the SPLADE deployment (1,069 rows held at 1M passages) a
+# 49-token query holds ~16 hot terms, a 128-token one ~41.
+DENSE_SLOTS = 64
+ROW_ABSENT = -128  # no posting: below the quantizer's -127..127
+# A row's stride in the plane: past n_docs + 1 (the accumulators'
+# width) to a multiple of 4096, so every row starts on a tile boundary
+# of the 8-bit layout and a launch streams the rows it names, not their
+# neighbours.
+ROW_ALIGN = 4096
+ROWS_FILL_TILES = 32768  # tiles a build launch scatters into the plane
+
+
+def sparse_flops(n_tile_slots: int, n_row_slots: int = 0) -> int:
+    """Estimated useful flops of one sparse job's plan on one segment:
+    its tiles' posting slots and its rows' document slots."""
+    return (
+        n_tile_slots * TILE_WIDTH + n_row_slots
+    ) * FLOPS_PER_IMPACT_SLOT
 
 
 def chunk_launches(tile_lists) -> int:
@@ -117,10 +160,127 @@ def _impact_chunk_add(doc_ids, values, acc, cnt, ti, tw, tv):
     return acc, cnt
 
 
+@functools.partial(jax.jit, donate_argnums=(1, 2))
+def _impact_dense_add(plane, acc, cnt, ids, tw):
+    """acc[B, n+1] += the dense rows a launch names: `ids` i32[B,
+    DENSE_SLOTS] rows of `plane` (-1 = unused, filled from slot 0 up),
+    `tw` f32[B, DENSE_SLOTS] their folded tile weights. Per slot and
+    query row: present = row != ROW_ABSENT, acc += where(present, tw *
+    f32(row), 0), cnt += present: `impact_tile_contrib`'s product over
+    the same postings, streamed instead of scattered.
+
+    A loop over the slots the launch USES (PR 26's finding in
+    `scoring._add_hot_rows`) with one dynamic slice a row of the flat
+    plane, whose rows start on tile boundaries: only the rows asked for
+    are read. Each query row's two planes ride the loop FLAT (PR 30's
+    finding in `scoring._add_rare_tiles`, met again here): measured on
+    the TPU v5e at 1M docs, one query row (PERF.md section 6, PR 41),
+    adding a flat row slice into the [1, n+1] planes relays them
+    through a reshape every slot, 72 us a row; relaid once a launch, as
+    `_impact_chunk_add` relays them around its scatters, a slot is one
+    fused pass a plane, 11 us a row. (Four or eight rows a trip read
+    8 us a row: 0.05 ms a launch of 16 rows, not worth the unrolling.)"""
+    n_q, width = acc.shape
+    stride = impact_row_stride(width - 1)
+    n_rows = plane.shape[0] // stride
+    slots = jnp.arange(1, ids.shape[1] + 1, dtype=jnp.int32)
+    used = jnp.max(jnp.where(ids >= 0, slots, 0))
+
+    def slot(h, carry):
+        accs, cnts = carry
+        out_a, out_c = [], []
+        for b in range(n_q):
+            rid = ids[b, h]
+            row = jax.lax.dynamic_slice(
+                plane, (jnp.clip(rid, 0, n_rows - 1) * stride,), (width,)
+            )
+            present = (row != ROW_ABSENT) & (rid >= 0)
+            s = tw[b, h] * row.astype(jnp.float32)
+            out_a.append(accs[b] + jnp.where(present, s, 0.0))
+            out_c.append(cnts[b] + present.astype(jnp.int32))
+        return tuple(out_a), tuple(out_c)
+
+    accs, cnts = jax.lax.fori_loop(
+        0,
+        used,
+        slot,
+        (tuple(acc[b] for b in range(n_q)), tuple(cnt[b] for b in range(n_q))),
+    )
+    return jnp.stack(accs), jnp.stack(cnts)
+
+
+def impact_row_stride(n_docs: int) -> int:
+    """A row's stride in the plane, which is the device bytes it costs:
+    the accumulators' width n_docs + 1 rounded up to ROW_ALIGN."""
+    return -(-(n_docs + 1) // ROW_ALIGN) * ROW_ALIGN
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("stride",))
+def _impact_rows_fill(plane, doc_ids, values, tiles, row_of_tile, stride):
+    """Sets the postings of `tiles` (row_of_tile < 0 = padding) into
+    the donated flat plane, row by `row_of_tile`."""
+    d = doc_ids[tiles]
+    ok = (d >= 0) & (row_of_tile >= 0)[:, None]
+    at = jnp.where(ok, row_of_tile[:, None] * stride + d, plane.shape[0])
+    return plane.at[at.ravel()].set(values[tiles].ravel(), mode="drop")
+
+
+@dataclass
+class ImpactRows:
+    """The dense rows one int8 column's hot terms hold on the device
+    (module comment): `plane` int8[n_rows * stride], row r the stored
+    impacts of term `held[r]` by document, ROW_ABSENT elsewhere;
+    `row_of_term` int32[n_terms], -1 for a term without a row."""
+
+    plane: object
+    row_of_term: np.ndarray
+    n_rows: int
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.plane.shape[0])
+
+
+def build_impact_rows(
+    doc_ids, values, term_tile_start, term_tile_count, held, n_docs: int
+) -> ImpactRows:
+    """Rows for the terms `held` (row r = held[r]), built ON the device
+    from the resident int8 tile planes `doc_ids` / `values`: nothing is
+    uploaded but the tile lists, ROWS_FILL_TILES a launch into the
+    donated plane, so the build's temporaries stay a few tens of MB
+    whatever the plane holds."""
+    held = np.asarray(held, np.int64)
+    stride = impact_row_stride(n_docs)
+    row_of_term = np.full(len(term_tile_start), -1, np.int32)
+    row_of_term[held] = np.arange(len(held), dtype=np.int32)
+    counts = term_tile_count[held].astype(np.int64)
+    total = int(counts.sum())
+    tiles = (
+        np.arange(total, dtype=np.int64)
+        - np.repeat(np.cumsum(counts) - counts, counts)
+        + np.repeat(term_tile_start[held].astype(np.int64), counts)
+    ).astype(np.int32)
+    row_of_tile = np.repeat(np.arange(len(held), dtype=np.int32), counts)
+    step = min(ROWS_FILL_TILES, 1 << max(total - 1, 0).bit_length())
+    plane = jnp.full((len(held) * stride,), ROW_ABSENT, jnp.int8)
+    for c0 in range(0, total, step):
+        t = np.zeros(step, np.int32)
+        r = np.full(step, -1, np.int32)
+        m = min(step, total - c0)
+        t[:m] = tiles[c0 : c0 + m]
+        r[:m] = row_of_tile[c0 : c0 + m]
+        plane = _impact_rows_fill(
+            plane, doc_ids, values, t, r, stride=stride
+        )
+    return ImpactRows(plane, row_of_term, len(held))
+
+
 class ImpactScorer:
     """Batched learned-sparse scoring over one segment's impact-ordered
     tiled postings with fixed launch shapes (ChunkedScorer's serving
-    recipe applied to the sparse column — see module comment)."""
+    recipe applied to the sparse column — see module comment). `rows`
+    (ImpactRows, the int8 column's hot terms) serve the terms they hold
+    through `add_rows`; without them every term goes through tiles."""
 
     def __init__(self, doc_ids, values, n_docs: int, live=None):
         self.doc_ids = jnp.asarray(doc_ids)
@@ -129,6 +289,38 @@ class ImpactScorer:
         self.values = jnp.asarray(values)
         self.n_docs = int(n_docs)
         self.live = jnp.asarray(live) if live is not None else None
+        # the builder's (executor_jax._impact_rows_build): the rows held
+        # and the count of terms that want one
+        self.rows: Optional[ImpactRows] = None
+        self.rows_wanted = 0
+
+    def row_slots(self, tids: Sequence[int]) -> np.ndarray:
+        """int32[len(tids)]: the row each query term is served from, -1
+        for a term that goes through its tiles: one without a row, or,
+        past DENSE_SLOTS hot terms, the least frequent of them (rows
+        number by df rank)."""
+        out = np.full(len(tids), -1, np.int32)
+        if self.rows is None or not len(tids):
+            return out
+        out[:] = self.rows.row_of_term[np.asarray(tids, np.int64)]
+        hot = np.flatnonzero(out >= 0)
+        if len(hot) > DENSE_SLOTS:
+            out[hot[np.argsort(out[hot], kind="stable")[DENSE_SLOTS:]]] = -1
+        return out
+
+    def add_rows(self, acc, cnt, row_lists, weight_lists):
+        """One `_impact_dense_add` launch: per query row (≤ acc rows)
+        the dense rows it names (≤ DENSE_SLOTS) and their folded tile
+        weights, into the donated accumulators."""
+        rows = int(acc.shape[0])
+        ids = np.full((rows, DENSE_SLOTS), -1, np.int32)
+        tw = np.zeros((rows, DENSE_SLOTS), np.float32)
+        for j, (rl, wl) in enumerate(zip(row_lists, weight_lists)):
+            ids[j, : len(rl)] = rl
+            tw[j, : len(rl)] = wl
+        for plane in (ids, tw):
+            note_transfer("h2d", plane.nbytes)
+        return _impact_dense_add(self.rows.plane, acc, cnt, ids, tw)
 
     def new_acc(self, rows: int = BPAD):
         """Donated accumulators at one query-row bucket of the ladder."""
@@ -199,13 +391,17 @@ class SparseBlockMax:
         tids: Sequence[int],  # query term ids present in the dictionary
         tws: Sequence[float],  # kernel tile weights (scale folded)
         bws: Optional[Sequence[float]] = None,  # bound weights (RAW)
+        dense: Optional[np.ndarray] = None,  # bool[T]: served from a row
     ):
         """`tws` multiplies the STORED plane inside the kernel, so for
         the int8 column it carries the dequant scale. The bound sidecar
         (`tile_qmax`) is already DEQUANTIZED — bounding with the folded
         weight would scale twice and prune tiles that still hold
         competitive mass — so the bound math uses `bws`, the raw query
-        weights (equal to `tws` for the fp32 column)."""
+        weights (equal to `tws` for the fp32 column). A `dense` term is
+        scored whole from its row (`ImpactScorer.add_rows`): its maximum
+        stays in every other term's bound and its first tile in theta,
+        but `kept` lists none of its tiles and drops none."""
         self.starts = term_tile_start[np.asarray(tids, np.int64)].astype(
             np.int64
         )
@@ -217,6 +413,11 @@ class SparseBlockMax:
             np.asarray(bws, np.float32) if bws is not None else self.tws
         )
         self.tile_bound = tile_bound
+        self.dense = (
+            np.asarray(dense, bool)
+            if dense is not None
+            else np.zeros(len(self.starts), bool)
+        )
         # impact ordering ⇒ a term's global max bound is its first tile's
         self.term_max = (
             tile_bound[self.starts].astype(np.float32)
@@ -279,16 +480,17 @@ class SparseBlockMax:
     def kept(
         self, theta: float
     ) -> Tuple[np.ndarray, np.ndarray, int]:
-        """(tiles, weights, dropped): the FULL surviving tile list —
-        first tiles always, tail tiles filtered against `theta` — laid
-        out per term in term order, so the one device pass accumulates
-        each doc cell in pure query-term order: the fp32 serving path
-        stays bit-identical to the numpy oracle whether or not pruning
+        """(tiles, weights, dropped): the FULL surviving tile list of
+        the terms not served from a row — first tiles always, tail
+        tiles filtered against `theta` — laid out per term in term
+        order, so the one device pass accumulates each doc cell in pure
+        query-term order: the fp32 serving path (no rows) stays
+        bit-identical to the numpy oracle whether or not pruning
         dropped anything."""
         tiles: List[np.ndarray] = []
         weights: List[np.ndarray] = []
         dropped = 0
-        for i in range(len(self.starts)):
+        for i in np.flatnonzero(~self.dense):
             c = int(self.counts[i])
             rng = np.arange(
                 self.starts[i], self.starts[i] + c, dtype=np.int64
@@ -316,9 +518,10 @@ class SparseBlockMax:
 
     @property
     def n_tail_tiles(self) -> int:
-        """Tiles beyond each term's first — zero means there is nothing
-        a threshold could drop, and none is computed."""
-        return int(np.maximum(self.counts - 1, 0).sum())
+        """Tiles beyond the first of each term that goes through tiles
+        — zero means there is nothing a threshold could drop, and none
+        is computed."""
+        return int(np.maximum(self.counts[~self.dense] - 1, 0).sum())
 
 
 def impact_tile_lists(

@@ -636,6 +636,13 @@ class RestActions:
             "worker_compile_ms": 0.0,
             "worker_compiles": 0,
         }
+        # the `sparse` block's gauges over every shard executor's loaded
+        # int8 impact columns (JaxExecutor.impact_rows_stats): hot terms
+        # that want a dense row, those that hold one, the rows' bytes
+        impact_rows = {
+            "dense_rows_wanted": 0, "dense_rows_held": 0,
+            "dense_rows_bytes": 0,
+        }
         mesh_stats = {
             "routed": 0, "launches": 0, "jobs": 0, "rebuilds": 0,
             "degraded": 0, "fallbacks": 0,
@@ -694,10 +701,12 @@ class RestActions:
                 batching["worker_compile_ms"] += bs["worker_compile_ms"]
                 batching["worker_compiles"] += bs["worker_compiles"]
             for _gen, ex in list(getattr(idx, "_executors", {}).values()):
-                rows = getattr(ex, "dense_rows_stats", None)
-                if rows is not None:
-                    for k, v in rows().items():
-                        batching[k] += v
+                for rows, into in (("dense_rows_stats", batching),
+                                   ("impact_rows_stats", impact_rows)):
+                    rows = getattr(ex, rows, None)
+                    if rows is not None:
+                        for k, v in rows().items():
+                            into[k] += v
             mex = getattr(idx, "_mesh", None)
             if mex is not None:
                 for k in mesh_stats:
@@ -772,6 +781,7 @@ class RestActions:
             for idx in self.cluster.indices.values()
             if getattr(idx, "_batcher", None) is not None
         )
+        sparse_block.update(impact_rows)
         # write-path durability counters (index/translog.py): live
         # uncommitted WAL state aggregated over local shards, plus the
         # process-wide hygiene/recovery counters (torn tails truncated,

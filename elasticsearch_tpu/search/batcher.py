@@ -280,7 +280,15 @@ class SparsePlan:
     Query weights arrive boost-folded (float32, exactly as the host
     oracle folds them) and term-sorted — the canonical accumulation
     order both paths share, which is what keeps the fp32 device path
-    bit-equal to the oracle. `spec` (search/sparse.SparseSpec) rides
+    bit-equal to the oracle: the float32 column scores every term
+    through its tiles, in term order. The int8 column's hot terms hold
+    a dense row of their stored impacts on the device (one int8 a
+    document, -128 where the term has no posting; a term wants one from
+    df >= max(1024, n / 128) on the segment, held by df rank inside the
+    text family's row budget): a document's score is then its rows'
+    products first, in term order, then its tiles' in term order — the
+    same float32 addends, within (T - 1) x 2^-24 of any other order.
+    `spec` (search/sparse.SparseSpec) rides
     the group key, so int8 and fp32 servings never share a launch.
     `tth_cap` is MatchPlan's: what `hits.total` has to be exact up to
     (None: exact whatever it is; 0: not tracked; N: up to N, the
@@ -696,7 +704,9 @@ class _Group:
         # (most tile slots a job and field used) and, of a serve group,
         # `fields` and `hot_slots` (most dense rows a job and field used)
         # of a sparse group also `terms`, `tiles_scored`, `tiles_pruned`,
-        # `chunk_launches` (sums over its jobs and segments), `quantized`
+        # `chunk_launches`, `dense_rows`, `tiles_dense` (sums over its
+        # jobs and segments: row slots used, the tiles they stand for),
+        # `quantized`
         self.plan_tags: Dict[str, int] = {}
         # (name, start_ns, end_ns, tags): spans inside `dispatch`, its
         # children (`sparse_theta`: the host's threshold from the query
@@ -2438,7 +2448,11 @@ class QueryBatcher:
         (`SparseBlockMax.host_theta`; the `sparse_theta` span, a child
         of `dispatch`), then the surviving block-max tile lists score
         in ONE device pass whose finalize triple stays ON DEVICE until
-        collect: the dispatch never waits for the device.
+        collect: the dispatch never waits for the device. Where the
+        scorer holds dense rows (the int8 column's hot terms,
+        ops/impact.ImpactRows), a query's hot terms are added from them
+        by one launch BEFORE the host computes theta, and only its
+        other terms go through tile lists.
 
         `hits.total` follows Elasticsearch's rule whatever was dropped
         (exact up to `track_total_hits`, then a `gte` bound): matches
@@ -2503,13 +2517,24 @@ class QueryBatcher:
             bound = sfh.tile_qmax if spec.quantized else sfh.tile_max
             bms = []
             theta_jobs = []  # jobs a threshold can drop tiles of
+            row_lists: List[np.ndarray] = []
+            row_weights: List[np.ndarray] = []
+            tiles_dense = 0
             for ji, j in enumerate(jobs):
-                tids, tws, bws, _, _ = impact_ops.impact_tile_lists(
+                tids, tws, bws, _, counts = impact_ops.impact_tile_lists(
                     sfh, j.plan.terms, j.plan.weights, spec.quantized
                 )
+                # the terms that hold a dense row (the int8 column's hot
+                # terms) are scored whole from it and leave the tile
+                # lists; the others keep their tiles
+                slots = sc.row_slots(tids)
+                hot = slots >= 0
+                row_lists.append(slots[hot])
+                row_weights.append(tws[hot])
+                tiles_dense += int(counts[hot].sum())
                 bm = impact_ops.SparseBlockMax(
                     sfh.term_tile_start, sfh.term_tile_count,
-                    bound, tids, tws, bws,
+                    bound, tids, tws, bws, dense=hot,
                 )
                 bms.append(bm)
                 # block-max upper bounds assume non-negative tile
@@ -2517,6 +2542,15 @@ class QueryBatcher:
                 # but unpruned
                 if may_drop[ji] and (tws >= 0).all() and bm.n_tail_tiles:
                     theta_jobs.append(ji)
+            acc, cnt = sc.new_acc(rows)
+            dense_rows = sum(len(r) for r in row_lists)
+            # launched FIRST: it needs nothing of theta or `kept`, so
+            # the device adds rows while the host computes both; a warm
+            # launch (`record` False) compiles it whatever its dummy holds
+            dense_launch = sc.rows is not None and (
+                dense_rows > 0 or not record)
+            if dense_launch:
+                acc, cnt = sc.add_rows(acc, cnt, row_lists, row_weights)
             thetas = np.full(len(jobs), -np.inf, np.float32)
             if theta_jobs:
                 t_theta = time.perf_counter_ns()
@@ -2544,7 +2578,11 @@ class QueryBatcher:
                 pruned_flags[ji] = dropped > 0
                 tiles_scored += len(t)
                 tiles_pruned += dropped
-            acc, cnt = sc.new_acc(rows)
+            if not record and not tiles_scored:
+                # a warm launch whose dummy is all rows compiles the
+                # tile pass too: one tile at weight 0, answer discarded
+                tile_lists[0] = np.zeros(1, np.int64)
+                weight_lists[0] = np.zeros(1, np.float32)
             acc, cnt = sc.score_into(acc, cnt, tile_lists, weight_lists)
             launches = impact_ops.chunk_launches(tile_lists)
             pend = sc.finalize_device(acc, cnt, kb)
@@ -2552,14 +2590,19 @@ class QueryBatcher:
                 sparse_mod.note_search(
                     nj, spec.quantized, tiles_scored, tiles_pruned,
                     chunk_launches=launches, theta_host=len(theta_jobs),
+                    dense_rows=dense_rows, tiles_dense=tiles_dense,
+                    dense_launches=int(dense_launch),
                 )
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["sparse_jobs"] += nj
-                _group_now().add_flops(impact_ops.sparse_flops(tiles_scored))
+                _group_now().add_flops(impact_ops.sparse_flops(
+                    tiles_scored, dense_rows * sc.n_docs))
                 for name, n in (("tiles_scored", tiles_scored),
                                 ("tiles_pruned", tiles_pruned),
-                                ("chunk_launches", launches)):
+                                ("chunk_launches", launches),
+                                ("dense_rows", dense_rows),
+                                ("tiles_dense", tiles_dense)):
                     tags[name] = tags.get(name, 0) + n
             items.append(("dev", si, (pend, pruned_flags)))
         return items

@@ -57,6 +57,26 @@ FUSED_MIN_DOCS = 100_000
 DENSE_ROWS_HBM_BUDGET = 1024 * 1024 * 1024
 
 
+def dense_row_min_df(n_docs: int) -> int:
+    """The df from which a term of a segment of `n_docs` WANTS a dense
+    per-document row: the text fields' hot terms (`_fused_parts_build`)
+    and a `sparse_vector` int8 column's (`impact_scorer`) alike."""
+    return max(1024, n_docs // 128)
+
+
+def dense_rows_room(row_bytes: int) -> int:
+    """Rows of `row_bytes` the budget holds for one field of one
+    segment: the static per-field cap AND the live global ledger — when
+    HBM is tight the terms past it stay on their tiles (an optimization
+    lost, not correctness); the caller counts it (`note_degraded`)."""
+    from ..common.memory import hbm_ledger
+
+    headroom = max(0, hbm_ledger.budget - hbm_ledger.used)
+    return min(
+        DENSE_ROWS_HBM_BUDGET // row_bytes, headroom // (row_bytes + 1)
+    )
+
+
 class DevicePostings:
     """A field's postings tiles on the device: the doc-id plane at
     once, the tf plane at its first use. A filter reads ids alone (the
@@ -1184,19 +1204,13 @@ class JaxExecutor:
         np.maximum.at(term_max_tf, term_of_tile, pf.tile_max_tf[tile_of])
         df = pf.term_df.astype(np.int64)
         wanted = np.nonzero(
-            (df >= max(1024, n // 128)) & (term_max_tf <= scoring.WIDE_TF_MAX)
+            (df >= dense_row_min_df(n)) & (term_max_tf <= scoring.WIDE_TF_MAX)
         )[0]
         from ..common.memory import hbm_ledger
 
-        # HBM budget for dense rows: the static per-field cap AND the
-        # live global ledger — when HBM is tight the fused path degrades
-        # to sparse tiles (an optimization lost, not correctness) and
-        # counts it
-        headroom = max(0, hbm_ledger.budget - hbm_ledger.used)
-        max_rows = min(DENSE_ROWS_HBM_BUDGET // n, headroom // (n + 1))
         by_df = wanted[np.argsort(-df[wanted], kind="stable")]
         is_wide = term_max_tf[by_df] > scoring.DENSE_TF_MAX
-        held = by_df[np.cumsum(1 + is_wide) <= max_rows]
+        held = by_df[np.cumsum(1 + is_wide) <= dense_rows_room(n)]
         if len(held) < len(wanted):
             hbm_ledger.note_degraded()
         planes = []
@@ -2026,7 +2040,10 @@ class JaxExecutor:
         the segment has no such column or the upload would not fit the
         HBM ledger (degrade to the host dense oracle, never trip).
         Charged to the `impacts` category and cached per executor
-        generation, exactly like the agg tables and IVF indexes."""
+        generation, exactly like the agg tables and IVF indexes. The
+        int8 column's scorer also holds its hot terms' dense rows
+        (`_impact_rows_build`); the float32 column's none, so its
+        answers stay the oracle's bit for bit."""
         key = ("sparse", si, field, bool(quantized))
         if key in self._impact_scorers:
             return self._impact_scorers[key]
@@ -2052,6 +2069,8 @@ class JaxExecutor:
                         self.reader.live_docs[si],
                     )
                     self._charge("impacts", est, False)
+                    if quantized:
+                        sc.rows = self._impact_rows_build(sc, sf, seg.num_docs)
                     from ..search import sparse as sparse_mod
 
                     # compression headline: the value plane actually
@@ -2064,6 +2083,46 @@ class JaxExecutor:
                     )
             self._impact_scorers[key] = sc
             return sc
+
+    def _impact_rows_build(self, sc, sf, n: int):
+        """The dense rows of an int8 impact column's hot terms
+        (ops/impact.ImpactRows), or None where no term wants one. The
+        text family's choice (`_fused_parts_build`) read from this
+        column: a term WANTS a row from df >= dense_row_min_df(n) on the
+        segment; rows are HELD by df rank while `dense_rows_room` lasts,
+        charged to the ledger's `dense_rows`; a wanted term without a
+        row keeps its tiles and is counted (`note_degraded`, the
+        `sparse.dense_rows_*` gauges)."""
+        from ..common.memory import hbm_ledger
+        from ..ops import impact as impact_ops
+
+        df = sf.term_df.astype(np.int64)
+        wanted = np.flatnonzero(df >= dense_row_min_df(n))
+        by_df = wanted[np.argsort(-df[wanted], kind="stable")]
+        held = by_df[: dense_rows_room(impact_ops.impact_row_stride(n))]
+        if len(held) < len(wanted):
+            hbm_ledger.note_degraded()
+        sc.rows_wanted = int(len(wanted))
+        if not len(held):
+            return None
+        rows = impact_ops.build_impact_rows(
+            sc.doc_ids, sc.values, sf.term_tile_start, sf.term_tile_count,
+            held, n,
+        )
+        self._charge("dense_rows", rows.nbytes, False)
+        return rows
+
+    def impact_rows_stats(self) -> Dict[str, int]:
+        """Over the int8 impact columns loaded: terms that want a dense
+        row, terms that hold one, and the rows' device bytes."""
+        scs = [sc for sc in list(self._impact_scorers.values())
+               if sc is not None]
+        held = [sc.rows for sc in scs if sc.rows is not None]
+        return {
+            "dense_rows_wanted": sum(sc.rows_wanted for sc in scs),
+            "dense_rows_held": sum(r.n_rows for r in held),
+            "dense_rows_bytes": sum(r.nbytes for r in held),
+        }
 
     # ---- second-stage rerank column (flat rank_vectors gather arrays) ----
 

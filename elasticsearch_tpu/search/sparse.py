@@ -63,6 +63,16 @@ SPARSE_STATS = {
     "tiles_pruned": 0,  # Σ tail tiles dropped by block-max bounds
     "pruned_searches": 0,  # scorings where at least one tile dropped
     "chunk_launches": 0,  # Σ `_impact_chunk_add` launches
+    # hot terms of an int8 column hold a dense row on the device (one
+    # int8 a document, -128 = no posting; ops/impact.ImpactRows: a term
+    # wants one from df >= max(1024, n / 128) on the segment, rows are
+    # held by df rank inside the text family's HBM budget). A scoring
+    # adds its query's rows first (`_impact_dense_add`), then the other
+    # terms' tiles in term order; `tiles_scored` / `chunk_launches`
+    # above count the tile pass alone.
+    "dense_rows_scored": 0,  # Σ row slots the scorings used
+    "tiles_dense": 0,  # Σ tiles those terms' postings would have been
+    "dense_launches": 0,  # Σ `_impact_dense_add` launches
     "theta_host": 0,  # thetas the host computed (a prunable job × segment)
     # bytes of the impact VALUE planes actually uploaded vs what the
     # same planes would cost at fp32 — the headline int8 compression
@@ -82,13 +92,16 @@ def note(key: str, n: int = 1) -> None:
 def note_search(
     jobs: int, quantized: bool, tiles_scored: int, tiles_pruned: int,
     chunk_launches: int = 0, theta_host: int = 0,
+    dense_rows: int = 0, tiles_dense: int = 0, dense_launches: int = 0,
 ) -> None:
-    """One impact-tile scoring of `jobs` queries against one segment:
-    `tiles_scored` and `chunk_launches` are its one device pass's,
+    """One impact scoring of `jobs` queries against one segment:
+    `tiles_scored` and `chunk_launches` are its tile pass's,
     `theta_host` the jobs among them whose threshold the host computed
     beforehand from the query terms' first tiles (how often the
     block-max mechanism engages; `tiles_pruned` says how often it
-    pays)."""
+    pays); `dense_rows` the row slots its row pass used, `tiles_dense`
+    the tiles those terms hold (what the rows took off the tile pass),
+    `dense_launches` that pass's launches (how often rows engage)."""
     with _STATS_LOCK:
         SPARSE_STATS["searches"] += jobs
         if quantized:
@@ -99,6 +112,9 @@ def note_search(
             SPARSE_STATS["pruned_searches"] += jobs
         SPARSE_STATS["chunk_launches"] += chunk_launches
         SPARSE_STATS["theta_host"] += theta_host
+        SPARSE_STATS["dense_rows_scored"] += dense_rows
+        SPARSE_STATS["tiles_dense"] += tiles_dense
+        SPARSE_STATS["dense_launches"] += dense_launches
 
 
 def stats_snapshot() -> dict:

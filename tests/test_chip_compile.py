@@ -452,6 +452,42 @@ def test_impact_scorer_chunk(one_chip):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("rows", [1, 32])
+def test_impact_dense_rows(one_chip, rows):
+    """The row pass of the same deployment (`_impact_dense_add`) at the
+    one-row bucket and the ladder's top, over the rows the 1 GiB budget
+    holds at 1M passages, and the launch that builds them from the
+    resident tile planes (`_impact_rows_fill`): temporaries of a few
+    tens of MB, not a second plane."""
+    from elasticsearch_tpu.ops import impact
+
+    n_tiles = 1_005_620
+    stride = impact.impact_row_stride(N_DOCS)
+    held = (1 << 30) // stride
+    assert stride % impact.ROW_ALIGN == 0 and held == 1069
+    s = _on(one_chip)
+    compiled = impact._impact_dense_add.lower(
+        s((held * stride,), jnp.int8),
+        s((rows, N_DOCS + 1), jnp.float32),
+        s((rows, N_DOCS + 1), jnp.int32),
+        s((rows, impact.DENSE_SLOTS), jnp.int32),
+        s((rows, impact.DENSE_SLOTS), jnp.float32),
+    ).compile()
+    _fits(compiled)
+    if rows > 1:
+        return
+    fill = impact._impact_rows_fill.lower(
+        s((held * stride,), jnp.int8),
+        s((n_tiles, TILE), jnp.int32),
+        s((n_tiles, TILE), jnp.int8),
+        s((impact.ROWS_FILL_TILES,), jnp.int32),
+        s((impact.ROWS_FILL_TILES,), jnp.int32),
+        stride=stride,
+    ).compile()
+    _fits(fill)
+    assert fill.memory_analysis().temp_size_in_bytes < 256 * 1024 * 1024
+
+
 def test_maxsim_rescore(one_chip):
     from elasticsearch_tpu.ops import rerank
 

@@ -17,6 +17,10 @@ Contract under test (the sparse-retrieval tentpole):
   * every device-path failure (injected `sparse.score` fault, HBM
     budget breach) deterministically falls back to the dense host
     oracle — same answer, counters bumped;
+  * hot terms of the int8 column (df >= max(1024, n / 128), held by df
+    rank inside the text family's row budget) are scored from dense
+    int8 rows: rows + tiles answer what tiles alone answer, whatever a
+    launch holds, and the float32 column builds none;
   * the mesh SPMD path is bit-identical to the per-shard path in both
     storage modes;
   * `sparse_vector` fuses as a third `rrf` retriever leg beside BM25
@@ -26,6 +30,7 @@ Contract under test (the sparse-retrieval tentpole):
     compression headline.
 """
 
+import functools
 import os
 import time
 
@@ -514,6 +519,305 @@ class TestDegradedPaths:
         finally:
             faults.clear()
             jx.close()
+            nps.close()
+
+
+# ---------------------------------------------------------------------------
+# dense rows: the int8 column's hot terms leave the scatter
+# ---------------------------------------------------------------------------
+
+HOT = [f"hot{i}" for i in range(5)]  # df 1,230-2,400 of 3,000: want a row
+COLD = [f"cold{i}" for i in range(8)]  # df 90-700: several tiles, no row
+ROW_DOCS = 3000
+
+
+@functools.lru_cache(maxsize=None)
+def row_docs(seed=41):
+    """3,000 docs over five tokens frequent enough to want a row and
+    eight that are not; `hot0` has a posting whose stored int8 impact
+    is 0 (doc 7: 0.005 beside doc 3's 3.5, the term's scale x 127)."""
+    rng = np.random.default_rng(seed)
+    p_hot = [0.8, 0.65, 0.55, 0.47, 0.41]
+    p_cold = [0.23, 0.17, 0.12, 0.1, 0.08, 0.06, 0.04, 0.03]
+    out = []
+    for i in range(ROW_DOCS):
+        vec = {}
+        for t, p in zip(HOT + COLD, p_hot + p_cold):
+            if rng.random() < p:
+                vec[t] = float(np.round(rng.random() * 3 + 0.05, 4))
+        if i == 3:
+            vec["hot0"] = 3.5
+        if i == 7:
+            vec["hot0"] = 0.005
+        if not vec:
+            vec["cold0"] = 0.5
+        out.append((str(i), {"ml": vec}))
+    return out
+
+
+def row_body(tokens, seed, size=10, **extra):
+    rng = np.random.default_rng(seed)
+    qv = {t: float(np.round(rng.random() * 2 + 0.1, 4)) for t in tokens}
+    return {"query": {"sparse_vector": {"field": "ml", "query_vector": qv}},
+            "size": size, **extra}
+
+
+ROW_QUERIES = {
+    "all_hot": [HOT[0], HOT[2], HOT[4]],
+    "none_hot": [COLD[0], COLD[3], COLD[7]],
+    "mixed": [HOT[1], HOT[3], COLD[1], COLD[2], COLD[6], "absent"],
+    "zero_q": [HOT[0]],
+}
+
+
+def run_group(svc, bodies, rows):
+    """One launch group of `bodies` at a `rows`-wide bucket, driven by
+    hand through the sparse family's dispatch / collect pair."""
+    from elasticsearch_tpu.search import batcher as batcher_mod
+    from elasticsearch_tpu.search import dsl
+
+    ex = svc._executor(svc.shards[0])
+    jobs = []
+    for body in bodies:
+        q = dsl.parse_query(body["query"])
+        q.sparse = sparse_mod.resolve(svc.settings, False)
+        plan = batcher_mod.extract_sparse_plan(
+            q, svc.mappings, body.get("track_total_hits", 10_000))
+        jobs.append(batcher_mod._Job(ex, plan, body["size"], kind="sparse",
+                                     query=q))
+    b = svc._batcher
+    b._collect_sparse_group(jobs, 16,
+                            b._dispatch_sparse_group(jobs, 16, rows=rows))
+    return [(j.result.total, j.result.relation,
+             [(h.doc_id, h.score) for h in j.result.hits]) for j in jobs]
+
+
+def same_answers(got, want, rtol=1e-6):
+    assert len(got) == len(want)
+    for (gt, gr, gh), (wt, wr, wh) in zip(got, want):
+        assert (gt, gr) == (wt, wr)
+        assert [d for d, _ in gh] == [d for d, _ in wh]
+        for (_, a), (_, b) in zip(gh, wh):
+            assert abs(a - b) <= rtol * abs(b), (a, b)
+
+
+class TestDenseRows:
+    @pytest.fixture(scope="class")
+    def pair(self):
+        """The same docs twice: `rows` as the node builds the scorer,
+        `tiles` with the scorer built under a zero row budget (the
+        test's own scorer: no setting switches rows off)."""
+        from elasticsearch_tpu.search import executor_jax
+
+        docs = row_docs()
+        rows = make_service("sp-rows", docs=docs)
+        tiles = make_service("sp-rows-off", docs=docs)
+        for svc in (rows, tiles):
+            for i in (11, 500, 2999):  # deleted docs, one in every tile range
+                svc.delete_doc(str(i))
+            svc.refresh()
+        budget = executor_jax.DENSE_ROWS_HBM_BUDGET
+        executor_jax.DENSE_ROWS_HBM_BUDGET = 0
+        try:
+            tiles.search(row_body(ROW_QUERIES["mixed"], 1))
+        finally:
+            executor_jax.DENSE_ROWS_HBM_BUDGET = budget
+        rows.search(row_body(ROW_QUERIES["mixed"], 1))
+        yield rows, tiles
+        rows.close()
+        tiles.close()
+
+    @staticmethod
+    def scorer(svc):
+        return svc._executor(svc.shards[0]).impact_scorer(0, "ml", True)
+
+    def test_rows_are_the_postings_in_another_layout(self, pair):
+        rows, tiles = pair
+        assert self.scorer(tiles).rows is None
+        held = self.scorer(rows).rows
+        seg = rows.shards[0].reader().segments[0]
+        sf = seg.sparse["ml"]
+        assert held.n_rows == len(HOT)
+        plane = np.asarray(held.plane).reshape(held.n_rows, -1)
+        by_df = sorted(HOT, key=lambda t: -int(sf.term_df[sf.term_id(t)]))
+        zero_q = 0
+        for r, t in enumerate(by_df):
+            tid = sf.term_id(t)
+            assert held.row_of_term[tid] == r
+            lo = int(sf.term_tile_start[tid])
+            d = sf.doc_ids[lo : lo + int(sf.term_tile_count[tid])].ravel()
+            q = sf.qweights[lo : lo + int(sf.term_tile_count[tid])].ravel()
+            want = np.full(plane.shape[1], impact_ops.ROW_ABSENT, np.int8)
+            want[d[d >= 0]] = q[d >= 0]
+            assert np.array_equal(plane[r], want), t
+            zero_q += int((q[d >= 0] == 0).sum())
+        assert zero_q >= 1  # a present posting whose stored impact is 0
+        assert (held.row_of_term >= 0).sum() == len(HOT)
+
+    @pytest.mark.parametrize("launch_rows", [1, 2, 4])
+    @pytest.mark.parametrize("which", sorted(ROW_QUERIES))
+    def test_rows_and_tiles_answer_what_tiles_alone_answer(
+        self, pair, which, launch_rows
+    ):
+        rows, tiles = pair
+        tokens = ROW_QUERIES[which]
+        bodies = [row_body(tokens, 7 * launch_rows + j,
+                           track_total_hits=True)
+                  for j in range(launch_rows)]
+        if launch_rows > 1:  # a launch whose rows differ in what is hot
+            bodies[-1] = row_body(ROW_QUERIES["mixed"], 99,
+                                  track_total_hits=True)
+        before = dict(sparse_mod.SPARSE_STATS)
+        got = run_group(rows, bodies, launch_rows)
+        moved = {k: v - before[k] for k, v in sparse_mod.SPARSE_STATS.items()}
+        same_answers(got, run_group(tiles, bodies, launch_rows))
+        hot = sum(t in HOT for b in bodies
+                  for t in b["query"]["sparse_vector"]["query_vector"])
+        assert moved["dense_rows_scored"] == hot
+        assert moved["dense_launches"] == (hot > 0)
+        assert (moved["tiles_dense"] > 0) == (hot > 0)
+        if which == "all_hot" and launch_rows == 1:
+            assert moved["chunk_launches"] == moved["tiles_scored"] == 0
+        if which == "none_hot" and launch_rows == 1:
+            assert moved["tiles_dense"] == 0 and moved["tiles_scored"] > 0
+        # exact totals: every holder of any token, live, counted once,
+        # the holder of a zero stored impact among them
+        deleted = {"11", "500", "2999"}
+        for body, (total, relation, _hits) in zip(bodies, got):
+            qv = body["query"]["sparse_vector"]["query_vector"]
+            holders = sum(1 for i, s in row_docs()
+                          if i not in deleted and set(s["ml"]) & set(qv))
+            assert (total, relation) == (holders, "eq")
+
+    def test_zero_stored_impact_matches_and_counts(self, pair):
+        rows, tiles = pair
+        body = row_body([HOT[0]], 5, size=ROW_DOCS, track_total_hits=True)
+        served = rows.search(dict(body))
+        ids = {h["_id"]: h["_score"] for h in served["hits"]["hits"]}
+        assert ids["7"] == 0.0  # present, stored impact 0: a hit of score 0
+        assert served["hits"]["total"]["value"] == len(ids)
+        assert hits_of(served) == hits_of(tiles.search(dict(body)))
+
+    def test_over_http_shaped_search_uses_rows(self, pair):
+        rows, tiles = pair
+        body = row_body(ROW_QUERIES["mixed"], 3)
+        before = dict(sparse_mod.SPARSE_STATS)
+        a = rows.search(dict(body))
+        moved = {k: v - before[k] for k, v in sparse_mod.SPARSE_STATS.items()}
+        b = tiles.search(dict(body))
+        assert a["hits"]["total"] == b["hits"]["total"]
+        assert [h["_id"] for h in a["hits"]["hits"]] == [
+            h["_id"] for h in b["hits"]["hits"]]
+        assert moved["dense_rows_scored"] == 2 and moved["dense_launches"] == 1
+
+    def test_hot_terms_past_the_slots_go_through_tiles(self, pair, monkeypatch):
+        """DENSE_SLOTS bounds a query row's rows: the least frequent of
+        its hot terms overflow to their tiles, with equal answers."""
+        rows, tiles = pair
+        monkeypatch.setattr(impact_ops, "DENSE_SLOTS", 2)
+        sc = self.scorer(rows)
+        sf = rows.shards[0].reader().segments[0].sparse["ml"]
+        slots = sc.row_slots([sf.term_id(t) for t in HOT])
+        assert sorted(slots[slots >= 0]) == [0, 1]  # the two of most df
+        bodies = [row_body(HOT + COLD[:2], 13, track_total_hits=True)]
+        before = dict(sparse_mod.SPARSE_STATS)
+        got = run_group(rows, bodies, 1)
+        assert (sparse_mod.SPARSE_STATS["dense_rows_scored"]
+                - before["dense_rows_scored"]) == 2
+        same_answers(got, run_group(tiles, bodies, 1))
+
+
+class TestDenseRowBudget:
+    def _service(self, name, quant="int8"):
+        return make_service(name, quant=quant, docs=row_docs())
+
+    @pytest.mark.parametrize("limit", ["budget", "headroom"])
+    def test_rows_are_held_by_df_rank_inside_the_budget(self, limit,
+                                                        monkeypatch):
+        from elasticsearch_tpu.common.memory import hbm_ledger
+        from elasticsearch_tpu.search import executor_jax
+
+        full = self._service(f"sp-budget-full-{limit}")
+        part = self._service(f"sp-budget-part-{limit}")
+        try:
+            row = impact_ops.impact_row_stride(ROW_DOCS)
+            k = 2
+            want = full.search(row_body(ROW_QUERIES["mixed"], 1))
+            used0 = hbm_ledger.stats()["by_category"].get("dense_rows", 0)
+            d0 = hbm_ledger.stats()["degraded_allocations"]
+            ex = part._executor(part.shards[0])
+            sf = part.shards[0].reader().segments[0].sparse["ml"]
+            tiles_bytes = int(sf.doc_ids.nbytes + sf.qweights.nbytes)
+            if limit == "budget":
+                monkeypatch.setattr(executor_jax, "DENSE_ROWS_HBM_BUDGET",
+                                    k * row + row // 2)
+            else:
+                # headroom left once the tile planes are charged: k rows
+                monkeypatch.setattr(
+                    hbm_ledger, "budget",
+                    hbm_ledger.used + tiles_bytes + k * (row + 1) + row // 2)
+            sc = ex.impact_scorer(0, "ml", True)
+            monkeypatch.undo()
+            assert sc.rows.n_rows == k and sc.rows_wanted == len(HOT)
+            by_df = sorted(HOT, key=lambda t: -int(sf.term_df[sf.term_id(t)]))
+            held = {t for t in HOT if sc.rows.row_of_term[sf.term_id(t)] >= 0}
+            assert held == set(by_df[:k])  # exactly the k most frequent
+            assert hbm_ledger.stats()["degraded_allocations"] == d0 + 1
+            assert (hbm_ledger.stats()["by_category"]["dense_rows"]
+                    == used0 + k * row == used0 + sc.rows.nbytes)
+            assert ex.impact_rows_stats() == {
+                "dense_rows_wanted": len(HOT), "dense_rows_held": k,
+                "dense_rows_bytes": k * row}
+            # the rest are served through tiles, with equal answers
+            got = part.search(row_body(ROW_QUERIES["mixed"], 1))
+            same_answers([(0, "", hits_of(got))], [(0, "", hits_of(want))])
+            assert got["hits"]["total"] == want["hits"]["total"]
+            # released with the scorer's generation
+            part.close()
+            assert (hbm_ledger.stats()["by_category"].get("dense_rows", 0)
+                    == used0)
+        finally:
+            full.close()
+            part.close()
+
+    def test_float32_column_builds_no_row(self):
+        from elasticsearch_tpu.common.memory import hbm_ledger
+
+        svc = self._service("sp-f32-norows", quant="none")
+        nps = make_service("sp-f32-norows-np", backend="numpy", quant="none",
+                           docs=row_docs())
+        try:
+            used0 = hbm_ledger.stats()["by_category"].get("dense_rows", 0)
+            body = row_body(ROW_QUERIES["mixed"], 2)
+            before = dict(sparse_mod.SPARSE_STATS)
+            got = svc.search(dict(body))
+            ex = svc._executor(svc.shards[0])
+            assert ex.impact_scorer(0, "ml", False).rows is None
+            assert ex.impact_rows_stats()["dense_rows_held"] == 0
+            assert (hbm_ledger.stats()["by_category"].get("dense_rows", 0)
+                    == used0)
+            assert (sparse_mod.SPARSE_STATS["dense_launches"]
+                    == before["dense_launches"])
+            # and stays bit-equal to the oracle
+            assert hits_of(got) == hits_of(nps.search(dict(body)))
+        finally:
+            svc.close()
+            nps.close()
+
+    def test_exact_escape_on_an_int8_index_stays_on_tiles(self):
+        svc = self._service("sp-exact-norows")
+        nps = make_service("sp-exact-norows-np", backend="numpy",
+                           quant="none", docs=row_docs())
+        try:
+            body = row_body(ROW_QUERIES["mixed"], 4)
+            body["exact"] = True
+            before = dict(sparse_mod.SPARSE_STATS)
+            got = svc.search(dict(body))
+            assert (sparse_mod.SPARSE_STATS["dense_launches"]
+                    == before["dense_launches"])
+            assert hits_of(got) == hits_of(nps.search(dict(body)))
+        finally:
+            svc.close()
             nps.close()
 
 

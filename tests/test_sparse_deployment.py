@@ -125,6 +125,18 @@ class Deployment:
         # tail tiles cannot reach the top ten, and the tiles that are
         # kept hold far fewer than 10,000 passages
         self.pruning_vector = {by_df[0]: 2.0, rare[0]: 0.05}
+        # the int8 column serves `by_df[0]` from its dense row, which
+        # never drops: there the tiles that drop are a heavy token's
+        # just under the row threshold (eight tiles), beside the most
+        # frequent one at a weight that only proves the total
+        self.cold = [t for t in by_df if self.df_of[t] < 1024]
+        self.pruning_vectors = {
+            "float32": self.pruning_vector,
+            "int8": {by_df[0]: 0.01, self.cold[0]: 2.0},
+        }
+        # tokens that all hold a row in the int8 column: no tile launch
+        self.hot_body = vector_body(
+            self.field, {t: 0.5 + 0.125 * i for i, t in enumerate(by_df[:5])})
         # a full-shape query that holds the most frequent token, as
         # nearly every query of the deployment's size holds one whose
         # postings alone pass 10,000: phase A and theta run
@@ -193,7 +205,7 @@ def test_pruned_job_answers_elasticsearchs_total(dep, storage):
     """One token alone is in more passages than `track_total_hits`
     counts to, so tiles drop; the page and the total stay the
     reference's: 10,000 and `gte`, not the count over the kept tiles."""
-    body = vector_body(dep.field, dep.pruning_vector)
+    body = vector_body(dep.field, dep.pruning_vectors[storage])
     assert dep.df_of[dep.frequent[0]] > 10_000
     before = dep.sparse_stats()
     served = dep.search(storage, body)
@@ -203,6 +215,9 @@ def test_pruned_job_answers_elasticsearchs_total(dep, storage):
     assert after["theta_host"] == before["theta_host"] + 1
     kept = 128 * (after["tiles_scored"] - before["tiles_scored"])
     assert kept < 10_000  # a count over the kept tiles would fall short
+    # the int8 column's most frequent token is counted whole by its row
+    assert (after["dense_rows_scored"] - before["dense_rows_scored"]
+            == (storage == "int8"))
     assert served["hits"]["total"] == {"value": 10_000, "relation": "gte"}
     dep.held(storage, body, served)
 
@@ -403,16 +418,19 @@ def test_host_theta_needs_a_full_page_of_live_matches(dep, storage):
         assert host <= twin <= final or not finite
 
 
-@pytest.mark.parametrize("which", ["full_shape", "long", "pruning", "rare"])
+@pytest.mark.parametrize("which", ["full_shape", "long", "pruning", "rare",
+                                   "hot"])
 def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
-    """`transfer.scoring.*` moves by exactly what the job moved: three
-    staged planes a chunk launch of the one device pass, and the packed
+    """`transfer.scoring.*` moves by exactly what the job moved: the hot
+    list's two planes where the query holds a term with a dense row,
+    three staged planes a chunk launch of the tile pass, and the packed
     collect, the job's one download (theta is the host's)."""
+    from elasticsearch_tpu.ops.impact import DENSE_SLOTS
     from elasticsearch_tpu.ops.scoring import TCHUNK
 
     body = {"full_shape": dep.proved_body, "long": dep.long_body,
-            "pruning": vector_body(dep.field, dep.pruning_vector),
-            "rare": dep.rare_body}[which]
+            "pruning": vector_body(dep.field, dep.pruning_vectors["int8"]),
+            "rare": dep.rare_body, "hot": dep.hot_body}[which]
     dep.search("int8", body)  # every program built
     s0, x0 = dep.sparse_stats(), tracing.transfer_stats()
     dep.search("int8", body)
@@ -421,11 +439,16 @@ def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
     rows = spans["dispatch"]["tags"]["rows"]
     tiles = s1["tiles_scored"] - s0["tiles_scored"]
     launches = s1["chunk_launches"] - s0["chunk_launches"]
-    assert launches == -(-tiles // TCHUNK) >= 1
-    assert s1["theta_host"] - s0["theta_host"] == (which != "rare")
-    assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches
+    dense = s1["dense_launches"] - s0["dense_launches"]
+    assert launches == -(-tiles // TCHUNK) >= (which != "hot")
+    assert dense == (which != "rare")
+    # theta is computed where a total is proved AND some tile could drop
+    assert s1["theta_host"] - s0["theta_host"] == (which not in ("rare",
+                                                                 "hot"))
+    assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches + 2 * dense
     assert (x1["h2d_bytes"] - x0["h2d_bytes"]
-            == launches * rows * TCHUNK * (4 + 4 + 1))
+            == launches * rows * TCHUNK * (4 + 4 + 1)
+            + dense * rows * DENSE_SLOTS * (4 + 4))
     assert x1["d2h_count"] - x0["d2h_count"] == 1
     assert (x1["d2h_bytes"] - x0["d2h_bytes"]
             == spans["collect"]["tags"]["d2h_bytes"])
@@ -475,6 +498,11 @@ def test_the_request_goes_the_normal_path(dep):
                                     - before["tiles_pruned"])
     assert tags["chunk_launches"] == (after["chunk_launches"]
                                       - before["chunk_launches"]) >= 1
+    assert tags["dense_rows"] == (after["dense_rows_scored"]
+                                  - before["dense_rows_scored"]) >= 1
+    assert tags["tiles_dense"] == (after["tiles_dense"]
+                                   - before["tiles_dense"]) >= 1
+    assert after["dense_launches"] == before["dense_launches"] + 1
     assert after["theta_host"] == before["theta_host"] + 1
     theta = spans["sparse_theta"]
     assert by_id[theta["parent_id"]]["name"] == "dispatch"
@@ -485,6 +513,142 @@ def test_the_request_goes_the_normal_path(dep):
             <= spans["dispatch"]["start_ns"]
             + spans["dispatch"]["duration_ns"])
     assert spans["collect"]["tags"]["merged"] is True
+
+
+def test_rows_take_the_hot_terms_off_the_tile_pass(dep):
+    """The same request under both storages: the int8 column scores its
+    hot terms from rows (`tiles_dense`, `dense_rows_scored`,
+    `dense_launches` move) and `tiles_scored` falls by what they held;
+    the float32 column moves none of the three."""
+    body = dep.proved_body
+    vector = body["query"]["sparse_vector"]["query_vector"]
+    hot = [t for t in vector if dep.df_of[t] >= 1024]
+    assert 0 < len(hot) < len(vector)
+    moved = {}
+    for storage in STORAGES:
+        before = dep.sparse_stats()
+        dep.held(storage, body, dep.search(storage, body))
+        after = dep.sparse_stats()
+        moved[storage] = {k: after[k] - before[k] for k in (
+            "tiles_scored", "tiles_pruned", "tiles_dense",
+            "dense_rows_scored", "dense_launches", "chunk_launches")}
+    f32, i8 = moved["float32"], moved["int8"]
+    assert (f32["tiles_dense"], f32["dense_rows_scored"],
+            f32["dense_launches"]) == (0, 0, 0)
+    assert i8["dense_rows_scored"] == len(hot) and i8["dense_launches"] == 1
+    assert i8["tiles_dense"] == sum(-(-dep.df_of[t] // 128) for t in hot)
+    assert i8["tiles_scored"] < f32["tiles_scored"]
+    assert (i8["tiles_scored"] + i8["tiles_pruned"] + i8["tiles_dense"]
+            == f32["tiles_scored"] + f32["tiles_pruned"])
+    assert i8["chunk_launches"] <= f32["chunk_launches"]
+    gauges = dep.sparse_stats()
+    assert 0 < gauges["dense_rows_held"] == gauges["dense_rows_wanted"] == len(
+        [t for t in dep.df_of if dep.df_of[t] >= 1024])
+    assert gauges["dense_rows_bytes"] == gauges["dense_rows_held"] * (
+        -(-(DOCS + 1) // 4096) * 4096)
+
+
+@pytest.mark.parametrize("first", ["rare", "hot"])
+def test_the_warm_up_leaves_no_program_to_build(dep, first):
+    """With the bucket ladder armed, the first request of a (field,
+    storage) compiles `_impact_dense_add` and `_impact_chunk_add` at
+    every bucket of the ladder but its own, whatever the dummy holds
+    (rare tokens alone: no row; hot tokens alone: no tile); once its
+    own bucket has seen both kinds of term, a launch of any width
+    builds nothing."""
+    from elasticsearch_tpu.ops import impact as impact_ops
+    from elasticsearch_tpu.search import batcher as batcher_mod
+
+    config = dep.configs["int8"]
+    index = f"{config['index']}-warm-{first}"
+    post(dep.port, f"/{index}", {"settings": dict(config["settings"]),
+                                 "mappings": dep.corpus["mappings"]}, "PUT")
+    svc = dep.server.cluster.indices[index]
+    place_segment(svc, dep.corpus["segment"])
+    svc._batcher.warmup_enabled = True
+    programs = (impact_ops._impact_dense_add, impact_ops._impact_chunk_add)
+    for p in programs:  # what earlier cases built at these shapes
+        p.clear_cache()
+    try:
+        post(dep.port, f"/{index}/_search",
+             {"rare": dep.rare_body, "hot": dep.hot_body}[first])
+        assert svc._batcher.wait_warm_idle()
+        # the ladder's own buckets hold both programs; the live
+        # request's bucket (one row) what that request used
+        n = len(svc._batcher.buckets)
+        assert n > 1
+        assert [p._cache_size() for p in programs] == (
+            [n - 1, n] if first == "rare" else [n, n - 1])
+        for body in (dep.proved_body, dep.hot_body, dep.long_body):
+            served = post(dep.port, f"/{index}/_search", body)
+            dep.held("int8", body, served)
+        built = [p._cache_size() for p in programs]
+        assert built == [n, n]
+        ex = svc._executor(svc.shards[0])
+        fam = batcher_mod.FAMILIES["sparse"]
+        for b in svc._batcher.buckets:
+            jobs = []
+            for body in (dep.proved_body, dep.hot_body)[:b]:
+                from elasticsearch_tpu.search import dsl
+                from elasticsearch_tpu.search import sparse as sparse_mod
+
+                q = dsl.parse_query(body["query"])
+                q.sparse = sparse_mod.resolve(svc.settings, False)
+                plan = batcher_mod.extract_sparse_plan(q, svc.mappings)
+                jobs.append(batcher_mod._Job(ex, plan, 10, kind="sparse",
+                                             query=q))
+            key = fam.share(jobs[0].plan)
+            fam.collect(svc._batcher, jobs, key, 16,
+                        fam.dispatch(svc._batcher, jobs, key, 16, b, False),
+                        False)
+        assert [p._cache_size() for p in programs] == built
+        assert svc._batcher.stats["warmup_failures"] == 0
+    finally:
+        svc._batcher.warmup_enabled = False
+
+
+def test_the_new_layer_metrics_read_a_rehearsals_observations(dep):
+    """The three metric files this family's rows add load, name readers
+    that exist, and read the node's own counters as the harness hands
+    them over (`run.py` `node_numbers` deltas; the traced window's
+    modules by the jitted program's name); from a program without the
+    counters or the kernel they read nothing and do not raise."""
+    from elasticsearch_tpu.ops import impact as impact_ops
+    from run import Http, node_numbers
+
+    http = Http(dep.port)
+    before = node_numbers(http)
+    for body in (dep.proved_body, dep.hot_body, dep.rare_body):
+        dep.search("int8", body)
+    after = node_numbers(http)
+    counts = {k: v - before[k] for k, v in after.items() if k in before}
+    module = "jit_" + impact_ops._impact_dense_add.__wrapped__.__name__
+    obs = {"counts": counts, "gauges": after,
+           "profile": {"modules": {module: (2, 0.003)}}}
+    bench = load_json("..", "BENCHMARK.json")
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    read = {}
+    for name in ("impact_dense_ms", "impact_dense_rows_per_req",
+                 "impact_dense_tiles_share"):
+        spec = load_json("layer_metrics", f"{name}.json")
+        entry = listed[name]
+        assert entry["workloads"] == ["msmarco-splade-sparse.solo"]
+        assert (spec["unit"], spec["layer"], spec["moves"]) == (
+            entry["unit"], entry["layer"], entry["moves"])
+        reader = load_plugin("readers", spec["reader"])
+        read[name] = reader.read(obs, spec["args"])
+        bare = {"counts": {"sparse.searches": 3, "sparse.tiles_scored": 9},
+                "gauges": {}, "profile": {"modules": {}}}
+        assert reader.read(bare, spec["args"]) is None
+    assert read["impact_dense_ms"] == pytest.approx(1.5)
+    vectors = [b["query"]["sparse_vector"]["query_vector"]
+               for b in (dep.proved_body, dep.hot_body, dep.rare_body)]
+    hot = sum(dep.df_of[t] >= 1024 for v in vectors for t in v)
+    assert read["impact_dense_rows_per_req"] == pytest.approx(hot / 3)
+    assert 50 < read["impact_dense_tiles_share"] < 100
+    assert read["impact_dense_tiles_share"] == pytest.approx(
+        100 * counts["sparse.tiles_dense"]
+        / (counts["sparse.tiles_dense"] + counts["sparse.tiles_scored"]))
 
 
 def test_the_builders_plan_is_the_planners(dep):
