@@ -1315,6 +1315,7 @@ def knn_scores(
     queries: jax.Array,  # float32[B, d]
     vectors: jax.Array,  # float32[N, d] (unit-normalized for cosine)
     similarity: str,
+    norms: Optional[jax.Array] = None,  # float32[N]: knn_row_norms(vectors)
 ) -> jax.Array:
     """Dense [B, N] similarity scores: one MXU matmul + the Lucene
     VectorSimilarityFunction transform (see models/similarity.py).
@@ -1322,14 +1323,19 @@ def knn_scores(
     here, inside the program: the device holds one byte an element.
     Whole numbers of 8 bits are exact in bfloat16 and every product and
     partial sum of them in float32 (192 x 128^2 < 2^24), so an MXU pass
-    at the default precision gives their exact dot products."""
+    at the default precision gives their exact dot products. `norms`
+    (l2_norm over integer rows alone) stands for the rows' `sum(v * v)`,
+    which the program then does not read the rows a second time for;
+    without it the program is the one it always was."""
     if jnp.issubdtype(vectors.dtype, jnp.integer):
         vectors = vectors.astype(jnp.float32)
     if similarity == "l2_norm":
         # ||q - v||² = |q|² + |v|² - 2 q·v — matmul-friendly
         dots = queries @ vectors.T
         q2 = jnp.sum(queries * queries, axis=1, keepdims=True)
-        v2 = jnp.sum(vectors * vectors, axis=1)[None, :]
+        if norms is None:
+            norms = jnp.sum(vectors * vectors, axis=1)
+        v2 = norms[None, :]
         d2 = jnp.maximum(q2 + v2 - 2.0 * dots, 0.0)
         scores = 1.0 / (1.0 + d2)
     else:
@@ -1480,6 +1486,73 @@ def knn_filter_mask(
     return mask, mask.sum(axis=1, dtype=jnp.int32)
 
 
+@jax.jit
+def knn_row_norms(vectors: jax.Array) -> jax.Array:
+    """float32[N] `sum(v * v)` of integer-typed stored rows, built once
+    when a field's rows are uploaded (executor_jax.DeviceSegment): the
+    plane `knn_scores` takes as `norms`. Every square is at most 128^2
+    and every sum at most d x 128^2, a whole number float32 holds for
+    d <= 1024, so the plane equals what `knn_scores` computes inside its
+    program bit for bit, in whatever order either sums. Float rows get
+    none: another program would sum theirs in another order."""
+    v = vectors.astype(jnp.float32)
+    return jnp.sum(v * v, axis=1)
+
+
+# `_block_topk` views a [B, N] plane as groups of KNN_BLOCK rows of
+# KNN_BLOCK lanes; a block is one lane of one group.
+KNN_BLOCK = 128
+
+
+def knn_block_select(n: int, k: int) -> bool:
+    """Whether `knn_topk_filtered` over `n` stored rows selects its top
+    `k` from block maxima: from 8 x k whole blocks on. Below that the
+    k chosen blocks are an eighth of the plane or more and the plain
+    `top_k` stays (a segment of 20,000 rows gains nothing at k = 128).
+    Read from the shapes at trace time; the batcher counts launches by
+    it."""
+    return n // (KNN_BLOCK * KNN_BLOCK) * KNN_BLOCK >= 8 * k
+
+
+def _block_topk(masked: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """`lax.top_k(masked, k)` over a wide [B, N] plane without sorting
+    it. The plane's first columns are viewed as [groups, G, L] (G = L =
+    KNN_BLOCK): a block is the G columns of one lane of one group (a
+    stride of L apart), its maximum an elementwise maximum over the
+    group's G rows: no reduction across lanes, and the view compiles in
+    seconds at every row bucket (blocks of L consecutive columns cost
+    the same on the device and 25-45 s of compile from two rows on:
+    PERF.md section 6, PR 42). Theta is the k-th largest block maximum;
+    the top k are selected among the k x G scores of the k blocks of
+    the largest maxima (their groups gathered whole, G x L contiguous
+    floats each, the block's lane picked out after) and the columns
+    past the last whole group. Exact: the k chosen maxima are k scores
+    >= theta, so the k-th best score is >= theta, and a block whose
+    maximum is > theta is among the chosen; a score EQUAL to theta in a
+    block not chosen ties the k-th, which `top_k` cuts either way too.
+    Sorted by score descending; -inf (and an arbitrary column) where
+    fewer than k columns hold a score."""
+    B, n = masked.shape
+    G = L = KNN_BLOCK
+    groups = n // (G * L)
+    m = groups * G * L
+    body = masked[:, :m].reshape(B, groups, G, L)
+    _, chosen = jax.lax.top_k(body.max(axis=2).reshape(B, groups * L), k)
+    group, lane = chosen // L, (chosen % L)[:, :, None]  # [B, k], [B, k, 1]
+    slabs = jnp.take_along_axis(body, group[:, :, None, None], axis=1)
+    in_lane = jnp.arange(L, dtype=jnp.int32) == lane[:, :, :, None]
+    cand = jnp.max(jnp.where(in_lane, slabs, -jnp.inf), axis=3)  # [B, k, G]
+    rows = group[:, :, None] * G + jnp.arange(G, dtype=jnp.int32)
+    cand, cols = (x.reshape(B, k * G) for x in (cand, rows * L + lane))
+    if m < n:
+        tail = jnp.arange(m, n, dtype=jnp.int32)
+        cand = jnp.concatenate([cand, masked[:, m:]], axis=1)
+        cols = jnp.concatenate(
+            [cols, jnp.broadcast_to(tail, (B, n - m))], axis=1)
+    s, pos = jax.lax.top_k(cand, k)
+    return s, jnp.take_along_axis(cols, pos, axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("similarity", "k"))
 def knn_topk_filtered(
     queries: jax.Array,  # float32[B, d] (padded rows are zeros)
@@ -1487,12 +1560,20 @@ def knn_topk_filtered(
     mask: jax.Array,  # bool[B, N]: each row's own candidates
     similarity: str,
     k: int,
+    norms: Optional[jax.Array] = None,  # float32[N], see knn_scores
 ) -> Tuple[jax.Array, jax.Array]:
     """`knn_topk_batch` where every query row brings a candidate mask of
     its own (`knn_filter_mask`): every row of `vectors` is scored, the
-    rows a filter passes compete. (scores[B, k], docs[B, k])."""
-    scores = knn_scores(queries, vectors, similarity)
-    return jax.lax.top_k(jnp.where(mask, scores, -jnp.inf), k)
+    rows a filter passes compete. (scores[B, k], docs[B, k]). The
+    program does only what depends on the query, in one pass over the
+    rows: their norms come as an operand where the field holds integers,
+    and a plane wide enough (`knn_block_select`) is never sorted: its top
+    k are selected from block maxima (`_block_topk`)."""
+    scores = knn_scores(queries, vectors, similarity, norms)
+    masked = jnp.where(mask, scores, -jnp.inf)
+    if knn_block_select(masked.shape[1], k):
+        return _block_topk(masked, k)
+    return jax.lax.top_k(masked, k)
 
 
 @functools.partial(jax.jit, static_argnames=("similarity", "k"))

@@ -659,10 +659,12 @@ class RestActions:
         # the knn family's filtered groups (QueryBatcher.knn_filtered):
         # scans under a mask of the job's own, rows scored and rows the
         # filters passed, postings tiles the mask launches scattered,
-        # and the scans that fell back to the unbatched executor
+        # the launches whose scan selected from block maxima, and the
+        # scans that fell back to the unbatched executor
         knn_filtered = {
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
-            "filter_tiles": 0, "mask_launches": 0, "fallbacks": 0,
+            "filter_tiles": 0, "mask_launches": 0,
+            "block_select_launches": 0, "fallbacks": 0,
         }
         for idx in self.cluster.indices.values():
             with idx._rrf_lock:

@@ -1115,11 +1115,14 @@ class QueryBatcher:
         # a mask the device built, the rows scored and the rows the
         # filters passed (counted on the device, read at collect; both
         # count a fallback's rows too), the postings tiles the mask
-        # launches scattered, those launches, and the (job x segment)
-        # scans that left the planned path for the unbatched executor
+        # launches scattered, those launches, those of them whose scan
+        # selected its top k from block maxima (a segment wide enough:
+        # scoring.knn_block_select), and the (job x segment) scans that
+        # left the planned path for the unbatched executor
         self.knn_filtered = {
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
-            "filter_tiles": 0, "mask_launches": 0, "fallbacks": 0,
+            "filter_tiles": 0, "mask_launches": 0,
+            "block_select_launches": 0, "fallbacks": 0,
         }
         # per-bucket launch histogram + occupancy sums (guarded by
         # self._lock; surfaced via batching_stats() → _nodes/stats):
@@ -2248,7 +2251,7 @@ class QueryBatcher:
                     )
                 items.append((si, n, s, d, None))
                 continue
-            vectors, exists = ex.device_segments[si].vectors[field]
+            vectors, exists, norms = ex.device_segments[si].vectors[field]
             cand_mask = exists
             if live is not None:
                 live = np.asarray(live)
@@ -2256,7 +2259,7 @@ class QueryBatcher:
                 cand_mask = cand_mask & live
             if filtered:
                 item = self._dispatch_knn_filtered(
-                    jobs, si, n, np.asarray(q), vectors, cand_mask,
+                    jobs, si, n, np.asarray(q), vectors, norms, cand_mask,
                     vf.similarity, kc, rows, record)
                 if item is not None:
                     items.append(item)
@@ -2278,15 +2281,17 @@ class QueryBatcher:
         return items
 
     def _dispatch_knn_filtered(self, jobs: List[_Job], si: int, n: int,
-                               q: np.ndarray, vectors, cand_mask,
+                               q: np.ndarray, vectors, norms, cand_mask,
                                similarity: str, kc: int, rows: int,
                                record: bool) -> Tuple:
         """One segment of a filtered group: the mask launch, then the
-        scan under the masks. -> (si, n, scores, docs, passed), the
-        three on the device; (si, n, None, None, None) where the segment
-        is left to the unbatched executor at collect; None where no
-        document of the segment holds the filter's field (nothing
-        passes, nothing is launched)."""
+        scan under the masks (`norms`: an integer field's norm plane,
+        None for a float field, whose scan computes its own).
+        -> (si, n, scores, docs, passed), the three on the device;
+        (si, n, None, None, None) where the segment is left to the
+        unbatched executor at collect; None where no document of the
+        segment holds the filter's field (nothing passes, nothing is
+        launched)."""
         ex = jobs[0].executor
         t0 = time.perf_counter_ns()
         fname = jobs[0].plan.filter.field
@@ -2315,7 +2320,8 @@ class QueryBatcher:
                 {"segment": si, "launches": 1, "tiles": tiles},
             ))
         note_transfer("h2d", q.nbytes)
-        s, d = scoring.knn_topk_filtered(q, vectors, mask, similarity, kc)
+        s, d = scoring.knn_topk_filtered(
+            q, vectors, mask, similarity, kc, norms)
         if record:
             dims = int(q.shape[1])
             with self._lock:
@@ -2326,6 +2332,8 @@ class QueryBatcher:
                 kf["rows_scanned"] += len(jobs) * n
                 kf["filter_tiles"] += tiles
                 kf["mask_launches"] += 1
+                if scoring.knn_block_select(n, kc):
+                    kf["block_select_launches"] += 1
             _group_now().add_flops(scoring.knn_flops(len(jobs), n, dims))
         return si, n, s, d, passed
 

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -150,6 +150,16 @@ class _LazyDeviceMap:
         return v
 
 
+class DeviceVectors(NamedTuple):
+    """A vector field's uploads: the stored rows (unit rows for cosine),
+    which documents hold one, and for integer rows under l2_norm their
+    float32 `sum(v * v)` (None for every other field)."""
+
+    rows: jax.Array
+    exists: jax.Array
+    norms: Optional[jax.Array] = None
+
+
 class DeviceSegment:
     """Device-resident mirror of a Segment's hot arrays (lazy per field)."""
 
@@ -173,19 +183,28 @@ class DeviceSegment:
         def _vec(f):
             vf = seg.vectors[f]
             mat = vf.unit_vectors if vf.similarity == "cosine" else vf.vectors
+            # integer rows under l2_norm bring their norms, exact in
+            # float32 and built on the device from the uploaded rows
+            # (scoring.knn_row_norms); any other field uploads what it
+            # always did
+            normed = vf.similarity == "l2_norm" and np.issubdtype(
+                mat.dtype, np.integer)
             if charge is not None:
                 # vectors are the big uploads: trip the breaker BEFORE
                 # shipping them (HierarchyCircuitBreakerService
                 # .addEstimateBytesAndMaybeBreak)
                 charge(
                     "vectors",
-                    int(mat.nbytes) + int(vf.exists.nbytes),
+                    int(mat.nbytes) + int(vf.exists.nbytes)
+                    + (4 * len(mat) if normed else 0),
                     True,
                     precheck_only=True,
                 )
-            out = (
-                jax.device_put(mat, device),
+            rows = jax.device_put(mat, device)
+            out = DeviceVectors(
+                rows,
                 jax.device_put(vf.exists, device),
+                scoring.knn_row_norms(rows) if normed else None,
             )
             if charge is not None:
                 charge("vectors", _tree_nbytes(out), False)
@@ -1719,11 +1738,10 @@ class JaxExecutor:
         dv = self.device_segments[si].vectors.get(sec.field)
         if dv is None:
             return jnp.zeros(n, bool), jnp.zeros(n, jnp.float32)
-        vectors, exists = dv
         vf = seg.vectors[sec.field]
         qv = jnp.asarray(np.asarray(sec.query_vector, np.float32))[None, :]
-        scores = scoring.knn_scores(qv, vectors, vf.similarity)[0]
-        mask = exists
+        scores = scoring.knn_scores(qv, dv.rows, vf.similarity)[0]
+        mask = dv.exists
         if sec.filter is not None:
             mask = mask & self.filter_mask(sec.filter, si)
         live = self.reader.live_docs[si]
@@ -2234,7 +2252,7 @@ class JaxExecutor:
         live = self.reader.live_docs[si]
         if live is not None:
             cand = cand & jnp.asarray(live)
-        vectors, _exists = self.device_segments[si].vectors[field]
+        vectors = self.device_segments[si].vectors[field].rows
         q = jnp.asarray(np.asarray(vector, np.float32))[None, :]
         top_s, top_d = scoring.knn_topk(
             q, vectors, cand, vf.similarity, min(k, seg.num_docs))
@@ -2289,7 +2307,7 @@ class JaxExecutor:
                 ann_mod.note_search(spec.nprobe, idx.nlist)
                 per_seg.append((cand_mask, top_s[0], top_d[0]))
                 continue
-            vectors, _exists = self.device_segments[si].vectors[sec.field]
+            vectors = self.device_segments[si].vectors[sec.field].rows
             top_s, top_d = scoring.knn_topk(q, vectors, cand_mask, vf.similarity, k)
             per_seg.append((cand_mask, top_s[0], top_d[0]))
         # global k cut across segments

@@ -23,6 +23,7 @@ plan widths read off a live CPU run of the same corpus at small scale).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
 
@@ -120,6 +121,34 @@ FILTERED_DOCS = 10_000_000
 FILTERED_TILES = 932_453
 
 
+def _elements(shape: str) -> int:
+    """Elements of the first array of an HLO shape string."""
+    dims = re.search(r"\[([\d,]*)\]", shape).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+
+
+def _sorted_widths(hlo: str) -> list:
+    """Elements every sort or top-k of a compiled program orders (a
+    `sort`'s own shape; a `TopK` custom call's operand), and a `while`
+    counted as the whole plane: the chip's compiler lowers a wide
+    `lax.top_k` to a loop of sorts."""
+    shape_of = dict(re.findall(r"%?([\w.\-]+) = (\(?\w+\[[\d,]*\])", hlo))
+    widths = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?\w+\[[\d,]*\]).*? "
+                     r"(sort|while|custom-call)\(%?([\w.\-]+)", line)
+        if m is None:
+            continue
+        shape, op, operand = m.groups()
+        if op == "sort":
+            widths.append(_elements(shape))
+        elif op == "while":
+            widths.append(FILTERED_DOCS)
+        elif 'custom_call_target="TopK"' in line:
+            widths.append(_elements(shape_of[operand]))
+    return widths
+
+
 @pytest.mark.parametrize("rows", [1, 32])
 def test_filtered_byte_knn_programs(one_chip, rows):
     s = _on(one_chip)
@@ -136,11 +165,32 @@ def test_filtered_byte_knn_programs(one_chip, rows):
         s((rows, FILTERED_DOCS), jnp.bool_),
         similarity="l2_norm",
         k=128,
+        norms=s((FILTERED_DOCS,), jnp.float32),
     ).compile()
-    # both beside what the deployment keeps resident: rows and two planes
-    resident = FILTERED_DOCS * 192 + 2 * FILTERED_TILES * TILE * 4
+    norms = scoring.knn_row_norms.lower(
+        s((FILTERED_DOCS, 192), jnp.int8)).compile()
+    # each beside what the deployment keeps resident: rows, their norms
+    # and two planes
+    resident = FILTERED_DOCS * 196 + 2 * FILTERED_TILES * TILE * 4
     assert _fits(mask) + resident < HBM_BYTES
     assert _fits(scan) + resident < HBM_BYTES
+    assert _fits(norms) + resident < HBM_BYTES
+    # the 10M-wide sort cannot come back unnoticed: nothing the scan
+    # orders is wider than the plane of block maxima (padded to whole
+    # tiles: twice over is room enough, the score plane is 128 times)
+    hlo = scan.as_text()
+    assert scoring.knn_block_select(FILTERED_DOCS, 128)
+    widest = 2 * rows * (FILTERED_DOCS // scoring.KNN_BLOCK)
+    widths = _sorted_widths(hlo)
+    assert widths and max(widths) <= widest, widths
+    # nor the second pass over the rows: ONE fusion reads the int8 operand
+    entry = hlo[hlo.index("ENTRY"):]
+    stored = re.search(
+        rf"%?([\w.\-]+) = s8\[{FILTERED_DOCS},192\]\S* parameter",
+        entry).group(1)
+    readers = [ln for ln in entry.splitlines() if "parameter(" not in ln
+               and re.search(rf"[(, ]%?{re.escape(stored)}[,)]", ln)]
+    assert len(readers) == 1 and " fusion(" in readers[0], readers
 
 
 # ---------------------------------------------------------------------------

@@ -378,6 +378,27 @@ def test_request_is_a_knn_job_with_its_spans_and_counters(dep):
     assert spans["plan"]["tags"] == {"family": "knn", "planned": True}
 
 
+@pytest.mark.parametrize("num_candidates, selects", [(16, 1), (100, 0)],
+                         ids=["wide_enough", "too_narrow"])
+def test_scan_selects_from_block_maxima_where_the_segment_is_wide_enough(
+        dep, num_candidates, selects):
+    """30,000 rows hold one whole group of 128 blocks: 8 x k of them at
+    the candidate bucket 16, not at 128 (the deployment's, which takes
+    the selection from 131,072 rows: its 10M). Either way the page is
+    the plain reference's."""
+    bucket = 16 if num_candidates == 16 else 128
+    assert scoring.knn_block_select(DOCS, bucket) is bool(selects)
+    for i in (1, 3, 5):
+        body = json.loads(json.dumps(dep.bodies[i]))
+        body["knn"]["num_candidates"] = num_candidates
+        kf0 = dep.node()["knn_filtered"]
+        dep.held(body, dep.search(body))
+        kf1 = dep.node()["knn_filtered"]
+        assert kf1["mask_launches"] == kf0["mask_launches"] + 1
+        assert (kf1["block_select_launches"]
+                == kf0["block_select_launches"] + selects)
+
+
 def test_every_transfer_of_a_filtered_job_is_counted(dep):
     """Up: the mask plan (one row of 3 x 8 slots and the clause count),
     the query row, the merge's slot map and rank cut; down: the packed
@@ -464,7 +485,7 @@ def test_configuration_keeps_the_sources_shapes(dep):
     props = dep.corpus["mappings"]["properties"]
     assert props["vec"]["element_type"] == "byte"
     svc = dep.server.cluster.indices[dep.index]
-    dev, _exists = svc._executor(svc.shards[0]).device_segments[0].vectors["vec"]
+    dev = svc._executor(svc.shards[0]).device_segments[0].vectors["vec"].rows
     assert dev.dtype == np.int8  # one byte an element on the device too
 
 
@@ -508,6 +529,31 @@ def test_byte_mapping_round_trips(dep, typed):
     held = [s.vectors["vec"].vectors.dtype
             for s in svc.shards[0].reader().segments]
     assert len(held) >= 3 and set(held) == {np.dtype(np.int8)}
+
+
+def test_byte_rows_bring_their_norms_and_float_rows_none(dep, typed):
+    """The norm plane is built at upload for integer rows under l2_norm,
+    equal to `sum(v * v)` bit for bit; a float field uploads what it
+    always did and its scan computes its own."""
+    vecs, _tags = typed
+    for index, held in (("typed-byte", True), ("typed-float", False)):
+        svc = dep.server.cluster.indices[index]
+        ex = svc._executor(svc.shards[0])
+        first = 0
+        for seg, dseg in zip(ex.reader.segments, ex.device_segments):
+            dv = dseg.vectors["vec"]
+            assert dv.rows.shape == (seg.num_docs, 8)
+            if not held:
+                assert dv.norms is None and dv.rows.dtype == np.float32
+                continue
+            assert dv.norms.dtype == np.float32
+            want = (vecs[first:first + seg.num_docs] ** 2).sum(axis=1)
+            np.testing.assert_array_equal(np.asarray(dv.norms), want)
+            first += seg.num_docs
+    svc = dep.server.cluster.indices[dep.index]
+    dv = svc._executor(svc.shards[0]).device_segments[0].vectors["vec"]
+    rows = dep.corpus["segment"].vectors["vec"].vectors.astype(np.int64)
+    np.testing.assert_array_equal(np.asarray(dv.norms), (rows ** 2).sum(axis=1))
 
 
 @pytest.mark.parametrize("vector, why", [

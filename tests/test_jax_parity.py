@@ -198,3 +198,124 @@ def test_min_score_parity():
     r_np = ORACLE.search(q, size=50, min_score=0.5)
     r_jx = JAXEX.search(q, size=50, min_score=0.5)
     assert_same(r_np, r_jx)
+
+
+# ---- the filtered scan's selection from block maxima (ops/scoring.py
+# `_block_topk`) against the plain `lax.top_k` it stands for ----------------
+
+BLOCK_K = 16
+BLOCK_N = 3 * 128 * 128  # three whole groups of 128 rows of 128 lanes
+
+
+def _block_case(case: str, rows: int):
+    """(scores float32[rows, n], mask bool[rows, n]) of one case; every
+    query row draws its own."""
+    rng = np.random.default_rng([42, rows, BLOCK_CASES.index(case)])
+    n = BLOCK_N + (77 if case in ("ragged_tail", "best_in_the_tail") else 0)
+    scores = rng.random((rows, n), dtype=np.float32)
+    mask = rng.random((rows, n)) < 0.3
+    if case == "best_in_the_tail":
+        scores[:, BLOCK_N:] += 1.0
+    elif case == "fewer_than_k_pass":
+        mask[:] = False
+        for r in range(rows):
+            mask[r, rng.choice(n, 5, replace=False)] = True
+    elif case == "one_passes":
+        mask[:] = False
+        mask[np.arange(rows), rng.integers(0, n, rows)] = True
+    elif case == "none_passes":
+        mask[:] = False
+    elif case == "all_scores_equal":
+        scores[:] = 0.25
+    elif case == "tie_group_across_the_kth_block":
+        # three levels: 15 columns hold a 3, 120 a 2, the rest a 1: the
+        # k-th block maximum is a 2 that blocks not chosen hold too
+        scores[:] = 1.0
+        mask[:] = True
+        for r in range(rows):
+            cols = rng.choice(n, 135, replace=False)
+            scores[r, cols[:15]] = 3.0
+            scores[r, cols[15:]] = 2.0
+    return scores, mask
+
+
+BLOCK_CASES = (
+    "whole_groups",  # n a multiple of the group
+    "ragged_tail",  # 77 columns past the last whole group
+    "best_in_the_tail",  # and the best scores lie there
+    "fewer_than_k_pass",  # 5 columns
+    "one_passes",
+    "none_passes",
+    "all_scores_equal",
+    "tie_group_across_the_kth_block",
+)
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4])
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_block_selection_is_the_plain_topk(case, rows):
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops import scoring
+
+    scores, mask = _block_case(case, rows)
+    masked = jnp.where(jnp.asarray(mask), jnp.asarray(scores), -jnp.inf)
+    assert scoring.knn_block_select(masked.shape[1], BLOCK_K)
+    got_s, got_d = (np.asarray(x) for x in jax.jit(
+        scoring._block_topk, static_argnums=1)(masked, BLOCK_K))
+    want_s = np.asarray(jax.lax.top_k(masked, BLOCK_K)[0])
+    assert got_s.shape == got_d.shape == (rows, BLOCK_K)
+    for r in range(rows):
+        # the same multiset of scores, best first; -inf past the passing
+        np.testing.assert_array_equal(got_s[r], want_s[r])
+        assert (got_s[r][:-1] >= got_s[r][1:]).all()
+        held = np.isfinite(got_s[r])
+        cols = got_d[r][held]
+        assert held.sum() == min(BLOCK_K, int(mask[r].sum()))
+        assert len(set(cols.tolist())) == len(cols)  # no row twice
+        assert mask[r][cols].all()  # every returned row passes its mask
+        np.testing.assert_array_equal(scores[r][cols], got_s[r][held])
+
+
+@pytest.mark.parametrize("n, k, selects", [
+    (8 * 128 * 128, 128, True), (8 * 128 * 128 - 1, 128, False),
+    (10_000_000, 128, True), (20_000, 128, False), (20_000, 16, True),
+    (16_383, 16, False),
+])
+def test_block_selection_engages_from_eight_k_whole_blocks(n, k, selects):
+    from elasticsearch_tpu.ops import scoring
+
+    assert scoring.knn_block_select(n, k) is selects
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4])
+def test_filtered_scan_with_the_norm_plane_is_the_scan_without(rows):
+    """int8 rows under l2_norm: the norm plane is the in-program
+    `sum(v * v)` bit for bit, so the scores are; the wide plane's block
+    selection returns what the plain top-k returns."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops import scoring
+
+    rng = np.random.default_rng([42, 7, rows])
+    n, d, k = BLOCK_N + 50, 192, BLOCK_K
+    vectors = rng.integers(-128, 128, (n, d)).astype(np.int8)
+    queries = rng.integers(-128, 128, (rows, d)).astype(np.float32)
+    mask = rng.random((rows, n)) < 0.05
+    norms = scoring.knn_row_norms(vectors)
+    np.testing.assert_array_equal(
+        np.asarray(norms), (vectors.astype(np.int64) ** 2).sum(axis=1))
+    plain = scoring.knn_scores(queries, vectors, "l2_norm")
+    np.testing.assert_array_equal(
+        np.asarray(plain),
+        np.asarray(scoring.knn_scores(queries, vectors, "l2_norm", norms)))
+    got_s, got_d = scoring.knn_topk_filtered(
+        queries, vectors, mask, "l2_norm", k, norms)
+    want_s, _ = jax.lax.top_k(jnp.where(mask, plain, -jnp.inf), k)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    # whole-number distances tie: rows of equal score may swap
+    picked = np.take_along_axis(np.asarray(plain), np.asarray(got_d), axis=1)
+    np.testing.assert_array_equal(picked, np.asarray(want_s))
+    assert np.take_along_axis(mask, np.asarray(got_d), axis=1).all()
