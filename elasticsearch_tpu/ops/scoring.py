@@ -25,10 +25,11 @@ parallel" idea from BASELINE.json's north star):
   per-doc accumulator; a query's tile list is split into TCHUNK-sized
   chunks, so a handful of programs total cover every (segment, query)
   combination. Used for small segments and as the overflow path.
-* `FusedScorer` — one round trip per large segment: the whole query
-  phase (rare-tile gather + dense hot-term rows + msm mask + top-k)
-  runs as a single compiled program fed by one packed int32 plan
-  upload and returning one packed download. On the attached chip a
+* `MultiFusedScorer` — one round trip per large segment: the whole
+  query phase of a `match` (one field), a `bool` or a `multi_match`
+  (rare-tile gather + dense hot-term rows + match mask + top-k) runs as
+  a single compiled program, `_fused_query_mf`, fed by one packed int32
+  plan upload and returning one packed download. On the attached chip a
   round trip is 0.4-0.5 ms and the program 1-2 ms, against ~27 launches
   and two blocking downloads on the chunked path (see the cost model
   below).
@@ -464,137 +465,6 @@ def build_dense_rows(doc_ids, tfs, hot_tiles, hot_rank_of_tile, n_hot, n_docs,
                  jnp.dtype(dtype))
 
 
-class FusedScorer:
-    """One-call batched BM25 query phase over one segment.
-
-    Plan packing (int32[B, 2*T_RARE + 2*H + 1]):
-      [0:T)          rare tile ids into the postings arrays (-1 = pad)
-      [T:2T)         float32 tile weights, bitcast
-      [2T:2T+H)      dense hot rows (-1 = pad): r < n8 is row r of the
-                     uint8 plane, r >= n8 row r - n8 of the uint16 one
-                     (those first: `wide_rows_first`)
-      [2T+H:2T+2H)   float32 hot weights, bitcast
-      [2T+2H]        minimum_should_match
-
-    Result packing (int32[B, 2k + 1]):
-      [0:k) float32 scores bitcast · [k:2k) doc ids · [2k] total
-    A row is in its final order (score desc, doc asc, -inf pads last),
-    so a shard with one scoring segment downloads it as it is
-    (`packed_segment_topk`); with more, `_merge_segments` unpacks it
-    inside its own trace. Nothing unpacks it eagerly on the device.
-    """
-
-    def __init__(
-        self,
-        doc_ids,
-        tfs,
-        inv_norm,
-        live,
-        dense_rows,  # uint8[n_hot, n_docs] (may be n_hot == 0)
-        t_rare: int = FUSED_T_RARE,
-        n_hot_slots: int = FUSED_H,
-        wide_rows=None,  # uint16[n_wide, n_docs]: tf past DENSE_TF_MAX
-    ):
-        self.doc_ids = doc_ids
-        self.tfs = tfs
-        self.inv_norm = jnp.asarray(inv_norm, jnp.float32)
-        self.live = jnp.asarray(live) if live is not None else None
-        self.dense = dense_rows
-        self.wide = wide_rows
-        self.n_docs = int(self.inv_norm.shape[0])
-        self.t_rare = t_rare
-        self.n_hot_slots = n_hot_slots
-
-    @property
-    def plan_shape(self):
-        return (BPAD, 2 * self.t_rare + 2 * self.n_hot_slots + 1)
-
-    def plan_shape_rows(self, rows: int):
-        """Plan shape at one query-row bucket of the launch ladder."""
-        return (rows, 2 * self.t_rare + 2 * self.n_hot_slots + 1)
-
-    def pack_plans(self, plans, out=None, rows=None) -> np.ndarray:
-        """plans: per job (rare_tiles i64[], rare_w f32[], hot_ranks
-        i64[], hot_w f32[], msm int). Jobs beyond the row bucket are an
-        error; overflowing a slot budget must be handled by the caller.
-        `rows` picks the launch's query-row bucket (default BPAD); `out`
-        optionally reuses a persistent staging slab (fully rewritten:
-        every region is reset before the per-job fills)."""
-        T, H = self.t_rare, self.n_hot_slots
-        if out is None:
-            out = np.empty(
-                self.plan_shape if rows is None else self.plan_shape_rows(rows),
-                np.int32,
-            )
-        out[:, :T] = -1
-        out[:, T : 2 * T] = 0
-        out[:, 2 * T : 2 * T + H] = -1
-        out[:, 2 * T + H :] = 0
-        fout = out.view(np.float32)
-        for j, (rt, rw, hr, hw, msm) in enumerate(plans):
-            nt, nh = len(rt), len(hr)
-            out[j, :nt] = rt
-            fout[j, T : T + nt] = rw
-            out[j, 2 * T : 2 * T + nh] = hr
-            fout[j, 2 * T + H : 2 * T + H + nh] = hw
-            out[j, 2 * T + 2 * H] = msm
-        return out
-
-    def search_async(self, plans, k: int, with_cnt: bool, live=None,
-                     staging=None, rows=None):
-        """Launches the fused kernel WITHOUT waiting for the result:
-        returns (device_out, k) for decode_result(). Device dispatch is
-        async in jax, so a caller can launch several groups (e.g. the
-        BM25 and kNN legs of a hybrid search) back-to-back and only
-        block when it collects. `live` optionally overrides the
-        constructor's live-docs mask — cached filter bitsets mask the
-        kernel through this operand (traced arg: no recompile).
-        `staging` optionally supplies the reusable plan-upload buffer
-        (a (family, shape, dtype) → np.ndarray callable); `rows` the
-        launch's query-row bucket (default BPAD)."""
-        k = min(k, self.n_docs)
-        shape = self.plan_shape if rows is None else self.plan_shape_rows(rows)
-        buf = (
-            staging("fused_plan", shape, np.int32)
-            if staging is not None
-            else None
-        )
-        packed = self.pack_plans(plans, out=buf, rows=shape[0])
-        note_transfer("h2d", packed.nbytes)
-        out = _fused_query(
-            self.doc_ids,
-            self.tfs,
-            self.inv_norm,
-            live if live is not None else self.live,
-            self.dense,
-            packed,  # the jitted call uploads it: no eager device_put
-            self.wide,
-            t_rare=self.t_rare,
-            n_hot=self.n_hot_slots,
-            k=k,
-            with_cnt=with_cnt,
-        )
-        return out, k
-
-    @staticmethod
-    def decode_result(pending):
-        """Blocks on the device transfer and unpacks to
-        (scores f32[B,k], docs i32[B,k], totals i64[B])."""
-        out, k = pending
-        out = _to_host(out)
-        scores = out[:, :k].copy().view(np.float32)
-        docs = out[:, k : 2 * k]
-        totals = out[:, 2 * k].astype(np.int64)
-        return scores, docs, totals
-
-    def search(self, plans, k: int, with_cnt: bool, live=None, rows=None):
-        """One device round trip for up to BPAD jobs. Returns
-        (scores f32[B,k], docs i32[B,k], totals i64[B])."""
-        return self.decode_result(
-            self.search_async(plans, k, with_cnt, live=live, rows=rows)
-        )
-
-
 def wide_rows_first(hot_rows: list, hot_w: list, dense) -> None:
     """Orders a plan's hot slots in place, uint16 rows (numbered on from
     the rows of the uint8 plane `dense`) before uint8 ones, each kind in
@@ -610,7 +480,7 @@ def wide_rows_first(hot_rows: list, hot_w: list, dense) -> None:
 
 def _add_hot_terms(acc, cnt, dense, wide, inv_norm, hot_ids, hot_w, signed,
                    clauses=False):
-    """The hot-term pass of both fused programs over a field's two dense
+    """The hot-term pass of the fused program over a field's two dense
     planes: `dense` uint8[n8, n] and, where some hot term's tf passes
     DENSE_TF_MAX, `wide` uint16[n16, n] (hot id r >= n8 is its row
     r - n8). Without `wide` (every deployment whose documents are short)
@@ -643,7 +513,7 @@ def _add_hot_rows(acc, cnt, dense, inv_norm, hot_ids, hot_w, signed,
     f32[B, n], `cnt` i32[B, >= n] or None (no count plane), `hot_ids`
     i32[B, H] rows of `dense` (-1 = unused), `hot_w` f32[B, H]. With
     `signed` a weight's sign says whether the term counts (w > 0) and
-    |w| scores (MultiFusedScorer); without, every match counts. With
+    |w| scores (a counted launch); without, every match counts. With
     `clauses` an id carries its clause counter above SLOT_ID_BITS and a
     counted match adds that counter's unit to the count plane
     (`clause_units`, inside the slot's own trip); without, 1.
@@ -733,7 +603,7 @@ def _add_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
     `rare_ti` i32[B, T] are tile ids into `doc_ids` / `tfs` (-1 =
     unused), `rare_tw` f32[B, T] their weights. With `signed` a weight's
     sign says whether the term counts (w > 0) and |w| scores
-    (MultiFusedScorer); without, every posting counts. With `clauses`
+    (a counted launch); without, every posting counts. With `clauses`
     a tile id carries its clause counter above SLOT_ID_BITS and a counted
     posting adds that counter's unit to the count plane (`clause_units`,
     on a trip's own C ids: nothing is decoded in front of the loop);
@@ -795,59 +665,16 @@ def _doc_planes(flat, rows: int, n: int):
     return flat.reshape(rows, n + 1)[:, :n]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("t_rare", "n_hot", "k", "with_cnt")
-)
-def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, wide=None, *,
-                 t_rare, n_hot, k, with_cnt):
-    n = inv_norm.shape[0]
-    T, H = t_rare, n_hot
-    rare_ti = plan[:, :T]
-    rare_tw = jax.lax.bitcast_convert_type(plan[:, T : 2 * T], jnp.float32)
-    hot_ids = plan[:, 2 * T : 2 * T + H]
-    hot_w = jax.lax.bitcast_convert_type(plan[:, 2 * T + H : 2 * T + 2 * H], jnp.float32)
-    msm = plan[:, 2 * T + 2 * H]
-
-    # ---- rare terms: tile gather + scatter-add, the slots in use ----
-    B = plan.shape[0]
-    acc = jnp.zeros(B * (n + 1), jnp.float32)
-    cnt = jnp.zeros(B * (n + 1), jnp.int32) if with_cnt else None
-    acc, cnt = _add_rare_tiles(
-        acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw, signed=False
-    )
-    acc = _doc_planes(acc, B, n)
-    if with_cnt:
-        cnt = _doc_planes(cnt, B, n)
-
-    # ---- hot terms: dense per-doc tf rows, pure vector math ----
-    acc, cnt = _add_hot_terms(
-        acc, cnt, dense, wide, inv_norm, hot_ids, hot_w, signed=False
-    )
-
-    # ---- collection ----
-    if with_cnt:
-        mask = cnt >= jnp.maximum(msm, 1)[:, None]
-    else:
-        mask = acc > 0
-    if live is not None:
-        mask = mask & live[None, :]
-    masked = jnp.where(mask, acc, -jnp.inf)
-    top_s, top_d = jax.lax.top_k(masked, k)
-    totals = mask.sum(axis=1, dtype=jnp.int32)
-    return jnp.concatenate(
-        [
-            jax.lax.bitcast_convert_type(top_s, jnp.int32),
-            top_d,
-            totals[:, None],
-        ],
-        axis=1,
-    )
-
-
 # ---------------------------------------------------------------------------
-# Multi-field fused scorer — round-5 extension of the single-round-trip
-# design to the remaining BASELINE shapes:
+# The fused scorer's plans: ONE program, `_fused_query_mf`, scores the F
+# fields of a plan over one segment, for every text query the batcher
+# plans:
 #
+#   * match on one field → F = 1, every term a counted clause of its own.
+#     A launch none of whose jobs holds a count threshold (msm <= 1: any
+#     hit matches) goes UNCOUNTED: no count plane, no second scatter, the
+#     mask `score > 0`. One `operator: and` / `minimum_should_match` job
+#     makes its launch a counted one.
 #   * bool must/should multi-term on one field  → per-slot REQUIRED flags
 #     (must terms count toward the match threshold, should terms only
 #     score). The flag rides the SIGN of the packed weight: w > 0 counts,
@@ -877,8 +704,8 @@ def _fused_query(doc_ids, tfs, inv_norm, live, dense, plan, wide=None, *,
 #     CLAUSE_DIGITS of them: the planner (search/batcher.py) turns away
 #     what passes either.
 #
-# Everything else follows the single-field fused design: one packed
-# int32 plan upload, whole query phase on device, one packed download.
+# Either way: one packed int32 plan upload, the whole query phase on the
+# device, one packed download.
 # ---------------------------------------------------------------------------
 
 SLOT_ID_BITS = 27  # a tile or dense-row id; the slot's counter above them
@@ -923,11 +750,24 @@ def clauses_hit(cnt):
 class MultiFusedScorer:
     """One-call batched BM25 query phase over one segment and F fields.
 
-    Per-field plan section (int32[2*T + 2*H]): rare tile ids + signed
-    float32 weights (bitcast) + dense hot row ids + signed hot weights.
-    A POSITIVE weight counts, into the counter its slot's id names above
-    SLOT_ID_BITS (`clause_slot_ids`). Trailing int32: msm, the counted
-    clauses a document must match (`clauses_hit`).
+    Plan packing (int32[B, F * (2*T + 2*H) + 1]), a section a field:
+      [0:T)          rare tile ids into the postings arrays (-1 = pad)
+      [T:2T)         float32 tile weights, bitcast
+      [2T:2T+H)      dense hot rows (-1 = pad): r < n8 is row r of the
+                     uint8 plane, r >= n8 row r - n8 of the uint16 one
+                     (those first: `wide_rows_first`)
+      [2T+H:2T+2H)   float32 hot weights, bitcast
+    and one trailing int32: msm, the counted clauses a document must
+    match (`clauses_hit`). In a counted launch a POSITIVE weight counts,
+    into the counter its slot's id names above SLOT_ID_BITS
+    (`clause_slot_ids`), and a negative one scores with |w|.
+
+    Result packing (int32[B, 2k + 1]):
+      [0:k) float32 scores bitcast · [k:2k) doc ids · [2k] total
+    A row is in its final order (score desc, doc asc, -inf pads last),
+    so a shard with one scoring segment downloads it as it is
+    (`packed_segment_topk`); with more, `_merge_segments` unpacks it
+    inside its own trace. Nothing unpacks it eagerly on the device.
     """
 
     def __init__(self, fields, parts, live, t_rare=FUSED_T_RARE,
@@ -942,12 +782,8 @@ class MultiFusedScorer:
         if any(p["doc_ids"].shape[0] > SLOT_ID_MASK for p in parts):
             raise ValueError("a field's tiles pass the plan's slot ids")
 
-    @property
-    def plan_shape(self):
-        sec = 2 * self.t_rare + 2 * self.n_hot_slots
-        return (BPAD, len(self.fields) * sec + 1)
-
     def plan_shape_rows(self, rows: int):
+        """Plan shape at one query-row bucket of the launch ladder."""
         sec = 2 * self.t_rare + 2 * self.n_hot_slots
         return (rows, len(self.fields) * sec + 1)
 
@@ -961,10 +797,7 @@ class MultiFusedScorer:
         F = len(self.fields)
         sec = 2 * T + 2 * H
         if out is None:
-            out = np.empty(
-                self.plan_shape if rows is None else self.plan_shape_rows(rows),
-                np.int32,
-            )
+            out = np.empty(self.plan_shape_rows(rows or BPAD), np.int32)
         out[:] = -1
         for f in range(F):
             base = f * sec
@@ -983,23 +816,28 @@ class MultiFusedScorer:
             out[j, F * sec] = msm
         return out
 
-    def search_async(self, plans, k: int, combine: str, tie: float,
-                     live=None, staging=None, rows=None):
-        """Async launch (see FusedScorer.search_async): returns
-        (device_out, k) for decode_result(). `live` optionally overrides
-        the live-docs mask (cached filter bitsets ride here); `staging`
-        optionally supplies the reusable plan-upload buffer; `rows` the
-        launch's query-row bucket (default BPAD)."""
+    def search_async(self, plans, k: int, combine: str, tie, live=None,
+                     staging=None, rows=None, counted: bool = True):
+        """Launches the fused kernel WITHOUT waiting for the result:
+        returns (device_out, k) for decode_result(). Device dispatch is
+        async in jax, so a caller can launch several groups (e.g. the
+        BM25 and kNN legs of a hybrid search) back-to-back and only
+        block when it collects. `live` optionally overrides the
+        live-docs mask (cached filter bitsets ride here: a traced arg,
+        no recompile); `staging` optionally supplies the reusable
+        plan-upload buffer (a (family, shape, dtype) → np.ndarray
+        callable); `rows` the launch's query-row bucket (default BPAD).
+        `tie` is `max_tie`'s tie breaker, or None where nothing reads
+        it (one field): no scalar is uploaded then. `counted` as
+        `_fused_query_mf` takes it."""
         k = min(k, self.n_docs)
-        shape = self.plan_shape if rows is None else self.plan_shape_rows(rows)
-        buf = (
-            staging("fused_plan_mf", shape, np.int32)
-            if staging is not None
-            else None
-        )
+        shape = self.plan_shape_rows(rows or BPAD)
+        buf = staging("fused_plan", shape, np.int32) if staging else None
         packed = self.pack_plans(plans, out=buf, rows=shape[0])
         note_transfer("h2d", packed.nbytes)
-        note_transfer("h2d", 4)  # tie
+        if tie is not None:
+            note_transfer("h2d", 4)
+            tie = np.float32(tie)
         out = _fused_query_mf(
             tuple(p["doc_ids"] for p in self.parts),
             tuple(p["tfs"] for p in self.parts),
@@ -1007,31 +845,46 @@ class MultiFusedScorer:
             tuple(p["dense"] for p in self.parts),
             live if live is not None else self.live,
             packed,  # the jitted call uploads both: no eager device_put
-            np.float32(tie),
+            tie,
             tuple(p["wide"] for p in self.parts),
             t_rare=self.t_rare,
             n_hot=self.n_hot_slots,
             k=k,
             combine=combine,
+            counted=counted,
         )
         return out, k
 
-    decode_result = staticmethod(FusedScorer.decode_result)
+    def search(self, plans, k: int, combine: str, tie, live=None,
+               rows=None, counted: bool = True):
+        return decode_result(self.search_async(
+            plans, k, combine, tie, live=live, rows=rows, counted=counted
+        ))
 
-    def search(self, plans, k: int, combine: str, tie: float, live=None,
-               rows=None):
-        return self.decode_result(
-            self.search_async(plans, k, combine, tie, live=live, rows=rows)
-        )
+
+def decode_result(pending):
+    """Blocks on the device transfer of `search_async`'s packed result
+    and unpacks to (scores f32[B,k], docs i32[B,k], totals i64[B])."""
+    out, k = pending
+    out = _to_host(out)
+    scores = out[:, :k].copy().view(np.float32)
+    docs = out[:, k : 2 * k]
+    totals = out[:, 2 * k].astype(np.int64)
+    return scores, docs, totals
 
 
 @functools.partial(
-    jax.jit, static_argnames=("t_rare", "n_hot", "k", "combine")
+    jax.jit, static_argnames=("t_rare", "n_hot", "k", "combine", "counted")
 )
 def _fused_query_mf(
-    doc_ids_f, tfs_f, inv_norm_f, dense_f, live, plan, tie, wide_f=None, *,
-    t_rare, n_hot, k, combine,
+    doc_ids_f, tfs_f, inv_norm_f, dense_f, live, plan, tie=None, wide_f=None,
+    *, t_rare, n_hot, k, combine, counted=True,
 ):
+    """`counted` (the launch's) says whether any job holds documents to
+    a count of clauses: with it a weight's sign says whether its term
+    counts and the mask is `clauses_hit(cnt) >= msm`; without, there is
+    no count plane, weights score as they are and a document matches
+    where its score is positive. `tie` is read by `max_tie` alone."""
     F = len(doc_ids_f)
     n = inv_norm_f[0].shape[0]
     T, H = t_rare, n_hot
@@ -1054,25 +907,26 @@ def _fused_query_mf(
         )
 
     # rare terms of every field first: the tile slots in use, into flat
-    # planes (one count plane over the fields); |w| scores, w>0 counts
-    # its slot's unit
-    cnt = jnp.zeros(B * (n + 1), jnp.int32)
+    # planes (one count plane over the fields); counted, |w| scores and
+    # w>0 counts its slot's unit
+    cnt = jnp.zeros(B * (n + 1), jnp.int32) if counted else None
     accs = []
     for f in range(F):
         rare_ti, rare_tw, _, _ = section(f)
         acc, cnt = _add_rare_tiles(
             jnp.zeros(B * (n + 1), jnp.float32), cnt, doc_ids_f[f],
-            tfs_f[f], inv_norm_f[f], rare_ti, rare_tw, signed=True,
-            clauses=True,
+            tfs_f[f], inv_norm_f[f], rare_ti, rare_tw, signed=counted,
+            clauses=counted,
         )
         accs.append(_doc_planes(acc, B, n))
-    cnt = _doc_planes(cnt, B, n)
-    # then each field's hot terms: dense rows; |w| scores, w>0 counts
+    if counted:
+        cnt = _doc_planes(cnt, B, n)
+    # then each field's hot terms: dense rows, scored and counted alike
     for f in range(F):
         _, _, hot_ids, hot_w = section(f)
         accs[f], cnt = _add_hot_terms(
             accs[f], cnt, dense_f[f], wide_f[f], inv_norm_f[f],
-            hot_ids, hot_w, signed=True, clauses=True,
+            hot_ids, hot_w, signed=counted, clauses=counted,
         )
     if F == 1:
         combined = accs[0]
@@ -1084,7 +938,10 @@ def _fused_query_mf(
         stack = jnp.stack(accs)
         best = stack.max(axis=0)
         combined = best + tie * (stack.sum(axis=0) - best)
-    mask = clauses_hit(cnt) >= jnp.maximum(msm, 1)[:, None]
+    if counted:
+        mask = clauses_hit(cnt) >= jnp.maximum(msm, 1)[:, None]
+    else:
+        mask = combined > 0
     if live is not None:
         mask = mask & live[None, :]
     masked = jnp.where(mask, combined, -jnp.inf)
@@ -1104,7 +961,7 @@ def _fused_query_mf(
 # Cross-segment top-k merge: one blocking download a group.
 #
 # Each scoring segment leaves its candidates on the device, as the fused
-# kernel's packed row (i32[B, 2k+1], see FusedScorer) or as the chunked /
+# kernel's packed row (i32[B, 2k+1], see MultiFusedScorer) or as the chunked /
 # sparse paths' (scores, docs, totals) triple. What the group then costs
 # follows from how many there are:
 #
@@ -1202,9 +1059,7 @@ def packed_segment_topk(si: int, packed):
     """`merge_segment_topk` of one fused launch, with no program: the
     one blocking download of the kernel's packed i32[B, 2k+1], decoded
     on the host. Same return, same floats, ids, order and totals."""
-    scores, docs, totals = FusedScorer.decode_result(
-        (packed, _part_width(packed))
-    )
+    scores, docs, totals = decode_result((packed, _part_width(packed)))
     return scores, np.full_like(docs, si), docs, totals[:, None]
 
 

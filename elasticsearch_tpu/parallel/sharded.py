@@ -413,7 +413,7 @@ def build_sharded_knn_step(index: ShardedIndex, k: int, similarity: str = "cosin
 #
 # The stacked axis carries (shard, segment) ENTRIES, not whole shards:
 # the sequential serving path scores per segment (ChunkedScorer /
-# FusedScorer accumulate one segment's doc space), so keeping the
+# MultiFusedScorer accumulate one segment's doc space), so keeping the
 # per-entry granularity makes the mesh program reproduce the sequential
 # kernels value-for-value — same tile plans in the same scatter order,
 # same `w - w/(1 + tf·inv)` BM25 formula, same live/count masking — and

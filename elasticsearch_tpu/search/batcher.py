@@ -1701,17 +1701,22 @@ class QueryBatcher:
         for si in range(len(reader.segments)):
             n_docs = reader.segments[si].num_docs
             # ---- fused single-round-trip path (large segments) ----
-            fs = ex.fused_scorer(si, field)
+            fs = ex.fused_scorer_mf(si, (field,))
             if fs is not None:
+                # every term a counted clause of its own; a boost <= 0
+                # cannot ride weights whose sign says whether a term counts
                 fplans = [
-                    ex.fused_plan(
-                        fs, si, field, j.plan.terms, j.plan.boost, j.plan.msm
-                    )
+                    ex.fused_plan_field(
+                        si, field, fs.parts[0],
+                        [(t, 1.0, 1) for t in j.plan.terms], j.plan.boost,
+                    ) if j.plan.boost > 0 else None
                     for j in jobs
                 ]
                 if all(p is not None for p in fplans):
                     pend = fs.search_async(
-                        fplans, kb, with_cnt, staging=staging, rows=rows
+                        [([p], j.plan.msm) for p, j in zip(fplans, jobs)],
+                        kb, "sum", None, staging=staging, rows=rows,
+                        counted=with_cnt,
                     )
                     if record:
                         rare = [len(p[0]) for p in fplans]

@@ -297,7 +297,6 @@ class JaxExecutor:
         # on the immutable segments and survive executor regeneration.
         self._block_indexes: Dict[Tuple[int, str], object] = {}
         self._chunked_scorers: Dict[Tuple[int, str], object] = {}
-        self._fused_scorers: Dict[Tuple[int, str], object] = {}
         self._fused_parts: Dict[Tuple[int, str], object] = {}
         self._fused_mf: Dict[Tuple[int, tuple], object] = {}
         self._sort_rank_cache: Dict[Tuple[int, str, bool], tuple] = {}
@@ -605,16 +604,20 @@ class JaxExecutor:
         field = plan.field
         n = self.reader.segments[si].num_docs
         kk = min(kb, n)
-        fs = self.fused_scorer(si, field)
-        if fs is not None:
-            fplan = self.fused_plan(
-                fs, si, field, plan.terms, plan.boost, plan.msm
+        fs = self.fused_scorer_mf(si, (field,))
+        # a boost <= 0 cannot ride the fused plan's weights, whose sign
+        # says whether a term counts: the chunked path scores it
+        if fs is not None and plan.boost > 0:
+            sec = self.fused_plan_field(
+                si, field, fs.parts[0],
+                [(t, 1.0, 1) for t in plan.terms], plan.boost,
             )
-            if fplan is not None:
+            if sec is not None:
                 # single-request path: a 1-row launch (the smallest
                 # ladder bucket), not the full padded width
                 s, d, tot = fs.search(
-                    [fplan], kk, plan.msm > 1, live=base, rows=1
+                    [([sec], plan.msm)], kk, "sum", None, live=base,
+                    rows=1, counted=plan.msm > 1,
                 )
                 return s[0], d[0], int(tot[0]), False
         bmx = self.block_index(si, field)
@@ -1111,24 +1114,12 @@ class JaxExecutor:
             self._chunked_scorers[key] = cs
             return cs
 
-    def fused_scorer(self, si: int, field: str):
-        """Cached single-round-trip FusedScorer for one large segment
-        (ops/scoring.py module comment: on the measured hardware, one
-        fused call with dense hot-term rows beats multi-phase pruning).
-        None for small segments (the chunked path compiles shared shapes
-        there) or fields without postings."""
-        key = (si, field)
-        if key in self._fused_scorers:
-            return self._fused_scorers[key]
-        with self._build_lock:
-            return self._fused_scorer_build(key, si, field)
-
     def fused_parts(self, si: int, field: str):
         """Cached per-(segment, field) device arrays for fused scoring:
         dict(doc_ids, tfs, inv_norm, dense, hot_rank), or None when the
         field has no postings / the segment is below FUSED_MIN_DOCS.
-        Shared by the single-field FusedScorer and the multi-field
-        MultiFusedScorer so dense hot rows are built once per field."""
+        Shared by every MultiFusedScorer over the field, so dense hot
+        rows are built once per field."""
         key = (si, field)
         if key in self._fused_parts:
             return self._fused_parts[key]
@@ -1154,8 +1145,10 @@ class JaxExecutor:
 
     def fused_scorer_mf(self, si: int, fields: tuple):
         """Cached MultiFusedScorer over one segment and a field tuple
-        (the multi_match / bool serving engine); None when any field
-        lacks parts."""
+        (a `match`'s one field, a bool's, a multi_match's several;
+        ops/scoring.py's module comment says why one fused call beats
+        multi-phase pruning); None when any field lacks parts (a small
+        segment, which the chunked path serves, or no postings)."""
         key = (si, tuple(fields))
         if key in self._fused_mf:
             return self._fused_mf[key]
@@ -1171,24 +1164,6 @@ class JaxExecutor:
                 )
             self._fused_mf[key] = fs
             return fs
-
-    def _fused_scorer_build(self, key, si: int, field: str):
-        if key in self._fused_scorers:
-            return self._fused_scorers[key]
-        parts = self.fused_parts(si, field)
-        fs = None
-        if parts is not None:
-            fs = scoring.FusedScorer(
-                parts["doc_ids"],
-                parts["tfs"],
-                parts["inv_norm"],
-                self.reader.live_docs[si],
-                parts["dense"],
-                wide_rows=parts["wide"],
-            )
-            fs.hot_rank = parts["hot_rank"]
-        self._fused_scorers[key] = fs
-        return fs
 
     def _fused_parts_build(self, si: int, field: str):
         """The field's device arrays for fused scoring, and its choice of
@@ -1332,41 +1307,6 @@ class JaxExecutor:
             np.asarray(rw, np.float32),
             np.asarray(hr, np.int64),
             np.asarray(hw, np.float32),
-        )
-
-    def fused_plan(self, fs, si: int, field: str, terms, boost: float, msm: int):
-        """(rare_tiles, rare_w, hot_ranks, hot_w, msm) for FusedScorer,
-        or None when the query overflows the fixed slot budgets."""
-        pf = self.reader.segments[si].postings[field]
-        weights = self._segment_weights(si, field)
-        rt: list = []
-        rw: list = []
-        hr: list = []
-        hw: list = []
-        for t in terms:
-            tid = pf.term_id(t)
-            if tid < 0:
-                continue
-            w = float(weights[tid]) * boost
-            r = fs.hot_rank.get(tid)
-            if r is not None:
-                hr.append(r)
-                hw.append(w)
-            else:
-                s0 = int(pf.term_tile_start[tid])
-                c = int(pf.term_tile_count[tid])
-                rt.extend(range(s0, s0 + c))
-                rw.extend([w] * c)
-        if len(rt) > fs.t_rare or len(hr) > fs.n_hot_slots:
-            return None
-        if fs.wide is not None:
-            scoring.wide_rows_first(hr, hw, fs.dense)
-        return (
-            np.asarray(rt, np.int64),
-            np.asarray(rw, np.float32),
-            np.asarray(hr, np.int64),
-            np.asarray(hw, np.float32),
-            msm,
         )
 
     def _sort_ranks(self, si: int, field: str, desc: bool):

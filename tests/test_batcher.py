@@ -216,6 +216,34 @@ class TestDirectBatcher:
 
 
 class TestFusedPath:
+    def test_zero_boost_match_leaves_the_fused_kernel(self, monkeypatch):
+        """The fused plan's weights carry the count flag in their sign,
+        so a `match` whose boost is 0 is scored by the chunked path: an
+        `and` of zero-weight terms still matches by count, with the
+        unbatched executor's page, scores of exactly 0.0 and total."""
+        from elasticsearch_tpu.search import executor_jax
+
+        monkeypatch.setattr(executor_jax, "FUSED_MIN_DOCS", 10)
+        svc = make_service(n_docs=200, seed=3)
+        try:
+            body = {"query": {"match": {"body": {
+                "query": "alpha beta", "operator": "and", "boost": 0}}},
+                "size": 10, "track_total_hits": True}
+            stats = svc._batcher.stats
+            before = dict(stats)
+            served = svc.search(body)
+            assert stats["fused_jobs"] == before["fused_jobs"]
+            assert stats["fused_overflow_jobs"] == (
+                before["fused_overflow_jobs"] + 1)
+            unbatched = svc.search({**body, "min_score": -1})
+            assert served["hits"]["total"]["value"] > 0
+            assert served["hits"]["total"] == unbatched["hits"]["total"]
+            assert [(h["_id"], h["_score"]) for h in served["hits"]["hits"]
+                    ] == [(h["_id"], 0.0)
+                          for h in unbatched["hits"]["hits"]]
+        finally:
+            svc.close()
+
     def test_fused_parity_with_unbatched(self, monkeypatch):
         """Force the fused single-round-trip scorer (normally gated to
         large segments) and check hit-for-hit parity + exact totals."""

@@ -199,23 +199,28 @@ def test_filtered_byte_knn_programs(one_chip, rows):
 
 
 def _plan_width(n_fields: int) -> int:
-    # FusedScorer / MultiFusedScorer.plan_shape_rows
+    # MultiFusedScorer.plan_shape_rows
     return n_fields * (2 * scoring.FUSED_T_RARE + 2 * scoring.FUSED_H) + 1
 
 
-def _lower_match(s, rows: int):
-    """FusedScorer's program (`match` on one field), lowered."""
-    return scoring._fused_query.lower(
-        s((BODY_TILES, TILE), jnp.int32),
-        s((BODY_TILES, TILE), jnp.int32),
-        s((N_DOCS,), jnp.float32),
+def _lower_match(s, rows: int, counted: bool = False, hot: int = BODY_HOT,
+                 tiles: int = BODY_TILES):
+    """The fused program as `match` launches it: one field, no
+    tie_breaker operand, uncounted unless a job of the launch holds a
+    count threshold; lowered."""
+    return scoring._fused_query_mf.lower(
+        (s((tiles, TILE), jnp.int32),),
+        (s((tiles, TILE), jnp.int32),),
+        (s((N_DOCS,), jnp.float32),),
+        (s((hot, N_DOCS), jnp.uint8),),
         None,  # live: no deletes in a freshly built segment
-        s((BODY_HOT, N_DOCS), jnp.uint8),
         s((rows, _plan_width(1)), jnp.int32),
+        None,  # tie: nothing reads it at one field, nothing is uploaded
         t_rare=scoring.FUSED_T_RARE,
         n_hot=scoring.FUSED_H,
         k=16,
-        with_cnt=False,
+        combine="sum",
+        counted=counted,
     )
 
 
@@ -297,26 +302,46 @@ def test_fused_bool_program_at_passage_shapes(one_chip):
     assert "popcnt" in compiled.as_text() or "population" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows", [1, 8])
+def test_uncounted_match_program_holds_no_count_plane(one_chip, rows):
+    """At the passage cell's shapes (`msmarco-passage-bm25`: 1,000,000
+    docs, 1,160,811 tiles, 500 dense rows) the program a `match` launch
+    of `or` jobs runs allocates no int32 count plane of rows x (n + 1)
+    and scatters once a trip of its rare loop (the scores); the counted
+    program of the same shapes holds the plane and the second scatter,
+    and the clause counters' population count."""
+    plane = f"s32[{rows * (N_DOCS + 1)}]"
+    texts = {
+        counted: _lower_match(
+            _on(one_chip), rows, counted, hot=500, tiles=1_160_811,
+        ).compile().as_text()
+        for counted in (False, True)
+    }
+    scatters = {c: len(re.findall(r" scatter\(", t)) for c, t in texts.items()}
+    assert plane not in texts[False] and scatters[False] == 1
+    assert plane in texts[True] and scatters[True] == 2
+    assert "popcnt" not in texts[False] and "popcnt" in texts[True]
+    assert all(" while(" in t for t in texts.values())
+
+
 @pytest.mark.parametrize("family", ["match", "serve"])
 def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
     """The rare-term pass compiles as a `while` over chunks of
-    RARE_CHUNK tiles inside the family's one program a row bucket: no
-    operand as wide as the slot budget (rows x 256 tiles x 128 postings)
-    is left, and nothing but the plan's shape decides the program, so
-    the programs `_warm_ladder` compiles a family are its row buckets, as
-    before the loop (no static argument was added to either program)."""
+    RARE_CHUNK tiles inside the one program a row bucket, as `match`
+    (one field, uncounted) and as the serve family launch it: no operand
+    as wide as the slot budget (rows x 256 tiles x 128 postings) is
+    left, and nothing but the plan's shape and whether the launch counts
+    decides the program, so the programs `_warm_ladder` compiles a
+    family are its row buckets, as before the loop."""
     import inspect
 
     rows = 1
-    fn, statics, lower = {
-        "match": (scoring._fused_query,
-                  {"t_rare", "n_hot", "k", "with_cnt"}, _lower_match),
-        "serve": (scoring._fused_query_mf,
-                  {"t_rare", "n_hot", "k", "combine"}, _lower_multi_field),
-    }[family]
+    lower = {"match": _lower_match, "serve": _lower_multi_field}[family]
     lowered = lower(_on(one_chip), rows)
-    params = inspect.signature(fn.__wrapped__).parameters.values()
-    assert {p.name for p in params if p.kind == p.KEYWORD_ONLY} == statics
+    params = inspect.signature(
+        scoring._fused_query_mf.__wrapped__).parameters.values()
+    assert {p.name for p in params if p.kind == p.KEYWORD_ONLY} == {
+        "t_rare", "n_hot", "k", "combine", "counted"}
     text = lowered.compile().as_text()
     budget = rows * scoring.FUSED_T_RARE * TILE
     chunk = rows * scoring.RARE_CHUNK * TILE

@@ -265,24 +265,51 @@ class TestBucketParity:
                     assert_same_topdocs(g, r, kind)
         tiny.close()
 
-    def test_fused_engine_bucket_parity(self, monkeypatch):
+    @pytest.mark.parametrize("counted", [
+        None,
+        {"operator": "and"},
+        {"minimum_should_match": 2},
+    ], ids=["or_jobs", "and_job_beside_or", "msm_job_beside_or"])
+    def test_fused_engine_bucket_parity(self, monkeypatch, counted):
         """Force the fused single-round-trip scorer (normally gated to
         large segments) so the bucketed plan upload path is exercised
-        too — not just the chunked engine."""
+        too — not just the chunked engine. With `counted`, one job of
+        every launch holds a count threshold, which makes the launch of
+        the `or` jobs beside it a counted one: every job still answers
+        with the unbatched executor's hits and totals."""
         from elasticsearch_tpu.search import executor_jax
 
         monkeypatch.setattr(executor_jax, "FUSED_MIN_DOCS", 10)
         svc = make_service(n_docs=300, seed=7, name="cb-fused")
         try:
             ex = svc._executor(svc.shards[0])
-            assert ex.fused_scorer(0, "body") is not None
+            assert ex.fused_scorer_mf(0, ("body",)) is not None
             tiny = workerless(monkeypatch, workers=1)
+            before = dict(tiny.stats)
             for rows in (1, 4, 32):
                 plans = match_plans(svc, rows)
+                if counted:
+                    q = dsl.parse_query({"match": {"body": {
+                        "query": "alpha beta gamma", **counted}}})
+                    p = extract_match_plan(
+                        q, svc.mappings, svc.analysis, 10_000)
+                    assert p.msm > 1
+                    plans[-1] = (p, q)
                 got = run_bucket(tiny, ex, plans, "match", 16, rows)
                 ref = run_bucket(tiny, ex, plans, "match", 16, scoring.BPAD)
-                for g, r in zip(got, ref):
+                for g, r, (_, q) in zip(got, ref, plans):
                     assert td_fingerprint(g) == td_fingerprint(r), rows
+                    alone = ex.search(q, size=10)
+                    assert [(h.doc_id, h.score) for h in g.hits] == [
+                        (h.doc_id, pytest.approx(h.score, rel=1e-6))
+                        for h in alone.hits]
+                    assert (g.total, g.relation) == (
+                        alone.total, alone.relation)
+            # every job of every launch stayed in the fused kernel
+            segs = len(ex.reader.segments)
+            assert tiny.stats["launches"] - before["launches"] == 6 * segs
+            assert tiny.stats["fused_jobs"] - before["fused_jobs"] == (
+                2 * (1 + 4 + 32) * segs)
             tiny.close()
         finally:
             svc.close()
@@ -486,7 +513,6 @@ def _cache_sizes():
         "_chunk_add": scoring._chunk_add,
         "_chunk_add_cnt": scoring._chunk_add_cnt,
         "_finalize": scoring._finalize,
-        "_fused_query": scoring._fused_query,
         "_fused_query_mf": scoring._fused_query_mf,
         "_merge_segments": scoring._merge_segments,
         "_knn_merge_segments": scoring._knn_merge_segments,

@@ -3,8 +3,8 @@ passes `scoring.DENSE_TF_MAX` (255) in some document keeps a dense row,
 a uint16 one; nothing is clipped.
 
 Contract under test: on a two-field segment holding hot terms whose
-largest tf is 255, 256 and 4,000, the fused paths (`_fused_query` for
-`match`, `_fused_query_mf` for `multi_match` as `sum` and `max_tie`) give
+largest tf is 255, 256 and 4,000, the fused program (`_fused_query_mf`:
+`match` at one field, `multi_match` as `sum` and `max_tie`) gives
 the ids, order, totals and scores (rtol 1e-6) of the NumPy executor, and
 the very floats of the all-sparse scoring of the same terms (no dense row
 at all) for one- and two-term queries, with the dense budget ample and
@@ -106,7 +106,7 @@ def with_budget(svc, monkeypatch, budget: str):
         monkeypatch.setattr(executor_jax, "DENSE_ROWS_HBM_BUDGET",
                             BUDGETS[budget])
     ex = svc._executor(svc.shards[0])
-    for cache in (ex._fused_parts, ex._fused_scorers, ex._fused_mf):
+    for cache in (ex._fused_parts, ex._fused_mf):
         cache.clear()
     return ex
 

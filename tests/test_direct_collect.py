@@ -76,7 +76,7 @@ def load_fused(svc, fused_min_docs):
     try:
         ex = svc._executor(svc.shards[0])
         return [
-            ex.fused_scorer(si, "body") is not None
+            ex.fused_scorer_mf(si, ("body",)) is not None
             and ex.fused_scorer_mf(si, ("title", "body")) is not None
             for si in range(len(ex.reader.segments))
         ]
@@ -314,7 +314,6 @@ class TestWhatARequestLaunches:
         return calls
 
     def test_no_eager_unpacking_is_left(self):
-        assert not hasattr(scoring.FusedScorer, "device_result")
         assert not hasattr(scoring.MultiFusedScorer, "device_result")
 
     @pytest.mark.parametrize("family", FAMILIES)
@@ -338,6 +337,31 @@ class TestWhatARequestLaunches:
         # kernel's packed i32[1, 2 * 16 + 1]
         assert moved["h2d_count"] == (1 if family == "match" else 2)
         assert (moved["d2h_count"], moved["d2h_bytes"]) == (1, 132)
+
+    @pytest.mark.parametrize("match", [
+        "alpha beta", {"query": "alpha beta", "operator": "and"},
+    ], ids=["uncounted", "counted"])
+    def test_a_fused_match_launch_moves_its_plan_and_its_page(
+        self, one_seg, match
+    ):
+        """Up: the packed one-field plan, rows x (2T + 2H + 1) int32,
+        and nothing beside it (no tie_breaker scalar: nothing reads one
+        at one field); down: the packed page, rows x (2k + 1) int32, in
+        one blocking download; counted or not."""
+        body = {"query": {"match": {"body": match}}, "size": 10}
+        one_seg.search(json.loads(json.dumps(body)))  # warm
+        rows, k = 1, 16
+        T, H = scoring.FUSED_T_RARE, scoring.FUSED_H
+        jobs = one_seg._batcher.stats["fused_jobs"]
+        xfer = tracing.transfer_stats()
+        assert one_seg.search(json.loads(json.dumps(body)))["hits"]["hits"]
+        assert one_seg._batcher.stats["fused_jobs"] == jobs + 1
+        moved = {k_: v - xfer[k_]
+                 for k_, v in tracing.transfer_stats().items()}
+        assert moved == {
+            "h2d_count": 1, "h2d_bytes": 4 * rows * (2 * T + 2 * H + 1),
+            "d2h_count": 1, "d2h_bytes": 4 * rows * (2 * k + 1),
+        }
 
     @pytest.mark.parametrize("family", FAMILIES)
     def test_two_segment_request_merges_once(

@@ -95,9 +95,9 @@ def dispatch_tags(svc, body: dict) -> dict:
 
 def test_the_index_really_has_hot_rows(service):
     ex = service._executor(service.shards[0])
-    fs = ex.fused_scorer(0, "body")
+    fs = ex.fused_scorer_mf(0, ("body",))
     assert fs is not None and fs.n_hot_slots == H
-    assert fs.dense.shape == (len(HOT), N_DOCS)
+    assert fs.parts[0]["dense"].shape == (len(HOT), N_DOCS)
     assert fs.plan_shape_rows(1) == (1, 2 * scoring.FUSED_T_RARE + 2 * H + 1)
 
 
@@ -128,7 +128,7 @@ def assert_same_as_chunked_and_oracle(service, oracle, monkeypatch, body,
     """ids, order, scores (rtol 1e-6) and totals of `served` against the
     chunked path alone (no fused scorer for the segment) and the oracle."""
     ex = service._executor(service.shards[0])
-    monkeypatch.setattr(ex, "fused_scorer", lambda si, field: None)
+    monkeypatch.setattr(ex, "fused_scorer_mf", lambda si, fields: None)
     stats = service._batcher.stats
     before = stats["fused_jobs"]
     chunked = search(service, body)

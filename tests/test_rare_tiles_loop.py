@@ -1,12 +1,12 @@
-"""The fused text programs' rare-term pass (`scoring._add_rare_tiles`).
+"""The fused text program's rare-term pass (`scoring._add_rare_tiles`).
 
 Contract under test: the pass walks the tile slots a launch CARRIES, in
 chunks of `scoring.RARE_CHUNK`, and a launch's packed result (scores,
 doc order, totals) is bit-equal on the CPU to the one-pass scatter over
 all `FUSED_T_RARE` slots that it replaced (kept here as the plain form),
 and agrees with a float64 NumPy scoring of the same plans; for `match`
-(with and without the count plane) and for the serve family's `sum` and
-`max_tie` combines with signed weights. The trip count depends on the
+(one field, launched uncounted and counted) and for the serve family's
+`sum` and `max_tie` combines with signed weights. The trip count depends on the
 plan alone, so one program serves every tile count; and the batcher's
 counters say how many slots its launches scattered of how many budgeted.
 """
@@ -38,8 +38,8 @@ TIE = np.float32(0.3)
 def one_pass_rare_tiles(acc, cnt, doc_ids, tfs, inv_norm, rare_ti, rare_tw,
                         signed, clauses=False):
     """The plain form: gather, score and scatter-add every slot of the
-    budget at once, used or not, a scatter a row (the rare pass of both
-    programs before the loop), on `_add_rare_tiles`' flat planes."""
+    budget at once, used or not, a scatter a row (the rare pass before
+    the loop), on `_add_rare_tiles`' flat planes."""
     n = inv_norm.shape[0]
     B = rare_ti.shape[0]
     if clauses:  # ids carry their clause counter: its unit counts
@@ -134,29 +134,26 @@ def launch(program: str, fields, plans, rows: int, rare_pass=None):
     """The packed result of one launch of `program` over `plans`
     [(sections, msm)]; with `rare_pass`, of the same program traced
     afresh with that function in `_add_rare_tiles`' place."""
-    if program.startswith("mf"):
-        fs = scoring.MultiFusedScorer(("title", "body"), fields, None)
-        fn, statics = scoring._fused_query_mf, {
-            "t_rare": T, "n_hot": H, "k": K, "combine": program[3:]}
-        packed = fs.pack_plans(plans, rows=rows)
-        args = (
-            tuple(f["doc_ids"] for f in fields),
-            tuple(f["tfs"] for f in fields),
-            tuple(f["inv_norm"] for f in fields),
-            tuple(f["dense"] for f in fields),
-            None, jnp.asarray(packed), TIE,
-        )
-    else:
-        f = fields[0]
-        fs = scoring.FusedScorer(
-            f["doc_ids"], f["tfs"], f["inv_norm"], None, f["dense"])
-        fn, statics = scoring._fused_query, {
-            "t_rare": T, "n_hot": H, "k": K,
-            "with_cnt": program == "match_cnt"}
-        packed = fs.pack_plans(
-            [(*sections[0], msm) for sections, msm in plans], rows=rows)
-        args = (f["doc_ids"], f["tfs"], f["inv_norm"], None, f["dense"],
-                jnp.asarray(packed))
+    # `match`: one field, no tie_breaker, counted only where a job holds
+    # a count threshold
+    one_field = program.startswith("match")
+    if one_field:
+        fields = fields[:1]
+    fs = scoring.MultiFusedScorer(
+        ("title", "body")[:len(fields)], fields, None)
+    fn, statics = scoring._fused_query_mf, {
+        "t_rare": T, "n_hot": H, "k": K,
+        "combine": "sum" if one_field else program[3:],
+        "counted": program != "match"}
+    packed = fs.pack_plans(
+        [(secs[:len(fields)], msm) for secs, msm in plans], rows=rows)
+    args = (
+        tuple(f["doc_ids"] for f in fields),
+        tuple(f["tfs"] for f in fields),
+        tuple(f["inv_norm"] for f in fields),
+        tuple(f["dense"] for f in fields),
+        None, jnp.asarray(packed), None if one_field else TIE,
+    )
     if rare_pass is None:
         return np.asarray(fn(*args, **statics))
     orig = scoring._add_rare_tiles
@@ -244,11 +241,11 @@ def test_a_budget_that_is_no_multiple_of_the_chunk(fields):
     """`t_rare` is a constructor argument: a budget of 20 slots takes
     two trips of 16, the second half padding."""
     f = fields[0]
-    fs = scoring.FusedScorer(
-        f["doc_ids"], f["tfs"], f["inv_norm"], None, f["dense"], t_rare=20)
+    fs = scoring.MultiFusedScorer(("body",), [f], None, t_rare=20)
     rng = np.random.default_rng(3)
     secs = [section(rng, n, 1, False) for n in (20, 17)]
-    s, d, tot = fs.search([(*sec, 1) for sec in secs], K, False, rows=2)
+    s, d, tot = fs.search(
+        [([sec], 1) for sec in secs], K, "sum", None, rows=2, counted=False)
     for row, sec in enumerate(secs):
         score, cnt = numpy_field_scores(f, sec)
         want_s, want_d, want_total = numpy_topk(score, score > 0)
@@ -259,12 +256,12 @@ def test_a_budget_that_is_no_multiple_of_the_chunk(fields):
 
 def test_one_program_serves_every_tile_count(fields):
     """The trip count is data: launches of 0, 1 and 256 tiles at one
-    row bucket compile `_fused_query` once."""
+    row bucket compile `_fused_query_mf` once."""
     launch("match", fields, make_plans("match", [3], seed=1), rows=2)
-    before = scoring._fused_query._cache_size()
+    before = scoring._fused_query_mf._cache_size()
     for used in (0, 1, T):
         launch("match", fields, make_plans("match", [used], seed=2), rows=2)
-    assert scoring._fused_query._cache_size() == before
+    assert scoring._fused_query_mf._cache_size() == before
 
 
 @pytest.mark.parametrize("rows, counts, want", [

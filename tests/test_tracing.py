@@ -227,11 +227,11 @@ class TestBatcherJobSpans:
         self, fused_service, monkeypatch, body, family, overflow
     ):
         ex = fused_service._executor(fused_service.shards[0])
-        fits = ex.fused_plan
+        fits = ex.fused_plan_field
         monkeypatch.setattr(
-            ex, "fused_plan",
-            lambda fs, si, field, terms, boost, msm: None
-            if len(terms) > 4 else fits(fs, si, field, terms, boost, msm))
+            ex, "fused_plan_field",
+            lambda si, field, parts, terms, boost: None
+            if len(terms) > 4 else fits(si, field, parts, terms, boost))
         fused_service.search(json.loads(json.dumps(body)))  # compile
         t_before = time.perf_counter_ns()
         _, by = traced_search(fused_service, body)
@@ -389,7 +389,7 @@ class TestBatcherJobSpans:
         body = {**MATCH, "size": 40}
         spans, by = traced_search(fused_service, body)
         compiles = [s for s in spans if s["name"] == "compile"]
-        assert {s["tags"]["program"] for s in compiles} == {"_fused_query"}
+        assert {s["tags"]["program"] for s in compiles} == {"_fused_query_mf"}
         for s in compiles:
             assert s["tags"]["seconds"] > 0
             assert s["parent_id"] == by["dispatch"]["id"]
