@@ -1599,6 +1599,7 @@ class IndexService:
             from ..search.batcher import (
                 extract_knn_plan,
                 extract_match_plan,
+                extract_phrase_plan,
                 extract_serve_plan,
                 extract_sparse_plan,
                 split_filtered_bool,
@@ -1623,6 +1624,14 @@ class IndexService:
                     plan = extract_match_plan(
                         query, self.mappings, self.analysis, tth
                     )
+                    if plan is None:
+                        # a bare exact `match_phrase`: the `phrase`
+                        # family (what it turns away, a sloppy or a
+                        # one-word phrase, no later planner takes)
+                        plan = extract_phrase_plan(
+                            query, self.mappings, self.analysis
+                        )
+                        kind = "phrase"
                     if plan is None:
                         plan = extract_serve_plan(
                             query, self.mappings, self.analysis
@@ -2622,6 +2631,9 @@ class IndexService:
                         # of several terms counts once however many of
                         # them a document holds: the shard path takes it
                         return None
+                    # a `match_phrase` gets no plan here either way: the
+                    # mesh step holds no positions, so the shards'
+                    # `phrase` family takes it (`plan is None` below)
         else:
             knn_body = body["knn"]
             knn = [

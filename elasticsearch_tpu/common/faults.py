@@ -42,6 +42,10 @@ Schedule shape (env `ES_TPU_FAULTS`, or `POST /_internal/faults`):
     segment — ctx carries field/segment; error kind proves the
     deterministic fallback to the unbatched executor's filter
     evaluation (exact answers, `knn_filtered.fallbacks` bump))
+  - ``phrase.score``        (a phrase group's kernel launch, per
+    segment — ctx carries field/segment; error kind proves the
+    deterministic fallback to the unbatched executor's `_exec_phrase`
+    (exact answers, `phrase.fallbacks` bump))
   - ``sparse.score``        (learned-sparse impact-tile scoring — per
     segment on the batcher path with ctx field/segment, mesh=1 on the
     SPMD path; error kind proves the deterministic impact→dense-host-

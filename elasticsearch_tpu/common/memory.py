@@ -26,7 +26,12 @@ fit DEGRADES TO SKIP — the request keeps its first-stage ranking).
 (executor_jax.impact_scorer: per-(segment, field, storage-mode) uploads
 of the impact-ordered doc/value planes, int8 or fp32; a column that
 cannot fit DEGRADES to the dense fp32 host oracle — exact answers,
-just not device-served). Per-category bytes surface as child breakers
+just not device-served). `positions` holds the text fields' positions
+planes (executor_jax.DeviceSegment.positions: the position-major term-id
+matrices the phrase kernel reads, uploaded when a field is first asked a
+bare phrase; an upload that cannot fit is REFUSED and the segment's
+phrases are served per job by the unbatched executor's `_exec_phrase`).
+Per-category bytes surface as child breakers
 in `_nodes/stats` (child_breakers())."""
 
 from __future__ import annotations

@@ -95,13 +95,19 @@ starts at `http`'s start and reaches the ring when `http` ends:
             (sums over its jobs and segments) and quantized; a filtered knn group filtered,
             clauses (the most a job's filter holds) and filter_tiles
             (postings tiles its mask launches scattered, summed over
-            jobs and segments)]
+            jobs and segments); a phrase group words (its jobs' words)
+            and occurrences (position entries its launches were handed,
+            summed over jobs and segments)]
             -> the group's last kernel is enqueued
           > filter_mask [segment, launches, tiles]  a filtered knn
             group's masks on one segment: the filters' terms looked up
             and packed, the plan uploaded, `knn_filter_mask` enqueued
             (one launch; the device builds each row's mask from the
             postings tiles; no host sync)
+          > phrase_plan [segment, launches, words]  a phrase group
+            on one segment: the words looked up in the term dictionary,
+            the plan packed and uploaded, `phrase_topk` enqueued (one
+            launch; no host sync)
           > sparse_theta [segment, launches, postings]  a sparse
             group's thresholds on one segment, computed on the host
             from its prunable jobs' first tiles (`postings` slots

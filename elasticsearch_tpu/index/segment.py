@@ -102,6 +102,8 @@ class PostingsField:
     impacts: Optional[np.ndarray] = None  # int8[n_tiles, TILE]
     impact_scales: Optional[np.ndarray] = None  # float32[n_terms]
     _term_index: Optional[Dict[str, int]] = None
+    # PositionsPlane | False (none can be built) | None (not asked yet)
+    _positions_plane: object = None
 
     def term_id(self, term: str) -> int:
         if self._term_index is None:
@@ -115,6 +117,15 @@ class PostingsField:
     @property
     def has_positions(self) -> bool:
         return self.pos_data is not None
+
+    def positions_plane(self, n_docs: int):
+        """The field's `PositionsPlane` (built at its first use and kept:
+        a segment is immutable), or None: no positions, a field past
+        the layout's limits, or tokens stacked at one position."""
+        if self._positions_plane is None:
+            self._positions_plane = (
+                build_positions_plane(self, n_docs) or False)
+        return self._positions_plane or None
 
     def term_docs(self, tid: int) -> np.ndarray:
         """Compact (unpadded) sorted doc-id list for one term."""
@@ -133,6 +144,158 @@ class PostingsField:
             return None
         p = int(self.term_pos_start[tid]) + k
         return self.pos_data[self.pos_offsets[p] : self.pos_offsets[p + 1]]
+
+
+# ---- the positions plane: word order as the device reads it ----------
+#
+# The CSR above answers "where does term t stand in document d"; a
+# phrase asks the converse of every document at once: "which term stands
+# at position p of document d". The plane is that forward view, laid out
+# for a streaming compare on the device: documents are binned by their
+# slot count (last position + 1) into classes of fixed width, and a
+# class is ONE int32 matrix [width, documents of the class], POSITION-
+# MAJOR: row p holds the term id at position p of every document of the
+# class (-1 where no token stands: past a document's end, a removed stop
+# word, the gap between the values of an array). A phrase of W words is
+# then W shifted row-slices compared with W scalars and AND-ed, summed
+# down the position axis: the phrase frequency of every document of the
+# class, with no gather, no sort and no shape that follows a word's
+# frequency. Documents sit along the minor (lane) axis, so a class of
+# any width tiles the device's (8, 128) layout without padding waste.
+
+PLANE_MIN_WIDTH = 8  # the narrowest class; also the longest phrase span
+# limits of the packed sort key (document 24 bits, position 16, term 24):
+# a field past any of them gets no plane and its phrases stay on the
+# host path
+PLANE_MAX_DOCS = 1 << 24
+PLANE_MAX_SLOTS = 1 << 16
+PLANE_MAX_TERMS = 1 << 24
+
+
+def plane_class_widths(max_slots: int) -> List[int]:
+    """Class widths up to the first that holds `max_slots` slots: 8, 16,
+    24, 32, 48, 64, 96, 128, ... (2^j and 1.5 x 2^j, every one a
+    multiple of 8): a document wastes under a third of its class."""
+    widths = [PLANE_MIN_WIDTH]
+    step = 16
+    while widths[-1] < max_slots:
+        widths.append(step)
+        if widths[-1] < max_slots and step >= 16:
+            widths.append(step + step // 2)
+        step *= 2
+    return widths
+
+
+@dataclass
+class PositionsPlane:
+    """One text field's positions as the phrase kernel reads them
+    (`build_positions_plane`; ops/phrase.py)."""
+
+    widths: Tuple[int, ...]  # the classes that hold a document, ascending
+    mats: List[np.ndarray]  # int32[width, n_class]: term id, -1 = none
+    # int32[n_plane]: the document of each plane column, class by class,
+    # ascending inside a class; documents without a token have no column
+    order: np.ndarray
+    occurrences: int  # token positions held (the CSR's len(pos_data))
+
+    @property
+    def nbytes(self) -> int:
+        return int(sum(m.nbytes for m in self.mats) + self.order.nbytes)
+
+
+def build_positions_plane(
+    pf: "PostingsField", n_docs: int
+) -> Optional[PositionsPlane]:
+    """The positions plane of one field from the columnar positions a
+    refresh leaves (`SegmentBuilder._attach_positions`, on the host and
+    the device build path alike); None where the field holds no
+    positions, passes a limit of the layout or stacks two tokens at one
+    position. Vectorised: one sort of the field's occurrences by
+    (document, position)."""
+    if pf.pos_data is None or not len(pf.pos_data):
+        return None
+    n_terms = len(pf.terms)
+    if n_docs > PLANE_MAX_DOCS or n_terms > PLANE_MAX_TERMS:
+        return None
+    if int(pf.pos_data.max()) >= PLANE_MAX_SLOTS:
+        return None
+    # postings in (term, document) order, as the CSR counts them:
+    # posting k of term t is slot k of the term's tile range
+    df = pf.term_df.astype(np.int64)
+    slot = np.arange(int(df.sum()), dtype=np.int64) + np.repeat(
+        pf.term_tile_start.astype(np.int64) * TILE - pf.term_pos_start, df)
+    post_doc = pf.doc_ids.ravel()[slot].astype(np.uint64)
+    del slot
+    per_post = np.diff(pf.pos_offsets)
+    key = np.repeat(
+        (post_doc << np.uint64(40))
+        | np.repeat(np.arange(n_terms, dtype=np.uint64), pf.term_df),
+        per_post,
+    )
+    key |= pf.pos_data.astype(np.uint64) << np.uint64(24)
+    key.sort()  # (document, position, term)
+    doc = (key >> np.uint64(40)).astype(np.int32)
+    pos = ((key >> np.uint64(24)) & np.uint64(0xFFFF)).astype(np.int64)
+    term = (key & np.uint64(0xFFFFFF)).astype(np.int32)
+    del key
+    return plane_from_occurrences(doc, pos, term)
+
+
+def plane_from_occurrences(
+    doc: np.ndarray, pos: np.ndarray, term: np.ndarray
+) -> Optional[PositionsPlane]:
+    """The positions plane of a field's token occurrences, given as
+    parallel arrays SORTED by (document, position): `doc` int32, `pos`
+    int64 (consumed), `term` int32 ids of the field's dictionary. What
+    `build_positions_plane` calls once it has turned the term-major CSR
+    around; a caller that holds the forward stream already (a corpus
+    builder) calls it directly. None under the same limits, and where
+    two tokens share a position (an index-time synonym filter stacks
+    them): a slot of the plane holds one term."""
+    if (not len(doc) or int(doc[-1]) >= PLANE_MAX_DOCS
+            or int(pos.max()) >= PLANE_MAX_SLOTS):
+        return None
+    if np.any((doc[1:] == doc[:-1]) & (pos[1:] == pos[:-1])):
+        return None
+    occurrences = len(doc)
+    # a document's slots: its last position + 1 (occurrences are sorted)
+    last = np.flatnonzero(doc[1:] != doc[:-1])
+    last = np.append(last, len(doc) - 1)
+    held = doc[last]  # documents with a token, ascending
+    slots = pos[last] + 1
+    all_widths = np.asarray(plane_class_widths(int(slots.max())), np.int64)
+    cls = np.searchsorted(all_widths, slots)  # smallest width >= slots
+    used = np.unique(cls)
+    counts = np.bincount(cls, minlength=len(all_widths))
+    # one flat buffer, a class after the other, filled DOCUMENT-major
+    # (a document's tokens side by side: sequential writes), then each
+    # class turned position-major
+    sizes = all_widths[used] * counts[used]
+    base = np.zeros(len(all_widths), np.int64)
+    base[used] = np.r_[0, np.cumsum(sizes)[:-1]]
+    by_class = np.argsort(cls, kind="stable")  # documents, class-major
+    order = held[by_class].astype(np.int32)
+    col = np.empty(len(held), np.int64)  # a document's column in its class
+    col[by_class] = np.arange(len(held)) - np.repeat(
+        np.r_[0, np.cumsum(counts[used])[:-1]], counts[used])
+    row = base[cls] + col * all_widths[cls]  # a document's first slot
+    per_doc = np.diff(last, prepend=-1)
+    pos += np.repeat(row, per_doc)
+    del doc
+    flat = np.full(int(sizes.sum()), -1, np.int32)
+    flat[pos] = term
+    del pos, term
+    mats = [
+        np.ascontiguousarray(
+            flat[base[c]: base[c] + all_widths[c] * counts[c]].reshape(
+                int(counts[c]), int(all_widths[c])).T)
+        for c in used
+    ]
+    del flat
+    return PositionsPlane(
+        widths=tuple(int(all_widths[c]) for c in used), mats=mats,
+        order=order, occurrences=occurrences,
+    )
 
 
 @dataclass

@@ -862,14 +862,17 @@ class MultiFusedScorer:
         ))
 
 
-def decode_result(pending):
+def decode_result(pending, extra: int = 0):
     """Blocks on the device transfer of `search_async`'s packed result
-    and unpacks to (scores f32[B,k], docs i32[B,k], totals i64[B])."""
+    and unpacks to (scores f32[B,k], docs i32[B,k], totals i64[B]); a
+    row with `extra` trailing counters gives them fourth, i64[B, extra]."""
     out, k = pending
     out = _to_host(out)
     scores = out[:, :k].copy().view(np.float32)
     docs = out[:, k : 2 * k]
     totals = out[:, 2 * k].astype(np.int64)
+    if extra:
+        return scores, docs, totals, out[:, 2 * k + 1:].astype(np.int64)
     return scores, docs, totals
 
 
@@ -985,27 +988,28 @@ def _fused_query_mf(
 # ---------------------------------------------------------------------------
 
 
-def _part_width(part) -> int:
-    """Candidates a row of one segment's part holds."""
+def _part_width(part, extra: int = 0) -> int:
+    """Candidates a row of one segment's part holds (`extra`: int32
+    counters a packed row carries past its total, ops/phrase.py)."""
     if isinstance(part, tuple):
         return int(part[0].shape[1])
-    return (int(part.shape[1]) - 1) // 2
+    return (int(part.shape[1]) - 1 - extra) // 2
 
 
-def _unpack_part(part):
+def _unpack_part(part, extra: int = 0):
     """(scores f32[B,k], docs i32[B,k], totals i32[B]) of one segment's
     candidates, traced: a triple as it is, a fused launch's packed row
     sliced and bitcast."""
     if isinstance(part, tuple):
         return part
-    k = _part_width(part)
+    k = _part_width(part, extra)
     scores = jax.lax.bitcast_convert_type(part[:, :k], jnp.float32)
     return scores, part[:, k : 2 * k], part[:, 2 * k]
 
 
-@functools.partial(jax.jit, static_argnames=("segs", "k"))
-def _merge_segments(parts, segs, k):
-    s_list, d_list, t_list = zip(*(_unpack_part(p) for p in parts))
+@functools.partial(jax.jit, static_argnames=("segs", "k", "extra"))
+def _merge_segments(parts, segs, k, extra=0):
+    s_list, d_list, t_list = zip(*(_unpack_part(p, extra) for p in parts))
     scores = jnp.concatenate(s_list, axis=1)  # [B, total_slots]
     docs = jnp.concatenate(d_list, axis=1)
     seg_of_slot = jnp.asarray(np.repeat(
@@ -1015,10 +1019,11 @@ def _merge_segments(parts, segs, k):
     seg = seg_of_slot[idx]
     doc = jnp.take_along_axis(docs, idx, axis=1)
     totals = jnp.stack([t.astype(jnp.int32) for t in t_list], axis=1)
-    return jnp.concatenate(
-        [jax.lax.bitcast_convert_type(s, jnp.int32), seg, doc, totals],
-        axis=1,
-    )
+    cols = [jax.lax.bitcast_convert_type(s, jnp.int32), seg, doc, totals]
+    if extra:
+        # packed rows' trailing counters, summed over the segments
+        cols.append(sum(p[:, -extra:] for p in parts))
+    return jnp.concatenate(cols, axis=1)
 
 
 def is_packed(part) -> bool:
@@ -1055,34 +1060,41 @@ def rank_order(scores: np.ndarray, segs: np.ndarray, docs: np.ndarray):
     return scores, segs, docs
 
 
-def packed_segment_topk(si: int, packed):
+def packed_segment_topk(si: int, packed, extra: int = 0):
     """`merge_segment_topk` of one fused launch, with no program: the
-    one blocking download of the kernel's packed i32[B, 2k+1], decoded
-    on the host. Same return, same floats, ids, order and totals."""
-    scores, docs, totals = decode_result((packed, _part_width(packed)))
-    return scores, np.full_like(docs, si), docs, totals[:, None]
+    one blocking download of the kernel's packed i32[B, 2k+1+extra],
+    decoded on the host. Same return, same floats, ids, order and
+    totals (and, with `extra`, the row's trailing counters last)."""
+    scores, docs, totals, *counters = decode_result(
+        (packed, _part_width(packed, extra)), extra)
+    return (scores, np.full_like(docs, si), docs, totals[:, None], *counters)
 
 
-def merge_segment_topk(items, k: int):
+def merge_segment_topk(items, k: int, extra: int = 0):
     """items: [(si, part)] in ascending segment order, `part` a fused
     launch's packed output i32[B, 2ki+1] or a device triple (scores
     f32[B,ki], docs i32[B,ki], totals i32[B]). Returns host arrays
     (scores f32[B,k], segments i32[B,k], docs i32[B,k], totals
     i64[B, n_segments]) via ONE program and ONE device→host transfer.
     Rows are ordered score desc / (segment, doc) asc; -inf entries pad
-    past the real candidates."""
-    k = min(k, sum(_part_width(p) for _, p in items))
+    past the real candidates. `extra`: every part is a packed row with
+    that many trailing counters; their sums over the segments come
+    fifth, i64[B, extra]."""
+    k = min(k, sum(_part_width(p, extra) for _, p in items))
     out = _to_host(
         _merge_segments(
             tuple(p for _, p in items),
             segs=tuple(int(si) for si, _ in items),
-            k=k,
+            k=k, extra=extra,
         )
     )
     scores = out[:, :k].copy().view(np.float32)
     segs = out[:, k : 2 * k]
     docs = out[:, 2 * k : 3 * k]
-    totals = out[:, 3 * k :].astype(np.int64)
+    totals = out[:, 3 * k : 3 * k + len(items)].astype(np.int64)
+    if extra:
+        return (scores, segs, docs, totals,
+                out[:, 3 * k + len(items):].astype(np.int64))
     return scores, segs, docs, totals
 
 

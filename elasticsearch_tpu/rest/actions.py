@@ -666,6 +666,17 @@ class RestActions:
             "filter_tiles": 0, "mask_launches": 0,
             "block_select_launches": 0, "fallbacks": 0,
         }
+        # the phrase family (QueryBatcher.phrase): scans on the device,
+        # their launches and words, position entries handed to them,
+        # documents holding every word and the words' occurrences inside
+        # them, documents matched, the bytes no exact search leaves
+        # unread, scans served by the unbatched executor
+        phrase = {
+            "searches": 0, "launches": 0, "words": 0,
+            "occurrences_read": 0, "candidates": 0,
+            "candidate_occurrences": 0, "matches": 0, "least_bytes": 0,
+            "fallbacks": 0,
+        }
         for idx in self.cluster.indices.values():
             with idx._rrf_lock:
                 for k, v in idx.rrf_stats.items():
@@ -680,6 +691,8 @@ class RestActions:
                 with b._lock:
                     for k, v in b.knn_filtered.items():
                         knn_filtered[k] += v
+                    for k, v in b.phrase.items():
+                        phrase[k] += v
                 queue_capacity = max(queue_capacity, b._queue.maxsize)
                 pipeline["depth"] = max(pipeline["depth"], b.pipeline_depth)
                 bs = b.batching_stats()
@@ -889,6 +902,7 @@ class RestActions:
                     "aggs": aggs_block,
                     "knn": knn_block,
                     "knn_filtered": knn_filtered,
+                    "phrase": phrase,
                     "rescore": rescore_block,
                     "sparse": sparse_block,
                     "translog": translog_block,

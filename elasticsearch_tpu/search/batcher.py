@@ -59,6 +59,7 @@ from ..common.tracing import (
     thread_d2h_bytes,
 )
 from ..index.mapping import KEYWORD, SPARSE_VECTOR, TEXT
+from ..ops import phrase as phrase_ops
 from ..ops import scoring
 from ..ops.scoring import BPAD
 from . import dsl
@@ -185,6 +186,56 @@ def extract_match_plan(
         msm=msm,
         boost=query.boost,
         tth_cap=_tth_cap(tth),
+    )
+
+
+@dataclass(frozen=True)
+class PhrasePlan:
+    """A bare exact `match_phrase` over one text field: the analyzed
+    words in the query's order, each at its position relative to the
+    first (the analyzer's position increments: a removed stop word
+    leaves a hole no word fills). `width`, the phrase's span in slots
+    (the last relative position + 1), rides the group key: one program
+    a span, so phrases of one span share a launch whatever their words
+    (ops/phrase.py). Scored as Lucene's PhraseWeight scores: one
+    pseudo-term, tf the phrase's frequency in the document, idf the sum
+    of the words' idfs."""
+
+    field: str
+    terms: Tuple[str, ...]
+    rel: Tuple[int, ...]
+    boost: float
+    width: int
+
+
+def extract_phrase_plan(query, mappings, analysis) -> Optional[PhrasePlan]:
+    """A PhrasePlan when `query` is a bare `match_phrase` at slop 0 over
+    a text field whose words span 2..PHRASE_TERMS_MAX slots, each slot
+    holding at most one word; else None -> the unbatched executor's
+    `_exec_phrase` (the caller counts it in `unplanned_queries`): a
+    sloppy phrase, a phrase that analyzes to one word or to none, a
+    longer one, two tokens at one position (a synonym filter)."""
+    if not isinstance(query, dsl.MatchPhraseQuery) or query.slop != 0:
+        return None
+    mf = mappings.get(query.field)
+    if mf is None or mf.type != TEXT:
+        return None
+    analyzer_name = query.analyzer or mf.search_analyzer or mf.analyzer
+    try:
+        toks = analysis.get(analyzer_name).analyze(query.query)
+    except ValueError:
+        return None
+    if len(toks) < 2:
+        return None
+    rel = tuple(t.position - toks[0].position for t in toks)
+    if any(b <= a for a, b in zip(rel, rel[1:])):
+        return None
+    width = phrase_ops.phrase_width(rel[-1] + 1)
+    if width is None:
+        return None
+    return PhrasePlan(
+        field=query.field, terms=tuple(t.text for t in toks), rel=rel,
+        boost=query.boost, width=width,
     )
 
 
@@ -928,6 +979,16 @@ FAMILIES: Dict[str, _Family] = {
             jobs, kb, pend, record=record),
         warm=_warm_first,
     ),
+    # exact phrases: the span in slots rides the key (one program a
+    # span); the words do not
+    "phrase": _Family(
+        "text", lambda p: (p.field, p.width),
+        lambda b, jobs, key, kb, rows, record: b._dispatch_phrase_group(
+            jobs, kb, rows=rows, record=record),
+        lambda b, jobs, key, kb, pend, record: b._collect_phrase_group(
+            jobs, kb, pend, record=record),
+        warm=_warm_first,
+    ),
     # `ann` rides the key: exact and IVF-probed jobs never share; nor
     # do bare and filtered jobs (two programs: one mask, a mask a row)
     "knn": _Family(
@@ -1123,6 +1184,21 @@ class QueryBatcher:
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
             "filter_tiles": 0, "mask_launches": 0,
             "block_select_launches": 0, "fallbacks": 0,
+        }
+        # the phrase family (`_nodes/stats` `phrase`; under self._lock):
+        # (job x segment) scans on the device, their launches, the words
+        # they held, the position entries the scans were handed (the
+        # whole plane a job), and of each job and segment: the documents
+        # holding every word and the occurrences of the phrase's words
+        # inside them (both counted on the device, read at collect), the
+        # documents matched, the bytes no exact search could leave
+        # unread (ops/phrase.least_bytes), and the scans that left the
+        # planned path for the unbatched executor's `_exec_phrase`
+        self.phrase = {
+            "searches": 0, "launches": 0, "words": 0,
+            "occurrences_read": 0, "candidates": 0,
+            "candidate_occurrences": 0, "matches": 0, "least_bytes": 0,
+            "fallbacks": 0,
         }
         # per-bucket launch histogram + occupancy sums (guarded by
         # self._lock; surfaced via batching_stats() → _nodes/stats):
@@ -1876,7 +1952,8 @@ class QueryBatcher:
             )
             j.finish()
 
-    def _group_topk(self, items: List[Tuple], kb: int, record: bool):
+    def _group_topk(self, items: List[Tuple], kb: int, record: bool,
+                    extra: int = 0):
         """A text or sparse group's device candidates, [(si, part)]
         (segment asc), merged on the host after ONE blocking download:
         (scores, segments, docs, totals[B, len(items)]) as
@@ -1884,19 +1961,25 @@ class QueryBatcher:
         follows from what the group holds: a lone fused launch's packed
         row is the answer already and is downloaded as the kernel wrote
         it (no program, no upload); anything else goes through the one
-        merge program, which unpacks packed rows in its own trace."""
+        merge program, which unpacks packed rows in its own trace.
+        `extra`: the packed rows end in that many int32 counters (a
+        phrase group's); their sums over the segments are returned
+        last, i64[B, extra]."""
         direct = len(items) == 1 and scoring.is_packed(items[0][1])
+        more = (extra,) if extra else ()  # a bare group: the calls it made
         if direct:
-            ms, mseg, mdoc, mtot = scoring.packed_segment_topk(*items[0])
+            ms, mseg, mdoc, mtot, *counters = scoring.packed_segment_topk(
+                *items[0], *more)
         else:
-            ms, mseg, mdoc, mtot = scoring.merge_segment_topk(items, kb)
+            ms, mseg, mdoc, mtot, *counters = scoring.merge_segment_topk(
+                items, kb, *more)
         _group_now().merged = not direct
         if record and direct:
             with self._lock:
                 self.stats["direct_collect_groups"] += 1
         # exact ties in (segment, doc) order: the device's top-k does not
         # promise it
-        return (*scoring.rank_order(ms, mseg, mdoc), mtot)
+        return (*scoring.rank_order(ms, mseg, mdoc), mtot, *counters)
 
     # ---- dispatch/collect pairs (device work launches in dispatch;
     # only collect blocks on host transfers) ----
@@ -2028,6 +2111,132 @@ class QueryBatcher:
                     si, s1[None, :], d1[None, :], np.array([t1]),
                 )
         self._finish_jobs(jobs, per_job_cands, totals, reader)
+
+    def _dispatch_phrase_group(self, jobs: List[_Job], kb: int,
+                               rows: Optional[int] = None,
+                               record: bool = True) -> List[Tuple]:
+        """One launch of the phrase kernel a segment (ops/phrase.py
+        `phrase_topk`) for a group of PhrasePlan jobs of one field and
+        span, enqueued WITHOUT a host sync: the words are looked up in
+        the segment's term dictionary, the plan (a row a job: term ids
+        by slot, the weight) is packed and uploaded with the launch, and
+        the packed result stays on the device until collect. The
+        `phrase_plan` span, a child of `dispatch`, covers a segment's
+        look-ups, packing and enqueue.
+
+        A segment whose positions plane cannot be held (the
+        `phrase.score` fault site, an upload the HBM breaker refuses, a
+        segment indexed before positions were columnar) is served per
+        job by the unbatched executor's `_exec_phrase` at collect, and
+        counted (`phrase.fallbacks`)."""
+        ex = jobs[0].executor
+        reader = ex.reader
+        nj = len(jobs)
+        rows = rows or BPAD
+        plan0 = jobs[0].plan
+        field, width = plan0.field, plan0.width
+        phrases = [
+            (j.plan.terms, j.plan.rel,
+             ex.phrase_weight(field, list(j.plan.terms), j.plan.boost))
+            for j in jobs
+        ]
+        words = sum(len(j.plan.terms) for j in jobs)
+        tags = _group_now().plan_tags
+        if record:
+            tags["words"] = words
+        items: List[Tuple] = []
+        for si, seg in enumerate(reader.segments):
+            pf = seg.postings.get(field)
+            if pf is None or seg.num_docs == 0:
+                continue
+            t0 = time.perf_counter_ns()
+            try:
+                if record:
+                    faults.check("phrase.score", field=field, segment=si)
+                held = ex.phrase_plane(si, field)
+            except Exception:
+                held = None
+            if held is None:
+                if record:
+                    with self._lock:
+                        self.phrase["fallbacks"] += nj
+                items.append(("fallback", si, None))
+                continue
+            dev, inv_norm, live = held
+            plan, df_min = phrase_ops.pack_phrase_plans(
+                pf, phrases, rows, width)
+            note_transfer("h2d", plan.nbytes)
+            out = phrase_ops.phrase_topk(
+                dev.mats, dev.order, inv_norm, live, plan,
+                k=min(kb, int(dev.order.shape[0])),
+            )
+            if record:
+                g = _group_now()
+                occ = dev.occurrences * nj
+                tags["occurrences"] = tags.get("occurrences", 0) + occ
+                g.sub_spans.append((
+                    "phrase_plan", t0, time.perf_counter_ns(),
+                    {"segment": si, "launches": 1, "words": words},
+                ))
+                with self._lock:
+                    self.stats["launches"] += 1
+                    self.stats["fused_jobs"] += nj
+                    ph = self.phrase
+                    ph["searches"] += nj
+                    ph["launches"] += 1
+                    ph["words"] += words
+                    ph["occurrences_read"] += occ
+                    ph["least_bytes"] += sum(
+                        phrase_ops.least_bytes(df, seg.num_docs, 0)
+                        for df in df_min)
+                g.add_flops(2 * width * occ)
+            items.append(("dev", si, out))
+        return items
+
+    def _collect_phrase_group(self, jobs: List[_Job], kb: int, items,
+                              record: bool = True):
+        """Host side of a phrase group: ONE blocking download covers
+        every device segment (`_group_topk`: one segment's packed row as
+        the kernel wrote it, several through the merge program, the
+        rows' two trailing counters summed beside the totals); fallback
+        segments run per job on the unbatched executor and join the
+        final merge. Totals are exact."""
+        ex = jobs[0].executor
+        nj = len(jobs)
+        per_job_cands: List[List[Tuple[float, int, int]]] = [[] for _ in jobs]
+        totals = np.zeros(nj, np.int64)
+        dev_items = [(si, out) for tag, si, out in items if tag == "dev"]
+        if dev_items:
+            ms, mseg, mdoc, mtot, counters = self._group_topk(
+                dev_items, kb, record, extra=phrase_ops.PHRASE_EXTRA)
+            for ji in range(nj):
+                finite = np.isfinite(ms[ji])
+                for s, si, d in zip(
+                    ms[ji][finite], mseg[ji][finite], mdoc[ji][finite]
+                ):
+                    per_job_cands[ji].append((float(s), int(si), int(d)))
+                totals[ji] += int(mtot[ji].sum())
+            if record:
+                held, occ = (int(c) for c in counters[:nj].sum(axis=0))
+                with self._lock:
+                    ph = self.phrase
+                    ph["candidates"] += held
+                    ph["candidate_occurrences"] += occ
+                    ph["least_bytes"] += occ
+                    ph["matches"] += int(totals.sum())
+        for tag, si, _out in items:
+            if tag != "fallback":
+                continue
+            for ji, j in enumerate(jobs):
+                s1, d1, t1 = ex.segment_topk(j.query, si, kb)
+                if record:
+                    with self._lock:
+                        self.stats["launches"] += 1
+                self._collect(
+                    [j], [per_job_cands[ji]], totals[ji: ji + 1],
+                    si, s1[None, :], d1[None, :], np.array([t1]),
+                )
+        self._finish_jobs(jobs, per_job_cands, totals, ex.reader)
 
     def _dispatch_agg_group(self, jobs: List[_Job]) -> List[Tuple]:
         """Launches the device-aggregation plans (search/aggs_device

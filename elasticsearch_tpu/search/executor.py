@@ -19,9 +19,10 @@ Lucene semantics honored here:
   - bool minimum_should_match defaults: 1 when no must/filter, else 0;
   - top-k ordering is (score desc, global doc asc), global doc order =
     segment order × local doc id (Lucene docBase);
-  - match_phrase is evaluated as a conjunction then position-verified
-    against re-analyzed stored source (positions are not yet columnar;
-    see ROADMAP).
+  - match_phrase is Lucene's PhraseWeight: the words' conjunction, then
+    each candidate's phrase frequency from the columnar positions (the
+    starts at which the words line up), scored as ONE term of that
+    frequency under the summed idf of the words.
 """
 
 from __future__ import annotations
@@ -1169,59 +1170,73 @@ class NumpyExecutor:
             mask = stacked.sum(axis=0) >= msm
         return mask, np.where(mask, scores, 0).astype(np.float32)
 
+    def phrase_weight(self, field: str, terms: List[str], boost: float):
+        """boost x the SUM of the words' idfs (a word at two slots
+        counts twice), float32 as Lucene's PhraseWeight sums them: the
+        weight of the one pseudo-term a phrase scores as."""
+        idf = np.float32(0.0)
+        for t in terms:
+            idf = np.float32(idf + np.float32(self._term_weight(field, t)))
+        return np.float32(boost) * idf
+
     def _exec_phrase(self, q: MatchPhraseQuery, seg: Segment) -> Tuple[np.ndarray, np.ndarray]:
+        """Lucene's PhraseWeight over BM25Similarity: a document matches
+        where the words stand at the query's relative positions at least
+        once, and scores as ONE term whose frequency is the number of
+        such starts, under the summed idf of the words."""
         mf = self.reader.mappings.get(q.field)
         n = seg.num_docs
         if mf is None or mf.type != TEXT:
             return np.zeros(n, bool), np.zeros(n, np.float32)
+        # the documents that hold every word (none where the phrase
+        # analyzes to no word)
+        conj, _ = self._exec_match(
+            MatchQuery(field=q.field, query=q.query, operator="and",
+                       analyzer=q.analyzer, boost=q.boost),
+            seg,
+        )
+        return self.phrase_scores(q, seg, np.flatnonzero(conj))
+
+    def phrase_scores(
+        self, q: MatchPhraseQuery, seg: Segment, cand: np.ndarray
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """(mask, scores) of one phrase over one segment, `cand` the
+        local documents that hold every word of it (ascending): their
+        phrase frequencies from the columnar positions (never from
+        `_source`, unless the segment predates them), scored."""
+        n = seg.num_docs
+        mf = self.reader.mappings.get(q.field)
         analyzer_name = q.analyzer or mf.search_analyzer or mf.analyzer
         analyzer = self.reader.analysis.get(analyzer_name)
         qtoks = analyzer.analyze(q.query)
         terms = [t.text for t in qtoks]
-        if not terms:
-            return np.zeros(n, bool), np.zeros(n, np.float32)
-        # conjunction prefilter
-        conj, scores = self._exec_match(
-            MatchQuery(field=q.field, query=q.query, operator="and",
-                       analyzer=analyzer_name, boost=q.boost),
-            seg,
-        )
-        # position verification against the columnar position index
-        # (Lucene PositionsEnum semantics) — never re-analyzes _source
-        qpos = [t.position for t in qtoks]
-        rel = [p - qpos[0] for p in qpos]
+        rel = [t.position - qtoks[0].position for t in qtoks]
         mask = np.zeros(n, bool)
+        scores = np.zeros(n, np.float32)
         pf = seg.postings.get(q.field)
-        if pf is not None and pf.has_positions:
-            tids = [pf.term_id(t) for t in terms]
-            for doc in np.nonzero(conj)[0]:
-                pos_of: Dict[str, List[int]] = {}
-                ok = True
-                for t, tid in zip(terms, tids):
-                    if t in pos_of:
-                        continue
-                    ps = pf.doc_positions(tid, int(doc)) if tid >= 0 else None
-                    if ps is None:
-                        ok = False
-                        break
-                    pos_of[t] = ps.tolist()
-                mask[doc] = ok and _phrase_match(pos_of, terms, rel, q.slop)
-            return mask, np.where(mask, scores, 0).astype(np.float32)
-        # legacy segments without stored positions: re-analyze _source
-        for doc in np.nonzero(conj)[0]:
-            src = seg.sources[doc] or {}
-            value = _extract_field(src, q.field)
-            ok = False
-            for v in value:
-                toks = analyzer.analyze(str(v))
-                pos_of = {}
-                for t in toks:
-                    pos_of.setdefault(t.text, []).append(t.position)
-                if _phrase_match(pos_of, terms, rel, q.slop):
-                    ok = True
-                    break
-            mask[doc] = ok
-        return mask, np.where(mask, scores, 0).astype(np.float32)
+        if pf is None or not len(cand):
+            return mask, scores
+        if pf.has_positions:
+            freq = phrase_freqs(
+                pf, [pf.term_id(t) for t in terms], rel, q.slop, cand)
+        else:
+            # legacy segments without stored positions: re-analyze _source
+            freq = np.zeros(len(cand), np.int64)
+            for ci, doc in enumerate(cand):
+                for v in _extract_field(seg.sources[doc] or {}, q.field):
+                    pos_of: Dict[str, List[int]] = {}
+                    for t in analyzer.analyze(str(v)):
+                        pos_of.setdefault(t.text, []).append(t.position)
+                    freq[ci] = max(freq[ci], _phrase_count(
+                        pos_of, terms, rel, q.slop))
+        docs = cand[freq > 0]
+        mask[docs] = True
+        scores[docs] = bm25.score_freqs(
+            freq[freq > 0], pf.norms[docs].astype(np.int64),
+            self.phrase_weight(q.field, terms, q.boost),
+            self._field_cache(q.field),
+        )
+        return mask, scores
 
     def _exec_term(self, q: TermQuery, seg: Segment) -> Tuple[np.ndarray, np.ndarray]:
         n = seg.num_docs
@@ -1967,24 +1982,66 @@ def _extract_field(src: dict, path: str):
     return node if isinstance(node, list) else [node]
 
 
-def _phrase_match(pos_of: Dict[str, List[int]], terms: List[str], rel: List[int], slop: int) -> bool:
-    """Exact phrase when slop=0: all terms at consecutive relative positions.
-    Sloppy phrases use a simple window check (admits standard slop cases)."""
-    first = pos_of.get(terms[0], [])
-    for p0 in first:
+def _phrase_starts(pos_of: Dict[str, List[int]], terms: List[str], rel: List[int], slop: int):
+    """The positions of the first word from which the phrase holds.
+    Exact when slop=0: all terms at consecutive relative positions.
+    Sloppy phrases use a simple window check (admits standard slop
+    cases; NOT Lucene's SloppyPhraseMatcher)."""
+    for p0 in pos_of.get(terms[0], []):
         if slop == 0:
             if all(p0 + r in pos_of.get(t, []) for t, r in zip(terms[1:], rel[1:])):
-                return True
-        else:
-            ok = True
-            for t, r in zip(terms[1:], rel[1:]):
-                cands = pos_of.get(t, [])
-                if not any(abs(p - (p0 + r)) <= slop for p in cands):
-                    ok = False
-                    break
-            if ok:
-                return True
-    return False
+                yield p0
+        elif all(
+            any(abs(p - (p0 + r)) <= slop for p in pos_of.get(t, []))
+            for t, r in zip(terms[1:], rel[1:])
+        ):
+            yield p0
+
+
+def _phrase_match(pos_of: Dict[str, List[int]], terms: List[str], rel: List[int], slop: int) -> bool:
+    return next(_phrase_starts(pos_of, terms, rel, slop), None) is not None
+
+
+def _phrase_count(pos_of: Dict[str, List[int]], terms: List[str], rel: List[int], slop: int) -> int:
+    """The phrase frequency a document scores with: its starts."""
+    return sum(1 for _ in _phrase_starts(pos_of, terms, rel, slop))
+
+
+def phrase_freqs(pf, tids: List[int], rel: List[int], slop: int,
+                 cand: np.ndarray) -> np.ndarray:
+    """int64[len(cand)]: the phrase frequency of each candidate (local
+    documents holding EVERY word, ascending) from the field's columnar
+    positions. The candidates' postings are found by one `searchsorted`
+    a word; an exact phrase is then an intersection of (candidate,
+    start) keys with no loop over documents, a sloppy one keeps the
+    window check a candidate."""
+    cand = np.asarray(cand, np.int32)  # searchsorted casts a mismatch
+    post = {
+        tid: pf.term_pos_start[tid] + np.searchsorted(pf.term_docs(tid), cand)
+        for tid in set(tids)
+    }
+    if slop > 0:
+        freq = np.zeros(len(cand), np.int64)
+        for ci in range(len(cand)):
+            pos_of = {}
+            for tid, p in post.items():
+                lo, hi = pf.pos_offsets[p[ci]], pf.pos_offsets[p[ci] + 1]
+                pos_of[tid] = pf.pos_data[lo:hi].tolist()
+            freq[ci] = _phrase_count(pos_of, tids, rel, slop)
+        return freq
+    keys = None
+    for tid, r in zip(tids, rel):
+        lo = pf.pos_offsets[post[tid]]
+        tf = pf.pos_offsets[post[tid] + 1] - lo
+        first = np.cumsum(tf) - tf  # a candidate's first occurrence
+        occ = np.arange(int(tf.sum()), dtype=np.int64)
+        occ += np.repeat(lo - first, tf)
+        # (candidate, the start this occurrence would belong to)
+        key = (np.repeat(np.arange(len(cand), dtype=np.int64) << 32, tf)
+               + (pf.pos_data[occ].astype(np.int64) - r + (1 << 24)))
+        keys = key if keys is None else np.intersect1d(
+            keys, key, assume_unique=True)
+    return np.bincount(keys >> 32, minlength=len(cand))
 
 
 def _coerce_numeric(ftype: str, value) -> float:

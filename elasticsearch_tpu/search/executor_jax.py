@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common.tracing import note_transfer
 from ..index.mapping import DATE, KEYWORD, TEXT, parse_date_millis
 from ..index.segment import Segment
 from ..models import bm25
@@ -160,6 +161,17 @@ class DeviceVectors(NamedTuple):
     norms: Optional[jax.Array] = None
 
 
+class DevicePositions(NamedTuple):
+    """A text field's positions plane on the device (index/segment.py
+    `PositionsPlane`; ops/phrase.py reads it): a position-major term-id
+    matrix a class of document lengths, and the document of each plane
+    column."""
+
+    mats: Tuple[jax.Array, ...]
+    order: jax.Array
+    occurrences: int  # token positions the plane holds
+
+
 class DeviceSegment:
     """Device-resident mirror of a Segment's hot arrays (lazy per field)."""
 
@@ -211,6 +223,25 @@ class DeviceSegment:
             return out
 
         self.vectors = _LazyDeviceMap(seg.vectors, _vec)
+
+        def _positions(f):
+            # uploaded when the field is first asked a bare phrase, never
+            # for a field that only answers `match` / `bool`; None where
+            # the field holds no positions (or passes the layout's limits)
+            plane = seg.postings[f].positions_plane(seg.num_docs)
+            if plane is None:
+                return None
+            if charge is not None:
+                charge("positions", plane.nbytes, True, precheck_only=True)
+            out = DevicePositions(
+                tuple(jax.device_put(m, device) for m in plane.mats),
+                jax.device_put(plane.order, device), plane.occurrences,
+            )
+            if charge is not None:
+                charge("positions", _tree_nbytes(out), False)
+            return out
+
+        self.positions = _LazyDeviceMap(seg.postings, _positions)
         # multi-value ordinal CSR for device range/terms masks
         self.ordinals = _LazyDeviceMap(
             seg.ordinals,
@@ -235,6 +266,7 @@ class DeviceSegment:
             (self.postings, other.postings, "postings"),
             (self.numerics, other.numerics, "doc_values"),
             (self.vectors, other.vectors, "vectors"),
+            (self.positions, other.positions, "positions"),
             (self.ordinals, other.ordinals, "doc_values"),
         ):
             with theirs._lock:
@@ -327,6 +359,10 @@ class JaxExecutor:
         # HbmLedger category; a build that would not fit DEGRADES TO
         # SKIP (None cached — the request keeps its first-stage order)
         self._rerank_columns: Dict[tuple, object] = {}
+        # the phrase family's operands a (segment, field): the shared
+        # positions plane with this generation's inverse norms and live
+        # mask in the plane's column order (`phrase_plane`)
+        self._phrase_planes: Dict[Tuple[int, str], tuple] = {}
         self._seg_weights: Dict[Tuple[int, str], np.ndarray] = {}
         self._df_maps: Dict[str, Dict[str, int]] = {}
         self._shard_dfs: Dict[Tuple[str, str], int] = {}
@@ -1027,6 +1063,42 @@ class JaxExecutor:
                 self._df_maps[field] = m
         return m
 
+    def phrase_weight(self, field: str, terms: List[str], boost: float):
+        """The oracle's: boost x the summed idfs of a phrase's words."""
+        return self._oracle.phrase_weight(field, terms, boost)
+
+    def phrase_plane(self, si: int, field: str) -> Optional[tuple]:
+        """(DevicePositions, inverse norms f32[n_plane], live bool[n_plane]
+        or None) of one segment's field, each in the plane's column
+        order: what `ops/phrase.phrase_topk` reads. None where the field
+        holds no positions there. The plane uploads once a field (and
+        is adopted across generations), under the `positions` category;
+        an upload the HBM breaker refuses raises, and the batcher serves
+        the segment's phrases per job on `_exec_phrase`."""
+        key = (si, field)
+        got = self._phrase_planes.get(key)
+        if got is None:
+            with self._build_lock:
+                got = self._phrase_planes.get(key)
+                if got is not None:
+                    return got or None
+                dev = self.device_segments[si].positions.get(field)
+                if dev is None:
+                    got = False
+                else:
+                    n = self.reader.segments[si].num_docs
+                    inv = self._inv_norm(si, field, n)[dev.order]
+                    live = self.reader.live_docs[si]
+                    if live is not None:
+                        note_transfer("h2d", np.asarray(live).nbytes)
+                        live = jnp.asarray(live)[dev.order]
+                    self._charge(
+                        "norms", int(inv.nbytes)
+                        + (0 if live is None else int(live.nbytes)), False)
+                    got = (dev, inv, live)
+                self._phrase_planes[key] = got
+        return got or None
+
     def shard_df(self, field: str, term: str) -> int:
         key = (field, term)
         df = self._shard_dfs.get(key)
@@ -1701,57 +1773,29 @@ class JaxExecutor:
     def _exec_phrase(
         self, q: MatchPhraseQuery, si: int
     ) -> Tuple[jax.Array, jax.Array]:
-        """Phrase = device conjunction scoring + host position verify
-        against the columnar position index (PositionsEnum analog). The
-        candidate set after the conjunction is small, so one device→host
-        sync of the mask mirrors ES's doc-at-a-time phrase scoring; BM25
-        weights stay on device and _source is never re-analyzed."""
-        from .executor import _phrase_match
-
+        """The unbatched phrase (a clause of a `bool`, a sloppy phrase,
+        a segment whose positions plane cannot be held): the words'
+        conjunction on the device, ONE download of its mask, the
+        candidates' phrase frequencies from the columnar positions on
+        the host (`executor.phrase_freqs`, PositionsEnum's analog:
+        `_source` is never re-analyzed), scored as the oracle scores
+        them (phrase frequency as tf, summed idf), one upload back. A
+        bare exact phrase rides the batcher's `phrase` family and never
+        comes here."""
         seg = self.reader.segments[si]
         n = seg.num_docs
         mf = self.reader.mappings.get(q.field)
         if mf is None or mf.type != TEXT:
             return jnp.zeros(n, bool), jnp.zeros(n, jnp.float32)
-        analyzer_name = q.analyzer or mf.search_analyzer or mf.analyzer
-        qtoks = self.reader.analysis.get(analyzer_name).analyze(q.query)
-        terms = [t.text for t in qtoks]
-        if not terms:
-            return jnp.zeros(n, bool), jnp.zeros(n, jnp.float32)
-        conj, scores = self._exec_match(
-            MatchQuery(
-                field=q.field,
-                query=q.query,
-                operator="and",
-                analyzer=analyzer_name,
-                boost=q.boost,
-            ),
+        conj, _ = self._exec_match(
+            MatchQuery(field=q.field, query=q.query, operator="and",
+                       analyzer=q.analyzer, boost=q.boost),
             si,
         )
-        pf = seg.postings.get(q.field)
-        if pf is None or not pf.has_positions:
-            # legacy segment without positions → oracle fallback
-            hm, hs = self._oracle._exec(q, seg)
-            return jnp.asarray(hm), jnp.asarray(hs)
-        qpos = [t.position for t in qtoks]
-        rel = [p - qpos[0] for p in qpos]
-        host_conj = np.asarray(conj)
-        mask = np.zeros(n, bool)
-        tids = [pf.term_id(t) for t in terms]
-        for doc in np.nonzero(host_conj)[0]:
-            pos_of = {}
-            ok = True
-            for t, tid in zip(terms, tids):
-                if t in pos_of:
-                    continue
-                ps = pf.doc_positions(tid, int(doc)) if tid >= 0 else None
-                if ps is None:
-                    ok = False
-                    break
-                pos_of[t] = ps.tolist()
-            mask[doc] = ok and _phrase_match(pos_of, terms, rel, q.slop)
-        dmask = jnp.asarray(mask)
-        return dmask, jnp.where(dmask, scores, 0.0)
+        mask, scores = self._oracle.phrase_scores(
+            q, seg, np.flatnonzero(scoring._to_host(conj)))
+        note_transfer("h2d", mask.nbytes + scores.nbytes)
+        return jnp.asarray(mask), jnp.asarray(scores)
 
     def _exec_term(self, q: TermQuery, si: int) -> Tuple[jax.Array, jax.Array]:
         seg = self.reader.segments[si]

@@ -40,6 +40,8 @@ KEYED = {
     # is filtered is keyed on, never which filter it carries)
     "knn": {"field": ("vec", "vec2"), "ann": (None, "ivf:8"),
             "filter": (None, "tags:t1")},
+    # `width`: a phrase's span in slots is one program; its words are not
+    "phrase": {"field": ("body", "title"), "width": (2, 3)},
     "agg": {"sig": ("terms:tag", "terms:cat")},
     "rerank": {"sig": ("m1:16:8", "m1:32:8")},
     "sparse": {"field": ("sv", "sv2"), "spec": ("fp32", "int8")},
@@ -57,7 +59,8 @@ KEYED = {
     "mesh_agg": {"sig": ("terms:tag", "terms:cat")},
 }
 OVERLAP = {
-    "match": "text", "serve": "text", "knn": "knn", "agg": "agg",
+    "match": "text", "serve": "text", "phrase": "text", "knn": "knn",
+    "agg": "agg",
     "rerank": "rerank", "sparse": "sparse", "mesh_match": "text",
     "mesh_serve": "text", "mesh_knn": "knn", "mesh_sparse": "sparse",
     "mesh_agg": "agg",
@@ -101,7 +104,7 @@ def groups_of(batcher, monkeypatch, jobs):
 
 
 class TestTheTable:
-    def test_holds_the_eleven_kinds_and_their_overlap_classes(self, batcher):
+    def test_holds_the_twelve_kinds_and_their_overlap_classes(self, batcher):
         assert {k: f.overlap for k, f in FAMILIES.items()} == OVERLAP
         assert set(batcher._inflight) == set(OVERLAP.values())
         assert {k for k, f in FAMILIES.items() if f.mesh} == {
@@ -109,7 +112,7 @@ class TestTheTable:
         }
         # the kinds whose first dispatch warms the bucket ladder
         assert {k for k, f in FAMILIES.items() if f.warm} == {
-            "match", "serve", "knn", "sparse",
+            "match", "serve", "phrase", "knn", "sparse",
         }
 
     def test_every_family_is_a_dispatch_collect_pair(self):

@@ -193,6 +193,46 @@ def test_filtered_byte_knn_programs(one_chip, rows):
     assert len(readers) == 1 and " fusion(" in readers[0], readers
 
 
+# the phrase deployment (benchmarks/configs/msmarco-phrase.json): the
+# positions plane of 1,000,000 passages as corpora/zipf_text_ordered.py
+# builds it (class width -> passages of the class; 271 MB of int32), a
+# phrase of three words; one row (the cell) and the ladder's top
+PHRASE_CLASSES = {8: 53, 16: 6255, 24: 46875, 32: 108819, 48: 299740,
+                  64: 243111, 96: 219359, 128: 56474, 192: 17794, 256: 1520}
+
+
+@pytest.mark.parametrize("rows", [1, 32])
+def test_phrase_program(one_chip, rows):
+    from elasticsearch_tpu.ops import phrase
+
+    s = _on(one_chip)
+    assert sum(PHRASE_CLASSES.values()) == N_DOCS
+    mats = tuple(s((w, n), jnp.int32) for w, n in PHRASE_CLASSES.items())
+    width = 3
+    compiled = phrase.phrase_topk.lower(
+        mats,
+        s((N_DOCS,), jnp.int32),
+        s((N_DOCS,), jnp.float32),
+        None,  # live: no deletes in a freshly built segment
+        s((rows, 2 * width + 1), jnp.int32),
+        k=16,
+    ).compile()
+    plane = sum(4 * w * n for w, n in PHRASE_CLASSES.items())
+    # beside what the deployment keeps resident: the text layout's
+    # tiles, norms and dense rows (~2 GB)
+    assert _fits(compiled) + 2 * 1024**3 < HBM_BYTES
+    # the plane is an operand, never copied: the program's temporaries
+    # stay under a few planes of scores a row, far under the plane's
+    # size times the rows
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes >= plane
+    assert m.temp_size_in_bytes < plane + rows * 64 * N_DOCS, (
+        m.temp_size_in_bytes)
+    # no sort wider than the score plane, no gather of the plane
+    hlo = compiled.as_text()
+    assert "scatter" not in hlo
+
+
 # ---------------------------------------------------------------------------
 # fused text programs at the plan shape the batcher sends
 # ---------------------------------------------------------------------------
