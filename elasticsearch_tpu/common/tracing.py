@@ -95,15 +95,20 @@ starts at `http`'s start and reaches the ring when `http` ends:
             (sums over its jobs and segments) and quantized; a filtered knn group filtered,
             clauses (the most a job's filter holds) and filter_tiles
             (postings tiles its mask launches scattered, summed over
-            jobs and segments); a phrase group words (its jobs' words)
+            jobs and segments: the tiles of terms a bit row answers are
+            not among them); a phrase group words (its jobs' words)
             and occurrences (position entries its launches were handed,
             summed over jobs and segments)]
             -> the group's last kernel is enqueued
-          > filter_mask [segment, launches, tiles]  a filtered knn
-            group's masks on one segment: the filters' terms looked up
-            and packed, the plan uploaded, `knn_filter_mask` enqueued
-            (one launch; the device builds each row's mask from the
-            postings tiles; no host sync)
+          > filter_mask [segment, launches, tiles, bitset_terms,
+            bitset_rows_held]  a filtered knn group's masks on one
+            segment: the filters' terms looked up and packed, the plan
+            uploaded, `knn_filter_mask` enqueued (one launch; the
+            device builds each row's mask from the bit rows of the
+            terms that hold one, `bitset_terms` of the launch's, and
+            the `tiles` postings tiles of the others; no host sync).
+            `bitset_rows_held`: the rows the segment's field holds
+            (built inside the field's first such span)
           > phrase_plan [segment, launches, words]  a phrase group
             on one segment: the words looked up in the term dictionary,
             the plan packed and uploaded, `phrase_topk` enqueued (one

@@ -1246,13 +1246,22 @@ def knn_topk_batch(
 # rows, one-row launch, device ms by the tag's tiles 1 / 76 / 728 /
 # 5,613 / 22,130; PERF.md section 6, PR 39): chunks of 16 0.96 / 1.06 /
 # 2.07 / 10.11 / 37.06, of 64 0.98 / 1.04 / 2.09 / 10.10 / 37.03, of 256
-# 0.97 / 1.04 / 2.11 / 10.07 / 37.08: a launch costs ~0.96 ms (the 40 MB
-# plane zeroed, counted and masked) and 13 ns a posting slot scattered,
-# whatever the chunk.
+# 0.97 / 1.04 / 2.11 / 10.07 / 37.08: a launch that scatters costs
+# ~0.96 ms (the 40 MB plane zeroed, counted and masked) and 13 ns a
+# posting slot scattered, whatever the chunk. Since PR 45 the terms that
+# hold a bit row (`FilterBitRows`) scatter nothing, and a launch none of
+# whose rows scatters skips the plane too.
 FILTER_CHUNK = 64
 # Term slots of a filter plan row: the compile buckets of the plan's
 # width. A filter of more terms than the last is not planned.
 FILTER_SLOT_BUCKETS = (8, 64)
+# A plan slot's unit where the slot names a bit row, not a tile range:
+# the row opens a clause of its own, or joins (ORs into) the clause the
+# bit-row slot before it opened.
+FILTER_BIT_OPENS, FILTER_BIT_JOINS = -1, -2
+# Words of a bit row are rounded up to this many, so that each of a
+# word's 32 bit planes unpacks into whole tiles of lanes.
+FILTER_BIT_WORDS_ALIGN = 1024
 
 
 def filter_slot_bucket(n_terms: int) -> Optional[int]:
@@ -1260,35 +1269,104 @@ def filter_slot_bucket(n_terms: int) -> Optional[int]:
     return next((b for b in FILTER_SLOT_BUCKETS if n_terms <= b), None)
 
 
-def pack_filter_plans(pf, filters, rows: int) -> Tuple[np.ndarray, int]:
-    """(`knn_filter_mask`'s plan int32[rows, 3 * S + 1], tiles it names)
-    for one launch over one segment: `pf` the filter field's
-    PostingsField there, `filters` each job's clauses (tuples of terms;
-    batcher.KnnFilter.clauses), S the slot bucket of the widest. A
-    clause of one term feeds the count plane's term counter, the d-th
-    clause of several terms its digit d (the planner admits at most
-    CLAUSE_DIGITS of them, of CLAUSE_TERMS_MAX terms each); a term the
-    segment does not hold keeps an empty range."""
+def filter_bit_words(n_docs: int) -> int:
+    """uint32 words of one bit row over `n_docs` documents."""
+    a = FILTER_BIT_WORDS_ALIGN
+    return max(-(-n_docs // (32 * a)), 1) * a
+
+
+@jax.jit
+def _pack_bit_rows(masks: jax.Array) -> jax.Array:
+    """bool[R, n] -> uint32[R, W], W = filter_bit_words(n): document d
+    is bit d // W of word d % W. Lane-strided, so a row unpacks without
+    a relayout: bit plane b of the words IS documents [b * W, (b + 1) *
+    W), in order (`_unpack_bit_rows`)."""
+    r, n = masks.shape
+    w = filter_bit_words(n)
+    planes = jnp.pad(masks, ((0, 0), (0, 32 * w - n))).reshape(r, 32, w)
+    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
+    return jnp.sum(planes.astype(jnp.uint32) << shifts, axis=1,
+                   dtype=jnp.uint32)
+
+
+def _unpack_bit_rows(words: jax.Array, n: int) -> jax.Array:
+    """uint32[B, W] -> bool[B, n]: `_pack_bit_rows` undone."""
+    rows, w = words.shape
+    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :, None, None]
+    lanes = words.reshape(rows, 1, w // 128, 128)
+    planes = (lanes >> shifts) & jnp.uint32(1)  # [B, 32, W / 128, 128]
+    return planes.reshape(rows, 32 * w)[:, :n].astype(jnp.bool_)
+
+
+class FilterBitRows(NamedTuple):
+    """The bit rows a segment's filter field holds on the device for its
+    commonest terms (executor_jax.DevicePostings.filter_bits chooses
+    them): `plane` uint32[R, W] in `_pack_bit_rows`' layout, row r the
+    documents that hold the term with `row_of_term[term id] == r`; no
+    plane where no term holds a row."""
+
+    plane: Optional[jax.Array] = None
+    row_of_term: dict = {}
+
+    @property
+    def nbytes(self) -> int:
+        return 0 if self.plane is None else int(self.plane.nbytes)
+
+
+class FilterPlans(NamedTuple):
+    """`pack_filter_plans`' launch: the plan, the postings tiles its
+    tile-range slots name (what the launch scatters), the terms of its
+    filters and those of them a bit row answers."""
+
+    plan: np.ndarray
+    tiles: int
+    terms: int
+    bit_terms: int
+
+
+def pack_filter_plans(pf, filters, rows: int,
+                      bit_rows: FilterBitRows = FilterBitRows()) -> FilterPlans:
+    """`knn_filter_mask`'s plan int32[rows, 3 * S + 1] for one launch
+    over one segment: `pf` the filter field's PostingsField there,
+    `filters` each job's clauses (tuples of terms;
+    batcher.KnnFilter.clauses), S the slot bucket of the widest,
+    `bit_rows` the rows the field holds there. A slot names a tile range
+    to scatter or a bit row to read. A clause whose every term the
+    segment holds has a row rides the rows (one term: its row; several:
+    their OR); any other clause is scattered whole: one term feeds the
+    count plane's term counter, the d-th scattered clause of several
+    terms its digit d (the planner admits at most CLAUSE_DIGITS of them,
+    of CLAUSE_TERMS_MAX terms each); a term the segment does not hold
+    keeps an empty range."""
     S = filter_slot_bucket(max(sum(map(len, f)) for f in filters))
     plan = np.zeros((rows, 3 * S + 1), np.int32)
-    tiles = 0
+    row_of = bit_rows.row_of_term
+    tiles = terms = bit_terms = 0
     for ji, clauses in enumerate(filters):
         slot = digit = 0
         for clause in clauses:
+            terms += len(clause)
+            tids = [tid for tid in map(pf.term_id, clause) if tid >= 0]
+            if tids and all(tid in row_of for tid in tids):
+                for i, tid in enumerate(tids):
+                    plan[ji, slot] = row_of[tid]
+                    plan[ji, 2 * S + slot] = (
+                        FILTER_BIT_JOINS if i else FILTER_BIT_OPENS)
+                    slot += 1
+                bit_terms += len(tids)
+                continue
             unit = 1
             if len(clause) > 1:
                 unit = 1 << (COUNT_TERM_BITS + CLAUSE_DIGIT_BITS * digit)
                 digit += 1
-            for term in clause:
-                tid = pf.term_id(term)
-                if tid >= 0:
-                    plan[ji, slot] = pf.term_tile_start[tid]
-                    plan[ji, S + slot] = pf.term_tile_count[tid]
-                    tiles += int(pf.term_tile_count[tid])
+            for tid in tids:
+                plan[ji, slot] = pf.term_tile_start[tid]
+                plan[ji, S + slot] = pf.term_tile_count[tid]
+                tiles += int(pf.term_tile_count[tid])
                 plan[ji, 2 * S + slot] = unit
                 slot += 1
         plan[ji, 3 * S] = len(clauses)
-    return plan, tiles
+    return FilterPlans(plan, tiles, terms, bit_terms)
 
 
 @jax.jit
@@ -1296,19 +1374,25 @@ def knn_filter_mask(
     doc_ids: jax.Array,  # int32[n_tiles, 128] the filter field's postings
     cand: jax.Array,  # bool[N] rows that hold a vector and are live
     plan: jax.Array,  # int32[B, 3 * S + 1]
+    bits: Optional[jax.Array] = None,  # uint32[R, W] FilterBitRows.plane
 ) -> Tuple[jax.Array, jax.Array]:
     """Each query row's candidate mask under its own filter, built on
-    the device from the filter field's postings tiles: (bool[B, N],
-    rows passed int32[B]).
+    the device from the filter field's postings tiles and bit rows:
+    (bool[B, N], rows passed int32[B]).
 
     A row of `plan` holds S term slots and the number of clauses a
-    document must match: the first tile of each term's contiguous tile
-    range, the ranges' lengths (0 = unused slot, or a term the segment
-    does not hold: its clause then matches nothing), each slot's unit in
-    the count plane (`clause_units`' units: 1 for a clause of one term,
-    a digit's unit for a clause of several, which counts once however
-    many of its terms a document holds) and, last, the clauses needed
-    (`clauses_hit(cnt) >= need`; 0 on a pad row, whose mask is empty).
+    document must match (0 on a pad row, whose mask is empty). A slot
+    is (start, count, unit). unit > 0: a term's contiguous tile range
+    (its first tile and length; 0 tiles = a term the segment does not
+    hold) whose postings add `unit` to the count plane (`clause_units`'
+    units: 1 for a clause of one term, a digit's unit for a clause of
+    several, which counts once however many of its terms a document
+    holds). unit < 0: `start` is a row of `bits`, which opens a clause
+    (FILTER_BIT_OPENS) or joins the one the bit-row slot before it
+    opened (FILTER_BIT_JOINS). unit 0: unused; the used slots come
+    first. The mask is `clauses_hit(cnt) >= the scattered clauses` &
+    the AND, over the bit-row clauses, of the OR of each clause's rows
+    & cand.
 
     The rows' tile lists are never uploaded: a term's tiles are
     consecutive, so trip t takes tiles [t * C, (t + 1) * C) of the row's
@@ -1317,7 +1401,12 @@ def knn_filter_mask(
     plane (`_add_rare_tiles`' layout: row b's document d at
     b * (N + 1) + d, pad postings spill at b * (N + 1) + N). The trip
     count is the longest row's, a value of the launch: one program
-    serves a tag of one tile and one of tens of thousands."""
+    serves a tag of one tile and one of tens of thousands. Where the
+    field holds bit rows, a launch none of whose rows names a tile
+    (another value of the launch) neither zeroes, counts nor masks the
+    plane: its masks are the unpacked rows alone. Without `bits` (a
+    segment too small for rows, or none held) the program is the one it
+    always was."""
     n = cand.shape[0]
     B = plan.shape[0]
     S = (plan.shape[1] - 1) // 3
@@ -1344,13 +1433,65 @@ def knn_filter_mask(
         unit = jnp.take_along_axis(units, slot, axis=1)[:, :, None]
         return cnt.at[tgt].add(jnp.where(valid, unit, 0).ravel())
 
-    cnt = jax.lax.fori_loop(
-        0, (jnp.max(total) + C - 1) // C, trip,
-        jnp.zeros(B * (n + 1), jnp.int32),
+    def scattered(need):
+        cnt = jax.lax.fori_loop(
+            0, (jnp.max(total) + C - 1) // C, trip,
+            jnp.zeros(B * (n + 1), jnp.int32),
+        )
+        return clauses_hit(_doc_planes(cnt, B, n)) >= need[:, None]
+
+    def masks(hit, in_rows=None):
+        # hit bool[B, N] or [B, 1]: the row's scattered clauses hold
+        mask = hit & (need > 0)[:, None] & cand[None, :]
+        if in_rows is not None:
+            mask = mask & _unpack_bit_rows(in_rows, n)
+        return mask, mask.sum(axis=1, dtype=jnp.int32)
+
+    if bits is None:
+        return masks(scattered(need))
+
+    def slot_rows(s, carry):
+        met, clause = carry  # uint32[B, W]: the clauses closed; the open one
+        unit = jax.lax.dynamic_index_in_dim(units, s, 1, keepdims=False)
+        row = jnp.take(
+            bits, jax.lax.dynamic_index_in_dim(starts, s, 1, keepdims=False),
+            axis=0, mode="clip")
+        row = jnp.where((unit < 0)[:, None], row, jnp.uint32(0))
+        opens = (unit == FILTER_BIT_OPENS)[:, None]
+        return (jnp.where(opens, met & clause, met),
+                jnp.where(opens, row, clause | row))
+
+    ones = jnp.full((B, bits.shape[1]), 0xFFFFFFFF, jnp.uint32)
+    met, clause = jax.lax.fori_loop(
+        0, jnp.max(jnp.sum(units != 0, axis=1)), slot_rows, (ones, ones))
+    in_rows = met & clause
+    scatters = need - jnp.sum(units == FILTER_BIT_OPENS, axis=1)
+    return jax.lax.cond(
+        jnp.max(total) > 0,
+        lambda: masks(scattered(scatters), in_rows),
+        lambda: masks((scatters <= 0)[:, None], in_rows),
     )
-    hit = clauses_hit(_doc_planes(cnt, B, n))
-    mask = (hit >= need[:, None]) & (need > 0)[:, None] & cand[None, :]
-    return mask, mask.sum(axis=1, dtype=jnp.int32)
+
+
+def build_filter_bit_rows(doc_ids, term_tile_start, term_tile_count, held,
+                          n_docs: int) -> FilterBitRows:
+    """Rows for the terms `held` (row r = held[r]), built ON the device
+    from the resident doc-id tiles: a row is the packed mask
+    `knn_filter_mask` scatters for a filter of its term alone over
+    every document (a launch a term: the launch's 0.96 ms and 13 ns a
+    posting slot at 10M documents), so "the row == the scattered mask"
+    holds by construction."""
+    S = FILTER_SLOT_BUCKETS[0]
+    everyone = jnp.ones(n_docs, jnp.bool_)
+    rows = []
+    for tid in held:
+        plan = np.zeros((1, 3 * S + 1), np.int32)
+        plan[0, 0], plan[0, S] = term_tile_start[tid], term_tile_count[tid]
+        plan[0, 2 * S] = plan[0, 3 * S] = 1  # one clause of one term
+        mask, _ = knn_filter_mask(doc_ids, everyone, plan)
+        rows.append(_pack_bit_rows(mask))
+    return FilterBitRows(
+        jnp.concatenate(rows), {int(t): r for r, t in enumerate(held)})
 
 
 @jax.jit

@@ -659,12 +659,14 @@ class RestActions:
         # the knn family's filtered groups (QueryBatcher.knn_filtered):
         # scans under a mask of the job's own, rows scored and rows the
         # filters passed, postings tiles the mask launches scattered,
-        # the launches whose scan selected from block maxima, and the
-        # scans that fell back to the unbatched executor
+        # the planned filters' terms and those of them answered from a
+        # bit row of the segment, the mask launches, those whose scan
+        # selected from block maxima, and the scans that fell back to
+        # the unbatched executor
         knn_filtered = {
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
-            "filter_tiles": 0, "mask_launches": 0,
-            "block_select_launches": 0, "fallbacks": 0,
+            "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
+            "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
         }
         # the phrase family (QueryBatcher.phrase): scans on the device,
         # their launches and words, position entries handed to them,

@@ -290,7 +290,8 @@ class KnnFilter:
     conjunction of clauses over ONE keyword field, each clause the
     terms of which a document must hold at least one (a `term`: one; a
     `terms`: any of its values). The device builds each job's candidate
-    mask from the field's postings tiles (`scoring.knn_filter_mask`);
+    mask from the field's postings tiles and the bit rows of its
+    commonest terms (`scoring.knn_filter_mask`);
     `query`, the parsed filter, serves a segment whose mask build
     failed, on the unbatched executor."""
 
@@ -1176,14 +1177,17 @@ class QueryBatcher:
         # a mask the device built, the rows scored and the rows the
         # filters passed (counted on the device, read at collect; both
         # count a fallback's rows too), the postings tiles the mask
-        # launches scattered, those launches, those of them whose scan
-        # selected its top k from block maxima (a segment wide enough:
-        # scoring.knn_block_select), and the (job x segment) scans that
-        # left the planned path for the unbatched executor
+        # launches scattered, the terms of the planned filters and those
+        # of them a bit row of the segment answered (nothing scattered:
+        # DevicePostings.filter_bits), the mask launches, those of them
+        # whose scan selected its top k from block maxima (a segment
+        # wide enough: scoring.knn_block_select), and the (job x
+        # segment) scans that left the planned path for the unbatched
+        # executor
         self.knn_filtered = {
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
-            "filter_tiles": 0, "mask_launches": 0,
-            "block_select_launches": 0, "fallbacks": 0,
+            "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
+            "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
         }
         # the phrase family (`_nodes/stats` `phrase`; under self._lock):
         # (job x segment) scans on the device, their launches, the words
@@ -2368,20 +2372,24 @@ class QueryBatcher:
 
         A FILTERED group (every job carries a `KnnFilter`; the group
         key keeps them apart from bare jobs) gives each row a candidate
-        mask of its own, built on the device from the filter field's
-        postings tiles by one launch of `scoring.knn_filter_mask` a
-        segment (the `filter_mask` span, a child of `dispatch`), and
-        scores every stored row under it (`knn_topk_filtered`); the
-        rows each filter passed are counted on the device and come down
-        with the collect's packed download. The planned path neither
-        reads nor feeds the node's filter-bitset cache: a tag's mask is
-        rebuilt from its postings in microseconds to milliseconds, a
-        cached one is a byte a document for every distinct filter. A
-        segment whose mask cannot be built (the `knn.filter` fault
-        site, a postings upload the HBM breaker refuses) is served per
-        job by the unbatched executor at collect, and counted
-        (`knn_filtered.fallbacks`); the IVF tier is not asked for
-        filtered jobs."""
+        mask of its own, built on the device by one launch of
+        `scoring.knn_filter_mask` a segment (the `filter_mask` span, a
+        child of `dispatch`), and scores every stored row under it
+        (`knn_topk_filtered`); the rows each filter passed are counted
+        on the device and come down with the collect's packed
+        download. The planned path neither reads nor feeds the node's
+        filter-bitset cache (a byte a document for every distinct
+        filter): a common tag (df >= dense_row_min_df on the segment)
+        is read from the bit row the segment holds for it, an eighth
+        of a byte a document, built once from the postings when the
+        field first serves such a search (`DevicePostings.filter_bits`);
+        any other tag's postings tiles are scattered, the launch's
+        0.96 ms and 13 ns a posting slot (under 1 ms more for a tag
+        just under the rule at 10M rows). A segment whose mask cannot
+        be built (the `knn.filter` fault site, a postings upload the
+        HBM breaker refuses) is served per job by the unbatched
+        executor at collect, and counted (`knn_filtered.fallbacks`);
+        the IVF tier is not asked for filtered jobs."""
         ex = jobs[0].executor
         reader = ex.reader
         nj = len(jobs)
@@ -2516,22 +2524,26 @@ class QueryBatcher:
             if record:
                 faults.check("knn.filter", field=fname, segment=si)
             dp = ex.device_segments[si].postings[fname]
+            bits = dp.filter_bits  # built at the field's first such search
         except Exception:
             if record:
                 with self._lock:
                     self.knn_filtered["fallbacks"] += len(jobs)
             return si, n, None, None, None
-        plan, tiles = scoring.pack_filter_plans(
-            pf, [j.plan.filter.clauses for j in jobs], rows)
-        note_transfer("h2d", plan.nbytes)
-        mask, passed = scoring.knn_filter_mask(dp.doc_ids, cand_mask, plan)
+        fp = scoring.pack_filter_plans(
+            pf, [j.plan.filter.clauses for j in jobs], rows, bits)
+        note_transfer("h2d", fp.plan.nbytes)
+        mask, passed = scoring.knn_filter_mask(
+            dp.doc_ids, cand_mask, fp.plan, bits.plane)
         if record:
             g = _group_now()
             g.plan_tags["filter_tiles"] = (
-                g.plan_tags.get("filter_tiles", 0) + tiles)
+                g.plan_tags.get("filter_tiles", 0) + fp.tiles)
             g.sub_spans.append((
                 "filter_mask", t0, time.perf_counter_ns(),
-                {"segment": si, "launches": 1, "tiles": tiles},
+                {"segment": si, "launches": 1, "tiles": fp.tiles,
+                 "bitset_terms": fp.bit_terms,
+                 "bitset_rows_held": len(bits.row_of_term)},
             ))
         note_transfer("h2d", q.nbytes)
         s, d = scoring.knn_topk_filtered(
@@ -2544,7 +2556,9 @@ class QueryBatcher:
                 kf = self.knn_filtered
                 kf["searches"] += len(jobs)
                 kf["rows_scanned"] += len(jobs) * n
-                kf["filter_tiles"] += tiles
+                kf["filter_tiles"] += fp.tiles
+                kf["filter_terms"] += fp.terms
+                kf["bitset_terms"] += fp.bit_terms
                 kf["mask_launches"] += 1
                 if scoring.knn_block_select(n, kc):
                     kf["block_select_launches"] += 1

@@ -78,16 +78,38 @@ def dense_rows_room(row_bytes: int) -> int:
     )
 
 
+def dense_rows_held(df: np.ndarray, n_docs: int, row_bytes: int):
+    """(ids of the terms that HOLD a dense row of `row_bytes`, most
+    frequent first; how many WANT one) by the rule the sparse column's
+    int8 rows and a filter field's bit rows share: a term wants a row
+    from df >= dense_row_min_df(n_docs), rows are held by df rank while
+    `dense_rows_room` lasts, and terms left without are counted
+    (`note_degraded`): they keep their tiles."""
+    from ..common.memory import hbm_ledger
+
+    df = np.asarray(df).astype(np.int64)
+    wanted = np.flatnonzero(df >= dense_row_min_df(n_docs))
+    by_df = wanted[np.argsort(-df[wanted], kind="stable")]
+    held = by_df[: dense_rows_room(row_bytes)]
+    if len(held) < len(wanted):
+        hbm_ledger.note_degraded()
+    return held, len(wanted)
+
+
 class DevicePostings:
     """A field's postings tiles on the device: the doc-id plane at
-    once, the tf plane at its first use. A filter reads ids alone (the
-    knn family's masks over a keyword field never upload its tfs: half
-    the field's bytes); every scoring path reads both."""
+    once, the tf plane at its first use, the commonest terms' bit rows
+    at the first filtered kNN search. A filter reads ids alone (the knn
+    family's masks over a keyword field never upload its tfs: half the
+    field's bytes); every scoring path reads both; a field no
+    `knn.filter` names builds no bit row."""
 
-    def __init__(self, pf, device=None, charge=None):
+    def __init__(self, pf, device=None, charge=None, n_docs: int = 0):
         self.doc_ids = jax.device_put(pf.doc_ids, device)
         self._pf, self._device, self._charge = pf, device, charge
+        self._n_docs = n_docs
         self._tfs = None
+        self._bits = None  # scoring.FilterBitRows once built
         self._lock = threading.Lock()
 
     @property
@@ -100,6 +122,40 @@ class DevicePostings:
                         self._charge("postings", int(tfs.nbytes), False)
                     self._tfs = tfs
         return self._tfs
+
+    @property
+    def filter_bits(self):
+        """The bit rows `scoring.knn_filter_mask` reads instead of
+        scattering (scoring.FilterBitRows; without a plane where no
+        term holds one). The sparse family's choice of dense rows
+        (`dense_rows_held`) read for a filter: a term WANTS a row from
+        df >= dense_row_min_df(n) on the segment (122 tags of the
+        filtered cell's 200,386, a third of the terms its requests name
+        and 97.5% of the tiles they scattered); rows are HELD by df
+        rank while `dense_rows_room` lasts (n / 8 bytes a row), built
+        once on the device from the resident tiles, charged to the
+        ledger with the postings; a wanted term without a row keeps its
+        tiles: an optimisation lost, never an answer. The room would
+        hold rows down to df ~11,400 there (858 rows, 1.07 GB): not
+        taken, what they would save is ~0.25 ms of a mean request of
+        ~7 (PERF.md section 6, PR 45)."""
+        if self._bits is None:
+            with self._lock:
+                if self._bits is None:
+                    self._bits = self._filter_bits_build()
+        return self._bits
+
+    def _filter_bits_build(self):
+        pf, n = self._pf, self._n_docs
+        held, _wanted = dense_rows_held(
+            pf.term_df, n, 4 * scoring.filter_bit_words(n))
+        if not len(held):
+            return scoring.FilterBitRows()
+        rows = scoring.build_filter_bit_rows(
+            self.doc_ids, pf.term_tile_start, pf.term_tile_count, held, n)
+        if self._charge is not None:
+            self._charge("postings", rows.nbytes, False)
+        return rows
 
 
 def _tree_nbytes(v) -> int:
@@ -180,7 +236,8 @@ class DeviceSegment:
         self.device = device
         self.postings = _LazyDeviceMap(
             seg.postings,
-            lambda f: DevicePostings(seg.postings[f], device, charge),
+            lambda f: DevicePostings(
+                seg.postings[f], device, charge, seg.num_docs),
             charge=charge, category="postings",
         )
         self.numerics = _LazyDeviceMap(
@@ -2095,16 +2152,10 @@ class JaxExecutor:
         charged to the ledger's `dense_rows`; a wanted term without a
         row keeps its tiles and is counted (`note_degraded`, the
         `sparse.dense_rows_*` gauges)."""
-        from ..common.memory import hbm_ledger
         from ..ops import impact as impact_ops
 
-        df = sf.term_df.astype(np.int64)
-        wanted = np.flatnonzero(df >= dense_row_min_df(n))
-        by_df = wanted[np.argsort(-df[wanted], kind="stable")]
-        held = by_df[: dense_rows_room(impact_ops.impact_row_stride(n))]
-        if len(held) < len(wanted):
-            hbm_ledger.note_degraded()
-        sc.rows_wanted = int(len(wanted))
+        held, sc.rows_wanted = dense_rows_held(
+            sf.term_df, n, impact_ops.impact_row_stride(n))
         if not len(held):
             return None
         rows = impact_ops.build_impact_rows(

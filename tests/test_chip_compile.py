@@ -193,6 +193,61 @@ def test_filtered_byte_knn_programs(one_chip, rows):
     assert len(readers) == 1 and " fusion(" in readers[0], readers
 
 
+# the bit rows the deployment's tag field holds: its 122 tags in 78,125
+# rows or more (dense_row_min_df(10M)), 1.25 MB a row
+FILTERED_BIT_ROWS = 122
+
+
+@pytest.mark.parametrize("slots", scoring.FILTER_SLOT_BUCKETS)
+def test_filtered_mask_program_with_bit_rows(one_chip, slots):
+    """One row (the cell) at both slot buckets: the program compiles, fits
+    beside the deployment's resident set, and its branch for a launch that
+    scatters nothing holds no operation over the 40 MB count plane."""
+    s = _on(one_chip)
+    words = scoring.filter_bit_words(FILTERED_DOCS)
+    assert words * 32 >= FILTERED_DOCS and words * 4 < 1_300_000
+    mask = scoring.knn_filter_mask.lower(
+        s((FILTERED_TILES, TILE), jnp.int32),
+        s((FILTERED_DOCS,), jnp.bool_),
+        s((1, 3 * slots + 1), jnp.int32),
+        s((FILTERED_BIT_ROWS, words), jnp.uint32),
+    ).compile()
+    resident = (FILTERED_DOCS * 196 + FILTERED_TILES * TILE * 4
+                + FILTERED_BIT_ROWS * words * 4)
+    assert _fits(mask) + resident < HBM_BYTES
+    hlo = mask.as_text()
+    (cond,) = re.findall(
+        r"conditional\(.*branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}",
+        hlo)
+    plane = f"s32[{FILTERED_DOCS + 1}]"
+    bodies = [_computation(hlo, name) for name in cond]
+    with_plane = [plane in _reachable(hlo, body) for body in bodies]
+    # false branch first: the launch that scatters nothing
+    assert with_plane == [False, True], with_plane
+    assert "while" not in _reachable(hlo, bodies[0])
+
+
+def _computation(hlo: str, name: str) -> str:
+    """The text of one named computation of an HLO module."""
+    m = re.search(rf"^%?{re.escape(name)} .*?^}}", hlo, re.M | re.S)
+    assert m is not None, name
+    return m.group(0)
+
+
+def _reachable(hlo: str, body: str) -> str:
+    """`body` and the text of every computation it calls, transitively."""
+    seen, todo, text = set(), [body], []
+    while todo:
+        cur = todo.pop()
+        text.append(cur)
+        for name in re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", cur):
+            if name not in seen:
+                seen.add(name)
+                todo.append(_computation(hlo, name))
+    return "\n".join(text)
+
+
 # the phrase deployment (benchmarks/configs/msmarco-phrase.json): the
 # positions plane of 1,000,000 passages as corpora/zipf_text_ordered.py
 # builds it (class width -> passages of the class; 271 MB of int32), a

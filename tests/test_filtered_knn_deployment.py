@@ -11,7 +11,10 @@ scores within 1e-6, `hits.total` equal).
 30,000 rows: the commonest tag is carried by 30% of them, 9,000 rows in
 71 tiles, more than one trip of the mask program takes
 (`scoring.FILTER_CHUNK` = 64), and the vocabulary is whole (200,386
-tags), so the rarest tags are in one row.
+tags), so the rarest tags are in one row. The tags in 1,024 rows or more
+(`dense_row_min_df`'s floor) hold a bit row of the segment, which the
+mask reads instead of scattering their tiles; every other tag is
+scattered, as every tag is where the HBM room refuses the rows.
 """
 
 import http.client
@@ -94,9 +97,18 @@ class Deployment:
         df = np.asarray(self.pf.term_df)
         self.by_df = np.argsort(-df, kind="stable")  # commonest first
         self.df = df
+        # the tags that hold a bit row of the segment (30,000 rows:
+        # dense_row_min_df is its floor)
+        self.on_rows = {self.tag(t) for t in np.flatnonzero(df >= 1024)}
 
     def tag(self, t: int) -> str:
         return self.pf.terms[int(t)]
+
+    def scattered_tiles(self, tags) -> int:
+        """Postings tiles a mask launch scatters for `tags`: those of
+        the tags without a bit row."""
+        return sum(int(self.pf.term_tile_count[int(t[1:])])
+                   for t in tags if t not in self.on_rows)
 
     def body(self, tags, vector=None, **knn) -> dict:
         section = dict(self.bodies[0]["knn"])
@@ -234,6 +246,9 @@ def test_terms_clause_counts_once_however_many_of_its_tags_a_row_holds(dep):
 
 
 def test_deleted_rows_do_not_pass(dep):
+    """The tag holds a bit row, built once from the segment's postings:
+    deletes are ANDed in from the live plane every launch."""
+    assert dep.tag(dep.by_df[3]) in dep.on_rows
     body = dep.body([dep.tag(dep.by_df[3])])
     index = "yfcc-deletes"
     first = dep.search(body, index)
@@ -253,6 +268,76 @@ def test_deleted_rows_do_not_pass(dep):
     assert [h["_id"] for h in served["hits"]["hits"]] == [
         h["_id"] for h in want]
     assert not {h["_id"] for h in served["hits"]["hits"]} & set(map(str, gone))
+
+
+# ---- the commonest tags' bit rows ------------------------------------------
+
+def test_common_tags_are_read_from_bit_rows_and_counted(dep):
+    """The rows held are the tags over the rule; a filter of two such
+    tags scatters nothing, one of a common and a rare tag scatters the
+    rare tag's tiles alone; `_nodes/stats` counts both."""
+    svc = dep.server.cluster.indices[dep.index]
+    dp = svc._executor(svc.shards[0]).device_segments[0].postings["tags"]
+    dep.search(dep.bodies[0])  # the field's first filtered search builds them
+    rows = dp.filter_bits
+    assert {dep.tag(t) for t in rows.row_of_term} == dep.on_rows
+    assert 3 <= len(dep.on_rows) < 100 and dp._tfs is None
+    assert rows.plane.shape == (len(dep.on_rows), scoring.filter_bit_words(DOCS))
+    commonest, second = dep.tag(dep.by_df[0]), dep.tag(dep.by_df[1])
+    rare = next(dep.tag(t) for t in dep.by_df if 100 <= dep.df[t] < 1024)
+    for tags, on_rows in (([commonest, second], 2), ([commonest, rare], 1),
+                          ([rare], 0)):
+        kf0 = dep.node()["knn_filtered"]
+        body = dep.body(tags)
+        dep.held(body, dep.search(body))
+        kf1 = dep.node()["knn_filtered"]
+        assert kf1["filter_terms"] == kf0["filter_terms"] + len(tags)
+        assert kf1["bitset_terms"] == kf0["bitset_terms"] + on_rows
+        assert (kf1["filter_tiles"] - kf0["filter_tiles"]
+                == dep.scattered_tiles(tags))
+        assert kf1["fallbacks"] == kf0["fallbacks"]
+    assert dep.node()["knn_filtered"]["bitset_terms"] > 0
+
+
+def test_rows_the_hbm_room_refuses_leave_the_terms_on_their_tiles(dep):
+    """No headroom in the ledger when a field's rows would be built:
+    none is held, it is counted as a degrade, every tag is scattered
+    (the commonest over more than one trip of the mask program) and
+    the answers are the plain reference's."""
+    from elasticsearch_tpu.common.memory import hbm_ledger
+
+    index = "yfcc-no-room"
+    ok(dep.port, "PUT", f"/{index}", {
+        "settings": dep.config["settings"],
+        "mappings": dep.corpus["mappings"]})
+    place_segment(dep.server.cluster.indices[index], dep.corpus["segment"])
+    bare = dict(dep.bodies[0]["knn"])
+    del bare["filter"]
+    dep.search({"knn": bare, "size": 1, "_source": False}, index)  # vectors up
+    svc = dep.server.cluster.indices[index]
+    dp = svc._executor(svc.shards[0]).device_segments[0].postings["tags"]
+    budget, degraded = hbm_ledger.budget, hbm_ledger.stats()["degraded_allocations"]
+    hbm_ledger.budget = hbm_ledger.used  # everything uploaded fits; no more
+    try:
+        before = dep.node()["knn_filtered"]
+        bodies = [dep.body([dep.tag(dep.by_df[0])]),
+                  dep.body([dep.tag(dep.by_df[1]), dep.tag(dep.by_df[2])]),
+                  dep.bodies[6], dep.bodies[7]]
+        for body in bodies:
+            dep.held(body, dep.search(body, index))
+    finally:
+        hbm_ledger.budget = budget
+    assert dp.filter_bits.plane is None
+    assert hbm_ledger.stats()["degraded_allocations"] == degraded + 1
+    after = dep.node()["knn_filtered"]
+    assert after["bitset_terms"] == before["bitset_terms"]
+    assert after["fallbacks"] == before["fallbacks"]
+    assert after["searches"] == before["searches"] + len(bodies)
+    assert (after["filter_tiles"] - before["filter_tiles"]
+            >= int(dep.pf.term_tile_count[dep.by_df[0]]) > scoring.FILTER_CHUNK)
+    mask = next(s for s in dep.last_trace()["spans"]
+                if s["name"] == "filter_mask")
+    assert mask["tags"]["bitset_rows_held"] == 0 == mask["tags"]["bitset_terms"]
 
 
 # ---- one launch, several jobs, each under its own mask --------------------
@@ -348,7 +433,8 @@ def test_bare_knn_keeps_the_program_it_had(dep, monkeypatch):
 def test_request_is_a_knn_job_with_its_spans_and_counters(dep):
     body = dep.bodies[3]
     tags = [c["term"]["tags"] for c in body["knn"]["filter"]["bool"]["filter"]]
-    tiles = sum(int(dep.pf.term_tile_count[int(t[1:])]) for t in tags)
+    tiles = dep.scattered_tiles(tags)
+    on_rows = sum(t in dep.on_rows for t in tags)
     before = dep.node()
     served = dep.search(body)
     after = dep.node()
@@ -359,6 +445,8 @@ def test_request_is_a_knn_job_with_its_spans_and_counters(dep):
     assert kf1["rows_scanned"] == kf0["rows_scanned"] + DOCS
     assert kf1["rows_passed"] == kf0["rows_passed"] + rows_passing(dep, tags)
     assert kf1["filter_tiles"] == kf0["filter_tiles"] + tiles
+    assert kf1["filter_terms"] == kf0["filter_terms"] + len(tags)
+    assert kf1["bitset_terms"] == kf0["bitset_terms"] + on_rows
     assert kf1["fallbacks"] == kf0["fallbacks"]
     b0, b1 = (n["pipeline"]["batching"] for n in (before, after))
     assert b1["unplanned_queries"] == b0["unplanned_queries"]
@@ -374,7 +462,9 @@ def test_request_is_a_knn_job_with_its_spans_and_counters(dep):
     assert disp["tags"]["filter_tiles"] == tiles
     mask = spans["filter_mask"]
     assert mask["parent_id"] == disp["id"]
-    assert mask["tags"] == {"segment": 0, "launches": 1, "tiles": tiles}
+    assert mask["tags"] == {"segment": 0, "launches": 1, "tiles": tiles,
+                            "bitset_terms": on_rows,
+                            "bitset_rows_held": len(dep.on_rows)}
     assert spans["plan"]["tags"] == {"family": "knn", "planned": True}
 
 

@@ -319,3 +319,172 @@ def test_filtered_scan_with_the_norm_plane_is_the_scan_without(rows):
     picked = np.take_along_axis(np.asarray(plain), np.asarray(got_d), axis=1)
     np.testing.assert_array_equal(picked, np.asarray(want_s))
     assert np.take_along_axis(mask, np.asarray(got_d), axis=1).all()
+
+
+# ---- the filtered kNN mask: terms answered from bit rows (ops/scoring.py
+# `knn_filter_mask` with `FilterBitRows`) against the scatter-only program --
+
+BITS_N = 8_011  # not a multiple of 32; dense_row_min_df(8,011) = 1,024
+# term -> df: one tag in 30% of the rows, two more over the rule, one just
+# under it, rare ones, one row
+BITS_DF = {"common": 2_403, "second": 1_500, "third": 1_024,
+           "under": 1_023, "rare": 300, "few": 7, "one": 1}
+
+
+@pytest.fixture(scope="module")
+def bits_field():
+    """(PostingsField of BITS_DF's tags over BITS_N documents, its
+    DevicePostings, the live / exists plane)."""
+    from elasticsearch_tpu.index.segment import TILE, FieldStats, PostingsField
+    from elasticsearch_tpu.search.executor_jax import DevicePostings
+
+    rng = np.random.default_rng(45)
+    terms = sorted(BITS_DF)
+    df = np.asarray([BITS_DF[t] for t in terms], np.int32)
+    count = ((df + TILE - 1) // TILE).astype(np.int32)
+    start = (np.cumsum(count) - count).astype(np.int32)
+    doc_ids = np.full((int(count.sum()), TILE), -1, np.int32)
+    for t, (s, c, d) in enumerate(zip(start, count, df)):
+        docs = np.sort(rng.choice(BITS_N, int(d), replace=False))
+        doc_ids[s : s + c].reshape(-1)[:d] = docs
+    tfs = (doc_ids >= 0).astype(np.int32)
+    pf = PostingsField(
+        terms=terms, term_df=df, term_total_tf=df.astype(np.int64),
+        term_tile_start=start, term_tile_count=count, doc_ids=doc_ids,
+        tfs=tfs, tile_max_tf=tfs.max(axis=1),
+        tile_min_norm=np.zeros(len(doc_ids), np.uint8),
+        norms=np.zeros(BITS_N, np.uint8), stats=FieldStats(),
+    )
+    cand = rng.random(BITS_N) < 0.9
+    return pf, DevicePostings(pf, n_docs=BITS_N), cand
+
+
+# name -> the launch's filters (each a job's clauses); one job unless named
+# a mix
+BITS_FILTERS = {
+    "dense_and_dense": [(("common",), ("second",))],
+    "dense_and_rare": [(("common",), ("rare",))],
+    "rare_and_rare": [(("under",), ("rare",))],
+    "one_dense_term": [(("third",),)],
+    "one_term_just_under_the_rule": [(("under",),)],
+    "clause_of_a_dense_and_a_rare_term": [(("common", "rare"), ("second",))],
+    "clause_of_dense_terms_alone": [(("common", "third"), ("second",))],
+    "two_clauses_of_dense_terms": [(("common", "third"), ("second", "third"))],
+    "term_the_segment_lacks": [(("common",), ("nowhere",))],
+    "dense_clause_with_a_term_the_segment_lacks": [(("common", "nowhere"),)],
+    "only_a_term_the_segment_lacks": [(("nowhere",),)],
+    "four_rows_mixed": [
+        (("common",), ("second",)), (("common",), ("rare",)),
+        (("under",), ("few",)), (("common", "rare"), ("third",))],
+    "three_rows_all_from_bit_rows": [
+        (("common",),), (("second",), ("third",)), (("common", "second"),)],
+}
+
+
+@pytest.mark.parametrize("case", BITS_FILTERS)
+def test_mask_from_bit_rows_is_the_scattered_mask(bits_field, case):
+    """Masks and rows passed equal the scatter-only program's bit for
+    bit; pad rows stay empty; only the terms without a row are
+    scattered."""
+    from elasticsearch_tpu.ops import scoring
+
+    pf, dp, cand = bits_field
+    rows = dp.filter_bits
+    filters = BITS_FILTERS[case]
+    width = 4 if len(filters) > 1 else 2  # pad rows either way
+    got = scoring.pack_filter_plans(pf, filters, width, rows)
+    want = scoring.pack_filter_plans(pf, filters, width)
+    assert got.plan.shape == want.plan.shape
+    held = {pf.terms[t] for t in rows.row_of_term}
+    assert held == {"common", "second", "third"}
+    present = [[t for t in c if t in BITS_DF] for f in filters for c in f]
+    on_rows = [c for c in present if c and set(c) <= held]
+    assert got.terms == want.terms == sum(len(c) for f in filters for c in f)
+    assert got.bit_terms == sum(map(len, on_rows)) and want.bit_terms == 0
+    assert got.tiles == sum(
+        int(pf.term_tile_count[pf.term_id(t)])
+        for c in present if c not in on_rows for t in c)
+    assert want.tiles == sum(
+        int(pf.term_tile_count[pf.term_id(t)]) for c in present for t in c)
+    mask, passed = scoring.knn_filter_mask(
+        dp.doc_ids, cand, got.plan, rows.plane)
+    ref_mask, ref_passed = scoring.knn_filter_mask(dp.doc_ids, cand, want.plan)
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(ref_mask))
+    np.testing.assert_array_equal(np.asarray(passed), np.asarray(ref_passed))
+    assert not np.asarray(mask)[len(filters):].any()
+    # and both are the filter's own meaning
+    for ji, clauses in enumerate(filters):
+        rows_ok = cand.copy()
+        for clause in clauses:
+            any_of = np.zeros(BITS_N, bool)
+            for t in clause:
+                if t in BITS_DF:
+                    any_of[pf.term_docs(pf.term_id(t))] = True
+            rows_ok &= any_of
+        np.testing.assert_array_equal(np.asarray(mask)[ji], rows_ok)
+
+
+def test_each_bit_row_is_the_packed_scatter_mask_of_its_tag(bits_field):
+    from elasticsearch_tpu.ops import scoring
+
+    pf, dp, _cand = bits_field
+    rows = dp.filter_bits
+    w = scoring.filter_bit_words(BITS_N)
+    assert w % scoring.FILTER_BIT_WORDS_ALIGN == 0 and 32 * w >= BITS_N
+    assert rows.plane.shape == (3, w) and rows.plane.dtype == np.uint32
+    assert rows.nbytes == 3 * w * 4
+    plane = np.asarray(rows.plane)
+    everyone = np.ones(BITS_N, bool)
+    for tid, r in rows.row_of_term.items():
+        docs = pf.term_docs(tid)
+        want = np.zeros(w, np.uint32)  # document d: bit d // w of word d % w
+        np.bitwise_or.at(want, docs % w, np.uint32(1) << (docs // w).astype(np.uint32))
+        np.testing.assert_array_equal(plane[r], want)
+        (mask, _) = scoring.knn_filter_mask(
+            dp.doc_ids, everyone,
+            scoring.pack_filter_plans(pf, [((pf.terms[tid],),)], 1).plan)
+        np.testing.assert_array_equal(
+            np.asarray(scoring._pack_bit_rows(mask))[0], plane[r])
+        np.testing.assert_array_equal(
+            np.asarray(scoring._unpack_bit_rows(rows.plane[r:r + 1], BITS_N)),
+            np.asarray(mask))
+    # rows number by df rank, the commonest first
+    by_rank = sorted(rows.row_of_term, key=rows.row_of_term.get)
+    assert [pf.terms[t] for t in by_rank] == ["common", "second", "third"]
+
+
+@pytest.mark.parametrize("n_docs, held", [(1_023, 0), (1_024, 1), (131_200, 0)],
+                         ids=["under_the_floor", "at_the_floor", "rule_above_every_df"])
+def test_segment_builds_bit_rows_only_for_terms_over_the_rule(n_docs, held):
+    """A tag in every row of a segment under `dense_row_min_df`'s floor
+    of 1,024 builds no row (the program runs as it always did); at the
+    floor it holds one; a wide segment whose rule (n / 128) no tag
+    passes holds none."""
+    from elasticsearch_tpu.index.segment import TILE, FieldStats, PostingsField
+    from elasticsearch_tpu.search.executor_jax import (
+        DevicePostings, dense_row_min_df)
+
+    df = min(n_docs, 1_024)
+    assert (df >= dense_row_min_df(n_docs)) is bool(held)
+    count = -(-df // TILE)
+    doc_ids = np.full((count, TILE), -1, np.int32)
+    doc_ids.reshape(-1)[:df] = np.arange(df)
+    tfs = (doc_ids >= 0).astype(np.int32)
+    pf = PostingsField(
+        terms=["every"], term_df=np.asarray([df], np.int32),
+        term_total_tf=np.asarray([df], np.int64),
+        term_tile_start=np.zeros(1, np.int32),
+        term_tile_count=np.asarray([count], np.int32), doc_ids=doc_ids,
+        tfs=tfs, tile_max_tf=tfs.max(axis=1),
+        tile_min_norm=np.zeros(count, np.uint8),
+        norms=np.zeros(n_docs, np.uint8), stats=FieldStats(),
+    )
+    charged = []
+    dp = DevicePostings(pf, charge=lambda *a: charged.append(a), n_docs=n_docs)
+    rows = dp.filter_bits
+    assert dp._tfs is None  # a filter's rows never upload the tf plane
+    if not held:
+        assert rows.plane is None and not rows.row_of_term and charged == []
+        return
+    assert len(rows.row_of_term) == 1 and dp.filter_bits is rows  # built once
+    assert charged == [("postings", rows.nbytes, False)]
