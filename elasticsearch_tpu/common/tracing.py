@@ -113,10 +113,18 @@ starts at `http`'s start and reaches the ring when `http` ends:
             on one segment: the words looked up in the term dictionary,
             the plan packed and uploaded, `phrase_topk` enqueued (one
             launch; no host sync)
+          > sparse_plan [segment, terms, cold_terms, tiles_kept]  a
+            sparse group on one segment, from the segment's entry to
+            just before its first `_impact_chunk_add` is enqueued: the
+            terms looked up, the row launch enqueued, `sparse_theta`,
+            the surviving tiles listed and staged (the host's share of
+            the request's critical path; `cold_terms` go through
+            tiles, `tiles_kept` of them after pruning)
           > sparse_theta [segment, launches, postings]  a sparse
             group's thresholds on one segment, computed on the host
             from its prunable jobs' first tiles (`postings` slots
-            gathered; launches 0: no device work, no host sync)
+            gathered; launches 0: no device work, no host sync);
+            inside `sparse_plan`
         inflight  -> the worker comes back to collect the group
         collect [d2h_bytes; a text or sparse group also merged: false
             when it downloaded the fused kernel's packed row as it was,
@@ -468,14 +476,15 @@ _xfer = {"h2d_count": 0, "h2d_bytes": 0, "d2h_count": 0, "d2h_bytes": 0}
 _xfer_thread = _ThreadTransfers()
 
 
-def note_transfer(direction: str, nbytes: int) -> None:
-    """One host<->device transfer of the query path, noted where it
-    happens. `direction` is "h2d" (an upload: an explicit `device_put`
-    or a host array handed to a jitted program) or "d2h" (a blocking
-    download, i.e. a host sync). Exact, so they repeat on any backend."""
+def note_transfer(direction: str, nbytes: int, count: int = 1) -> None:
+    """`count` host<->device transfers of the query path (one launch's
+    operands, `nbytes` together), noted where they happen. `direction`
+    is "h2d" (an upload: an explicit `device_put` or a host array handed
+    to a jitted program) or "d2h" (a blocking download, i.e. a host
+    sync). Exact, so they repeat on any backend."""
     nbytes = int(nbytes)
     with _xfer_lock:
-        _xfer[direction + "_count"] += 1
+        _xfer[direction + "_count"] += count
         _xfer[direction + "_bytes"] += nbytes
     if direction == "d2h":
         _xfer_thread.d2h_bytes += nbytes

@@ -36,6 +36,7 @@ atomic manifest rename at the shard level (see engine.py).
 
 from __future__ import annotations
 
+import itertools
 import json
 import math
 import os
@@ -386,6 +387,16 @@ class SparseField:
         if self._term_index is None:
             self._term_index = {t: i for i, t in enumerate(self.terms)}
         return self._term_index.get(term, -1)
+
+    def term_ids(self, terms: Sequence[str]) -> np.ndarray:
+        """int64[len(terms)]: `term_id` of each, in one pass."""
+        if self._term_index is None:
+            self.term_id("")
+        return np.fromiter(
+            map(self._term_index.get, terms, itertools.repeat(-1)),
+            np.int64,
+            len(terms),
+        )
 
     @property
     def n_tiles(self) -> int:

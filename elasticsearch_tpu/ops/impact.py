@@ -160,9 +160,23 @@ def _impact_chunk_add(doc_ids, values, acc, cnt, ti, tw, tv):
     return acc, cnt
 
 
-@functools.partial(jax.jit, donate_argnums=(1, 2))
-def _impact_dense_add(plane, acc, cnt, ids, tw):
-    """acc[B, n+1] += the dense rows a launch names: `ids` i32[B,
+@functools.partial(jax.jit, static_argnames=("rows", "width"))
+def _impact_zeros(rows: int, width: int):
+    """The two accumulator planes of a launch bucket, zeroed: ONE
+    program where a scoring starts from its tiles (no row launch makes
+    them). Eager `jnp.zeros` are two programs a plane, each a dispatch
+    the host pays before the first kernel (PERF.md section 6, PR 46)."""
+    return (
+        jnp.zeros((rows, width), jnp.float32),
+        jnp.zeros((rows, width), jnp.int32),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def _impact_dense_add(plane, ids, tw, width: int):
+    """(acc, cnt)[B, width = n+1]: the dense rows a launch names added
+    into planes ZEROED HERE, since the row launch is a scoring's first
+    program (nothing to donate, no fill in front of it): `ids` i32[B,
     DENSE_SLOTS] rows of `plane` (-1 = unused, filled from slot 0 up),
     `tw` f32[B, DENSE_SLOTS] their folded tile weights. Per slot and
     query row: present = row != ROW_ABSENT, acc += where(present, tw *
@@ -180,7 +194,7 @@ def _impact_dense_add(plane, acc, cnt, ids, tw):
     `_impact_chunk_add` relays them around its scatters, a slot is one
     fused pass a plane, 11 us a row. (Four or eight rows a trip read
     8 us a row: 0.05 ms a launch of 16 rows, not worth the unrolling.)"""
-    n_q, width = acc.shape
+    n_q = ids.shape[0]
     stride = impact_row_stride(width - 1)
     n_rows = plane.shape[0] // stride
     slots = jnp.arange(1, ids.shape[1] + 1, dtype=jnp.int32)
@@ -204,9 +218,22 @@ def _impact_dense_add(plane, acc, cnt, ids, tw):
         0,
         used,
         slot,
-        (tuple(acc[b] for b in range(n_q)), tuple(cnt[b] for b in range(n_q))),
+        (
+            tuple(jnp.zeros((width,), jnp.float32) for _ in range(n_q)),
+            tuple(jnp.zeros((width,), jnp.int32) for _ in range(n_q)),
+        ),
     )
     return jnp.stack(accs), jnp.stack(cnts)
+
+
+def term_tiles(starts: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """int64 tile ids of terms whose contiguous tile ranges begin at
+    `starts` and hold `counts` tiles, laid out term after term."""
+    counts = counts.astype(np.int64, copy=False)
+    first = np.cumsum(counts) - counts  # where each term's range begins
+    return np.arange(int(counts.sum()), dtype=np.int64) + np.repeat(
+        starts.astype(np.int64, copy=False) - first, counts
+    )
 
 
 def impact_row_stride(n_docs: int) -> int:
@@ -254,12 +281,8 @@ def build_impact_rows(
     row_of_term = np.full(len(term_tile_start), -1, np.int32)
     row_of_term[held] = np.arange(len(held), dtype=np.int32)
     counts = term_tile_count[held].astype(np.int64)
-    total = int(counts.sum())
-    tiles = (
-        np.arange(total, dtype=np.int64)
-        - np.repeat(np.cumsum(counts) - counts, counts)
-        + np.repeat(term_tile_start[held].astype(np.int64), counts)
-    ).astype(np.int32)
+    tiles = term_tiles(term_tile_start[held], counts).astype(np.int32)
+    total = len(tiles)
     row_of_tile = np.repeat(np.arange(len(held), dtype=np.int32), counts)
     step = min(ROWS_FILL_TILES, 1 << max(total - 1, 0).bit_length())
     plane = jnp.full((len(held) * stride,), ROW_ABSENT, jnp.int8)
@@ -293,6 +316,7 @@ class ImpactScorer:
         # and the count of terms that want one
         self.rows: Optional[ImpactRows] = None
         self.rows_wanted = 0
+        self._msm: dict = {}  # launch bucket -> int32[rows] of ones
 
     def row_slots(self, tids: Sequence[int]) -> np.ndarray:
         """int32[len(tids)]: the row each query term is served from, -1
@@ -308,54 +332,72 @@ class ImpactScorer:
             out[hot[np.argsort(out[hot], kind="stable")[DENSE_SLOTS:]]] = -1
         return out
 
-    def add_rows(self, acc, cnt, row_lists, weight_lists):
-        """One `_impact_dense_add` launch: per query row (≤ acc rows)
-        the dense rows it names (≤ DENSE_SLOTS) and their folded tile
-        weights, into the donated accumulators."""
-        rows = int(acc.shape[0])
+    def add_rows(self, rows: int, row_lists, weight_lists):
+        """One `_impact_dense_add` launch, a scoring's first program:
+        per query row (≤ `rows`, the launch bucket) the dense rows it
+        names (≤ DENSE_SLOTS) and their folded tile weights, into
+        accumulators the program zeroes itself."""
         ids = np.full((rows, DENSE_SLOTS), -1, np.int32)
         tw = np.zeros((rows, DENSE_SLOTS), np.float32)
         for j, (rl, wl) in enumerate(zip(row_lists, weight_lists)):
             ids[j, : len(rl)] = rl
             tw[j, : len(rl)] = wl
-        for plane in (ids, tw):
-            note_transfer("h2d", plane.nbytes)
-        return _impact_dense_add(self.rows.plane, acc, cnt, ids, tw)
+        note_transfer("h2d", ids.nbytes + tw.nbytes, count=2)
+        return _impact_dense_add(
+            self.rows.plane, ids, tw, width=self.n_docs + 1
+        )
 
     def new_acc(self, rows: int = BPAD):
-        """Donated accumulators at one query-row bucket of the ladder."""
-        acc = jnp.zeros((rows, self.n_docs + 1), jnp.float32)
-        cnt = jnp.zeros((rows, self.n_docs + 1), jnp.int32)
-        return acc, cnt
+        """Zeroed accumulators at one query-row bucket of the ladder,
+        for a scoring no row launch starts: one program."""
+        return _impact_zeros(rows=rows, width=self.n_docs + 1)
 
-    def score_into(self, acc, cnt, tile_lists, weight_lists):
-        """Streams per-row tile/weight lists (≤ acc rows, any length)
-        through TCHUNK-wide launches into the donated accumulators.
-        Every launch is handed three host planes of its own, never
-        written again: a jitted call may still be reading a host
+    def stage_chunks(self, rows: int, tile_lists, weight_lists):
+        """The host planes (tiles i32, weights f32, valid bool, each
+        `[launches, rows, TCHUNK]`) of every `_impact_chunk_add` launch
+        that per-row tile/weight lists (≤ `rows`, any length) need,
+        allocated once. `add_chunks` hands every launch its own slice,
+        never written again: a jitted call may still be reading a host
         operand after it returns (the CPU backend aliases an aligned
         NumPy buffer and runs the program later), so one slab refilled
         chunk after chunk would score the wrong tiles (PERF.md section
         4, the sparse deployment's table)."""
-        rows = int(acc.shape[0])
-        t_max = max((len(t) for t in tile_lists), default=0)
-        for c0 in range(0, t_max, TCHUNK):
-            ti = np.zeros((rows, TCHUNK), np.int32)
-            tw = np.zeros((rows, TCHUNK), np.float32)
-            tv = np.zeros((rows, TCHUNK), bool)
-            for j, (tl, wl) in enumerate(zip(tile_lists, weight_lists)):
-                sl = tl[c0 : c0 + TCHUNK]
-                m = len(sl)
-                if m:
-                    ti[j, :m] = sl
-                    tw[j, :m] = wl[c0 : c0 + TCHUNK]
-                    tv[j, :m] = True
-            for plane in (ti, tw, tv):  # host arrays: the launch uploads them
-                note_transfer("h2d", plane.nbytes)
+        n = chunk_launches(tile_lists)
+        ti = np.zeros((n, rows, TCHUNK), np.int32)
+        tw = np.zeros((n, rows, TCHUNK), np.float32)
+        tv = np.zeros((n, rows, TCHUNK), bool)
+        for j, (tl, wl) in enumerate(zip(tile_lists, weight_lists)):
+            full, rest = divmod(len(tl), TCHUNK)
+            cut = full * TCHUNK
+            if full:
+                ti[:full, j] = np.reshape(tl[:cut], (full, TCHUNK))
+                tw[:full, j] = np.reshape(wl[:cut], (full, TCHUNK))
+                tv[:full, j] = True
+            if rest:
+                ti[full, j, :rest] = tl[cut:]
+                tw[full, j, :rest] = wl[cut:]
+                tv[full, j, :rest] = True
+        return ti, tw, tv
+
+    def add_chunks(self, acc, cnt, staged):
+        """Streams `stage_chunks`' planes through TCHUNK-wide launches
+        into the donated accumulators; each launch uploads its three."""
+        ti, tw, tv = staged
+        for c in range(len(ti)):
+            note_transfer(
+                "h2d", ti[c].nbytes + tw[c].nbytes + tv[c].nbytes, count=3
+            )
             acc, cnt = _impact_chunk_add(
-                self.doc_ids, self.values, acc, cnt, ti, tw, tv
+                self.doc_ids, self.values, acc, cnt, ti[c], tw[c], tv[c]
             )
         return acc, cnt
+
+    def score_into(self, acc, cnt, tile_lists, weight_lists):
+        """`stage_chunks` then `add_chunks` at the accumulators' rows."""
+        staged = self.stage_chunks(
+            int(acc.shape[0]), tile_lists, weight_lists
+        )
+        return self.add_chunks(acc, cnt, staged)
 
     def finalize(self, acc, cnt, k: int, live=None):
         s, d, tot = self.finalize_device(acc, cnt, k, live=live)
@@ -366,13 +408,18 @@ class ImpactScorer:
         merge_segment_topk-compatible triple shape. The sparse match
         mask is cnt > 0 (every query term is optional), which is exactly
         the finalize kernel at msm=1 — the ONE finalize kernel serves
-        text, serve and sparse families alike."""
+        text, serve and sparse families alike. The all-ones `msm` is a
+        device constant kept a bucket: made once, by the bucket's first
+        scoring."""
         rows = int(acc.shape[0])
+        msm = self._msm.get(rows)
+        if msm is None:
+            msm = self._msm[rows] = jnp.ones((rows,), jnp.int32)
         return _finalize(
             acc,
             cnt,
             live if live is not None else self.live,
-            jnp.ones((rows,), jnp.int32),
+            msm,
             k=min(k, self.n_docs),
         )
 
@@ -486,35 +533,34 @@ class SparseBlockMax:
         order, so the one device pass accumulates each doc cell in pure
         query-term order: the fp32 serving path (no rows) stays
         bit-identical to the numpy oracle whether or not pruning
-        dropped anything."""
-        tiles: List[np.ndarray] = []
-        weights: List[np.ndarray] = []
-        dropped = 0
-        for i in np.flatnonzero(~self.dense):
-            c = int(self.counts[i])
-            rng = np.arange(
-                self.starts[i], self.starts[i] + c, dtype=np.int64
-            )
-            if c > 1 and np.isfinite(theta):
-                others = self.sum_bound - float(
-                    self.bws[i] * self.term_max[i]
-                )
-                bound = (
-                    self.bws[i] * self.tile_bound[rng].astype(np.float32)
-                    + np.float32(others)
-                )
-                keep = bound >= theta
-                keep[0] = True  # first tile anchors theta; never drop
-                dropped += int((~keep).sum())
-                rng = rng[keep]
-            if len(rng):
-                tiles.append(rng)
-                weights.append(np.full(len(rng), self.tws[i], np.float32))
-        return (
-            np.concatenate(tiles) if tiles else np.zeros(0, np.int64),
-            np.concatenate(weights) if weights else np.zeros(0, np.float32),
-            dropped,
+        dropped anything.
+
+        Array operations over all those terms' tiles at once (a Python
+        loop a term cost the chip machine's host 0.69 ms a 49-token
+        query: PERF.md section 5, PR 46). A tile's bound is formed as
+        the loop formed it, float32(bw) x float32(tile bound) +
+        float32(others), `others` = the summed bound less the term's
+        own share, in float64 a term: the tiles, weights and `dropped`
+        are that loop's bit for bit (tier-1 holds them to it)."""
+        cold = np.flatnonzero(~self.dense)
+        counts = self.counts[cold]
+        tiles = term_tiles(self.starts[cold], counts)
+        weights = np.repeat(self.tws[cold], counts)
+        if not np.isfinite(theta) or len(tiles) == np.count_nonzero(counts):
+            return tiles, weights, 0  # nothing asked, or no tail tile
+        bws = self.bws[cold]
+        others = (
+            self.sum_bound
+            - (bws * self.term_max[cold]).astype(np.float64)
+        ).astype(np.float32)
+        bound = (
+            np.repeat(bws, counts) * self.tile_bound[tiles].astype(np.float32)
+            + np.repeat(others, counts)
         )
+        keep = bound >= np.float32(theta)
+        # a term's first tile anchors theta; never drop
+        keep[(np.cumsum(counts) - counts)[counts > 0]] = True
+        return tiles[keep], weights[keep], int(len(keep) - keep.sum())
 
     @property
     def n_tail_tiles(self) -> int:
@@ -534,28 +580,17 @@ def impact_tile_lists(
     host multiply per query term), so the device kernel is identical in
     both storage modes; the RAW weights ride along for SparseBlockMax,
     whose tile_qmax sidecar is already dequantized."""
-    tids: List[int] = []
-    tws: List[float] = []
-    bws: List[float] = []
-    for t, w in zip(terms, weights):
-        tid = sf.term_id(t)
-        if tid < 0:
-            continue
-        bw = np.float32(w)
-        tw = bw
-        if quantized:
-            tw = np.float32(tw * sf.scales[tid])
-        tids.append(tid)
-        tws.append(float(tw))
-        bws.append(float(bw))
+    tids = sf.term_ids(terms)
+    here = tids >= 0
+    tids = tids[here]
+    bws = np.asarray(weights, np.float32)[here]
+    tws = bws
+    if quantized:
+        tws = (bws * sf.scales[tids]).astype(np.float32, copy=False)
     return (
-        tids,
-        np.asarray(tws, np.float32),
-        np.asarray(bws, np.float32),
-        sf.term_tile_start[np.asarray(tids, np.int64)]
-        if tids
-        else np.zeros(0, np.int32),
-        sf.term_tile_count[np.asarray(tids, np.int64)]
-        if tids
-        else np.zeros(0, np.int32),
+        tids.tolist(),
+        tws,
+        bws,
+        sf.term_tile_start[tids],
+        sf.term_tile_count[tids],
     )

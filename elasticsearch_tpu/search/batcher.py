@@ -2707,6 +2707,7 @@ class QueryBatcher:
         from ..ops import impact as impact_ops
         from . import sparse as sparse_mod
 
+        t_plan = time.perf_counter_ns()
         ex = jobs[0].executor
         reader = ex.reader
         nj = len(jobs)
@@ -2719,13 +2720,11 @@ class QueryBatcher:
             cap = j.plan.tth_cap
             ok = cap is not None
             if ok and cap:
-                max_df = max(
-                    (ex.sparse_shard_df(field, t) for t in j.plan.terms),
-                    default=0,
-                )
+                max_df = ex.sparse_shard_max_df(field, j.plan.terms)
                 ok = max_df - ex.deleted_count > cap
             may_drop.append(ok)
-        tags = _group_now().plan_tags
+        group = _group_now()
+        tags = group.plan_tags
         if record:
             tags["quantized"] = bool(spec.quantized)
             tags["terms"] = sum(len(j.plan.terms) for j in jobs)
@@ -2756,6 +2755,7 @@ class QueryBatcher:
             row_lists: List[np.ndarray] = []
             row_weights: List[np.ndarray] = []
             tiles_dense = 0
+            terms_here = 0
             for ji, j in enumerate(jobs):
                 tids, tws, bws, _, counts = impact_ops.impact_tile_lists(
                     sfh, j.plan.terms, j.plan.weights, spec.quantized
@@ -2768,6 +2768,7 @@ class QueryBatcher:
                 row_lists.append(slots[hot])
                 row_weights.append(tws[hot])
                 tiles_dense += int(counts[hot].sum())
+                terms_here += len(tids)
                 bm = impact_ops.SparseBlockMax(
                     sfh.term_tile_start, sfh.term_tile_count,
                     bound, tids, tws, bws, dense=hot,
@@ -2778,15 +2779,24 @@ class QueryBatcher:
                 # but unpruned
                 if may_drop[ji] and (tws >= 0).all() and bm.n_tail_tiles:
                     theta_jobs.append(ji)
-            acc, cnt = sc.new_acc(rows)
             dense_rows = sum(len(r) for r in row_lists)
-            # launched FIRST: it needs nothing of theta or `kept`, so
-            # the device adds rows while the host computes both; a warm
+            # the scoring's FIRST program, which zeroes the planes it
+            # adds into: it needs nothing of theta or `kept`, so the
+            # device adds rows while the host computes both; a warm
             # launch (`record` False) compiles it whatever its dummy holds
             dense_launch = sc.rows is not None and (
                 dense_rows > 0 or not record)
-            if dense_launch:
-                acc, cnt = sc.add_rows(acc, cnt, row_lists, row_weights)
+            if dense_launch and record:
+                acc, cnt = sc.add_rows(rows, row_lists, row_weights)
+            else:
+                # no row to add: the planes come from the one fill
+                # program. A warm launch compiles both, and waits for
+                # the row launch so that its planes are gone before the
+                # fill's are made (256 MB a set at the widest bucket)
+                if dense_launch:
+                    for plane in sc.add_rows(rows, row_lists, row_weights):
+                        plane.block_until_ready()
+                acc, cnt = sc.new_acc(rows)
             thetas = np.full(len(jobs), -np.inf, np.float32)
             if theta_jobs:
                 t_theta = time.perf_counter_ns()
@@ -2796,7 +2806,7 @@ class QueryBatcher:
                         sfh.doc_ids, values, reader.live_docs[si], kb
                     )
                 if record:
-                    _group_now().sub_spans.append((
+                    group.sub_spans.append((
                         "sparse_theta", t_theta, time.perf_counter_ns(),
                         {"segment": si, "launches": 0,
                          "postings": impact_ops.TILE_WIDTH * sum(
@@ -2819,8 +2829,18 @@ class QueryBatcher:
                 # tile pass too: one tile at weight 0, answer discarded
                 tile_lists[0] = np.zeros(1, np.int64)
                 weight_lists[0] = np.zeros(1, np.float32)
-            acc, cnt = sc.score_into(acc, cnt, tile_lists, weight_lists)
-            launches = impact_ops.chunk_launches(tile_lists)
+            staged = sc.stage_chunks(rows, tile_lists, weight_lists)
+            if record:
+                # the host's share of the segment's critical path: all
+                # it does before the first chunk launch can be enqueued
+                group.sub_spans.append((
+                    "sparse_plan", t_plan, time.perf_counter_ns(),
+                    {"segment": si, "terms": terms_here,
+                     "cold_terms": terms_here - dense_rows,
+                     "tiles_kept": tiles_scored},
+                ))
+            acc, cnt = sc.add_chunks(acc, cnt, staged)
+            launches = len(staged[0])
             pend = sc.finalize_device(acc, cnt, kb)
             if record:
                 sparse_mod.note_search(
@@ -2832,7 +2852,7 @@ class QueryBatcher:
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["sparse_jobs"] += nj
-                _group_now().add_flops(impact_ops.sparse_flops(
+                group.add_flops(impact_ops.sparse_flops(
                     tiles_scored, dense_rows * sc.n_docs))
                 for name, n in (("tiles_scored", tiles_scored),
                                 ("tiles_pruned", tiles_pruned),
@@ -2841,6 +2861,7 @@ class QueryBatcher:
                                 ("tiles_dense", tiles_dense)):
                     tags[name] = tags.get(name, 0) + n
             items.append(("dev", si, (pend, pruned_flags)))
+            t_plan = time.perf_counter_ns()  # the next segment's entry
         return items
 
     def _collect_sparse_group(self, jobs: List[_Job], kb: int, items,

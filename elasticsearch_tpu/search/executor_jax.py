@@ -1164,21 +1164,19 @@ class JaxExecutor:
             self._shard_dfs[key] = df
         return df
 
-    def sparse_shard_df(self, field: str, term: str) -> int:
-        """Postings of one `sparse_vector` term over the shard's
-        segments (each a distinct doc that matches any query holding
-        the term): what proves a capped total before tiles may drop."""
-        key = ("sparse", field, term)
-        df = self._shard_dfs.get(key)
-        if df is None:
-            df = 0
-            for seg in self.reader.segments:
-                sf = (getattr(seg, "sparse", None) or {}).get(field)
-                tid = sf.term_id(term) if sf is not None else -1
-                if tid >= 0:
-                    df += int(sf.term_df[tid])
-            self._shard_dfs[key] = df
-        return df
+    def sparse_shard_max_df(self, field: str, terms) -> int:
+        """The most postings any ONE of `terms` holds in a
+        `sparse_vector` field over the shard's segments (each a
+        distinct doc that matches any query holding the term): what
+        proves a capped total before tiles may drop. Array look-ups a
+        segment, no Python call a term."""
+        df = np.zeros(len(terms), np.int64)
+        for seg in self.reader.segments:
+            sf = (getattr(seg, "sparse", None) or {}).get(field)
+            if sf is not None:
+                tids = sf.term_ids(terms)
+                df += np.where(tids >= 0, sf.term_df[tids], 0)
+        return int(df.max(initial=0))
 
     @property
     def deleted_count(self) -> int:

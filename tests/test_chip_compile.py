@@ -638,10 +638,9 @@ def test_impact_dense_rows(one_chip, rows):
     s = _on(one_chip)
     compiled = impact._impact_dense_add.lower(
         s((held * stride,), jnp.int8),
-        s((rows, N_DOCS + 1), jnp.float32),
-        s((rows, N_DOCS + 1), jnp.int32),
         s((rows, impact.DENSE_SLOTS), jnp.int32),
         s((rows, impact.DENSE_SLOTS), jnp.float32),
+        width=N_DOCS + 1,
     ).compile()
     _fits(compiled)
     if rows > 1:

@@ -278,6 +278,110 @@ class TestImpactKernel:
 # ---------------------------------------------------------------------------
 
 
+def kept_by_loop(bm, theta):
+    """`SparseBlockMax.kept` as it stood until PR 46, a Python loop a
+    term that goes through tiles: the reference its array form is held
+    to, bit for bit."""
+    tiles, weights = [], []
+    dropped = 0
+    for i in np.flatnonzero(~bm.dense):
+        c = int(bm.counts[i])
+        rng = np.arange(bm.starts[i], bm.starts[i] + c, dtype=np.int64)
+        if c > 1 and np.isfinite(theta):
+            others = bm.sum_bound - float(bm.bws[i] * bm.term_max[i])
+            bound = (
+                bm.bws[i] * bm.tile_bound[rng].astype(np.float32)
+                + np.float32(others)
+            )
+            keep = bound >= theta
+            keep[0] = True  # first tile anchors theta; never drop
+            dropped += int((~keep).sum())
+            rng = rng[keep]
+        if len(rng):
+            tiles.append(rng)
+            weights.append(np.full(len(rng), bm.tws[i], np.float32))
+    return (
+        np.concatenate(tiles) if tiles else np.zeros(0, np.int64),
+        np.concatenate(weights) if weights else np.zeros(0, np.float32),
+        dropped,
+    )
+
+
+def random_block_max(kind, seed):
+    """A seeded plan over a made-up field whose tile bounds fall
+    within a term, as impact ordering leaves them."""
+    rng = np.random.default_rng([seed, sum(map(ord, kind))])
+    n_terms = 60
+    counts = rng.integers(1, 12, n_terms).astype(np.int32)
+    if kind == "one_tile_terms":
+        counts[:] = 1
+    starts = (np.cumsum(counts) - counts).astype(np.int32)
+    bound = np.concatenate([
+        np.sort(rng.random(c).astype(np.float32) * 3 + 0.01)[::-1]
+        for c in counts])
+    n_q = 0 if kind == "empty" else int(rng.integers(1, 40))
+    tids = np.sort(rng.choice(n_terms, n_q, replace=False)).tolist()
+    bws = (rng.random(n_q) * 2 + 0.05).astype(np.float32)
+    tws = (bws * rng.random(n_q).astype(np.float32)).astype(np.float32)
+    dense = {"all_dense": np.ones(n_q, bool),
+             "no_dense": np.zeros(n_q, bool)}.get(kind, rng.random(n_q) < 0.4)
+    return impact_ops.SparseBlockMax(
+        starts, counts, bound, tids, tws, bws, dense=dense)
+
+
+def thetas_of(bm, kind):
+    """The thresholds a plan is tried at; `tie` is the bound of one of
+    its tail tiles exactly (kept: the test is `>=`), `above_tie` the
+    next float32 (dropped)."""
+    if kind == "-inf":
+        return [-np.inf]
+    if kind == "first_tiles_only":
+        return [float(np.float32(1e30))]
+    if kind == "quantiles":
+        return [float(np.float32(bm.sum_bound * f))
+                for f in (0.25, 0.6, 0.9, 1.0)]
+    out = []
+    for i in np.flatnonzero(~bm.dense & (bm.counts > 1)):
+        others = bm.sum_bound - float(bm.bws[i] * bm.term_max[i])
+        tail = bm.tile_bound[bm.starts[i] + bm.counts[i] - 1]
+        tie = bm.bws[i] * np.float32(tail) + np.float32(others)
+        assert tie.dtype == np.float32
+        out.append(float(tie) if kind == "tie" else float(
+            np.nextafter(tie, np.float32(np.inf))))
+    return out[:6]
+
+
+class TestKeptAsArrays:
+    @pytest.mark.parametrize("seed", range(3))
+    @pytest.mark.parametrize("theta_kind", [
+        "-inf", "tie", "above_tie", "first_tiles_only", "quantiles"])
+    @pytest.mark.parametrize("plan_kind", [
+        "mixed", "all_dense", "no_dense", "one_tile_terms", "empty"])
+    def test_kept_is_the_loops_bit_for_bit(self, plan_kind, theta_kind,
+                                           seed):
+        bm = random_block_max(plan_kind, seed)
+        cold = ~bm.dense
+        thetas = thetas_of(bm, theta_kind)
+        if theta_kind in ("tie", "above_tie"):
+            assert bool(thetas) == bool((cold & (bm.counts > 1)).any())
+        for theta in thetas:
+            tiles, weights, dropped = bm.kept(theta)
+            want_t, want_w, want_dropped = kept_by_loop(bm, theta)
+            _arrays_equal("tiles", tiles, want_t)
+            _arrays_equal("weights", weights, want_w)
+            assert dropped == want_dropped and isinstance(dropped, int)
+            assert len(tiles) + dropped == int(bm.counts[cold].sum())
+            if theta_kind == "first_tiles_only":
+                assert np.array_equal(tiles, bm.starts[cold])
+            if theta_kind == "-inf":
+                assert dropped == 0
+        if theta_kind == "tie" and thetas:
+            # the tied tile stays at its own bound and goes one ulp up
+            assert (bm.kept(thetas[0])[2]
+                    < bm.kept(float(np.nextafter(
+                        np.float32(thetas[0]), np.float32(np.inf))))[2])
+
+
 class TestServingParity:
     def test_fp32_serving_float_identical_to_oracle(self):
         jx = make_service("sp-fp32", quant="none")
@@ -725,6 +829,130 @@ class TestDenseRows:
         assert (sparse_mod.SPARSE_STATS["dense_rows_scored"]
                 - before["dense_rows_scored"]) == 2
         same_answers(got, run_group(tiles, bodies, 1))
+
+
+    def test_back_to_back_requests_answer_as_fresh_ones(self, pair):
+        """The row launch zeroes the planes it adds into and every
+        later launch is donated them: nothing of one request is left
+        for the next. A, B, A on one scorer answer as each does on the
+        scorer that never held a row, and the two A's bit for bit."""
+        rows, tiles = pair
+        a = [row_body(ROW_QUERIES["mixed"], 21, track_total_hits=True)]
+        b = [row_body(ROW_QUERIES["all_hot"], 22, track_total_hits=True)]
+        c = [row_body(ROW_QUERIES["none_hot"], 23, track_total_hits=True)]
+        first = run_group(rows, a, 1)
+        for other in (b, c):  # a row launch alone, then the fill alone
+            same_answers(run_group(rows, other, 1),
+                         run_group(tiles, other, 1))
+            assert run_group(rows, a, 1) == first
+        same_answers(first, run_group(tiles, a, 1))
+
+    @pytest.mark.parametrize("bucket", [1, 4, 8, 16, 32])
+    def test_the_first_kernel_starts_from_zeroed_planes(self, pair, bucket):
+        """At every bucket of the ladder: the fill program hands the
+        tile pass zeros, and the row launch's answer is its rows'
+        products and nothing else (the query rows that name no row read
+        zero everywhere)."""
+        rows, _tiles = pair
+        sc = self.scorer(rows)
+        width = sc.n_docs + 1
+        for plane, dtype in zip(sc.new_acc(bucket),
+                                (np.float32, np.int32)):
+            got = np.asarray(plane)
+            assert got.shape == (bucket, width) and got.dtype == dtype
+            assert not got.any()
+        stride = impact_ops.impact_row_stride(sc.n_docs)
+        held = np.asarray(sc.rows.plane).reshape(sc.rows.n_rows, stride)
+        last = bucket - 1  # the one query row that names a row
+        lists = [np.zeros(0, np.int32)] * last + [np.asarray([2], np.int32)]
+        ws = [np.zeros(0, np.float32)] * last + [np.asarray([1.5],
+                                                            np.float32)]
+        acc, cnt = (np.asarray(x) for x in sc.add_rows(bucket, lists, ws))
+        assert acc.shape == cnt.shape == (bucket, width)
+        assert not acc[:last].any() and not cnt[:last].any()
+        present = held[2, :width] != impact_ops.ROW_ABSENT
+        assert np.array_equal(cnt[last], present.astype(np.int32))
+        assert np.array_equal(acc[last], np.where(
+            present, np.float32(1.5) * held[2, :width].astype(np.float32),
+            np.float32(0)))
+        empty = sc.add_rows(bucket, [], [])
+        assert not np.asarray(empty[0]).any()
+        assert not np.asarray(empty[1]).any()
+
+    @pytest.mark.parametrize("which", sorted(ROW_QUERIES))
+    def test_a_warmed_request_makes_no_eager_fill(self, pair, which,
+                                                  monkeypatch):
+        """Once a bucket has served a request of each kind, a request
+        builds nothing and runs no eager `jnp.zeros` / `jnp.ones` /
+        `jnp.full` in ops/impact: its planes come zeroed from the row
+        launch (or the one fill program), `_finalize`'s `msm` from the
+        scorer's constant."""
+        import jax.numpy as jnp
+
+        rows, tiles = pair
+        for warm in sorted(ROW_QUERIES):
+            rows.search(row_body(ROW_QUERIES[warm], 31))
+        programs = (impact_ops._impact_zeros, impact_ops._impact_dense_add,
+                    impact_ops._impact_chunk_add)
+        built = [p._cache_size() for p in programs]
+        eager = []
+
+        class NoFill:
+            def __getattr__(self, name):
+                if name in ("zeros", "ones", "full"):
+                    eager.append(name)
+                    raise AssertionError(f"eager jnp.{name} in ops/impact")
+                return getattr(jnp, name)
+
+        monkeypatch.setattr(impact_ops, "jnp", NoFill())
+        body = row_body(ROW_QUERIES[which], 32)
+        before = dict(sparse_mod.SPARSE_STATS)
+        served = rows.search(dict(body))
+        assert eager == []
+        assert sparse_mod.SPARSE_STATS["fallbacks"] == before["fallbacks"]
+        assert sparse_mod.SPARSE_STATS["searches"] == before["searches"] + 1
+        assert [p._cache_size() for p in programs] == built
+        monkeypatch.undo()
+        want = tiles.search(dict(body))
+        assert served["hits"]["total"] == want["hits"]["total"]
+        assert [h["_id"] for h in served["hits"]["hits"]] == [
+            h["_id"] for h in want["hits"]["hits"]]
+
+    def test_sparse_plan_span_holds_the_hosts_share_of_dispatch(self, pair):
+        """`sparse_plan`: a child of `dispatch` beside `sparse_theta`,
+        from the segment's entry to just before the first chunk launch
+        is enqueued: it holds `sparse_theta`, ends before `dispatch`
+        does, and says what it planned."""
+        from elasticsearch_tpu.common import tracing
+
+        rows, _tiles = pair
+        tokens = ROW_QUERIES["mixed"]
+        # no total to hold: tiles may drop, so theta is computed
+        body = row_body(tokens, 41, track_total_hits=False)
+        rows.search(dict(body))
+        handle = tracing.begin("search", index="sp-rows")
+        rows.search(dict(body))
+        tracing.end(handle)
+        spans = tracing.recent(1)[0]["spans"]
+        by_name = {s["name"]: s for s in spans}
+        by_id = {s["id"]: s for s in spans}
+        plan, theta, disp = (by_name[n] for n in (
+            "sparse_plan", "sparse_theta", "dispatch"))
+        assert by_id[plan["parent_id"]]["name"] == "dispatch"
+        assert by_id[theta["parent_id"]]["name"] == "dispatch"
+
+        def ends(sp):
+            return sp["start_ns"] + sp["duration_ns"]
+
+        assert disp["start_ns"] <= plan["start_ns"] <= theta["start_ns"]
+        assert ends(theta) <= ends(plan) < ends(disp)
+        present = [t for t in tokens if t != "absent"]
+        hot = [t for t in present if t in HOT]
+        assert plan["tags"] == {
+            "segment": 0, "terms": len(present),
+            "cold_terms": len(present) - len(hot),
+            "tiles_kept": disp["tags"]["tiles_scored"]}
+        assert disp["tags"]["tiles_scored"] > 0
 
 
 class TestDenseRowBudget:

@@ -454,6 +454,48 @@ def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
             == spans["collect"]["tags"]["d2h_bytes"])
 
 
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("tiles", [0, 1, 512, 513, 1300])
+def test_staged_planes_are_noted_as_the_launches_upload_them(dep, rows,
+                                                             tiles):
+    """The three planes of ALL a scoring's chunk launches are staged at
+    once; what is noted stays what each launch uploads: three transfers
+    and 4,608 B a query row a launch (512 tiles x (4 + 4 + 1) B), and
+    the row launch's two planes, 512 B a query row."""
+    from elasticsearch_tpu.ops import impact as impact_ops
+
+    sf = dep.corpus["segment"].sparse[dep.field]
+    sc = impact_ops.ImpactScorer(sf.doc_ids, sf.qweights, DOCS)
+    tl = np.arange(tiles, dtype=np.int64) % sf.n_tiles
+    lists = [tl[: tiles // (j + 1)] for j in range(rows)]
+    weights = [np.full(len(t), 0.5, np.float32) for t in lists]
+    launches = -(-tiles // 512)
+    x0 = tracing.transfer_stats()
+    staged = sc.stage_chunks(rows, lists, weights)
+    assert tracing.transfer_stats() == x0  # staging uploads nothing
+    assert [p.shape for p in staged] == [(launches, rows, 512)] * 3
+    for j, t in enumerate(lists):  # each row's tiles, launch after launch
+        flat = staged[0][:, j].ravel()
+        assert np.array_equal(flat[: len(t)], t)
+        assert staged[2][:, j].ravel().sum() == len(t)
+    acc, cnt = sc.add_chunks(*sc.new_acc(rows), staged)
+    x1 = tracing.transfer_stats()
+    assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches
+    assert x1["h2d_bytes"] - x0["h2d_bytes"] == 4608 * rows * launches
+    assert x1["d2h_count"] == x0["d2h_count"]
+    assert acc.shape == cnt.shape == (rows, DOCS + 1)
+    held = dep.server.cluster.indices[dep.index["int8"]]
+    dep.search("int8", dep.hot_body)
+    rows_sc = held._executor(held.shards[0]).impact_scorer(
+        0, dep.field, True)
+    x0 = tracing.transfer_stats()
+    rows_sc.add_rows(rows, [np.asarray([0, 1], np.int32)],
+                     [np.asarray([1.0, 2.0], np.float32)])
+    x1 = tracing.transfer_stats()
+    assert x1["h2d_count"] - x0["h2d_count"] == 2
+    assert x1["h2d_bytes"] - x0["h2d_bytes"] == 512 * rows
+
+
 def test_the_request_goes_the_normal_path(dep):
     """A planned sparse job on the request thread's inline fan-out: the
     seven job spans under `shard_search`, `sparse_theta` under
@@ -512,6 +554,16 @@ def test_the_request_goes_the_normal_path(dep):
             and theta["start_ns"] + theta["duration_ns"]
             <= spans["dispatch"]["start_ns"]
             + spans["dispatch"]["duration_ns"])
+    plan = spans["sparse_plan"]
+    assert by_id[plan["parent_id"]]["name"] == "dispatch"
+    assert plan["start_ns"] <= theta["start_ns"]
+    assert (theta["start_ns"] + theta["duration_ns"]
+            <= plan["start_ns"] + plan["duration_ns"]
+            < spans["dispatch"]["start_ns"] + spans["dispatch"]["duration_ns"])
+    assert plan["tags"] == {
+        "segment": 0, "terms": n_terms,
+        "cold_terms": n_terms - tags["dense_rows"],
+        "tiles_kept": tags["tiles_scored"]}
     assert spans["collect"]["tags"]["merged"] is True
 
 
