@@ -1669,6 +1669,9 @@ class IndexService:
                             tr.add_span(
                                 "plan", ts, job.t_enq,
                                 family=kind, planned=True,
+                                **({"filtered": plan.filter is not None,
+                                    "negated": plan.excluded > 0}
+                                   if kind == "serve" else {}),
                             )
                         # the batcher future honors the shard's timeout
                         # budget: an expired wait abandons the job (the
@@ -2626,10 +2629,14 @@ class IndexService:
                         query, self.mappings, self.analysis
                     )
                     kind = "mesh_serve"
-                    if plan is not None and plan.counts_clauses:
+                    if plan is not None and (
+                            plan.counts_clauses or plan.filter is not None):
                         # the mesh kernels count terms, and a clause
                         # of several terms counts once however many of
-                        # them a document holds: the shard path takes it
+                        # them a document holds (a prohibited term rides
+                        # such a counter), and they take one live mask a
+                        # shard, not a filter's a row: the shard path
+                        # takes it
                         return None
                     # a `match_phrase` gets no plan here either way: the
                     # mesh step holds no positions, so the shards'
@@ -4004,8 +4011,9 @@ class IndexService:
             if plan is not None:
                 return ex, plan, "match", query
             plan = extract_serve_plan(query, self.mappings, self.analysis)
-            if plan is not None:
+            if plan is not None and plan.filter is None and not plan.excluded:
                 return ex, plan, "serve", query
+            # a filtered or negated bool is the shard path's to plan
             return None
         if kind == "knn":
             try:

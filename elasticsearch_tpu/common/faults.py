@@ -42,6 +42,10 @@ Schedule shape (env `ES_TPU_FAULTS`, or `POST /_internal/faults`):
     segment — ctx carries field/segment; error kind proves the
     deterministic fallback to the unbatched executor's filter
     evaluation (exact answers, `knn_filtered.fallbacks` bump))
+  - ``serve.filter``        (a filtered serve group's mask plan, per
+    segment — ctx carries field/segment; error kind proves the
+    deterministic fallback to the unbatched executor's `_exec_bool`
+    (exact answers, `serve_filtered.fallbacks` bump))
   - ``phrase.score``        (a phrase group's kernel launch, per
     segment — ctx carries field/segment; error kind proves the
     deterministic fallback to the unbatched executor's `_exec_phrase`

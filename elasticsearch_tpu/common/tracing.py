@@ -79,7 +79,9 @@ starts at `http`'s start and reaches the ring when `http` ends:
     collect | wake | fetch; what is left of it is the hand-over between
     them. A `leg:<label>` of an rrf retriever (or `mesh_search`) holds
     the four job spans alone.
-        plan [family, planned]   the shard's entry -> the job's submit
+        plan [family, planned; a serve plan also filtered, negated:
+            the bool brought a planned `filter` / `must_not`]   the
+            shard's entry -> the job's submit
             mark in `submit_nowait` (`t_enq`, where `queue_wait` starts):
             parse_query / the kNN section, extract_*_plan; planned
             false: no plan, the unbatched executor ran what follows
@@ -109,6 +111,13 @@ starts at `http`'s start and reaches the ring when `http` ends:
             the `tiles` postings tiles of the others; no host sync).
             `bitset_rows_held`: the rows the segment's field holds
             (built inside the field's first such span)
+            Under a filtered SERVE group (a `bool` with a planned
+            `filter`) the span is the host's part alone, launches 0:
+            the filter field's postings and bit rows fetched, the
+            filters' terms looked up, the plan packed; the plan is an
+            operand of the fused launch, whose program builds the
+            masks (that group's `dispatch` span also carries filtered,
+            filter_clauses, excluded_terms and filter_tiles)
           > phrase_plan [segment, launches, words]  a phrase group
             on one segment: the words looked up in the term dictionary,
             the plan packed and uploaded, `phrase_topk` enqueued (one

@@ -703,6 +703,13 @@ def _doc_planes(flat, rows: int, n: int):
 #     digit holds 2**CLAUSE_DIGIT_BITS - 1 terms and there are
 #     CLAUSE_DIGITS of them: the planner (search/batcher.py) turns away
 #     what passes either.
+#   * bool `filter` / `must_not` (PR 48): a launch whose jobs carry a
+#     keyword filter builds every row's own mask INSIDE the program from
+#     the filter field's postings tiles and bit rows (`filter_row_masks`,
+#     the knn family's mask) and ANDs it in; a launch whose jobs carry
+#     excluded terms reads the count plane's last digit as a veto
+#     (VETO_DIGIT). Both are static properties of the launch, as
+#     `counted` is: a launch of neither is the program it was.
 #
 # Either way: one packed int32 plan upload, the whole query phase on the
 # device, one packed download.
@@ -714,6 +721,18 @@ COUNT_TERM_BITS = 16  # the count plane's low bits: one-term clauses hit
 CLAUSE_DIGITS = 4  # counted clauses of several terms a plan may hold
 CLAUSE_DIGIT_BITS = 4
 CLAUSE_TERMS_MAX = (1 << CLAUSE_DIGIT_BITS) - 1  # terms of such a clause
+# A NEGATED launch (one whose jobs hold `must_not` terms) reads the last
+# digit as a veto: an excluded term is planned as a term of "clause"
+# VETO_DIGIT (batcher.FieldGroup count VETO_COUNT) with a positive
+# weight, so its hit adds that digit's unit in the scatter (a rare term)
+# or row pass (a hot one) every counted term makes, and the mask drops a
+# document whose digit is not zero. What the hit added to the score goes
+# with the document. No second plane: the count plane is read and
+# written by those passes as it was; a plan with excluded terms may hold
+# CLAUSE_DIGITS - 1 counted clauses of several terms and
+# CLAUSE_TERMS_MAX excluded terms (the planner's limits).
+VETO_DIGIT = CLAUSE_DIGITS - 1
+VETO_COUNT = 2 + VETO_DIGIT
 
 
 def clause_slot_ids(ids, count: int):
@@ -737,14 +756,23 @@ def clause_units(ids):
             jnp.where(used, jnp.left_shift(jnp.int32(1), shift), 0))
 
 
-def clauses_hit(cnt):
+def clauses_hit(cnt, negated: bool = False):
     """Counted clauses each document matched, of a count plane built
     from `clause_units`: its one-term clauses' hits plus its multi-term
-    clauses' nonzero digits."""
+    clauses' nonzero digits (`negated`: but the last, VETO_DIGIT,
+    which then counts the hits of excluded terms)."""
     digits = jax.lax.shift_right_logical(cnt, jnp.int32(COUNT_TERM_BITS))
     nz = digits | (digits >> 1)
-    nz = (nz | (nz >> 2)) & 0x1111  # a digit's lowest bit: any bit set
+    # a digit's lowest bit: any bit set
+    nz = (nz | (nz >> 2)) & (0x0111 if negated else 0x1111)
     return (cnt & ((1 << COUNT_TERM_BITS) - 1)) + jax.lax.population_count(nz)
+
+
+def vetoed(cnt):
+    """Documents that hold an excluded term, of a negated launch's
+    count plane: VETO_DIGIT, the plane's top bits, is not zero."""
+    return jax.lax.shift_right_logical(cnt, jnp.int32(
+        COUNT_TERM_BITS + CLAUSE_DIGIT_BITS * VETO_DIGIT)) != 0
 
 
 class MultiFusedScorer:
@@ -817,7 +845,8 @@ class MultiFusedScorer:
         return out
 
     def search_async(self, plans, k: int, combine: str, tie, live=None,
-                     staging=None, rows=None, counted: bool = True):
+                     staging=None, rows=None, counted: bool = True,
+                     fmask=None, negated: bool = False):
         """Launches the fused kernel WITHOUT waiting for the result:
         returns (device_out, k) for decode_result(). Device dispatch is
         async in jax, so a caller can launch several groups (e.g. the
@@ -828,8 +857,12 @@ class MultiFusedScorer:
         plan-upload buffer (a (family, shape, dtype) → np.ndarray
         callable); `rows` the launch's query-row bucket (default BPAD).
         `tie` is `max_tie`'s tie breaker, or None where nothing reads
-        it (one field): no scalar is uploaded then. `counted` as
-        `_fused_query_mf` takes it."""
+        it (one field): no scalar is uploaded then. `counted`, `fmask`
+        (the rows' filters: the filter field's doc-id tiles, the host
+        plan of `pack_filter_plans`, the field's bit-row plane or None)
+        and `negated` as `_fused_query_mf` takes them; with `fmask` the
+        packed row ends in one more int32, the documents the row's
+        filter passed."""
         k = min(k, self.n_docs)
         shape = self.plan_shape_rows(rows or BPAD)
         buf = staging("fused_plan", shape, np.int32) if staging else None
@@ -838,6 +871,12 @@ class MultiFusedScorer:
         if tie is not None:
             note_transfer("h2d", 4)
             tie = np.float32(tie)
+        # a launch of neither makes the call it always made
+        special = {}
+        if fmask is not None:
+            special["fmask"] = fmask
+        if negated:
+            special["negated"] = True
         out = _fused_query_mf(
             tuple(p["doc_ids"] for p in self.parts),
             tuple(p["tfs"] for p in self.parts),
@@ -852,6 +891,7 @@ class MultiFusedScorer:
             k=k,
             combine=combine,
             counted=counted,
+            **special,
         )
         return out, k
 
@@ -877,17 +917,27 @@ def decode_result(pending, extra: int = 0):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("t_rare", "n_hot", "k", "combine", "counted")
+    jax.jit,
+    static_argnames=("t_rare", "n_hot", "k", "combine", "counted", "negated"),
 )
 def _fused_query_mf(
     doc_ids_f, tfs_f, inv_norm_f, dense_f, live, plan, tie=None, wide_f=None,
-    *, t_rare, n_hot, k, combine, counted=True,
+    fmask=None, *, t_rare, n_hot, k, combine, counted=True, negated=False,
 ):
     """`counted` (the launch's) says whether any job holds documents to
     a count of clauses: with it a weight's sign says whether its term
     counts and the mask is `clauses_hit(cnt) >= msm`; without, there is
     no count plane, weights score as they are and a document matches
-    where its score is positive. `tie` is read by `max_tie` alone."""
+    where its score is positive. `tie` is read by `max_tie` alone.
+
+    `negated` (a counted launch's) says that some job holds excluded
+    terms: they ride the plan as terms of "clause" VETO_DIGIT, and
+    a document whose digit is not zero is dropped, whatever it scored.
+    `fmask` (the launch's too) is (doc-id tiles of the filter field,
+    the rows' filter plan, the field's bit rows or None): every row's
+    own mask is built here by `filter_row_masks` over the live
+    documents and ANDed in, and the documents it passed are appended to
+    the packed row. Without either the program is the one it was."""
     F = len(doc_ids_f)
     n = inv_norm_f[0].shape[0]
     T, H = t_rare, n_hot
@@ -942,10 +992,20 @@ def _fused_query_mf(
         best = stack.max(axis=0)
         combined = best + tie * (stack.sum(axis=0) - best)
     if counted:
-        mask = clauses_hit(cnt) >= jnp.maximum(msm, 1)[:, None]
+        mask = clauses_hit(cnt, negated) >= jnp.maximum(msm, 1)[:, None]
+        if negated:
+            mask = mask & ~vetoed(cnt)
     else:
         mask = combined > 0
-    if live is not None:
+    cols = []
+    if fmask is not None:
+        f_doc_ids, f_plan, f_bits = fmask
+        passes, passed = filter_row_masks(
+            f_doc_ids, jnp.ones(n, jnp.bool_) if live is None else live,
+            f_plan, f_bits)
+        mask = mask & passes
+        cols = [passed[:, None]]
+    elif live is not None:
         mask = mask & live[None, :]
     masked = jnp.where(mask, combined, -jnp.inf)
     top_s, top_d = jax.lax.top_k(masked, k)
@@ -955,6 +1015,7 @@ def _fused_query_mf(
             jax.lax.bitcast_convert_type(top_s, jnp.int32),
             top_d,
             totals[:, None],
+            *cols,
         ],
         axis=1,
     )
@@ -1329,7 +1390,7 @@ def pack_filter_plans(pf, filters, rows: int,
     """`knn_filter_mask`'s plan int32[rows, 3 * S + 1] for one launch
     over one segment: `pf` the filter field's PostingsField there,
     `filters` each job's clauses (tuples of terms;
-    batcher.KnnFilter.clauses), S the slot bucket of the widest,
+    batcher.KeywordFilter.clauses), S the slot bucket of the widest,
     `bit_rows` the rows the field holds there. A slot names a tile range
     to scatter or a bit row to read. A clause whose every term the
     segment holds has a row rides the rows (one term: its row; several:
@@ -1369,8 +1430,7 @@ def pack_filter_plans(pf, filters, rows: int,
     return FilterPlans(plan, tiles, terms, bit_terms)
 
 
-@jax.jit
-def knn_filter_mask(
+def filter_row_masks(
     doc_ids: jax.Array,  # int32[n_tiles, 128] the filter field's postings
     cand: jax.Array,  # bool[N] rows that hold a vector and are live
     plan: jax.Array,  # int32[B, 3 * S + 1]
@@ -1378,7 +1438,9 @@ def knn_filter_mask(
 ) -> Tuple[jax.Array, jax.Array]:
     """Each query row's candidate mask under its own filter, built on
     the device from the filter field's postings tiles and bit rows:
-    (bool[B, N], rows passed int32[B]).
+    (bool[B, N], rows passed int32[B]). Traced inside its caller: the
+    knn family's mask program `knn_filter_mask`, and the fused text
+    program of a launch whose jobs carry a filter (`_fused_query_mf`).
 
     A row of `plan` holds S term slots and the number of clauses a
     document must match (0 on a pad row, whose mask is empty). A slot
@@ -1471,6 +1533,13 @@ def knn_filter_mask(
         lambda: masks(scattered(scatters), in_rows),
         lambda: masks((scatters <= 0)[:, None], in_rows),
     )
+
+
+@jax.jit
+def knn_filter_mask(doc_ids, cand, plan, bits=None):
+    """`filter_row_masks` as a program of its own: the knn family's mask
+    launch in front of its scan, and the launch a bit row is built by."""
+    return filter_row_masks(doc_ids, cand, plan, bits)
 
 
 def build_filter_bit_rows(doc_ids, term_tile_start, term_tile_count, held,

@@ -668,6 +668,21 @@ class RestActions:
             "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
             "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
         }
+        # the serve family's filtered and negated groups
+        # (QueryBatcher.serve_filtered): (job x segment) scans of the
+        # fused program under a `filter` mask or a veto, the fused
+        # launches that built masks, the filters' terms and those read
+        # from a bit row, the postings tiles the others scattered,
+        # documents the masked launches scored and documents their
+        # filters passed, `must_not` terms carried and the tiles of
+        # those without a dense row, scans that fell back to the
+        # unbatched executor
+        serve_filtered = {
+            "searches": 0, "mask_launches": 0, "filter_terms": 0,
+            "bitset_terms": 0, "filter_tiles": 0, "rows_scanned": 0,
+            "rows_passed": 0, "excluded_terms": 0, "excluded_tiles": 0,
+            "fallbacks": 0,
+        }
         # the phrase family (QueryBatcher.phrase): scans on the device,
         # their launches and words, position entries handed to them,
         # documents holding every word and the words' occurrences inside
@@ -695,6 +710,8 @@ class RestActions:
                         knn_filtered[k] += v
                     for k, v in b.phrase.items():
                         phrase[k] += v
+                    for k, v in b.serve_filtered.items():
+                        serve_filtered[k] += v
                 queue_capacity = max(queue_capacity, b._queue.maxsize)
                 pipeline["depth"] = max(pipeline["depth"], b.pipeline_depth)
                 bs = b.batching_stats()
@@ -905,6 +922,7 @@ class RestActions:
                     "knn": knn_block,
                     "knn_filtered": knn_filtered,
                     "phrase": phrase,
+                    "serve_filtered": serve_filtered,
                     "rescore": rescore_block,
                     "sparse": sparse_block,
                     "translog": translog_block,

@@ -254,46 +254,17 @@ class FieldGroup:
 
 
 @dataclass(frozen=True)
-class ServePlan:
-    """A bool / multi_match query reduced to per-field weighted-term
-    groups for the multi-field fused kernel (round-5 extension of
-    MatchPlan; BASELINE configs 2 and 3). Every term of every group
-    scores; a document matches when at least `msm` of the plan's
-    COUNTED CLAUSES hold a term of theirs in it, as BooleanQuery counts
-    (`FieldGroup` says which terms are which clause)."""
-
-    groups: Tuple[FieldGroup, ...]
-    msm: int  # counted clauses a document must match
-    combine: str  # "sum" (bool, most_fields) | "max_tie" (best_fields)
-    tie: float
-    boost: float
-    # the query's counted clauses, and those of more than one term (a
-    # should-only bool at msm 1 and a multi_match count them term by
-    # term: any hit passes)
-    clauses: int = 1
-    multi_term_clauses: int = 0
-
-    @property
-    def fields(self) -> Tuple[str, ...]:
-        return tuple(g.field for g in self.groups)
-
-    @property
-    def counts_clauses(self) -> bool:
-        """Some clause of several terms counts once (the count plane's
-        digits): a plan the term-counting mesh twin cannot take."""
-        return any(t[2] > 1 for g in self.groups for t in g.terms)
-
-
-@dataclass(frozen=True)
-class KnnFilter:
-    """A knn section's `filter` as the knn family plans it: a
-    conjunction of clauses over ONE keyword field, each clause the
-    terms of which a document must hold at least one (a `term`: one; a
-    `terms`: any of its values). The device builds each job's candidate
-    mask from the field's postings tiles and the bit rows of its
-    commonest terms (`scoring.knn_filter_mask`);
-    `query`, the parsed filter, serves a segment whose mask build
-    failed, on the unbatched executor."""
+class KeywordFilter:
+    """A knn section's `filter`, or a bool's `filter` clauses, as the
+    knn and serve families plan them: a conjunction of clauses over ONE
+    keyword field, each clause the terms of which a document must hold
+    at least one (a `term`: one; a `terms`: any of its values). The
+    device builds each job's mask from the field's postings tiles and
+    the bit rows of its commonest terms (`scoring.filter_row_masks`: a
+    launch of its own in front of the knn scan, inside the fused text
+    program for a serve job); `query`, a knn section's parsed filter,
+    serves a segment whose mask build failed, on the unbatched executor
+    (a serve job's whole query does)."""
 
     field: str
     clauses: Tuple[Tuple[str, ...], ...]
@@ -305,9 +276,51 @@ class KnnFilter:
 
 
 @dataclass(frozen=True)
+class ServePlan:
+    """A bool / multi_match query reduced to per-field weighted-term
+    groups for the multi-field fused kernel (round-5 extension of
+    MatchPlan; BASELINE configs 2 and 3). Every term of every group
+    scores; a document matches when at least `msm` of the plan's
+    COUNTED CLAUSES hold a term of theirs in it, as BooleanQuery counts
+    (`FieldGroup` says which terms are which clause), and, where the
+    bool brings them, when it passes `filter` (keyword `term` / `terms`
+    clauses on one field: a mask of the job's own, built inside the
+    launch) and holds none of its `excluded` terms (`must_not`: text
+    terms, which ride the groups with count scoring.VETO_COUNT and
+    feed the count plane's last digit, the veto). Neither scores.
+    Whether a plan is filtered (and on which field) and whether it is
+    negated ride the group key: a launch of neither is the program it
+    was."""
+
+    groups: Tuple[FieldGroup, ...]
+    msm: int  # counted clauses a document must match
+    combine: str  # "sum" (bool, most_fields) | "max_tie" (best_fields)
+    tie: float
+    boost: float
+    # the query's counted clauses, and those of more than one term (a
+    # should-only bool at msm 1 and a multi_match count them term by
+    # term: any hit passes)
+    clauses: int = 1
+    multi_term_clauses: int = 0
+    filter: Optional[KeywordFilter] = None
+    excluded: int = 0  # `must_not` terms among the groups' terms
+
+    @property
+    def fields(self) -> Tuple[str, ...]:
+        return tuple(g.field for g in self.groups)
+
+    @property
+    def counts_clauses(self) -> bool:
+        """Some clause of several terms counts once, or some term is
+        excluded (the count plane's digits): a plan the term-counting
+        mesh twin cannot take."""
+        return any(t[2] > 1 for g in self.groups for t in g.terms)
+
+
+@dataclass(frozen=True)
 class KnnPlan:
     """A single top-level knn section with no similarity threshold,
-    bare or under a `filter` the planner took (`KnnFilter`): batched
+    bare or under a `filter` the planner took (`KeywordFilter`): batched
     brute-force matmul per segment (BASELINE config 4), or — when `ann`
     carries a resolved search/ann.AnnSpec — the IVF probed path over
     the same launch/merge plumbing. `ann` rides the group key, so exact
@@ -322,7 +335,7 @@ class KnnPlan:
     num_candidates: int
     boost: float
     ann: Optional[object] = None
-    filter: Optional[KnnFilter] = None
+    filter: Optional[KeywordFilter] = None
 
 
 @dataclass(frozen=True)
@@ -426,9 +439,11 @@ def _clause_terms(
 def extract_serve_plan(
     query, mappings, analysis
 ) -> Optional[ServePlan]:
-    """Reduces a bool (must/should of text clauses) or a multi_match
-    (best_fields/most_fields, operator=or) to a ServePlan for the
-    multi-field fused kernel. None → normal executor path.
+    """Reduces a bool (must/should of text clauses, under `filter`
+    clauses on a keyword field and `must_not` text clauses) or a
+    multi_match (best_fields/most_fields, operator=or) to a ServePlan
+    for the multi-field fused kernel. None → normal executor path (the
+    caller counts it in `unplanned_queries`).
 
     Count semantics (BooleanQuery's: clauses are counted, not terms):
       * a clause is a term, a match (operator or) of any number of
@@ -443,9 +458,24 @@ def extract_serve_plan(
       * a counted clause of several terms takes one of the count
         plane's scoring.CLAUSE_DIGITS digits and holds at most
         scoring.CLAUSE_TERMS_MAX terms: a query past either is turned
-        away, as are must_not, filter, `operator: and` inside a clause,
+        away, as are `operator: and` inside a clause,
         minimum_should_match beside must and anything that is no text
-        clause.
+        clause;
+      * `filter`: what `extract_knn_filter` takes of a knn section,
+        `term` / `terms` clauses on ONE keyword field, bare or inside a
+        bool of only those, every one required (a `range`, `exists` or
+        `prefix`, two fields, a text or numeric field, more terms than
+        scoring.filter_slot_bucket holds: turned away). It masks and
+        does not score. A bool with `filter` and no `must` has
+        minimum_should_match 0 by default (every passing document
+        matches): turned away, not answered at 1;
+      * `must_not`: text clauses as `_clause_terms` takes them; a
+        document holding ANY of their terms is dropped, none of them
+        scores. They take the count plane's last digit
+        (scoring.VETO_DIGIT), so beside them a plan holds one
+        counted clause of several terms fewer, and at most
+        scoring.CLAUSE_TERMS_MAX distinct excluded terms; `must_not`
+        of anything else, or with no positive clause, is turned away.
     """
     if isinstance(query, dsl.TermQuery):
         # a bare term on a text field is a one-term plan — without this
@@ -465,15 +495,20 @@ def extract_serve_plan(
             boost=query.boost,
         )
     if isinstance(query, dsl.BoolQuery):
-        if query.must_not or query.filter:
-            return None
         if query.must and query.minimum_should_match is not None:
             return None  # msm-on-should next to must: two thresholds
+        flt = None
+        if query.filter:
+            flt = _keyword_filter(_filter_leaves(query.filter), mappings)
+            if flt is None:
+                return None
         if query.must:
             counted, scored, msm = query.must, query.should, len(query.must)
         else:
             if not query.should:
                 return None
+            if flt is not None and query.minimum_should_match is None:
+                return None  # should beside a filter: msm 0, all pass
             msm_req = dsl.parse_minimum_should_match(
                 query.minimum_should_match, len(query.should)
             )
@@ -485,6 +520,7 @@ def extract_serve_plan(
         groups: Dict[str, List[Tuple[str, float, int]]] = {}
         multi = 0  # counted clauses of several terms
         digits = 0  # those that take a digit of the count plane
+        free = scoring.CLAUSE_DIGITS - bool(query.must_not)
         for c in counted:
             got = _clause_terms(c, mappings, analysis)
             if got is None:
@@ -493,7 +529,7 @@ def extract_serve_plan(
             if len(got) > 1:
                 multi += 1
                 if msm > 1:
-                    if (digits == scoring.CLAUSE_DIGITS
+                    if (digits == free
                             or len(got) > scoring.CLAUSE_TERMS_MAX):
                         return None
                     count = 2 + digits
@@ -506,6 +542,17 @@ def extract_serve_plan(
                 return None
             for field, t, cb in got:
                 groups.setdefault(field, []).append((t, cb, 0))
+        banned: Dict[Tuple[str, str], None] = {}  # distinct, in order
+        for c in query.must_not:
+            got = _clause_terms(c, mappings, analysis)
+            if got is None:
+                return None
+            banned.update(((field, t), None) for field, t, _cb in got)
+        if len(banned) > scoring.CLAUSE_TERMS_MAX:
+            return None
+        for field, t in banned:
+            groups.setdefault(field, []).append(
+                (t, 1.0, scoring.VETO_COUNT))
         return ServePlan(
             groups=tuple(
                 FieldGroup(field=f, terms=tuple(ts))
@@ -517,6 +564,8 @@ def extract_serve_plan(
             boost=query.boost,
             clauses=len(counted),
             multi_term_clauses=multi,
+            filter=flt,
+            excluded=len(banned),
         )
     if isinstance(query, dsl.MultiMatchQuery):
         if query.type not in ("best_fields", "most_fields"):
@@ -588,22 +637,39 @@ def split_filtered_bool(query):
     return stripped, list(query.filter)
 
 
-def extract_knn_filter(query, mappings) -> Optional[KnnFilter]:
+def extract_knn_filter(query, mappings) -> Optional[KeywordFilter]:
     """A knn `filter` the device can build a mask for from postings
     tiles: a `term` or `terms` on a keyword field, or a `bool` whose
     `filter` / `must` hold only those, all on one field (a conjunction
     of counted clauses). None for anything else (`range`, `must_not`,
     `should`, nested bools, another field type) and for a filter past
-    the mask program's counters: more than scoring.CLAUSE_DIGITS
-    clauses of several terms, one of more than scoring.CLAUSE_TERMS_MAX
-    terms, more terms than its widest plan."""
-    if isinstance(query, dsl.BoolQuery):
-        if (query.should or query.must_not
-                or query.minimum_should_match is not None):
-            return None
-        leaves = list(query.filter) + list(query.must)
-    else:
-        leaves = [query]
+    the mask program's counters (`_keyword_filter`)."""
+    return _keyword_filter(_filter_leaves([query]), mappings, query)
+
+
+def _filter_leaves(clauses) -> list:
+    """The required leaves of filter clauses: a clause as it is, or,
+    of a `bool` that holds only `filter` / `must` clauses, those. A
+    bool that brings anything else (`should`, `must_not`, a
+    minimum_should_match) is a leaf no filter plan takes."""
+    leaves = []
+    for q in clauses:
+        if isinstance(q, dsl.BoolQuery):
+            if not (q.should or q.must_not
+                    or q.minimum_should_match is not None):
+                leaves += list(q.filter) + list(q.must)
+                continue
+        leaves.append(q)
+    return leaves
+
+
+def _keyword_filter(leaves, mappings, query=None) -> Optional[KeywordFilter]:
+    """The conjunction of `leaves` as a KeywordFilter: every leaf a
+    `term` or `terms` on the same keyword field. None for any other
+    leaf or field and for a filter past the mask program's counters:
+    more than scoring.CLAUSE_DIGITS clauses of several terms, one of
+    more than scoring.CLAUSE_TERMS_MAX terms, more terms than its
+    widest plan."""
     clauses: List[Tuple[str, ...]] = []
     fields = set()
     for q in leaves:
@@ -628,7 +694,7 @@ def extract_knn_filter(query, mappings) -> Optional[KnnFilter]:
             or any(len(c) > scoring.CLAUSE_TERMS_MAX for c in multi)
             or scoring.filter_slot_bucket(sum(map(len, clauses))) is None):
         return None
-    return KnnFilter(field=fname, clauses=tuple(clauses), query=query)
+    return KeywordFilter(field=fname, clauses=tuple(clauses), query=query)
 
 
 def extract_knn_plan(knn_sections, mappings) -> Optional[KnnPlan]:
@@ -938,8 +1004,24 @@ def _mesh_family(overlap: str, share: Callable, dispatch: str,
     return _Family(overlap, share, launch, download, mesh=True)
 
 
-def _serve_share(p) -> Tuple:
+def _mesh_serve_share(p) -> Tuple:
     return p.fields, p.combine, p.tie
+
+
+def _serve_share(p) -> Tuple:
+    # a launch's filters read one field's postings, and both whether it
+    # masks a row and whether it vetoes are static in its program
+    return (*_mesh_serve_share(p),
+            p.filter.field if p.filter else None, p.excluded > 0)
+
+
+def _warm_serve(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
+    # a filtered group's program specializes on its mask plan's width
+    def terms(j: _Job) -> int:
+        return j.plan.filter.n_terms if j.plan.filter else 0
+
+    j0 = max(jobs, key=terms)
+    return (scoring.filter_slot_bucket(terms(j0)) if terms(j0) else 0,), j0
 
 
 def _warm_first(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
@@ -978,7 +1060,7 @@ FAMILIES: Dict[str, _Family] = {
             jobs, kb, rows=rows, record=record),
         lambda b, jobs, key, kb, pend, record: b._collect_serve_group(
             jobs, kb, pend, record=record),
-        warm=_warm_first,
+        warm=_warm_serve,
     ),
     # exact phrases: the span in slots rides the key (one program a
     # span); the words do not
@@ -1034,7 +1116,7 @@ FAMILIES: Dict[str, _Family] = {
         "text", lambda p: (p.field, getattr(p, "rescore_sig", None)),
         "dispatch_match", "collect_match"),
     "mesh_serve": _mesh_family(
-        "text", _serve_share, "dispatch_serve", "collect_match"),
+        "text", _mesh_serve_share, "dispatch_serve", "collect_match"),
     "mesh_knn": _mesh_family(
         "knn", lambda p: (p.field, p.ann), "dispatch_knn", "collect_knn"),
     "mesh_sparse": _mesh_family(
@@ -1188,6 +1270,25 @@ class QueryBatcher:
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
             "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
             "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
+        }
+        # the serve family's filtered and negated groups (`_nodes/stats`
+        # `serve_filtered`; under self._lock): (job x segment) scans of
+        # the fused program under a `filter` mask of the row's own or a
+        # veto, the fused launches that built masks inside themselves,
+        # the planned filters' terms, those of them a bit row of the
+        # segment answered and the postings tiles the others scattered,
+        # the documents the masked launches scored and those their
+        # filters passed (counted on the device, read at collect), the
+        # `must_not` terms the launches carried and the postings tiles
+        # of those that hold no dense row (scattered into the veto
+        # counter), and the (job x segment) scans that left the planned
+        # path for the unbatched executor (the filter field's postings
+        # or bit rows not to be had, a slot overflow, a small segment)
+        self.serve_filtered = {
+            "searches": 0, "mask_launches": 0, "filter_terms": 0,
+            "bitset_terms": 0, "filter_tiles": 0, "rows_scanned": 0,
+            "rows_passed": 0, "excluded_terms": 0, "excluded_tiles": 0,
+            "fallbacks": 0,
         }
         # the phrase family (`_nodes/stats` `phrase`; under self._lock):
         # (job x segment) scans on the device, their launches, the words
@@ -2004,15 +2105,44 @@ class QueryBatcher:
         sync. Segments without a fused scorer (below FUSED_MIN_DOCS) or
         jobs overflowing slot budgets are marked for the per-job
         fallback, which runs at collect time. `rows` pads the launch to
-        one ladder bucket; `record=False` (warmup) mutes stats."""
+        one ladder bucket; `record=False` (warmup) mutes stats.
+
+        A FILTERED group (every job under a `KeywordFilter` on one
+        field: the group key's) hands the launch the filter field's
+        doc-id tiles, the rows' filter plan and the field's bit rows,
+        and the program builds every row's own mask itself
+        (`scoring.filter_row_masks`): no launch in front of the fused
+        one, one more host operand (the plan, 100 B a row); what the
+        host does for it is the `filter_mask` span (`launches: 0`). It
+        neither reads nor feeds the node's filter-bitset cache. A
+        segment whose filter field cannot be had on the device (the
+        `serve.filter` fault site, an upload the HBM breaker refuses)
+        runs per job on the unbatched executor at collect; one without
+        the field passes nothing and is skipped. A NEGATED group (the
+        key's too) launches the program that reads the count plane's
+        last digit as a veto; its plans carry the excluded terms.
+        `serve_filtered` counts both kinds' scans, and in `fallbacks`
+        those of them that left the planned path."""
         ex = jobs[0].executor
         nj = len(jobs)
         rows = rows or BPAD
         staging = getattr(ex, "staging_slab", None)
         plan0 = jobs[0].plan
         fields = plan0.fields
+        flt0, negated = plan0.filter, plan0.excluded > 0  # the key's
+        special = flt0 is not None or negated
+        if record and special:
+            # a group of neither writes no tag more
+            tags = _group_now().plan_tags
+            tags["filtered"] = flt0 is not None
+            tags["filter_clauses"] = max(
+                len(j.plan.filter.clauses) if j.plan.filter else 0
+                for j in jobs)
+            tags["excluded_terms"] = max(j.plan.excluded for j in jobs)
         items: List[Tuple] = []
-        for si in range(len(ex.reader.segments)):
+        for si, seg in enumerate(ex.reader.segments):
+            if flt0 is not None and seg.postings.get(flt0.field) is None:
+                continue  # no document holds the field: none passes
             fs = ex.fused_scorer_mf(si, fields)
             fplans = None
             if fs is not None:
@@ -2035,11 +2165,20 @@ class QueryBatcher:
                     fplans.append(
                         (sections, j.plan.msm) if sections is not None else None
                     )
-            if fs is not None and all(p is not None for p in fplans):
+            fused = fs is not None and all(p is not None for p in fplans)
+            if record and fs is not None and not fused:
+                self._count_overflow(fplans)
+            fmask = fp = None
+            if fused and flt0 is not None:
+                fmask, fp = self._serve_filter_plan(jobs, si, rows, record)
+                fused = fmask is not None
+            if fused:
                 pend = fs.search_async(
                     fplans, kb, plan0.combine, plan0.tie, staging=staging,
-                    rows=rows,
+                    rows=rows, fmask=fmask, negated=negated,
                 )
+                if record and special:
+                    self._count_serve_filtered(jobs, si, fp, fplans)
                 if record:
                     secs = [sec for sections, _ in fplans for sec in sections]
                     rare = [len(sec[0]) for sec in secs]
@@ -2072,10 +2211,73 @@ class QueryBatcher:
                     ))
                 items.append(("fused", si, pend[0]))
             else:
-                if record and fs is not None and fplans is not None:
-                    self._count_overflow(fplans)
+                if record and special:
+                    with self._lock:
+                        self.serve_filtered["fallbacks"] += nj
                 items.append(("fallback", si, None))
         return items
+
+    def _serve_filter_plan(self, jobs: List[_Job], si: int, rows: int,
+                           record: bool):
+        """One segment of a filtered serve group: ((doc-id tiles, plan,
+        bit rows) for the fused launch, the packed FilterPlans); (None,
+        None) where the field's postings or bit rows are not to be had
+        on the device and the segment is left to the unbatched executor
+        at collect. The `filter_mask` span (the knn family's name,
+        here with `launches: 0`): the field's postings and bit rows
+        fetched (built at the field's first filtered search), the
+        filters' terms looked up, the plan packed; its upload and the
+        mask itself ride the fused launch."""
+        ex = jobs[0].executor
+        t0 = time.perf_counter_ns()
+        fname = jobs[0].plan.filter.field
+        try:
+            if record:
+                faults.check("serve.filter", field=fname, segment=si)
+            dp = ex.device_segments[si].postings[fname]
+            bits = dp.filter_bits
+        except Exception:
+            return None, None
+        fp = scoring.pack_filter_plans(
+            ex.reader.segments[si].postings[fname],
+            [j.plan.filter.clauses for j in jobs], rows, bits)
+        note_transfer("h2d", fp.plan.nbytes)
+        if record:
+            g = _group_now()
+            g.plan_tags["filter_tiles"] = (
+                g.plan_tags.get("filter_tiles", 0) + fp.tiles)
+            g.sub_spans.append((
+                "filter_mask", t0, time.perf_counter_ns(),
+                {"segment": si, "launches": 0, "tiles": fp.tiles,
+                 "bitset_terms": fp.bit_terms,
+                 "bitset_rows_held": len(bits.row_of_term)},
+            ))
+        return (dp.doc_ids, fp.plan, bits.plane), fp
+
+    def _count_serve_filtered(self, jobs: List[_Job], si: int, fp,
+                              fplans) -> None:
+        """A filtered or negated fused launch over one segment, in
+        `serve_filtered`: `fp` its packed filters (None for a launch
+        that only excludes), `fplans` its jobs' (sections, msm). An
+        excluded term on tiles has rare slots that carry the veto's
+        counter above their ids (`scoring.clause_slot_ids`); one on a
+        dense row takes a hot slot and no tile."""
+        veto = scoring.VETO_COUNT - 1
+        tiles = sum(
+            int(np.count_nonzero(sec[0] >> scoring.SLOT_ID_BITS == veto))
+            for sections, _msm in fplans for sec in sections)
+        with self._lock:
+            sf = self.serve_filtered
+            sf["searches"] += len(jobs)
+            sf["excluded_terms"] += sum(j.plan.excluded for j in jobs)
+            sf["excluded_tiles"] += tiles
+            if fp is not None:
+                sf["mask_launches"] += 1
+                sf["filter_terms"] += fp.terms
+                sf["bitset_terms"] += fp.bit_terms
+                sf["filter_tiles"] += fp.tiles
+                sf["rows_scanned"] += len(jobs) * (
+                    jobs[0].executor.reader.segments[si].num_docs)
 
     def _collect_serve_group(self, jobs: List[_Job], kb: int, items,
                              record: bool = True):
@@ -2093,7 +2295,15 @@ class QueryBatcher:
             (si, packed) for tag, si, packed in items if tag == "fused"
         ]
         if fused_items:
-            ms, mseg, mdoc, mtot = self._group_topk(fused_items, kb, record)
+            # a filtered group's packed rows end in the documents each
+            # row's filter passed
+            filtered = jobs[0].plan.filter is not None
+            ms, mseg, mdoc, mtot, *passed = self._group_topk(
+                fused_items, kb, record, extra=int(filtered))
+            if filtered and record:
+                with self._lock:
+                    self.serve_filtered["rows_passed"] += int(
+                        passed[0][:len(jobs)].sum())
             for ji in range(len(jobs)):
                 finite = np.isfinite(ms[ji])
                 for s, si, d in zip(
@@ -2370,7 +2580,7 @@ class QueryBatcher:
         (BASELINE config 4); results stay on device until collect.
         `rows` pads the query-row dimension to one ladder bucket.
 
-        A FILTERED group (every job carries a `KnnFilter`; the group
+        A FILTERED group (every job carries a `KeywordFilter`; the group
         key keeps them apart from bare jobs) gives each row a candidate
         mask of its own, built on the device by one launch of
         `scoring.knn_filter_mask` a segment (the `filter_mask` span, a

@@ -35,6 +35,12 @@ KEYED = {
         "fields": (("title", "body"), ("body",)),
         "combine": ("sum", "max_tie"),
         "tie": (0.0, 0.3),
+        # a filtered launch builds its rows' masks from ONE keyword
+        # field's postings, and a negated one reads the count plane's
+        # last digit as a veto: the field and WHETHER a job excludes
+        # are keyed on, never the filter's values or the excluded terms
+        "filter": (None, SimpleNamespace(field="tag")),
+        "excluded": (0, 2),
     },
     # `filter`: bare and filtered jobs are two programs (WHETHER a job
     # is filtered is keyed on, never which filter it carries)

@@ -161,7 +161,8 @@ def test_every_request_of_the_mix_is_one_serve_job(deployment):
     # request thread only asks; the pool's run of it is the one counted)
     post(server.port, path, {"query": {"bool": {
         "must": [{"term": {"body": "w00051"}}],
-        "must_not": [{"term": {"body": "w00052"}}]}}, "size": 10})
+        "must_not": [{"match_phrase": {"body": "w00052 w00053"}}]}},
+        "size": 10})
     last = node_numbers(server)
     assert last["unplanned_queries"] == after["unplanned_queries"] + 1
     assert (last["fan_out.inline"], last["fan_out.pooled"]) == (

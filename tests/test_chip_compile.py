@@ -398,6 +398,52 @@ def test_fused_bool_program_at_passage_shapes(one_chip):
 
 
 @pytest.mark.parametrize("rows", [1, 8])
+@pytest.mark.parametrize("filtered, negated", [
+    (True, False), (False, True), (True, True)],
+    ids=["filtered", "negated", "both"])
+def test_fused_bool_program_under_masks_and_a_veto(
+        one_chip, rows, filtered, negated):
+    """The filtered and negated Boolean deployment's launches (the
+    benchmark's `msmarco-filtered-bool.solo`: 1,000,000 passages,
+    1,160,811 text tiles, 500 dense rows; the tag field's 28,819
+    doc-id tiles, 39 bit rows of 31,744 words, a mask plan of 8 slots):
+    the rows' masks are built INSIDE the one fused program (its loop
+    over the plan's tile ranges and the bit rows' unpack are there, no
+    second module), the veto is one more shift of the count plane it
+    already holds, and an unfiltered launch carries neither the tag
+    field's operands nor the mask's second count plane."""
+    s = _on(one_chip)
+    words = scoring.filter_bit_words(N_DOCS)
+    fmask = (s((28_819, TILE), jnp.int32), s((rows, 3 * 8 + 1), jnp.int32),
+             s((39, words), jnp.uint32)) if filtered else None
+    text = {}
+    for key, (fm, neg) in {"bare": (None, False),
+                           "this": (fmask, negated)}.items():
+        compiled = scoring._fused_query_mf.lower(
+            (s((1_160_811, TILE), jnp.int32),),
+            (s((1_160_811, TILE), jnp.int32),),
+            (s((N_DOCS,), jnp.float32),),
+            (s((500, N_DOCS), jnp.uint8),),
+            None,
+            s((rows, _plan_width(1)), jnp.int32),
+            s((), jnp.float32),
+            None,
+            fm,
+            t_rare=scoring.FUSED_T_RARE, n_hot=scoring.FUSED_H, k=16,
+            combine="sum", negated=neg,
+        ).compile()
+        _fits(compiled)
+        text[key] = compiled.as_text()
+    row = f"u32[39,{words}]"
+    assert row not in text["bare"] and "s32[28819,128]" not in text["bare"]
+    assert (row in text["this"]) == filtered
+    assert ("s32[28819,128]" in text["this"]) == filtered
+    # a filtered launch's output row ends in the documents its filter passed
+    assert (f"s32[{rows},34]" in text["this"]) == filtered
+    assert f"s32[{rows},33]" in text["bare"]
+
+
+@pytest.mark.parametrize("rows", [1, 8])
 def test_uncounted_match_program_holds_no_count_plane(one_chip, rows):
     """At the passage cell's shapes (`msmarco-passage-bm25`: 1,000,000
     docs, 1,160,811 tiles, 500 dense rows) the program a `match` launch
@@ -436,7 +482,7 @@ def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
     params = inspect.signature(
         scoring._fused_query_mf.__wrapped__).parameters.values()
     assert {p.name for p in params if p.kind == p.KEYWORD_ONLY} == {
-        "t_rare", "n_hot", "k", "combine", "counted"}
+        "t_rare", "n_hot", "k", "combine", "counted", "negated"}
     text = lowered.compile().as_text()
     budget = rows * scoring.FUSED_T_RARE * TILE
     chunk = rows * scoring.RARE_CHUNK * TILE

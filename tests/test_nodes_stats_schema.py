@@ -56,6 +56,13 @@ REQUIRED = {
     "transfer.scoring": {
         "h2d_count", "h2d_bytes", "d2h_count", "d2h_bytes",
     },
+    # the benchmark's `bool_filter_*` / `bool_excluded_tiles_per_req` /
+    # `bool_filtered_fallback_share` read these by dotted path
+    "serve_filtered": {
+        "searches", "mask_launches", "filter_terms", "bitset_terms",
+        "filter_tiles", "rows_scanned", "rows_passed", "excluded_terms",
+        "excluded_tiles", "fallbacks",
+    },
 }
 
 

@@ -149,8 +149,13 @@ class TestExtraction:
 
     def test_rejections(self, service):
         cases = [
-            {"bool": {"must_not": [{"match": {"body": "x"}}],
+            # a prohibition with nothing positive beside it
+            {"bool": {"must_not": [{"match": {"body": "x"}}]}},
+            # a prohibited clause that is no text clause
+            {"bool": {"must_not": [{"match_phrase": {"body": "x z"}}],
                       "should": [{"match": {"body": "y"}}]}},
+            # a filter on a text field (PR 47 plans keyword filters and
+            # text prohibitions: tests/test_filtered_bool_deployment.py)
             {"bool": {"filter": [{"term": {"body": "x"}}],
                       "must": [{"match": {"body": "y"}}]}},
             # every word required INSIDE a clause: not a count of clauses
