@@ -111,6 +111,15 @@ starts at `http`'s start and reaches the ring when `http` ends:
             the `tiles` postings tiles of the others; no host sync).
             `bitset_rows_held`: the rows the segment's field holds
             (built inside the field's first such span)
+          > knn_lead [segment, launches, tiles, lead_rows,
+            bitset_terms, bitset_rows_held]  in `filter_mask`'s place
+            where every job of the group leads by postings on the
+            segment (the group's `dispatch` span then carries lead):
+            the terms looked up, the lead plan packed and uploaded,
+            `knn_topk_lead` enqueued (the group's ONE launch there:
+            `tiles` the leads' and the verified ranges' postings tiles
+            it gathers, `lead_rows` the candidate slots it scores; no
+            mask, no scan, no host sync)
             Under a filtered SERVE group (a `bool` with a planned
             `filter`) the span is the host's part alone, launches 0:
             the filter field's postings and bit rows fetched, the

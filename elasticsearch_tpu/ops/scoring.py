@@ -1263,21 +1263,36 @@ def knn_scores(
         q2 = jnp.sum(queries * queries, axis=1, keepdims=True)
         if norms is None:
             norms = jnp.sum(vectors * vectors, axis=1)
-        v2 = norms[None, :]
-        d2 = jnp.maximum(q2 + v2 - 2.0 * dots, 0.0)
-        scores = 1.0 / (1.0 + d2)
+        scores = _knn_l2_scores(q2, norms[None, :], dots)
     else:
-        if similarity == "cosine":
-            qn = jnp.linalg.norm(queries, axis=1, keepdims=True)
-            queries = queries / jnp.where(qn == 0, 1.0, qn)
-        dots = queries @ vectors.T
-        if similarity in ("cosine", "dot_product"):
-            scores = (1.0 + dots) / 2.0
-        elif similarity == "max_inner_product":
-            scores = jnp.where(dots < 0, 1.0 / (1.0 - dots), dots + 1.0)
-        else:
-            raise ValueError(f"unknown similarity [{similarity}]")
+        dots = _knn_unit_queries(queries, similarity) @ vectors.T
+        scores = _knn_dot_scores(dots, similarity)
     return scores.astype(jnp.float32)
+
+
+# The Lucene VectorSimilarityFunction transforms, shared by the program
+# that scores every stored row (`knn_scores`) and the one that scores a
+# lead clause's rows (`knn_topk_lead`): one arithmetic, two shapes.
+
+
+def _knn_l2_scores(q2, v2, dots):
+    d2 = jnp.maximum(q2 + v2 - 2.0 * dots, 0.0)
+    return 1.0 / (1.0 + d2)
+
+
+def _knn_unit_queries(queries, similarity: str):
+    if similarity == "cosine":
+        qn = jnp.linalg.norm(queries, axis=1, keepdims=True)
+        queries = queries / jnp.where(qn == 0, 1.0, qn)
+    return queries
+
+
+def _knn_dot_scores(dots, similarity: str):
+    if similarity in ("cosine", "dot_product"):
+        return (1.0 + dots) / 2.0
+    if similarity == "max_inner_product":
+        return jnp.where(dots < 0, 1.0 / (1.0 - dots), dots + 1.0)
+    raise ValueError(f"unknown similarity [{similarity}]")
 
 
 @functools.partial(jax.jit, static_argnames=("similarity", "k"))
@@ -1375,14 +1390,28 @@ class FilterBitRows(NamedTuple):
 
 
 class FilterPlans(NamedTuple):
-    """`pack_filter_plans`' launch: the plan, the postings tiles its
-    tile-range slots name (what the launch scatters), the terms of its
-    filters and those of them a bit row answers."""
+    """`pack_filter_plans`' (or `pack_lead_plans`') launch: the plan,
+    the postings tiles its tile-range slots name (what the launch
+    scatters, or gathers), the terms of its filters and those of them a
+    bit row answers."""
 
     plan: np.ndarray
     tiles: int
     terms: int
     bit_terms: int
+    lead_rows: int = 0  # candidate slots, where the launch leads
+
+
+def _put_bit_row_slots(plan: np.ndarray, ji: int, slot: int, rows) -> int:
+    """One clause answered from bit rows, written into row `ji` of a
+    filter plan from `slot` on: the first row opens the clause, the
+    others join it. -> the next free slot."""
+    S = (plan.shape[1] - 1) // 3
+    for i, row in enumerate(rows):
+        plan[ji, slot] = row
+        plan[ji, 2 * S + slot] = FILTER_BIT_JOINS if i else FILTER_BIT_OPENS
+        slot += 1
+    return slot
 
 
 def pack_filter_plans(pf, filters, rows: int,
@@ -1409,11 +1438,8 @@ def pack_filter_plans(pf, filters, rows: int,
             terms += len(clause)
             tids = [tid for tid in map(pf.term_id, clause) if tid >= 0]
             if tids and all(tid in row_of for tid in tids):
-                for i, tid in enumerate(tids):
-                    plan[ji, slot] = row_of[tid]
-                    plan[ji, 2 * S + slot] = (
-                        FILTER_BIT_JOINS if i else FILTER_BIT_OPENS)
-                    slot += 1
+                slot = _put_bit_row_slots(
+                    plan, ji, slot, [row_of[tid] for tid in tids])
                 bit_terms += len(tids)
                 continue
             unit = 1
@@ -1641,7 +1667,9 @@ def knn_topk_filtered(
 ) -> Tuple[jax.Array, jax.Array]:
     """`knn_topk_batch` where every query row brings a candidate mask of
     its own (`knn_filter_mask`): every row of `vectors` is scored, the
-    rows a filter passes compete. (scores[B, k], docs[B, k]). The
+    rows a filter passes compete. (scores[B, k], docs[B, k]). The scan
+    path of a filtered launch: one whose rows all lead by short
+    postings never gets here (`knn_topk_lead`). The
     program does only what depends on the query, in one pass over the
     rows: their norms come as an operand where the field holds integers,
     and a plane wide enough (`knn_block_select`) is never sorted: its top
@@ -1651,6 +1679,288 @@ def knn_topk_filtered(
     if knn_block_select(masked.shape[1], k):
         return _block_topk(masked, k)
     return jax.lax.top_k(masked, k)
+
+
+# The lead route of a filtered kNN launch (`pack_lead_plans`,
+# `knn_topk_lead`). Measured on the TPU v5e at the filtered cell's
+# shapes (10M x 192 int8 rows held along the lanes, one query row,
+# top 112; scripts/probe_knn_lead.py, the profiler's device ms a launch;
+# PERF.md section 6, PR 50), by the lead's candidate slots 128 / 1,024 /
+# 2,048 / 8,192 / 32,768: the lead alone 0.24 / 0.30 / 0.37 / 0.81 /
+# 3.22; with a bit-row clause checked 0.31 / 0.37 / 0.44 / 0.88 / 3.49;
+# a second range of 64 tiles checked costs what a bit row costs (+0.05 at
+# 8,192; the lead is the shorter of two ranges); 78,080 slots read 8.2;
+# beside them one mask launch and one scan, 4.04 (the mask 0.89). So
+# ~0.22 ms a launch and ~0.09 us a slot (the block fetch 0.072 of it:
+# ops/pallas_lead.py), against 0.40 ns a scanned row: a slot costs what
+# ~230 scanned rows cost, and a lead pays while each of its slots stands
+# for KNN_LEAD_SCAN_ROWS rows of the segment or more (39,062 slots, 305
+# tiles at 10M rows: 3.8 ms against the scan's 4.04 and its second
+# launch's ~0.3 ms of host time; the cell's median lead is 8 tiles). The
+# program as first written, a row gather `vectors[docs]`, read 10.1 ms
+# whatever the lead: the compiler relays the whole matrix out (2.56 GB),
+# so the rows are fetched block by block. A verified range is compared
+# whole with every candidate (64 tiles: 8,192 x 8,192 compares a trip).
+KNN_LEAD_SCAN_ROWS = 256
+KNN_LEAD_VERIFY_TILES_MAX = 64
+# Lead tiles a trip of `knn_topk_lead` takes over all its query rows. A
+# trip gathers over all its slots whatever the lead holds: 16 tiles a
+# trip read 0.09 / 0.15 / 0.22 / 0.88 / 3.49 on the line above (short
+# leads 0.15 ms sooner, long ones 0.07-0.27 later) and the cell the same
+# to 1% (p50 4.28 against 4.34 ms, 192.9 against 192.3 req/s).
+KNN_LEAD_CHUNK = 64
+
+
+def knn_lead_tiles_max(n_docs: int) -> int:
+    """The longest lead, in postings tiles, over a segment of `n_docs`
+    stored rows: each of its slots must stand for KNN_LEAD_SCAN_ROWS
+    rows the scan would read (305 tiles at 10M rows; no lead but an
+    empty one under 32,768 rows, where a scan costs next to nothing)."""
+    return n_docs // (KNN_LEAD_SCAN_ROWS * TILE_WIDTH)
+
+
+def rows_on_lanes(vectors) -> bool:
+    """Whether the device holds `vectors[N, d]` with its ROWS along the
+    lanes (dimension 0 minor), as the TPU lays out a `byte` field's
+    10M x 192 rows: the layout `knn_topk_lead` fetches blocks from
+    (ops/pallas_lead.py). Host arrays and row-major device arrays (every
+    CPU array) say no, and are gathered by row."""
+    layout = getattr(getattr(vectors, "format", None), "layout", None)
+    return (layout is not None
+            and tuple(layout.major_to_minor) == (1, 0)
+            and vectors.shape[0] >= TILE_WIDTH)
+
+
+def pack_lead_plans(pf, filters, rows: int, n_docs: int,
+                    bit_rows: FilterBitRows = FilterBitRows(),
+                    ) -> Optional[FilterPlans]:
+    """`knn_topk_lead`'s plan for one launch over one segment of
+    `n_docs` rows, or None where a job of the launch does not LEAD BY
+    POSTINGS (the launch then builds masks and scans:
+    `pack_filter_plans`). Other arguments and the layout as
+    `pack_filter_plans`', int32[rows, 3 * S + 1]; slot 0 is the lead.
+
+    The rows an AND of clauses can pass are among the postings of any
+    one of them, so a job whose rarest clause is short is answered from
+    that clause's rows alone (Lucene's `AbstractKnnVectorQuery
+    .exactSearch` over a conjunction led by its cheapest iterator). A
+    job's lead is the clause of fewest tiles among its clauses of ONE
+    term that holds no bit row. The job leads when the lead has at most
+    `knn_lead_tiles_max(n_docs)` tiles and every other clause can be
+    checked a candidate at a time: a bit-row clause
+    (`pack_filter_plans`' test: slots of unit FILTER_BIT_OPENS /
+    FILTER_BIT_JOINS) or one more term of at most
+    KNN_LEAD_VERIFY_TILES_MAX tiles (a tile-range slot, unit 1). A
+    clause of several terms that hold no rows could repeat a document:
+    such a job does not lead. A clause of one term the segment does not
+    hold passes nothing whatever the others say: the job leads by an
+    empty range. All read from the launch's own inputs (the field's
+    tile counts on this segment, its rows); nothing is set.
+
+    `tiles` counts the leads' and the verified ranges' tiles,
+    `lead_rows` the candidate slots the launch scores."""
+    S = filter_slot_bucket(max(sum(map(len, f)) for f in filters))
+    plan = np.zeros((rows, 3 * S + 1), np.int32)
+    row_of = bit_rows.row_of_term
+    lead_max = knn_lead_tiles_max(n_docs)
+    tiles = terms = bit_terms = lead_tiles = 0
+    for ji, clauses in enumerate(filters):
+        terms += sum(map(len, clauses))
+        ranges, in_rows = [], []  # (tiles, first tile); bit-row clauses
+        for clause in clauses:
+            tids = [tid for tid in map(pf.term_id, clause) if tid >= 0]
+            if tids and all(tid in row_of for tid in tids):
+                in_rows.append(tids)
+            elif len(clause) == 1:
+                ranges.append(
+                    (int(pf.term_tile_count[tids[0]]),
+                     int(pf.term_tile_start[tids[0]])) if tids else (0, 0))
+            else:
+                return None
+        ranges.sort()
+        if not ranges or ranges[0][0] > lead_max:
+            return None
+        if ranges[0][0] == 0:
+            ranges, in_rows = ranges[:1], []
+        elif any(c > KNN_LEAD_VERIFY_TILES_MAX for c, _start in ranges[1:]):
+            return None
+        lead_tiles += ranges[0][0]
+        for slot, (count, start) in enumerate(ranges):
+            plan[ji, slot], plan[ji, S + slot] = start, count
+            plan[ji, 2 * S + slot] = 1
+            tiles += count
+        slot = len(ranges)
+        for tids in in_rows:
+            slot = _put_bit_row_slots(
+                plan, ji, slot, [row_of[tid] for tid in tids])
+            bit_terms += len(tids)
+        plan[ji, 3 * S] = len(clauses)
+    return FilterPlans(plan, tiles, terms, bit_terms, lead_tiles * TILE_WIDTH)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("similarity", "k", "blocks", "interpret"))
+def knn_topk_lead(
+    queries: jax.Array,  # float32[B, d] (padded rows are zeros)
+    vectors: jax.Array,  # [N, d] float rows, or int8 of a byte field
+    norms: Optional[jax.Array],  # float32[N], see knn_scores
+    cand: jax.Array,  # bool[N] rows that hold a vector and are live
+    doc_ids: jax.Array,  # int32[n_tiles, 128] the filter field's postings
+    bits: Optional[jax.Array],  # uint32[R, W] FilterBitRows.plane
+    plan: jax.Array,  # int32[B, 3 * S + 1] of pack_lead_plans
+    similarity: str,
+    k: int,
+    blocks: bool = False,  # rows_on_lanes(vectors)
+    interpret: bool = False,  # the block kernel off the chip (tests)
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A filtered kNN launch every row of which leads by postings
+    (`pack_lead_plans`): each row's lead clause's documents are
+    gathered, checked against its other clauses and scored; no other
+    stored row is scored, no mask plane and no score plane is made.
+    (scores[B, k], docs[B, k], rows passed int32[B]): what
+    `knn_filter_mask` + `knn_topk_filtered` return for the same filters,
+    -inf (and an arbitrary document) where fewer than k rows pass.
+
+    Trip t takes the next KNN_LEAD_CHUNK // B tiles of each row's lead
+    range (the trip count is the longest row's, a value of the launch,
+    as `filter_row_masks`'): their doc ids and those documents' `cand`
+    bits are gathered. A candidate fails where it is a pad posting,
+    `cand` is false, or one of the row's other clauses misses it: a
+    bit-row slot reads bit d // W of word d % W of its row
+    (`_pack_bit_rows`' layout; slots that join OR into the clause the
+    slot before opened), a tile-range slot looks the document up among
+    the range's gathered ids (at most KNN_LEAD_VERIFY_TILES_MAX tiles).
+    The candidates left are scored by `knn_scores`' arithmetic: their
+    products with the query (`blocks`: from the 128-row block around
+    each, fetched by ops/pallas_lead.py where the device holds the rows
+    along the lanes; else from the gathered rows), their norms, the
+    similarity's transform. Integer rows are cast here; whole numbers
+    of 8 bits, so the sums are exact in whatever order and equal the
+    scan's bit for bit; float rows agree to KNN_SCORE_RTOL. The trip's
+    scores fold into the running top k: a lead's postings ascend, so
+    `top_k`'s preference for the earlier of equal scores is the scan's
+    preference for the lower document."""
+    n = cand.shape[0]
+    B = plan.shape[0]
+    S = (plan.shape[1] - 1) // 3
+    V = KNN_LEAD_VERIFY_TILES_MAX
+    chunk = max(KNN_LEAD_CHUNK // B, 1)
+    M = chunk * TILE_WIDTH
+    starts, counts = plan[:, :S], plan[:, S : 2 * S]
+    units, need = plan[:, 2 * S : 3 * S], plan[:, 3 * S]
+    last_tile = doc_ids.shape[0] - 1
+    lane = jnp.arange(chunk, dtype=jnp.int32)[None, :]
+    queries = _knn_unit_queries(queries, similarity)
+    nothing = jnp.zeros((B, M), jnp.bool_)
+
+    def others(docs):
+        """bool[B, M]: the row's clauses past the lead hold docs[B, M]."""
+        def in_rows(row):
+            w = bits.shape[1]
+            words = jnp.take(bits, row, axis=0, mode="clip")  # [B, W]
+            word = jnp.take_along_axis(words, docs % w, axis=1)
+            return ((word >> (docs // w).astype(jnp.uint32)) & 1) > 0
+
+        def in_range(start, count):
+            held = jnp.arange(V, dtype=jnp.int32)[None, :]  # [1, V]
+            ids = doc_ids[jnp.clip(start[:, None] + held, 0, last_tile)]
+            ids = jnp.where((held < count[:, None])[:, :, None], ids, -1)
+            return jnp.any(
+                docs[:, :, None] == ids.reshape(B, 1, V * TILE_WIDTH),
+                axis=2)
+
+        def slot(s, carry):
+            met, clause = carry  # bool[B, M]: clauses closed; the open one
+            a, count, unit = (
+                jax.lax.dynamic_index_in_dim(x, s, 1, keepdims=False)
+                for x in (starts, counts, units))
+            hit = jax.lax.cond(
+                jnp.any(unit > 0), lambda: in_range(a, count),
+                lambda: nothing)
+            if bits is not None:
+                hit = jnp.where(
+                    (unit < 0)[:, None],
+                    jax.lax.cond(jnp.any(unit < 0), lambda: in_rows(a),
+                                 lambda: nothing),
+                    hit)
+            opens = ((unit > 0) | (unit == FILTER_BIT_OPENS))[:, None]
+            joins = (unit == FILTER_BIT_JOINS)[:, None]
+            return (jnp.where(opens, met & clause, met),
+                    jnp.where(opens, hit,
+                              jnp.where(joins, clause | hit, clause)))
+
+        ones = jnp.ones((B, M), jnp.bool_)
+        met, clause = jax.lax.fori_loop(
+            1, jnp.max(jnp.sum(units != 0, axis=1)), slot, (ones, ones))
+        return met & clause
+
+    def dots_by_row(docs):
+        """(q . each candidate's gathered row, the rows' own `sum(v *
+        v)` where l2_norm has no norm plane to read)."""
+        rows = vectors[docs]  # [B, M, d]
+        if jnp.issubdtype(rows.dtype, jnp.integer):
+            rows = rows.astype(jnp.float32)
+        v2 = None
+        if similarity == "l2_norm" and norms is None:
+            v2 = jnp.sum(rows * rows, axis=2)
+        return jnp.einsum("bd,bmd->bm", queries, rows), v2
+
+    def dots_by_block(docs, ok, left):
+        """q . each candidate still `ok`, picked out of its block's 128
+        products; `left` the slots of each row's range from this trip
+        on. The rows past the last whole block (fewer than 128) are
+        multiplied whole."""
+        from .pallas_lead import BLOCK, block_dots
+
+        whole = n // BLOCK
+        blk = jnp.where(ok, jnp.minimum(docs // BLOCK, whole - 1), -1)
+        # the candidate's lane, picked by a compare and a sum over the
+        # block's 128 products (a gather of M elements costs 8 ns each)
+        lanes = (jnp.arange(BLOCK, dtype=jnp.int32)
+                 == (docs % BLOCK)[:, :, None])
+        dots = jnp.sum(
+            jnp.where(lanes, block_dots(
+                queries, vectors, blk, jnp.clip(left, 0, M),
+                interpret=interpret), 0.0), axis=2)
+        if whole * BLOCK < n:
+            tail = vectors[whole * BLOCK:].astype(jnp.float32)
+            at = jnp.clip(docs - whole * BLOCK, 0, n - whole * BLOCK - 1)
+            dots = jnp.where(
+                docs >= whole * BLOCK,
+                jnp.take_along_axis(queries @ tail.T, at, axis=1), dots)
+        return dots
+
+    def trip(t, carry):
+        top_s, top_d, passed = carry
+        i = t * chunk + lane  # [1, chunk] positions in a row's lead range
+        tile = jnp.clip(starts[:, :1] + i, 0, last_tile)
+        docs = doc_ids[tile]  # [B, chunk, 128]
+        ok = ((docs >= 0) & (i < counts[:, :1])[:, :, None]).reshape(B, M)
+        docs = jnp.maximum(docs, 0).reshape(B, M)
+        ok = ok & (need > 0)[:, None] & cand[docs] & others(docs)
+        if blocks and not (similarity == "l2_norm" and norms is None):
+            dots, v2 = dots_by_block(
+                docs, ok, (counts[:, 0] - t * chunk) * TILE_WIDTH), None
+        else:
+            dots, v2 = dots_by_row(docs)
+        if similarity == "l2_norm":
+            q2 = jnp.sum(queries * queries, axis=1, keepdims=True)
+            scores = _knn_l2_scores(
+                q2, norms[docs] if v2 is None else v2, dots)
+        else:
+            scores = _knn_dot_scores(dots, similarity)
+        scores = jnp.where(ok, scores.astype(jnp.float32), -jnp.inf)
+        top_s, pos = jax.lax.top_k(
+            jnp.concatenate([top_s, scores], axis=1), k)
+        top_d = jnp.take_along_axis(
+            jnp.concatenate([top_d, docs], axis=1), pos, axis=1)
+        return top_s, top_d, passed + ok.sum(axis=1, dtype=jnp.int32)
+
+    return jax.lax.fori_loop(
+        0, (jnp.max(counts[:, 0]) + chunk - 1) // chunk, trip,
+        (jnp.full((B, k), -jnp.inf, jnp.float32),
+         jnp.zeros((B, k), jnp.int32), jnp.zeros(B, jnp.int32)),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("similarity", "k"))

@@ -661,12 +661,14 @@ class RestActions:
         # filters passed, postings tiles the mask launches scattered,
         # the planned filters' terms and those of them answered from a
         # bit row of the segment, the mask launches, those whose scan
-        # selected from block maxima, and the scans that fell back to
-        # the unbatched executor
+        # selected from block maxima, the scans that fell back to the
+        # unbatched executor, and the searches a lead launch served
+        # with the candidate slots they scored
         knn_filtered = {
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
             "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
             "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
+            "lead_searches": 0, "lead_rows": 0,
         }
         # the serve family's filtered and negated groups
         # (QueryBatcher.serve_filtered): (job x segment) scans of the

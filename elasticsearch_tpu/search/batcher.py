@@ -1036,13 +1036,17 @@ def _warm_match(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
 
 def _warm_knn(jobs: List[_Job]) -> Tuple[Tuple, _Job]:
     # the kNN candidate page is a compile bucket of its own, and so is
-    # the width of a filtered group's mask plan
+    # the width of a filtered group's mask plan; a filtered group that
+    # led by postings (its `lead` tag) ran another program than one that
+    # scanned, and a job of it leads at any bucket
     def terms(j: _Job) -> int:
         return j.plan.filter.n_terms if j.plan.filter else 0
 
     j0 = max(jobs, key=lambda j: (j.plan.num_candidates, terms(j)))
+    led = bool(jobs[0].group and jobs[0].group.plan_tags.get("lead"))
     return (scoring.next_bucket(j0.plan.num_candidates, 16),
-            scoring.filter_slot_bucket(terms(j0)) if terms(j0) else 0), j0
+            scoring.filter_slot_bucket(terms(j0)) if terms(j0) else 0,
+            led), j0
 
 
 FAMILIES: Dict[str, _Family] = {
@@ -1255,21 +1259,25 @@ class QueryBatcher:
             "warmup_failures": 0,
         }
         # the knn family's filtered groups (`_nodes/stats`
-        # `knn_filtered`; under self._lock): (job x segment) scans under
-        # a mask the device built, the rows scored and the rows the
-        # filters passed (counted on the device, read at collect; both
-        # count a fallback's rows too), the postings tiles the mask
-        # launches scattered, the terms of the planned filters and those
-        # of them a bit row of the segment answered (nothing scattered:
-        # DevicePostings.filter_bits), the mask launches, those of them
-        # whose scan selected its top k from block maxima (a segment
-        # wide enough: scoring.knn_block_select), and the (job x
-        # segment) scans that left the planned path for the unbatched
-        # executor
+        # `knn_filtered`; under self._lock): (job x segment) searches
+        # the device planned (under a mask it built, or led by
+        # postings), the rows scored (every stored row of a scan, the
+        # candidate slots of a lead) and the rows the filters passed
+        # (counted on the device, read at collect; both count a
+        # fallback's rows too), the postings tiles the mask launches
+        # scattered and the lead launches gathered, the terms of the
+        # planned filters and those of them a bit row of the segment
+        # answered (DevicePostings.filter_bits), the mask launches,
+        # those of them whose scan selected its top k from block maxima
+        # (a segment wide enough: scoring.knn_block_select), the (job x
+        # segment) searches that left the planned path for the
+        # unbatched executor, and those a lead launch served
+        # (`scoring.knn_topk_lead`) with the candidate slots they scored
         self.knn_filtered = {
             "searches": 0, "rows_scanned": 0, "rows_passed": 0,
             "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
             "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
+            "lead_searches": 0, "lead_rows": 0,
         }
         # the serve family's filtered and negated groups (`_nodes/stats`
         # `serve_filtered`; under self._lock): (job x segment) scans of
@@ -2581,22 +2589,29 @@ class QueryBatcher:
         `rows` pads the query-row dimension to one ladder bucket.
 
         A FILTERED group (every job carries a `KeywordFilter`; the group
-        key keeps them apart from bare jobs) gives each row a candidate
-        mask of its own, built on the device by one launch of
-        `scoring.knn_filter_mask` a segment (the `filter_mask` span, a
-        child of `dispatch`), and scores every stored row under it
-        (`knn_topk_filtered`); the rows each filter passed are counted
-        on the device and come down with the collect's packed
-        download. The planned path neither reads nor feeds the node's
-        filter-bitset cache (a byte a document for every distinct
-        filter): a common tag (df >= dense_row_min_df on the segment)
-        is read from the bit row the segment holds for it, an eighth
-        of a byte a document, built once from the postings when the
-        field first serves such a search (`DevicePostings.filter_bits`);
-        any other tag's postings tiles are scattered, the launch's
-        0.96 ms and 13 ns a posting slot (under 1 ms more for a tag
-        just under the rule at 10M rows). A segment whose mask cannot
-        be built (the `knn.filter` fault site, a postings upload the
+        key keeps them apart from bare jobs) takes one of two paths a
+        segment, chosen from what the launch can read of its own input
+        (`_dispatch_knn_filtered`). Where every job LEADS BY POSTINGS
+        (its rarest tag is short and its other clauses can be checked
+        a candidate at a time: `scoring.pack_lead_plans`) one launch of
+        `scoring.knn_topk_lead` scores the lead tag's documents and
+        nothing else (the `knn_lead` span, a child of `dispatch`; the
+        group's `lead` tag). Any other launch gives each row a
+        candidate mask of its own, built on the device by one launch of
+        `scoring.knn_filter_mask` (the `filter_mask` span), and scores
+        every stored row under it (`knn_topk_filtered`). Either way the
+        rows each filter passed are counted on the device and come
+        down with the collect's packed download. The planned path
+        neither reads nor feeds the node's filter-bitset cache (a byte
+        a document for every distinct filter): a common tag (df >=
+        dense_row_min_df on the segment) is read from the bit row the
+        segment holds for it, an eighth of a byte a document, built
+        once from the postings when the field first serves such a
+        search (`DevicePostings.filter_bits`); any other tag's
+        postings tiles are gathered (a lead, a verified range) or
+        scattered (a mask: the launch's 0.96 ms and 13 ns a posting
+        slot at 10M rows). A segment whose filter cannot be planned on
+        the device (the `knn.filter` fault site, a postings upload the
         HBM breaker refuses) is served per job by the unbatched
         executor at collect, and counted (`knn_filtered.fallbacks`);
         the IVF tier is not asked for filtered jobs."""
@@ -2716,9 +2731,16 @@ class QueryBatcher:
                                q: np.ndarray, vectors, norms, cand_mask,
                                similarity: str, kc: int, rows: int,
                                record: bool) -> Tuple:
-        """One segment of a filtered group: the mask launch, then the
-        scan under the masks (`norms`: an integer field's norm plane,
-        None for a float field, whose scan computes its own).
+        """One segment of a filtered group (`norms`: an integer field's
+        norm plane, None for a float field, whose programs compute
+        their own). A launch all of whose jobs lead by postings
+        (`scoring.pack_lead_plans`: read from the field's tile counts
+        on this segment and its rows) is ONE launch of
+        `scoring.knn_topk_lead` over the leads' documents; any other
+        launch, a mixed one too, is the mask launch and then the scan
+        under the masks (splitting a mixed launch is
+        `yfcc10m-filtered-knn.load4`'s business, PERF.md section 7 row
+        9: no cell sends one).
         -> (si, n, scores, docs, passed), the three on the device;
         (si, n, None, None, None) where the segment is left to the
         unbatched executor at collect; None where no document of the
@@ -2740,39 +2762,57 @@ class QueryBatcher:
                 with self._lock:
                     self.knn_filtered["fallbacks"] += len(jobs)
             return si, n, None, None, None
-        fp = scoring.pack_filter_plans(
-            pf, [j.plan.filter.clauses for j in jobs], rows, bits)
+        filters = [j.plan.filter.clauses for j in jobs]
+        fp = scoring.pack_lead_plans(pf, filters, rows, n, bits)
+        lead = fp is not None
+        if not lead:
+            fp = scoring.pack_filter_plans(pf, filters, rows, bits)
         note_transfer("h2d", fp.plan.nbytes)
-        mask, passed = scoring.knn_filter_mask(
-            dp.doc_ids, cand_mask, fp.plan, bits.plane)
+        note_transfer("h2d", q.nbytes)
+        if lead:
+            s, d, passed = scoring.knn_topk_lead(
+                q, vectors, norms, cand_mask, dp.doc_ids, bits.plane,
+                fp.plan, similarity=similarity, k=kc,
+                blocks=scoring.rows_on_lanes(vectors))
+        else:
+            mask, passed = scoring.knn_filter_mask(
+                dp.doc_ids, cand_mask, fp.plan, bits.plane)
         if record:
             g = _group_now()
             g.plan_tags["filter_tiles"] = (
                 g.plan_tags.get("filter_tiles", 0) + fp.tiles)
+            tags = {"segment": si, "launches": 1, "tiles": fp.tiles,
+                    "bitset_terms": fp.bit_terms,
+                    "bitset_rows_held": len(bits.row_of_term)}
+            if lead:
+                g.plan_tags["lead"] = True
+                tags["lead_rows"] = fp.lead_rows
             g.sub_spans.append((
-                "filter_mask", t0, time.perf_counter_ns(),
-                {"segment": si, "launches": 1, "tiles": fp.tiles,
-                 "bitset_terms": fp.bit_terms,
-                 "bitset_rows_held": len(bits.row_of_term)},
-            ))
-        note_transfer("h2d", q.nbytes)
-        s, d = scoring.knn_topk_filtered(
-            q, vectors, mask, similarity, kc, norms)
+                "knn_lead" if lead else "filter_mask", t0,
+                time.perf_counter_ns(), tags))
+        if not lead:
+            s, d = scoring.knn_topk_filtered(
+                q, vectors, mask, similarity, kc, norms)
         if record:
-            dims = int(q.shape[1])
+            # what the launch scored: the leads' slots, or every row
+            scored = fp.lead_rows if lead else len(jobs) * n
             with self._lock:
                 self.stats["launches"] += 1
                 self.stats["fused_jobs"] += len(jobs)
                 kf = self.knn_filtered
                 kf["searches"] += len(jobs)
-                kf["rows_scanned"] += len(jobs) * n
+                kf["rows_scanned"] += scored
                 kf["filter_tiles"] += fp.tiles
                 kf["filter_terms"] += fp.terms
                 kf["bitset_terms"] += fp.bit_terms
-                kf["mask_launches"] += 1
-                if scoring.knn_block_select(n, kc):
-                    kf["block_select_launches"] += 1
-            _group_now().add_flops(scoring.knn_flops(len(jobs), n, dims))
+                if lead:
+                    kf["lead_searches"] += len(jobs)
+                    kf["lead_rows"] += fp.lead_rows
+                else:
+                    kf["mask_launches"] += 1
+                    if scoring.knn_block_select(n, kc):
+                        kf["block_select_launches"] += 1
+            g.add_flops(scoring.knn_flops(1, scored, int(q.shape[1])))
         return si, n, s, d, passed
 
     def _collect_knn_group(self, jobs: List[_Job], items,

@@ -227,6 +227,35 @@ def test_filtered_mask_program_with_bit_rows(one_chip, slots):
     assert "while" not in _reachable(hlo, bodies[0])
 
 
+@pytest.mark.parametrize("rows", [1, 32])
+def test_filtered_knn_lead_program(one_chip, rows):
+    """The lead route's one program at the deployment's shapes, its rows
+    fetched block by block as the chip's layout asks: Mosaic takes the
+    kernel, the transposed view of the rows is no copy of them (the
+    program's temporaries stay under a tenth of the 1.92 GB a
+    relayout would make), and no program text gathers stored rows."""
+    s = _on(one_chip)
+    words = scoring.filter_bit_words(FILTERED_DOCS)
+    lead = scoring.knn_topk_lead.lower(
+        s((rows, 192), jnp.float32),
+        s((FILTERED_DOCS, 192), jnp.int8),
+        s((FILTERED_DOCS,), jnp.float32),
+        s((FILTERED_DOCS,), jnp.bool_),
+        s((FILTERED_TILES, TILE), jnp.int32),
+        s((FILTERED_BIT_ROWS, words), jnp.uint32),
+        s((rows, 3 * scoring.FILTER_SLOT_BUCKETS[0] + 1), jnp.int32),
+        similarity="l2_norm", k=128, blocks=True,
+    ).compile()
+    hlo = lead.as_text()
+    assert "tpu_custom_call" in hlo and "while" in hlo
+    assert lead.memory_analysis().temp_size_in_bytes < FILTERED_DOCS * 192 // 10
+    resident = (FILTERED_DOCS * 196 + FILTERED_TILES * TILE * 4
+                + FILTERED_BIT_ROWS * words * 4)
+    assert _fits(lead) + resident < HBM_BYTES
+    assert f"s8[{FILTERED_DOCS},192]" in hlo  # the resident rows, as held
+    assert not re.search(r"= s8\[\d+,\d+,192\]", hlo)  # no gathered rows
+
+
 def _computation(hlo: str, name: str) -> str:
     """The text of one named computation of an HLO module."""
     m = re.search(rf"^%?{re.escape(name)} .*?^}}", hlo, re.M | re.S)
