@@ -28,7 +28,13 @@ Design:
     layer's self time is its duration less what its children cover.
   * Completed traces go into a bounded ring (`ES_TPU_TRACE_RING`,
     default 256) queryable via `GET /_internal/traces` — a test/smoke
-    surface, not a production exporter.
+    surface, not a production exporter. The answer is assembled from
+    one encoding a trace (`Trace.encoded`, kept on the trace: a
+    finished trace does not change) with the interpreter yielded
+    between traces, so a poll of the whole ring never holds the
+    request threads and the dispatcher workers for its length
+    (`export`); `_nodes/stats` `tracing.*` counts the exports, their
+    traces and milliseconds, and the traces the ring dropped unread.
   * `ES_TPU_TRACING=off` disables arming entirely (`begin()` → None).
 
 The spans of a search over HTTP, parent > children (clock:
@@ -89,7 +95,8 @@ starts at `http`'s start and reaches the ring when `http` ends:
         dispatcher worker from submit to the worker's completion mark:
         queue_wait [family, cold_ms]  submit -> a worker starts the
             job's group; cold_ms = compile time that accrued meanwhile
-        dispatch [family, jobs, rows, launches, express, overflow; a
+        dispatch [family, jobs, rows, launches (its `launch`
+            children), express, overflow; a
             fused match or serve group also rare_tiles, a serve group
             fields and hot_slots: the most tile slots / dense rows a
             job and field used; a sparse group terms, tiles_scored,
@@ -143,12 +150,34 @@ starts at `http`'s start and reaches the ring when `http` ends:
             from its prunable jobs' first tiles (`postings` slots
             gathered; launches 0: no device work, no host sync);
             inside `sparse_plan`
+          > launch [program, host_operands, h2d_bytes]  the entry of
+            one jitted call -> its return (`launch`, the bracket every
+            launch site of the query path stands in): the host's cost
+            of a launch. `host_operands` the operands handed over as
+            host arrays, `h2d_bytes` theirs (what `note_transfer`
+            notes at the site). A child of `dispatch`, inside
+            `filter_mask` / `knn_lead` / `phrase_plan` where the group
+            has one (a sparse group's row launch inside `sparse_plan`,
+            its chunk launches and `_finalize` after it), with the
+            host's packing between the launches; the merge program a
+            collect still launches (`_merge_segments`,
+            `_knn_merge_segments`) is a child of `collect`
         inflight  -> the worker comes back to collect the group
         collect [d2h_bytes; a text or sparse group also merged: false
             when it downloaded the fused kernel's packed row as it was,
             true when the merge program ran]  blocking download, hits
             -> the job's `t_done`, the WORKER's mark before it writes
-            the job's spans and sets the waiter's event
+            the job's spans and sets the waiter's event. Tiled
+            launch* | download+ | unpack up to the hand-over between
+            the marks:
+          > download [bytes]  `ops/scoring._to_host`'s entry -> its
+            return: the worker blocked until the awaited program is
+            done AND its bytes are on the host (the one site of a
+            blocking download; the children's `bytes` sum to
+            `d2h_bytes`). Under `dispatch` where a dispatch blocks
+            (the chunked path's threshold round)
+          > unpack  the group's last download ended -> the job's
+            `t_done`: decode_result, rank_order, the Hits and TopDocs
         compile [program, seconds]  child of the dispatch (or collect)
             span the worker compiled in; one per program
         wake   `t_done` -> the waiter is back from `QueryBatcher.wait`:
@@ -158,12 +187,20 @@ starts at `http`'s start and reaches the ring when `http` ends:
 
 On the profiler's clock the same phases are
 `jax.profiler.TraceAnnotation`s: the workers' `es.dispatch` /
-`es.collect` (arguments `family`, `rows`), the request thread's
-`es.http` (the `http` span's interval) and `es.search` [`route`] (around
-the action's `cluster.search` call: the `coordinator` span's thread and
-interval). Start `jax.profiler.start_trace(dir)` on the serving process
-and they land on the host plane of the `.xplane.pb`, one line per
-dispatcher or connection thread, beside the device's `XLA Ops` line.
+`es.collect` (arguments `family`, `rows`) and, nested in them,
+`es.launch` [`program`], `es.download` and `es.unpack` (the three spans
+above; `es.unpack` closes with the group's collect, after its last
+job); the request thread's `es.http` (the `http` span's interval),
+`es.search` [`route`] (around the action's `cluster.search` call: the
+`coordinator` span's thread and interval) and `es.trace_export` (the
+traces action assembling its answer). Start
+`jax.profiler.start_trace(dir)` on the serving process and they land on
+the host plane of the `.xplane.pb`, one line per dispatcher or
+connection thread, beside the device's `XLA Ops` line. With the
+runtime's `DoEnqueueProgram` events and the device's `XLA Modules`
+line (joined by `run_id`) a launch's life is on one clock: `es.launch`
+opens -> `DoEnqueueProgram` -> the module starts -> it ends ->
+`es.download` closes -> `es.unpack` closes.
 
 `note_transfer` counts the query path's host<->device transfers where
 they happen (ops/scoring.py, the kNN upload in search/batcher.py, the
@@ -185,6 +222,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import itertools
+import json
 import os
 import threading
 import time
@@ -281,6 +319,11 @@ class Trace:
         self._dropped = 0
         self._span_ids = itertools.count(1)
         self._lock = threading.Lock()
+        # the finished trace as JSON (`encoded`), and whether an export
+        # has read it (the ring's `ring_overwritten` counts those it
+        # drops unread)
+        self._encoded: Optional[bytes] = None
+        self.exported = False
 
     # ---- recording ----
 
@@ -288,12 +331,15 @@ class Trace:
         """An id for a span that is written later, when its end is
         known, so that spans recorded meanwhile can name it as their
         parent (`under(id)`, or an explicit `parent_id`)."""
-        with self._lock:
-            return next(self._span_ids)
+        # no lock: `next` of an `itertools.count` is one step of the
+        # interpreter, and a worker reserves a job's parents ahead of
+        # the one `add_spans` that writes them
+        return next(self._span_ids)
 
     def _write(self, name, start_ns, end_ns, parent_id, span_id, tags):
         """One span, the lock held. -> its id, or None if the trace is
         full (the drop is counted)."""
+        self._encoded = None  # a straggler's span after `finish`
         if len(self._spans) >= MAX_SPANS:
             self._dropped += 1
             return None
@@ -354,6 +400,16 @@ class Trace:
             "spans": spans,
         }
 
+    def encoded(self) -> bytes:
+        """`to_dict()` as JSON. A finished trace does not change, so
+        its encoding is made once and kept for the next export."""
+        enc = self._encoded
+        if enc is None:
+            enc = json.dumps(self.to_dict()).encode()
+            if self.end_ns is not None:
+                self._encoded = enc
+        return enc
+
 
 @contextlib.contextmanager
 def under(span_id: Optional[int]):
@@ -371,18 +427,62 @@ def under(span_id: Optional[int]):
 
 _ring_lock = threading.Lock()
 _ring: deque = deque(maxlen=_ring_cap())
+# `_nodes/stats` `tracing`: exports answered, the traces they carried,
+# the milliseconds they took, and traces the ring pushed out before any
+# export had read them (an operator sizing ES_TPU_TRACE_RING against
+# the poll's period reads the last)
+_export = {"exports": 0, "exported_traces": 0, "export_ms": 0.0,
+           "ring_overwritten": 0}
 
 
 def _ring_append(trace: Trace) -> None:
     with _ring_lock:
+        if len(_ring) == _ring.maxlen and not _ring[0].exported:
+            _export["ring_overwritten"] += 1
         _ring.append(trace)
+
+
+def _newest(n: int) -> List[Trace]:
+    """The last `n` completed traces, newest first."""
+    with _ring_lock:
+        return list(_ring)[-max(0, int(n)):][::-1] if n > 0 else []
 
 
 def recent(n: int = 50) -> List[dict]:
     """Newest-first dicts of the last `n` completed traces."""
+    return [t.to_dict() for t in _newest(n)]
+
+
+def export(n: int = 50) -> bytes:
+    """The body of `GET /_internal/traces`: the JSON document
+    `{"enabled", "count", "traces": recent(n)}`, assembled from the
+    traces' own encodings. The interpreter is yielded after each trace
+    encoded here (one an earlier export encoded costs an append), so
+    the poll of a full ring holds the serving threads for one trace's
+    encoding at a time (a tenth of a millisecond), not for the
+    document's (tens)."""
+    t0 = time.perf_counter_ns()
+    traces = _newest(n)
+    parts = []
+    for t in traces:
+        fresh = t._encoded is None
+        parts.append(t.encoded())
+        t.exported = True
+        if fresh:
+            time.sleep(0)  # lets a waiting thread have the interpreter
+    head = json.dumps({"enabled": enabled(), "count": len(parts)})
+    body = b"%s, \"traces\": [%s]}" % (
+        head[:-1].encode(), b", ".join(parts))
     with _ring_lock:
-        traces = list(_ring)[-max(0, int(n)):]
-    return [t.to_dict() for t in reversed(traces)]
+        _export["exports"] += 1
+        _export["exported_traces"] += len(parts)
+        _export["export_ms"] += (time.perf_counter_ns() - t0) / 1e6
+    return body
+
+
+def export_stats() -> Dict[str, Any]:
+    with _ring_lock:
+        return {**_export, "export_ms": round(_export["export_ms"], 3)}
 
 
 def clear() -> None:
@@ -487,11 +587,16 @@ def reserve():
 
 class _ThreadTransfers(threading.local):
     d2h_bytes = 0
+    # on a dispatcher worker: the group it is dispatching or collecting
+    # right now (search/batcher.py `_Group`), which takes the marks of
+    # the launches and downloads made meanwhile; else None
+    group = None
 
 
 _xfer_lock = threading.Lock()
 _xfer = {"h2d_count": 0, "h2d_bytes": 0, "d2h_count": 0, "d2h_bytes": 0}
 _xfer_thread = _ThreadTransfers()
+_NO_GROUP = contextlib.nullcontext()
 
 
 def note_transfer(direction: str, nbytes: int, count: int = 1) -> None:
@@ -517,3 +622,39 @@ def thread_d2h_bytes() -> int:
     """Bytes the calling thread has downloaded so far: a dispatcher
     worker reads it around a group's collect for the span's tag."""
     return _xfer_thread.d2h_bytes
+
+
+def set_worker_group(group) -> None:
+    """The calling dispatcher worker starts (or, with None, leaves) a
+    group's dispatch or collect."""
+    _xfer_thread.group = group
+
+
+def worker_group():
+    return _xfer_thread.group
+
+
+def launch(program: str, host_operands: int = 0, h2d_bytes: int = 0,
+           flops: int = 0):
+    """The bracket around ONE jitted call of the query path, wherever
+    the call is made (ops/scoring.py, ops/impact.py, search/batcher.py):
+    on a dispatcher worker the group's `_Group.launch` (two marks, the
+    `es.launch` annotation, the launch and its `flops` counted, a
+    `launch` span of every job's trace), elsewhere nothing.
+    `host_operands`: the operands handed over as host arrays, which the
+    call uploads; `h2d_bytes`: theirs, as `note_transfer` is told."""
+    g = _xfer_thread.group
+    if g is None:
+        return _NO_GROUP
+    return g.launch(program, host_operands, h2d_bytes, flops)
+
+
+def note_download(start_ns: int, nbytes: int) -> None:
+    """One blocking download that began at `start_ns` and has just
+    ended (`ops/scoring._to_host`): counted as a "d2h" transfer, and on
+    a dispatcher worker a `download` span of its group."""
+    end_ns = time.perf_counter_ns()
+    note_transfer("d2h", nbytes)
+    g = _xfer_thread.group
+    if g is not None:
+        g.downloaded(start_ns, end_ns, nbytes)

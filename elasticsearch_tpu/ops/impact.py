@@ -77,7 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common.tracing import note_transfer
+from ..common.tracing import launch, note_transfer
 from .scoring import BPAD, TCHUNK, _finalize, _to_host
 
 TILE_WIDTH = 128
@@ -343,14 +343,18 @@ class ImpactScorer:
             ids[j, : len(rl)] = rl
             tw[j, : len(rl)] = wl
         note_transfer("h2d", ids.nbytes + tw.nbytes, count=2)
-        return _impact_dense_add(
-            self.rows.plane, ids, tw, width=self.n_docs + 1
-        )
+        slots = sum(len(rl) for rl in row_lists)
+        with launch("_impact_dense_add", 2, ids.nbytes + tw.nbytes,
+                    sparse_flops(0, slots * self.n_docs)):
+            return _impact_dense_add(
+                self.rows.plane, ids, tw, width=self.n_docs + 1
+            )
 
     def new_acc(self, rows: int = BPAD):
         """Zeroed accumulators at one query-row bucket of the ladder,
         for a scoring no row launch starts: one program."""
-        return _impact_zeros(rows=rows, width=self.n_docs + 1)
+        with launch("_impact_zeros"):
+            return _impact_zeros(rows=rows, width=self.n_docs + 1)
 
     def stage_chunks(self, rows: int, tile_lists, weight_lists):
         """The host planes (tiles i32, weights f32, valid bool, each
@@ -384,12 +388,13 @@ class ImpactScorer:
         into the donated accumulators; each launch uploads its three."""
         ti, tw, tv = staged
         for c in range(len(ti)):
-            note_transfer(
-                "h2d", ti[c].nbytes + tw[c].nbytes + tv[c].nbytes, count=3
-            )
-            acc, cnt = _impact_chunk_add(
-                self.doc_ids, self.values, acc, cnt, ti[c], tw[c], tv[c]
-            )
+            nbytes = ti[c].nbytes + tw[c].nbytes + tv[c].nbytes
+            note_transfer("h2d", nbytes, count=3)
+            with launch("_impact_chunk_add", 3, nbytes,
+                        sparse_flops(int(tv[c].sum()))):
+                acc, cnt = _impact_chunk_add(
+                    self.doc_ids, self.values, acc, cnt, ti[c], tw[c], tv[c]
+                )
         return acc, cnt
 
     def score_into(self, acc, cnt, tile_lists, weight_lists):
@@ -415,13 +420,14 @@ class ImpactScorer:
         msm = self._msm.get(rows)
         if msm is None:
             msm = self._msm[rows] = jnp.ones((rows,), jnp.int32)
-        return _finalize(
-            acc,
-            cnt,
-            live if live is not None else self.live,
-            msm,
-            k=min(k, self.n_docs),
-        )
+        with launch("_finalize"):
+            return _finalize(
+                acc,
+                cnt,
+                live if live is not None else self.live,
+                msm,
+                k=min(k, self.n_docs),
+            )
 
 
 class SparseBlockMax:

@@ -40,22 +40,28 @@ Scores are float32 end-to-end for oracle parity.
 from __future__ import annotations
 
 import functools
+import time
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from ..common.tracing import note_transfer
+from ..common.tracing import launch, note_download, note_transfer
 
 
 def _to_host(x) -> np.ndarray:
     """The blocking download of one device array (a host sync), noted
-    for `_nodes/stats` `transfer.scoring`. Uploads note themselves where
-    they happen: an explicit `device_put`, or a host array handed to a
-    jitted program."""
-    out = np.asarray(x)
-    note_transfer("d2h", out.nbytes)
+    for `_nodes/stats` `transfer.scoring`; entry to return it is the
+    `download` span of a dispatcher worker's group and `es.download` on
+    the profiler's clock: the wait for the awaited program and the way
+    back. Uploads note themselves where they happen: an explicit
+    `device_put`, or a host array handed to a jitted program."""
+    t0 = time.perf_counter_ns()
+    with TraceAnnotation("es.download"):
+        out = np.asarray(x)
+    note_download(t0, out.nbytes)
     return out
 
 
@@ -153,7 +159,8 @@ TCHUNK = 512  # fixed tiles per row per launch
 
 # ---- FLOP estimates for the `"profile": true` breakdown -----------------
 # Useful (non-padding) work per scored element, counted at dispatch time
-# into the group's `flops` (search/batcher.py _Group.add_flops).
+# into the group's `flops` (the launch bracket: search/batcher.py
+# `_Group.launch`).
 # Per posting slot the BM25 kernel does ~6 flops (tf·inv_norm multiply,
 # 1+x add, divide, w−x subtract, validity select, scatter add); a dense
 # hot-term row does ~4 per doc (no gather/scatter). top_k selection is
@@ -318,24 +325,32 @@ class ChunkedScorer:
                     tv[j, :m] = True
             for plane in (ti, tw, tv):  # host arrays: the launch uploads them
                 note_transfer("h2d", plane.nbytes)
-            if cnt is None:
-                acc = _chunk_add(self.doc_ids, self.tfs, self.inv_norm, acc, ti, tw, tv)
-            else:
-                acc, cnt = _chunk_add_cnt(
-                    self.doc_ids, self.tfs, self.inv_norm, acc, cnt, ti, tw, tv
-                )
+            with launch(
+                "_chunk_add" if cnt is None else "_chunk_add_cnt", 3,
+                ti.nbytes + tw.nbytes + tv.nbytes,
+                text_plan_flops(int(tv.sum()), 0, 0),
+            ):
+                if cnt is None:
+                    acc = _chunk_add(
+                        self.doc_ids, self.tfs, self.inv_norm, acc, ti, tw, tv)
+                else:
+                    acc, cnt = _chunk_add_cnt(
+                        self.doc_ids, self.tfs, self.inv_norm, acc, cnt,
+                        ti, tw, tv,
+                    )
         return acc, cnt
 
     def threshold(self, acc, k: int, live=None):
         """`live` optionally overrides the constructor's live-docs mask
         (a cached filter bitset ANDed with live docs rides here — same
         traced operand, no recompile)."""
-        theta, accmax = _threshold(
-            acc,
-            live if live is not None else self.live,
-            k=min(k, self.n_docs),
-            block_size=self.block_size,
-        )
+        with launch("_threshold"):
+            theta, accmax = _threshold(
+                acc,
+                live if live is not None else self.live,
+                k=min(k, self.n_docs),
+                block_size=self.block_size,
+            )
         return _to_host(theta), _to_host(accmax)
 
     def finalize(self, acc, cnt, msm: np.ndarray, k: int, live=None):
@@ -347,13 +362,14 @@ class ChunkedScorer:
         device, so the cross-segment merge kernel can consume it with no
         per-segment host sync."""
         note_transfer("h2d", 4 * len(msm))
-        return _finalize(
-            acc,
-            cnt,
-            live if live is not None else self.live,
-            jnp.asarray(msm, jnp.int32),
-            k=min(k, self.n_docs),
-        )
+        with launch("_finalize", 1, 4 * len(msm)):
+            return _finalize(
+                acc,
+                cnt,
+                live if live is not None else self.live,
+                jnp.asarray(msm, jnp.int32),
+                k=min(k, self.n_docs),
+            )
 
 
 def _score_tiles_inner(doc_rows, tf_rows, tile_weights, tile_valid, inv_norm, n_docs):
@@ -868,31 +884,43 @@ class MultiFusedScorer:
         buf = staging("fused_plan", shape, np.int32) if staging else None
         packed = self.pack_plans(plans, out=buf, rows=shape[0])
         note_transfer("h2d", packed.nbytes)
+        host_operands, h2d_bytes = 1, packed.nbytes
         if tie is not None:
             note_transfer("h2d", 4)
             tie = np.float32(tie)
+            host_operands, h2d_bytes = 2, h2d_bytes + 4
         # a launch of neither makes the call it always made
         special = {}
         if fmask is not None:
             special["fmask"] = fmask
+            # the rows' filter plan, a host array too (noted where it
+            # was packed)
+            host_operands, h2d_bytes = (
+                host_operands + 1, h2d_bytes + fmask[1].nbytes)
         if negated:
             special["negated"] = True
-        out = _fused_query_mf(
-            tuple(p["doc_ids"] for p in self.parts),
-            tuple(p["tfs"] for p in self.parts),
-            tuple(p["inv_norm"] for p in self.parts),
-            tuple(p["dense"] for p in self.parts),
-            live if live is not None else self.live,
-            packed,  # the jitted call uploads both: no eager device_put
-            tie,
-            tuple(p["wide"] for p in self.parts),
-            t_rare=self.t_rare,
-            n_hot=self.n_hot_slots,
-            k=k,
-            combine=combine,
-            counted=counted,
-            **special,
+        flops = sum(
+            text_plan_flops(len(rt), len(hr), self.n_docs)
+            for field_plans, _msm in plans
+            for rt, _rw, hr, _hw in field_plans
         )
+        with launch("_fused_query_mf", host_operands, h2d_bytes, flops):
+            out = _fused_query_mf(
+                tuple(p["doc_ids"] for p in self.parts),
+                tuple(p["tfs"] for p in self.parts),
+                tuple(p["inv_norm"] for p in self.parts),
+                tuple(p["dense"] for p in self.parts),
+                live if live is not None else self.live,
+                packed,  # the jitted call uploads both: no eager device_put
+                tie,
+                tuple(p["wide"] for p in self.parts),
+                t_rare=self.t_rare,
+                n_hot=self.n_hot_slots,
+                k=k,
+                combine=combine,
+                counted=counted,
+                **special,
+            )
         return out, k
 
     def search(self, plans, k: int, combine: str, tie, live=None,
@@ -1142,13 +1170,13 @@ def merge_segment_topk(items, k: int, extra: int = 0):
     that many trailing counters; their sums over the segments come
     fifth, i64[B, extra]."""
     k = min(k, sum(_part_width(p, extra) for _, p in items))
-    out = _to_host(
-        _merge_segments(
+    with launch("_merge_segments"):
+        merged = _merge_segments(
             tuple(p for _, p in items),
             segs=tuple(int(si) for si, _ in items),
             k=k, extra=extra,
         )
-    )
+    out = _to_host(merged)
     scores = out[:, :k].copy().view(np.float32)
     segs = out[:, k : 2 * k]
     docs = out[:, 2 * k : 3 * k]
@@ -1197,27 +1225,28 @@ def knn_merge_segment_topk(items, nc_rows: np.ndarray, k: int, passed=None):
     fifth result, their sums i64[B], to the same transfer."""
     widths = [int(s.shape[1]) for _, s, _ in items]
     k = min(k, sum(widths))
-    seg_of_slot = _to_device(
-        np.repeat(np.asarray([si for si, *_ in items], np.int32), widths)
-    )
+    seg_of_slot = np.repeat(
+        np.asarray([si for si, *_ in items], np.int32), widths)
     rank_of_slot = np.concatenate(
         [np.arange(w, dtype=np.int32) for w in widths]
     )
     # bool [B, total_slots]: slot rank < that (job, segment)'s budget
-    nc_cat = _to_device(
+    nc_cat = (
         rank_of_slot[None, :]
         < np.repeat(nc_rows.astype(np.int32), widths, axis=1)
     )
-    out = _to_host(
-        _knn_merge_segments(
+    # the two uploads stand in front of the runtime as a host operand's
+    # staging does: they are the launch's
+    with launch("_knn_merge_segments", 2, seg_of_slot.nbytes + nc_cat.nbytes):
+        merged = _knn_merge_segments(
             tuple(s for _, s, _ in items),
             tuple(d for _, _, d in items),
-            seg_of_slot,
-            nc_cat,
+            _to_device(seg_of_slot),
+            _to_device(nc_cat),
             k=k,
             passed=None if passed is None else tuple(passed),
         )
-    )
+    out = _to_host(merged)
     scores = out[:, :k].copy().view(np.float32)
     segs = out[:, k : 2 * k]
     docs = out[:, 2 * k : 3 * k]

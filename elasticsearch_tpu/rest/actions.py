@@ -307,13 +307,14 @@ class RestActions:
     # ---- per-request trace ring (GET /_internal/traces) ----
 
     def get_traces(self, body, params, qs):
+        """{"enabled", "count", "traces": the newest `n`, newest first},
+        as bytes: `tracing.export` assembles the document from the
+        traces' own encodings and yields the interpreter between them
+        (`es.trace_export` on the profiler's clock), so the handler has
+        nothing left to encode."""
         n = int(qs.get("n", ["50"])[0]) if qs else 50
-        traces = tracing.recent(n)
-        return 200, {
-            "enabled": tracing.enabled(),
-            "count": len(traces),
-            "traces": traces,
-        }
+        with TraceAnnotation("es.trace_export"):
+            return 200, tracing.export(n)
 
     def delete_traces(self, body, params, qs):
         tracing.clear()
@@ -920,6 +921,9 @@ class RestActions:
                     # named for their scope: ops/scoring.py and the kNN
                     # upload. d2h_count = blocking downloads (host syncs)
                     "transfer": {"scoring": tracing.transfer_stats()},
+                    # the trace ring's exports (GET /_internal/traces)
+                    # and the traces it dropped before any read them
+                    "tracing": tracing.export_stats(),
                     "aggs": aggs_block,
                     "knn": knn_block,
                     "knn_filtered": knn_filtered,

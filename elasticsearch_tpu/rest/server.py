@@ -89,7 +89,9 @@ class ElasticHandler(BaseHTTPRequestHandler):
         m.status = status
         m.t_respond = time.perf_counter_ns()
         try:
-            if isinstance(payload, (dict, list)):
+            if isinstance(payload, bytes):  # a JSON document, encoded
+                data, ctype = payload, "application/json"
+            elif isinstance(payload, (dict, list)):
                 data = json.dumps(payload).encode()
                 ctype = "application/json"
             else:

@@ -56,7 +56,9 @@ from ..common.tracing import (
     PARENT_CTX,
     TRACE_CTX,
     note_transfer,
+    set_worker_group,
     thread_d2h_bytes,
+    worker_group,
 )
 from ..index.mapping import KEYWORD, SPARSE_VECTOR, TEXT
 from ..ops import phrase as phrase_ops
@@ -86,8 +88,8 @@ MAX_BATCH = BPAD
 # the correction only ever errs towards admitting while compiling.
 _COMPILE_EVENTS = "/jax/core/compile/"
 _BACKEND_COMPILE = _COMPILE_EVENTS + "backend_compile_duration"
-# on dispatcher workers: .batcher (the owner) and .group (the _Group the
-# worker is dispatching or collecting right now, else None)
+# on dispatcher workers: .batcher (the owner). The _Group a worker is
+# dispatching or collecting right now is `tracing.worker_group()`
 _worker_tl = threading.local()
 
 
@@ -101,7 +103,7 @@ def _on_compile_seconds(
     with b._cold_lock:
         b._cold_s += float(duration)
         b._compiles += built
-    g = getattr(_worker_tl, "group", None)
+    g = worker_group()
     if g is not None:
         g.note_compile(fun_name, float(duration), built)
 
@@ -791,6 +793,37 @@ class _Job:
         self.event.set()
 
 
+class _Launch:
+    """`_Group.launch`: the bracket around one jitted call. Two marks,
+    `es.launch` on the profiler's clock between them, and on the way out
+    the launch counted with its flops and written as a `launch` span of
+    the group (a call that raised counts nothing)."""
+
+    __slots__ = ("group", "flops", "tags", "start_ns", "on_profiler")
+
+    def __init__(self, group: "_Group", program: str, host_operands: int,
+                 h2d_bytes: int, flops: int):
+        self.group = group
+        self.flops = flops  # may be set inside the block (a mesh launch)
+        self.tags = {"program": program, "host_operands": host_operands,
+                     "h2d_bytes": int(h2d_bytes)}
+        self.on_profiler = TraceAnnotation("es.launch", program=program)
+
+    def __enter__(self) -> "_Launch":
+        self.on_profiler.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        end_ns = time.perf_counter_ns()
+        self.on_profiler.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            g = self.group
+            g.flops += int(self.flops)
+            g.launches += not g.t_dispatched
+            g.sub_spans.append(("launch", self.start_ns, end_ns, self.tags))
+
+
 class _Group:
     """One dispatched group's marks: consecutive `perf_counter_ns`
     readings the worker takes once, whatever reads them. They tile each
@@ -802,8 +835,9 @@ class _Group:
 
     __slots__ = (
         "family", "jobs", "rows", "express", "cold_s", "t_start",
-        "t_dispatched", "t_collect", "d2h0", "launches", "flops",
-        "overflow", "compiles", "plan_tags", "merged", "sub_spans",
+        "t_dispatched", "t_collect", "t_unpack", "d2h0", "launches",
+        "flops", "overflow", "compiles", "plan_tags", "merged",
+        "sub_spans", "unpacking",
     )
 
     def __init__(self, family: str, jobs: int, rows: Optional[int],
@@ -814,8 +848,10 @@ class _Group:
         self.express = express
         self.cold_s = cold_s  # the batcher's cold clock at t_start
         self.t_dispatched = self.t_collect = 0
+        # the end of the collect's last download: where `unpack` starts
+        self.t_unpack = 0
         self.d2h0 = 0
-        self.launches = 0
+        self.launches = 0  # made in dispatch: its `launch` children
         self.flops = 0
         self.overflow = False  # the group left the fused kernel
         # a group's fused plans, for its `dispatch` span: `rare_tiles`
@@ -826,15 +862,19 @@ class _Group:
         # jobs and segments: row slots used, the tiles they stand for),
         # `quantized`
         self.plan_tags: Dict[str, int] = {}
-        # (name, start_ns, end_ns, tags): spans inside `dispatch`, its
-        # children (`sparse_theta`: the host's threshold from the query
-        # terms' first tiles)
+        # (name, start_ns, end_ns, tags): spans inside `dispatch` or
+        # `collect`, children of the one they ended in (`launch`,
+        # `download`, a segment's `filter_mask` / `phrase_plan` /
+        # `sparse_plan`, `sparse_theta`)
         self.sub_spans: List[Tuple] = []
         # a text or sparse group's `collect` span: whether its candidates
         # went through the merge program (`_group_topk`), else None
         self.merged: Optional[bool] = None
         # program -> [start_ns, end_ns, seconds, built] compiled meanwhile
         self.compiles: Dict[str, list] = {}
+        # `es.unpack` on the profiler's clock, open from the collect's
+        # last download to `unpacked`
+        self.unpacking: Optional[TraceAnnotation] = None
         self.t_start = time.perf_counter_ns()
 
     def dispatched(self) -> None:
@@ -846,11 +886,31 @@ class _Group:
         self.t_collect = time.perf_counter_ns()
         self.d2h0 = thread_d2h_bytes()
 
-    def add_flops(self, n: int) -> None:
-        """One recorded launch and its estimated useful flops (the
-        `dispatch` span's `launches`, the profile breakdown's `flops`)."""
-        self.launches += 1
-        self.flops += int(n)
+    def launch(self, program: str, host_operands: int = 0,
+               h2d_bytes: int = 0, flops: int = 0) -> _Launch:
+        """The bracket around one jitted call (`tracing.launch` leads
+        the launch sites of ops/ here): one recorded launch and its
+        estimated useful flops (the `dispatch` span's `launches`, the
+        profile breakdown's `flops`), a `launch` span, `es.launch`."""
+        return _Launch(self, program, host_operands, h2d_bytes, flops)
+
+    def downloaded(self, start_ns: int, end_ns: int, nbytes: int) -> None:
+        """One blocking download of this worker (`tracing.note_download`):
+        a `download` span, and in a collect the start of `unpack`."""
+        self.sub_spans.append(
+            ("download", start_ns, end_ns, {"bytes": int(nbytes)}))
+        if self.t_collect:
+            self.unpacked()
+            self.t_unpack = end_ns
+            self.unpacking = TraceAnnotation("es.unpack")
+            self.unpacking.__enter__()
+
+    def unpacked(self) -> None:
+        """Closes `es.unpack`: the next download has come down, or the
+        collect has finished its last job."""
+        ann, self.unpacking = self.unpacking, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def phase(self, name: str) -> TraceAnnotation:
         """The worker's phase on the profiler's clock (`es.dispatch`,
@@ -881,33 +941,44 @@ class _Group:
         t2 = self.t_collect or t1
         tr = j.trace
         if tr is not None:
+            # every span of the job in ONE write, on the request's
+            # critical path (the waiter's event is set after it): the
+            # two parents' ids are reserved without the lock
             up = j.parent
-            tr.add_span(
-                "queue_wait", j.t_enq, self.t_start, parent_id=up,
-                family=self.family,
-                cold_ms=round((self.cold_s - j.cold0) * 1000.0, 3),
-            )
-            disp = tr.add_span(
-                "dispatch", self.t_start, t1, parent_id=up,
-                family=self.family, jobs=self.jobs, rows=self.rows,
-                launches=self.launches, express=self.express,
-                overflow=self.overflow, **self.plan_tags,
-            )
-            for name, s0, s1, tags in self.sub_spans:
-                tr.add_span(name, s0, s1, parent_id=disp, **tags)
-            tr.add_span("inflight", t1, t2, parent_id=up)
-            coll = tr.add_span(
-                "collect", t2, t_done, parent_id=up,
-                d2h_bytes=thread_d2h_bytes() - self.d2h0,
-                **({} if self.merged is None else {"merged": self.merged}),
-            )
-            for program, (c0, c1, secs, built) in self.compiles.items():
-                if built:
-                    tr.add_span(
-                        "compile", c0, c1,
-                        parent_id=disp if c1 <= t1 else coll,
-                        program=program, seconds=round(secs, 6),
-                    )
+            disp, coll = tr.reserve_span(), tr.reserve_span()
+            spans = [
+                ("queue_wait", j.t_enq, self.t_start, up, None, {
+                    "family": self.family,
+                    "cold_ms": round((self.cold_s - j.cold0) * 1000.0, 3),
+                }),
+                ("dispatch", self.t_start, t1, up, disp, {
+                    "family": self.family, "jobs": self.jobs,
+                    "rows": self.rows, "launches": self.launches,
+                    "express": self.express, "overflow": self.overflow,
+                    **self.plan_tags,
+                }),
+                ("inflight", t1, t2, up, None, {}),
+                ("collect", t2, t_done, up, coll, {
+                    "d2h_bytes": thread_d2h_bytes() - self.d2h0,
+                    **({} if self.merged is None
+                       else {"merged": self.merged}),
+                }),
+            ]
+            # a child falls under the phase it ended in
+            spans += [
+                (name, s0, s1, disp if s1 <= t1 else coll, None, tags)
+                for name, s0, s1, tags in self.sub_spans
+            ]
+            if self.t_collect:
+                spans.append(("unpack", self.t_unpack or t2, t_done, coll,
+                              None, {}))
+            spans += [
+                ("compile", c0, c1, disp if c1 <= t1 else coll, None,
+                 {"program": program, "seconds": round(secs, 6)})
+                for program, (c0, c1, secs, built) in self.compiles.items()
+                if built
+            ]
+            tr.add_spans(spans)
         p = j.prof
         if p is not None:
             # built aside and dict-swapped in, so a reader that races
@@ -948,7 +1019,7 @@ def _group_now() -> _Group:
     """The group this worker is dispatching or collecting. Off a worker
     and in a warm-up (a dispatch / collect pair driven by hand) a
     throwaway nothing reads."""
-    return getattr(_worker_tl, "group", None) or _Group("", 0, 0)
+    return worker_group() or _Group("", 0, 0)
 
 
 @dataclass(frozen=True)
@@ -991,11 +1062,12 @@ def _mesh_family(overlap: str, share: Callable, dispatch: str,
     MeshExecutor's pair (B queries x all shards in one SPMD program)."""
 
     def launch(b, jobs, key, kb, rows, record):
-        pend = getattr(jobs[0].executor, dispatch)(jobs, kb)
+        with _group_now().launch(dispatch) as launched:
+            pend = getattr(jobs[0].executor, dispatch)(jobs, kb)
+            launched.flops = pend["flops"]
         with b._lock:
             b.stats["launches"] += 1
             b.stats["fused_jobs"] += len(jobs)
-        _group_now().add_flops(pend["flops"])
         return pend
 
     def download(b, jobs, key, kb, pend, record):
@@ -1606,7 +1678,7 @@ class QueryBatcher:
                 g = _Group(key[1], len(jobs), rows, express, self._cold_s)
                 for j in jobs:
                     j.group = g
-                _worker_tl.group = g
+                set_worker_group(g)
                 self._enter_kind(fam.overlap)
                 dispatched = False
                 try:
@@ -1632,7 +1704,7 @@ class QueryBatcher:
                             j.error = e
                             j.finish()
                 finally:
-                    _worker_tl.group = None
+                    set_worker_group(None)
                     if not dispatched:
                         self._exit_kind(fam.overlap)
             if len(ctx.pending) >= 2:
@@ -1660,7 +1732,7 @@ class QueryBatcher:
         try:
             for fam, key, kb, jobs, pend in ctx.pending:
                 g = jobs[0].group
-                _worker_tl.group = g
+                set_worker_group(g)
                 g.collecting()
                 try:
                     # claimed before the waiters wake: `wait_warm_idle`
@@ -1670,20 +1742,23 @@ class QueryBatcher:
                     if j0 is not None:
                         warm.append((fam, key, kb, g.rows, j0))
                     with g.phase("es.collect"):
-                        # fault site: a collect-phase failure (device→
-                        # host transfer) fails this group's waiters only
-                        faults.check(
-                            "batcher.collect", family=fam.overlap,
-                            jobs=len(jobs), mesh=int(fam.mesh),
-                        )
-                        fam.collect(self, jobs, key, kb, pend, True)
+                        try:
+                            # fault site: a collect-phase failure (device→
+                            # host transfer) fails this group's waiters only
+                            faults.check(
+                                "batcher.collect", family=fam.overlap,
+                                jobs=len(jobs), mesh=int(fam.mesh),
+                            )
+                            fam.collect(self, jobs, key, kb, pend, True)
+                        finally:
+                            g.unpacked()  # `es.unpack` ends inside it
                 except BaseException as e:
                     for j in jobs:
                         if not j.event.is_set():
                             j.error = e
                             j.finish()
                 finally:
-                    _worker_tl.group = None
+                    set_worker_group(None)
                     self._exit_kind(fam.overlap)
         finally:
             ctx.pending = []
@@ -1888,7 +1963,6 @@ class QueryBatcher:
         empty_i = np.empty(0, np.int64)
         empty_w = np.empty(0, np.float32)
         for si in range(len(reader.segments)):
-            n_docs = reader.segments[si].num_docs
             # ---- fused single-round-trip path (large segments) ----
             fs = ex.fused_scorer_mf(si, (field,))
             if fs is not None:
@@ -1918,12 +1992,6 @@ class QueryBatcher:
                                 self._fused_hot_slots[len(p[2])] += 1
                         t = _group_now().plan_tags
                         t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
-                        _group_now().add_flops(sum(
-                            scoring.text_plan_flops(
-                                len(p[0]), len(p[2]), n_docs
-                            )
-                            for p in fplans
-                        ))
                     dev_items.append((si, pend[0]))
                     continue
                 if record:
@@ -1968,9 +2036,6 @@ class QueryBatcher:
             if record:
                 with self._lock:
                     self.stats["launches"] += 1
-                _group_now().add_flops(scoring.text_plan_flops(
-                    sum(len(t) for t in a_tiles), 0, 0
-                ))
             if any(deferred):
                 # ---- the threshold broadcast + survival test (the one
                 # host-dependent round: only runs when pruning engages) ----
@@ -2001,9 +2066,6 @@ class QueryBatcher:
                 if record:
                     with self._lock:
                         self.stats["launches"] += 1
-                    _group_now().add_flops(scoring.text_plan_flops(
-                        sum(len(t) for t in b_tiles), 0, 0
-                    ))
             msm = np.ones(rows, np.int32)
             msm[:nj] = [j.plan.msm for j in jobs]
             dev_items.append((si, cs.finalize_device(acc, cnt, msm, kb)))
@@ -2212,11 +2274,6 @@ class QueryBatcher:
                     t["rare_tiles"] = max(t.get("rare_tiles", 0), *rare)
                     t["clauses"] = max(j.plan.clauses for j in jobs)
                     t["msm"] = max(j.plan.msm for j in jobs)
-                    n_docs = ex.reader.segments[si].num_docs
-                    _group_now().add_flops(sum(
-                        scoring.text_plan_flops(r, h, n_docs)
-                        for r, h in zip(rare, hot)
-                    ))
                 items.append(("fused", si, pend[0]))
             else:
                 if record and special:
@@ -2388,13 +2445,14 @@ class QueryBatcher:
             plan, df_min = phrase_ops.pack_phrase_plans(
                 pf, phrases, rows, width)
             note_transfer("h2d", plan.nbytes)
-            out = phrase_ops.phrase_topk(
-                dev.mats, dev.order, inv_norm, live, plan,
-                k=min(kb, int(dev.order.shape[0])),
-            )
+            g = _group_now()
+            occ = dev.occurrences * nj
+            with g.launch("phrase_topk", 1, plan.nbytes, 2 * width * occ):
+                out = phrase_ops.phrase_topk(
+                    dev.mats, dev.order, inv_norm, live, plan,
+                    k=min(kb, int(dev.order.shape[0])),
+                )
             if record:
-                g = _group_now()
-                occ = dev.occurrences * nj
                 tags["occurrences"] = tags.get("occurrences", 0) + occ
                 g.sub_spans.append((
                     "phrase_plan", t0, time.perf_counter_ns(),
@@ -2411,7 +2469,6 @@ class QueryBatcher:
                     ph["least_bytes"] += sum(
                         phrase_ops.least_bytes(df, seg.num_docs, 0)
                         for df in df_min)
-                g.add_flops(2 * width * occ)
             items.append(("dev", si, out))
         return items
 
@@ -2470,14 +2527,15 @@ class QueryBatcher:
         out: List[Tuple] = []
         for j in jobs:
             try:
-                pend = j.plan.dispatch()
+                with _group_now().launch(
+                        "agg_plan", flops=j.plan.flops_estimate()):
+                    pend = j.plan.dispatch()
             except BaseException as e:
                 out.append(("err", e))
                 continue
             with self._lock:
                 self.stats["launches"] += 1
                 self.stats["agg_jobs"] += 1
-            _group_now().add_flops(j.plan.flops_estimate())
             out.append(("ok", pend))
         return out
 
@@ -2545,18 +2603,18 @@ class QueryBatcher:
             first[ji, :w] = p.first
             valid[ji, :w] = True
         t0 = time.perf_counter()
-        out = rerank_ops.maxsim_rescore_batch(
-            qtoks, qvalid, col["starts"], col["counts"], col["toks"],
-            col["scales"], docs, first, valid,
-            plan0.spec.query_weight, plan0.spec.rescore_query_weight,
-            col["tmax"], plan0.win_static,
-        )
+        with _group_now().launch(
+                "maxsim_rescore_batch", flops=rerank_ops.rerank_flops(
+                    nj, qb, wb, col["tmax"], dims)):
+            out = rerank_ops.maxsim_rescore_batch(
+                qtoks, qvalid, col["starts"], col["counts"], col["toks"],
+                col["scales"], docs, first, valid,
+                plan0.spec.query_weight, plan0.spec.rescore_query_weight,
+                col["tmax"], plan0.win_static,
+            )
         with self._lock:
             self.stats["launches"] += 1
             self.stats["rerank_jobs"] += nj
-        _group_now().add_flops(
-            rerank_ops.rerank_flops(nj, qb, wb, col["tmax"], dims)
-        )
         return ("ok", out, t0)
 
     def _collect_rerank_group(self, jobs: List[_Job], pend: Tuple):
@@ -2680,10 +2738,13 @@ class QueryBatcher:
                     cand = vf.exists
                     if live is not None:
                         cand = cand & np.asarray(live)
-                s, d = ivf.ann_topk_batch(
-                    idx, np.asarray(q), np.asarray(valid), cand,
-                    spec.nprobe, kc, quantized=spec.quantized,
-                )
+                with _group_now().launch(
+                        "ann_topk_batch", flops=ivf.ann_flops(
+                            nj, idx.nlist, spec.nprobe, idx.cmax, dims)):
+                    s, d = ivf.ann_topk_batch(
+                        idx, np.asarray(q), np.asarray(valid), cand,
+                        spec.nprobe, kc, quantized=spec.quantized,
+                    )
                 if record:
                     from . import ann as ann_mod
 
@@ -2691,11 +2752,6 @@ class QueryBatcher:
                     with self._lock:
                         self.stats["launches"] += 1
                         self.stats["fused_jobs"] += nj
-                    _group_now().add_flops(
-                        ivf.ann_flops(
-                            nj, idx.nlist, spec.nprobe, idx.cmax, dims
-                        )
-                    )
                 items.append((si, n, s, d, None))
                 continue
             vectors, exists, norms = ex.device_segments[si].vectors[field]
@@ -2715,15 +2771,17 @@ class QueryBatcher:
             # them (noted here, the program itself cannot)
             note_transfer("h2d", q.nbytes)
             note_transfer("h2d", valid.nbytes)
-            s, d, _ = scoring.knn_topk_batch(
-                np.asarray(q), np.asarray(valid),
-                vectors, cand_mask, vf.similarity, kc,
-            )
+            with _group_now().launch(
+                    "knn_topk_batch", 2, q.nbytes + valid.nbytes,
+                    scoring.knn_flops(nj, n, dims)):
+                s, d, _ = scoring.knn_topk_batch(
+                    np.asarray(q), np.asarray(valid),
+                    vectors, cand_mask, vf.similarity, kc,
+                )
             if record:
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["fused_jobs"] += nj
-                _group_now().add_flops(scoring.knn_flops(nj, n, dims))
             items.append((si, n, s, d, None))
         return items
 
@@ -2769,16 +2827,23 @@ class QueryBatcher:
             fp = scoring.pack_filter_plans(pf, filters, rows, bits)
         note_transfer("h2d", fp.plan.nbytes)
         note_transfer("h2d", q.nbytes)
+        g = _group_now()
+        # what the segment's scoring launch scores: the leads' slots, or
+        # every row
+        scored = fp.lead_rows if lead else len(jobs) * n
+        flops = scoring.knn_flops(1, scored, int(q.shape[1]))
         if lead:
-            s, d, passed = scoring.knn_topk_lead(
-                q, vectors, norms, cand_mask, dp.doc_ids, bits.plane,
-                fp.plan, similarity=similarity, k=kc,
-                blocks=scoring.rows_on_lanes(vectors))
+            with g.launch("knn_topk_lead", 2, fp.plan.nbytes + q.nbytes,
+                          flops):
+                s, d, passed = scoring.knn_topk_lead(
+                    q, vectors, norms, cand_mask, dp.doc_ids, bits.plane,
+                    fp.plan, similarity=similarity, k=kc,
+                    blocks=scoring.rows_on_lanes(vectors))
         else:
-            mask, passed = scoring.knn_filter_mask(
-                dp.doc_ids, cand_mask, fp.plan, bits.plane)
+            with g.launch("knn_filter_mask", 1, fp.plan.nbytes):
+                mask, passed = scoring.knn_filter_mask(
+                    dp.doc_ids, cand_mask, fp.plan, bits.plane)
         if record:
-            g = _group_now()
             g.plan_tags["filter_tiles"] = (
                 g.plan_tags.get("filter_tiles", 0) + fp.tiles)
             tags = {"segment": si, "launches": 1, "tiles": fp.tiles,
@@ -2791,11 +2856,10 @@ class QueryBatcher:
                 "knn_lead" if lead else "filter_mask", t0,
                 time.perf_counter_ns(), tags))
         if not lead:
-            s, d = scoring.knn_topk_filtered(
-                q, vectors, mask, similarity, kc, norms)
+            with g.launch("knn_topk_filtered", 1, q.nbytes, flops):
+                s, d = scoring.knn_topk_filtered(
+                    q, vectors, mask, similarity, kc, norms)
         if record:
-            # what the launch scored: the leads' slots, or every row
-            scored = fp.lead_rows if lead else len(jobs) * n
             with self._lock:
                 self.stats["launches"] += 1
                 self.stats["fused_jobs"] += len(jobs)
@@ -2812,7 +2876,6 @@ class QueryBatcher:
                     kf["mask_launches"] += 1
                     if scoring.knn_block_select(n, kc):
                         kf["block_select_launches"] += 1
-            g.add_flops(scoring.knn_flops(1, scored, int(q.shape[1])))
         return si, n, s, d, passed
 
     def _collect_knn_group(self, jobs: List[_Job], items,
@@ -2884,10 +2947,10 @@ class QueryBatcher:
             return
         for si, n, s, d, passed in items:
             if s is not None:
-                s = np.asarray(s)
-                d = np.asarray(d)
+                s = scoring._to_host(s)
+                d = scoring._to_host(d)
                 if filtered:
-                    passed_rows += int(np.asarray(passed)[:nj].sum())
+                    passed_rows += int(scoring._to_host(passed)[:nj].sum())
             for ji, j in enumerate(jobs):
                 nc = min(j.plan.num_candidates, n)
                 if s is None:
@@ -3102,8 +3165,6 @@ class QueryBatcher:
                 with self._lock:
                     self.stats["launches"] += 1
                     self.stats["sparse_jobs"] += nj
-                group.add_flops(impact_ops.sparse_flops(
-                    tiles_scored, dense_rows * sc.n_docs))
                 for name, n in (("tiles_scored", tiles_scored),
                                 ("tiles_pruned", tiles_pruned),
                                 ("chunk_launches", launches),

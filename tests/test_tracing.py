@@ -19,6 +19,13 @@ Contract under test:
     response's last byte: the handler's `http_read`, `request_parse`
     and `respond`, and `admission_wait` and `coordinator`, are its
     children, and the trace reaches the ring after the response;
+  * `dispatch` and `collect` hold what they are made of: a `launch`
+    span a jitted call (its host operands and their bytes), a
+    `download` span a blocking download, `unpack` from the last download
+    to the job's completion mark; the same phases are annotations on
+    the profiler's clock; a job's spans are one write;
+  * `GET /_internal/traces` answers the ring's document from one
+    encoding a trace, and `_nodes/stats` `tracing` counts the exports;
   * the query path's host<->device transfers are counted exactly
     (`_nodes/stats` `transfer.scoring`).
 """
@@ -156,6 +163,9 @@ class TestSearchTracing:
 # ---------------------------------------------------------------------
 
 JOB_SPANS = ("queue_wait", "dispatch", "inflight", "collect")
+# what `dispatch` and `collect` hold: every jitted call, every blocking
+# download, and the host's work on what came down
+PHASE_SPANS = ("launch", "download", "unpack")
 DIMS = 8
 WORDS = ["alpha", "beta", "gamma", "delta", "epsilon", "zeta", "eta",
          "theta", "iota", "kappa"]
@@ -344,9 +354,10 @@ class TestBatcherJobSpans:
         seen = []
         for tr, parent in traces:
             # (the four-row shape is new to this process: it compiles,
-            # and the compile spans hang off the job's spans)
+            # and the compile spans hang off the job's spans, as their
+            # launches, download and unpack do: TestPhaseSpans)
             spans = {s["name"]: s for s in tr.to_dict()["spans"]
-                     if s["name"] != "compile"}
+                     if s["name"] not in ("compile", *PHASE_SPANS)}
             assert set(spans) == set(JOB_SPANS)
             assert all(s["parent_id"] == parent for s in spans.values())
             seen.append(spans)
@@ -480,6 +491,276 @@ class TestTransferCounters:
         assert by["collect"]["tags"] == {"d2h_bytes": 124}
 
 
+# ---------------------------------------------------------------------
+# inside `dispatch` and `collect`: launch, download, unpack
+# ---------------------------------------------------------------------
+
+VEC = [1.0] + [0.0] * (DIMS - 1)
+BM25_LEG = {"query": {"match": {"body": "alpha beta"}}}
+KNN_LEG = {"field": "vec", "query_vector": VEC, "k": 10,
+           "num_candidates": 10}
+# one body a family of the batcher, and the programs its request launches
+# (dispatch first, then collect)
+FAMILIES = {
+    "match": (BM25_LEG, ["_fused_query_mf"], []),
+    "serve": ({"query": {"multi_match": {
+        "query": "alpha beta", "fields": ["body", "title"]}}},
+        ["_fused_query_mf"], []),
+    "filtered_serve": ({"query": {"bool": {
+        "must": [{"match": {"body": "alpha"}}],
+        "filter": [{"term": {"tag": "even"}}]}}},
+        ["_fused_query_mf"], []),
+    "knn": ({"knn": KNN_LEG}, ["knn_topk_batch"], ["_knn_merge_segments"]),
+    "filtered_knn_scan": (
+        {"knn": {**KNN_LEG, "filter": {"term": {"tag": "even"}}}},
+        ["knn_filter_mask", "knn_topk_filtered"], ["_knn_merge_segments"]),
+    "filtered_knn_lead": (
+        {"knn": {**KNN_LEG, "filter": {"term": {"tag": "rare"}}}},
+        ["knn_topk_lead"], ["_knn_merge_segments"]),
+    "phrase": ({"query": {"match_phrase": {"body": "alpha beta"}}},
+               ["phrase_topk"], []),
+    "sparse": ({"query": {"sparse_vector": {
+        "field": "ml", "query_vector": {"alpha": 1.0, "beta": 0.5}}}},
+        ["_impact_zeros", "_impact_chunk_add", "_finalize"],
+        ["_merge_segments"]),
+    "rrf_leg": ({"retriever": {"rrf": {"retrievers": [
+        {"standard": BM25_LEG}, {"knn": KNN_LEG}]}}, "size": 5},
+        ["_fused_query_mf", "knn_topk_batch"], ["_knn_merge_segments"]),
+}
+
+
+@pytest.fixture(scope="module")
+def family_service():
+    """One 300-doc segment every family of the batcher can serve: two
+    text fields, a keyword, a vector and a sparse vector, the fused
+    kernel forced on."""
+    from elasticsearch_tpu.cluster.indices import IndexService
+    from elasticsearch_tpu.search import executor_jax
+
+    orig = executor_jax.FUSED_MIN_DOCS
+    executor_jax.FUSED_MIN_DOCS = 10
+    rng = np.random.default_rng(7)
+    svc = IndexService(
+        "tr-families",
+        settings={"number_of_shards": 1, "search.backend": "jax"},
+        mappings_json={"properties": {
+            "body": {"type": "text"}, "title": {"type": "text"},
+            "tag": {"type": "keyword"},
+            "vec": {"type": "dense_vector", "dims": DIMS,
+                    "similarity": "cosine"},
+            "ml": {"type": "sparse_vector"},
+        }},
+    )
+    for i in range(300):
+        svc.index_doc(str(i), {
+            "body": " ".join(rng.choice(WORDS, int(rng.integers(3, 9)))),
+            "title": " ".join(rng.choice(WORDS, 2)),
+            "tag": ["even" if i % 2 == 0 else "odd"]
+            + (["rare"] if i % 50 == 0 else []),
+            "vec": [float(x) for x in rng.normal(size=DIMS)],
+            "ml": {w: float(rng.random()) + 0.1
+                   for w in rng.choice(WORDS, 3, replace=False)},
+        })
+    svc.refresh()
+    yield svc
+    svc.close()
+    executor_jax.FUSED_MIN_DOCS = orig
+
+
+class TestPhaseSpans:
+    @pytest.mark.parametrize("family", list(FAMILIES))
+    def test_dispatch_and_collect_hold_their_launches_and_downloads(
+        self, family_service, monkeypatch, family
+    ):
+        body, in_dispatch, in_collect = FAMILIES[family]
+        if family == "filtered_knn_lead":
+            # a lead slot need stand for one row only: a 300-row segment
+            # leads by a tag of six documents
+            monkeypatch.setattr(scoring, "KNN_LEAD_SCAN_ROWS", 1)
+        family_service.search(json.loads(json.dumps(body)))  # compile
+        syncs0 = tracing.transfer_stats()
+        spans, _ = traced_search(family_service, body)
+        moved = {k: v - syncs0[k]
+                 for k, v in tracing.transfer_stats().items()}
+        ids = {s["id"]: s for s in spans}
+        phase = [s for s in spans if s["name"] in PHASE_SPANS]
+        # every one lies inside the span of the group that made it
+        for s in phase:
+            parent = ids[s["parent_id"]]
+            assert parent["name"] in ("dispatch", "collect"), s
+            assert parent["start_ns"] <= s["start_ns"], s
+            assert end_ns(s) <= end_ns(parent), s
+        launched = {"dispatch": [], "collect": []}
+        for s in sorted(phase, key=lambda s: s["start_ns"]):
+            if s["name"] == "launch":
+                launched[ids[s["parent_id"]]["name"]].append(
+                    s["tags"]["program"])
+        assert launched == {"dispatch": in_dispatch, "collect": in_collect}
+        # the host operands the launches were handed are the uploads the
+        # request noted, to the byte
+        launches = [s for s in phase if s["name"] == "launch"]
+        assert all(s["tags"].keys() == {
+            "program", "host_operands", "h2d_bytes"} for s in launches)
+        assert sum(s["tags"]["host_operands"] for s in launches) == (
+            moved["h2d_count"])
+        assert sum(s["tags"]["h2d_bytes"] for s in launches) == (
+            moved["h2d_bytes"])
+        downloads = [s for s in phase if s["name"] == "download"]
+        assert len(downloads) == moved["d2h_count"]  # one a `_to_host`
+        for disp in (s for s in spans if s["name"] == "dispatch"):
+            kids = children_of(spans, disp)
+            assert disp["tags"]["launches"] == sum(
+                s["name"] == "launch" for s in kids) >= 1
+        collects = [s for s in spans if s["name"] == "collect"]
+        assert len(collects) == (2 if family == "rrf_leg" else 1)
+        for coll in collects:
+            # launch* | download+ | unpack, in this order, none
+            # overlapping; what is left is the hand-over between marks
+            kids = children_of(spans, coll)
+            names = [s["name"] for s in kids]
+            n_launch = names.count("launch")
+            assert names == (["launch"] * n_launch + ["download"] * (
+                len(names) - n_launch - 1) + ["unpack"]), names
+            assert len(names) - n_launch - 1 >= 1
+            for a, b in zip(kids, kids[1:]):
+                assert end_ns(a) <= b["start_ns"], (a["name"], b["name"])
+            # `unpack` starts where the last download ended and ends at
+            # the job's completion mark, the span's own end
+            assert kids[-1]["start_ns"] == end_ns(kids[-2])
+            assert end_ns(kids[-1]) == end_ns(coll)
+            assert kids[-1]["tags"] == {}
+            assert sum(s["tags"]["bytes"] for s in kids
+                       if s["name"] == "download") == (
+                coll["tags"]["d2h_bytes"]) > 0
+        assert sum(c["tags"]["d2h_bytes"] for c in collects) == (
+            moved["d2h_bytes"])
+
+    def test_fused_launch_carries_what_note_transfer_noted(
+        self, family_service
+    ):
+        """The filtered serve launch: the packed plan, the tie breaker
+        and the rows' filter plan, three host operands of one call."""
+        body = FAMILIES["filtered_serve"][0]
+        family_service.search(json.loads(json.dumps(body)))
+        before = tracing.transfer_stats()
+        _, by = traced_search(family_service, body)
+        after = tracing.transfer_stats()
+        assert by["launch"]["tags"] == {
+            "program": "_fused_query_mf",
+            "host_operands": after["h2d_count"] - before["h2d_count"],
+            "h2d_bytes": after["h2d_bytes"] - before["h2d_bytes"],
+        }
+        assert by["launch"]["tags"]["host_operands"] == 3
+        # the mask's host part ends before the launch that builds it
+        assert end_ns(by["filter_mask"]) <= by["launch"]["start_ns"]
+
+    def test_a_dispatch_that_blocks_holds_its_download(
+        self, fused_service, monkeypatch
+    ):
+        """The chunked path's finalize is a launch of the dispatch; a
+        download made before the group is collected is the dispatch's
+        child, and no `unpack` follows it there."""
+        from elasticsearch_tpu.search.batcher import _Group
+
+        g = _Group("match", 1, 1)
+        tracing.set_worker_group(g)
+        try:
+            with g.phase("es.dispatch"):
+                with tracing.launch("_threshold"):
+                    pass
+                scoring._to_host(np.zeros(4, np.float32))
+            g.dispatched()
+            g.collecting()
+            with g.phase("es.collect"):
+                scoring._to_host(np.zeros(2, np.float32))
+                scoring._to_host(np.zeros(2, np.float32))
+                assert g.unpacking is not None
+                g.unpacked()
+            assert g.unpacking is None
+        finally:
+            tracing.set_worker_group(None)
+        assert g.launches == 1
+        assert [(n, t.get("bytes")) for n, _s, _e, t in g.sub_spans] == [
+            ("launch", None), ("download", 16), ("download", 8),
+            ("download", 8)]
+        assert g.sub_spans[1][2] <= g.t_dispatched  # the dispatch's
+        assert g.t_unpack == g.sub_spans[-1][2] >= g.t_collect
+        # off a worker the brackets are inert and nothing is kept
+        with tracing.launch("_fused_query_mf", 1, 8):
+            scoring._to_host(np.zeros(1, np.float32))
+        assert len(g.sub_spans) == 4
+
+    def test_one_write_a_job(self, fused_service, monkeypatch):
+        """The worker writes a job's spans in one `add_spans` call."""
+        calls = []
+        inner = tracing.Trace.add_spans
+
+        def spy(self, spans):
+            spans = list(spans)
+            calls.append([s[0] for s in spans])
+            return inner(self, spans)
+
+        monkeypatch.setattr(tracing.Trace, "add_spans", spy)
+        monkeypatch.setattr(
+            tracing.Trace, "add_span",
+            lambda self, name, *a, **kw: calls.append(name) or None)
+        traced_search(fused_service, MATCH)
+        jobs = [c for c in calls if isinstance(c, list) and "dispatch" in c]
+        assert jobs == [[*JOB_SPANS, "launch", "download", "unpack"]]
+
+    def test_phase_annotations_nest_on_the_profilers_clock(
+        self, fused_service, http_server, tmp_path
+    ):
+        """Inside a profiler session (here the CPU's) the host plane
+        holds `es.launch` inside `es.dispatch`, `es.download` and
+        `es.unpack` inside `es.collect`, and `es.trace_export`."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        fused_service.search(json.loads(json.dumps(MATCH)))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            fused_service.search(json.loads(json.dumps(MATCH)))
+            http_call(http_server, "GET", "/_internal/traces?n=4")
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(
+            tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        found = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("es."):
+                        found.setdefault(e.name, []).append((
+                            line.name, e.start_ns,
+                            e.start_ns + e.duration_ns, dict(e.stats)))
+        assert found.keys() >= {
+            "es.dispatch", "es.collect", "es.launch", "es.download",
+            "es.unpack", "es.trace_export"}
+
+        def inside(inner, outer):
+            return all(any(
+                line == o_line and o0 <= s0 and e0 <= o1
+                for o_line, o0, o1, _ in found[outer])
+                for line, s0, e0, _ in found[inner])
+
+        assert inside("es.launch", "es.dispatch")
+        assert inside("es.download", "es.collect")
+        assert inside("es.unpack", "es.collect")
+        assert inside("es.trace_export", "es.http")
+        assert [st["program"] for *_, st in found["es.launch"]] == [
+            "_fused_query_mf"]
+        # download | unpack on the worker's line, in this order
+        (_, _, down_end, _), = found["es.download"]
+        (_, up_start, _, _), = found["es.unpack"]
+        assert down_end <= up_start
+
+
 class TestRestSurface:
     @pytest.fixture
     def server(self):
@@ -547,6 +828,66 @@ class TestRestSurface:
         assert status == 200
         _, out = self._call(server, "GET", "/_internal/traces")
         assert out["count"] == 0
+
+    def test_export_answers_the_ring_as_before_and_counts_itself(
+        self, server, monkeypatch
+    ):
+        """`GET /_internal/traces` is `{"enabled", "count", "traces":
+        recent(n)}` assembled from each trace's own encoding; a second
+        export of the same ring equals the first; `_nodes/stats`
+        `tracing.*` counts exports, traces and the ones the ring dropped
+        before any export read them."""
+        def tracing_stats():
+            _, stats = self._call(server, "GET", "/_nodes/stats")
+            return next(iter(stats["nodes"].values()))["tracing"]
+
+        ring = tracing._ring.__class__(maxlen=4)
+        monkeypatch.setattr(tracing, "_ring", ring)
+        for i in range(3):
+            tr = tracing.Trace(f"t{i}", opaque_id=f"o{i}", index="i")
+            root = tr.add_span("coordinator", 10, 90, shards=1)
+            tr.add_span("fan_out", 20, 80, parent_id=root, inline=True)
+            tr.finish()
+        s0 = tracing_stats()
+        assert s0.keys() == {"exports", "exported_traces", "export_ms",
+                             "ring_overwritten"}
+        for n, want in ((2, 2), (50, 3), (0, 0)):
+            _, out = self._call(server, "GET", f"/_internal/traces?n={n}")
+            assert out == {"enabled": True, "count": want,
+                           "traces": tracing.recent(n)}
+            assert [t["name"] for t in out["traces"]] == [
+                "t2", "t1", "t0"][:want]
+        _, first = self._call(server, "GET", "/_internal/traces")
+        _, again = self._call(server, "GET", "/_internal/traces")
+        assert first == again and first["count"] == 3
+        s1 = tracing_stats()
+        assert s1["exports"] == s0["exports"] + 5
+        assert s1["exported_traces"] == s0["exported_traces"] + 2 + 3 + 3 + 3
+        assert s1["export_ms"] > s0["export_ms"]
+        assert s1["ring_overwritten"] == s0["ring_overwritten"]
+        # the ring holds four: three more traces push out two that were
+        # read and none unread; three after that push out the unread
+        for i in range(3, 6):
+            tracing.Trace(f"t{i}").finish()
+        assert tracing_stats()["ring_overwritten"] == s0["ring_overwritten"]
+        for i in range(6, 9):
+            tracing.Trace(f"t{i}").finish()
+        # t2 had been read; t3 and t4 had not
+        assert tracing_stats()["ring_overwritten"] == (
+            s0["ring_overwritten"] + 2)
+        # a span written after `finish` (a straggler of an abandoned
+        # fan-out) is in the next export all the same
+        late = ring[-1]
+        assert json.loads(late.encoded())["span_count"] == 0
+        late.add_span("straggler", 1, 2)
+        _, out = self._call(server, "GET", "/_internal/traces?n=1")
+        assert [s["name"] for s in out["traces"][0]["spans"]] == [
+            "straggler"]
+        # tracing off: the ring still answers, and says so
+        monkeypatch.setenv("ES_TPU_TRACING", "off")
+        _, out = self._call(server, "GET", "/_internal/traces?n=1")
+        assert out["enabled"] is False and out["count"] == 1
+
 
     def test_opaque_id_in_slowlog_record(self, server):
         import logging
