@@ -100,7 +100,8 @@ starts at `http`'s start and reaches the ring when `http` ends:
             fused match or serve group also rare_tiles, a serve group
             fields and hot_slots: the most tile slots / dense rows a
             job and field used; a sparse group terms, tiles_scored,
-            tiles_pruned, chunk_launches, dense_rows, tiles_dense
+            tiles_pruned, chunk_launches, trips (the loops' trips of
+            those launches), dense_rows, tiles_dense
             (sums over its jobs and segments) and quantized; a filtered knn group filtered,
             clauses (the most a job's filter holds) and filter_tiles
             (postings tiles its mask launches scattered, summed over
@@ -158,7 +159,7 @@ starts at `http`'s start and reaches the ring when `http` ends:
             notes at the site). A child of `dispatch`, inside
             `filter_mask` / `knn_lead` / `phrase_plan` where the group
             has one (a sparse group's row launch inside `sparse_plan`,
-            its chunk launches and `_finalize` after it), with the
+            its chunk launch and `_finalize` after it), with the
             host's packing between the launches; the merge program a
             collect still launches (`_merge_segments`,
             `_knn_merge_segments`) is a child of `collect`

@@ -14,11 +14,15 @@ ops/index_build.sparse_planes_device):
   → scatter-add into a dense per-doc accumulator (term-at-a-time)
   → lax.top_k (ties broken by lowest index = doc asc).
 
-`ImpactScorer` mirrors ops/scoring.ChunkedScorer shape-for-shape: tile
-lists of any length stream through [rows, TCHUNK] launches into donated
-accumulators, rows ride the same power-of-two bucket ladder, and
-finalize reuses the ONE finalize kernel so its device triples feed
-ops/scoring.merge_segment_topk unchanged.
+`ImpactScorer` hands the tile lists of a scoring to ONE launch of
+`_impact_chunk_add` a row bucket: the lists ride one staged int32 plan
+of TILE_CAP tiles a query row (tile ids, -1 past a row's last, and the
+weights' bits), and the program loops over the tiles the plan uses,
+TILE_STEP at a trip, on flat donated accumulators; a row longer than
+TILE_CAP takes a further launch. Rows ride the power-of-two bucket
+ladder ops/scoring's scorers ride, and finalize reuses the ONE finalize
+kernel so its device triples feed ops/scoring.merge_segment_topk
+unchanged.
 
 `SparseBlockMax` is the ops/wand.py analog for impact-ordered tiles.
 Because every term's postings are sorted by impact DESC, the per-tile
@@ -62,8 +66,8 @@ order; block-max bounds still sum EVERY term's maximum and hot terms
 are never dropped, which keeps more tiles, never fewer.
 
 Every host<->device transfer of the family is noted where it happens
-(`common/tracing.note_transfer`): the three staged planes a chunk
-launch uploads and the two of a row launch; the packed collect notes
+(`common/tracing.note_transfer`): the one staged plan a chunk launch
+uploads and the two planes of a row launch; the packed collect notes
 itself in ops/scoring.
 """
 
@@ -78,7 +82,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.tracing import launch, note_transfer
-from .scoring import BPAD, TCHUNK, _finalize, _to_host
+from .scoring import BPAD, _finalize, _to_host
 
 TILE_WIDTH = 128
 
@@ -114,11 +118,45 @@ def sparse_flops(n_tile_slots: int, n_row_slots: int = 0) -> int:
     ) * FLOPS_PER_IMPACT_SLOT
 
 
+# Tiles a query row hands ONE launch of `_impact_chunk_add` (the staged
+# plan's width, a compile shape) and tiles a trip of its loop gathers and
+# scatters. The program runs the trips its plan USES, so a short query
+# pays for its own tiles rounded up to a trip, whatever TILE_CAP is; a
+# row longer than TILE_CAP takes a further launch. Measured on the TPU
+# v5e at 1M documents, one query row, int8 (PERF.md section 6, PR 52,
+# Step 0; device ms a tile pass by tiles in use 1 / 128 / 512 / 1,264 /
+# 2,048 / 4,096): trips of 128 0.457 / 0.391 / 1.077 / 2.458 / 3.822 /
+# 7.480, of 256 0.747 / 0.681 / 1.067 / 2.432 / 3.780 / 7.398, of 512
+# 1.328 / 1.262 / 1.063 / 3.003 / 3.763 / 7.362; launches of a fixed
+# 512 tiles (the program before) 1.327 / 1.261 / 1.062 / 3.328 / 4.250
+# / 8.500. A launch costs 0.16 ms fixed (the planes relaid in and out),
+# a trip ~5 us, a tile in use 1.74 us and a PAD tile 2.24 (its 128
+# slots meet in the one spill cell), so the step sets the padding of the
+# last trip, half a step on average, against 5 us a trip: 128 (0.14 ms
+# of padding, ten trips a 1,264-tile query) reads within 0.03 ms of 256
+# where both pad alike and 0.3 under it where 256 pads a trip more (at
+# 1,100 / 1,200 / 1,300 / 1,400 tiles, trips of 64 2.295 / 2.393 / 2.642
+# / 2.741, of 128 2.247 / 2.491 / 2.734 / 2.682, of 256 2.517 / 2.465 /
+# 2.998 / 2.946: means 2.52 / 2.54 / 2.73). A plan of 2,048 tiles costs
+# the host what one of 4,096 does (the call, not the 32 KB) and a
+# 4,096-tile query a second launch's 0.16 ms.
+TILE_CAP = 4096
+TILE_STEP = 128
+
+
 def chunk_launches(tile_lists) -> int:
     """`_impact_chunk_add` launches `ImpactScorer.score_into` makes for
-    these per-row tile lists: the longest row, TCHUNK tiles a launch."""
+    these per-row tile lists: the longest row, TILE_CAP tiles a launch."""
     t_max = max((len(t) for t in tile_lists), default=0)
-    return -(-t_max // TCHUNK)
+    return -(-t_max // TILE_CAP)
+
+
+def tile_trips(tile_lists) -> int:
+    """Trips of TILE_STEP tiles those launches' loops run: every row
+    rides every trip, so the longest row decides, launch by launch."""
+    t_max = max((len(t) for t in tile_lists), default=0)
+    full, rest = divmod(t_max, TILE_CAP)
+    return full * (TILE_CAP // TILE_STEP) + -(-rest // TILE_STEP)
 
 
 def impact_tile_contrib(rows_d, rows_v, tw, valid, n_docs):
@@ -134,30 +172,60 @@ def impact_tile_contrib(rows_d, rows_v, tw, valid, n_docs):
     return tgt, jnp.where(valid, s, 0.0)
 
 
-def _impact_chunk_scores(doc_ids, values, ti, tw, tv):
-    rows_d = doc_ids[ti]  # [B, TC, 128]
-    rows_v = values[ti]
-    valid = (rows_d >= 0) & tv[:, :, None]
-    return rows_d, rows_v, valid
+def _impact_tile_loop(doc_ids, values, acc, cnt, plan, step: int):
+    """`_impact_chunk_add`'s body at `step` tiles a trip (TILE_STEP in
+    the served program; scripts/probe_impact_loop.py measures others).
+
+    A loop over the tiles the plan USES, `step` at a trip, everything
+    proportional to tiles inside it (the gathers of tile rows, the
+    product, the two scatter-adds): no tile, no trip. The planes ride
+    the loop FLAT, row b's document d at b * (n + 1) + d and the spill
+    of its pad slots at b * (n + 1) + n (PR 30's finding in
+    `scoring._add_rare_tiles`): the TPU's scatter works on the flat
+    plane, so a [B, n + 1] plane is relaid into it and back around
+    every scatter; here once on the way into the program and once on
+    the way out. Trips go in plan order and a trip's postings in plan
+    order, so a document receives its addends in the order the plan
+    lists them: the float32 sums of one pass over the whole plan."""
+    rows, width = acc.shape
+    n_docs = width - 1
+    ids = plan[0]
+    tws = jax.lax.bitcast_convert_type(plan[1], jnp.float32)
+    slots = jnp.arange(1, ids.shape[1] + 1, dtype=jnp.int32)
+    used = jnp.max(jnp.where(ids >= 0, slots, 0))
+    last_tile = doc_ids.shape[0] - 1
+    row_base = (jnp.arange(rows, dtype=jnp.int32) * width)[:, None, None]
+
+    def trip(i, carry):
+        acc, cnt = carry
+        ti = jax.lax.dynamic_slice_in_dim(ids, i * step, step, axis=1)
+        tw = jax.lax.dynamic_slice_in_dim(tws, i * step, step, axis=1)
+        safe = jnp.clip(ti, 0, last_tile)
+        rows_d = doc_ids[safe]  # [B, step, 128]
+        valid = (rows_d >= 0) & (ti >= 0)[:, :, None]
+        tgt, s = impact_tile_contrib(
+            rows_d, values[safe], tw[:, :, None], valid, n_docs
+        )
+        tgt = (tgt + row_base).ravel()
+        acc = acc.at[tgt].add(s.ravel())
+        cnt = cnt.at[tgt].add(valid.ravel().astype(jnp.int32))
+        return acc, cnt
+
+    acc, cnt = jax.lax.fori_loop(
+        0, (used + step - 1) // step, trip, (acc.ravel(), cnt.ravel())
+    )
+    return acc.reshape(rows, width), cnt.reshape(rows, width)
 
 
 @functools.partial(jax.jit, donate_argnums=(2, 3))
-def _impact_chunk_add(doc_ids, values, acc, cnt, ti, tw, tv):
-    """acc[B, n+1] += impact contributions of one [B, TCHUNK] chunk;
-    cnt counts matching postings per doc (one per term — the sparse
-    match mask is cnt > 0)."""
-    n_docs = acc.shape[1] - 1
-    rows_d, rows_v, valid = _impact_chunk_scores(doc_ids, values, ti, tw, tv)
-    tgt, s = impact_tile_contrib(
-        rows_d, rows_v, tw[:, :, None], valid, n_docs
-    )
-    acc = jax.vmap(lambda a, d, v: a.at[d.ravel()].add(v.ravel()))(
-        acc, tgt, s
-    )
-    cnt = jax.vmap(
-        lambda c, d, v: c.at[d.ravel()].add(v.ravel().astype(jnp.int32))
-    )(cnt, tgt, valid)
-    return acc, cnt
+def _impact_chunk_add(doc_ids, values, acc, cnt, plan):
+    """(acc, cnt)[B, n+1] += the impact contributions of ONE staged plan
+    `plan` i32[2, B, TILE_CAP]: plane 0 a query row's tile ids from slot
+    0 up (-1 = unused), plane 1 their folded weights' float32 bits. cnt
+    counts matching postings per doc (one per term: the sparse match
+    mask is cnt > 0). The trips run are read from the plan, so one
+    program serves every tile count of a row bucket."""
+    return _impact_tile_loop(doc_ids, values, acc, cnt, plan, TILE_STEP)
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "width"))
@@ -191,7 +259,7 @@ def _impact_dense_add(plane, ids, tw, width: int):
     the TPU v5e at 1M docs, one query row (PERF.md section 6, PR 41),
     adding a flat row slice into the [1, n+1] planes relays them
     through a reshape every slot, 72 us a row; relaid once a launch, as
-    `_impact_chunk_add` relays them around its scatters, a slot is one
+    `_impact_chunk_add` relays them around its loop, a slot is one
     fused pass a plane, 11 us a row. (Four or eight rows a trip read
     8 us a row: 0.05 ms a launch of 16 rows, not worth the unrolling.)"""
     n_q = ids.shape[0]
@@ -300,8 +368,8 @@ def build_impact_rows(
 
 class ImpactScorer:
     """Batched learned-sparse scoring over one segment's impact-ordered
-    tiled postings with fixed launch shapes (ChunkedScorer's serving
-    recipe applied to the sparse column — see module comment). `rows`
+    tiled postings with fixed launch shapes (one looped program a row
+    bucket, whatever the tile counts: see module comment). `rows`
     (ImpactRows, the int8 column's hot terms) serve the terms they hold
     through `add_rows`; without them every term goes through tiles."""
 
@@ -357,43 +425,39 @@ class ImpactScorer:
             return _impact_zeros(rows=rows, width=self.n_docs + 1)
 
     def stage_chunks(self, rows: int, tile_lists, weight_lists):
-        """The host planes (tiles i32, weights f32, valid bool, each
-        `[launches, rows, TCHUNK]`) of every `_impact_chunk_add` launch
-        that per-row tile/weight lists (≤ `rows`, any length) need,
-        allocated once. `add_chunks` hands every launch its own slice,
+        """The host plans, i32[launches, 2, rows, TILE_CAP], of every
+        `_impact_chunk_add` launch that per-row tile/weight lists (≤
+        `rows`, any length) need: ONE for rows of up to TILE_CAP tiles.
+        Plane 0 holds a row's tile ids from slot 0 up and -1 past them,
+        plane 1 the weights' float32 bits: one host operand a launch.
+        Allocated once; `add_chunks` hands every launch its own slab,
         never written again: a jitted call may still be reading a host
         operand after it returns (the CPU backend aliases an aligned
         NumPy buffer and runs the program later), so one slab refilled
-        chunk after chunk would score the wrong tiles (PERF.md section
-        4, the sparse deployment's table)."""
+        launch after launch would score the wrong tiles (PERF.md
+        section 4, the sparse deployment's table)."""
         n = chunk_launches(tile_lists)
-        ti = np.zeros((n, rows, TCHUNK), np.int32)
-        tw = np.zeros((n, rows, TCHUNK), np.float32)
-        tv = np.zeros((n, rows, TCHUNK), bool)
+        plans = np.empty((n, 2, rows, TILE_CAP), np.int32)
+        plans[:, 0] = -1
+        plans[:, 1] = 0
         for j, (tl, wl) in enumerate(zip(tile_lists, weight_lists)):
-            full, rest = divmod(len(tl), TCHUNK)
-            cut = full * TCHUNK
-            if full:
-                ti[:full, j] = np.reshape(tl[:cut], (full, TCHUNK))
-                tw[:full, j] = np.reshape(wl[:cut], (full, TCHUNK))
-                tv[:full, j] = True
-            if rest:
-                ti[full, j, :rest] = tl[cut:]
-                tw[full, j, :rest] = wl[cut:]
-                tv[full, j, :rest] = True
-        return ti, tw, tv
+            wl = np.asarray(wl, np.float32).view(np.int32)
+            for c in range(-(-len(tl) // TILE_CAP)):
+                part = slice(c * TILE_CAP, (c + 1) * TILE_CAP)
+                m = len(tl[part])
+                plans[c, 0, j, :m] = tl[part]
+                plans[c, 1, j, :m] = wl[part]
+        return plans
 
     def add_chunks(self, acc, cnt, staged):
-        """Streams `stage_chunks`' planes through TCHUNK-wide launches
-        into the donated accumulators; each launch uploads its three."""
-        ti, tw, tv = staged
-        for c in range(len(ti)):
-            nbytes = ti[c].nbytes + tw[c].nbytes + tv[c].nbytes
-            note_transfer("h2d", nbytes, count=3)
-            with launch("_impact_chunk_add", 3, nbytes,
-                        sparse_flops(int(tv[c].sum()))):
+        """One `_impact_chunk_add` launch a staged plan into the donated
+        accumulators; each launch uploads its plan, one operand."""
+        for plan in staged:
+            note_transfer("h2d", plan.nbytes)
+            with launch("_impact_chunk_add", 1, plan.nbytes,
+                        sparse_flops(np.count_nonzero(plan[0] >= 0))):
                 acc, cnt = _impact_chunk_add(
-                    self.doc_ids, self.values, acc, cnt, ti[c], tw[c], tv[c]
+                    self.doc_ids, self.values, acc, cnt, plan
                 )
         return acc, cnt
 

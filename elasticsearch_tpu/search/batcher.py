@@ -858,8 +858,9 @@ class _Group:
         # (most tile slots a job and field used) and, of a serve group,
         # `fields` and `hot_slots` (most dense rows a job and field used)
         # of a sparse group also `terms`, `tiles_scored`, `tiles_pruned`,
-        # `chunk_launches`, `dense_rows`, `tiles_dense` (sums over its
-        # jobs and segments: row slots used, the tiles they stand for),
+        # `chunk_launches`, `trips`, `dense_rows`, `tiles_dense` (sums
+        # over its jobs and segments: the tile pass's launches and their
+        # loops' trips, row slots used, the tiles they stand for),
         # `quantized`
         self.plan_tags: Dict[str, int] = {}
         # (name, start_ns, end_ns, tags): spans inside `dispatch` or
@@ -3153,14 +3154,15 @@ class QueryBatcher:
                      "tiles_kept": tiles_scored},
                 ))
             acc, cnt = sc.add_chunks(acc, cnt, staged)
-            launches = len(staged[0])
+            launches = len(staged)
+            trips = impact_ops.tile_trips(tile_lists)
             pend = sc.finalize_device(acc, cnt, kb)
             if record:
                 sparse_mod.note_search(
                     nj, spec.quantized, tiles_scored, tiles_pruned,
                     chunk_launches=launches, theta_host=len(theta_jobs),
                     dense_rows=dense_rows, tiles_dense=tiles_dense,
-                    dense_launches=int(dense_launch),
+                    dense_launches=int(dense_launch), tile_trips=trips,
                 )
                 with self._lock:
                     self.stats["launches"] += 1
@@ -3168,6 +3170,7 @@ class QueryBatcher:
                 for name, n in (("tiles_scored", tiles_scored),
                                 ("tiles_pruned", tiles_pruned),
                                 ("chunk_launches", launches),
+                                ("trips", trips),
                                 ("dense_rows", dense_rows),
                                 ("tiles_dense", tiles_dense)):
                     tags[name] = tags.get(name, 0) + n

@@ -63,6 +63,7 @@ SPARSE_STATS = {
     "tiles_pruned": 0,  # Σ tail tiles dropped by block-max bounds
     "pruned_searches": 0,  # scorings where at least one tile dropped
     "chunk_launches": 0,  # Σ `_impact_chunk_add` launches
+    "tile_trips": 0,  # Σ trips of impact.TILE_STEP tiles their loops ran
     # hot terms of an int8 column hold a dense row on the device (one
     # int8 a document, -128 = no posting; ops/impact.ImpactRows: a term
     # wants one from df >= max(1024, n / 128) on the segment, rows are
@@ -93,9 +94,12 @@ def note_search(
     jobs: int, quantized: bool, tiles_scored: int, tiles_pruned: int,
     chunk_launches: int = 0, theta_host: int = 0,
     dense_rows: int = 0, tiles_dense: int = 0, dense_launches: int = 0,
+    tile_trips: int = 0,
 ) -> None:
     """One impact scoring of `jobs` queries against one segment:
-    `tiles_scored` and `chunk_launches` are its tile pass's,
+    `tiles_scored`, `chunk_launches` and `tile_trips` are its tile
+    pass's (one launch a scoring whose longest row holds at most
+    impact.TILE_CAP tiles, looping over the trips its plan uses),
     `theta_host` the jobs among them whose threshold the host computed
     beforehand from the query terms' first tiles (how often the
     block-max mechanism engages; `tiles_pruned` says how often it
@@ -111,6 +115,7 @@ def note_search(
         if tiles_pruned:
             SPARSE_STATS["pruned_searches"] += jobs
         SPARSE_STATS["chunk_launches"] += chunk_launches
+        SPARSE_STATS["tile_trips"] += tile_trips
         SPARSE_STATS["theta_host"] += theta_host
         SPARSE_STATS["dense_rows_scored"] += dense_rows
         SPARSE_STATS["tiles_dense"] += tiles_dense

@@ -676,10 +676,11 @@ def test_ivf_probe(one_chip):
 
 
 def test_impact_scorer_chunk(one_chip):
-    """ImpactScorer's chunk launch over an int8 impact column at the
+    """ImpactScorer's looped tile pass over an int8 impact column at the
     learned-sparse deployment's size (`msmarco-splade-sparse`: ~127
     non-zeros a passage over 1M passages), at the one-row (express lane)
-    bucket; the 32-row bucket takes ~10 s here."""
+    bucket: one plan of TILE_CAP tiles a row, the flat planes the loop's
+    carry; the 32-row bucket takes ~10 s here."""
     from elasticsearch_tpu.ops import impact
 
     n_tiles = 1_005_620  # the builder's count at 1,000,000 passages
@@ -690,11 +691,10 @@ def test_impact_scorer_chunk(one_chip):
         s((n_tiles, TILE), jnp.int8),
         s((rows, N_DOCS + 1), jnp.float32),
         s((rows, N_DOCS + 1), jnp.int32),
-        s((rows, scoring.TCHUNK), jnp.int32),
-        s((rows, scoring.TCHUNK), jnp.float32),
-        s((rows, scoring.TCHUNK), jnp.bool_),
+        s((2, rows, impact.TILE_CAP), jnp.int32),
     ).compile()
     _fits(compiled)
+    assert " while(" in compiled.as_text()  # the trips stayed a loop
 
 
 @pytest.mark.parametrize("rows", [1, 32])

@@ -274,6 +274,141 @@ class TestImpactKernel:
 
 
 # ---------------------------------------------------------------------------
+# the tile pass: ONE looped program a scoring (PR 52)
+# ---------------------------------------------------------------------------
+
+LOOP_DOCS, LOOP_TILES = 3000, 700
+IN_USE = [0, 1, 127, 128, 129, 511, 512, 513, 1264,
+          impact_ops.TILE_CAP, impact_ops.TILE_CAP + 1]
+
+
+@functools.lru_cache(maxsize=None)
+def loop_scorer(storage: str):
+    """A made-up column whose tiles hold documents in no order of id,
+    some twice (a plan may name a tile twice too): every cell's float32
+    sum depends on the order its addends arrive in."""
+    rng = np.random.default_rng(52)
+    doc_ids = rng.integers(-1, LOOP_DOCS, (LOOP_TILES, 128)).astype(np.int32)
+    if storage == "int8":
+        values = rng.integers(-127, 128, (LOOP_TILES, 128)).astype(np.int8)
+    else:
+        values = rng.random((LOOP_TILES, 128)).astype(np.float32)
+    return impact_ops.ImpactScorer(doc_ids, values, LOOP_DOCS)
+
+
+def loop_lists(in_use: int, rows: int):
+    """Row 0 holds `in_use` tiles, the rows under it fewer."""
+    rng = np.random.default_rng([52, in_use, rows])
+    tiles = [rng.integers(0, LOOP_TILES, max(in_use - 5 * j, 0))
+             for j in range(rows)]
+    weights = [(rng.random(len(t)) + 0.01).astype(np.float32) for t in tiles]
+    return tiles, weights
+
+
+def impact_sum(sc, tiles, weights):
+    """The parent's formula, one posting at a time in plan order:
+    float32(tw) x float32(value) added into the document's float32
+    cell, 1 into its count; pad postings (-1) nowhere."""
+    doc_ids, values = np.asarray(sc.doc_ids), np.asarray(sc.values)
+    acc = np.zeros(sc.n_docs, np.float32)
+    cnt = np.zeros(sc.n_docs, np.int32)
+    d = doc_ids[tiles].ravel()
+    p = (np.repeat(weights, 128).astype(np.float32)
+         * values[tiles].ravel().astype(np.float32))
+    np.add.at(acc, d[d >= 0], p[d >= 0])
+    np.add.at(cnt, d[d >= 0], 1)
+    return acc, cnt
+
+
+@pytest.mark.parametrize("storage", ["int8", "float32"])
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("in_use", IN_USE)
+def test_looped_tile_pass_is_the_impact_sum(in_use, rows, storage):
+    """Whatever a plan holds, the looped program's planes are the
+    NumPy impact sum over the tiles in use, bit for bit, its counts
+    equal, and a scoring is one launch up to TILE_CAP tiles."""
+    sc = loop_scorer(storage)
+    tiles, weights = loop_lists(in_use, rows)
+    assert impact_ops.chunk_launches(tiles) == -(-in_use // impact_ops.TILE_CAP)
+    full, rest = divmod(in_use, impact_ops.TILE_CAP)
+    assert impact_ops.tile_trips(tiles) == (
+        full * (impact_ops.TILE_CAP // impact_ops.TILE_STEP)
+        + -(-rest // impact_ops.TILE_STEP))
+    acc, cnt = sc.score_into(*sc.new_acc(rows), tiles, weights)
+    acc, cnt = np.asarray(acc), np.asarray(cnt)
+    for j in range(rows):
+        want_acc, want_cnt = impact_sum(sc, tiles[j], weights[j])
+        assert np.array_equal(acc[j, :-1].view(np.int32),
+                              want_acc.view(np.int32)), j
+        assert np.array_equal(cnt[j, :-1], want_cnt), j
+
+
+def test_tile_counts_of_one_row_bucket_share_one_program():
+    """The trips are read from the plan: two scorings of different tile
+    counts at one row bucket build no program between them."""
+    sc = loop_scorer("int8")
+    sc.score_into(*sc.new_acc(4), *loop_lists(3, 4))
+    built = impact_ops._impact_chunk_add._cache_size()
+    for in_use in (700, 1, impact_ops.TILE_CAP + 9):
+        sc.score_into(*sc.new_acc(4), *loop_lists(in_use, 4))
+    assert impact_ops._impact_chunk_add._cache_size() == built
+
+
+def _finalize_text() -> str:
+    from elasticsearch_tpu.ops import scoring
+
+    return scoring._finalize.lower(
+        np.zeros((2, 1001), np.float32), np.zeros((2, 1001), np.int32),
+        np.ones(1000, bool), np.ones(2, np.int32), k=16).as_text()
+
+
+def _dense_add_text() -> str:
+    stride = impact_ops.impact_row_stride(1000)
+    return impact_ops._impact_dense_add.lower(
+        np.zeros(3 * stride, np.int8),
+        np.zeros((2, impact_ops.DENSE_SLOTS), np.int32),
+        np.zeros((2, impact_ops.DENSE_SLOTS), np.float32),
+        width=1001).as_text()
+
+
+def _programs_left_alone() -> dict:
+    """program -> (its lowering, that text's digest at this PR's parent,
+    7ea22af, computed there with these very functions): the programs a
+    sparse scoring launches beside the looped one, and one `match`, one
+    serve and one kNN launch of the families that never build an
+    `ImpactScorer` (PR 48's and PR 50's pins, held again)."""
+    import test_filtered_bool_deployment as text_programs
+    import test_knn_lead as knn_programs
+
+    return {
+        "finalize": (
+            _finalize_text,
+            "5c1d754b373a42950862a123ce2a567184f0418c5dfa9cd223091c3484ae587f"),
+        "impact_dense_add": (
+            _dense_add_text,
+            "4a83a7b5b20c7ddddd4142e8bdda19ae54ed048b77644b2bfc415f4d0a463639"),
+        "impact_zeros": (
+            lambda: impact_ops._impact_zeros.lower(
+                rows=2, width=1001).as_text(),
+            "ca46f5eb5b2969cd742b2fb121e96a3e6d8f058b66959dd35d274f4a32383bf3"),
+        "match_launch": text_programs.PARENT_PROGRAMS["match_launch"],
+        "serve_launch": text_programs.PARENT_PROGRAMS["serve_launch"],
+        "knn_bare_float_rows": knn_programs.SCAN_PROGRAMS[
+            "knn_bare_float_rows"],
+    }
+
+
+@pytest.mark.parametrize("program", [
+    "finalize", "impact_dense_add", "impact_zeros", "match_launch",
+    "serve_launch", "knn_bare_float_rows"])
+def test_programs_beside_the_looped_one_lower_to_the_parents_text(program):
+    import hashlib
+
+    lower, digest = _programs_left_alone()[program]
+    assert hashlib.sha256(lower().encode()).hexdigest() == digest
+
+
+# ---------------------------------------------------------------------------
 # serving: fp32 float parity, int8 recall gate, exact escape hatch
 # ---------------------------------------------------------------------------
 

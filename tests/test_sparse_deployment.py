@@ -114,8 +114,8 @@ class Deployment:
         self.frequent = by_df  # most frequent first
         corpus_mod = load_plugin("corpora", base["corpus"]["builder"])
         rng = np.random.default_rng(11)
-        # a query of the 128 most frequent tokens: several launches of
-        # TCHUNK tiles at one row
+        # a query of the 128 most frequent tokens: several trips of
+        # TILE_STEP tiles at one row
         w = corpus_mod.draw_weights(
             rng, np.full(128, 0.6), base["body"]["args"]["weights"])
         self.long_body = vector_body(self.field, {
@@ -247,29 +247,34 @@ def test_exact_total_is_counted_over_every_tile(dep, storage):
 
 
 @pytest.mark.parametrize("storage", STORAGES)
-def test_query_of_several_chunk_launches_stays_right(dep, storage):
-    """128 frequent tokens: more tiles than one launch carries at one
-    row, so the three staged planes are made launch after launch."""
-    from elasticsearch_tpu.ops.scoring import TCHUNK
+def test_query_of_several_trips_stays_right(dep, storage):
+    """128 frequent tokens: more tiles than one trip of the looped
+    program carries at one row. The int8 column (its hot terms on rows)
+    keeps under TILE_CAP tiles and scores them in ONE launch; the
+    float32 column's row is longer than TILE_CAP and takes a second."""
+    from elasticsearch_tpu.ops.impact import TILE_CAP, TILE_STEP, tile_trips
 
     before = dep.sparse_stats()
     served = dep.search(storage, dep.long_body)
     after = dep.sparse_stats()
     tiles = after["tiles_scored"] - before["tiles_scored"]
     launches = after["chunk_launches"] - before["chunk_launches"]
-    assert tiles > 3 * TCHUNK
-    assert launches >= -(-tiles // TCHUNK) >= 4
+    trips = after["tile_trips"] - before["tile_trips"]
+    assert tiles > 3 * TILE_STEP
+    assert (tiles > TILE_CAP) == (storage == "float32")
+    assert launches == -(-tiles // TILE_CAP) == 1 + (storage == "float32")
+    assert trips == tile_trips([range(tiles)]) >= 4
     dep.held(storage, dep.long_body, served)
 
 
-def test_no_launch_is_handed_a_plane_that_is_written_again(dep, monkeypatch):
+def test_no_launch_is_handed_a_plan_that_is_written_again(dep, monkeypatch):
     """A jitted call may read a host operand after it returns (the CPU
     backend aliases an aligned NumPy buffer), so `score_into` gives
-    every launch planes of its own: when the last launch is enqueued,
-    each launch's planes still hold that launch's own chunk."""
+    every launch a plan of its own: when the last launch is enqueued,
+    each launch's plan still holds that launch's own tiles."""
     from elasticsearch_tpu.ops import impact as impact_ops
-    from elasticsearch_tpu.ops.scoring import TCHUNK
 
+    cap = impact_ops.TILE_CAP
     sf = dep.corpus["segment"].sparse[dep.field]
     sc = impact_ops.ImpactScorer(sf.doc_ids, sf.qweights, DOCS)
     vector = dep.long_body["query"]["sparse_vector"]["query_vector"]
@@ -278,23 +283,29 @@ def test_no_launch_is_handed_a_plane_that_is_written_again(dep, monkeypatch):
     tiles = np.concatenate([np.arange(s, s + c) for s, c in
                             zip(starts, counts)])
     weights = np.repeat(tws, counts)
+    # the query four times over: a row past two launches' capacity
+    reps = -(-(2 * cap + 1) // len(tiles))
+    tiles, weights = np.tile(tiles, reps), np.tile(weights, reps)
     handed = []
     launch = impact_ops._impact_chunk_add
 
-    def recording(doc_ids, values, acc, cnt, ti, tw, tv):
-        handed.append((ti, tw, tv))
-        return launch(doc_ids, values, acc, cnt, ti, tw, tv)
+    def recording(doc_ids, values, acc, cnt, plan):
+        handed.append(plan)
+        return launch(doc_ids, values, acc, cnt, plan)
 
     monkeypatch.setattr(impact_ops, "_impact_chunk_add", recording)
     sc.score_into(*sc.new_acc(1), [tiles], [weights])
-    assert len(handed) == impact_ops.chunk_launches([tiles]) >= 4
-    assert len({id(p) for planes in handed for p in planes}) == 3 * len(handed)
-    for c, (ti, tw, tv) in enumerate(handed):
-        want = tiles[c * TCHUNK:(c + 1) * TCHUNK]
+    assert len(handed) == impact_ops.chunk_launches([tiles]) >= 3
+    assert not any(np.shares_memory(a, b) for i, a in enumerate(handed)
+                   for b in handed[i + 1:])
+    for c, plan in enumerate(handed):
+        want = tiles[c * cap:(c + 1) * cap]
         m = len(want)
-        assert (ti[0, :m] == want).all() and tv[0, :m].all()
-        assert not tv[0, m:].any()
-        assert (tw[0, :m] == weights[c * TCHUNK:c * TCHUNK + m]).all()
+        assert plan.shape == (2, 1, cap) and plan.dtype == np.int32
+        assert (plan[0, 0, :m] == want).all()
+        assert (plan[0, 0, m:] == -1).all()
+        assert (plan[1, 0, :m].view(np.float32)
+                == weights[c * cap:c * cap + m]).all()
 
 
 @pytest.mark.parametrize("storage", STORAGES)
@@ -423,10 +434,9 @@ def test_host_theta_needs_a_full_page_of_live_matches(dep, storage):
 def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
     """`transfer.scoring.*` moves by exactly what the job moved: the hot
     list's two planes where the query holds a term with a dense row,
-    three staged planes a chunk launch of the tile pass, and the packed
+    the one staged plan of the tile pass's launch, and the packed
     collect, the job's one download (theta is the host's)."""
-    from elasticsearch_tpu.ops.impact import DENSE_SLOTS
-    from elasticsearch_tpu.ops.scoring import TCHUNK
+    from elasticsearch_tpu.ops.impact import DENSE_SLOTS, TILE_CAP, TILE_STEP
 
     body = {"full_shape": dep.proved_body, "long": dep.long_body,
             "pruning": vector_body(dep.field, dep.pruning_vectors["int8"]),
@@ -440,14 +450,16 @@ def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
     tiles = s1["tiles_scored"] - s0["tiles_scored"]
     launches = s1["chunk_launches"] - s0["chunk_launches"]
     dense = s1["dense_launches"] - s0["dense_launches"]
-    assert launches == -(-tiles // TCHUNK) >= (which != "hot")
+    assert launches == -(-tiles // TILE_CAP) == (which != "hot")
+    assert (s1["tile_trips"] - s0["tile_trips"] == -(-tiles // TILE_STEP)
+            == spans["dispatch"]["tags"]["trips"])
     assert dense == (which != "rare")
     # theta is computed where a total is proved AND some tile could drop
     assert s1["theta_host"] - s0["theta_host"] == (which not in ("rare",
                                                                  "hot"))
-    assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches + 2 * dense
+    assert x1["h2d_count"] - x0["h2d_count"] == launches + 2 * dense
     assert (x1["h2d_bytes"] - x0["h2d_bytes"]
-            == launches * rows * TCHUNK * (4 + 4 + 1)
+            == launches * rows * TILE_CAP * (4 + 4)
             + dense * rows * DENSE_SLOTS * (4 + 4))
     assert x1["d2h_count"] - x0["d2h_count"] == 1
     assert (x1["d2h_bytes"] - x0["d2h_bytes"]
@@ -456,32 +468,36 @@ def test_every_transfer_of_a_sparse_job_is_counted(dep, which):
 
 @pytest.mark.parametrize("rows", [1, 4])
 @pytest.mark.parametrize("tiles", [0, 1, 512, 513, 1300])
-def test_staged_planes_are_noted_as_the_launches_upload_them(dep, rows,
-                                                             tiles):
-    """The three planes of ALL a scoring's chunk launches are staged at
-    once; what is noted stays what each launch uploads: three transfers
-    and 4,608 B a query row a launch (512 tiles x (4 + 4 + 1) B), and
-    the row launch's two planes, 512 B a query row."""
+def test_staged_plans_are_noted_as_the_launches_upload_them(dep, rows,
+                                                            tiles):
+    """The plans of ALL a scoring's chunk launches are staged at once;
+    what is noted stays what each launch uploads: one transfer of
+    TILE_CAP x (4 + 4) B a query row a launch (tile ids, weight bits),
+    and the row launch's two planes, 512 B a query row."""
     from elasticsearch_tpu.ops import impact as impact_ops
 
+    cap = impact_ops.TILE_CAP
     sf = dep.corpus["segment"].sparse[dep.field]
     sc = impact_ops.ImpactScorer(sf.doc_ids, sf.qweights, DOCS)
     tl = np.arange(tiles, dtype=np.int64) % sf.n_tiles
     lists = [tl[: tiles // (j + 1)] for j in range(rows)]
     weights = [np.full(len(t), 0.5, np.float32) for t in lists]
-    launches = -(-tiles // 512)
+    launches = -(-tiles // cap)
     x0 = tracing.transfer_stats()
     staged = sc.stage_chunks(rows, lists, weights)
     assert tracing.transfer_stats() == x0  # staging uploads nothing
-    assert [p.shape for p in staged] == [(launches, rows, 512)] * 3
+    assert staged.shape == (launches, 2, rows, cap)
+    assert staged.dtype == np.int32
     for j, t in enumerate(lists):  # each row's tiles, launch after launch
-        flat = staged[0][:, j].ravel()
+        flat = staged[:, 0, j].ravel()
         assert np.array_equal(flat[: len(t)], t)
-        assert staged[2][:, j].ravel().sum() == len(t)
+        assert (flat[len(t):] == -1).all()
+        assert (staged[:, 1, j].ravel()[: len(t)].view(np.float32)
+                == 0.5).all()
     acc, cnt = sc.add_chunks(*sc.new_acc(rows), staged)
     x1 = tracing.transfer_stats()
-    assert x1["h2d_count"] - x0["h2d_count"] == 3 * launches
-    assert x1["h2d_bytes"] - x0["h2d_bytes"] == 4608 * rows * launches
+    assert x1["h2d_count"] - x0["h2d_count"] == launches
+    assert x1["h2d_bytes"] - x0["h2d_bytes"] == 8 * cap * rows * launches
     assert x1["d2h_count"] == x0["d2h_count"]
     assert acc.shape == cnt.shape == (rows, DOCS + 1)
     held = dep.server.cluster.indices[dep.index["int8"]]
@@ -539,7 +555,9 @@ def test_the_request_goes_the_normal_path(dep):
     assert tags["tiles_pruned"] == (after["tiles_pruned"]
                                     - before["tiles_pruned"])
     assert tags["chunk_launches"] == (after["chunk_launches"]
-                                      - before["chunk_launches"]) >= 1
+                                      - before["chunk_launches"]) == 1
+    assert tags["trips"] == (after["tile_trips"]
+                             - before["tile_trips"]) >= 1
     assert tags["dense_rows"] == (after["dense_rows_scored"]
                                   - before["dense_rows_scored"]) >= 1
     assert tags["tiles_dense"] == (after["tiles_dense"]
