@@ -46,7 +46,7 @@ from ..search.admission import (
     apply_brownout,
 )
 from ..search.coordinator import _col_key
-from ..search.executor import NumpyExecutor, ShardReader
+from ..search.executor import NumpyExecutor, ShardReader, TopDocs
 from ..search.failures import (
     SearchTimeoutError,
     deadline_from,
@@ -1530,6 +1530,26 @@ class IndexService:
                     f"search_after has {len(search_after)} value(s) but sort "
                     f"has {len(sort_specs)}"
                 )
+        # ---- a `rescore` widens the shard's first stage to its window:
+        # upstream's query phase collects max(from + size, window_size)
+        # documents a shard when a rescore is present, the rescore
+        # orders the window, and the page (`page_k`) is cut afterwards.
+        # The window's own cut is Lucene's, exact ties by lowest doc id
+        # (`exact_window`: the match family's `_window_topk`); a
+        # retriever's `standard` leg that feeds `_rescore_ranked` asks
+        # for the same cut of its whole page (`_exact_window`) ----
+        page_k = k
+        rescore_spec = None
+        if "rescore" in body and sort_specs is None:
+            from ..search import rescorer
+
+            rescore_spec = rescorer.parse_rescore(body, validate_size=False)
+            if rescore_spec is not None:
+                k = max(k, int(rescore_spec.window_size))
+        exact_window = (
+            int(rescore_spec.window_size) if rescore_spec is not None
+            else k if body.get("_exact_window") else 0
+        )
         query = dsl.parse_query(body["query"]) if "query" in body else None
         knn_body = body.get("knn")
         knn = None
@@ -1662,6 +1682,7 @@ class IndexService:
                         job = self._batcher.submit_nowait(
                             ex, plan, k, kind=kind, query=query,
                             deadline=shard_deadline, prof=prof_phases,
+                            window=exact_window if kind == "match" else 0,
                         )
                         if tr is not None:
                             # the shard's entry -> the job's submit mark,
@@ -1819,26 +1840,34 @@ class IndexService:
         # re-sorted page. Any rerank-path failure keeps the
         # first-stage ranking (deterministic fallback, never a failed
         # request). ----
-        if (
-            "rescore" in body
-            and sort_specs is None
-            and td is not None
-            and td.hits
-        ):
-            from ..search import rescorer
-
-            rescore_spec = rescorer.parse_rescore(body, validate_size=False)
-            if rescore_spec is not None:
-                t_resc = time.perf_counter_ns()
+        if rescore_spec is not None and td is not None and td.hits:
+            t_resc = time.perf_counter_ns()
+            # the `rescore` span: the whole second stage on this thread;
+            # the rerank job's spans and `rerank_plan` are its children
+            tr, resc_id = tracing.reserve()
+            candidates = len(td.hits)
+            with tracing.under(resc_id):
                 td = self._apply_rescore(
                     ex, rescore_spec, td, sid, shard_deadline, task,
                     prof=prof_phases,
                 )
-                if prof_phases is not None:
-                    prof_phases["rescore_ns"] = (
-                        prof_phases.get("rescore_ns", 0)
-                        + time.perf_counter_ns() - t_resc
-                    )
+            if len(td.hits) > page_k:
+                # the page, cut AFTER the window was ordered
+                td = TopDocs(
+                    total=td.total, hits=td.hits[:page_k],
+                    max_score=td.max_score, relation=td.relation,
+                )
+            t_resc_end = time.perf_counter_ns()
+            if tr is not None:
+                tr.add_span(
+                    "rescore", t_resc, t_resc_end, span_id=resc_id,
+                    window=int(rescore_spec.window_size),
+                    candidates=candidates,
+                )
+            if prof_phases is not None:
+                prof_phases["rescore_ns"] = (
+                    prof_phases.get("rescore_ns", 0) + t_resc_end - t_resc
+                )
 
         # ---- folded fetch phase: sources + highlight for this shard's
         # candidates (FetchPhase, SURVEY.md §3.3) ----
@@ -2659,6 +2688,7 @@ class IndexService:
                 return None
         if plan is None:
             return None
+        k_mesh = from_ + size
         if "rescore" in body:
             # fused mesh rescore: only flat match plans carry it (knn +
             # rescore stays on the shard path), and only when the
@@ -2685,9 +2715,13 @@ class IndexService:
                     # rides the MatchPlan into the batcher group key:
                     # different specs / page sizes never share a launch
                     # (MatchPlan is frozen — attach out-of-band)
+                    # every entry's first stage collects the WINDOW
+                    # (as a shard's does: `_shard_search`), the merged
+                    # page is cut below
+                    k_mesh = max(k_mesh, int(spec.window_size))
                     object.__setattr__(plan, "rescore", (model, spec))
                     object.__setattr__(
-                        plan, "rescore_sig", (model, spec, from_ + size)
+                        plan, "rescore_sig", (model, spec, k_mesh)
                     )
         from ..parallel.mesh_executor import MeshUnavailable
         from ..tasks import TaskCancelledException
@@ -2699,7 +2733,7 @@ class IndexService:
         try:
             with tracing.under(mesh_id):
                 job = self._batcher.submit_nowait(
-                    mesh, plan, from_ + size, kind=kind, prof=mesh_prof,
+                    mesh, plan, k_mesh, kind=kind, prof=mesh_prof,
                 )
             td = QueryBatcher.wait(job)
         except MeshUnavailable as e:
@@ -3315,6 +3349,7 @@ class IndexService:
         if isinstance(ex, NumpyExecutor):
             # the numpy backend IS the float oracle
             return rescorer.host_rescore_topdocs(ex.reader, model, spec, td)
+        t_plan = time.perf_counter_ns()
         plan = rescorer.build_plan(
             ex.reader, model, spec,
             [(h.score, h.segment, h.local_doc) for h in td.hits],
@@ -3324,6 +3359,13 @@ class IndexService:
                 ex, plan, len(td.hits), kind="rerank",
                 deadline=shard_deadline, prof=prof,
             )
+            if job.trace is not None:
+                # the candidates and the query matrix as arrays, up to
+                # the job's submit mark, where its `queue_wait` starts
+                job.trace.add_span(
+                    "rerank_plan", t_plan, job.t_enq,
+                    candidates=len(td.hits), query_vectors=len(plan.qtoks),
+                )
             got = self._wait_batched(job, sid, shard_deadline, task)
         except (
             SearchTimeoutError,
@@ -3525,6 +3567,16 @@ class IndexService:
             except KeyError:
                 pins = None
         window = max(from_ + size, 10)
+        rescore_spec = None
+        if "rescore" in body:
+            from ..search import rescorer
+
+            # the rescore's window widens what the retriever ranks, as
+            # a shard's first stage (`_shard_search`): the page is cut
+            # after the window was ordered
+            rescore_spec = rescorer.parse_rescore(body)
+            if rescore_spec is not None:
+                window = max(window, int(rescore_spec.window_size))
         # the new kwargs ride only on profiled requests so external
         # wrappers of the original signatures keep working
         tr, retr_id = tracing.reserve()
@@ -3532,6 +3584,8 @@ class IndexService:
             ranked = self._run_retriever(
                 body["retriever"], window, size, extra_filter, pins,
                 **({"prof_out": prof} if prof is not None else {}),
+                **({"exact_window": True} if rescore_spec is not None
+                   else {}),
             )
         m_retr = time.perf_counter_ns()
         # what upstream reports for a ranked search: the total of the
@@ -3540,18 +3594,14 @@ class IndexService:
             "value": len(ranked), "relation": "eq",
         }
         where = getattr(ranked, "where", {})
-        if "rescore" in body and ranked:
-            from ..search import rescorer
-
-            rescore_spec = rescorer.parse_rescore(body)
-            if rescore_spec is not None:
-                # second stage over the FUSED candidates (the RAG
-                # shape: filtered hybrid retrieval → rerank → fetch);
-                # sources are fetched below, after the window re-sort
-                ranked = self._rescore_ranked(
-                    rescore_spec, ranked, pins,
-                    **({"prof": prof} if prof is not None else {}),
-                )
+        if rescore_spec is not None and ranked:
+            # second stage over the FUSED candidates (the RAG shape:
+            # filtered hybrid retrieval → rerank → fetch); sources are
+            # fetched below, after the window re-sort
+            ranked = self._rescore_ranked(
+                rescore_spec, ranked, pins,
+                **({"prof": prof} if prof is not None else {}),
+            )
         m_resc = time.perf_counter_ns()
         page = ranked[from_ : from_ + size]
         from ..search.executor import filter_source
@@ -3647,13 +3697,18 @@ class IndexService:
     def _run_retriever(
         self, ret: dict, window: int, size: int,
         extra_filter: Optional[dict], pins=None, prof_out=None,
+        exact_window: bool = False,
     ) -> List[tuple]:
-        """ranked [(doc_id, score)] for one retriever node (sync)."""
+        """ranked [(doc_id, score)] for one retriever node (sync).
+        `exact_window`: the ranks feed a rescore window, so a `standard`
+        leg's page is cut Lucene's way (`_shard_search`)."""
         if not isinstance(ret, dict) or len(ret) != 1:
             raise dsl.QueryParseError("[retriever] malformed")
         kind, params = next(iter(ret.items()))
         if kind == "standard":
             sub = {"size": window, "_source": False}
+            if exact_window:
+                sub["_exact_window"] = True
             if prof_out is not None:
                 # sub-search rides the (parity-tested) profiled search
                 # path; its profile block becomes this leg's breakdown

@@ -84,7 +84,18 @@ starts at `http`'s start and reaches the ring when `http` ends:
     shard_search is tiled plan | queue_wait | dispatch | inflight |
     collect | wake | fetch; what is left of it is the hand-over between
     them. A `leg:<label>` of an rrf retriever (or `mesh_search`) holds
-    the four job spans alone.
+    the four job spans alone. Under a `rescore` the shard's second
+    stage stands between `wake` and `fetch`:
+        rescore [window, candidates]   the first stage's TopDocs -> the
+            rescored page, on the request thread; tiled
+            rerank_plan [candidates, query_vectors] (`build_plan`: the
+            window's doc ids and scores as arrays, the query matrix; ->
+            the rerank job's submit mark) | the `rerank` job's
+            queue_wait | dispatch | inflight | collect | wake, what is
+            left the permutation applied and the page cut. The FIRST
+            stage's `collect` span then carries window and
+            ties_refilled (`QueryBatcher._window_topk`: the window's
+            cut, Lucene's).
         plan [family, planned; a serve plan also filtered, negated:
             the bool brought a planned `filter` / `must_not`]   the
             shard's entry -> the job's submit

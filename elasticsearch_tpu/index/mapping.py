@@ -182,10 +182,25 @@ class Mappings:
                 f"pruning_ratio on field [{path}] must be in [0, 1), "
                 f"got [{f.pruning_ratio}]"
             )
-        if ftype == DENSE_VECTOR and f.element_type not in VECTOR_ELEMENT_TYPES:
+        if (
+            ftype in (DENSE_VECTOR, RANK_VECTORS)
+            and f.element_type not in VECTOR_ELEMENT_TYPES
+        ):
             raise MappingParseError(
                 f"invalid element_type [{f.element_type}] on field [{path}]; "
                 f"available types are {list(VECTOR_ELEMENT_TYPES)}"
+            )
+        if (
+            ftype == RANK_VECTORS
+            and f.element_type == "byte"
+            and f.similarity != "dot_product"
+        ):
+            # the stored bytes ARE the values (no unit-normalized twin,
+            # no scales): only the raw dot product reads them as they are
+            raise MappingParseError(
+                f"rank_vectors field [{path}] with element_type [byte] "
+                f"supports similarity [dot_product] only, got "
+                f"[{f.similarity}]"
             )
         if ftype == DENSE_VECTOR and f.dims <= 0:
             # ES infers dims from the first vector if unset; we allow that too
@@ -691,6 +706,10 @@ class DocumentParser:
                         f"rank_vectors field [{path}] must hold an array "
                         "of vectors"
                     )
+                if f.element_type == "byte":
+                    why = byte_vector_error(row)
+                    if why is not None:
+                        raise MappingParseError(f"[{path}]: {why}")
                 vec = [float(x) for x in row]
                 if f.dims and len(vec) != f.dims:
                     raise MappingParseError(

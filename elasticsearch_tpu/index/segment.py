@@ -345,7 +345,11 @@ class MultiVectorField:
     blocks, so rows stay contiguous per doc; cosine fields store rows
     unit-normalized at build (maxsim over unit rows = cosine maxsim)."""
 
-    tok_vectors: np.ndarray  # float32[total_tokens, dims]
+    # float32[total_tokens, dims], or int8[total_tokens, dims] of an
+    # `element_type: byte` field (the stored bytes ARE the values: no
+    # normalized twin, no scales; host and device hold one byte an
+    # element and the kernels cast)
+    tok_vectors: np.ndarray
     tok_offsets: np.ndarray  # int32[N+1]
     exists: np.ndarray  # bool[N]
     similarity: str
@@ -355,6 +359,53 @@ class MultiVectorField:
         if len(self.tok_offsets) <= 1:
             return 0
         return int(np.diff(self.tok_offsets).max())
+
+    @property
+    def element_type(self) -> str:
+        return "byte" if self.tok_vectors.dtype == np.int8 else "float"
+
+
+def stored_token_rows(mat, mf, similarity: str) -> np.ndarray:
+    """One document's `rank_vectors` matrix as its mapping's
+    `element_type` stores it: float32 rows (unit-normalized for cosine),
+    `byte` rows as int8 (the mapper admitted whole numbers in
+    [-128, 127] only, so the cast loses nothing)."""
+    if mf is not None and getattr(mf, "element_type", "float") == "byte":
+        return np.asarray(mat, dtype=np.float32).astype(np.int8)
+    arr = np.asarray(mat, dtype=np.float32)
+    return _unit_normalize(arr) if similarity == "cosine" else arr
+
+
+def byte_multi_vector_field(
+    tok_bytes: np.ndarray, tok_offsets: np.ndarray,
+    similarity: str = "dot_product",
+) -> MultiVectorField:
+    """The byte form of a `rank_vectors` column from a prebuilt plane:
+    int8[total_tokens, dims] rows and their int32[N+1] CSR offsets, held
+    as they are (no copy, no float32 on the host). What a refresh of an
+    `element_type: byte` field builds row by row, for a plane too large
+    to go through `_bulk`."""
+    if tok_bytes.dtype != np.int8 or tok_bytes.ndim != 2:
+        raise ValueError(
+            "a byte rank_vectors plane is int8[total_tokens, dims], got "
+            f"{tok_bytes.dtype}{list(tok_bytes.shape)}"
+        )
+    offsets = np.ascontiguousarray(tok_offsets, np.int32)
+    if len(offsets) < 1 or int(offsets[-1]) != len(tok_bytes):
+        raise ValueError(
+            "tok_offsets must run from 0 to the plane's row count "
+            f"({len(tok_bytes)})"
+        )
+    if similarity != "dot_product":
+        raise ValueError(
+            "a byte rank_vectors field supports [dot_product] only"
+        )
+    return MultiVectorField(
+        tok_vectors=tok_bytes,
+        tok_offsets=offsets,
+        exists=np.diff(offsets) > 0,
+        similarity=similarity,
+    )
 
 
 @dataclass
@@ -1046,9 +1097,7 @@ class SegmentBuilder:
             for local_id, d in enumerate(docs):
                 mat = d.multi_vectors.get(fname)
                 if mat:
-                    arr = np.asarray(mat, dtype=np.float32)
-                    if sim == "cosine":
-                        arr = _unit_normalize(arr)
+                    arr = stored_token_rows(mat, mf, sim)
                     chunks.append(arr)
                     total += len(arr)
                     exists[local_id] = True
@@ -1056,7 +1105,7 @@ class SegmentBuilder:
             tok = (
                 np.concatenate(chunks, axis=0)
                 if chunks
-                else np.zeros((0, dims), np.float32)
+                else stored_token_rows(np.zeros((0, dims)), mf, sim)
             )
             multi_vectors[fname] = MultiVectorField(
                 tok_vectors=tok,

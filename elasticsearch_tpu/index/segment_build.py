@@ -54,6 +54,7 @@ from .segment import (
     _unit_normalize,
     sparse_plan,
     stored_rows,
+    stored_token_rows,
 )
 
 # ---------------------------------------------------------------------------
@@ -331,15 +332,13 @@ def _device_build(builder: SegmentBuilder) -> Segment:
         for local_id, d in enumerate(docs):
             mat = d.multi_vectors.get(fname)
             if mat:
-                arr = np.asarray(mat, dtype=np.float32)
-                if sim == "cosine":
-                    arr = _unit_normalize(arr)
+                arr = stored_token_rows(mat, mf, sim)
                 chunks.append(arr)
                 counts[local_id] = len(arr)
         tok = (
             np.concatenate(chunks, axis=0)
             if chunks
-            else np.zeros((0, dims), np.float32)
+            else stored_token_rows(np.zeros((0, dims)), mf, sim)
         )
         nb = _charge_build(ib.bucket_pow2(n) * 8)
         try:

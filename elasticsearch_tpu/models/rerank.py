@@ -42,6 +42,14 @@ class RerankModel:
     dims: int
     similarity: str  # dot_product | cosine (rows unit-normalized at build)
     quantized: bool
+    # `byte`: the column holds the mapped bytes themselves (int8 rows, no
+    # scales, never quantized again) and the kernel states its precision
+    element_type: str = "float"
+
+    @property
+    def element_bytes(self) -> int:
+        """Bytes one stored element takes on the device."""
+        return 1 if self.quantized or self.element_type == "byte" else 4
 
 
 def resolve_model(mappings, settings, field: str) -> Optional[RerankModel]:
@@ -50,12 +58,17 @@ def resolve_model(mappings, settings, field: str) -> Optional[RerankModel]:
     mf = mappings.get(field)
     if mf is None or mf.type != RANK_VECTORS:
         return None
-    quant = str(settings.get("rerank.quantization", "none")) == "int8"
+    element_type = getattr(mf, "element_type", "float")
+    quant = (
+        element_type != "byte"
+        and str(settings.get("rerank.quantization", "none")) == "int8"
+    )
     return RerankModel(
         field=field,
         dims=int(mf.dims),
         similarity=mf.similarity,
         quantized=quant,
+        element_type=element_type,
     )
 
 
@@ -66,11 +79,13 @@ def resolve_model(mappings, settings, field: str) -> Optional[RerankModel]:
 
 def host_maxsim(
     query_vecs: np.ndarray,  # f32 [Qt, d]
-    doc_toks: np.ndarray,  # f32 [T, d] (unit rows for cosine fields)
+    doc_toks: np.ndarray,  # f32 [T, d] (unit rows for cosine fields),
+    # or the int8 rows of a byte field: the bytes are the values
 ) -> float:
     """Σ_q max_t q·d_t — 0.0 for docs without tokens (a candidate
     missing the rank_vectors field contributes nothing, so its blended
-    score reduces to query_weight · first_stage)."""
+    score reduces to query_weight · first_stage). Products and sums in
+    float32, a byte field's rows cast exactly."""
     if doc_toks.shape[0] == 0:
         return 0.0
     dots = query_vecs.astype(np.float32) @ doc_toks.astype(np.float32).T
@@ -134,16 +149,64 @@ RESCORE_STATS = {
     "fallbacks": 0,  # rerank-path failures → first-stage ranking
     "kernel_ms": 0.0,  # Σ maxsim kernel wall time (dispatch+collect)
     "windows": {},  # window-size histogram (post-clamp, str keys)
+    # every request that entered the rescore phase, and those of them
+    # answered in their first-stage order (`skipped` + `fallbacks`: at a
+    # deployment's size a wrong answer with HTTP 200, so it is readable)
+    "requests": 0,
+    "first_stage_kept": 0,
+    # column builds the HBM budget refused (every request of that
+    # generation is then `skipped`)
+    "columns_refused": 0,
+    # the device launches' work (`note_launch`): launches, candidates
+    # rescored, the token rows those candidates own, the slots the
+    # rectangular gather touched (rows x window bucket x tmax) and those
+    # of them that hold no token, and what ANY implementation must read
+    # (`least_bytes`: the owned rows at their stored width + CSR bounds)
+    "launches": 0,
+    "windows_docs": 0,
+    "tokens_scored": 0,
+    "slots_gathered": 0,
+    "slots_padded": 0,
+    "least_bytes": 0,
+    # first-stage windows whose tie group at the last rank ran past the
+    # fetched bucket and was refilled by lowest doc id
+    "window_ties_refilled": 0,
 }
+
+
+def least_bytes(tokens: int, candidates: int, dims: int,
+                element_bytes: int) -> int:
+    """What a MaxSim rescore of `candidates` documents owning `tokens`
+    token rows must read whatever gathers them: the rows once at their
+    stored width and two int32 CSR bounds a candidate."""
+    return tokens * dims * element_bytes + 8 * candidates
 
 
 def note(key: str, n: int = 1) -> None:
     with _STATS_LOCK:
         RESCORE_STATS[key] += n
+        if key in ("skipped", "fallbacks"):
+            RESCORE_STATS["requests"] += n
+            RESCORE_STATS["first_stage_kept"] += n
+
+
+def note_launch(candidates: int, tokens: int, slots: int, dims: int,
+                element_bytes: int) -> None:
+    """One maxsim launch's work, counted from the host's own CSR
+    offsets (no download)."""
+    with _STATS_LOCK:
+        RESCORE_STATS["launches"] += 1
+        RESCORE_STATS["windows_docs"] += candidates
+        RESCORE_STATS["tokens_scored"] += tokens
+        RESCORE_STATS["slots_gathered"] += slots
+        RESCORE_STATS["slots_padded"] += slots - tokens
+        RESCORE_STATS["least_bytes"] += least_bytes(
+            tokens, candidates, dims, element_bytes)
 
 
 def note_rescore(window: int, device: bool, kernel_ms: float = 0.0) -> None:
     with _STATS_LOCK:
+        RESCORE_STATS["requests"] += 1
         RESCORE_STATS["device_rescores" if device else "host_rescores"] += 1
         RESCORE_STATS["kernel_ms"] += kernel_ms
         w = str(int(window))

@@ -18,6 +18,21 @@ cluster-gather trick). The int8 twin stores per-token symmetric scales
 `(q · v_int8) · scale` in float32 — the exact float path the host
 oracle `host_maxsim_quantized` reproduces.
 
+Precision of a BYTE column (`element_type: byte`: int8 rows, no scales;
+the bytes ARE the values): stated, not the einsum's default. The
+gathered bytes are exact in bfloat16; the float32 query row is split
+into three bfloat16 parts whose sum is the float32 value (`split_bf16`)
+and the three are contracted against the bytes in ONE bfloat16 MXU
+pass with float32 accumulation, then added in float32. Every product
+is exact (8 x 8 significant bits) and only the float32 sums round: the
+result is float32 of sum_i max_j q_i . d_j to a few ulps, where the
+default precision would round the query to ONE bfloat16 part (4e-3
+relative a component). Its temporaries are bounded whatever the
+launch's rows: the rows are walked (`lax.map`), so a launch allocates
+what ONE row needs (window bucket x tmax x d bytes gathered, twice
+that in bfloat16, 3 x Qt x window x tmax float32 products: 23.6 MB +
+47 MB + 71 MB at 1,024 x 180 x 128 and 32 query rows).
+
 Ordering contract: the rescore window is re-sorted by blended score
 desc with ties broken by FIRST-STAGE rank asc (lax.top_k is stable, so
 equal blended scores keep their incoming order — candidates arrive
@@ -34,6 +49,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .scoring import _to_host
 
 
 def rerank_flops(
@@ -59,6 +76,15 @@ def maxsim_candidates(
     d = jnp.clip(docs, 0, starts.shape[0] - 1)
     st = jnp.take(starts, d)  # [B, W]
     ct = jnp.take(counts, d)
+    if toks.dtype == jnp.int8 and scales is None:
+        # a byte column: exact products at the stated precision, one
+        # query row's temporaries at a time
+        def one(args):
+            return _maxsim_bytes_row(*args, toks, tmax)
+
+        if qtoks.shape[0] == 1:
+            return one((qtoks[0], qvalid[0], st[0], ct[0]))[None]
+        return jax.lax.map(one, (qtoks, qvalid, st, ct))
     off = jnp.arange(tmax, dtype=jnp.int32)
     slot = st[:, :, None] + off[None, None, :]  # [B, W, T]
     slot = jnp.clip(slot, 0, toks.shape[0] - 1)
@@ -73,6 +99,40 @@ def maxsim_candidates(
     per_q = jnp.where(jnp.isfinite(per_q), per_q, 0.0)
     per_q = jnp.where(qvalid[:, :, None], per_q, 0.0)
     return per_q.sum(axis=1)  # [B, W]
+
+
+def split_bf16(x: jax.Array) -> jax.Array:
+    """f32 [..., d] -> bf16 [3, ..., d] whose float32 sum is `x`: the
+    leading 8 significant bits, the next 8 and the last 8."""
+    def head(v):
+        # rounds to bfloat16's 8 significant bits and STAYS float32: a
+        # convert there and back is what a compiler may take out
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+    hi = head(x)
+    mid = head(x - hi)
+    lo = head((x - hi) - mid)
+    return jnp.stack([hi, mid, lo]).astype(jnp.bfloat16)  # exact casts
+
+
+def _maxsim_bytes_row(q, qv, st, ct, toks, tmax: int) -> jax.Array:
+    """One query row against its candidates' byte rows: q f32 [Qt, d],
+    qv bool [Qt], st / ct i32 [W], toks int8 [Tflat, d] -> f32 [W]."""
+    qt = q.shape[0]
+    off = jnp.arange(tmax, dtype=jnp.int32)
+    slot = jnp.clip(st[:, None] + off[None, :], 0, toks.shape[0] - 1)
+    tok_ok = off[None, :] < ct[:, None]  # [W, T]
+    tv = jnp.take(toks, slot, axis=0).astype(jnp.bfloat16)  # exact
+    parts = jnp.einsum(
+        "qd,wtd->qwt", split_bf16(q).reshape(3 * qt, -1), tv,
+        preferred_element_type=jnp.float32,
+    )  # [3 Qt, W, T]: exact products, float32 sums
+    dots = (parts[:qt] + parts[qt : 2 * qt]) + parts[2 * qt :]
+    dots = jnp.where(tok_ok[None, :, :], dots, -jnp.inf)
+    per_q = dots.max(axis=2)  # [Qt, W]
+    per_q = jnp.where(jnp.isfinite(per_q), per_q, 0.0)
+    per_q = jnp.where(qv[:, None], per_q, 0.0)
+    return per_q.sum(axis=0)
 
 
 def blend_and_sort(
@@ -157,7 +217,7 @@ def maxsim_rescore_batch(
 
 def unpack_rescore(packed) -> Tuple[np.ndarray, np.ndarray]:
     """The ONE packed download: (scores f32 [B, W], perm i32 [B, W])."""
-    out = np.asarray(packed)
+    out = _to_host(packed)  # noted: `transfer.scoring`, a `download` span
     w = out.shape[1] // 2
     scores = out[:, :w].copy().view(np.float32)
     perm = out[:, w:]

@@ -253,9 +253,10 @@ def _threshold(acc, live, k, block_size):
     return theta, accmax
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _finalize(acc, cnt, live, msm, k):
-    """(scores[B,k], docs[B,k], totals[B]); score desc / doc asc."""
+@functools.partial(jax.jit, static_argnames=("k", "tie_window"))
+def _finalize(acc, cnt, live, msm, k, tie_window=0):
+    """(scores[B,k], docs[B,k], totals[B]); score desc / doc asc. With
+    `tie_window` a fourth, `window_tie_refill`'s i32[B,k]."""
     a = acc[:, :-1]
     n = a.shape[1]
     if cnt is None:
@@ -266,7 +267,10 @@ def _finalize(acc, cnt, live, msm, k):
         mask = mask & live[None, :]
     masked = jnp.where(mask, a, -jnp.inf)
     s, d = jax.lax.top_k(masked, min(k, n))
-    return s, d, mask.sum(axis=1, dtype=jnp.int32)
+    totals = mask.sum(axis=1, dtype=jnp.int32)
+    if tie_window:
+        return s, d, totals, window_tie_refill(masked, s, tie_window)
+    return s, d, totals
 
 
 class ChunkedScorer:
@@ -357,18 +361,23 @@ class ChunkedScorer:
         s, d, tot = self.finalize_device(acc, cnt, msm, k, live=live)
         return _to_host(s), _to_host(d), _to_host(tot)
 
-    def finalize_device(self, acc, cnt, msm: np.ndarray, k: int, live=None):
+    def finalize_device(self, acc, cnt, msm: np.ndarray, k: int, live=None,
+                        tie_window: int = 0):
         """Like finalize() but the (scores, docs, totals) triple STAYS on
         device, so the cross-segment merge kernel can consume it with no
-        per-segment host sync."""
+        per-segment host sync. `tie_window` (a rescore's first stage):
+        a fourth array, `window_tie_refill`'s."""
         note_transfer("h2d", 4 * len(msm))
+        k = min(k, self.n_docs)
         with launch("_finalize", 1, 4 * len(msm)):
             return _finalize(
                 acc,
                 cnt,
                 live if live is not None else self.live,
                 jnp.asarray(msm, jnp.int32),
-                k=min(k, self.n_docs),
+                k=k,
+                **({"tie_window": min(int(tie_window), k)}
+                   if tie_window else {}),
             )
 
 
@@ -862,7 +871,8 @@ class MultiFusedScorer:
 
     def search_async(self, plans, k: int, combine: str, tie, live=None,
                      staging=None, rows=None, counted: bool = True,
-                     fmask=None, negated: bool = False):
+                     fmask=None, negated: bool = False,
+                     tie_window: int = 0):
         """Launches the fused kernel WITHOUT waiting for the result:
         returns (device_out, k) for decode_result(). Device dispatch is
         async in jax, so a caller can launch several groups (e.g. the
@@ -878,7 +888,8 @@ class MultiFusedScorer:
         plan of `pack_filter_plans`, the field's bit-row plane or None)
         and `negated` as `_fused_query_mf` takes them; with `fmask` the
         packed row ends in one more int32, the documents the row's
-        filter passed."""
+        filter passed. `tie_window` (a rescore's first stage): the
+        packed row ends in k more int32, `window_tie_refill`'s."""
         k = min(k, self.n_docs)
         shape = self.plan_shape_rows(rows or BPAD)
         buf = staging("fused_plan", shape, np.int32) if staging else None
@@ -899,6 +910,8 @@ class MultiFusedScorer:
                 host_operands + 1, h2d_bytes + fmask[1].nbytes)
         if negated:
             special["negated"] = True
+        if tie_window:
+            special["tie_window"] = min(int(tie_window), k)
         flops = sum(
             text_plan_flops(len(rt), len(hr), self.n_docs)
             for field_plans, _msm in plans
@@ -944,13 +957,75 @@ def decode_result(pending, extra: int = 0):
     return scores, docs, totals
 
 
+# ---------------------------------------------------------------------------
+# A rescore window's cut, Lucene's: the first `window` by (score desc, doc
+# asc). `lax.top_k` on the TPU returns exact ties in no particular order
+# and, at the cut, not the lowest-index members; `rank_order` repairs the
+# order of what was fetched, not WHICH members of a tie group the cut
+# split were fetched. For a page that is forgiven (a tie the page cuts may
+# fall either way); a window decides which documents are rescored at all.
+# So a first stage that feeds a window (`tie_window`) also returns, where
+# the tie group at the window's last rank runs past the k it fetched, the
+# k LOWEST doc ids at that score (`window_tie_refill`, a second selection
+# under a `cond`: a launch no row of which needs it pays a compare), and
+# the host takes the group's members from there (`window_cut`).
+# ---------------------------------------------------------------------------
+
+
+def window_tie_refill(masked, top_s, window: int):
+    """i32[B, k]: for a row whose k fetched all score >= its `window`-th
+    score theta AND whose k-th equals theta (the tie group at the
+    window's edge may run past the fetch), the k lowest doc ids scoring
+    exactly theta, ascending, -1 past the group; -1 everywhere for a
+    row that needs none. `masked` f32[B, n] (-inf = no match), `top_s`
+    its `lax.top_k(masked, k)` scores."""
+    B, n = masked.shape
+    k = top_s.shape[1]
+    theta = top_s[:, min(window, k) - 1]
+    need = jnp.isfinite(theta) & (top_s[:, k - 1] == theta)
+    lowest = jnp.iinfo(jnp.int32).min
+
+    def refill(_):
+        key = jnp.where(
+            (masked == theta[:, None]) & need[:, None],
+            -jnp.arange(n, dtype=jnp.int32)[None, :], lowest)
+        neg, _ = jax.lax.top_k(key, k)
+        return jnp.where(neg == lowest, -1, -neg)
+
+    return jax.lax.cond(
+        need.any(), refill, lambda _: jnp.full((B, k), -1, jnp.int32), None)
+
+
+def window_cut(scores: np.ndarray, docs: np.ndarray, refill: np.ndarray,
+               window: int):
+    """One row of one segment: its first `window` by (score desc, doc
+    asc), exactly, from the host rows of a top-k download put in rank
+    order (`rank_order`; -inf padding last) and the launch's refill.
+    -> (scores, docs, refilled)."""
+    k = len(scores)
+    n = int(np.isfinite(scores).sum())
+    w = min(window, k)
+    if n < k or scores[k - 1] != scores[w - 1]:
+        # everything that matched was fetched, or the tie group at the
+        # window's edge ends inside the fetch: the rank order is exact
+        return scores[: min(n, w)], docs[: min(n, w)], False
+    theta = scores[w - 1]
+    above = int((scores > theta).sum())
+    tied = refill[: w - above]
+    return (np.concatenate([scores[:above],
+                            np.full(len(tied), theta, scores.dtype)]),
+            np.concatenate([docs[:above], tied.astype(docs.dtype)]), True)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("t_rare", "n_hot", "k", "combine", "counted", "negated"),
+    static_argnames=("t_rare", "n_hot", "k", "combine", "counted", "negated",
+                     "tie_window"),
 )
 def _fused_query_mf(
     doc_ids_f, tfs_f, inv_norm_f, dense_f, live, plan, tie=None, wide_f=None,
     fmask=None, *, t_rare, n_hot, k, combine, counted=True, negated=False,
+    tie_window=0,
 ):
     """`counted` (the launch's) says whether any job holds documents to
     a count of clauses: with it a weight's sign says whether its term
@@ -965,7 +1040,11 @@ def _fused_query_mf(
     the rows' filter plan, the field's bit rows or None): every row's
     own mask is built here by `filter_row_masks` over the live
     documents and ANDed in, and the documents it passed are appended to
-    the packed row. Without either the program is the one it was."""
+    the packed row. Without either the program is the one it was.
+
+    `tie_window` (a rescore's first stage, whose first `tie_window`
+    ranks are a window cut Lucene's way): `window_tie_refill`'s k doc
+    ids are appended to the packed row, last."""
     F = len(doc_ids_f)
     n = inv_norm_f[0].shape[0]
     T, H = t_rare, n_hot
@@ -1038,6 +1117,8 @@ def _fused_query_mf(
     masked = jnp.where(mask, combined, -jnp.inf)
     top_s, top_d = jax.lax.top_k(masked, k)
     totals = mask.sum(axis=1, dtype=jnp.int32)
+    if tie_window:
+        cols.append(window_tie_refill(masked, top_s, tie_window))
     return jnp.concatenate(
         [
             jax.lax.bitcast_convert_type(top_s, jnp.int32),

@@ -738,17 +738,21 @@ class _Job:
     __slots__ = (
         "executor", "kind", "plan", "k", "query", "event", "result",
         "error", "deadline", "t_enq", "cold0", "prof", "trace", "parent",
-        "group", "t_done",
+        "group", "t_done", "window",
     )
 
     def __init__(
         self, executor, plan, k: int, kind: str = "match", query=None,
-        deadline: Optional[float] = None, prof=None,
+        deadline: Optional[float] = None, prof=None, window: int = 0,
     ):
         self.executor = executor
         self.kind = kind  # a key of FAMILIES
         self.plan = plan
         self.k = k
+        # > 0: the job's first `window` ranks feed a rescore window and
+        # are cut Lucene's way, exact ties at the cut by lowest doc id
+        # (the match family: `_window_topk`); rides the group key
+        self.window = int(window)
         self.query = query  # parsed Query node for per-segment fallback
         self.event = threading.Event()
         self.result: Optional[TopDocs] = None
@@ -837,7 +841,7 @@ class _Group:
         "family", "jobs", "rows", "express", "cold_s", "t_start",
         "t_dispatched", "t_collect", "t_unpack", "d2h0", "launches",
         "flops", "overflow", "compiles", "plan_tags", "merged",
-        "sub_spans", "unpacking",
+        "sub_spans", "unpacking", "collect_tags",
     )
 
     def __init__(self, family: str, jobs: int, rows: Optional[int],
@@ -871,6 +875,9 @@ class _Group:
         # a text or sparse group's `collect` span: whether its candidates
         # went through the merge program (`_group_topk`), else None
         self.merged: Optional[bool] = None
+        # more of the `collect` span: a rescore window's cut (`window`,
+        # `ties_refilled`: `_window_topk`)
+        self.collect_tags: Dict[str, int] = {}
         # program -> [start_ns, end_ns, seconds, built] compiled meanwhile
         self.compiles: Dict[str, list] = {}
         # `es.unpack` on the profiler's clock, open from the collect's
@@ -963,6 +970,7 @@ class _Group:
                     "d2h_bytes": thread_d2h_bytes() - self.d2h0,
                     **({} if self.merged is None
                        else {"merged": self.merged}),
+                    **self.collect_tags,
                 }),
             ]
             # a child falls under the phase it ended in
@@ -1453,7 +1461,7 @@ class QueryBatcher:
 
     def submit_nowait(
         self, executor, plan, k: int, kind: str = "match", query=None,
-        deadline: Optional[float] = None, prof=None,
+        deadline: Optional[float] = None, prof=None, window: int = 0,
     ) -> _Job:
         """Enqueues a job and returns its future handle WITHOUT waiting.
         Raises EsRejectedExecutionError (429) on queue overflow — the
@@ -1468,7 +1476,7 @@ class QueryBatcher:
         if kind not in FAMILIES:
             raise ValueError(f"unknown job kind [{kind}]")
         job = _Job(executor, plan, k, kind=kind, query=query,
-                   deadline=deadline, prof=prof)
+                   deadline=deadline, prof=prof, window=window)
         job.cold0 = self._cold_s
         self._ensure_thread()
         try:
@@ -1665,7 +1673,8 @@ class QueryBatcher:
             for j in batch:
                 fam = FAMILIES[j.kind]
                 kb = 16 if j.k <= 16 else scoring.next_bucket(j.k, 16)
-                key = (id(j.executor), j.kind, *fam.share(j.plan), kb)
+                key = (id(j.executor), j.kind, *fam.share(j.plan), kb,
+                       j.window)
                 groups.setdefault(key, (fam, kb, []))[2].append(j)
             for key, (fam, kb, jobs) in groups.items():
                 # pad-bucket ladder: the group's launch width is the
@@ -1893,7 +1902,7 @@ class QueryBatcher:
                     continue
                 dummy = [
                     _Job(j0.executor, j0.plan, j0.k, kind=j0.kind,
-                         query=j0.query)
+                         query=j0.query, window=j0.window)
                 ]
                 try:
                     fam.collect(
@@ -1957,6 +1966,9 @@ class QueryBatcher:
                 ok = max_df - ex.deleted_count >= j.plan.tth_cap
             prune.append(ok)
         with_cnt = any(j.plan.msm > 1 for j in jobs)
+        # the group key's: every job feeds a rescore window of this
+        # width, or none does (0: the programs are the ones they were)
+        tie_window = jobs[0].window
         # per-segment candidates STAY on device, a fused launch's as the
         # kernel packed them: the collect is one download (`_group_topk`)
         dev_items: List[Tuple] = []  # (si, packed | (s, d, tot))
@@ -1980,7 +1992,7 @@ class QueryBatcher:
                     pend = fs.search_async(
                         [([p], j.plan.msm) for p, j in zip(fplans, jobs)],
                         kb, "sum", None, staging=staging, rows=rows,
-                        counted=with_cnt,
+                        counted=with_cnt, tie_window=tie_window,
                     )
                     if record:
                         rare = [len(p[0]) for p in fplans]
@@ -2069,7 +2081,9 @@ class QueryBatcher:
                         self.stats["launches"] += 1
             msm = np.ones(rows, np.int32)
             msm[:nj] = [j.plan.msm for j in jobs]
-            dev_items.append((si, cs.finalize_device(acc, cnt, msm, kb)))
+            dev_items.append(
+                (si, cs.finalize_device(acc, cnt, msm, kb,
+                                        tie_window=tie_window)))
         return dev_items, pruned_flags
 
     def _collect_match_group(self, jobs: List[_Job], kb: int, pend: Tuple,
@@ -2081,7 +2095,10 @@ class QueryBatcher:
         dev_items, pruned_flags = pend
         reader = jobs[0].executor.reader
         nj = len(jobs)
-        if dev_items:
+        if dev_items and jobs[0].window:
+            ms, mseg, mdoc, mtot = self._window_topk(
+                dev_items, jobs[0].window, record)
+        elif dev_items:
             ms, mseg, mdoc, mtot = self._group_topk(dev_items, kb, record)
         else:
             ms = np.full((nj, 0), -np.inf, np.float32)
@@ -2156,6 +2173,61 @@ class QueryBatcher:
         # exact ties in (segment, doc) order: the device's top-k does not
         # promise it
         return (*scoring.rank_order(ms, mseg, mdoc), mtot, *counters)
+
+    def _window_topk(self, items: List[Tuple], window: int, record: bool):
+        """`_group_topk` for a first stage that feeds a rescore window:
+        the group's first `window` by (score desc, (segment, doc) asc),
+        EXACTLY, exact ties at the cut included (`scoring.window_cut`).
+        Every segment's candidates are downloaded as their launch left
+        them, with its tie refill (a packed row's trailing k, the
+        chunked path's fourth array: one blocking download a segment,
+        no merge program), cut to the segment's own exact first
+        `window`, and merged on the host. A window whose tie group ran
+        past the fetched bucket counts in `rescore.window_ties_refilled`
+        and on the `collect` span (`window`, `ties_refilled`)."""
+        scores_l, segs_l, docs_l, tots = [], [], [], []
+        refilled = None
+        for si, part in items:
+            if scoring.is_packed(part):
+                k = (int(part.shape[1]) - 1) // 3
+                s, sg, d, tot, fill = scoring.packed_segment_topk(
+                    si, part, k)
+            else:
+                s, d, tot, fill = (scoring._to_host(x) for x in part)
+                sg, tot = np.full_like(d, si), tot.astype(np.int64)[:, None]
+            s, sg, d = scoring.rank_order(s, sg, d)
+            rows = [scoring.window_cut(s[b], d[b], fill[b], window)
+                    for b in range(len(s))]
+            flags = np.array([r[2] for r in rows], bool)
+            refilled = flags if refilled is None else refilled | flags
+            scores_l.append([r[0] for r in rows])
+            docs_l.append([r[1] for r in rows])
+            segs_l.append(si)
+            tots.append(tot)
+        B = len(refilled)
+        ms = np.full((B, window), -np.inf, np.float32)
+        mseg = np.zeros((B, window), np.int32)
+        mdoc = np.zeros((B, window), np.int32)
+        for b in range(B):
+            s = np.concatenate([sl[b] for sl in scores_l])
+            d = np.concatenate([dl[b] for dl in docs_l])
+            sg = np.repeat(segs_l, [len(sl[b]) for sl in scores_l])
+            keep = (np.lexsort((d, sg, -s))[:window] if len(items) > 1
+                    else slice(None))  # one segment: cut and in order
+            n = len(s[keep])
+            ms[b, :n], mseg[b, :n], mdoc[b, :n] = s[keep], sg[keep], d[keep]
+        g = _group_now()
+        g.merged = False  # no merge program, whatever the segments
+        if record and len(items) == 1 and scoring.is_packed(items[0][1]):
+            with self._lock:
+                self.stats["direct_collect_groups"] += 1
+        g.collect_tags = {"window": int(window),
+                          "ties_refilled": int(refilled.sum())}
+        if record and refilled.any():
+            from ..models import rerank as rerank_model
+
+            rerank_model.note("window_ties_refilled", int(refilled.sum()))
+        return ms, mseg, mdoc, np.concatenate(tots, axis=1)
 
     # ---- dispatch/collect pairs (device work launches in dispatch;
     # only collect blocks on host transfers) ----
@@ -2564,6 +2636,7 @@ class QueryBatcher:
         first-stage ranking (the deterministic rerank fallback). A
         missing column (HBM degrade-to-skip) completes the group with a
         "skip" marker instead of device work."""
+        from ..models import rerank as rerank_model
         from ..ops import rerank as rerank_ops
 
         ex = jobs[0].executor
@@ -2595,6 +2668,7 @@ class QueryBatcher:
         docs[:] = 0
         first[:] = -np.inf
         valid[:] = False
+        cands = tokens = 0
         for ji, j in enumerate(jobs):
             p = j.plan
             qtoks[ji, : len(p.qtoks)] = p.qtoks
@@ -2603,10 +2677,20 @@ class QueryBatcher:
             docs[ji, :w] = p.gdocs.astype(np.int32)
             first[ji, :w] = p.first
             valid[ji, :w] = True
+            # the launch's work, from the host's copy of the CSR counts
+            rescored = p.gdocs[: min(w, p.win_static)]
+            cands += len(rescored)
+            tokens += int(col["counts_host"][rescored].sum())
+        rerank_model.note_launch(
+            cands, tokens, rows * wb * col["tmax"], dims,
+            plan0.model.element_bytes)
+        planes = (qtoks, qvalid, docs, first, valid)
+        h2d = sum(a.nbytes for a in planes) + 8  # and the two weights
+        note_transfer("h2d", h2d, count=len(planes) + 1)
         t0 = time.perf_counter()
         with _group_now().launch(
-                "maxsim_rescore_batch", flops=rerank_ops.rerank_flops(
-                    nj, qb, wb, col["tmax"], dims)):
+                "maxsim_rescore_batch", len(planes) + 1, h2d,
+                rerank_ops.rerank_flops(nj, qb, wb, col["tmax"], dims)):
             out = rerank_ops.maxsim_rescore_batch(
                 qtoks, qvalid, col["starts"], col["counts"], col["toks"],
                 col["scales"], docs, first, valid,

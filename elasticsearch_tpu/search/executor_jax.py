@@ -13,6 +13,7 @@ north star's "posting lists block-decoded once into HBM-resident arrays".
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -56,6 +57,43 @@ FUSED_MIN_DOCS = 100_000
 # FUSED_T_RARE tiles with the terms left sparse, under 1 GiB (2,672 rows)
 # 0.02% (PERF.md section 6, PR 27)
 DENSE_ROWS_HBM_BUDGET = 1024 * 1024 * 1024
+# rows an upload block of a rerank column holds (`rerank_column`): the
+# column is assembled on the device block by block, and its row count is
+# a whole number of blocks. 1M rows of 128 bytes = 128 MiB a block
+RERANK_BLOCK_ROWS = 1 << 20
+
+
+def _row_blocks(chunks: List[np.ndarray], block: int):
+    """(first row, rows[block, d]) over the concatenation of `chunks`
+    in whole blocks, without building it: a block inside one chunk is a
+    view of it; one that spans chunks, and the last short one
+    (zero-filled to size), is a copy of at most `block` rows."""
+    at, buf, fill = 0, None, 0
+    for c in chunks:
+        pos = 0
+        while pos < len(c):
+            if not fill and len(c) - pos >= block:
+                yield at, c[pos : pos + block]
+                at, pos = at + block, pos + block
+                continue
+            if buf is None:
+                buf = np.zeros((block, c.shape[1]), c.dtype)
+            take = min(block - fill, len(c) - pos)
+            buf[fill : fill + take] = c[pos : pos + take]
+            fill, pos = fill + take, pos + take
+            if fill == block:
+                yield at, buf
+                at, buf, fill = at + block, None, 0
+    if fill:
+        yield at, buf
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _place_block(buf, block, at):
+    """`buf` with `block` written at row `at`, in place (donated)."""
+    return jax.lax.dynamic_update_slice(
+        buf, block, (at,) + (0,) * (buf.ndim - 1)
+    )
 
 
 def dense_row_min_df(n_docs: int) -> int:
@@ -2181,13 +2219,23 @@ class JaxExecutor:
         """Device-resident shard-level `rank_vectors` column for one
         RerankModel: per-doc CSR bounds over the GLOBAL doc encoding
         (segment-base + local doc — the same bases rescorer.build_plan
-        uses) plus the flat token matrix, tail-padded with `tmax` zero
-        rows so the maxsim gather never reads out of bounds. int8
-        models store quantized rows + per-token scales
-        (models/rerank.quantize_tokens). Charged to the `rerank`
-        HbmLedger category; a build that would not fit degrades to
-        SKIP (returns None — first-stage ranking survives). Cached per
-        executor generation, exactly like the agg tables and IVF
+        uses) plus the flat token matrix, tail-padded with at least
+        `tmax` zero rows so the maxsim gather never reads out of bounds.
+
+        The matrix is assembled ON THE DEVICE from the segments' planes
+        in blocks of `RERANK_BLOCK_ROWS` rows (`_row_blocks`: a block
+        inside one plane is a view; no full-size host copy, and no
+        float32 copy of a byte or int8 column): each block is uploaded
+        and written into a donated buffer whose row count is a whole
+        number of blocks, so the programs' shapes do not move with a
+        few rows more or less. A byte field's rows are uploaded as they
+        are (the bytes ARE the values: no scales); int8 models
+        (`index.rerank.quantization`) quantize block by block and keep
+        per-token scales (models/rerank.quantize_tokens). Charged to
+        the `rerank` HbmLedger category what is resident; a build that
+        would not fit degrades to SKIP (returns None — first-stage
+        ranking survives), counted in `rescore.columns_refused`. Cached
+        per executor generation, exactly like the agg tables and IVF
         indexes."""
         key = ("rerank", model)
         if key in self._rerank_columns:
@@ -2219,50 +2267,61 @@ class JaxExecutor:
             dims = int(model.dims) or (
                 int(chunks[0].shape[1]) if chunks else 1
             )
-            toks_host = (
-                np.concatenate(chunks, axis=0)
-                if chunks
-                else np.zeros((0, dims), np.float32)
+            as_bytes = bool(chunks) and chunks[0].dtype == np.int8
+            block = min(
+                RERANK_BLOCK_ROWS, scoring.next_bucket(flat + tmax, 16)
             )
-            pad = np.zeros((tmax, toks_host.shape[1]), toks_host.dtype)
-            toks_host = np.concatenate([toks_host, pad], axis=0)
-            est = (
-                starts.nbytes
+            rows = -(-(flat + tmax) // block) * block
+            width = 1 if as_bytes or model.quantized else 4
+            nbytes = (
+                rows * dims * width
+                + (rows * 4 if model.quantized else 0)
+                + starts.nbytes
                 + counts.nbytes
-                + toks_host.nbytes
-                + (
-                    # int8 twin replaces the f32 rows but adds scales
-                    toks_host.shape[0] * 4
-                    if model.quantized
-                    else 0
-                )
             )
-            if not hbm_ledger.would_fit(est):
+            if not hbm_ledger.would_fit(nbytes):
                 # degrade-to-skip: reranking is an optimization of the
                 # ranking, never worth failing (or OOMing) the request
                 hbm_ledger.note_degraded()
-                rerank_model.note("skipped")
+                rerank_model.note("columns_refused")
                 self._rerank_columns[key] = None
                 return None
-            scales_dev = None
-            if model.quantized:
-                qv, scales = rerank_model.quantize_tokens(toks_host)
-                toks_dev = jax.device_put(qv, self.device)
-                scales_dev = jax.device_put(scales, self.device)
-                nbytes = int(qv.nbytes + scales.nbytes)
-            else:
-                toks_dev = jax.device_put(
-                    toks_host.astype(np.float32), self.device
+            dtype = np.int8 if width == 1 else np.float32
+            toks_dev = jnp.zeros((rows, dims), dtype, device=self.device)
+            scales_dev = (
+                jnp.zeros((rows,), np.float32, device=self.device)
+                if model.quantized else None
+            )
+            for at, blk in _row_blocks(chunks, block):
+                scales = None
+                if model.quantized:
+                    blk, scales = rerank_model.quantize_tokens(blk)
+                elif blk.dtype != dtype:
+                    blk = blk.astype(dtype)
+                toks_dev = _place_block(
+                    toks_dev, jax.device_put(blk, self.device), at
                 )
-                nbytes = int(toks_host.nbytes)
+                if scales is not None:
+                    scales_dev = _place_block(
+                        scales_dev, jax.device_put(scales, self.device), at
+                    )
+                # one block in flight: uploads enqueued ahead of the
+                # device would each hold a block's buffer beside the
+                # column (a 15.0e9 B peak on a 16.9e9 B chip, PERF.md
+                # section 6, PR 53)
+                toks_dev.block_until_ready()
             col = {
                 "starts": jax.device_put(starts, self.device),
                 "counts": jax.device_put(counts, self.device),
+                # the same CSR counts on the host: a launch's work is
+                # counted from them, with no download
+                "counts_host": counts,
                 "toks": toks_dev,
                 "scales": scales_dev,
                 "tmax": int(tmax),
-                "dims": int(toks_host.shape[1]),
-                "nbytes": int(nbytes + starts.nbytes + counts.nbytes),
+                "dims": dims,
+                "rows": int(flat),
+                "nbytes": int(nbytes),
             }
             self._charge("rerank", col["nbytes"], False)
             self._rerank_columns[key] = col

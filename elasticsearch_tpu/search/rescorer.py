@@ -180,7 +180,12 @@ class RerankPlan:
         self.first = first  # f32 [W_real] first-stage scores (desc)
         self.gdocs = gdocs  # i64 [W_real] global (segment-base + doc)
         self.field = model.field
-        self.wb = max(16, scoring.next_bucket(max(len(first), 1), 16))
+        # the launch's width is the WINDOW's bucket, not the bucket of
+        # the candidates this request happened to find: a question few
+        # passages match would otherwise build a program of its own
+        # width in the middle of serving (one a power of two)
+        self.wb = max(16, scoring.next_bucket(
+            max(len(first), int(spec.window_size), 1), 16))
         self.qb = max(4, scoring.next_bucket(max(len(qtoks), 1), 4))
         self.win_static = min(int(spec.window_size), self.wb)
         self.sig = (

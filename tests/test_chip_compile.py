@@ -502,7 +502,9 @@ def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
     as wide as the slot budget (rows x 256 tiles x 128 postings) is
     left, and nothing but the plan's shape and whether the launch counts
     decides the program, so the programs `_warm_ladder` compiles a
-    family are its row buckets, as before the loop."""
+    family are its row buckets, as before the loop (`negated` and
+    `tie_window` ride the group key: a negated group, and a first stage
+    that feeds a rescore window, warm ladders of their own)."""
     import inspect
 
     rows = 1
@@ -511,7 +513,8 @@ def test_rare_pass_is_a_loop_inside_the_one_program(one_chip, family):
     params = inspect.signature(
         scoring._fused_query_mf.__wrapped__).parameters.values()
     assert {p.name for p in params if p.kind == p.KEYWORD_ONLY} == {
-        "t_rare", "n_hot", "k", "combine", "counted", "negated"}
+        "t_rare", "n_hot", "k", "combine", "counted", "negated",
+        "tie_window"}
     text = lowered.compile().as_text()
     budget = rows * scoring.FUSED_T_RARE * TILE
     chunk = rows * scoring.RARE_CHUNK * TILE
@@ -753,6 +756,87 @@ def test_maxsim_rescore(one_chip):
         window=window,
     ).compile()
     _fits(compiled)
+
+
+def test_maxsim_rescore_at_the_late_interaction_deployments_shapes(one_chip):
+    """`msmarco-colbert-rescore`: one query row of 32 vectors against a
+    window bucket of 1,024 candidates x 180 token slots x 128 bytes,
+    gathered from a ~69M-row byte column (described, not allocated; 67
+    upload blocks of 1,048,576 rows), at the stated precision. Its
+    temporaries at the widest launch the cell warms (one row: the
+    rerank family warms no ladder) must fit beside the 10.9 GB the
+    deployment keeps resident, and a launch of several rows walks them
+    (`lax.map`), so its temporaries do not grow with the rows."""
+    from elasticsearch_tpu.ops import rerank
+    from elasticsearch_tpu.search import executor_jax
+
+    s = _on(one_chip)
+    col_rows = 67 * executor_jax.RERANK_BLOCK_ROWS
+    resident = col_rows * 128 + 8 * N_DOCS + 2_030_000_000
+
+    def lower(rows):
+        return rerank._maxsim_rescore.lower(
+            s((rows, 32, 128), jnp.float32),
+            s((rows, 32), jnp.bool_),
+            s((N_DOCS,), jnp.int32),
+            s((N_DOCS,), jnp.int32),
+            s((col_rows, 128), jnp.int8),
+            None,  # a byte field: no scales plane
+            s((rows, 1024), jnp.int32),
+            s((rows, 1024), jnp.float32),
+            s((rows, 1024), jnp.bool_),
+            s((2,), jnp.float32),
+            tmax=180,
+            window=1000,
+        ).compile()
+
+    one = lower(1)
+    m = one.memory_analysis()
+    assert m.argument_size_in_bytes >= col_rows * 128
+    assert resident + m.temp_size_in_bytes + m.output_size_in_bytes < HBM_BYTES
+    # one row's gathered bytes, their bfloat16 twin and the three
+    # parts' products would be 23.6 + 47 + 71 MB if each were a plane
+    assert m.temp_size_in_bytes < 160 * 1024 * 1024, m.temp_size_in_bytes
+    hlo = one.as_text()
+    assert "bf16" in hlo  # the split query parts, not a float32 pass
+    four = lower(4).memory_analysis()
+    assert four.temp_size_in_bytes < 160 * 1024 * 1024, (
+        four.temp_size_in_bytes)
+    # the column's assembly: a block written in place into the donated
+    # buffer (no second copy of 8.9 GB)
+    place = executor_jax._place_block.lower(
+        s((col_rows, 128), jnp.int8),
+        s((executor_jax.RERANK_BLOCK_ROWS, 128), jnp.int8),
+        s((), jnp.int32),
+    ).compile().memory_analysis()
+    assert place.alias_size_in_bytes >= col_rows * 128
+    assert place.temp_size_in_bytes < 1024 * 1024
+
+
+def test_fused_match_program_selects_a_rescore_window(one_chip):
+    """The first stage of the late-interaction deployment: the fused
+    program at the window's bucket (k 1,024) with the window's tie
+    refill (`tie_window` 1,000), one row, beside the resident set."""
+    s = _on(one_chip)
+    compiled = scoring._fused_query_mf.lower(
+        (s((1_160_811, TILE), jnp.int32),),
+        (s((1_160_811, TILE), jnp.int32),),
+        (s((N_DOCS,), jnp.float32),),
+        (s((500, N_DOCS), jnp.uint8),),
+        None,
+        s((1, _plan_width(1)), jnp.int32),
+        None,
+        t_rare=scoring.FUSED_T_RARE,
+        n_hot=scoring.FUSED_H,
+        k=1024,
+        combine="sum",
+        counted=False,
+        tie_window=1000,
+    ).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes >= 4 * (3 * 1024 + 1)  # the refill rides
+    assert _fits(compiled) + 67 * (1 << 20) * 128 < HBM_BYTES
+    assert "conditional" in compiled.as_text()  # the refill is a branch
 
 
 def test_segment_build_postings(one_chip):

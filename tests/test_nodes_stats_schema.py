@@ -35,7 +35,14 @@ REQUIRED = {
     },
     "aggs": {"batched_jobs"},
     "knn.ann": set(),  # block presence is the contract
-    "rescore": {"batched_jobs"},
+    # the benchmark's `rerank_*`, `window_ties_refilled_share` and
+    # `maxsim_gather_roofline` read these by dotted path
+    "rescore": {
+        "batched_jobs", "device_rescores", "host_rescores", "skipped",
+        "fallbacks", "requests", "first_stage_kept", "columns_refused",
+        "launches", "windows_docs", "tokens_scored", "slots_gathered",
+        "slots_padded", "least_bytes", "window_ties_refilled",
+    },
     "sparse": {"batched_jobs"},
     "translog": {
         "uncommitted_ops", "uncommitted_bytes", "pending_unsynced_ops",
