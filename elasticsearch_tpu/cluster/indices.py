@@ -1617,6 +1617,7 @@ class IndexService:
             and str(self.settings.get("search.backend")) == "jax"
         ):
             from ..search.batcher import (
+                extract_fuzzy_plan,
                 extract_knn_plan,
                 extract_match_plan,
                 extract_phrase_plan,
@@ -1644,6 +1645,14 @@ class IndexService:
                     plan = extract_match_plan(
                         query, self.mappings, self.analysis, tth
                     )
+                    if plan is None:
+                        # a `match` with `fuzziness`, a `fuzzy` query:
+                        # the `fuzzy` family (what it turns away no
+                        # later planner takes)
+                        plan = extract_fuzzy_plan(
+                            query, self.mappings, self.analysis
+                        )
+                        kind = "fuzzy"
                     if plan is None:
                         # a bare exact `match_phrase`: the `phrase`
                         # family (what it turns away, a sloppy or a
@@ -1880,7 +1889,8 @@ class IndexService:
 
             highlight_specs = parse_highlight(body["highlight"])
             highlight_terms = extract_highlight_terms(
-                query, self.mappings, self.analysis
+                query, self.mappings, self.analysis,
+                expand=getattr(ex, "fuzzy_terms", None),
             )
         from ..search.executor import filter_source
 

@@ -146,6 +146,15 @@ starts at `http`'s start and reaches the ring when `http` ends:
             operand of the fused launch, whose program builds the
             masks (that group's `dispatch` span also carries filtered,
             filter_clauses, excluded_terms and filter_tiles)
+          > fuzzy_expand [segment, words, launches]  a fuzzy group: its
+            distinct words that take an edit packed, `fuzzy_expand`
+            launched and the kept ordinals and distances downloaded
+            (the `launch` and `download` inside it are its children's
+            siblings; `es.fuzzy_expand` on the profiler's clock)
+          > fuzzy_plan [segment, terms_kept, tiles, hot_terms]  the
+            same group: ordinals -> boosts, blended idf, weights, dense
+            rows and tiles, and the fused program at the family's slot
+            budgets enqueued (`es.fuzzy_plan`)
           > phrase_plan [segment, launches, words]  a phrase group
             on one segment: the words looked up in the term dictionary,
             the plan packed and uploaded, `phrase_topk` enqueued (one

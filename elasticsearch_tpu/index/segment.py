@@ -105,6 +105,7 @@ class PostingsField:
     _term_index: Optional[Dict[str, int]] = None
     # PositionsPlane | False (none can be built) | None (not asked yet)
     _positions_plane: object = None
+    _term_plane: object = None  # models/fuzzy.TermPlane once asked for
 
     def term_id(self, term: str) -> int:
         if self._term_index is None:
@@ -127,6 +128,18 @@ class PostingsField:
             self._positions_plane = (
                 build_positions_plane(self, n_docs) or False)
         return self._positions_plane or None
+
+    def term_plane(self):
+        """The dictionary as the fuzzy expansion reads it (models/fuzzy.py
+        `TermPlane`: code points transposed, lengths), built at the
+        field's first fuzzy search and kept: a segment is immutable. Its
+        columns are padded to a multiple of 1,024, so the device's copy
+        (ops/fuzzy.py) is the same array."""
+        if self._term_plane is None:
+            from ..models.fuzzy import build_term_plane
+
+            self._term_plane = build_term_plane(self.terms, pad_to=1024)
+        return self._term_plane
 
     def term_docs(self, tid: int) -> np.ndarray:
         """Compact (unpadded) sorted doc-id list for one term."""

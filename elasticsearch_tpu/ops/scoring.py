@@ -459,6 +459,15 @@ def _score_tiles_inner(doc_rows, tf_rows, tile_weights, tile_valid, inv_norm, n_
 
 FUSED_T_RARE = 256  # rare tile slots per query (fixed compile shape)
 FUSED_H = 12  # dense hot-term slots per query (fixed compile shape)
+# The fuzzy family's budgets (search/batcher.py `_dispatch_fuzzy_group`):
+# a question of up to 12 words, each expanded to up to 50 dictionary
+# terms, brings hundreds of weighted terms where a plain one brings its
+# own 2-12. The program is this file's one fused program at another
+# static shape, compiled for that family alone; its loops run over the
+# slots a launch USES, so the budgets cost the width of the plan's upload
+# (2 * (T + H) + 1 int32 a row: 33.5 KB) and nothing else.
+FUZZY_T_RARE = 4096
+FUZZY_H = 96
 DENSE_TF_MAX = 255  # uint8 dense rows
 # uint16 rows for the few hot terms whose tf passes DENSE_TF_MAX somewhere
 # (a stop word in a document of thousands of tokens); past this a term

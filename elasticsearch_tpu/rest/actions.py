@@ -697,6 +697,14 @@ class RestActions:
             "candidate_occurrences": 0, "matches": 0, "least_bytes": 0,
             "fallbacks": 0,
         }
+        # the fuzzy family (QueryBatcher.fuzzy): jobs, words, words
+        # expanded on the device, terms kept, words that kept every
+        # place, the plans' dense rows and tiles, jobs that passed a
+        # slot budget or found no plane, the expansion's least work
+        fuzzy = dict.fromkeys((
+            "requests", "words", "words_expanded", "terms_kept", "words_saturated", "hot_terms", "tiles",
+            "overflows", "fallbacks", "launches", "score_launches",
+            "least_bytes", "least_cells"), 0)
         for idx in self.cluster.indices.values():
             with idx._rrf_lock:
                 for k, v in idx.rrf_stats.items():
@@ -713,6 +721,8 @@ class RestActions:
                         knn_filtered[k] += v
                     for k, v in b.phrase.items():
                         phrase[k] += v
+                    for k, v in b.fuzzy.items():
+                        fuzzy[k] += v
                     for k, v in b.serve_filtered.items():
                         serve_filtered[k] += v
                 queue_capacity = max(queue_capacity, b._queue.maxsize)
@@ -928,6 +938,7 @@ class RestActions:
                     "knn": knn_block,
                     "knn_filtered": knn_filtered,
                     "phrase": phrase,
+                    "fuzzy": fuzzy,
                     "serve_filtered": serve_filtered,
                     "rescore": rescore_block,
                     "sparse": sparse_block,

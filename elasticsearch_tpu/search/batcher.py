@@ -61,6 +61,8 @@ from ..common.tracing import (
     worker_group,
 )
 from ..index.mapping import KEYWORD, SPARSE_VECTOR, TEXT
+from ..models import fuzzy as fuzzy_model
+from ..ops import fuzzy as fuzzy_ops
 from ..ops import phrase as phrase_ops
 from ..ops import scoring
 from ..ops.scoring import BPAD
@@ -164,7 +166,9 @@ def extract_match_plan(
 ) -> Optional[MatchPlan]:
     """Returns a MatchPlan when `query` is a match query over a text
     field (the hot REST shape), else None → normal executor path."""
-    if not isinstance(query, dsl.MatchQuery):
+    if not isinstance(query, dsl.MatchQuery) or query.fuzzy is not None:
+        # a `match` with `fuzziness` is no flat list of the query's own
+        # words: `extract_fuzzy_plan`
         return None
     mf = mappings.get(query.field)
     if mf is None or mf.type != TEXT:
@@ -189,6 +193,56 @@ def extract_match_plan(
         boost=query.boost,
         tth_cap=_tth_cap(tth),
     )
+
+
+@dataclass(frozen=True)
+class FuzzyPlan:
+    """A `match` with `fuzziness` (operator or) or a `fuzzy` query over
+    one text field: the analyzed words, each to be expanded to its kept
+    dictionary terms (models/fuzzy.py) and every kept term scored.
+    `params.max_expansions` and `params.transpositions` are static in
+    the expansion program and ride the group key."""
+
+    field: str
+    words: Tuple[str, ...]
+    params: fuzzy_model.FuzzyParams
+    boost: float
+
+
+def extract_fuzzy_plan(query, mappings, analysis) -> Optional[FuzzyPlan]:
+    """A FuzzyPlan when `query` is a `match` with `fuzziness` that any
+    word's kept term satisfies (operator or, no `minimum_should_match`
+    past 1) or a `fuzzy` query, over a text field, at `prefix_length` 0
+    and a positive boost, of 1..WORDS_PER_ROW words none longer than the
+    dictionary plane answers; else None -> the unbatched executor's
+    rewrite (the caller counts it in `unplanned_queries`)."""
+    if isinstance(query, dsl.FuzzyQuery):
+        params, words = query.params, [query.value]
+    elif isinstance(query, dsl.MatchQuery) and query.fuzzy is not None:
+        params, words = query.fuzzy, None
+    else:
+        return None
+    mf = mappings.get(query.field)
+    if mf is None or mf.type != TEXT:
+        return None
+    if words is None:
+        analyzer_name = query.analyzer or mf.search_analyzer or mf.analyzer
+        try:
+            words = analysis.get(analyzer_name).terms(query.query)
+        except ValueError:
+            return None
+        if query.operator == "and" and len(words) > 1:
+            return None
+        if dsl.parse_minimum_should_match(
+                query.minimum_should_match, len(words)) > 1:
+            return None
+    if (not 1 <= len(words) <= fuzzy_ops.WORDS_PER_ROW
+            or params.prefix_length or query.boost <= 0
+            or params.max_expansions > fuzzy_ops.KEEP_MAX
+            or any(not 1 <= len(fuzzy_model.code_points(w))
+                   <= fuzzy_ops.MAX_WORD_LEN for w in words)):
+        return None
+    return FuzzyPlan(query.field, tuple(words), params, float(query.boost))
 
 
 @dataclass(frozen=True)
@@ -408,7 +462,8 @@ def _clause_terms(
         mf = mappings.get(q.field)
         if mf is None or mf.type != TEXT:
             return None
-        if q.minimum_should_match is not None:
+        if q.minimum_should_match is not None or q.fuzzy is not None:
+            # (a fuzzy clause scores its words' kept terms, not its words)
             return None
         analyzer_name = q.analyzer or mf.search_analyzer or mf.analyzer
         try:
@@ -1147,6 +1202,18 @@ FAMILIES: Dict[str, _Family] = {
             jobs, kb, pend, record=record),
         warm=_warm_serve,
     ),
+    # typo-tolerant text: what is static in the expansion program rides
+    # the key; the scoring program is the fused one at the family's own
+    # slot budgets (a program of its own)
+    "fuzzy": _Family(
+        "text", lambda p: (p.field, p.params.max_expansions,
+                           p.params.transpositions),
+        lambda b, jobs, key, kb, rows, record: b._dispatch_fuzzy_group(
+            jobs, kb, rows=rows, record=record),
+        lambda b, jobs, key, kb, pend, record: b._collect_fuzzy_group(
+            jobs, kb, pend, record=record),
+        warm=_warm_first,
+    ),
     # exact phrases: the span in slots rides the key (one program a
     # span); the words do not
     "phrase": _Family(
@@ -1393,6 +1460,21 @@ class QueryBatcher:
             "occurrences_read": 0, "candidates": 0,
             "candidate_occurrences": 0, "matches": 0, "least_bytes": 0,
             "fallbacks": 0,
+        }
+        # the fuzzy family (`_nodes/stats` `fuzzy`; under self._lock): jobs
+        # served, their words, the words that took an edit (expanded on
+        # the device), terms kept and words that kept all `max_expansions`,
+        # of the plans: dense rows and tiles, jobs that passed a slot
+        # budget (`overflows`) or found no plane (`fallbacks`) and were
+        # served by the unbatched executor; expansion launches, and what
+        # ANY exact expansion of the words would read and compute
+        # (ops/fuzzy.least_work: the benchmark's roofline)
+        self.fuzzy = {
+            "requests": 0, "words": 0, "words_expanded": 0,
+            "terms_kept": 0, "words_saturated": 0,
+            "hot_terms": 0, "tiles": 0, "overflows": 0, "fallbacks": 0,
+            "launches": 0, "score_launches": 0, "least_bytes": 0,
+            "least_cells": 0,
         }
         # per-bucket launch histogram + occupancy sums (guarded by
         # self._lock; surfaced via batching_stats() → _nodes/stats):
@@ -2600,6 +2682,171 @@ class QueryBatcher:
                     [j], [per_job_cands[ji]], totals[ji: ji + 1],
                     si, s1[None, :], d1[None, :], np.array([t1]),
                 )
+        self._finish_jobs(jobs, per_job_cands, totals, ex.reader)
+
+    def _dispatch_fuzzy_group(self, jobs: List[_Job], kb: int,
+                              rows: Optional[int] = None,
+                              record: bool = True):
+        """A group of FuzzyPlan jobs of one field: TWO dependent device
+        steps, the second planned from the first's output.
+
+        `fuzzy_expand` (span, `es.fuzzy_expand` on the profiler's clock):
+        the group's distinct (word, edits) pairs go up as code points
+        (a job's fuzziness is its own: the key holds none), ONE launch
+        of ops/fuzzy.py `fuzzy_expand` walks each against the field's
+        dictionary plane and the kept ordinals and distances come down
+        (a blocking download: this dispatch waits, as a chunked match
+        group's threshold round does). A word that takes no edit in its
+        job is looked up on the host.
+
+        `fuzzy_plan` (span, `es.fuzzy_plan`): boosts, the word's blended
+        idf and the weights (models/fuzzy.py, float32 as the oracle
+        computes them), then every kept term's dense row or tiles
+        (`fuzzy_plan_field`), and the fused program at the family's slot
+        budgets is enqueued; collect downloads its packed row.
+
+        A job whose plan passes a budget (`fuzzy.overflows`), and every
+        job of a shard the device path does not hold (several scoring
+        segments, a segment under FUSED_MIN_DOCS: `fuzzy.fallbacks`), is
+        served by the unbatched executor at collect: never by leaving
+        kept terms out."""
+        ex = jobs[0].executor
+        reader = ex.reader
+        nj = len(jobs)
+        rows = rows or BPAD
+        plan0 = jobs[0].plan
+        field, params = plan0.field, plan0.params
+        segs = [si for si, seg in enumerate(reader.segments)
+                if seg.num_docs and seg.postings.get(field) is not None]
+        fz = ex.fuzzy_parts(segs[0], field) if len(segs) == 1 else None
+        if fz is None:
+            if record and segs:
+                with self._lock:
+                    self.fuzzy["fallbacks"] += nj
+            return None, [bool(segs)] * nj
+        si = segs[0]
+        pf = reader.segments[si].postings[field]
+        g = _group_now()
+        # ---- expand
+        t0 = time.perf_counter_ns()
+        with TraceAnnotation("es.fuzzy_expand"):
+            # (the fuzziness is the job's own, not the group's key: a
+            # word is expanded once for each number of edits it is asked)
+            todo: Dict[Tuple[str, int], int] = {}
+            sent: List[Tuple[np.ndarray, int]] = []
+            for j in jobs:
+                for w in j.plan.words:
+                    k = j.plan.params.edits(w)
+                    if k and (w, k) not in todo:
+                        todo[w, k] = len(sent)
+                        sent.append((fuzzy_model.code_points(w), k))
+            found = []
+            if sent:
+                out = fuzzy_ops.expand_async(
+                    fz["plane"], sent, fuzzy_ops.word_slots(rows),
+                    params.max_expansions, params.transpositions)
+                found = fuzzy_ops.decode(
+                    scoring._to_host(out), len(sent), params.max_expansions)
+        t1 = time.perf_counter_ns()
+        # ---- plan
+        with TraceAnnotation("es.fuzzy_plan"):
+            doc_count = reader.field_stats(field)[0]
+            lens = pf.term_plane().lens
+            fplans, kept_n, sat_n = [], 0, 0
+            for j in jobs:
+                ords_l, w_l = [], []
+                for w in j.plan.words:
+                    at = todo.get((w, j.plan.params.edits(w)))
+                    if at is None:
+                        tid = pf.term_id(w)
+                        ords = np.array([tid] if tid >= 0 else [], np.int64)
+                        boosts = np.ones(len(ords), np.float32)
+                    else:
+                        ords, dist = found[at]
+                        boosts = fuzzy_model.boosts_of(
+                            dist, len(sent[at][0]), lens[ords])
+                    if not len(ords):
+                        continue
+                    kept_n += len(ords)
+                    sat_n += len(ords) >= params.max_expansions
+                    ords_l.append(ords)
+                    w_l.append(fuzzy_model.term_weights(
+                        j.plan.boost,
+                        fuzzy_model.blended_idf(doc_count, pf.term_df[ords]),
+                        boosts))
+                fplans.append(ex.fuzzy_plan_field(
+                    si, field, fz,
+                    np.concatenate(ords_l or [np.empty(0, np.int64)]),
+                    np.concatenate(w_l or [np.empty(0, np.float32)])))
+            empty = (np.empty(0, np.int64), np.empty(0, np.float32)) * 2
+            fs = fz["scorer"]
+            pend = fs.search_async(
+                [([p if p is not None else empty], 1) for p in fplans],
+                kb, "sum", None,
+                staging=getattr(ex, "staging_slab", None), rows=rows,
+                counted=False)
+        t2 = time.perf_counter_ns()
+        if record:
+            n_words = sum(len(j.plan.words) for j in jobs)
+            rare = [len(p[0]) for p in fplans if p is not None]
+            hot = [len(p[2]) for p in fplans if p is not None]
+            g.sub_spans.append(("fuzzy_expand", t0, t1, {
+                "segment": si, "words": len(sent),
+                "launches": int(bool(sent))}))
+            g.sub_spans.append(("fuzzy_plan", t1, t2, {
+                "segment": si, "terms_kept": kept_n,
+                "tiles": sum(rare), "hot_terms": sum(hot)}))
+            g.plan_tags.update(words=n_words, terms_kept=kept_n,
+                               rare_tiles=max(rare, default=0),
+                               hot_slots=max(hot, default=0))
+            nbytes, cells = fuzzy_ops.least_work(
+                [len(cp) for cp, _k in sent], [k for _cp, k in sent],
+                fz["terms_by_len"], fz["plane"].chars.dtype.itemsize)
+            if any(p is None for p in fplans):
+                g.overflow = True
+            with self._lock:
+                self.stats["launches"] += 1 + bool(sent)
+                self.stats["fused_jobs"] += nj
+                self._count_rare_slots(rows, fs.t_rare, rare)
+                fzs = self.fuzzy
+                fzs["requests"] += nj
+                fzs["words"] += n_words
+                fzs["words_expanded"] += len(sent)
+                fzs["terms_kept"] += kept_n
+                fzs["words_saturated"] += sat_n
+                fzs["hot_terms"] += sum(hot)
+                fzs["tiles"] += sum(rare)
+                fzs["overflows"] += sum(p is None for p in fplans)
+                fzs["launches"] += bool(sent)
+                fzs["score_launches"] += 1
+                fzs["least_bytes"] += nbytes
+                fzs["least_cells"] += cells
+        return (si, pend[0]), [p is None for p in fplans]
+
+    def _collect_fuzzy_group(self, jobs: List[_Job], kb: int, pend,
+                             record: bool = True):
+        """The group's one blocking download of collect (the fused
+        program's packed row: `_group_topk`), then each job's hits; a
+        job the device path turned away runs on the unbatched executor
+        (the oracle's rewrite), segment by segment."""
+        item, turned_away = pend
+        ex = jobs[0].executor
+        nj = len(jobs)
+        per_job_cands: List[List[Tuple[float, int, int]]] = [[] for _ in jobs]
+        totals = np.zeros(nj, np.int64)
+        if item is not None:
+            # (a job turned away rode an empty plan: no hit, total 0)
+            ms, _mseg, mdoc, mtot = self._group_topk([item], kb, record)
+            self._collect(jobs, per_job_cands, totals, item[0], ms, mdoc,
+                          mtot.sum(axis=1))
+        for ji, j in enumerate(jobs):
+            if not turned_away[ji]:
+                continue
+            for si in range(len(ex.reader.segments)):
+                s1, d1, t1 = ex.segment_topk(j.query, si, kb)
+                self._collect(
+                    [j], [per_job_cands[ji]], totals[ji: ji + 1],
+                    si, s1[None, :], d1[None, :], np.array([t1]))
         self._finish_jobs(jobs, per_job_cands, totals, ex.reader)
 
     def _dispatch_agg_group(self, jobs: List[_Job]) -> List[Tuple]:

@@ -19,6 +19,8 @@ import math
 from dataclasses import dataclass, field as dc_field
 from typing import Any, Dict, List, Optional
 
+from ..models.fuzzy import FuzzinessError, FuzzyParams, parse_fuzziness
+
 
 class QueryParseError(ValueError):
     pass
@@ -46,6 +48,18 @@ class MatchQuery(Query):
     operator: str = "or"  # or | and
     minimum_should_match: Optional[str] = None
     analyzer: Optional[str] = None
+    # `fuzziness` set: every analyzed word is a FuzzyQuery (models/fuzzy.py)
+    fuzziness: Optional[str] = None  # AUTO | AUTO:lo,hi | 0 | 1 | 2
+    prefix_length: int = 0
+    max_expansions: int = 50
+    fuzzy_transpositions: bool = True
+
+    @property
+    def fuzzy(self) -> Optional[FuzzyParams]:
+        if self.fuzziness is None:
+            return None
+        return FuzzyParams(self.fuzziness, self.prefix_length,
+                           self.max_expansions, self.fuzzy_transpositions)
 
 
 @dataclass
@@ -259,6 +273,12 @@ class FuzzyQuery(Query):
     fuzziness: str = "AUTO"
     prefix_length: int = 0
     max_expansions: int = 50
+    transpositions: bool = True
+
+    @property
+    def params(self) -> FuzzyParams:
+        return FuzzyParams(self.fuzziness, self.prefix_length,
+                           self.max_expansions, self.transpositions)
 
 
 @dataclass
@@ -336,9 +356,48 @@ def _field_params(params: dict, qname: str) -> tuple:
     return fname, cfg
 
 
+# MatchQueryBuilder's fields: another key is a parsing error upstream
+# ("[match] query does not support [x]"). `fuzzy_rewrite`, `lenient`,
+# `zero_terms_query`, `auto_generate_synonyms_phrase_query` and `_name`
+# are accepted and not acted on (ROADMAP C11).
+_MATCH_KEYS = frozenset((
+    "query", "operator", "analyzer", "boost", "minimum_should_match",
+    "fuzziness", "prefix_length", "max_expansions", "fuzzy_transpositions",
+    "fuzzy_rewrite", "lenient", "zero_terms_query",
+    "auto_generate_synonyms_phrase_query", "_name",
+))
+
+
+def _fuzzy_keys(cfg: dict, qname: str, transpositions_key: str) -> dict:
+    """The four fuzzy settings of a `match` or a `fuzzy` body, checked."""
+    out = {}
+    if cfg.get("fuzziness") is not None:
+        try:
+            parse_fuzziness(cfg["fuzziness"])
+        except FuzzinessError as e:
+            raise QueryParseError(f"[{qname}] {e}")
+        out["fuzziness"] = str(cfg["fuzziness"])
+    for key in ("prefix_length", "max_expansions"):
+        if key in cfg:
+            try:
+                out[key] = int(cfg[key])
+            except (TypeError, ValueError):
+                raise QueryParseError(f"[{qname}] [{key}] must be a number")
+            if out[key] < 0 or (key == "max_expansions" and out[key] < 1):
+                raise QueryParseError(
+                    f"[{qname}] [{key}] cannot be {out[key]}")
+    if transpositions_key in cfg:
+        out[transpositions_key] = bool(cfg[transpositions_key])
+    return out
+
+
 def _parse_match(params):
     fname, cfg = _field_params(params, "match")
     if isinstance(cfg, dict):
+        for key in cfg:
+            if key not in _MATCH_KEYS:
+                raise QueryParseError(
+                    f"[match] query does not support [{key}]")
         return MatchQuery(
             field=fname,
             query=str(cfg.get("query", "")),
@@ -346,6 +405,7 @@ def _parse_match(params):
             minimum_should_match=cfg.get("minimum_should_match"),
             analyzer=cfg.get("analyzer"),
             boost=float(cfg.get("boost", 1.0)),
+            **_fuzzy_keys(cfg, "match", "fuzzy_transpositions"),
         )
     return MatchQuery(field=fname, query=str(cfg))
 
@@ -590,10 +650,8 @@ def _parse_fuzzy(params):
         return FuzzyQuery(
             field=fname,
             value=str(cfg.get("value", "")),
-            fuzziness=str(cfg.get("fuzziness", "AUTO")),
-            prefix_length=int(cfg.get("prefix_length", 0)),
-            max_expansions=int(cfg.get("max_expansions", 50)),
             boost=float(cfg.get("boost", 1.0)),
+            **_fuzzy_keys(cfg, "fuzzy", "transpositions"),
         )
     return FuzzyQuery(field=fname, value=str(cfg))
 

@@ -44,6 +44,7 @@ from ..index.mapping import (
 )
 from ..index.segment import Segment
 from ..models import bm25
+from ..models import fuzzy as fuzzy_model
 from ..models.similarity import score_vectors
 from . import dsl
 from .dsl import (
@@ -140,6 +141,9 @@ DFS_STATS: contextvars.ContextVar = contextvars.ContextVar(
     "dfs_stats", default=None
 )
 
+# words whose fuzzy expansion an executor keeps (`fuzzy_expansion`)
+FUZZY_CACHE_WORDS = 1024
+
 # per-request device-array cache for DFS norm uploads (kept OUT of the
 # DFS stats dict, which rides the wire as JSON)
 DFS_NORM_CACHE: contextvars.ContextVar = contextvars.ContextVar(
@@ -162,6 +166,10 @@ class NumpyExecutor:
         self.k1 = k1
         self.b = b
         self._weight_cache: Dict[Tuple[str, str], float] = {}
+        # (field, word, FuzzyParams) -> the word's kept terms: the newest
+        # FUZZY_CACHE_WORDS (a search asks a word once a segment, and its
+        # highlighter again; misspellings never repeat)
+        self._fuzzy_cache: Dict[tuple, tuple] = {}
         self._norm_cache: Dict[str, np.ndarray] = {}
         # filter-bitset cache identity; None (executors constructed
         # outside IndexService) disables the node-level cache
@@ -580,34 +588,90 @@ class NumpyExecutor:
             mask |= m
         return mask, np.where(mask, np.float32(q.boost), 0).astype(np.float32)
 
-    def _fuzzy_terms(self, q: "dsl.FuzzyQuery", seg: Segment) -> List[str]:
-        """FuzzyQuery expansion against the term dictionary (bounded by
-        max_expansions, Lucene FuzzyTermsEnum semantics)."""
-        pf = seg.postings.get(q.field)
-        if pf is None:
-            return []
-        max_edits = _fuzziness_edits(q.fuzziness, q.value)
-        prefix = q.value[: q.prefix_length]
-        cands: List[str] = []
-        for t in pf.terms:
-            if abs(len(t) - len(q.value)) > max_edits:
+    def fuzzy_expansion(self, field: str, word: str,
+                        params: "fuzzy_model.FuzzyParams"):
+        """One word's kept terms over the SHARD's dictionary (a
+        MultiTermQuery rewrites against the top-level reader), as
+        models/fuzzy.py's equations 1-5 state them: ([term], float32
+        boosts, the blended float32 idf), best boost first, ties by
+        term; ([], ...) where nothing is kept. A word that takes no edit
+        is itself, under its own idf. Vectorised over each segment's
+        `TermPlane`; kept a word, a reader is a point in time."""
+        key = (field, word, params)
+        hit = self._fuzzy_cache.get(key)
+        if hit is not None:
+            return hit
+        k = params.edits(word)
+        found: Dict[str, np.float32] = {}
+        if k == 0:
+            if self.reader.term_stats(field, word)[0] > 0:
+                found[word] = np.float32(1.0)
+        else:
+            for seg in self.reader.segments:
+                pf = seg.postings.get(field)
+                if pf is None or not pf.terms:
+                    continue
+                ids, boosts, _d = fuzzy_model.expand_word(
+                    pf.term_plane(), pf.terms, word, k,
+                    params.prefix_length, params.max_expansions,
+                    params.transpositions)
+                for i, b in zip(ids.tolist(), boosts):
+                    found[pf.terms[i]] = b
+        terms = sorted(found, key=lambda t: (-found[t], t))
+        terms = terms[: params.max_expansions]
+        boosts = np.array([found[t] for t in terms], np.float32)
+        idf = np.float32(0.0)
+        if terms:
+            dfs = DFS_STATS.get()
+            if dfs is not None and field in dfs.get("fields", {}):
+                dc = dfs["fields"][field][0]
+                known = dfs.get("terms", {}).get(field, {})
+                df = [known.get(t) or self.reader.term_stats(field, t)[0]
+                      for t in terms]
+            else:
+                dc = self.reader.field_stats(field)[0]
+                df = [self.reader.term_stats(field, t)[0] for t in terms]
+            idf = fuzzy_model.blended_idf(dc, np.asarray(df))
+        out = (terms, boosts, idf)
+        if DFS_STATS.get() is None:
+            if len(self._fuzzy_cache) >= FUZZY_CACHE_WORDS:
+                self._fuzzy_cache.pop(next(iter(self._fuzzy_cache)))
+            self._fuzzy_cache[key] = out
+        return out
+
+    def fuzzy_terms(self, field: str, word: str, params) -> List[str]:
+        """The kept terms alone (what a highlighter marks)."""
+        return self.fuzzy_expansion(field, word, params)[0]
+
+    def _exec_fuzzy_words(
+        self, seg: Segment, field: str, words: List[str], params,
+        boost: float, msm: int = 1,
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Equation 6 over one segment: every kept term of every word
+        scores boost(t) * idf(df*_w) under the text law; a document
+        matches where at least `msm` WORDS have a kept term in it."""
+        n = seg.num_docs
+        scores = np.zeros(n, np.float32)
+        words_hit = np.zeros(n, np.int32)
+        for w in words:
+            terms, boosts, idf = self.fuzzy_expansion(field, w, params)
+            if not terms:
                 continue
-            if prefix and not t.startswith(prefix):
-                continue
-            if _levenshtein_at_most(q.value, t, max_edits):
-                cands.append(t)
-                if len(cands) >= q.max_expansions:
-                    break
-        return cands
+            weights = fuzzy_model.term_weights(boost, idf, boosts)
+            hit = np.zeros(n, bool)
+            for t, wt in zip(terms, weights):
+                m, s = self._score_term_dense(seg, field, t, 1.0, weight=wt)
+                hit |= m
+                scores = (scores + s).astype(np.float32)
+            words_hit += hit
+        mask = words_hit >= max(1, msm)
+        return mask, np.where(mask, scores, 0).astype(np.float32)
 
     def _exec_fuzzy(self, q: "dsl.FuzzyQuery", seg: Segment) -> Tuple[np.ndarray, np.ndarray]:
-        n = seg.num_docs
-        cands = self._fuzzy_terms(q, seg)
-        mask = np.zeros(n, bool)
-        for t in cands:
-            m, _ = self._score_term_dense(seg, q.field, t, 1.0)
-            mask |= m
-        return mask, np.where(mask, np.float32(q.boost), 0).astype(np.float32)
+        """Lucene's FuzzyQuery under TopTermsBlendedFreqScoringRewrite:
+        scored, the best `max_expansions` terms."""
+        return self._exec_fuzzy_words(
+            seg, q.field, [q.value], q.params, q.boost)
 
     def _exec_dis_max(self, q: "dsl.DisMaxQuery", seg: Segment) -> Tuple[np.ndarray, np.ndarray]:
         n = seg.num_docs
@@ -1110,9 +1174,12 @@ class NumpyExecutor:
     # ---- leaves ----
 
     def _score_term_dense(
-        self, seg: Segment, field: str, term: str, boost: float
+        self, seg: Segment, field: str, term: str, boost: float,
+        weight: Optional[float] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """TermQuery scoring: dense (mask, scores) for one term."""
+        """TermQuery scoring: dense (mask, scores) for one term, under
+        boost x its own idf or, where a rewrite brings one (a fuzzy
+        word's blended weight), under `weight`."""
         n = seg.num_docs
         mask = np.zeros(n, bool)
         scores = np.zeros(n, np.float32)
@@ -1135,7 +1202,9 @@ class NumpyExecutor:
             norm_bytes = np.ones(len(docs), np.int64)
         else:
             norm_bytes = pf.norms[docs].astype(np.int64)
-        weight = np.float32(boost) * np.float32(self._term_weight(field, term))
+        if weight is None:
+            weight = np.float32(boost) * np.float32(
+                self._term_weight(field, term))
         cache = self._field_cache(field)
         s = bm25.score_freqs(tfs, norm_bytes, weight, cache)
         mask[docs] = True
@@ -1155,6 +1224,14 @@ class NumpyExecutor:
         if not terms:
             # analyzes to no tokens → matches nothing (MatchNoDocsQuery)
             return np.zeros(n, bool), np.zeros(n, np.float32)
+        if q.fuzzy is not None:
+            # every word a FuzzyQuery (a TermQuery where it takes no
+            # edit); a word is one counted clause
+            msm = len(terms) if q.operator == "and" else max(
+                1, dsl.parse_minimum_should_match(
+                    q.minimum_should_match, len(terms)))
+            return self._exec_fuzzy_words(
+                seg, q.field, terms, q.fuzzy, q.boost, msm)
         masks = []
         scores = np.zeros(n, np.float32)
         for t in terms:
@@ -1654,18 +1731,6 @@ def _included(path, includes, prefix_ok=False):
         if prefix_ok and fnmatch.fnmatch(path, inc + "*"):
             return True
     return False
-
-
-def _fuzziness_edits(fuzziness: str, term: str) -> int:
-    """Fuzziness.AUTO: 0 edits for length<3, 1 for 3-5, else 2."""
-    f = str(fuzziness).upper()
-    if f.startswith("AUTO"):
-        n = len(term)
-        return 0 if n < 3 else (1 if n <= 5 else 2)
-    try:
-        return max(0, min(int(float(f)), 2))
-    except ValueError:
-        raise QueryParseError(f"invalid fuzziness [{fuzziness}]")
 
 
 def _levenshtein_at_most(a: str, b: str, k: int) -> bool:

@@ -26,6 +26,7 @@ from ..common.tracing import note_transfer
 from ..index.mapping import DATE, KEYWORD, TEXT, parse_date_millis
 from ..index.segment import Segment
 from ..models import bm25
+from ..ops import fuzzy as fuzzy_ops
 from ..ops import scoring
 from . import dsl
 from .dsl import (
@@ -458,6 +459,9 @@ class JaxExecutor:
         # positions plane with this generation's inverse norms and live
         # mask in the plane's column order (`phrase_plane`)
         self._phrase_planes: Dict[Tuple[int, str], tuple] = {}
+        # (si, field) -> the fuzzy family's (dictionary plane on the
+        # device, wide fused scorer, each term's dense row or -1) | None
+        self._fuzzy_parts: Dict[Tuple[int, str], object] = {}
         self._seg_weights: Dict[Tuple[int, str], np.ndarray] = {}
         self._df_maps: Dict[str, Dict[str, int]] = {}
         self._shard_dfs: Dict[Tuple[str, str], int] = {}
@@ -1044,7 +1048,7 @@ class JaxExecutor:
         if isinstance(q, dsl.IdsQuery):
             return self._exec_ids(q, si)
         if isinstance(
-            q, (dsl.PrefixQuery, dsl.WildcardQuery, dsl.RegexpQuery, dsl.FuzzyQuery)
+            q, (dsl.PrefixQuery, dsl.WildcardQuery, dsl.RegexpQuery)
         ):
             # MultiTermQuery constant-score rewrite: dictionary expansion
             # stays on the host (as the reference's rewrites do), but the
@@ -1415,6 +1419,81 @@ class JaxExecutor:
             "rows_held": int(len(held)),
             "tf_overflow_postings": tf_overflow_postings,
         }
+
+    def fuzzy_parts(self, si: int, field: str):
+        """What the batcher's `fuzzy` family reads of one segment's text
+        field, built at the field's FIRST fuzzy search (a field no fuzzy
+        search names pays nothing) and kept: the term dictionary as a
+        device plane (ops/fuzzy.py `DeviceTermPlane`, charged to the HBM
+        ledger with the postings: 36 B a term), the fused scorer at the
+        family's own slot budgets over the field's resident postings and
+        dense rows (`scoring.FUZZY_T_RARE`, `FUZZY_H`: a program of its
+        own, so no other family's changes), and `hot_row`, each term's
+        dense row or -1. None: no postings, or a segment under
+        FUSED_MIN_DOCS (the unbatched executor serves it)."""
+        key = (si, field)
+        if key in self._fuzzy_parts:
+            return self._fuzzy_parts[key]
+        with self._build_lock:
+            if key in self._fuzzy_parts:
+                return self._fuzzy_parts[key]
+            parts = self.fused_parts(si, field)
+            built = None
+            if parts is not None:
+                pf = self.reader.segments[si].postings[field]
+                plane = fuzzy_ops.DeviceTermPlane(
+                    pf.term_plane(), len(pf.terms), self.device)
+                self._charge("postings", plane.nbytes, False)
+                hot_row = np.full(len(pf.terms), -1, np.int64)
+                if parts["hot_rank"]:
+                    tids = np.fromiter(parts["hot_rank"], np.int64)
+                    hot_row[tids] = np.fromiter(
+                        parts["hot_rank"].values(), np.int64)
+                built = {
+                    "plane": plane,
+                    "scorer": scoring.MultiFusedScorer(
+                        (field,), [parts], self.reader.live_docs[si],
+                        t_rare=scoring.FUZZY_T_RARE,
+                        n_hot_slots=scoring.FUZZY_H),
+                    "hot_row": hot_row,
+                    # terms by code points: `fuzzy_ops.least_work`'s
+                    "terms_by_len": np.bincount(pf.term_plane().lens[
+                        : len(pf.terms)]),
+                    "parts": parts,
+                }
+            self._fuzzy_parts[key] = built
+            return built
+
+    def fuzzy_plan_field(self, si: int, field: str, fz, ords: np.ndarray,
+                         weights: np.ndarray):
+        """One job's section of the fuzzy family's fused plan from its
+        kept terms' ordinals in this segment's dictionary and their
+        float32 weights (a term two words kept rides twice, under each
+        word's weight): (rare tiles, their weights, hot rows, their
+        weights), every term a clause of its own that counts; None where
+        the plan passes a slot budget. No loop over the terms: a
+        question brings hundreds."""
+        pf = self.reader.segments[si].postings[field]
+        rows = fz["hot_row"][ords]
+        hot = rows >= 0
+        hr, hw = rows[hot], weights[hot]
+        cold = ords[~hot]
+        counts = pf.term_tile_count[cold].astype(np.int64)
+        n_tiles = int(counts.sum())
+        if n_tiles > scoring.FUZZY_T_RARE or len(hr) > scoring.FUZZY_H:
+            return None
+        first = np.cumsum(counts) - counts
+        rt = (np.arange(n_tiles, dtype=np.int64) - np.repeat(first, counts)
+              + np.repeat(pf.term_tile_start[cold].astype(np.int64), counts))
+        rw = np.repeat(weights[~hot], counts)
+        if fz["parts"]["wide"] is not None:
+            hr, hw = list(hr), list(hw)
+            scoring.wide_rows_first(hr, hw, fz["parts"]["dense"])
+            hr, hw = np.asarray(hr, np.int64), np.asarray(hw, np.float32)
+        return rt, rw.astype(np.float32), hr, hw.astype(np.float32)
+
+    def fuzzy_terms(self, field: str, word: str, params):
+        return self._oracle.fuzzy_terms(field, word, params)
 
     def fused_plan_field(
         self, si: int, field: str, parts, terms_flagged, boost: float
@@ -1787,6 +1866,11 @@ class JaxExecutor:
             return self._exec_term(
                 TermQuery(field=q.field, value=q.query, boost=q.boost), si
             )
+        if q.fuzzy is not None:
+            # a fuzzy `match` no planner took (inside a bool, a shard of
+            # several segments' fallback): the oracle's rewrite
+            hm, hs = self._oracle._exec(q, seg)
+            return jnp.asarray(hm), jnp.asarray(hs)
         analyzer_name = q.analyzer or mf.search_analyzer or mf.analyzer
         terms = self.reader.analysis.get(analyzer_name).terms(q.query)
         if not terms:
@@ -1821,14 +1905,15 @@ class JaxExecutor:
         return dmask, jnp.where(dmask, jnp.float32(q.boost), 0.0)
 
     def _exec_expanded(self, q, si: int) -> Tuple[jax.Array, jax.Array]:
-        """prefix/wildcard/regexp/fuzzy: host term-dict expansion, then
-        the expanded terms score as one device launch (constant score)."""
+        """prefix/wildcard/regexp: host term-dict expansion, then the
+        expanded terms score as one device launch (constant score).
+        `fuzzy` is not among them: Lucene scores its best
+        `max_expansions` terms (TopTermsBlendedFreqScoringRewrite), which
+        the batcher's `fuzzy` family serves on the device and, inside
+        another query, the oracle (`_exec`'s last branch)."""
         seg = self.reader.segments[si]
         n = seg.num_docs
-        if isinstance(q, dsl.FuzzyQuery):
-            terms = self._oracle._fuzzy_terms(q, seg)
-        else:
-            terms = self._oracle._expand_terms(q, seg)
+        terms = self._oracle._expand_terms(q, seg)
         if not terms:
             return jnp.zeros(n, bool), jnp.zeros(n, jnp.float32)
         _, cnt = self._field_terms_scored(si, q.field, terms, 1.0)

@@ -9,9 +9,11 @@ matching tokens are located by their character offsets, and fragments of
 ~fragment_size characters are cut around match runs.
 
 Term extraction walks the parsed query tree per field (the
-WeightedSpanTermExtractor analog), including multi-term expansions
-(prefix/wildcard/regexp/fuzzy are expanded against the segment term
-dictionary by the caller's executor, so here we accept plain term sets).
+WeightedSpanTermExtractor analog). A `fuzzy` query and a `match` with
+`fuzziness` mark what they SCORE: each word's kept terms, the best
+`max_expansions` of the shard's dictionary by Lucene's blended-frequency
+rewrite (models/fuzzy.py), which the caller's executor hands in as
+`expand`; prefix / wildcard / regexp mark their raw value, a best effort.
 """
 
 from __future__ import annotations
@@ -23,9 +25,17 @@ from .executor import expand_match_fields
 
 
 def extract_highlight_terms(
-    query: Optional[dsl.Query], mappings, analysis
+    query: Optional[dsl.Query], mappings, analysis, expand=None
 ) -> Dict[str, Set[str]]:
-    """field → analyzed query terms that should highlight."""
+    """field → analyzed query terms that should highlight. `expand`
+    (field, word, FuzzyParams) -> the word's kept terms, an executor's
+    `fuzzy_terms`; without it a fuzzy word marks itself alone."""
+
+    def fuzzy(field: str, words, params) -> List[str]:
+        if expand is None:
+            return list(words)
+        return [t for w in words for t in expand(field, w, params)]
+
     out: Dict[str, Set[str]] = {}
 
     def add(field: str, terms) -> None:
@@ -43,7 +53,11 @@ def extract_highlight_terms(
         if q is None:
             return
         if isinstance(q, dsl.MatchQuery):
-            add(q.field, analyzed(q.field, q.query))
+            words = analyzed(q.field, q.query)
+            add(q.field, words if q.fuzzy is None
+                else fuzzy(q.field, words, q.fuzzy))
+        elif isinstance(q, dsl.FuzzyQuery):
+            add(q.field, fuzzy(q.field, [q.value], q.params))
         elif isinstance(q, dsl.MatchPhraseQuery):
             add(q.field, analyzed(q.field, q.query))
         elif isinstance(q, dsl.TermQuery):
@@ -53,7 +67,7 @@ def extract_highlight_terms(
         elif isinstance(q, dsl.MultiMatchQuery):
             for fname, _ in expand_match_fields(mappings, q.fields):
                 add(fname, analyzed(fname, q.query))
-        elif isinstance(q, (dsl.PrefixQuery, dsl.WildcardQuery, dsl.RegexpQuery, dsl.FuzzyQuery)):
+        elif isinstance(q, (dsl.PrefixQuery, dsl.WildcardQuery, dsl.RegexpQuery)):
             # marker: caller may expand against the dictionary; highlight
             # the raw value as a best effort
             add(q.field, [q.value.lower()])

@@ -48,6 +48,13 @@ KEYED = {
             "filter": (None, "tags:t1")},
     # `width`: a phrase's span in slots is one program; its words are not
     "phrase": {"field": ("body", "title"), "width": (2, 3)},
+    # what is static in the expansion program (`max_expansions`,
+    # `transpositions`) is keyed on; the fuzziness and the words are not
+    "fuzzy": {"field": ("body", "title"),
+              "params": (SimpleNamespace(max_expansions=50,
+                                         transpositions=True),
+                         SimpleNamespace(max_expansions=50,
+                                         transpositions=False))},
     "agg": {"sig": ("terms:tag", "terms:cat")},
     "rerank": {"sig": ("m1:16:8", "m1:32:8")},
     "sparse": {"field": ("sv", "sv2"), "spec": ("fp32", "int8")},
@@ -65,8 +72,8 @@ KEYED = {
     "mesh_agg": {"sig": ("terms:tag", "terms:cat")},
 }
 OVERLAP = {
-    "match": "text", "serve": "text", "phrase": "text", "knn": "knn",
-    "agg": "agg",
+    "match": "text", "serve": "text", "phrase": "text", "fuzzy": "text",
+    "knn": "knn", "agg": "agg",
     "rerank": "rerank", "sparse": "sparse", "mesh_match": "text",
     "mesh_serve": "text", "mesh_knn": "knn", "mesh_sparse": "sparse",
     "mesh_agg": "agg",
@@ -110,7 +117,7 @@ def groups_of(batcher, monkeypatch, jobs):
 
 
 class TestTheTable:
-    def test_holds_the_twelve_kinds_and_their_overlap_classes(self, batcher):
+    def test_holds_the_thirteen_kinds_and_their_overlap_classes(self, batcher):
         assert {k: f.overlap for k, f in FAMILIES.items()} == OVERLAP
         assert set(batcher._inflight) == set(OVERLAP.values())
         assert {k for k, f in FAMILIES.items() if f.mesh} == {
@@ -118,7 +125,7 @@ class TestTheTable:
         }
         # the kinds whose first dispatch warms the bucket ladder
         assert {k for k, f in FAMILIES.items() if f.warm} == {
-            "match", "serve", "phrase", "knn", "sparse",
+            "match", "serve", "phrase", "fuzzy", "knn", "sparse",
         }
 
     def test_every_family_is_a_dispatch_collect_pair(self):

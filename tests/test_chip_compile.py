@@ -318,6 +318,49 @@ def test_phrase_program(one_chip, rows):
 
 
 # ---------------------------------------------------------------------------
+# the fuzzy family: the expansion program over the passage shard's
+# dictionary plane, and the fused program at the family's slot budgets
+# ---------------------------------------------------------------------------
+
+FUZZY_TERMS = 894_836  # msmarco-fuzzy-match's spelled dictionary
+PASSAGE_TILES = 1_160_811  # the passage shard's tiles, 500 dense rows
+
+
+def test_fuzzy_expansion_and_its_wide_fused_program(one_chip):
+    from elasticsearch_tpu.models.fuzzy import PLANE_FRONT, PLANE_LEN
+    from elasticsearch_tpu.ops import fuzzy as fuzzy_ops
+
+    s = _on(one_chip)
+    width = -(-FUZZY_TERMS // 1024) * 1024
+    slots = fuzzy_ops.word_slots(1)
+    compiled = fuzzy_ops.fuzzy_expand.lower(
+        s((PLANE_FRONT + PLANE_LEN, width), jnp.uint8),
+        s((width,), jnp.int32),
+        s((slots + 1, fuzzy_ops._SLOT), jnp.int32),
+        keep=50, transpositions=True,
+    ).compile()
+    m = compiled.memory_analysis()
+    assert slots * 100 * 4 <= m.output_size_in_bytes <= 2 * slots * 100 * 4
+    # the band lives in bytes: two rows of five diagonals a term and the
+    # keys, not a table a word
+    assert m.temp_size_in_bytes < 64 * width, m.temp_size_in_bytes
+    assert _fits(compiled) + 3 * 1024**3 < HBM_BYTES
+    T, H = scoring.FUZZY_T_RARE, scoring.FUZZY_H
+    assert T > scoring.FUSED_T_RARE and H > scoring.FUSED_H
+    wide = scoring._fused_query_mf.lower(
+        (s((PASSAGE_TILES, TILE), jnp.int32),),
+        (s((PASSAGE_TILES, TILE), jnp.int32),),
+        (s((N_DOCS,), jnp.float32),),
+        (s((500, N_DOCS), jnp.uint8),),
+        None,
+        s((32, 2 * T + 2 * H + 1), jnp.int32),
+        None,
+        t_rare=T, n_hot=H, k=16, combine="sum", counted=False,
+    ).compile()
+    _fits(wide)
+
+
+# ---------------------------------------------------------------------------
 # fused text programs at the plan shape the batcher sends
 # ---------------------------------------------------------------------------
 

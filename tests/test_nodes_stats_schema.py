@@ -44,6 +44,12 @@ REQUIRED = {
         "slots_padded", "least_bytes", "window_ties_refilled",
         "window_block_selected",
     },
+    # the benchmark's `fuzzy_*` metrics read these by dotted path
+    "fuzzy": {
+        "requests", "words", "words_expanded", "terms_kept",
+        "words_saturated", "hot_terms", "tiles", "overflows", "fallbacks",
+        "launches", "score_launches", "least_bytes", "least_cells",
+    },
     "sparse": {"batched_jobs"},
     "translog": {
         "uncommitted_ops", "uncommitted_bytes", "pending_unsynced_ops",
