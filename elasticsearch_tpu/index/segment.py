@@ -133,12 +133,12 @@ class PostingsField:
         """The dictionary as the fuzzy expansion reads it (models/fuzzy.py
         `TermPlane`: code points transposed, lengths), built at the
         field's first fuzzy search and kept: a segment is immutable. Its
-        columns are padded to a multiple of 1,024, so the device's copy
-        (ops/fuzzy.py) is the same array."""
+        columns are padded to whole blocks of the expansion kernel, so
+        the device's copy (ops/fuzzy.py) is the same array, reshaped."""
         if self._term_plane is None:
-            from ..models.fuzzy import build_term_plane
+            from ..models.fuzzy import PLANE_PAD, build_term_plane
 
-            self._term_plane = build_term_plane(self.terms, pad_to=1024)
+            self._term_plane = build_term_plane(self.terms, pad_to=PLANE_PAD)
         return self._term_plane
 
     def term_docs(self, tid: int) -> np.ndarray:

@@ -48,6 +48,9 @@ PLANE_LEN = 32
 # starts at row i and never leaves the array
 PLANE_FRONT = MAX_EDITS + 2
 NO_TERM_LEN = 255  # the length of a padding column: no word reaches it
+# columns a plane that goes to the device is padded to: a block of the
+# expansion kernel (ops/fuzzy.py), 128 sublane rows of 128 lanes
+PLANE_PAD = 128 * 128
 
 
 class FuzzinessError(ValueError):

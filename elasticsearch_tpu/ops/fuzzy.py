@@ -8,12 +8,12 @@ The dictionary is a plane the segment keeps beside its postings
 lengths; 36 B a term, 32 MB at the passage shard's 894,836 terms),
 uploaded at the field's first fuzzy search. One program, `fuzzy_expand`,
 serves a launch: for each word, in a loop that runs as many trips as the
-launch holds words, it walks the word's code points (a trip a code
-point: one row of the banded optimal-string-alignment table against
-EVERY term at once, `V`-wide integer vector work with no matrix product
-in it), reads each term's distance off row m, keys the candidates by
-(boost class, ordinal) and selects the best `keep` with one top-k (from
-block maxima on a wide plane: `scoring._block_topk`). The
+launch holds words, it computes the word's banded
+optimal-string-alignment table against every term (integer vector work
+with no matrix product in it), reads each term's distance off row m,
+keys the candidates by (boost class, ordinal) and selects the best
+`keep` with one top-k (from block maxima on a wide plane:
+`scoring._block_topk`). The
 key is exact: a word of m code points at most MAX_EDITS edits away has
 at most 1 + MAX_EDITS * (MAX_EDITS + 1) distinct boosts (d / min(m,
 len(t)), len(t) >= m - d), the host ranks them as fractions
@@ -21,6 +21,24 @@ len(t)), len(t) >= m - d), the host ranks them as fractions
 which are distinct, so the top-k has no tie to break. Every term within
 a word's edits is a candidate; none is skipped because a bound says it
 is unlikely.
+
+The table is walked a BLOCK of dictionary columns at a time
+(`_block_kernel`, a Pallas kernel with a grid over the plane's blocks of
+PLANE_PAD columns): the block's 36 plane rows are read once and widened
+to int32 in VMEM, then the word's m rows are looped INSIDE the block,
+the ten band rows of rows i - 1 and i - 2 the loop's values, so the band
+never reaches HBM, and only the distances are written. The columns lie
+over sublanes AND lanes (the plane is `[rows, width / 128, 128]`), so
+every vector register the recurrence touches is full (a band stacked
+`[5, width]` lies along the sublanes: an operation on one diagonal
+fills an eighth of each register it touches, and the stack is rebuilt
+every row: 0.34 ms a row at 894,976 columns against 0.0175 here,
+PERF.md section 6, PR 55 and PR 56). Mosaic compiles
+the kernel, so it serves where the plane lives on a TPU
+(`DeviceTermPlane.blocked`); elsewhere the same `_word_table` runs as a
+plain loop over the rows with every column at once
+(`_distances_plain`), which is also the kernel's reference in the tests.
+Both call the one `band_row`.
 
 What it reads that it need not: every term, whatever its length (a word
 of m code points can only reach lengths m - k .. m + k: a plane ordered
@@ -39,16 +57,13 @@ import numpy as np
 
 from ..common.tracing import launch, note_transfer
 from ..models import fuzzy as fuzzy_model
-from ..models.fuzzy import BAND, MAX_EDITS, PLANE_LEN
+from ..models.fuzzy import BAND, MAX_EDITS, PLANE_LEN, PLANE_PAD
 from . import scoring
 
 # words a query row may bring to a launch: the launch's word slots are
 # rows x this, a shape of the row bucket alone (a question of 2-12 words
 # fits; a longer one is the unbatched executor's)
 WORDS_PER_ROW = 16
-# a cell of the band is 0 .. MAX_EDITS + 1: a byte, so a trip reads and
-# writes a quarter of what int32 rows would
-_STATE = jnp.int8
 # the longest word the plane answers: a longer one could reach a term
 # that is kept apart (`TermPlane.long_ids`)
 MAX_WORD_LEN = PLANE_LEN - MAX_EDITS
@@ -60,6 +75,16 @@ _CLASSES = (MAX_EDITS + 1) * (MAX_EDITS + 1)
 # then m, k and the class ranks; the launch's word count rides slot 0
 _SLOT = 1 + PLANE_LEN + 2 + _CLASSES
 
+LANES = 128
+# sublane rows of a block of the plane: PLANE_PAD columns, whole native
+# tiles of a plane of one, two or four bytes a code point (32 / 16 / 8
+# rows). 128 and 32 read fastest of the sizes probed on the chip (a
+# smaller block pays more grid steps a word, a smaller chunk leaves
+# vector slots empty, a larger one spills: PERF.md section 6, PR 56)
+BLOCK_ROWS = PLANE_PAD // LANES
+# sublane rows whose band the kernel walks at once
+CHUNK_ROWS = 32
+
 
 def word_slots(rows: int) -> int:
     """The word slots of a launch of `rows` query rows: two shapes, a
@@ -70,16 +95,26 @@ def word_slots(rows: int) -> int:
 
 
 class DeviceTermPlane:
-    """A `TermPlane` on the device, in dictionary order."""
+    """A `TermPlane` on the device, in dictionary order, as the
+    expansion reads it: `chars` [rows, width / 128, 128] (column c at
+    [c // 128, c % 128]: the columns fill sublanes and lanes), `lens`
+    int32[width], the width whole blocks of PLANE_PAD columns.
+    `blocked`: the plane lives on a TPU, where the blocked kernel
+    serves (`fuzzy_expand`)."""
 
     def __init__(self, plane: fuzzy_model.TermPlane, n_terms: int,
                  device=None):
-        self.chars = jax.device_put(plane.chars, device)
+        rows, width = plane.chars.shape
+        if width % PLANE_PAD or width > _ORDINAL_MASK:
+            raise ValueError(
+                "a dictionary plane of %d columns: not whole blocks of %d,"
+                " or past the key's ordinal bits" % (width, PLANE_PAD))
+        self.chars = jax.device_put(
+            plane.chars.reshape(rows, width // LANES, LANES), device)
         self.lens = jax.device_put(plane.lens, device)
         self.n_terms = n_terms
         self.nbytes = plane.nbytes
-        if self.chars.shape[1] > _ORDINAL_MASK:
-            raise ValueError("a dictionary past the key's ordinal bits")
+        self.blocked = next(iter(self.chars.devices())).platform == "tpu"
 
 
 def pack_words(words: Sequence[Tuple[np.ndarray, int]], slots: int):
@@ -97,35 +132,111 @@ def pack_words(words: Sequence[Tuple[np.ndarray, int]], slots: int):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("keep", "transpositions"))
-def fuzzy_expand(chars, lens, packed, *, keep: int, transpositions: bool):
+def _word_table(slab_at, code_point, m, lens, transpositions: bool):
+    """D[m][len(t)] of one word against the columns `lens` is shaped as
+    (min(distance, CAP), int32): rows 1 .. m of the banded table, the
+    ten band rows of rows i - 1 and i - 2 carried as separate arrays.
+    `slab_at(i)`: the plane's rows i .. i + BAND over those columns,
+    int32; `code_point(i)`: the word's code point i - 1 (0 at i = 0)."""
+    # (tied to `lens`, which is never negative: a loop's carry cannot
+    # start as a constant in the kernel, Mosaic lays a constant out
+    # replicated and the rows it becomes not)
+    row0, none = ([jnp.maximum(cell, lens - (1 << 20)) for cell in r]
+                  for r in fuzzy_model.first_rows(jnp, lens, jnp.int32))
+
+    def table_row(i, carry):
+        prev, prev2 = carry
+        cur = fuzzy_model.band_row(
+            jnp, i, list(prev), list(prev2), slab_at(i),
+            code_point(i), code_point(i - 1), transpositions)
+        return tuple(cur), prev
+
+    last, _ = jax.lax.fori_loop(
+        1, m + 1, table_row, (tuple(row0), tuple(none)))
+    return fuzzy_model.last_cell(jnp, m, lens, list(last))
+
+
+def _block_kernel(row_ref, chars_ref, lens_ref, dist_ref, wide_ref, *,
+                  transpositions: bool):
+    """One block of columns of one word's table: the block's plane rows
+    widened once, then the word's rows walked a chunk of sublane rows at
+    a time, the band never out of the chip's registers / VMEM."""
+    from jax.experimental import pallas as pl
+
+    wide_ref[...] = chars_ref[...].astype(jnp.int32)
+    m = row_ref[1 + PLANE_LEN]
+
+    def chunk(c, carry):
+        at = pl.ds(pl.multiple_of(c * CHUNK_ROWS, CHUNK_ROWS), CHUNK_ROWS)
+        dist_ref[at, :] = _word_table(
+            lambda i: [wide_ref[i + e, at, :] for e in range(BAND + 1)],
+            lambda i: row_ref[i], m, lens_ref[at, :], transpositions)
+        return carry
+
+    jax.lax.fori_loop(0, BLOCK_ROWS // CHUNK_ROWS, chunk, 0)
+
+
+def _distances_blocked(chars, lens, row, transpositions: bool,
+                       interpret: bool):
+    """The word of slot `row` against every column, a block at a time
+    (grid: the plane's blocks; the slot rides in SMEM)."""
+    # (here, as scoring imports its kernel: Pallas costs a second to
+    # import, which a process that never expands a word need not pay)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, sub, _ = chars.shape
+    block = pl.BlockSpec((BLOCK_ROWS, LANES), lambda b, *_: (b, 0))
+    return pl.pallas_call(
+        functools.partial(_block_kernel, transpositions=transpositions),
+        out_shape=jax.ShapeDtypeStruct((sub, LANES), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(sub // BLOCK_ROWS,),
+            in_specs=[
+                pl.BlockSpec((rows, BLOCK_ROWS, LANES),
+                             lambda b, *_: (0, b, 0)),
+                block,
+            ],
+            out_specs=block,
+            scratch_shapes=[pltpu.VMEM((rows, BLOCK_ROWS, LANES), jnp.int32)],
+        ),
+        interpret=interpret,
+    )(row, chars, lens)
+
+
+def _distances_plain(chars, lens, row, transpositions: bool):
+    """The same by a plain loop over the table's rows, every column at
+    once (the band through memory a row): the form off the chip, and the
+    kernel's reference."""
+    return _word_table(
+        lambda i: list(jax.lax.dynamic_slice_in_dim(
+            chars, i, BAND + 1, axis=0).astype(jnp.int32)),
+        lambda i: row[i], row[1 + PLANE_LEN], lens, transpositions)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "keep", "transpositions", "blocked", "interpret"))
+def fuzzy_expand(chars, lens, packed, *, keep: int, transpositions: bool,
+                 blocked: bool = False, interpret: bool = False):
     """-> int32[slots, 2 * keep]: each word's kept ordinals (best boost
-    first, ties by ordinal; -1 past the last) and their distances."""
+    first, ties by ordinal; -1 past the last) and their distances.
+    `blocked` (`DeviceTermPlane.blocked`): the table by the blocked
+    kernel, which Mosaic compiles (`interpret`: off the chip, tests)."""
     slots = packed.shape[0] - 1
     n_words = packed[0, 0]
     width = lens.shape[0]
     pos = jnp.arange(width, dtype=jnp.int32)
+    lens_sl = lens.reshape(chars.shape[1:])
 
     def one_word(wi, out):
         row = jax.lax.dynamic_index_in_dim(packed, wi + 1, keepdims=False)
-        word = row[: 1 + PLANE_LEN]
         m, k = row[1 + PLANE_LEN], row[2 + PLANE_LEN]
         ranks = row[3 + PLANE_LEN:]
-        row0, none = fuzzy_model.first_rows(jnp, lens, _STATE)
-
-        def table_row(i, carry):
-            prev, prev2 = carry
-            slab = jax.lax.dynamic_slice_in_dim(
-                chars, i, BAND + 1, axis=0).astype(jnp.int32)
-            cur = fuzzy_model.band_row(
-                jnp, i, list(prev), list(prev2), list(slab),
-                word[i], word[i - 1], transpositions)
-            return jnp.stack(cur), prev
-
-        last, _ = jax.lax.fori_loop(
-            1, m + 1, table_row, (jnp.stack(row0), jnp.stack(none)))
-        dist = fuzzy_model.last_cell(jnp, m, lens, list(last)).astype(
-            jnp.int32)
+        dist = (_distances_blocked(chars, lens_sl, row, transpositions,
+                                   interpret) if blocked
+                else _distances_plain(chars, lens_sl, row, transpositions)
+                ).reshape(width)
         # the candidate's boost class: (distance, how much shorter than
         # the word the shorter of the two is)
         shorter = m - jnp.minimum(m, lens)
@@ -167,7 +278,8 @@ def expand_async(plane: DeviceTermPlane, words: List[Tuple[np.ndarray, int]],
     cells = sum(len(cp) * BAND for cp, _k in words) * plane.n_terms
     with launch("fuzzy_expand", 1, packed.nbytes, cells):
         return fuzzy_expand(plane.chars, plane.lens, packed, keep=keep,
-                            transpositions=transpositions)
+                            transpositions=transpositions,
+                            blocked=plane.blocked)
 
 
 def decode(out: np.ndarray, n_words: int, keep: int):

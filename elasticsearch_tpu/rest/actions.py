@@ -703,8 +703,8 @@ class RestActions:
         # slot budget or found no plane, the expansion's least work
         fuzzy = dict.fromkeys((
             "requests", "words", "words_expanded", "terms_kept", "words_saturated", "hot_terms", "tiles",
-            "overflows", "fallbacks", "launches", "score_launches",
-            "least_bytes", "least_cells"), 0)
+            "overflows", "fallbacks", "launches", "blocked_launches",
+            "score_launches", "least_bytes", "least_cells"), 0)
         for idx in self.cluster.indices.values():
             with idx._rrf_lock:
                 for k, v in idx.rrf_stats.items():

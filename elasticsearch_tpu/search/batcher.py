@@ -1466,15 +1466,16 @@ class QueryBatcher:
         # the device), terms kept and words that kept all `max_expansions`,
         # of the plans: dense rows and tiles, jobs that passed a slot
         # budget (`overflows`) or found no plane (`fallbacks`) and were
-        # served by the unbatched executor; expansion launches, and what
-        # ANY exact expansion of the words would read and compute
+        # served by the unbatched executor; expansion launches (and those
+        # the blocked kernel served: a plane on a TPU), and what ANY exact
+        # expansion of the words would read and compute
         # (ops/fuzzy.least_work: the benchmark's roofline)
         self.fuzzy = {
             "requests": 0, "words": 0, "words_expanded": 0,
             "terms_kept": 0, "words_saturated": 0,
             "hot_terms": 0, "tiles": 0, "overflows": 0, "fallbacks": 0,
-            "launches": 0, "score_launches": 0, "least_bytes": 0,
-            "least_cells": 0,
+            "launches": 0, "blocked_launches": 0, "score_launches": 0,
+            "least_bytes": 0, "least_cells": 0,
         }
         # per-bucket launch histogram + occupancy sums (guarded by
         # self._lock; surfaced via batching_stats() → _nodes/stats):
@@ -2818,6 +2819,7 @@ class QueryBatcher:
                 fzs["tiles"] += sum(rare)
                 fzs["overflows"] += sum(p is None for p in fplans)
                 fzs["launches"] += bool(sent)
+                fzs["blocked_launches"] += bool(sent) and fz["plane"].blocked
                 fzs["score_launches"] += 1
                 fzs["least_bytes"] += nbytes
                 fzs["least_cells"] += cells

@@ -327,24 +327,39 @@ PASSAGE_TILES = 1_160_811  # the passage shard's tiles, 500 dense rows
 
 
 def test_fuzzy_expansion_and_its_wide_fused_program(one_chip):
-    from elasticsearch_tpu.models.fuzzy import PLANE_FRONT, PLANE_LEN
+    from elasticsearch_tpu.models.fuzzy import (
+        PLANE_FRONT, PLANE_LEN, PLANE_PAD)
     from elasticsearch_tpu.ops import fuzzy as fuzzy_ops
 
     s = _on(one_chip)
-    width = -(-FUZZY_TERMS // 1024) * 1024
-    slots = fuzzy_ops.word_slots(1)
-    compiled = fuzzy_ops.fuzzy_expand.lower(
-        s((PLANE_FRONT + PLANE_LEN, width), jnp.uint8),
-        s((width,), jnp.int32),
-        s((slots + 1, fuzzy_ops._SLOT), jnp.int32),
-        keep=50, transpositions=True,
-    ).compile()
-    m = compiled.memory_analysis()
-    assert slots * 100 * 4 <= m.output_size_in_bytes <= 2 * slots * 100 * 4
-    # the band lives in bytes: two rows of five diagonals a term and the
-    # keys, not a table a word
-    assert m.temp_size_in_bytes < 64 * width, m.temp_size_in_bytes
-    assert _fits(compiled) + 3 * 1024**3 < HBM_BYTES
+    width = -(-FUZZY_TERMS // PLANE_PAD) * PLANE_PAD
+    sub = width // fuzzy_ops.LANES
+    # a lone request's launch and a full one: Mosaic compiles the
+    # blocked kernel inside both
+    for rows in (1, 32):
+        slots = fuzzy_ops.word_slots(rows)
+        compiled = fuzzy_ops.fuzzy_expand.lower(
+            s((PLANE_FRONT + PLANE_LEN, sub, fuzzy_ops.LANES), jnp.uint8),
+            s((width,), jnp.int32),
+            s((slots + 1, fuzzy_ops._SLOT), jnp.int32),
+            keep=50, transpositions=True, blocked=True,
+        ).compile()
+        m = compiled.memory_analysis()
+        assert (slots * 100 * 4 <= m.output_size_in_bytes
+                <= 2 * slots * 100 * 4)
+        hlo = compiled.as_text()
+        assert hlo.count("tpu_custom_call") == 1
+        # the band never leaves the kernel: no loop of the program
+        # carries rows as wide as the plane beside the lengths (the plain
+        # row loop carries ten, the program before it two of
+        # `[5, width]`), and what the program keeps beside its operands
+        # is a word's distances and keys, not a table
+        wide_row = "s32[%d,%d]" % (sub, fuzzy_ops.LANES)
+        loops = [ln for ln in hlo.splitlines() if " while(" in ln]
+        assert loops and max(ln.count(wide_row) for ln in loops) <= 1, loops
+        assert "[5,%d]" % width not in hlo
+        assert m.temp_size_in_bytes < 64 * width, m.temp_size_in_bytes
+        assert _fits(compiled) + 3 * 1024**3 < HBM_BYTES
     T, H = scoring.FUZZY_T_RARE, scoring.FUZZY_H
     assert T > scoring.FUSED_T_RARE and H > scoring.FUSED_H
     wide = scoring._fused_query_mf.lower(

@@ -469,7 +469,7 @@ def test_a_wide_dictionary_is_selected_from_block_maxima_and_exactly():
     spelled = load_plugin("corpora", "zipf_text_spelled").spell(
         70000, 23, law)
     terms = sorted(spelled)
-    plane = fuzzy_model.build_term_plane(terms, pad_to=1024)
+    plane = fuzzy_model.build_term_plane(terms, pad_to=fuzzy_model.PLANE_PAD)
     dev = fuzzy_ops.DeviceTermPlane(plane, len(terms))
     width = dev.lens.shape[0]
     assert width // (128 * 128) * 128 >= 8 * 50

@@ -48,7 +48,8 @@ REQUIRED = {
     "fuzzy": {
         "requests", "words", "words_expanded", "terms_kept",
         "words_saturated", "hot_terms", "tiles", "overflows", "fallbacks",
-        "launches", "score_launches", "least_bytes", "least_cells",
+        "launches", "blocked_launches", "score_launches", "least_bytes",
+        "least_cells",
     },
     "sparse": {"batched_jobs"},
     "translog": {

@@ -727,6 +727,10 @@ class TestPhaseSpans:
         try:
             fused_service.search(json.loads(json.dumps(MATCH)))
             http_call(http_server, "GET", "/_internal/traces?n=4")
+            # the connection's thread leaves `es.http` after the
+            # response's last byte, which the client may read first: an
+            # annotation still open when the session stops is not written
+            time.sleep(0.05)
         finally:
             jax.profiler.stop_trace()
         (path,) = glob.glob(str(
