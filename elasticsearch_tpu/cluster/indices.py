@@ -433,6 +433,32 @@ class IndexService:
     """The shard set of one index (see module docstring for the two
     deployment shapes)."""
 
+    # The index's own counters in the node's document (`_nodes/stats`), at
+    # zero, under their blocks' dotted paths: `node_stats` hands back the
+    # counts, a node with no index reports these (`node_stats_schema`).
+    NODE_STATS = {
+        # hybrid (RRF) searches: how many, how many fused on the device
+        # (0: the serving path has no device fuse) and on the host (every
+        # search), the fuse's and the legs' summed milliseconds, a leg
+        # from the legs' common start to its own completion mark, so
+        # overlapped legs sum to MORE than the request wall time
+        "pipeline.rrf": {
+            "searches": 0,
+            "bm25_leg_ms": 0.0,
+            "knn_leg_ms": 0.0,
+            "sparse_leg_ms": 0.0,
+            "fuse_ms": 0.0,
+            "device_fused": 0,
+            "host_fused": 0,
+        },
+        # `_fan_out` calls by where the shards ran: on the request's own
+        # thread (one local shard whose wait polls the task itself) or in
+        # the fan-out pool
+        "thread_pool.search.fan_out": {"inline": 0, "pooled": 0},
+        # indices whose background refresher thread is alive
+        "ingest": {"refreshers_running": 0},
+    }
+
     def __init__(
         self,
         name: str,
@@ -542,25 +568,12 @@ class IndexService:
         # from the dynamic search.slowlog.threshold.* index settings
         self._slowlog = SearchSlowLog(self.name)
         self._slowlog.configure(self.settings)
-        # hybrid (RRF) execution breakdown: cumulative per-leg wall
-        # times measured from leg fan-out start, so overlapped legs sum
-        # to MORE than the request wall time — bench.py reports the
-        # averages (bm25_leg_ms / knn_leg_ms / fuse_ms)
+        # the hybrid searches' and the fan-outs' counters (NODE_STATS)
         self._rrf_lock = threading.Lock()
-        self.rrf_stats = {
-            "searches": 0,
-            "bm25_leg_ms": 0.0,
-            "knn_leg_ms": 0.0,
-            "sparse_leg_ms": 0.0,
-            "fuse_ms": 0.0,
-            "device_fused": 0,
-            "host_fused": 0,
-        }
-        # `_fan_out` calls by where the shards ran: on the request's own
-        # thread (one local shard whose wait polls the task itself) or
-        # in the fan-out pool (`thread_pool.search.fan_out.*`)
+        self.rrf_stats = dict(self.NODE_STATS["pipeline.rrf"])
         self._fan_out_lock = threading.Lock()
-        self.fan_out_stats = {"inline": 0, "pooled": 0}
+        self.fan_out_stats = dict(
+            self.NODE_STATS["thread_pool.search.fan_out"])
         # bounded per-leg latency reservoirs (newest-wins) so bench.py
         # can report per-leg p50/p99 next to the cumulative averages —
         # kept OUTSIDE rrf_stats, whose values are reset-to-zero numbers
@@ -4422,6 +4435,51 @@ class IndexService:
             "merge_total": ops["merge_total"],
             "segments": sum(len(s.segments) for s in shards),
         }
+
+    def node_stats(self) -> List[Dict[str, dict]]:
+        """What this index adds to the node's document: one map a layer
+        that counts (the index itself, its batcher, its local shards'
+        engines, its device executors, its mesh executor), each
+        {a block's dotted path: its leaves}. The node folds the maps of
+        all its indices leaf by leaf (`node_stats_schema`)."""
+        with self._rrf_lock:
+            rrf = dict(self.rrf_stats)
+        with self._fan_out_lock:
+            fan_out = dict(self.fan_out_stats)
+        r = self._refresher
+        out = [{
+            "pipeline.rrf": rrf,
+            "thread_pool.search.fan_out": fan_out,
+            "ingest": {
+                "refreshers_running": int(r is not None and r.is_alive())},
+        }, self._batcher.node_stats()]
+        out += [eng.node_stats() for eng in list(self._local.values())]
+        # (the numpy oracle's executor holds nothing on the device)
+        out += [ex.node_stats() for _gen, ex in list(self._executors.values())
+                if hasattr(ex, "node_stats")]
+        if self._mesh is not None:
+            out.append(self._mesh.node_stats())
+        return out
+
+    @classmethod
+    def node_stats_schema(cls) -> Tuple[dict, dict, dict]:
+        """How a node folds `node_stats` maps, from the layers'
+        declarations: (what a node with no index reports, path -> leaves;
+        the leaves that are no sums, dotted path -> f(the reported
+        values); the leaves computed from their folded block, dotted
+        path -> f(block))."""
+        from ..parallel.mesh_executor import MeshExecutor
+        from ..search import batcher
+        from ..search.executor_jax import JaxExecutor
+
+        zeros: Dict[str, dict] = {}
+        for layer in (cls.NODE_STATS, batcher.node_stats_zeros(),
+                      ShardEngine.NODE_STATS, JaxExecutor.node_stats_zeros(),
+                      MeshExecutor.NODE_STATS):
+            for path, block in layer.items():
+                zeros.setdefault(path, {}).update(block)
+        fold = {**batcher.NODE_STATS_FOLD, **ShardEngine.NODE_STATS_FOLD}
+        return zeros, fold, batcher.NODE_STATS_DERIVED
 
     def stats(self) -> dict:
         agg = self.local_stats()

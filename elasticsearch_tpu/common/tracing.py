@@ -228,10 +228,9 @@ they happen (ops/scoring.py, the kNN upload in search/batcher.py, the
 serve family's per-job fallback and `JaxExecutor.segment_topk`, the
 sparse family's chunk planes and theta in ops/impact.py; the rrf fuse
 moves nothing);
-`_nodes/stats` reports the totals as `transfer.scoring.*`, and the
-hybrid searches' own counters (`IndexService.rrf_stats`: searches,
-host_fused (every search), device_fused (0: the serving path has no
-device fuse), fuse_ms, the legs' summed ms) as `pipeline.rrf.*`.
+`_nodes/stats` reports the totals as `transfer.scoring.*`. The other
+counters of that document are declared, and explained, by the layer
+that counts them (search/batcher.NODE_STATS, IndexService.NODE_STATS).
 
 `OPAQUE_ID_CTX` carries the request's `X-Opaque-Id` header value so
 task descriptions, slow-log records, and traces can all attribute work

@@ -942,25 +942,28 @@ class ShardEngine:
     def max_seq_no(self) -> int:
         return self._next_seq - 1
 
-    def translog_stats(self) -> dict:
-        """The per-shard slice of the `_nodes/stats` translog block."""
+    # the shard's live WAL state in the node's document (`translog`):
+    # operations and bytes not yet committed, operations appended and not
+    # yet fsynced, and the age of the last fsync. The node sums them over
+    # its shards, but the age: it reports the oldest
+    NODE_STATS = {"translog": {
+        "uncommitted_ops": 0, "uncommitted_bytes": 0,
+        "pending_unsynced_ops": 0, "last_fsync_age_ms": 0.0,
+    }}
+    NODE_STATS_FOLD = {"translog.last_fsync_age_ms": max}
+
+    def node_stats(self) -> Dict[str, dict]:
         with self._lock:
-            out = {
-                "uncommitted_ops": max(
-                    0, (self._next_seq - 1) - self.committed_seq_no
-                ),
-                "uncommitted_bytes": 0,
-                "last_fsync_age_ms": None,
-                "pending_ops": 0,
-                "durability": None,
-            }
+            out = dict(self.NODE_STATS["translog"])
+            out["uncommitted_ops"] = max(
+                0, (self._next_seq - 1) - self.committed_seq_no
+            )
             if self.translog is not None:
                 tl = self.translog.stats()
                 out["uncommitted_bytes"] = tl["uncommitted_bytes"]
+                out["pending_unsynced_ops"] = tl["pending_ops"]
                 out["last_fsync_age_ms"] = tl["last_fsync_age_ms"]
-                out["pending_ops"] = tl["pending_ops"]
-                out["durability"] = tl["durability"]
-            return out
+            return {"translog": out}
 
     def close(self) -> None:
         with self._lock:

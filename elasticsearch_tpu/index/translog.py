@@ -95,6 +95,38 @@ def durability_stats_snapshot() -> dict:
         return dict(DURABILITY_STATS)
 
 
+def node_stats() -> dict:
+    """The process-wide counters under their dotted paths in the node's
+    document: `translog` (beside the shards' live state,
+    `ShardEngine.node_stats`) and `recovery`."""
+    dur = durability_stats_snapshot()
+    return {
+        "translog": {
+            "fsyncs": dur["translog_fsyncs"],
+            "appended_ops": dur["translog_appended_ops"],
+            "torn_tails_truncated": dur["torn_tails_truncated"],
+            "torn_bytes_dropped": dur["torn_bytes_dropped"],
+            "orphan_checkpoints_removed": dur["orphan_checkpoints_removed"],
+            "stale_generations_removed": dur["stale_generations_removed"],
+        },
+        "recovery": {
+            "replayed_ops": dur["replayed_ops"],
+            "tail_replays": dur["tail_replays"],
+            "quarantined_segments": dur["quarantined_segments"],
+            "orphan_manifests_removed": dur["orphan_manifests_removed"],
+            "peer": {
+                "started": dur["recoveries_started"],
+                "completed": dur["recoveries_completed"],
+                "failed": dur["recoveries_failed"],
+                "retries": dur["recovery_retries"],
+                "files": dur["recovered_files"],
+                "ops": dur["recovered_ops"],
+                "finalize_redelivered": dur["finalize_redelivered"],
+            },
+        },
+    }
+
+
 def reset_durability_stats() -> None:
     with _DSTATS_LOCK:
         DURABILITY_STATS.clear()

@@ -151,9 +151,9 @@ class IvfSegmentIndex:
     owns slots [starts[c], starts[c]+counts[c]); the flat arrays carry
     `cmax` rows of padding at the tail so `starts[c] + arange(cmax)`
     never reads out of bounds (padded slots are masked by the
-    rank < counts test). The int8 twin mirrors ops/pallas_knn's
-    symmetric per-vector quantization so `index.knn.quantization: int8`
-    probes read 4x less HBM."""
+    rank < counts test). The int8 twin is a symmetric per-vector
+    quantization (q = rint(v / scale), scale = max|v| / 127) so
+    `index.knn.quantization: int8` probes read 4x less HBM."""
 
     def __init__(
         self,
@@ -196,9 +196,9 @@ class IvfSegmentIndex:
         self.host_qvecs_flat = None
         self.host_scales_flat = None
         if quantized:
-            # symmetric per-vector int8 — ops/pallas_knn.quantize_int8's
-            # scheme WITHOUT the lane padding (the probe gather is a
-            # plain XLA einsum, not the pallas kernel)
+            # symmetric per-vector int8, no lane padding: the probe
+            # gather is a plain XLA einsum (models/rerank.quantize_tokens
+            # is the same scheme a token)
             vf32 = vecs_flat.astype(np.float32)
             maxabs = np.abs(vf32).max(axis=1)
             scales = (maxabs / 127.0).astype(np.float32)

@@ -23,7 +23,7 @@ issues DMAs and products from one instruction stream: little overlaps)
 and are not kept.
 
 Compiled by Mosaic unless the caller passes `interpret=True` (the CPU
-tests do, explicitly), as ops/pallas_knn.py.
+tests do, explicitly), as ops/fuzzy.py's blocked expansion.
 """
 
 from __future__ import annotations

@@ -21,7 +21,6 @@ from .sharded import (
     build_mesh_text_step,
     build_sharded_bm25_step,
     build_sharded_knn_step,
-    rrf_fuse,
 )
 
 __all__ = [
@@ -39,5 +38,4 @@ __all__ = [
     "build_mesh_text_step",
     "build_sharded_bm25_step",
     "build_sharded_knn_step",
-    "rrf_fuse",
 ]

@@ -215,19 +215,25 @@ class MeshExecutor:
     split (and pipeline depth) as the single-device families.
     """
 
+    # the counters of the node's `pipeline.mesh` block, at zero (summed
+    # over the node's mesh executors; a node with none reports these)
+    NODE_STATS = {"pipeline.mesh": {
+        "routed": 0,  # requests served start-to-finish by the mesh
+        "launches": 0,  # SPMD programs dispatched
+        "jobs": 0,  # queries carried by those programs
+        "rebuilds": 0,  # snapshot rebuilds on generation bumps
+        "degraded": 0,  # HBM-budget degrades to single-device
+        "fallbacks": 0,  # routed requests that fell back mid-flight
+    }}
+
     def __init__(self, service):
         self.service = service
         self._lock = threading.RLock()
         self._snapshot: Optional[_MeshSnapshot] = None
         self.stats = {
-            "routed": 0,  # requests served start-to-finish by the mesh
-            "launches": 0,  # SPMD programs dispatched
-            "jobs": 0,  # queries carried by those programs
-            "rebuilds": 0,  # snapshot rebuilds on generation bumps
+            **self.NODE_STATS["pipeline.mesh"],
             "incremental_rebuilds": 0,  # rebuilds that reused prev rows
             "entries_reused": 0,  # stacked rows copied, not re-extracted
-            "degraded": 0,  # HBM-budget degrades to single-device
-            "fallbacks": 0,  # routed requests that fell back mid-flight
         }
 
     # ---- routing predicate ----
@@ -1861,6 +1867,11 @@ class MeshExecutor:
     def note_degraded(self) -> None:
         with self._lock:
             self.stats["degraded"] += 1
+
+    def node_stats(self) -> Dict[str, dict]:
+        with self._lock:
+            return {path: {k: self.stats[k] for k in block}
+                    for path, block in self.NODE_STATS.items()}
 
     def stats_snapshot(self) -> dict:
         with self._lock:

@@ -1254,21 +1254,3 @@ def build_mesh_agg_step(
         return fn(*static_arrays, ti, tw, tv, msm)
 
     return step
-
-
-def rrf_fuse(
-    lex: ShardedTopK, vec: ShardedTopK, k: int, rank_constant: int = 60
-) -> Tuple[jax.Array, jax.Array]:
-    """Reciprocal-rank fusion of two ranked lists (x-pack rank-rrf:
-    `RRFQueryPhaseRankCoordinatorContext`, score = Σ 1/(rank_constant+rank)).
-
-    Device-side via the ops/fusion kernel (the lists are on the device
-    already; the serving path fuses host-side hits with
-    `rrf_fuse_ranked`): exact-doc dedup over the union of both lists, top-k
-    with ascending-global-doc tie-break. Returns (scores[B,k],
-    global_docs[B,k])."""
-    from ..ops.fusion import rrf_fuse_device
-
-    return rrf_fuse_device(
-        (lex.global_docs, vec.global_docs), k, rank_constant
-    )

@@ -38,6 +38,22 @@ def _auto_id() -> str:
     )
 
 
+def _fold_stats(zero, values, rules, path=""):
+    """One block or leaf of the node's document over the layers that
+    reported it (`values`): a block key by key, a leaf by its rule in
+    `rules` (dotted path -> f(values)) or else summed; `zero` where no
+    layer reported it."""
+    if isinstance(zero, dict):
+        keys = dict.fromkeys(k for v in (zero, *values) for k in v)
+        out = {}
+        for k in keys:
+            vals = [v[k] for v in values if k in v]
+            z = zero[k] if k in zero else type(vals[0])()
+            out[k] = _fold_stats(z, vals, rules, f"{path}.{k}" if path else k)
+        return out
+    return rules.get(path, sum)(values) if values else zero
+
+
 class RestActions:
     def __init__(self, cluster: ClusterService):
         self.cluster = cluster
@@ -579,417 +595,96 @@ class RestActions:
         }
 
     def nodes_stats(self, body, params, qs):
+        """GET /_nodes/stats: the response's skeleton, and a fold. Every
+        counter is declared, explained and counted by a layer under this
+        one: an index hands its layers' blocks by dotted path
+        (`IndexService.node_stats`; the batcher's table is
+        search/batcher.NODE_STATS), a module its own block, and a node
+        with no index reports the layers' declared zeros."""
         import resource
 
+        from ..cluster.allocation import relocation_stats_snapshot
+        from ..cluster.indices import IndexService
         from ..common.memory import hbm_ledger
-
-        ru = resource.getrusage(resource.RUSAGE_SELF)
-        total_docs = sum(i.num_docs for i in self.cluster.indices.values())
-        hbm = hbm_ledger.stats()
-        # batcher dispatch counters across indices (threadpool analog:
-        # queue/rejected for the `search` pool)
-        batch = {
-            "jobs": 0, "launches": 0, "rejected": 0, "fused_jobs": 0,
-            "pruned_jobs": 0, "fused_overflow_jobs": 0,
-            "shed_dead_jobs": 0, "cancelled_jobs": 0,
-            "serve_fallback_jobs": 0, "serve_launches": 0,
-            "serve_rare_tiles": 0, "serve_hot_rows": 0,
-            "serve_clauses": 0, "serve_multi_term_clauses": 0,
-            "fused_rare_tiles": 0,
-        }
-        # the serving pipeline: the workers' in-flight ring bound, the
-        # continuous-batching block and the mesh counters. Device time is
-        # not here: the profiler's device plane measures it (PERF.md §3)
-        pipeline = {"depth": 0}
-        queue_capacity = 0
-        # continuous-batching counters (QueryBatcher.batching_stats):
-        # per-bucket launch histogram + occupancy, so padding waste is a
-        # measured number; express_lane_hits counts depth-1 lone-query
-        # dispatches
-        batching = {
-            "buckets": [],
-            "launches_by_bucket": {},
-            "occupancy_jobs": 0,
-            "occupancy_slots": 0,
-            "express_lane_hits": 0,
-            # groups whose result was downloaded as the fused kernel
-            # packed it (one scoring segment: no merge program)
-            "direct_collect_groups": 0,
-            # groups launched beside another group of their batch (a
-            # hybrid request's legs), of `launches_by_bucket`'s groups
-            "groups_launched_together": 0,
-            # query-only searches of a jax shard that no planner took:
-            # they ran on the unbatched executor (0 from the node's
-            # start, so a window without one reads 0)
-            "unplanned_queries": 0,
-            # tile slots the fused launches' rare-term pass scattered,
-            # of the slots of their budget (rows x 256 a field)
-            "rare_slots_scattered": 0,
-            "rare_slots_budget": 0,
-            "warmup_failures": 0,
-            "fused_hot_slots": {},
-            "serve_hot_slots": {},
-            # dense hot-term rows over the loaded fields of every shard's
-            # executor (JaxExecutor.dense_rows_stats): gauges
-            "dense_rows_wanted": 0,
-            "dense_rows_held": 0,
-            "dense_tf_overflow_postings": 0,
-            "worker_compile_ms": 0.0,
-            "worker_compiles": 0,
-        }
-        # the `sparse` block's gauges over every shard executor's loaded
-        # int8 impact columns (JaxExecutor.impact_rows_stats): hot terms
-        # that want a dense row, those that hold one, the rows' bytes
-        impact_rows = {
-            "dense_rows_wanted": 0, "dense_rows_held": 0,
-            "dense_rows_bytes": 0,
-        }
-        mesh_stats = {
-            "routed": 0, "launches": 0, "jobs": 0, "rebuilds": 0,
-            "degraded": 0, "fallbacks": 0,
-        }
-        # hybrid (rrf) searches over the indices (IndexService.rrf_stats):
-        # how many, how many fused on the device and on the host, the
-        # fuse's and the legs' summed milliseconds (a leg from the legs'
-        # common start to its own completion mark)
-        rrf: dict = {}
-        # coordinator fan-outs by where the shards ran: the request's
-        # own thread (one local shard whose wait polls the task) or the
-        # fan-out pool (IndexService.fan_out_stats)
-        fan_out = {"inline": 0, "pooled": 0}
-        # the knn family's filtered groups (QueryBatcher.knn_filtered):
-        # scans under a mask of the job's own, rows scored and rows the
-        # filters passed, postings tiles the mask launches scattered,
-        # the planned filters' terms and those of them answered from a
-        # bit row of the segment, the mask launches, those whose scan
-        # selected from block maxima, the scans that fell back to the
-        # unbatched executor, and the searches a lead launch served
-        # with the candidate slots they scored
-        knn_filtered = {
-            "searches": 0, "rows_scanned": 0, "rows_passed": 0,
-            "filter_tiles": 0, "filter_terms": 0, "bitset_terms": 0,
-            "mask_launches": 0, "block_select_launches": 0, "fallbacks": 0,
-            "lead_searches": 0, "lead_rows": 0,
-        }
-        # the serve family's filtered and negated groups
-        # (QueryBatcher.serve_filtered): (job x segment) scans of the
-        # fused program under a `filter` mask or a veto, the fused
-        # launches that built masks, the filters' terms and those read
-        # from a bit row, the postings tiles the others scattered,
-        # documents the masked launches scored and documents their
-        # filters passed, `must_not` terms carried and the tiles of
-        # those without a dense row, scans that fell back to the
-        # unbatched executor
-        serve_filtered = {
-            "searches": 0, "mask_launches": 0, "filter_terms": 0,
-            "bitset_terms": 0, "filter_tiles": 0, "rows_scanned": 0,
-            "rows_passed": 0, "excluded_terms": 0, "excluded_tiles": 0,
-            "fallbacks": 0,
-        }
-        # the phrase family (QueryBatcher.phrase): scans on the device,
-        # their launches and words, position entries handed to them,
-        # documents holding every word and the words' occurrences inside
-        # them, documents matched, the bytes no exact search leaves
-        # unread, scans served by the unbatched executor
-        phrase = {
-            "searches": 0, "launches": 0, "words": 0,
-            "occurrences_read": 0, "candidates": 0,
-            "candidate_occurrences": 0, "matches": 0, "least_bytes": 0,
-            "fallbacks": 0,
-        }
-        # the fuzzy family (QueryBatcher.fuzzy): jobs, words, words
-        # expanded on the device, terms kept, words that kept every
-        # place, the plans' dense rows and tiles, jobs that passed a
-        # slot budget or found no plane, the expansion's least work
-        fuzzy = dict.fromkeys((
-            "requests", "words", "words_expanded", "terms_kept", "words_saturated", "hot_terms", "tiles",
-            "overflows", "fallbacks", "launches", "blocked_launches",
-            "score_launches", "least_bytes", "least_cells"), 0)
-        for idx in self.cluster.indices.values():
-            with idx._rrf_lock:
-                for k, v in idx.rrf_stats.items():
-                    rrf[k] = rrf.get(k, 0) + v
-            with idx._fan_out_lock:
-                for k, v in idx.fan_out_stats.items():
-                    fan_out[k] += v
-            b = getattr(idx, "_batcher", None)
-            if b is not None:
-                for k in batch:
-                    batch[k] += b.stats.get(k, 0)
-                with b._lock:
-                    for k, v in b.knn_filtered.items():
-                        knn_filtered[k] += v
-                    for k, v in b.phrase.items():
-                        phrase[k] += v
-                    for k, v in b.fuzzy.items():
-                        fuzzy[k] += v
-                    for k, v in b.serve_filtered.items():
-                        serve_filtered[k] += v
-                queue_capacity = max(queue_capacity, b._queue.maxsize)
-                pipeline["depth"] = max(pipeline["depth"], b.pipeline_depth)
-                bs = b.batching_stats()
-                if len(bs["buckets"]) > len(batching["buckets"]):
-                    batching["buckets"] = bs["buckets"]
-                for bk, n in bs["launches_by_bucket"].items():
-                    batching["launches_by_bucket"][bk] = (
-                        batching["launches_by_bucket"].get(bk, 0) + n
-                    )
-                batching["occupancy_jobs"] += bs["occupancy_jobs"]
-                batching["occupancy_slots"] += bs["occupancy_slots"]
-                batching["express_lane_hits"] += bs["express_lane_hits"]
-                for k in ("direct_collect_groups",
-                          "groups_launched_together", "unplanned_queries",
-                          "rare_slots_scattered", "rare_slots_budget"):
-                    batching[k] += bs[k]
-                batching["warmup_failures"] += bs["warmup_failures"]
-                for hist in ("fused_hot_slots", "serve_hot_slots"):
-                    for h, n in bs[hist].items():
-                        batching[hist][h] = batching[hist].get(h, 0) + n
-                batching["worker_compile_ms"] += bs["worker_compile_ms"]
-                batching["worker_compiles"] += bs["worker_compiles"]
-            for _gen, ex in list(getattr(idx, "_executors", {}).values()):
-                for rows, into in (("dense_rows_stats", batching),
-                                   ("impact_rows_stats", impact_rows)):
-                    rows = getattr(ex, rows, None)
-                    if rows is not None:
-                        for k, v in rows().items():
-                            into[k] += v
-            mex = getattr(idx, "_mesh", None)
-            if mex is not None:
-                for k in mesh_stats:
-                    mesh_stats[k] += mex.stats.get(k, 0)
-        if pipeline["depth"] == 0:
-            from ..common.settings import pipeline_depth
-
-            pipeline["depth"] = pipeline_depth()
-        batching["avg_occupancy"] = (
-            round(batching["occupancy_jobs"] / batching["occupancy_slots"], 4)
-            if batching["occupancy_slots"]
-            else 0.0
-        )
-        if not batching["buckets"]:
-            from ..common.settings import batch_buckets
-            from ..ops.scoring import BPAD
-
-            batching["buckets"] = list(batch_buckets(BPAD))
-        pipeline["batching"] = batching
-        pipeline["mesh"] = mesh_stats
-        pipeline["rrf"] = rrf
-        if queue_capacity == 0:
-            from ..search.batcher import QUEUE_CAPACITY
-
-            queue_capacity = QUEUE_CAPACITY
-        from ..search.admission import admission
-        from ..search.query_cache import filter_cache, request_cache
-
-        # per-category child breakers next to the "hbm" parent (per-
-        # category bytes were accounted but invisible before)
-        category_breakers = hbm_ledger.child_breakers()
-        # device-aggregations engine counters (search/aggs_device.py):
-        # device_routed vs host_routed shard collections, mid-flight
-        # fallbacks, mesh SPMD agg launches, kernel wall time, and the
-        # `aggs` HBM ledger bytes (int offset / value-ordinal columns)
-        from ..search.aggs_device import stats_snapshot as agg_stats
-
-        aggs_block = agg_stats()
-        aggs_block["batched_jobs"] = sum(
-            getattr(idx, "_batcher", None).stats.get("agg_jobs", 0)
-            for idx in self.cluster.indices.values()
-            if getattr(idx, "_batcher", None) is not None
-        )
-        # IVF ANN tier counters (search/ann.py): probe counts, clusters
-        # scanned vs total, exact-fallback/escape-hatch routings, index
-        # build wall time, and the `ann` HBM ledger bytes
-        from ..search.ann import stats_snapshot as ann_stats
-
-        knn_block = {"ann": ann_stats()}
-        # second-stage reranking counters (models/rerank.py):
-        # device/host rescores, degrade-to-skip and first-stage
-        # fallbacks, maxsim kernel wall time, the window-size
-        # histogram, and the `rerank` HBM ledger bytes
+        from ..index import translog
+        from ..index.segment_build import stats_snapshot as ingest_stats
         from ..models.rerank import stats_snapshot as rescore_stats
-
-        rescore_block = rescore_stats()
-        rescore_block["batched_jobs"] = sum(
-            getattr(idx, "_batcher", None).stats.get("rerank_jobs", 0)
-            for idx in self.cluster.indices.values()
-            if getattr(idx, "_batcher", None) is not None
-        )
-        # learned-sparse retrieval counters (search/sparse.py):
-        # quantized/exact/fallback routings, impact tiles scored vs
-        # pruned by the block-max pass, the `impacts` HBM ledger bytes,
-        # and the int8-vs-fp32-equivalent upload sizes (the compression
-        # headline)
+        from ..search.admission import admission
+        from ..search.aggs_device import stats_snapshot as agg_stats
+        from ..search.ann import stats_snapshot as ann_stats
+        from ..search.query_cache import filter_cache, request_cache
         from ..search.sparse import stats_snapshot as sparse_stats
 
-        sparse_block = sparse_stats()
-        sparse_block["batched_jobs"] = sum(
-            getattr(idx, "_batcher", None).stats.get("sparse_jobs", 0)
-            for idx in self.cluster.indices.values()
-            if getattr(idx, "_batcher", None) is not None
-        )
-        sparse_block.update(impact_rows)
-        # write-path durability counters (index/translog.py): live
-        # uncommitted WAL state aggregated over local shards, plus the
-        # process-wide hygiene/recovery counters (torn tails truncated,
-        # orphan checkpoint/manifest cleanup, WAL replays, quarantined
-        # segment dirs, peer-recovery lifecycle)
-        from ..index.translog import durability_stats_snapshot
-
-        dur = durability_stats_snapshot()
-        translog_block = {
-            "uncommitted_ops": 0,
-            "uncommitted_bytes": 0,
-            "pending_unsynced_ops": 0,
-            "last_fsync_age_ms": 0.0,
-            "fsyncs": dur["translog_fsyncs"],
-            "appended_ops": dur["translog_appended_ops"],
-            "torn_tails_truncated": dur["torn_tails_truncated"],
-            "torn_bytes_dropped": dur["torn_bytes_dropped"],
-            "orphan_checkpoints_removed": dur["orphan_checkpoints_removed"],
-            "stale_generations_removed": dur["stale_generations_removed"],
-        }
-        for idx in self.cluster.indices.values():
-            for eng in getattr(idx, "_local", {}).values():
-                ts = eng.translog_stats()
-                translog_block["uncommitted_ops"] += ts["uncommitted_ops"]
-                translog_block["uncommitted_bytes"] += ts["uncommitted_bytes"]
-                translog_block["pending_unsynced_ops"] += ts["pending_ops"]
-                if ts["last_fsync_age_ms"] is not None:
-                    translog_block["last_fsync_age_ms"] = max(
-                        translog_block["last_fsync_age_ms"],
-                        ts["last_fsync_age_ms"],
-                    )
-        # streaming-ingest counters (index/segment_build.py): refresh
-        # count + visibility-lag percentiles, device vs host segment
-        # builds (+ degrade/fallback/discard counters), per-column-family
-        # build kernel ms, concurrent-build overlap, post-swap prewarm
-        # time, and the transient `build` ledger bytes
-        from ..index.segment_build import stats_snapshot as ingest_stats
-
-        ingest_block = ingest_stats()
-        ingest_block["refreshers_running"] = sum(
-            1
-            for idx in self.cluster.indices.values()
-            if getattr(idx, "_refresher", None) is not None
-            and idx._refresher.is_alive()
-        )
-        recovery_block = {
-            "replayed_ops": dur["replayed_ops"],
-            "tail_replays": dur["tail_replays"],
-            "quarantined_segments": dur["quarantined_segments"],
-            "orphan_manifests_removed": dur["orphan_manifests_removed"],
-            "peer": {
-                "started": dur["recoveries_started"],
-                "completed": dur["recoveries_completed"],
-                "failed": dur["recoveries_failed"],
-                "retries": dur["recovery_retries"],
-                "files": dur["recovered_files"],
-                "ops": dur["recovered_ops"],
-                "finalize_redelivered": dur["finalize_redelivered"],
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        indices = list(self.cluster.indices.values())
+        hbm = hbm_ledger.stats()
+        node = {
+            "name": self.cluster.node_name,
+            "roles": ["master", "data", "ingest"],
+            "indices": {
+                "docs": {"count": sum(i.num_docs for i in indices)},
+                "query_cache": filter_cache.node_stats(),
+                "request_cache": request_cache.node_stats(),
             },
+            "jvm": {  # shape parity; values are process RSS
+                "mem": {"heap_used_in_bytes": ru.ru_maxrss * 1024}
+            },
+            "os": {"cpu": {"percent": 0}},
+            "process": {
+                "open_file_descriptors": 0,
+                "max_file_descriptors": 0,
+            },
+            "breakers": {
+                "hbm": {
+                    key: hbm[key]
+                    for key in ("limit_size_in_bytes",
+                                "estimated_size_in_bytes", "tripped",
+                                "by_category", "degraded_allocations")
+                },
+                # per-category child breakers next to the "hbm" parent
+                **hbm_ledger.child_breakers(),
+            },
+            "uptime_in_millis": int((time.time() - self.started_at) * 1000),
         }
-        from ..cluster.allocation import relocation_stats_snapshot
-
-        relocation_block = relocation_stats_snapshot()
+        # the indices' blocks, folded leaf by leaf over the layers that
+        # reported each: a number summed (a histogram key by key) unless
+        # its declaration says otherwise
+        zeros, fold, derived = IndexService.node_stats_schema()
+        blocks = _fold_stats(
+            zeros, [m for idx in indices for m in idx.node_stats()], fold)
+        for path, derive in derived.items():
+            block, _, leaf = path.rpartition(".")
+            blocks[block][leaf] = derive(blocks[block])
+        # the modules that keep a node-wide block of their own, each
+        # explained where it is counted: device aggregations
+        # (search/aggs_device.py), the IVF ANN tier (search/ann.py), the
+        # second-stage rerank (models/rerank.py), learned-sparse retrieval
+        # (search/sparse.py), streaming ingest (index/segment_build.py),
+        # write-path durability and recovery (index/translog.py),
+        # relocation (cluster/allocation.py), overload protection
+        # (search/admission.py), the query path's host<->device transfers
+        # (common/tracing.note_transfer) and the trace ring's exports
+        modules = {
+            "aggs": agg_stats(),
+            "knn.ann": ann_stats(),
+            "rescore": rescore_stats(),
+            "sparse": sparse_stats(),
+            "ingest": ingest_stats(),
+            **translog.node_stats(),
+            "relocation": relocation_stats_snapshot(),
+            "admission": admission.stats(),
+            "transfer.scoring": tracing.transfer_stats(),
+            "tracing": tracing.export_stats(),
+        }
+        for source in (blocks, modules):
+            for path, block in source.items():
+                at = node
+                for part in path.split("."):
+                    at = at.setdefault(part, {})
+                at.update(block)
         return 200, {
             "cluster_name": self.cluster.cluster_name,
-            "nodes": {
-                "node-0": {
-                    "name": self.cluster.node_name,
-                    "roles": ["master", "data", "ingest"],
-                    "indices": {
-                        "docs": {"count": total_docs},
-                        "query_cache": filter_cache.node_stats(),
-                        "request_cache": request_cache.node_stats(),
-                    },
-                    "jvm": {  # shape parity; values are process RSS
-                        "mem": {"heap_used_in_bytes": ru.ru_maxrss * 1024}
-                    },
-                    "os": {"cpu": {"percent": 0}},
-                    "process": {
-                        "open_file_descriptors": 0,
-                        "max_file_descriptors": 0,
-                    },
-                    "breakers": {
-                        "hbm": {
-                            "limit_size_in_bytes": hbm["limit_size_in_bytes"],
-                            "estimated_size_in_bytes": hbm[
-                                "estimated_size_in_bytes"
-                            ],
-                            "tripped": hbm["tripped"],
-                            "by_category": hbm["by_category"],
-                            "degraded_allocations": hbm[
-                                "degraded_allocations"
-                            ],
-                        },
-                        **category_breakers,
-                    },
-                    "pipeline": pipeline,
-                    # host<->device transfers of the query path, counted
-                    # where they happen (common/tracing.note_transfer);
-                    # named for their scope: ops/scoring.py and the kNN
-                    # upload. d2h_count = blocking downloads (host syncs)
-                    "transfer": {"scoring": tracing.transfer_stats()},
-                    # the trace ring's exports (GET /_internal/traces)
-                    # and the traces it dropped before any read them
-                    "tracing": tracing.export_stats(),
-                    "aggs": aggs_block,
-                    "knn": knn_block,
-                    "knn_filtered": knn_filtered,
-                    "phrase": phrase,
-                    "fuzzy": fuzzy,
-                    "serve_filtered": serve_filtered,
-                    "rescore": rescore_block,
-                    "sparse": sparse_block,
-                    "translog": translog_block,
-                    "ingest": ingest_block,
-                    "recovery": recovery_block,
-                    # relocation lifecycle counters (cluster/allocation.py):
-                    # started/completed/cancelled/failed moves, transferred
-                    # bytes, handoff drains and their cumulative latency
-                    "relocation": relocation_block,
-                    # overload-protection block (search/admission.py):
-                    # per-tenant queue depths, the adaptive concurrency
-                    # limit, pressure tier, shed/brownout/retry-budget
-                    # counters
-                    "admission": admission.stats(),
-                    "thread_pool": {
-                        "search": {
-                            "queue_capacity": queue_capacity,
-                            "completed": batch["jobs"],
-                            "rejected": batch["rejected"],
-                            "launches": batch["launches"],
-                            "fused_jobs": batch["fused_jobs"],
-                            "pruned_jobs": batch["pruned_jobs"],
-                            "fused_overflow_jobs": batch[
-                                "fused_overflow_jobs"
-                            ],
-                            "shed_dead_jobs": batch["shed_dead_jobs"],
-                            "cancelled_jobs": batch["cancelled_jobs"],
-                            # the serve family (bool / multi_match)
-                            "serve_fallback_jobs": batch[
-                                "serve_fallback_jobs"
-                            ],
-                            "serve_launches": batch["serve_launches"],
-                            "serve_rare_tiles": batch["serve_rare_tiles"],
-                            "serve_hot_rows": batch["serve_hot_rows"],
-                            # counted clauses over serve jobs, and those
-                            # of more than one term
-                            "serve_clauses": batch["serve_clauses"],
-                            "serve_multi_term_clauses": batch[
-                                "serve_multi_term_clauses"
-                            ],
-                            # the match family's twin of serve_rare_tiles
-                            "fused_rare_tiles": batch["fused_rare_tiles"],
-                            "fan_out": fan_out,
-                        }
-                    },
-                    "uptime_in_millis": int(
-                        (time.time() - self.started_at) * 1000
-                    ),
-                }
-            },
+            "nodes": {"node-0": node},
         }
 
     def all_stats(self, body, params, qs):

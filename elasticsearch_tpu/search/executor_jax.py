@@ -1299,11 +1299,8 @@ class JaxExecutor:
             self._fused_parts[key] = parts
             return parts
 
-    def dense_rows_stats(self) -> Dict[str, int]:
-        """Over the fields whose fused parts are loaded: terms that want
-        a dense row, terms that hold one, and the postings whose tf the
-        uint16 rows carry past DENSE_TF_MAX (`_fused_parts_build`)."""
-        parts = [p for p in list(self._fused_parts.values()) if p is not None]
+    @staticmethod
+    def _dense_rows_stats(parts) -> Dict[str, int]:
         return {
             "dense_rows_wanted": sum(p["rows_wanted"] for p in parts),
             "dense_rows_held": sum(p["rows_held"] for p in parts),
@@ -1311,6 +1308,13 @@ class JaxExecutor:
                 p["tf_overflow_postings"] for p in parts
             ),
         }
+
+    def dense_rows_stats(self) -> Dict[str, int]:
+        """Over the fields whose fused parts are loaded: terms that want
+        a dense row, terms that hold one, and the postings whose tf the
+        uint16 rows carry past DENSE_TF_MAX (`_fused_parts_build`)."""
+        return self._dense_rows_stats(
+            [p for p in list(self._fused_parts.values()) if p is not None])
 
     def fused_scorer_mf(self, si: int, fields: tuple):
         """Cached MultiFusedScorer over one segment and a field tuple
@@ -2286,17 +2290,33 @@ class JaxExecutor:
         self._charge("dense_rows", rows.nbytes, False)
         return rows
 
-    def impact_rows_stats(self) -> Dict[str, int]:
-        """Over the int8 impact columns loaded: terms that want a dense
-        row, terms that hold one, and the rows' device bytes."""
-        scs = [sc for sc in list(self._impact_scorers.values())
-               if sc is not None]
-        held = [sc.rows for sc in scs if sc.rows is not None]
+    @staticmethod
+    def _impact_rows_stats(scorers) -> Dict[str, int]:
+        held = [sc.rows for sc in scorers if sc.rows is not None]
         return {
-            "dense_rows_wanted": sum(sc.rows_wanted for sc in scs),
+            "dense_rows_wanted": sum(sc.rows_wanted for sc in scorers),
             "dense_rows_held": sum(r.n_rows for r in held),
             "dense_rows_bytes": sum(r.nbytes for r in held),
         }
+
+    def impact_rows_stats(self) -> Dict[str, int]:
+        """Over the int8 impact columns loaded: terms that want a dense
+        row, terms that hold one, and the rows' device bytes."""
+        return self._impact_rows_stats(
+            [sc for sc in list(self._impact_scorers.values())
+             if sc is not None])
+
+    def node_stats(self) -> Dict[str, dict]:
+        """This executor's gauges in the node's document, by the dotted
+        path of their block (the node sums them over its executors)."""
+        return {"pipeline.batching": self.dense_rows_stats(),
+                "sparse": self.impact_rows_stats()}
+
+    @classmethod
+    def node_stats_zeros(cls) -> Dict[str, dict]:
+        """The same gauges over nothing loaded: a node with no executor."""
+        return {"pipeline.batching": cls._dense_rows_stats([]),
+                "sparse": cls._impact_rows_stats([])}
 
     # ---- second-stage rerank column (flat rank_vectors gather arrays) ----
 

@@ -678,30 +678,6 @@ def test_mesh_text_step(mesh4):
 
 
 # ---------------------------------------------------------------------------
-# the Pallas int8 kernel (imported only by tests today, ROADMAP D5)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("rows", [1, 32])
-def test_pallas_int8_dot_scores(one_chip, rows):
-    """Refused by Mosaic before this file existed: the 1-D f32 `scales`
-    operand carries XLA's T(1024) tiling, which a 512-doc block cannot
-    match. The scales now ride as a [1, N] row."""
-    from elasticsearch_tpu.ops.pallas_knn import DOC_BLOCK, int8_dot_scores
-
-    n_pad = 1_000_448
-    assert n_pad % DOC_BLOCK == 0
-    s = _on(one_chip)
-    compiled = int8_dot_scores.lower(
-        s((rows, DIMS), jnp.float32),
-        s((n_pad, DIMS), jnp.int8),
-        s((n_pad,), jnp.float32),
-    ).compile()
-    _fits(compiled)
-    assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not interpret
-
-
-# ---------------------------------------------------------------------------
 # one program each from the families that compile only on first use
 # (the agg segment-sum, ops/agg_kernels.sorted_bucket_counts, compiles
 # too but takes ~20 s here at 1M docs — too slow to keep in tier-1)

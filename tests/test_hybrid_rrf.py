@@ -3,10 +3,8 @@
 Covers the tentpole contract of the hybrid pipeline:
   * the served fuse (ops/fusion.rrf_fuse_ranked: the host's dictionary
     over the hits the legs returned) is hit-for-hit with the NumPy
-    oracle and with the mesh path's device program — ranks, scores,
-    exact-doc dedup, and the ascending doc-id tie-break;
-  * device RRF fusion (ops/fusion.rrf_fuse_device, the mesh path's) is
-    hit-for-hit with the same oracle;
+    oracle — ranks, scores, exact-doc dedup, and the ascending doc-id
+    tie-break;
   * both hybrid legs are genuinely in flight at the same time
     (instrumented batcher counters);
   * the async submission path (`submit_nowait`) keeps the dispatcher's
@@ -23,7 +21,6 @@ import pytest
 
 from elasticsearch_tpu.cluster.indices import IndexService
 from elasticsearch_tpu.ops.fusion import (
-    rrf_fuse_device,
     rrf_fuse_host,
     rrf_fuse_ranked,
 )
@@ -99,69 +96,6 @@ def service():
     svc.close()
 
 
-class TestDeviceHostParity:
-    """rrf_fuse_device must be hit-for-hit with the host oracle."""
-
-    def _check(self, legs, k, rank_constant=60):
-        ds, dd = rrf_fuse_device(legs, k, rank_constant)
-        hs, hd = rrf_fuse_host(legs, k, rank_constant)
-        ds, dd = np.asarray(ds), np.asarray(dd)
-        np.testing.assert_array_equal(dd, hd)
-        # identical float32 accumulation order → exact score equality
-        finite = np.isfinite(hs)
-        np.testing.assert_array_equal(ds[finite], hs[finite])
-        assert not np.isfinite(ds[~finite]).any()
-
-    def test_random_legs(self):
-        rng = np.random.default_rng(42)
-        for trial in range(8):
-            B = int(rng.integers(1, 5))
-            ka = int(rng.integers(3, 12))
-            kb = int(rng.integers(3, 12))
-            # overlapping doc universes force cross-leg accumulation
-            la = np.stack(
-                [rng.permutation(30)[:ka] for _ in range(B)]
-            ).astype(np.int32)
-            lb = np.stack(
-                [rng.permutation(30)[:kb] for _ in range(B)]
-            ).astype(np.int32)
-            # sprinkle padding (must be ignored, not ranked)
-            la[la % 7 == 3] = -1
-            self._check((la, lb), k=int(rng.integers(3, 16)))
-
-    def test_tie_breaks_on_ascending_doc(self):
-        # doc 5 at ranks (1,2) and doc 9 at ranks (2,1): identical RRF
-        # sums — the winner must be the LOWER doc id, deterministically
-        la = np.array([[5, 9]], np.int32)
-        lb = np.array([[9, 5]], np.int32)
-        self._check((la, lb), k=2)
-        s, d = rrf_fuse_device((la, lb), 2)
-        d = np.asarray(d)
-        assert d[0, 0] == 5 and d[0, 1] == 9
-
-    def test_exact_dedup_single_contribution_per_leg(self):
-        # doc present in both legs: ONE fused slot carrying both
-        # contributions, never two slots
-        la = np.array([[7, 3, -1]], np.int32)
-        lb = np.array([[7, 11]], np.int32)
-        s, d = rrf_fuse_device((la, lb), 5)
-        d = np.asarray(d)[0]
-        valid = d[d >= 0]
-        assert len(np.unique(valid)) == len(valid)
-        assert 7 in valid
-        self._check((la, lb), k=5)
-
-    def test_three_legs(self):
-        rng = np.random.default_rng(7)
-        legs = tuple(
-            np.stack([rng.permutation(20)[:6] for _ in range(2)]).astype(
-                np.int32
-            )
-            for _ in range(3)
-        )
-        self._check(legs, k=10)
-
-
 def _random_legs(seed, n_legs, universe, width):
     rng = np.random.default_rng(seed)
     return [rng.permutation(universe)[:width].tolist() for _ in range(n_legs)]
@@ -182,12 +116,11 @@ SERVED_FUSE_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(SERVED_FUSE_CASES))
-def test_served_host_fuse_matches_oracle_and_device_program(case):
+def test_served_host_fuse_matches_oracle(case):
     """What the serving path runs (`rrf_fuse_ranked`, Python floats over
-    the keys the legs returned) against the NumPy oracle and the mesh
-    path's program on the CPU, both fed the same legs as the rows of
-    one -1-padded array: same documents in the same order, scores
-    within float32's rounding of the float64 sums."""
+    the keys the legs returned) against the NumPy oracle, fed the same
+    legs as the rows of one -1-padded array: same documents in the same
+    order, scores within float32's rounding of the float64 sums."""
     legs, k = SERVED_FUSE_CASES[case]
     served = rrf_fuse_ranked(legs, k, 60)
     width = max(len(leg) for leg in legs)
@@ -198,14 +131,11 @@ def test_served_host_fuse_matches_oracle_and_device_program(case):
     union = set().union(*legs)
     assert len(served) == min(k, len(union))
     assert len({doc for doc, _ in served}) == len(served)
-    for name, (s, d) in (("host", rrf_fuse_host(rows, k, 60)),
-                         ("device", rrf_fuse_device(rows, k, 60))):
-        s, d = np.asarray(s)[0], np.asarray(d)[0]
-        assert d[:len(served)].tolist() == [doc for doc, _ in served], name
-        assert (d[len(served):] == -1).all(), name
-        np.testing.assert_allclose(
-            s[:len(served)], [sc for _, sc in served], rtol=1e-6,
-            err_msg=name)
+    s, d = (a[0] for a in rrf_fuse_host(rows, k, 60))
+    assert d[:len(served)].tolist() == [doc for doc, _ in served]
+    assert (d[len(served):] == -1).all()
+    np.testing.assert_allclose(
+        s[:len(served)], [sc for _, sc in served], rtol=1e-6)
     if case == "two_legs_tied_at_every_rank":
         # each tie group comes out lower doc first
         a, b = legs

@@ -18,7 +18,6 @@ from elasticsearch_tpu.parallel import (
     build_sharded_bm25_step,
     build_sharded_knn_step,
     make_mesh,
-    rrf_fuse,
 )
 from elasticsearch_tpu.search import dsl
 from elasticsearch_tpu.search.executor import NumpyExecutor, ShardReader
@@ -156,24 +155,3 @@ class TestShardedKnn:
             order = np.argsort(-ref[bi], kind="stable")[:10]
             np.testing.assert_array_equal(docs[bi], order)
             np.testing.assert_allclose(scores[bi], ref[bi][order], rtol=1e-5)
-
-
-class TestRRF:
-    def test_fuse_ranks(self, sharded):
-        _, mappings, analysis, segments, _, index = sharded
-        bm25_step = build_sharded_bm25_step(index, k=10)
-        knn_step = build_sharded_knn_step(index, k=10, similarity="cosine")
-        ti, tw, tv, msm = index.compile_queries([["quick", "fox"]] * 8, ["or"] * 8)
-        lex = bm25_step(ti, tw, tv, msm)
-        rng = np.random.default_rng(5)
-        vec = knn_step(rng.standard_normal((8, 8)).astype(np.float32))
-        s, d = rrf_fuse(lex, vec, k=10)
-        s = np.asarray(s)
-        d = np.asarray(d)
-        # fused scores are RRF sums: bounded by 2/(60+1), monotone per row
-        assert (s[np.isfinite(s)] <= 2 / 61 + 1e-6).all()
-        for bi in range(s.shape[0]):
-            row = s[bi][np.isfinite(s[bi])]
-            assert (np.diff(row) <= 1e-9).all()
-            valid = d[bi][d[bi] >= 0]
-            assert len(np.unique(valid)) == len(valid), "no duplicate docs"

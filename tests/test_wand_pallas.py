@@ -1,4 +1,4 @@
-"""Block-max pruning exactness + Pallas int8 kNN kernel tests."""
+"""Block-max pruning exactness tests."""
 
 import numpy as np
 import pytest
@@ -6,7 +6,6 @@ import pytest
 from elasticsearch_tpu.analysis import AnalysisRegistry
 from elasticsearch_tpu.index.mapping import DocumentParser, Mappings
 from elasticsearch_tpu.index.segment import SegmentBuilder
-from elasticsearch_tpu.ops.pallas_knn import QuantizedVectors, quantize_int8
 from elasticsearch_tpu.ops.scoring import BPAD, ChunkedScorer
 from elasticsearch_tpu.ops.wand import BlockMaxIndex, get_tiling
 
@@ -177,52 +176,3 @@ class TestBlockMaxWand:
             np.testing.assert_allclose(s[bi][:nn], rs[bi][:nn], rtol=1e-5)
             np.testing.assert_array_equal(d[bi][:nn], rd[bi][:nn])
             assert not np.isin(d[bi][:nn], np.nonzero(~live)[0]).any()
-
-
-class TestInt8Quantization:
-    def test_quantize_roundtrip_error(self):
-        rng = np.random.default_rng(0)
-        v = rng.standard_normal((100, 64)).astype(np.float32)
-        q, scales = quantize_int8(v)
-        assert q.shape == (100, 128)  # padded to lane
-        deq = q[:, :64].astype(np.float32) * scales[:, None]
-        err = np.abs(deq - v).max()
-        assert err <= scales.max() * 0.5 + 1e-6
-
-    def test_int8_search_recall_vs_exact(self):
-        rng = np.random.default_rng(1)
-        n, d, k = 2000, 96, 10
-        vectors = rng.standard_normal((n, d)).astype(np.float32)
-        qv = QuantizedVectors(vectors, similarity="cosine")
-        queries = rng.standard_normal((4, d)).astype(np.float32)
-        s, docs = qv.search(queries, k=k, interpret=True)
-        docs = np.asarray(docs)
-        # exact reference
-        vn = vectors / np.linalg.norm(vectors, axis=1, keepdims=True)
-        qn = queries / np.linalg.norm(queries, axis=1, keepdims=True)
-        exact = (1 + qn @ vn.T) / 2
-        for bi in range(4):
-            top_exact = set(np.argsort(-exact[bi])[:k].tolist())
-            recall = len(top_exact & set(docs[bi].tolist())) / k
-            assert recall >= 0.8, f"query {bi} recall {recall}"
-
-    def test_dot_product_and_mip(self):
-        rng = np.random.default_rng(2)
-        vectors = rng.standard_normal((600, 32)).astype(np.float32)
-        for sim in ("dot_product", "max_inner_product"):
-            qv = QuantizedVectors(vectors, similarity=sim)
-            s, docs = qv.search(
-                rng.standard_normal((2, 32)), k=5, interpret=True
-            )
-            s = np.asarray(s)
-            assert np.isfinite(s).all()
-            assert (np.diff(s, axis=1) <= 1e-6).all()
-
-    def test_padding_docs_excluded(self):
-        rng = np.random.default_rng(3)
-        vectors = rng.standard_normal((100, 16)).astype(np.float32)  # < DOC_BLOCK
-        qv = QuantizedVectors(vectors, similarity="cosine")
-        s, docs = qv.search(
-            rng.standard_normal((1, 16)), k=50, interpret=True
-        )
-        assert (np.asarray(docs) < 100).all()
