@@ -46,7 +46,7 @@ from ..search.admission import (
     apply_brownout,
 )
 from ..search.coordinator import _col_key
-from ..search.executor import NumpyExecutor, ShardReader, TopDocs
+from ..search.executor import NumpyExecutor, ShardReader
 from ..search.failures import (
     SearchTimeoutError,
     deadline_from,
@@ -1862,23 +1862,21 @@ class IndexService:
         # re-sorted page. Any rerank-path failure keeps the
         # first-stage ranking (deterministic fallback, never a failed
         # request). ----
-        if rescore_spec is not None and td is not None and td.hits:
+        if rescore_spec is not None and td is not None and len(td):
             t_resc = time.perf_counter_ns()
             # the `rescore` span: the whole second stage on this thread;
             # the rerank job's spans and `rerank_plan` are its children
             tr, resc_id = tracing.reserve()
-            candidates = len(td.hits)
+            candidates = len(td)
             with tracing.under(resc_id):
                 td = self._apply_rescore(
                     ex, rescore_spec, td, sid, shard_deadline, task,
                     prof=prof_phases,
                 )
-            if len(td.hits) > page_k:
-                # the page, cut AFTER the window was ordered
-                td = TopDocs(
-                    total=td.total, hits=td.hits[:page_k],
-                    max_score=td.max_score, relation=td.relation,
-                )
+            if len(td) > page_k:
+                # the page, cut AFTER the window was ordered: of a
+                # window held as columns these are the `Hit`s made
+                td = td.head(page_k)
             t_resc_end = time.perf_counter_ns()
             if tr is not None:
                 tr.add_span(
@@ -3373,13 +3371,14 @@ class IndexService:
             # the numpy backend IS the float oracle
             return rescorer.host_rescore_topdocs(ex.reader, model, spec, td)
         t_plan = time.perf_counter_ns()
-        plan = rescorer.build_plan(
-            ex.reader, model, spec,
-            [(h.score, h.segment, h.local_doc) for h in td.hits],
-        )
+        # the window as columns: a match first stage's own download
+        # (`_collect_match_group`), any other's `Hit`s turned once; the
+        # ways out that keep the first stage return `td` as it came
+        window = td.as_columns(ex.reader)
+        plan = rescorer.build_plan(ex.reader, model, spec, *window.cols)
         try:
             job = self._batcher.submit_nowait(
-                ex, plan, len(td.hits), kind="rerank",
+                ex, plan, len(td), kind="rerank",
                 deadline=shard_deadline, prof=prof,
             )
             if job.trace is not None:
@@ -3387,7 +3386,7 @@ class IndexService:
                 # the job's submit mark, where its `queue_wait` starts
                 job.trace.add_span(
                     "rerank_plan", t_plan, job.t_enq,
-                    candidates=len(td.hits), query_vectors=len(plan.qtoks),
+                    candidates=len(td), query_vectors=len(plan.qtoks),
                 )
             got = self._wait_batched(job, sid, shard_deadline, task)
         except (
@@ -3409,10 +3408,10 @@ class IndexService:
             rerank_model.note("skipped")
             return td
         rerank_model.note_rescore(
-            min(spec.window_size, len(td.hits)), device=True,
+            min(spec.window_size, len(td)), device=True,
             kernel_ms=kernel_ms,
         )
-        return rescorer.apply_perm_to_topdocs(td, scores, perm)
+        return rescorer.apply_perm_to_topdocs(window, scores, perm)
 
     def _rescore_ranked(
         self, spec, ranked: List[tuple], pins=None, prof=None
@@ -3471,7 +3470,11 @@ class IndexService:
                         break
                     cands.append((float(score), int(loc[0]), int(loc[1])))
                 if cands is not None:
-                    plan = rescorer.build_plan(ex.reader, model, spec, cands)
+                    first, segs, docs = zip(*cands)
+                    plan = rescorer.build_plan(
+                        ex.reader, model, spec, first,
+                        np.asarray(segs, np.int64), np.asarray(docs, np.int64),
+                    )
                     try:
                         job = self._batcher.submit_nowait(
                             ex, plan, len(cands), kind="rerank",

@@ -89,10 +89,11 @@ starts at `http`'s start and reaches the ring when `http` ends:
         rescore [window, candidates]   the first stage's TopDocs -> the
             rescored page, on the request thread; tiled
             rerank_plan [candidates, query_vectors] (`build_plan`: the
-            window's doc ids and scores as arrays, the query matrix; ->
+            window's columns as they came down, the query matrix; ->
             the rerank job's submit mark) | the `rerank` job's
             queue_wait | dispatch | inflight | collect | wake, what is
-            left the permutation applied and the page cut. The FIRST
+            left the columns permuted and the page's `Hit`s made
+            (`TopDocs.head`). The FIRST
             stage's `collect` span then carries window and
             ties_refilled (`QueryBatcher._window_topk`: the window's
             cut, Lucene's).

@@ -175,6 +175,10 @@ RESCORE_STATS = {
     # launch selected from block maxima (`scoring.topk_block_rows`: a
     # plane wide enough under a k the compiler would sort it for)
     "window_block_selected": 0,
+    # `Hit`s built from a rescore window held as columns
+    # (`TopDocs.of_columns`): the page's, where nobody reads the window
+    # one candidate at a time
+    "hits_built": 0,
 }
 
 

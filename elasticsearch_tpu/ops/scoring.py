@@ -1211,6 +1211,11 @@ def is_packed(part) -> bool:
     return not isinstance(part, tuple)
 
 
+# `rank_order`'s packed key: 31 bits of doc id under (run x segments +
+# segment), which has to stay under 2**32 for the whole to fit an int64
+RANK_KEY_ROOM = 2**32
+
+
 def rank_order(scores: np.ndarray, segs: np.ndarray, docs: np.ndarray):
     """Host rows [B, k] of a top-k download, put in the engine's rank
     order: score descending, then (segment, doc) ascending (Lucene's).
@@ -1224,18 +1229,41 @@ def rank_order(scores: np.ndarray, segs: np.ndarray, docs: np.ndarray):
     Which passages of a tie group that the cut at k splits were selected
     stays the device's choice.
 
-    A row with a tie is sorted as Python lists, not by `np.lexsort`:
-    NumPy's sorts release the interpreter lock whatever the size, and a
-    dispatcher worker that lets go of it under load waits for it again."""
+    Only the tied candidates are sorted (a row comes down score
+    descending, so each maximal run of equal finite scores is put in
+    (segment, doc) order where it lies and the scores stay as they
+    are): ONE sort of the runs' members keyed by (run, segment, doc),
+    so the cost follows the ties and not the row's width. Ties are the
+    rule, not the exception, at a window's depth: of the first 1,024
+    BM25 scores of a passage question over a million passages a median
+    782 stand in 157 runs (PERF.md section 6, PR 58), so the three keys
+    ride ONE integer a member wherever they fit 63 bits (a row of k
+    ranks over S segments: k x S < 2**32) and the sort compares
+    integers; where they do not, tuples. Either way it is a sort of a
+    Python list, not `np.lexsort`: NumPy's sorts release the interpreter
+    lock whatever the size, and a dispatcher worker that lets go of it
+    under load waits for it again."""
     tied = (scores[:, 1:] == scores[:, :-1]) & np.isfinite(scores[:, 1:])
     if not tied.any():
         return scores, segs, docs
-    scores, segs, docs = scores.copy(), segs.copy(), docs.copy()
+    segs, docs = segs.copy(), docs.copy()
     for b in np.flatnonzero(tied.any(axis=1)).tolist():
-        ranked = sorted(zip((-scores[b]).tolist(), segs[b].tolist(),
-                            docs[b].tolist()))
-        negated, segs[b], docs[b] = zip(*ranked)
-        scores[b] = [-x for x in negated]
+        t = tied[b]  # t[i]: ranks i and i + 1 tie
+        member = np.zeros(len(t) + 1, bool)
+        member[1:] = t
+        member[:-1] |= t
+        at = np.flatnonzero(member)
+        # a rank's run: the boundaries without a tie in front of it
+        run = np.concatenate(([0], np.cumsum(~t)))[at]
+        sg, dc = segs[b, at], docs[b, at]
+        width = int(sg.max()) + 1
+        if len(member) * width < RANK_KEY_ROOM:
+            key = np.asarray(sorted(
+                ((run * width + sg) << 31 | dc).tolist()), np.int64)
+            segs[b, at], docs[b, at] = (key >> 31) % width, key & 0x7FFFFFFF
+        else:
+            _, segs[b, at], docs[b, at] = zip(*sorted(zip(
+                run.tolist(), sg.tolist(), dc.tolist())))
     return scores, segs, docs
 
 

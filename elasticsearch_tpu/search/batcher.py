@@ -2270,7 +2270,9 @@ class QueryBatcher:
             mtot = np.zeros((nj, 0), np.int64)
         for ji, j in enumerate(jobs):
             finite = np.isfinite(ms[ji])
-            hits = [
+            # a rescore window stays the columns it was downloaded as
+            # (`TopDocs.of_columns`): `Hit`s are made for its page alone
+            hits = None if j.window else [
                 Hit(
                     score=float(s),
                     segment=int(si),
@@ -2306,6 +2308,9 @@ class QueryBatcher:
                 hits=hits,
                 max_score=hits[0].score if hits else None,
                 relation=relation,
+            ) if not j.window else TopDocs.of_columns(
+                total, reader, ms[ji][finite][: j.k],
+                mseg[ji][finite][: j.k], mdoc[ji][finite][: j.k], relation,
             )
             j.finish()
 

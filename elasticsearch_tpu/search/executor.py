@@ -45,6 +45,7 @@ from ..index.mapping import (
 from ..index.segment import Segment
 from ..models import bm25
 from ..models import fuzzy as fuzzy_model
+from ..models import rerank as rerank_model
 from ..models.similarity import score_vectors
 from . import dsl
 from .dsl import (
@@ -74,15 +75,85 @@ class Hit:
     doc_id: str
 
 
-@dataclass
 class TopDocs:
-    total: int
-    hits: List[Hit]
-    max_score: Optional[float] = None
-    # Lucene TotalHits.Relation: "eq" when total is exact, "gte" when a
-    # pruned collection proved at least `total` matches (WANDScorer under
-    # totalHitsThreshold)
-    relation: str = "eq"
+    """A shard's ranked candidates: `total` matches under `relation`
+    (Lucene TotalHits.Relation: "eq" when total is exact, "gte" when a
+    pruned collection proved at least `total` matches — WANDScorer
+    under totalHitsThreshold), the best score, and the candidates in
+    rank order, held one of two ways. A list of `Hit`s, as every
+    executor builds it. Or COLUMNS (`of_columns`: a rescore window,
+    whose thousand candidates are ranked, rescored and permuted as
+    arrays and of which only the page is returned): `scores`,
+    `segments`, `docs` in rank order beside the reader that names their
+    documents. `len`, `head` and `as_columns` read either; `.hits` on
+    columns builds the list once, on first access, so a consumer that
+    reads `.hits` gets what a list would have given it. `Hit`s built
+    from columns count in `rescore.hits_built`."""
+
+    def __init__(self, total: int, hits: Optional[List[Hit]],
+                 max_score: Optional[float] = None, relation: str = "eq"):
+        self.total = total
+        self.max_score = max_score
+        self.relation = relation
+        self._hits = hits
+        self.reader = None
+        self.cols = None
+
+    @classmethod
+    def of_columns(cls, total: int, reader: "ShardReader",
+                   scores: np.ndarray, segments: np.ndarray,
+                   docs: np.ndarray, relation: str = "eq") -> "TopDocs":
+        """Candidates as columns in rank order (finite scores only);
+        `reader` is the point-in-time view their (segment, doc) pairs
+        index."""
+        td = cls(total, None, float(scores[0]) if len(scores) else None,
+                 relation)
+        td.reader = reader
+        td.cols = (scores, segments, docs)
+        return td
+
+    def __len__(self) -> int:
+        return len(self.cols[0] if self._hits is None else self._hits)
+
+    def __repr__(self) -> str:
+        return (f"TopDocs(total={self.total!r}, n={len(self)}, "
+                f"max_score={self.max_score!r}, relation={self.relation!r})")
+
+    def _built(self, n: int) -> List[Hit]:
+        """The first `n` columns as `Hit`s: the id look-ups happen
+        here, for the candidates somebody reads one by one."""
+        scores, segments, docs = (c[:n].tolist() for c in self.cols)
+        segs = self.reader.segments
+        rerank_model.note("hits_built", len(scores))
+        return [
+            Hit(score=s, segment=si, local_doc=d, doc_id=segs[si].doc_ids[d])
+            for s, si, d in zip(scores, segments, docs)
+        ]
+
+    @property
+    def hits(self) -> List[Hit]:
+        if self._hits is None:
+            self._hits = self._built(len(self))
+        return self._hits
+
+    def head(self, n: int) -> "TopDocs":
+        """The first `n` candidates, as built `Hit`s (a page)."""
+        hits = self._built(n) if self._hits is None else self._hits[:n]
+        return TopDocs(self.total, hits, self.max_score, self.relation)
+
+    def as_columns(self, reader: "ShardReader") -> "TopDocs":
+        """Itself where it holds columns, else its `Hit`s as columns
+        over `reader` (the view that served them), scores as float32:
+        what a rescore plans from and permutes."""
+        if self.cols is not None:
+            return self
+        return TopDocs.of_columns(
+            self.total, reader,
+            np.asarray([h.score for h in self._hits], np.float32),
+            np.asarray([h.segment for h in self._hits], np.int32),
+            np.asarray([h.local_doc for h in self._hits], np.int32),
+            self.relation,
+        )
 
 
 class ShardReader:

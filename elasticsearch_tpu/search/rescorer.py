@@ -38,13 +38,13 @@ first-stage score and order below the window.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
 from ..models import rerank as rerank_model
 from . import dsl
-from .executor import Hit, TopDocs
+from .executor import TopDocs
 
 
 @dataclass(frozen=True)
@@ -194,43 +194,38 @@ class RerankPlan:
         )
 
 
-def build_plan(reader, model, spec: RescoreSpec, cands) -> RerankPlan:
-    """cands: [(score, segment, local_doc)] in first-stage order (score
-    desc, (segment, doc) asc). Encodes (segment, doc) as global doc ids
-    over the shard-level concatenated rerank column (segment bases are
-    cumulative segment sizes — the same encoding rerank_column uses)."""
+def build_plan(reader, model, spec: RescoreSpec, first: np.ndarray,
+               segments: np.ndarray, docs: np.ndarray) -> RerankPlan:
+    """The candidates as columns in first-stage order (score desc,
+    (segment, doc) asc): a columnar `TopDocs`'s own arrays. Encodes
+    (segment, doc) as global doc ids over the shard-level concatenated
+    rerank column (segment bases are cumulative segment sizes — the
+    same encoding rerank_column uses)."""
     bases = np.zeros(len(reader.segments) + 1, np.int64)
     np.cumsum([s.num_docs for s in reader.segments], out=bases[1:])
     qtoks = rerank_model.prepare_query_vectors(
         spec.query_vectors, model.dims, model.similarity
     )
-    first = np.asarray([c[0] for c in cands], np.float32)
-    gdocs = np.asarray(
-        [bases[c[1]] + c[2] for c in cands], np.int64
-    )
-    return RerankPlan(model, spec, qtoks, first, gdocs)
+    return RerankPlan(model, spec, qtoks, np.asarray(first, np.float32),
+                      bases[segments] + docs)
 
 
 def apply_perm_to_topdocs(
     td: TopDocs, scores: np.ndarray, perm: np.ndarray
 ) -> TopDocs:
-    """Rebuilds a TopDocs from the rerank result: `perm[i]` is the
-    first-stage rank now sitting at position i, `scores[i]` its blended
-    (or retained first-stage) score."""
-    hits: List[Hit] = []
-    for s, p in zip(scores, perm):
-        if not np.isfinite(s):
-            break
-        h = td.hits[int(p)]
-        hits.append(
-            Hit(score=float(s), segment=h.segment,
-                local_doc=h.local_doc, doc_id=h.doc_id)
-        )
-    return TopDocs(
-        total=td.total,
-        hits=hits,
-        max_score=hits[0].score if hits else None,
-        relation=td.relation,
+    """The rerank result applied to a COLUMNAR first stage
+    (`TopDocs.as_columns`), as columns: `perm[i]` is the first-stage rank
+    now sitting at position i, `scores[i]` its blended (or retained
+    first-stage) score; the ranking ends at the first score that is not
+    finite. No `Hit` is made here: the page's are, by whoever cuts it
+    (`TopDocs.head`)."""
+    _, segments, docs = td.cols
+    ended = np.flatnonzero(~np.isfinite(scores))
+    keep = int(ended[0]) if len(ended) else len(scores)
+    order = perm[:keep]
+    return TopDocs.of_columns(
+        td.total, td.reader, scores[:keep], segments[order], docs[order],
+        td.relation,
     )
 
 
@@ -281,4 +276,4 @@ def host_rescore_topdocs(reader, model, spec: RescoreSpec,
     scores, perm = host_blend(reader, model, spec, cands)
     rerank_model.note_rescore(min(spec.window_size, len(cands)),
                               device=False)
-    return apply_perm_to_topdocs(td, scores, perm)
+    return apply_perm_to_topdocs(td.as_columns(reader), scores, perm)

@@ -167,6 +167,22 @@ def test_the_configurations_bodies_are_served_as_the_reference_answers(dep):
         tokens, docs, 128, 1)
 
 
+def test_hits_are_built_for_the_page_and_not_for_the_window(dep):
+    """A window of 1,000 under a page of 10 (the cell's request): the
+    window travels as columns (PR 58) and ten `Hit`s are made."""
+    body = dep.bodies[0]
+    assert body["rescore"]["window_size"] == 1000 and body["size"] == 10
+    before = dep.node()["rescore"]
+    served = dep.search(body)
+    after = dep.node()["rescore"]
+    dep.held(body, served)
+    assert len(served["hits"]["hits"]) == 10
+    assert served["hits"]["total"]["value"] > 100  # a window worth the name
+    assert after["requests"] - before["requests"] == 1
+    assert after["hits_built"] - before["hits_built"] == 10
+    assert after["device_rescores"] - before["device_rescores"] == 1
+
+
 @pytest.mark.parametrize("where", ["below", "at", "above"])
 def test_window_below_at_and_above_the_number_of_matches(dep, where):
     word, df = dep.rare_question(40, 400)
@@ -298,10 +314,16 @@ def test_a_retrievers_rescore_window_is_wider_than_its_page():
         query = {"match": {"body": "alpha"}}
         direct = svc.search({"query": query, "size": 10,
                              "rescore": rescore(qv, 100)})
+        before = rerank_model.stats_snapshot()
         ranked = svc.search({
             "retriever": {"standard": {"query": query}}, "size": 10,
             "rescore": rescore(qv, 100)})
+        after = rerank_model.stats_snapshot()
         assert pairs(ranked) == pairs(direct)
+        # its leg ran on the request's pinned executor, whose candidates
+        # are a list from the start: nothing was built from columns
+        assert after["requests"] - before["requests"] == 1
+        assert after["hits_built"] == before["hits_built"]
     finally:
         svc.close()
 

@@ -58,7 +58,7 @@ REQUIRED = {
         "fallbacks", "requests", "first_stage_kept", "columns_refused",
         "launches", "windows_docs", "tokens_scored", "slots_gathered",
         "slots_padded", "least_bytes", "window_ties_refilled",
-        "window_block_selected",
+        "window_block_selected", "hits_built",
     },
     # the benchmark's `fuzzy_*` metrics read these by dotted path
     "fuzzy": {
@@ -133,7 +133,8 @@ def test_nodes_stats_blocks_stable():
 # a benchmark metric reads is there, and two indices fold as one index did.
 
 # the dotted numeric paths of the served node below at the commit before
-# the handler became a fold (PR 56), written by `numeric_paths` then
+# the handler became a fold (PR 56), written by `numeric_paths` then, and
+# what a layer has declared since (PR 58: `rescore.hits_built`)
 PARENT_PATHS = os.path.join(HERE, "nodes_stats_paths_pr56.json")
 LAYER_METRICS = sorted(glob.glob(os.path.join(
     BENCH, "layer_metrics", "*.json")))

@@ -292,6 +292,81 @@ def test_window_launch_at_the_cells_width_sorts_nothing_a_million_wide():
     assert not _top_ks_over(text, CELL_DOCS)
 
 
+# ---- `rank_order`: the tied runs sorted where they lie (PR 58) ---------------
+
+def whole_row_rank_order(scores, segs, docs):
+    """`scoring.rank_order` as it was: a row with a tie sorted whole,
+    as Python tuples (the reference the run-wise form is held to)."""
+    scores, segs, docs = scores.copy(), segs.copy(), docs.copy()
+    for b in range(len(scores)):
+        ranked = sorted(zip((-scores[b]).tolist(), segs[b].tolist(),
+                            docs[b].tolist()))
+        negated, segs[b], docs[b] = zip(*ranked)
+        scores[b] = [-x for x in negated]
+    return scores, segs, docs
+
+
+def downloaded_rows(rng, rows: int, k: int):
+    """[rows, k] as a top-k download holds them: scores descending with
+    planted runs of exact ties (some at rank 0, some at the last real
+    rank), -inf padding last, candidates of two segments in the order a
+    device might have left them (any)."""
+    scores = np.full((rows, k), -np.inf, np.float32)
+    segs = rng.integers(0, 2, (rows, k)).astype(np.int32)
+    docs = np.stack([rng.permutation(4 * k)[:k] for _ in range(rows)]
+                    ).astype(np.int32)
+    for b in range(rows):
+        n = int(rng.integers(2, k + 1)) if b % 3 else k  # real candidates
+        distinct = int(rng.integers(1, n + 1)) if b % 4 else n  # b 0: no tie
+        vals = np.sort(rng.choice(np.arange(1, 8 * k), distinct,
+                                  replace=False))[::-1] / np.float32(4)
+        # every value once, the rest of the row repeats of some of them
+        pick = np.r_[np.arange(distinct),
+                     rng.integers(0, distinct, n - distinct)]
+        scores[b, :n] = vals[np.sort(pick)]
+    return scores, segs, docs
+
+
+@pytest.mark.parametrize("keys", ["packed", "tuples"])
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("k, rows", [(16, 24), (1024, 8)])
+def test_rank_order_sorts_the_tied_runs_as_the_whole_row_sort_did(
+        k, rows, seed, keys, monkeypatch):
+    if keys == "tuples":  # a row whose (run, segment, doc) pass 63 bits
+        monkeypatch.setattr(scoring, "RANK_KEY_ROOM", 0)
+    rng = np.random.default_rng([58, k, seed])
+    scores, segs, docs = downloaded_rows(rng, rows, k)
+    kept = (scores.copy(), segs.copy(), docs.copy())
+    got = scoring.rank_order(scores, segs, docs)
+    want = whole_row_rank_order(scores, segs, docs)
+    real = np.isfinite(scores)
+    tied_rows = 0
+    for b in range(rows):
+        n = int(real[b].sum())
+        for g, w, given in zip(got, want, kept):
+            assert g.dtype == given.dtype
+            assert g[b, :n].tolist() == w[b, :n].tolist()
+            # the padding stays where the download left it
+            assert g[b, n:].tolist() == given[b, n:].tolist()
+        tied_rows += len(set(scores[b, :n].tolist())) < n
+    assert tied_rows >= rows // 2  # the rows do hold what is tested
+    # the arguments are the caller's: never written to
+    assert all(np.array_equal(a, b) for a, b in
+               zip((scores, segs, docs), kept))
+
+
+@pytest.mark.parametrize("k", [16, 1024])
+def test_rank_order_returns_rows_without_a_tie_as_they_are(k):
+    rng = np.random.default_rng([58, k])
+    scores = np.sort(rng.permutation(8 * k)[:k].astype(np.float32))[::-1]
+    scores = np.tile(scores, (3, 1))
+    scores[1, k // 2:] = -np.inf  # padding ties with itself: not a tie
+    segs = rng.integers(0, 2, (3, k)).astype(np.int32)
+    docs = rng.integers(0, 9, (3, k)).astype(np.int32)
+    got = scoring.rank_order(scores, segs, docs)
+    assert got[0] is scores and got[1] is segs and got[2] is docs
+
+
 # ---- the served path: the rule engages, the window stays Lucene's ------------
 
 @pytest.fixture(scope="module")
